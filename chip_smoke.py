@@ -1,0 +1,548 @@
+#!/usr/bin/env python
+"""chip_smoke — the quickest proof that the system still starts on the chip.
+
+One process, one chip. Drives the system's main path once at the size
+its bench tiers call real, and checks every result against a plain
+numpy recompute over the same events:
+
+  stage "served": NEXmark q5 and q8 through SqlSession(exec_mode="graph")
+    — CREATE TABLE x3 + CREATE MATERIALIZED VIEW, chunks routed to
+    ``session.dml._targets`` exactly as ``pump_sources`` routes them, a
+    checkpoint committed on every barrier (checkpoint_frequency=1 over a
+    LocalFsObjectStore), MVs read back through a started PgServer with a
+    plain socket client. Per barrier: 200,000 events in 8,192-row chunks
+    (bench.py TIERS["full"]).
+  stage "q7": build_q7 + fuse_pipeline (the fused two-input program),
+    registered on the same kind of runtime and checkpointed. Per
+    barrier: 50,000 events in 4,096-row chunks (bench.py TIERS["mid"]).
+    SQL-planned q7 is not run (see SQL_Q7_SKIPPED).
+
+Events come from NexmarkGenerator with the spec defaults in
+NexmarkConfig (10,000 events/s, 1:3:46 person:auction:bid, hot ratios
+2/4/4) and ``--seed``.
+
+Refuses to start unless ``jax.devices()[0].platform == "tpu"``. The
+last line of stdout is one JSON object:
+``{"ok": true, "device": {"platform": ..., "kind": ..., "count": ...}}``.
+Any stage that raises, outlives the deadline or disagrees with its
+reference exits non-zero and prints no such line.
+
+``--dry-run-cpu`` runs the same stage code at a tiny size on the CPU
+backend (prints platform=cpu; what tests/test_chip_smoke.py drives).
+"""
+
+from __future__ import annotations
+
+import argparse
+import faulthandler
+import json
+import os
+import socket
+import struct
+import sys
+import tempfile
+import threading
+import time
+
+# whole-run deadline: the contract allows 1200 s, compilation included
+DEADLINE_S = 1100
+# the graph runtime's barrier deadman (RW_BARRIER_TIMEOUT_S, default
+# 120 s) must outlast a cold first-barrier compile; the run's own
+# deadline above is the real backstop
+BARRIER_TIMEOUT_S = "900"
+
+# (events per barrier, chunk rows) — bench.py TIERS "full" and "mid"
+SERVED_SHAPE = (200_000, 8_192)
+Q7_SHAPE = (50_000, 4_096)
+DRY_SHAPE = (4_000, 512)
+BARRIERS = 5
+
+SQL_Q7_SKIPPED = (
+    "SQL-planned q7 not run: its join runs interpreted with a fixed "
+    "fanout=16 and overflows the left side at the first barrier on the "
+    "CPU already (a sizing gap, not a bring-up matter)"
+)
+
+TABLE_DDL = (
+    "CREATE TABLE person (id BIGINT, name VARCHAR, city VARCHAR, "
+    "state VARCHAR, date_time TIMESTAMP)",
+    "CREATE TABLE auction (id BIGINT, item_name VARCHAR, "
+    "initial_bid BIGINT, reserve BIGINT, date_time TIMESTAMP, "
+    "expires TIMESTAMP, seller BIGINT, category BIGINT)",
+    "CREATE TABLE bid (auction BIGINT, bidder BIGINT, price BIGINT, "
+    "channel VARCHAR, date_time TIMESTAMP)",
+)
+
+
+class PgClient:
+    """Minimal pgwire v3 client (startup + simple query) that returns
+    the DataRow cells as text tuples."""
+
+    def __init__(self, port: int):
+        self.sock = socket.create_connection(("127.0.0.1", port), timeout=600)
+        body = struct.pack("!I", 196608) + b"user\0smoke\0database\0dev\0\0"
+        self.sock.sendall(struct.pack("!I", len(body) + 4) + body)
+        self._drain()
+
+    def _recv(self, n: int) -> bytes:
+        buf = bytearray()
+        while len(buf) < n:
+            got = self.sock.recv(min(1 << 20, n - len(buf)))
+            if not got:
+                raise ConnectionError("pgwire server closed the connection")
+            buf += got
+        return bytes(buf)
+
+    def _drain(self):
+        rows = []
+        while True:
+            head = self._recv(5)
+            (length,) = struct.unpack("!I", head[1:])
+            body = self._recv(length - 4)
+            tag = head[:1]
+            if tag == b"D":
+                (ncols,) = struct.unpack("!h", body[:2])
+                off, row = 2, []
+                for _ in range(ncols):
+                    (ln,) = struct.unpack("!i", body[off : off + 4])
+                    off += 4
+                    if ln < 0:
+                        row.append(None)
+                    else:
+                        row.append(body[off : off + ln].decode())
+                        off += ln
+                rows.append(tuple(row))
+            elif tag == b"E":
+                raise RuntimeError(f"pgwire error: {body!r}")
+            elif tag == b"Z":
+                return rows
+
+    def query(self, sql: str):
+        body = sql.encode() + b"\0"
+        self.sock.sendall(b"Q" + struct.pack("!I", len(body) + 4) + body)
+        return self._drain()
+
+    def close(self) -> None:
+        try:
+            self.sock.sendall(b"X" + struct.pack("!I", 4))
+        finally:
+            self.sock.close()
+
+
+class CompileMeter:
+    """Seconds jax spent tracing, lowering and compiling (or loading
+    from the persistent cache) and how many executables it asked for,
+    read once per barrier — so a compile inside a steady barrier shows
+    instead of passing for device time."""
+
+    def __init__(self):
+        import jax
+
+        self._lock = threading.Lock()
+        self._seconds, self._programs = 0.0, 0
+        jax.monitoring.register_event_duration_secs_listener(self._on_event)
+
+    def _on_event(self, name: str, secs: float, **_kw) -> None:
+        if name.startswith("/jax/core/compile/"):
+            with self._lock:
+                self._seconds += secs
+                self._programs += name.endswith("backend_compile_duration")
+
+    def take(self):
+        with self._lock:
+            out = (round(self._seconds, 3), self._programs)
+            self._seconds, self._programs = 0.0, 0
+        return out
+
+
+def _barrier_fields(barrier_s, compiled, runtime) -> dict:
+    """Per-barrier wall seconds (first apart from steady), what jax
+    compiled inside each, and the last barrier's host-clock stage
+    attribution from the runtime's own EpochTrace."""
+    tr = runtime.last_epoch_trace
+    return {
+        "first_barrier_s": round(barrier_s[0], 3),
+        "steady_barrier_s": [round(s, 3) for s in barrier_s[1:]],
+        "compile_s_per_barrier": [c[0] for c in compiled],
+        "programs_per_barrier": [c[1] for c in compiled],
+        "last_barrier_stages_ms": {
+            k: round(v, 1) for k, v in tr.stages_ms.items()
+        },
+    }
+
+
+def _poll_events(gen, events: int, chunk_events: int):
+    """One barrier's events as a source would deliver them: one poll
+    of ``chunk_events`` events at a time, split by stream. Returns
+    [(stream, host column dict)] in arrival order."""
+    out, done = [], 0
+    while done < events:
+        n = min(chunk_events, events - done)
+        done += n
+        ev = gen.next_events(n)
+        for stream in ("person", "auction", "bid"):
+            cols = ev[stream]
+            if cols and len(next(iter(cols.values()))):
+                out.append((stream, cols))
+    return out
+
+
+def _mv_backend(mview) -> str:
+    from risingwave_tpu.executors.materialize import DeviceMaterializeExecutor
+
+    if isinstance(mview, DeviceMaterializeExecutor):
+        return "device"
+    return getattr(mview, "_backend", None) or "unset"
+
+
+def _close_fragments(runtime) -> None:
+    """Graph pipelines own actor threads: a process that exits with one
+    still open aborts inside the XLA client teardown."""
+    for p in runtime.fragments.values():
+        close = getattr(p, "close", None)
+        if close is not None:
+            close()
+
+
+def _assert_checkpointed(runtime, barriers: int) -> None:
+    runtime.wait_checkpoints()
+    committed = runtime.mgr.max_committed_epoch
+    if committed != runtime.epoch:
+        raise AssertionError(
+            f"checkpoint lag: committed epoch {committed} != "
+            f"barrier epoch {runtime.epoch} after {barriers} barriers"
+        )
+
+
+def stage_served(seed, barriers, shape, state_dir, meter) -> dict:
+    """q5 + q8 through SQL -> planner -> graph runtime -> fused barrier
+    program, checkpointed every barrier, read back over pgwire."""
+    from __graft_entry__ import Q5_SQL, Q8_SQL
+    from bench import _state_cap, cpu_actor_baseline, cpu_actor_q8
+    from risingwave_tpu.array.chunk import StreamChunk
+    from risingwave_tpu.connectors.nexmark import (
+        AUCTION_SCHEMA,
+        BID_SCHEMA,
+        PERSON_SCHEMA,
+        NexmarkConfig,
+        NexmarkGenerator,
+    )
+    from risingwave_tpu.frontend import PgServer, SqlSession
+    from risingwave_tpu.queries.nexmark_q import (
+        Q5_SLIDE_MS,
+        Q5_WINDOW_MS,
+        Q8_WINDOW_MS,
+    )
+    from risingwave_tpu.runtime import StreamingRuntime
+    from risingwave_tpu.runtime.fused_step import (
+        fused_fragments,
+        fusion_refusals,
+    )
+    from risingwave_tpu.sql import Catalog
+    from risingwave_tpu.storage.object_store import LocalFsObjectStore
+
+    events, chunk_events = shape
+    schemas = {
+        "person": PERSON_SCHEMA,
+        "auction": AUCTION_SCHEMA,
+        "bid": BID_SCHEMA,
+    }
+    fusion_refusals(clear=True)
+    runtime = StreamingRuntime(LocalFsObjectStore(state_dir))
+    capacity = _state_cap(2 * events, 1 << 16)
+    session = SqlSession(
+        Catalog({}), runtime, capacity=capacity, exec_mode="graph"
+    )
+    pg = client = None
+    try:
+        t0 = time.perf_counter()
+        for sql in TABLE_DDL + (Q5_SQL, Q8_SQL):
+            session.execute(sql)
+        ddl_s = time.perf_counter() - t0
+        pg = PgServer(session, port=0).start()
+        # every VARCHAR lane shares the session dictionary, so the codes
+        # the generator emits decode at the pgwire result edge
+        gen = NexmarkGenerator(
+            NexmarkConfig(),
+            seed=seed,
+            dictionaries={
+                k: session.strings
+                for k in NexmarkGenerator.make_dictionaries()
+            },
+        )
+        host = []  # every (stream, cols) polled, for the reference
+        barrier_s, compiled = [], []
+        ddl_compile = meter.take()
+        for _ in range(barriers):
+            polled = _poll_events(gen, events, chunk_events)
+            host.extend(polled)
+            t0 = time.perf_counter()
+            with runtime.lock:
+                for stream, cols in polled:
+                    chunk = StreamChunk.from_numpy(
+                        cols, chunk_events, schema=schemas[stream]
+                    )
+                    for frag, side in session.dml._targets.get(stream, ()):
+                        runtime.push(frag, chunk, side)
+            runtime.barrier()
+            barrier_s.append(time.perf_counter() - t0)
+            compiled.append(meter.take())
+        _assert_checkpointed(runtime, barriers)
+
+        t0 = time.perf_counter()
+        client = PgClient(pg.port)
+        got5 = {
+            (int(a), int(w)): int(n)
+            for a, w, n in client.query(
+                "SELECT auction, window_start, num FROM q5"
+            )
+        }
+        got8 = {
+            (int(i), int(w)): nm
+            for i, nm, w in client.query(
+                "SELECT id, name, starttime FROM q8"
+            )
+        }
+        read_s = time.perf_counter() - t0
+
+        _, want5 = cpu_actor_baseline(
+            [c for s, c in host if s == "bid"], Q5_WINDOW_MS, Q5_SLIDE_MS
+        )
+        _, want8_codes = cpu_actor_q8(
+            [
+                ("p" if s == "person" else "a", c)
+                for s, c in host
+                if s != "bid"
+            ],
+            Q8_WINDOW_MS,
+        )
+        want8 = {
+            k: session.strings.decode_one(int(code))
+            for k, code in want8_codes.items()
+        }
+        q5_fused = fused_fragments(runtime.fragments["q5"])
+        q8_fused = fused_fragments(runtime.fragments["q8"])
+        out = {
+            "stage": "served",
+            "queries": ["q5", "q8"],
+            "events": barriers * events,
+            "events_per_barrier": events,
+            "chunk_rows": chunk_events,
+            "barriers": barriers,
+            "session_capacity": capacity,
+            "ddl_s": round(ddl_s, 3),
+            "ddl_compile_s": ddl_compile[0],
+            **_barrier_fields(barrier_s, compiled, runtime),
+            "pgwire_read_s": round(read_s, 3),
+            "q5_mv_rows": len(got5),
+            "q8_mv_rows": len(got8),
+            "mv_backend": {
+                t: _mv_backend(session.batch.tables[t])
+                for t in ("q5", "q8", "person", "auction", "bid")
+            },
+            "state_bytes": runtime.state_nbytes(),
+            "q5_fused": q5_fused,
+            "q8_fused": q8_fused,
+            "fusion_refusals": fusion_refusals(),
+            "q5_correct": got5 == want5,
+            "q8_correct": got8 == want8,
+        }
+        print(json.dumps(out), flush=True)
+        if not (out["q5_correct"] and out["q8_correct"]):
+            raise AssertionError(
+                f"served MVs != numpy recompute: q5 {len(got5)} vs "
+                f"{len(want5)} rows, q8 {len(got8)} vs {len(want8)} rows"
+            )
+        if not want5 or not want8:
+            raise AssertionError("empty reference result")
+        if not (q5_fused["count"] == 1 and q5_fused["whole_chain"]):
+            raise AssertionError(
+                f"q5 did not run as one whole-chain fused program: "
+                f"{q5_fused}"
+            )
+        return out
+    finally:
+        if client is not None:
+            client.close()
+        if pg is not None:
+            pg.shutdown()
+        session.close()
+        _close_fragments(runtime)
+
+
+def stage_q7(seed, barriers, shape, state_dir, meter) -> dict:
+    """q7 through the fused two-input program (build_q7 +
+    fuse_pipeline), registered on a checkpointing runtime."""
+    from bench import _state_cap, cpu_actor_q7
+    from risingwave_tpu.array.chunk import StreamChunk
+    from risingwave_tpu.connectors.nexmark import (
+        NexmarkConfig,
+        NexmarkGenerator,
+    )
+    from risingwave_tpu.queries.nexmark_q import build_q7
+    from risingwave_tpu.runtime import StreamingRuntime
+    from risingwave_tpu.runtime.fused_step import (
+        FusedTwoInputExecutor,
+        fuse_pipeline,
+        fused_fragments,
+        fusion_refusals,
+    )
+    from risingwave_tpu.storage.object_store import LocalFsObjectStore
+
+    events, chunk_events = shape
+    window_ms = 10_000
+    fusion_refusals(clear=True)
+    runtime = StreamingRuntime(LocalFsObjectStore(state_dir))
+    cap = _state_cap(events, 1 << 16)
+    q7 = build_q7(
+        capacity=cap,
+        fanout=16,
+        out_cap=1 << 14,
+        window_ms=window_ms,
+        agg_capacity=cap,
+        filter_capacity=cap,
+    )
+    wrappers = fuse_pipeline(q7.pipeline, label="q7")
+    runtime.register("q7", q7.pipeline)
+    try:
+        gen = NexmarkGenerator(NexmarkConfig(), seed=seed)
+        keep = ("auction", "bidder", "price", "date_time")
+        host, barrier_s, compiled = [], [], []
+        meter.take()
+        for _ in range(barriers):
+            bids = [
+                {k: cols[k] for k in keep}
+                for stream, cols in _poll_events(gen, events, chunk_events)
+                if stream == "bid"
+            ]
+            host.extend(bids)
+            t0 = time.perf_counter()
+            with runtime.lock:
+                for cols in bids:
+                    runtime.push(
+                        "q7", StreamChunk.from_numpy(cols, chunk_events), "both"
+                    )
+            runtime.barrier()
+            with runtime.lock:
+                # watermarks bound bid-side state to the open windows
+                q7.pipeline.watermark(
+                    "date_time", int(bids[-1]["date_time"].max())
+                )
+            barrier_s.append(time.perf_counter() - t0)
+            compiled.append(meter.take())
+        _assert_checkpointed(runtime, barriers)
+
+        got = q7.mview.snapshot()
+        _, want = cpu_actor_q7(host, window_ms)
+        fused = fused_fragments(q7.pipeline)
+        refusals = fusion_refusals()
+        out = {
+            "stage": "q7",
+            "queries": ["q7"],
+            "events": barriers * events,
+            "events_per_barrier": events,
+            "chunk_rows": chunk_events,
+            "barriers": barriers,
+            "capacity": cap,
+            **_barrier_fields(barrier_s, compiled, runtime),
+            "q7_mv_rows": len(got),
+            "mv_backend": {"q7": _mv_backend(q7.mview)},
+            "state_bytes": runtime.state_nbytes(),
+            "q7_fused": fused,
+            "fusion_refusals": refusals,
+            "q7_correct": got == want,
+        }
+        print(json.dumps(out), flush=True)
+        if not out["q7_correct"] or not want:
+            raise AssertionError(
+                f"q7 MV != numpy recompute: {len(got)} vs {len(want)} rows"
+            )
+        if not (
+            len(wrappers) == 1
+            and isinstance(wrappers[0], FusedTwoInputExecutor)
+            and fused["whole_chain"]
+        ):
+            raise AssertionError(
+                f"q7 did not run as one FusedTwoInputExecutor: {fused}"
+            )
+        if refusals:
+            raise AssertionError(f"q7 fusion refused: {refusals}")
+        return out
+    finally:
+        _close_fragments(runtime)
+
+
+def main(argv=None) -> int:
+    ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
+    ap.add_argument("--seed", type=int, default=42)
+    ap.add_argument(
+        "--barriers",
+        type=int,
+        default=BARRIERS,
+        help="barriers per stage (the per-barrier shape is never cut)",
+    )
+    ap.add_argument(
+        "--dry-run-cpu",
+        action="store_true",
+        help="same stage code at a tiny size on the CPU backend",
+    )
+    args = ap.parse_args(argv)
+
+    faulthandler.dump_traceback_later(DEADLINE_S, exit=True)
+    import jax
+
+    from risingwave_tpu.config import enable_compile_cache, select_device
+
+    dev = select_device("cpu" if args.dry_run_cpu else "tpu")
+    device = {
+        "platform": dev.platform,
+        "kind": dev.device_kind,
+        "count": len(jax.devices()),
+    }
+    print(
+        f"platform={device['platform']} device_kind={device['kind']!r} "
+        f"device_count={device['count']} jax={jax.__version__}",
+        flush=True,
+    )
+    if args.dry_run_cpu:
+        served_shape = q7_shape = DRY_SHAPE
+        barriers = 2
+        print(
+            f"DRY RUN on cpu: {barriers} barriers x {DRY_SHAPE[0]} events "
+            f"in {DRY_SHAPE[1]}-row chunks; no device number comes from "
+            "this run",
+            flush=True,
+        )
+    else:
+        served_shape, q7_shape, barriers = SERVED_SHAPE, Q7_SHAPE, args.barriers
+        if barriers < BARRIERS:
+            print(
+                f"CUT: {barriers} barriers per stage instead of {BARRIERS} "
+                "(per-barrier shape unchanged)",
+                flush=True,
+            )
+
+    os.environ.setdefault("RW_BARRIER_TIMEOUT_S", BARRIER_TIMEOUT_S)
+    print(
+        f"compile_cache={enable_compile_cache()} "
+        f"RW_BARRIER_TIMEOUT_S={os.environ['RW_BARRIER_TIMEOUT_S']} "
+        f"seed={args.seed}",
+        flush=True,
+    )
+    print(SQL_Q7_SKIPPED, flush=True)
+    meter = CompileMeter()
+    with tempfile.TemporaryDirectory(prefix="chip_smoke_") as tmp:
+        stage_served(
+            args.seed, barriers, served_shape, f"{tmp}/served", meter
+        )
+        stage_q7(args.seed, barriers, q7_shape, f"{tmp}/q7", meter)
+    faulthandler.cancel_dump_traceback_later()
+    doc = {"ok": True, "device": device}
+    if args.dry_run_cpu:
+        doc["dry_run"] = True
+    print(json.dumps(doc), flush=True)
+    return 0
+
+
+if __name__ == "__main__":
+    sys.exit(main())

@@ -39,32 +39,6 @@ import jax as _jax
 # BIGINT truncation and is unsupported.
 _jax.config.update("jax_enable_x64", True)
 
-# jax < 0.5 ships shard_map under jax.experimental and spells the
-# replication-check kwarg ``check_rep`` (renamed ``check_vma`` later).
-# The sharded fragments use the modern spelling (``jax.shard_map`` +
-# ``check_vma``); shim whichever implementation this image has so one
-# tree runs on both — without this every parallel/* module dies with
-# AttributeError/TypeError on older images.
-if hasattr(_jax, "shard_map"):
-    _shard_map = _jax.shard_map
-else:
-    from jax.experimental.shard_map import shard_map as _shard_map
-
-import inspect as _inspect
-
-if "check_vma" not in _inspect.signature(_shard_map).parameters:
-
-    def _compat_shard_map(f=None, *, _inner=_shard_map, **kw):
-        if "check_vma" in kw:
-            kw["check_rep"] = kw.pop("check_vma")
-        if f is None:
-            return lambda g: _inner(g, **kw)
-        return _inner(f, **kw)
-
-    _jax.shard_map = _compat_shard_map
-elif not hasattr(_jax, "shard_map"):
-    _jax.shard_map = _shard_map
-
 from risingwave_tpu.types import DataType, Field, Op, Schema
 from risingwave_tpu.array.chunk import DataChunk, StreamChunk
 from risingwave_tpu.array.dictionary import StringDictionary

@@ -201,11 +201,10 @@ def run(port: int, state_dir: str, device: str = "cpu") -> None:
     direct module execution — ONE place defines the role's setup."""
     import os
 
-    if device == "cpu":
-        os.environ["JAX_PLATFORMS"] = "cpu"
-        import jax
+    from risingwave_tpu.config import enable_compile_cache, select_device
 
-        jax.config.update("jax_platforms", "cpu")
+    select_device(device)
+    enable_compile_cache()
     # cross-process failpoint (the reference's fail::fail_point! over
     # its sync-point sites): RW_TPU_FAULT="<sync_point>:<nth>" arms the
     # named sync point to raise on its nth hit — tests drive exact

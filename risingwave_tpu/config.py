@@ -14,10 +14,8 @@ unrecognized-capture pattern.
 
 from __future__ import annotations
 
-try:
-    import tomllib
-except ModuleNotFoundError:  # python < 3.11
-    import tomli as tomllib
+import os
+import tomllib
 from dataclasses import dataclass, field, fields
 from typing import Any, Dict, Optional
 
@@ -161,40 +159,55 @@ def load_config(
     return cfg
 
 
-def enable_compile_cache(cache_dir: str = None) -> str:
-    """Point JAX's persistent XLA compilation cache at ``cache_dir``
-    (default: ``<repo>/.jax_cache``) so identical compiles re-load
-    across processes — bench children, watcher re-runs, and test runs
-    all share it. Best-effort: returns the dir, or "" on refusal."""
-    import os
+def select_device(device: str):
+    """Bind this process to the backend asked for (``"tpu"`` or
+    ``"cpu"``) and fail at start when jax found another one — an entry
+    point never falls back to a backend nobody asked for. Names the
+    platform and ``device_kind`` it runs on (on stderr: stdout belongs
+    to the caller's protocol); returns the first device."""
+    import sys
 
     import jax
 
-    base = (
-        cache_dir
-        or os.environ.get("JAX_COMPILATION_CACHE_DIR")
-        or os.path.join(
-            os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
-            ".jax_cache",
+    if device == "cpu":
+        jax.config.update("jax_platforms", "cpu")
+    dev = jax.devices()[0]
+    if dev.platform != device:
+        raise SystemExit(
+            f"device {device!r} was asked for, jax found platform="
+            f"{dev.platform!r} (device_kind {dev.device_kind!r})"
         )
+    print(
+        f"platform={dev.platform} device_kind={dev.device_kind!r} "
+        f"device_count={len(jax.devices())} jax={jax.__version__}",
+        file=sys.stderr,
+        flush=True,
     )
-    # partition by platform context: XLA:CPU AOT results embed target-
-    # machine features that vary with XLA_FLAGS/platform — loading a
-    # bench-context artifact under pytest warns about feature
-    # mismatches and risks SIGILL
-    import hashlib
+    return dev
 
-    ctx = "{}|{}".format(
-        os.environ.get("JAX_PLATFORMS", ""),
-        os.environ.get("XLA_FLAGS", ""),
-    )
-    d = os.path.join(base, hashlib.sha1(ctx.encode()).hexdigest()[:8])
-    try:
-        os.makedirs(d, exist_ok=True)
-        jax.config.update("jax_compilation_cache_dir", d)
-        jax.config.update("jax_persistent_cache_min_compile_time_secs", 1.0)
-        # children inherit the BASE dir and derive their own context
-        os.environ.setdefault("JAX_COMPILATION_CACHE_DIR", base)
-    except Exception:
-        return ""
-    return d
+
+# the one default location: fixed under the checkout, because the path
+# is part of what makes a cache entry findable again — nothing here may
+# come from the environment, a pid or the clock
+DEFAULT_COMPILE_CACHE_DIR = os.path.join(
+    os.path.dirname(os.path.dirname(os.path.abspath(__file__))), ".jax_cache"
+)
+
+
+def enable_compile_cache() -> str:
+    """Turn on JAX's persistent XLA compilation cache so identical
+    compiles re-load across processes (``serve``, ``compute-node``,
+    ``bench.py`` children, ``chip_smoke.py`` and the tests all call
+    this one function). Where ``JAX_COMPILATION_CACHE_DIR`` is set, jax
+    reads it on its own and no directory is set here; otherwise the
+    cache is ``<checkout>/.jax_cache`` itself. jax keys every entry by
+    backend, device kind and XLA flags, so one flat directory serves
+    every context. The environment is never written. Returns the
+    directory in effect."""
+    import jax
+
+    if not os.environ.get("JAX_COMPILATION_CACHE_DIR"):
+        jax.config.update(
+            "jax_compilation_cache_dir", DEFAULT_COMPILE_CACHE_DIR
+        )
+    return jax.config.jax_compilation_cache_dir

@@ -37,8 +37,7 @@ Recording sites (grow as subsystems need them):
 - ``restored``       — degraded spill fully replayed, store healthy
 - ``degraded_discard`` — recovery discarded a stale degraded spill
                        (sources replay those epochs instead)
-- ``device_state``   — blackbox sentinel (or the out-of-process tunnel
-                       prober) observed an ALIVE/SLOW/WEDGED transition
+- ``device_state``   — blackbox sentinel observed an ALIVE/SLOW/WEDGED transition
 - ``wedge_dump``     — blackbox sentinel captured a WEDGE_*.json
                        forensic bundle for a wedged device
 - ``recompile_hazard`` — SignatureWatch saw a post-warmup novel

@@ -92,7 +92,9 @@ class _Conn(socketserver.BaseRequestHandler):
     @staticmethod
     def _data_rows(cols) -> bytes:
         names = list(cols)
-        out = b""
+        # joined once: appending to one bytes object copies the whole
+        # reply per row, quadratic in the size of a full-MV read
+        out = []
         n = len(cols[names[0]]) if names else 0
         for i in range(n):
             row = b""
@@ -105,8 +107,8 @@ class _Conn(socketserver.BaseRequestHandler):
                         v.item() if hasattr(v, "item") else v
                     ).encode()
                     row += struct.pack("!i", len(s)) + s
-            out += _msg(b"D", struct.pack("!h", len(names)) + row)
-        return out
+            out.append(_msg(b"D", struct.pack("!h", len(names)) + row))
+        return b"".join(out)
 
     @staticmethod
     def _bind_params(sql: str, params) -> str:

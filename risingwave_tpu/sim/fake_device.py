@@ -67,7 +67,7 @@ class WedgeableDevice:
         with self._lock:
             self.beats += 1
         if self.latency_s:
-            # injected latency models a SLOW (congested-tunnel) device
+            # injected latency models a SLOW device
             threading.Event().wait(self.latency_s)
         if self._wedged.is_set():
             with self._lock:

@@ -20,9 +20,9 @@ writes ``BENCH_SERVING.json``:
    Reported: reader p50/p99, reads/s, barrier p99 under read load vs
    idle, registry publish overhead per barrier.
 
-CPU-safe by default (the artifact is a serving-tier scaling proof, not
-a TPU kernel number); run on device hardware via the usual bench
-babysitter for HBM-scale numbers.
+Runs on the CPU by default (the artifact is a serving-tier scaling
+proof, not a device number); ``--device tpu`` through the chip tool for
+HBM-scale numbers.
 """
 
 from __future__ import annotations
@@ -375,14 +375,12 @@ def main(argv=None) -> int:
     ap.add_argument("--read-seconds", type=float, default=4.0)
     ap.add_argument("--exec-mode", default="graph")
     ap.add_argument("--out", default=os.path.join(ROOT, "BENCH_SERVING.json"))
-    ap.add_argument("--device", choices=["auto", "cpu"], default="cpu")
+    ap.add_argument("--device", choices=["tpu", "cpu"], default="cpu")
     args = ap.parse_args(argv)
-    if args.device == "cpu":
-        os.environ["JAX_PLATFORMS"] = "cpu"
-        import jax
-
-        jax.config.update("jax_platforms", "cpu")
+    from risingwave_tpu.config import select_device
     from risingwave_tpu.provenance import stamp
+
+    select_device(args.device)
 
     out = run_serving(
         mvs=args.mvs,

@@ -5,6 +5,7 @@ compact_chunk.rs)."""
 import json
 
 import numpy as np
+import pytest
 
 from risingwave_tpu.array.chunk import StreamChunk
 from risingwave_tpu.config import RwConfig, load_config
@@ -190,14 +191,20 @@ def test_roofline_fields_and_stage_breakdown():
         stage_breakdown,
     )
 
-    rf = roofline(10 * 10**9, 1.0, platform="cpu")
+    rf = roofline(10 * 10**9, 1.0, device_kind="cpu")
     assert rf["achieved_bw_gbps"] == 10.0
     assert 0.0 < rf["achieved_bw_frac"] <= 1.0
     assert rf["achieved_bw_frac"] == round(10.0 / rf["hbm_peak_gbps"], 6)
     assert roofline(0, 0.0)["achieved_bw_frac"] == 0.0
+    # the peak table is keyed by jax's device_kind: the v5e reports
+    # itself as "TPU v5 lite"; a kind the table does not know is an
+    # error, never a default
+    assert hbm_peak_gbps("TPU v5 lite") == 819.0
+    with pytest.raises(KeyError, match="TPU v9"):
+        hbm_peak_gbps("TPU v9")
     os.environ["RW_HBM_PEAK_GBPS"] = "123.0"
     try:
-        assert hbm_peak_gbps("tpu") == 123.0
+        assert hbm_peak_gbps("TPU v9") == 123.0
     finally:
         del os.environ["RW_HBM_PEAK_GBPS"]
 
