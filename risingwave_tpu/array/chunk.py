@@ -33,6 +33,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from risingwave_tpu.trace import span
 from risingwave_tpu.types import Schema, op_sign
 
 
@@ -211,6 +212,10 @@ class StreamChunk(DataChunk):
     ops: jnp.ndarray = None  # (capacity,) int32 of types.Op; required —
     # dataclass inheritance forces a default, __post_init__ rejects None
 
+    # rows a chunk built on the host holds (from_numpy); None once a
+    # device step derived the chunk. Not a field, not a pytree leaf.
+    host_rows = None
+
     def __post_init__(self):
         if self.ops is None:
             raise TypeError(
@@ -258,16 +263,23 @@ class StreamChunk(DataChunk):
         schema: Optional[Schema] = None,
         nulls: Optional[Mapping[str, np.ndarray]] = None,
     ) -> "StreamChunk":
-        base = DataChunk.from_numpy(cols, capacity, schema, nulls)
-        if ops is None:
-            dev_ops = jnp.zeros(capacity, dtype=jnp.int32)
-        else:
-            pad = np.zeros(capacity, dtype=np.int32)
-            pad[: len(ops)] = np.asarray(ops, dtype=np.int32)
-            dev_ops = jnp.asarray(pad)
-        return StreamChunk(
+        # the host knows how many lanes hold a row without asking the
+        # device: the spans of a push and of an actor's chunk carry it
+        rows = _common_len(cols)
+        # pad to the chunk's capacity and hand the lanes to the device
+        with span("ingest.chunk_build", rows=rows, capacity=capacity):
+            base = DataChunk.from_numpy(cols, capacity, schema, nulls)
+            if ops is None:
+                dev_ops = jnp.zeros(capacity, dtype=jnp.int32)
+            else:
+                pad = np.zeros(capacity, dtype=np.int32)
+                pad[: len(ops)] = np.asarray(ops, dtype=np.int32)
+                dev_ops = jnp.asarray(pad)
+        chunk = StreamChunk(
             columns=base.columns, valid=base.valid, nulls=base.nulls, ops=dev_ops
         )
+        chunk.host_rows = rows
+        return chunk
 
     # -- semantics ------------------------------------------------------
     def signs(self) -> jnp.ndarray:
@@ -282,12 +294,16 @@ class StreamChunk(DataChunk):
         new = dict(self.columns)
         new.update(cols)
         nulls = {n: a for n, a in self.nulls.items() if n not in cols}
-        return StreamChunk(new, self.valid, nulls, self.ops)
+        out = StreamChunk(new, self.valid, nulls, self.ops)
+        out.host_rows = self.host_rows  # same rows, more lanes
+        return out
 
     def with_nulls(self, **lanes: jnp.ndarray) -> "StreamChunk":
         new = dict(self.nulls)
         new.update(lanes)
-        return StreamChunk(self.columns, self.valid, new, self.ops)
+        out = StreamChunk(self.columns, self.valid, new, self.ops)
+        out.host_rows = self.host_rows
+        return out
 
     def select(self, names) -> "StreamChunk":
         return StreamChunk(

@@ -27,6 +27,8 @@ from typing import Iterable, List, Sequence
 
 import numpy as np
 
+from risingwave_tpu.trace import span
+
 
 class StringDictionary:
     """Bidirectional append-only str <-> int32 code mapping.
@@ -63,9 +65,12 @@ class StringDictionary:
 
     def encode(self, values: Sequence[str]) -> np.ndarray:
         """Vector encode; assigns fresh codes to unseen strings."""
-        return np.fromiter(
-            (self.encode_one(s) for s in values), dtype=np.int32, count=len(values)
-        )
+        with span("ingest.encode", strings=len(values)):
+            return np.fromiter(
+                (self.encode_one(s) for s in values),
+                dtype=np.int32,
+                count=len(values),
+            )
 
     def decode_one(self, code: int) -> str:
         return self._strings[code]

@@ -25,13 +25,12 @@ recovers independently through the single-node replay protocol
 from __future__ import annotations
 
 import re
-import time
 from typing import Dict, List, Optional, Sequence
 
 import numpy as np
 
 from risingwave_tpu.cluster.client import ComputeClient
-from risingwave_tpu.epoch_trace import record_stage
+from risingwave_tpu.trace import span
 from risingwave_tpu.event_log import EVENT_LOG
 from risingwave_tpu.resilience import (
     CircuitBreaker,
@@ -222,22 +221,17 @@ class ShardedClusterClient:
         bounded by the recover policy's deadline and the node breaker."""
         epochs = []
         for i, node in enumerate(self.nodes):
-            t0 = time.perf_counter()
-            try:
-                if node.sock is None:  # killed: socket torn down
-                    raise ConnectionError("node down")
-                epochs.append(node.barrier())
-            except _NODE_TRANSIENT as e:
-                epochs.append(
-                    self._recover_node(i, node, e, node.barrier)
-                )
             # per-node barrier RTT: the cross-node half of the epoch's
             # stage attribution (wire + that node's full commit)
-            record_stage(
-                "node_commit",
-                (time.perf_counter() - t0) * 1e3,
-                fragment=f"node{i}",
-            )
+            with span("node.commit", stage="node_commit", fragment=f"node{i}"):
+                try:
+                    if node.sock is None:  # killed: socket torn down
+                        raise ConnectionError("node down")
+                    epochs.append(node.barrier())
+                except _NODE_TRANSIENT as e:
+                    epochs.append(
+                        self._recover_node(i, node, e, node.barrier)
+                    )
         return epochs
 
     # -- reads (scatter-gather) -------------------------------------------

@@ -5,14 +5,17 @@ dumps.
 Reference: the reference threads ``tracing`` spans through every actor,
 dumps await trees on stall (src/utils/runtime/), and attributes barrier
 latency per stage in its grafana dashboards. Here every barrier gets an
-``EpochTrace``: the runtime stamps each lifecycle stage (chunk ingest,
-dispatch/flush, device step, checkpoint staging, SST upload, manifest
-commit) into it, mirrors the stage durations into the
-``barrier_stage_ms{stage=...}`` histogram (prometheus + chrome-trace via
-trace.span), and derives per-barrier HBM telemetry: bytes touched =
-device-state delta (utils_heap accounting) + chunk bytes moved, reported
-as achieved bandwidth vs the configured chip peak so every bench JSON
-carries a MEASURED roofline fraction (PROFILE.md "measured vs modeled").
+``EpochTrace``, and it is fed from ``trace.span``: a span opened with
+``stage=`` reports its duration to the stage sink bound on its thread
+(``trace.bind``) — the epoch's EpochTrace on the barrier's thread and on
+the checkpoint worker, a ``StageSums`` on a pushing or an actor thread,
+folded into the EpochTrace when the barrier comes. ``add_stage`` mirrors
+every stamp into the ``barrier_stage_ms{stage,fragment}`` histogram
+(prometheus). From the stages and the state's size it derives
+per-barrier HBM telemetry: bytes touched = device-state delta
+(utils_heap accounting) + chunk bytes moved, reported as achieved
+bandwidth vs the configured chip peak so every bench JSON carries a
+MEASURED roofline fraction (PROFILE.md "measured vs modeled").
 
 ``dump_stalls()`` is the q7-wedge forensic path: when a barrier exceeds
 its deadline, snapshot every thread's open span stack, each actor's
@@ -105,19 +108,89 @@ def record_stage(stage: str, ms: float, fragment: str = "-") -> None:
     )
 
 
+class StageSums:
+    """Stage sink of a thread whose epoch has no EpochTrace yet (pushes
+    before their barrier) or lives elsewhere (an actor): sums per
+    (stage, fragment), handed over with ``take`` when the barrier
+    comes."""
+
+    epoch = None
+
+    def __init__(self):
+        self._ms: Dict[tuple, float] = {}
+
+    def add_stage(self, stage: str, ms: float, fragment: str = "-") -> None:
+        key = (stage, fragment)
+        self._ms[key] = self._ms.get(key, 0.0) + ms
+
+    def get(self, stage: str, fragment: str = "-") -> float:
+        return self._ms.get((stage, fragment), 0.0)
+
+    def take(self) -> Dict[tuple, float]:
+        out, self._ms = self._ms, {}
+        return out
+
+
 @dataclass
 class EpochTrace:
     """Everything one barrier did, attributed by lifecycle stage.
 
-    ``stages_ms`` keys (the barrier lifecycle):
-      ingest          — host time in push() since the previous barrier
-      dispatch        — per-fragment barrier walk (flush + routing)
-      device_step     — barrier-fence device wait (block_until_ready +
-                        staged-scalar materialization; the ONLY forced
-                        sync, at the barrier)
-      checkpoint_stage— delta pull + mark flips (mgr.stage)
-      upload          — SST build + object-store puts
-      manifest_commit — version write (the durability point)
+    ``stages_ms`` keys, each the sum of the spans that carry it as
+    ``stage=`` (span names in brackets; a child key ``parent.child``
+    lies inside its parent's time and never changes what the parent
+    holds). A key whose span did not run in an epoch reads 0.0; a key
+    that is absent was never instrumented on that path. ``compile``
+    alone appears only when it happened.
+
+      ingest                 — [push] host time in push() since the
+                               previous barrier
+      ingest.permit_wait     — [push.permit_wait] of it, blocked on a
+                               graph channel's permits
+      dispatch               — [barrier.fragment] per-fragment barrier
+                               walk: inject, wait, drain, route
+      dispatch.drain         — [dispatch.drain] barrier injected -> the
+                               fragment's last actor has taken it off
+                               its channels (the actors finishing the
+                               epoch's queued chunks)
+      dispatch.flush         — [dispatch.flush] from there to collected
+                               (flush, finish_barrier fence, drain)
+      actor_busy.<actor>     — [actor.chunk] an actor's chunk time in
+      actor_idle.<actor>       the epoch less its blocked part, [actor.
+      actor_blocked.<actor>    idle] its wait on empty inputs, [actor.
+                               blocked] its wait for downstream permits
+      actor_fence.<actor>    — [actor.fence] finish_barrier: the
+                               barrier-only device fence
+      actor.mv_apply         — [mv.apply] host-map MV applies
+      dispatch.fence         — [pipeline.fence] a serial pipeline's
+                               finish_barrier: the barrier-only device
+                               fence (block_until_ready + staged-scalar
+                               materialization)
+      device_step            — written by nobody: the fence lies inside
+                               dispatch's wall (dispatch.fence, actor_
+                               fence.*); the benchmark's dispatch.ms_
+                               per_barrier still adds the key, as 0
+      checkpoint_stage       — [checkpoint.stage] delta pull + mark
+                               flips (mgr.stage), on the barrier's
+                               thread
+      checkpoint_stage.pull  — [checkpoint.pull] pull_rows: gather
+                               dispatch + the device->host copy
+      checkpoint_stage.dictionary — [checkpoint.dictionary] the session
+                               dictionary's persistence
+      upload                 — [checkpoint.upload] SST build + put, on
+      manifest_commit          the checkpoint worker; [checkpoint.
+                               manifest] version write (the durability
+                               point). Land after ``finalize``.
+      publish                — [barrier.publish] arrangements.publish:
+                               to the point a reader can see the epoch
+      bookkeeping            — [barrier.bookkeeping] state_nbytes,
+                               finalize, freshness, governors,
+                               MESHPROF, flight recorder, event log
+      compile                — [compile] jax trace/lower/compile that
+                               fell into the epoch, on any thread
+
+    ``wall_ms`` closes in ``finalize`` (the flight recorder, itself
+    bookkeeping, reads it): ``publish`` and ``bookkeeping`` lie after
+    it, as do the worker's stages.
     """
 
     epoch: int
@@ -166,11 +239,18 @@ class EpochTrace:
 
     def add_stage(self, stage: str, ms: float, fragment: str = "-") -> None:
         self.stages_ms[stage] = self.stages_ms.get(stage, 0.0) + ms
-        if fragment != "-":
+        if fragment != "-" and "." not in stage:
+            # a child stage lies inside its parent's wall
             self.fragment_ms[fragment] = (
                 self.fragment_ms.get(fragment, 0.0) + ms
             )
         record_stage(stage, ms, fragment)
+
+    def declare(self, *stages: str) -> None:
+        """These stages are instrumented on this barrier's path: one
+        whose span does not run reads 0.0, not absent."""
+        for s in stages:
+            self.stages_ms.setdefault(s, 0.0)
 
     def finalize(
         self,

@@ -23,6 +23,7 @@ import numpy as np
 from risingwave_tpu.array.chunk import StreamChunk
 from risingwave_tpu.executors.base import Executor
 from risingwave_tpu.storage.state_table import Checkpointable, StateDelta
+from risingwave_tpu.trace import span
 from risingwave_tpu.types import Op
 
 
@@ -127,7 +128,14 @@ class MaterializeExecutor(Executor, Checkpointable):
 
     # -- data ------------------------------------------------------------
     def apply(self, chunk: StreamChunk) -> List[StreamChunk]:
-        data = chunk.to_numpy(with_ops=True)
+        # host-map MV: the chunk comes to the host (waiting for the
+        # step that made it), then the map applies it row by row
+        with span("mv.apply", stage="actor.mv_apply", table_id=self.table_id):
+            return self._apply(chunk)
+
+    def _apply(self, chunk: StreamChunk) -> List[StreamChunk]:
+        with span("mv.to_numpy"):
+            data = chunk.to_numpy(with_ops=True)
         ops = data["__op__"]
         n = len(ops)
         if n == 0:

@@ -331,7 +331,17 @@ def _rows_barrier_latency(session) -> List[dict]:
                 "checkpoint": int(getattr(tr, "checkpoint", False)),
                 "wall_ms": round(getattr(tr, "wall_ms", 0.0), 3),
                 "dispatch_ms": round(st.get("dispatch", 0.0), 3),
-                "device_step_ms": round(st.get("device_step", 0.0), 3),
+                # the barrier-only device fences: a serial pipeline's
+                # (dispatch.fence) or, in graph mode, the actors'
+                "device_step_ms": round(
+                    sum(
+                        v
+                        for k, v in st.items()
+                        if k == "dispatch.fence"
+                        or k.startswith("actor_fence.")
+                    ),
+                    3,
+                ),
                 "backpressure_fragment": getattr(
                     tr, "backpressure_fragment", None
                 )

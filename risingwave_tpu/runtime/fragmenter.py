@@ -302,6 +302,7 @@ class GraphPipeline(FreshnessSurface):
     ):
         self._specs = list(specs)
         self._epoch_batch = epoch_batch
+        self._label: Optional[str] = None
         self.graph = GraphRuntime(
             self._specs, epoch_batch=epoch_batch
         ).start()
@@ -344,10 +345,18 @@ class GraphPipeline(FreshnessSurface):
         except BaseException:
             pass  # a wedged/failed graph cannot block the rebuild
         self.graph = GraphRuntime(
-            self._specs, epoch_batch=self._epoch_batch
+            self._specs, epoch_batch=self._epoch_batch, label=self._label
         ).start()
         self.graph._epoch = self._epoch
         self.graph.capture_deltas = getattr(self, "_capture", False)
+
+    def set_label(self, name: str) -> None:
+        """The name the runtime registered this pipeline under: its
+        actors' stage keys and spans carry it (survives ``rebuild``)."""
+        self._label = name
+        self.graph.label = name
+        for a in self.graph.actors:
+            a.label = f"{name}/{a.actor_name}"
 
     # -- partial-recovery surface (the runtime's supervisor reads these)
     def failure_scope(self) -> Optional[Dict[str, object]]:

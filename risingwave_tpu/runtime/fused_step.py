@@ -78,6 +78,7 @@ from risingwave_tpu import integrity
 from risingwave_tpu.expr.expr import StaticTree, lift_literals, param_scope
 from risingwave_tpu.ops import agg as agg_ops
 from risingwave_tpu.parallel.sharded_agg import stack_chunks
+from risingwave_tpu.trace import span
 from risingwave_tpu.profiler import PROFILER
 from risingwave_tpu.runtime.bucketing import flush_pad_schedule
 
@@ -925,15 +926,13 @@ class FusedChainExecutor(Executor):
             self._prove_lift(states, stacked, flush_rounds, pads)
         self._deviceprof_hook(states, stacked, flush_rounds, pads, has_data)
         # attribution contexts: dispatch counting (PROFILER.attribute)
-        # and — under an armed jax_trace capture — a TraceAnnotation so
-        # the device trace carries the fragment label next to the
-        # program's fused/<stage> named scopes
-        attr = ann = nullcontext()
+        # and the program's span — under a profiler session the
+        # annotation "rw/fused:<label>", so the device trace carries the
+        # fragment label next to the program's fused/<stage> named scopes
+        attr = nullcontext()
         if PROFILER.enabled:
             attr = PROFILER.attribute(f"fused:{self.label}")
-            if PROFILER.jax_trace:
-                ann = jax.profiler.TraceAnnotation(f"fused:{self.label}")
-        with attr, ann:
+        with attr, span(f"fused:{self.label}"):
             (agg_st, mv_st), outs, packed = _fused_barrier_step(
                 states,
                 stacked,
@@ -1489,7 +1488,6 @@ class FusedTwoInputExecutor(Executor):
         import time
 
         from risingwave_tpu.ops.hash_table import finish_scalars
-        from risingwave_tpu.trace import span
 
         pending, self._pending = self._pending, []
         retired, self._retired = self._retired, []
@@ -1881,12 +1879,10 @@ class FusedTwoInputExecutor(Executor):
         self._deviceprof_hook(
             states, left_batches, right_batches, flush_rounds, pads
         )
-        attr = ann = nullcontext()
+        attr = nullcontext()
         if PROFILER.enabled:
             attr = PROFILER.attribute(f"fused:{self.label}")
-            if PROFILER.jax_trace:
-                ann = jax.profiler.TraceAnnotation(f"fused:{self.label}")
-        with attr, ann:
+        with attr, span(f"fused:{self.label}"):
             (l_st, r_st, (jl, jr), mv_st, latches), outs, packed = (
                 _fused_two_input_step(
                     states,

@@ -43,12 +43,13 @@ import jax.numpy as jnp
 from risingwave_tpu import utils_sync_point as sync_point
 from risingwave_tpu.analysis.jax_sanitizer import transfer_guard
 from risingwave_tpu.array.chunk import StreamChunk
-from risingwave_tpu.epoch_trace import record_stage
+from risingwave_tpu.epoch_trace import StageSums
 from risingwave_tpu.executors.base import Barrier, Epoch, Executor, Watermark
 from risingwave_tpu.ops.hashing import VNODE_COUNT, hash_columns
+from risingwave_tpu.metrics import REGISTRY
 from risingwave_tpu.profiler import PROFILER
 from risingwave_tpu.runtime.pipeline import _pcall, _walk_watermark, walk_chain
-from risingwave_tpu.trace import span
+from risingwave_tpu.trace import add_stage, bind, close_epoch, span
 
 
 def _default_barrier_timeout() -> float:
@@ -61,6 +62,11 @@ def _default_barrier_timeout() -> float:
 
 # message kinds flowing through channels
 CHUNK, BARRIER, WATERMARK, STOP = "chunk", "barrier", "watermark", "stop"
+
+# an actor's sums of one epoch that are reported per actor, as
+# stages_ms["<key>.<actor label>"]: the operator that is busy while
+# those before it are blocked is the bottleneck
+_PER_ACTOR = ("actor_busy", "actor_idle", "actor_blocked", "actor_fence")
 
 
 class PermitChannel:
@@ -100,17 +106,36 @@ class PermitChannel:
     def send_chunk(self, chunk: StreamChunk) -> None:
         cost = min(chunk.capacity, self._budget)
         with self._cv:
-            while self._avail < cost:
-                if self._abort is not None and self._abort.is_set():
-                    return  # graph aborting: drop data, never wedge
-                if self._fence is not None and self._fence.is_set():
-                    return  # consumer fenced for rebuild: drop, replay re-derives
-                self._cv.wait(timeout=0.1)
+            if self._avail < cost and not self._wait_for_permits(cost):
+                return
             if self._fence is not None and self._fence.is_set():
                 return
             self._avail -= cost
             self._q.append((CHUNK, chunk, cost, time.perf_counter()))
             self._cv.notify_all()
+
+    def _wait_for_permits(self, cost: int) -> bool:
+        """Block (``_cv`` held) until ``cost`` permits are free; False
+        when the send is to be dropped instead. The wait is the sender's
+        backpressure and is timed where it happens: an actor's is its
+        ``actor_blocked`` time, the pushing thread's the part of
+        ``ingest`` that is no work of its own."""
+        if isinstance(threading.current_thread(), FragmentActor):
+            name, stage, sender = "actor.blocked", "actor_blocked", "actor"
+        else:
+            name, stage, sender = (
+                "push.permit_wait", "ingest.permit_wait", "push",
+            )
+        short = cost - self._avail
+        REGISTRY.counter("permits_waited_total").inc(short, sender=sender)
+        with span(name, stage=stage, permits=short):
+            while self._avail < cost:
+                if self._abort is not None and self._abort.is_set():
+                    return False  # graph aborting: drop data, never wedge
+                if self._fence is not None and self._fence.is_set():
+                    return False  # consumer fenced: drop, replay re-derives
+                self._cv.wait(timeout=0.1)
+        return True
 
     def send_control(self, kind: str, payload=None) -> None:
         with self._cv:
@@ -304,6 +329,11 @@ class FragmentActor(threading.Thread):
     ):
         super().__init__(name=f"actor-{name}", daemon=True)
         self.actor_name = name
+        # the actor's name in stage keys: unique across a runtime's
+        # graphs once the runtime has labelled them
+        self.label = f"{mgr.label}/{name}" if mgr.label else name
+        # stage sink of this thread's spans; handed over at each barrier
+        self._sums = StageSums()
         self.chain = list(chain)
         self.join_exec = join
         self.right_chain = list(right_chain)
@@ -337,17 +367,15 @@ class FragmentActor(threading.Thread):
             self._emit(self._through(self.chain, [chunk]))
             return
         if port == 0:
-            outs = []
-            for c in self._through(self.chain, [chunk]):
-                outs.extend(
-                    _pcall(self.join_exec, "apply", self.join_exec.apply_left, c)
-                )
+            side, chain, feed = "left", self.chain, self.join_exec.apply_left
         else:
-            outs = []
-            for c in self._through(self.right_chain, [chunk]):
-                outs.extend(
-                    _pcall(self.join_exec, "apply", self.join_exec.apply_right, c)
-                )
+            side, chain = "right", self.right_chain
+            feed = self.join_exec.apply_right
+        outs = []
+        for c in self._through(chain, [chunk]):
+            # the join step's enqueue (the device runs it asynchronously)
+            with span("actor.join_step", side=side):
+                outs.extend(_pcall(self.join_exec, "apply", feed, c))
         self._emit(self._through(self.tail, outs))
 
     def _process_barrier(self, b: Barrier) -> None:
@@ -355,13 +383,14 @@ class FragmentActor(threading.Thread):
         # path): a delay here holds THIS actor's collection back while
         # the rest of the graph reaches the barrier
         sync_point.hit(f"actor_barrier:{self.actor_name}")
-        import time as _time
-
+        # the epoch's queued chunks are done: this thread's chunk, idle
+        # and blocked spans since the last barrier belong to this epoch
+        close_epoch(b.epoch.curr)
+        self.mgr._took(self.actor_name, b)
         # epoch-correlated span: every actor a barrier crosses emits a
         # slice carrying (epoch, fragment, actor) — chrome_trace links
         # them with flow events, so one barrier is one arrow chain
         # across the actor threads in Perfetto
-        t0 = _time.perf_counter()
         with span(
             "actor.barrier",
             epoch=b.epoch.curr,
@@ -369,26 +398,31 @@ class FragmentActor(threading.Thread):
             actor=self.actor_name,
         ), PROFILER.barrier_window(fragment=self.actor_name):
             self._process_barrier_inner(b)
-            t1 = _time.perf_counter()
             # flush + emit happened above; finish_barrier below is the
             # barrier-only device fence (staged-scalar materialization);
             # transfer_guard (when armed) rejects implicit transfers here
-            with transfer_guard():
+            with span("actor.fence", stage="actor_fence"), transfer_guard():
                 for ex in self.executors:
                     ex.finish_barrier()
-        t2 = _time.perf_counter()
-        record_stage("dispatch", (t1 - t0) * 1e3, fragment=self.actor_name)
-        record_stage("device_step", (t2 - t1) * 1e3, fragment=self.actor_name)
-        if b.checkpoint and self.mgr.capture_deltas:
-            # pipelined barriers: seal this epoch's delta NOW, before
-            # any next-epoch chunk in the input queue mutates state
-            # (shared-buffer seal; uploader.rs:548 overlap analogue)
-            for ex in self.executors:
-                cap = getattr(ex, "capture_checkpoint", None)
-                if cap is not None:
-                    cap()
-        self.dispatcher.control(BARRIER, b)
-        self.mgr._collect(self.actor_name, b)
+            if b.checkpoint and self.mgr.capture_deltas:
+                # pipelined barriers: seal this epoch's delta NOW, before
+                # any next-epoch chunk in the input queue mutates state
+                # (shared-buffer seal; uploader.rs:548 overlap analogue)
+                for ex in self.executors:
+                    cap = getattr(ex, "capture_checkpoint", None)
+                    if cap is not None:
+                        cap()
+            self.dispatcher.control(BARRIER, b)
+        self.mgr._collect(self.actor_name, b, self._epoch_sums())
+
+    def _epoch_sums(self) -> Dict[str, float]:
+        """What this actor's spans summed to since the last barrier, by
+        stage key; the four per-actor sums carry the actor's label."""
+        out = {f"{k}.{self.label}": 0.0 for k in _PER_ACTOR}
+        for (stage, _frag), ms in self._sums.take().items():
+            key = f"{stage}.{self.label}" if stage in _PER_ACTOR else stage
+            out[key] = out.get(key, 0.0) + ms
+        return out
 
     def _process_barrier_inner(self, b: Barrier) -> None:
         # watermarks generated behind the barrier are sent AFTER the
@@ -530,7 +564,8 @@ class FragmentActor(threading.Thread):
     # -- input loop -------------------------------------------------------
     def run(self) -> None:  # pragma: no cover - exercised via runtime
         try:
-            self._run_loop()
+            with bind(self._sums):
+                self._run_loop()
         except BaseException as e:  # noqa: BLE001 - surfaced to driver
             self.error = e
             self.mgr._actor_failed(self.actor_name, e)
@@ -556,7 +591,29 @@ class FragmentActor(threading.Thread):
                 progressed = True
                 kind, payload = msg
                 if kind == CHUNK:
-                    self._process_chunk(port, payload)
+                    rows = payload.host_rows
+                    if rows is not None:
+                        # useful rows against the lanes a step walks
+                        REGISTRY.counter("actor_chunk_rows_total").inc(
+                            rows, actor=self.label
+                        )
+                    REGISTRY.counter("actor_chunk_lanes_total").inc(
+                        payload.capacity, actor=self.label
+                    )
+                    blocked = self._sums.get("actor_blocked")
+                    with span(
+                        "actor.chunk",
+                        actor=self.actor_name,
+                        port=port,
+                        rows=rows,
+                        capacity=payload.capacity,
+                    ) as sp:
+                        self._process_chunk(port, payload)
+                    self._sums.add_stage(
+                        "actor_busy",
+                        sp.dur * 1e3
+                        - (self._sums.get("actor_blocked") - blocked),
+                    )
                 elif kind == WATERMARK:
                     self._process_watermark(i, payload)
                 elif kind == BARRIER:
@@ -592,7 +649,7 @@ class FragmentActor(threading.Thread):
                     cv = waitable[0]._cv
                     self.busy = False
                     try:
-                        with cv:
+                        with span("actor.idle", stage="actor_idle"), cv:
                             cv.wait_for(
                                 lambda: self.halt.is_set()
                                 or any(len(ch._q) for ch in waitable),
@@ -652,10 +709,14 @@ class GraphRuntime:
         specs: Sequence[FragmentSpec],
         channel_permits: int = 1 << 16,
         epoch_batch: bool = True,
+        label: Optional[str] = None,
     ):
         self.specs = {s.name: s for s in specs}
         self._channel_permits = channel_permits
         self._epoch_batch = epoch_batch
+        # the owning pipeline's name in its runtime: makes actor names
+        # unique in stage keys (two graphs may both have a "mv#0")
+        self.label = label
         # pipelined barriers: actors seal checkpoint deltas at the
         # barrier instead of the runtime staging after a full drain
         self.capture_deltas = False
@@ -664,6 +725,11 @@ class GraphRuntime:
         self._source_channels: Dict[str, List[PermitChannel]] = {}
         self._collect_lock = threading.Condition()
         self._collected: Dict[int, set] = {}
+        # per pending epoch: the actors that have taken the barrier off
+        # their channels (their queued chunks are done), and the stage
+        # sums each actor handed over when it collected
+        self._taken: Dict[int, set] = {}
+        self._actor_sums: Dict[int, List[Dict[str, float]]] = {}
         # last epoch each actor fully collected (stall-dump attribution:
         # the actor whose last epoch lags is the stuck one)
         self._last_collected: Dict[str, int] = {}
@@ -981,6 +1047,8 @@ class GraphRuntime:
             self.fenced_fragments -= fragments
             self._failure = next(iter(self.actor_errors.values()), None)
             self._collected.clear()
+            self._taken.clear()
+            self._actor_sums.clear()
             self._collect_lock.notify_all()
         fresh = []
         for s in ordered:
@@ -1060,6 +1128,7 @@ class GraphRuntime:
         b = Barrier(Epoch(prev, self._epoch), checkpoint)
         with self._collect_lock:
             self._collected[target] = set()
+            self._taken[target] = set()
         for chans in self._source_channels.values():
             for ch in chans:
                 ch.send_control(BARRIER, b)
@@ -1076,55 +1145,26 @@ class GraphRuntime:
         XLA compiles, so device benches raise it via the env var."""
         if timeout is None:
             timeout = _default_barrier_timeout()
-        from risingwave_tpu import blackbox
-
         deadline = time.perf_counter() + timeout
-        pred = (
-            lambda: self._failure is not None
-            or len(self._collected.get(epoch, ())) == len(self.actors)
-        )
+        tag = {"fragment": self.label} if self.label else {}
         with self._collect_lock:
             try:
-                # sliced wait: the full deadman stands, but an armed
-                # device-wedge sentinel converts the hang into a
-                # structured DeviceWedged within ~a slice instead of
-                # burning the whole barrier timeout (the q7 wedge used
-                # to sit here for 360s and then die evidence-free)
-                while True:
-                    remain = deadline - time.perf_counter()
-                    ok = self._collect_lock.wait_for(
-                        pred, timeout=max(0.0, min(1.0, remain))
+                # the actors finishing the epoch's queued chunks ...
+                with span("dispatch.drain", stage="dispatch.drain", **tag):
+                    ok = self._await(
+                        lambda: len(self._taken.get(epoch, ()))
+                        >= len(self.actors),
+                        deadline,
+                        epoch,
                     )
-                    if ok or remain <= 0:
-                        break
-                    wedged = blackbox.SENTINEL.wedged_error()
-                    if wedged is not None:
-                        got = self._collected.get(epoch, set())
-                        stuck = sorted(
-                            a.actor_name
-                            for a in self.actors
-                            if a.actor_name not in got
-                        )
-                        # forensics on a SIDE thread, raise NOW: the
-                        # dump's device sections (memory_stats, array
-                        # census) can block on the very wedge being
-                        # reported, and it must not do so holding the
-                        # collect lock — fail-fast first, evidence
-                        # best-effort (same arm-first rule the
-                        # sentinel's bundle capture follows)
-                        from risingwave_tpu.epoch_trace import dump_stalls
-
-                        threading.Thread(
-                            target=dump_stalls,
-                            args=(
-                                f"device wedged while barrier {epoch} "
-                                f"awaited {stuck}: {wedged}",
-                            ),
-                            kwargs={"graph": self},
-                            daemon=True,
-                            name="rw-wedge-dump",
-                        ).start()
-                        raise wedged
+                # ... then flush, finish_barrier fence, collection
+                with span("dispatch.flush", stage="dispatch.flush", **tag):
+                    ok = ok and self._await(
+                        lambda: len(self._collected.get(epoch, ()))
+                        >= len(self.actors),
+                        deadline,
+                        epoch,
+                    )
                 if self._failure is not None:
                     raise RuntimeError("actor failed") from self._failure
                 if not ok:
@@ -1150,6 +1190,58 @@ class GraphRuntime:
                     )
             finally:
                 self._collected.pop(epoch, None)
+                self._taken.pop(epoch, None)
+                sums = self._actor_sums.pop(epoch, ())
+        # the actors' own sums of the epoch, into the barrier's trace
+        for actor_sums in sums:
+            for key, ms in actor_sums.items():
+                add_stage(key, ms)
+
+    def _await(self, pred, deadline: float, epoch: int) -> bool:
+        """Wait (``_collect_lock`` held) until ``pred`` or a failure;
+        False when the deadline passed first. A sliced wait: the full
+        deadman stands, but an armed device-wedge sentinel converts the
+        hang into a structured DeviceWedged within ~a slice instead of
+        burning the whole barrier timeout (the q7 wedge used to sit
+        here for 360s and then die evidence-free)."""
+        from risingwave_tpu import blackbox
+
+        done = lambda: self._failure is not None or pred()
+        while True:
+            remain = deadline - time.perf_counter()
+            if self._collect_lock.wait_for(
+                done, timeout=max(0.0, min(1.0, remain))
+            ):
+                return True
+            if remain <= 0:
+                return False
+            wedged = blackbox.SENTINEL.wedged_error()
+            if wedged is not None:
+                got = self._collected.get(epoch, set())
+                stuck = sorted(
+                    a.actor_name
+                    for a in self.actors
+                    if a.actor_name not in got
+                )
+                # forensics on a SIDE thread, raise NOW: the dump's
+                # device sections (memory_stats, array census) can
+                # block on the very wedge being reported, and it must
+                # not do so holding the collect lock — fail-fast first,
+                # evidence best-effort (same arm-first rule the
+                # sentinel's bundle capture follows)
+                from risingwave_tpu.epoch_trace import dump_stalls
+
+                threading.Thread(
+                    target=dump_stalls,
+                    args=(
+                        f"device wedged while barrier {epoch} "
+                        f"awaited {stuck}: {wedged}",
+                    ),
+                    kwargs={"graph": self},
+                    daemon=True,
+                    name="rw-wedge-dump",
+                ).start()
+                raise wedged
 
     def inject_barrier(
         self,
@@ -1261,7 +1353,22 @@ class GraphRuntime:
         return out
 
     # -- actor callbacks --------------------------------------------------
-    def _collect(self, actor_name: str, b: Barrier) -> None:
+    def _took(self, actor_name: str, b: Barrier) -> None:
+        """The actor has the barrier off every input: the epoch's
+        queued chunks are behind it."""
+        with self._collect_lock:
+            got = self._taken.get(b.epoch.curr)
+            if got is not None:
+                got.add(actor_name)
+                if len(got) >= len(self.actors):
+                    self._collect_lock.notify_all()
+
+    def _collect(
+        self,
+        actor_name: str,
+        b: Barrier,
+        sums: Optional[Dict[str, float]] = None,
+    ) -> None:
         with self._collect_lock:
             self._last_collected[actor_name] = max(
                 self._last_collected.get(actor_name, 0), b.epoch.curr
@@ -1270,6 +1377,10 @@ class GraphRuntime:
             # not re-registered — only live epochs have an entry
             if b.epoch.curr in self._collected:
                 self._collected[b.epoch.curr].add(actor_name)
+                if sums:
+                    self._actor_sums.setdefault(b.epoch.curr, []).append(
+                        sums
+                    )
                 self._collect_lock.notify_all()
 
     def _actor_failed(self, actor_name: str, err: BaseException) -> None:

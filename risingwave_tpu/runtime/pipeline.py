@@ -26,6 +26,14 @@ from risingwave_tpu.array.chunk import StreamChunk
 from risingwave_tpu.blackbox import RECORDER
 from risingwave_tpu.executors.base import Barrier, Epoch, Executor, Watermark
 from risingwave_tpu.profiler import PROFILER
+from risingwave_tpu.trace import bound, span
+
+
+def _walk_span():
+    """A serial pipeline's barrier walk. Under a runtime the walk lies
+    inside the ``barrier.fragment`` span that stamps ``dispatch``; a
+    standalone pipeline (bench drivers, tests) stamps it itself."""
+    return span("pipeline.walk", stage=None if bound() else "dispatch")
 
 
 class FreshnessSurface:
@@ -150,42 +158,39 @@ class Pipeline(FreshnessSurface):
             else max(int(time.time() * 1000) << 16, prev + 1)
         )
         b = Barrier(Epoch(prev, self._epoch), checkpoint)
-        t0 = time.perf_counter()
+        # stage attribution (EpochTrace lifecycle): the walk is host
+        # dispatch; the scalar materialization is the barrier-only
+        # device fence
         with PROFILER.barrier_window():
-            pending = walk_chain(self.executors, [], barrier=b)
-            # executor-GENERATED watermarks (watermark_filter.rs) walk
-            # the rest of the chain after the barrier flushes
-            for i, ex in enumerate(self.executors):
-                wm = ex.emit_watermark()
-                if wm is not None:
-                    self._note_watermark(wm.value)
-                    _, outs = _walk_watermark(self.executors[i + 1 :], wm)
-                    pending.extend(outs)
-            t1 = time.perf_counter()
+            with _walk_span() as walk:
+                pending = walk_chain(self.executors, [], barrier=b)
+                # executor-GENERATED watermarks (watermark_filter.rs)
+                # walk the rest of the chain after the barrier flushes
+                for i, ex in enumerate(self.executors):
+                    wm = ex.emit_watermark()
+                    if wm is not None:
+                        self._note_watermark(wm.value)
+                        _, outs = _walk_watermark(
+                            self.executors[i + 1 :], wm
+                        )
+                        pending.extend(outs)
             # materialize every executor's staged barrier scalars AFTER
             # the walk: the async transfers overlapped, so the chain
             # pays ~one round-trip; raises still precede the runtime's
             # epoch commit. transfer_guard: when armed
             # (RW_TRANSFER_GUARD, tests) any IMPLICIT host<->device
             # transfer here raises at the offender
-            with transfer_guard():
+            with span(
+                "pipeline.fence", stage="dispatch.fence"
+            ) as fence, transfer_guard():
                 for ex in self.executors:
                     ex.finish_barrier()
-        # stage attribution (EpochTrace lifecycle): the walk is host
-        # dispatch; the scalar materialization is the barrier-only
-        # device fence
-        from risingwave_tpu.epoch_trace import record_stage
-
-        t2 = time.perf_counter()
-        record_stage("dispatch", (t1 - t0) * 1e3)
-        record_stage("device_step", (t2 - t1) * 1e3)
-        self._sample_freshness((t2 - t0) * 1e3)
+        walk_ms, fence_ms = walk.dur * 1e3, fence.dur * 1e3
+        self._sample_freshness(walk_ms + fence_ms)
         # standalone pipelines (bench drivers, tests) feed the black
         # box directly — a runtime-driven barrier records via its
         # EpochTrace instead
-        RECORDER.record_pipeline_barrier(
-            self._epoch, (t1 - t0) * 1e3, (t2 - t1) * 1e3
-        )
+        RECORDER.record_pipeline_barrier(self._epoch, walk_ms, fence_ms)
         # mesh observability: close this pipeline's per-shard window
         # (no-op unless MESHPROF is armed and watched this chain; the
         # import is deferred — meshprof pulls in the parallel package,
@@ -285,47 +290,45 @@ class TwoInputPipeline(FreshnessSurface):
             else max(int(time.time() * 1000) << 16, prev + 1)
         )
         b = Barrier(Epoch(prev, self._epoch), checkpoint)
-        t0 = time.perf_counter()
         with PROFILER.barrier_window():
-            if self._fused is not None:
-                # ONE donated device program for the whole fragment
-                # barrier; finish defers to the K-boundary under
-                # RW_FUSED_PIPELINE_DEPTH (the wrapper decides)
-                outs = _pcall(
-                    self._fused, "flush", self._fused.on_barrier, b
-                )
+            with _walk_span() as walk:
+                if self._fused is not None:
+                    # ONE donated device program for the whole fragment
+                    # barrier; finish defers to the K-boundary under
+                    # RW_FUSED_PIPELINE_DEPTH (the wrapper decides)
+                    outs = _pcall(
+                        self._fused, "flush", self._fused.on_barrier, b
+                    )
+                else:
+                    joined: List[StreamChunk] = []
+                    for c in self._through(self.left, [], barrier=b):
+                        joined.extend(
+                            _pcall(
+                                self.join, "apply", self.join.apply_left, c
+                            )
+                        )
+                    for c in self._through(self.right, [], barrier=b):
+                        joined.extend(
+                            _pcall(
+                                self.join, "apply", self.join.apply_right, c
+                            )
+                        )
+                    joined.extend(
+                        _pcall(self.join, "flush", self.join.on_barrier, b)
+                    )
+                    outs = self._through(self.tail, joined, barrier=b)
                 outs.extend(self._generated_watermarks())
-                t1 = time.perf_counter()
-                with transfer_guard():
+            with span(
+                "pipeline.fence", stage="dispatch.fence"
+            ) as fence, transfer_guard():
+                if self._fused is not None:
                     self._fused.finish_barrier()
-            else:
-                joined: List[StreamChunk] = []
-                for c in self._through(self.left, [], barrier=b):
-                    joined.extend(
-                        _pcall(self.join, "apply", self.join.apply_left, c)
-                    )
-                for c in self._through(self.right, [], barrier=b):
-                    joined.extend(
-                        _pcall(self.join, "apply", self.join.apply_right, c)
-                    )
-                joined.extend(
-                    _pcall(self.join, "flush", self.join.on_barrier, b)
-                )
-                outs = self._through(self.tail, joined, barrier=b)
-                outs.extend(self._generated_watermarks())
-                t1 = time.perf_counter()
-                with transfer_guard():
+                else:
                     for ex in self.executors:
                         ex.finish_barrier()
-        from risingwave_tpu.epoch_trace import record_stage
-
-        t2 = time.perf_counter()
-        record_stage("dispatch", (t1 - t0) * 1e3)
-        record_stage("device_step", (t2 - t1) * 1e3)
-        self._sample_freshness((t2 - t0) * 1e3)
-        RECORDER.record_pipeline_barrier(
-            self._epoch, (t1 - t0) * 1e3, (t2 - t1) * 1e3
-        )
+        walk_ms, fence_ms = walk.dur * 1e3, fence.dur * 1e3
+        self._sample_freshness(walk_ms + fence_ms)
+        RECORDER.record_pipeline_barrier(self._epoch, walk_ms, fence_ms)
         from risingwave_tpu.parallel.meshprof import MESHPROF
 
         if MESHPROF.enabled:

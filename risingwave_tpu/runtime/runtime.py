@@ -43,7 +43,12 @@ import numpy as np
 from risingwave_tpu import blackbox
 from risingwave_tpu import utils_sync_point as sync_point
 from risingwave_tpu.array.chunk import StreamChunk
-from risingwave_tpu.epoch_trace import EpochTrace, chunk_nbytes, dump_stalls
+from risingwave_tpu.epoch_trace import (
+    EpochTrace,
+    StageSums,
+    chunk_nbytes,
+    dump_stalls,
+)
 from risingwave_tpu.event_log import EVENT_LOG
 from risingwave_tpu.freshness import FRESHNESS, attribute_backpressure
 from risingwave_tpu.metrics import REGISTRY
@@ -55,7 +60,7 @@ from risingwave_tpu.resilience import (
     RetryPolicy,
 )
 from risingwave_tpu.profiler import PROFILER
-from risingwave_tpu.trace import span
+from risingwave_tpu.trace import TRACER, bind, close_epoch, span
 from risingwave_tpu.storage.object_store import ObjectStore
 from risingwave_tpu.storage.state_table import CheckpointManager
 
@@ -353,7 +358,9 @@ class StreamingRuntime:
         self.epoch_traces: deque = deque(maxlen=256)
         self.last_epoch_trace: Optional[EpochTrace] = None
         self._traces_by_epoch: Dict[int, EpochTrace] = {}
-        self._ingest_s = 0.0  # host time in push() since last barrier
+        # stage sums of the pushes since the last barrier (the open
+        # epoch has no EpochTrace yet): _begin_trace folds them in
+        self._open_stages = StageSums()
         self._ingest_bytes = 0  # chunk bytes moved since last barrier
         self._prev_state_bytes = 0
         # stall watchdog: if a barrier exceeds this deadline, dump every
@@ -395,6 +402,9 @@ class StreamingRuntime:
         if upstream is not None and upstream not in self.fragments:
             raise KeyError(f"unknown upstream fragment {upstream!r}")
         self.fragments[name] = pipeline
+        set_label = getattr(pipeline, "set_label", None)
+        if set_label is not None:  # graph-backed: actors get unique keys
+            set_label(name)
         if self.mgr is not None:
             for ex in pipeline.executors:
                 # sinks: delivery is deferred until the epoch's manifest
@@ -639,13 +649,15 @@ class StreamingRuntime:
         """Feed one chunk into a fragment and route its emitted deltas
         into every subscribed downstream fragment (the exchange edge an
         MV-on-MV chain rides)."""
-        t0 = time.perf_counter()
-        outs = self._push_into(name, chunk, side)
-        REGISTRY.counter("chunks_pushed_total").inc(fragment=name)
-        self._route(name, outs)
         # ingest attribution: the next barrier's EpochTrace charges this
         # host time + chunk bytes to its "ingest" stage
-        self._ingest_s += time.perf_counter() - t0
+        with bind(self._open_stages), span(
+            "push", stage="ingest", fragment=name, side=side,
+            rows=chunk.host_rows,
+        ):
+            outs = self._push_into(name, chunk, side)
+            REGISTRY.counter("chunks_pushed_total").inc(fragment=name)
+            self._route(name, outs)
         self._ingest_bytes += chunk_nbytes(chunk)
         return outs
 
@@ -1210,38 +1222,23 @@ class StreamingRuntime:
             try:
                 if not self._closer_err and not self._closer_abort.is_set():
                     tr = self._traces_by_epoch.get(epoch)
-                    t_close = time.perf_counter()
-                    for name, p in self.fragments.items():
-                        with span("barrier.close", fragment=name):
-                            p.wait_barrier(epoch)
-                    if tr is not None:
-                        tr.add_stage(
-                            "close", (time.perf_counter() - t_close) * 1e3
-                        )
-                    if is_ckpt:
-                        # deltas were SEALED by the actors at the
-                        # barrier (capture_checkpoint): stage consumes
-                        # host buffers, never racing next-epoch compute
-                        t_staged = time.perf_counter()
-                        with span("checkpoint.stage", epoch=epoch):
-                            staged = self.mgr.stage(
-                                self._staging_executors()
+                    with bind(tr, epoch):
+                        for name, p in self.fragments.items():
+                            with span(
+                                "barrier.close", stage="close", fragment=name
+                            ):
+                                p.wait_barrier(epoch)
+                        if is_ckpt:
+                            # deltas were SEALED by the actors at the
+                            # barrier (capture_checkpoint): stage consumes
+                            # host buffers, never racing next-epoch compute
+                            self._enqueue_commit(
+                                epoch, *self._stage(), tr
                             )
                         if tr is not None:
-                            tr.add_stage(
-                                "checkpoint_stage",
-                                (time.perf_counter() - t_staged) * 1e3,
-                            )
-                        REGISTRY.counter("checkpoints_total").inc()
-                        with self._inflight_lock:
-                            self._inflight += 1
-                        self._work_q.append((epoch, staged, t_staged, tr))
-                        self._ensure_worker()
-                        self._work_event.set()
-                    if tr is not None:
-                        # finalize over admission->closed (the epoch's
-                        # real span), not admission-only wall time
-                        self._end_trace(tr)
+                            # finalize over admission->closed (the epoch's
+                            # real span), not admission-only wall time
+                            self._end_trace(tr)
                     self.epoch_close_ms.append(
                         (time.perf_counter() - t_adm) * 1e3
                     )
@@ -1295,6 +1292,23 @@ class StreamingRuntime:
             and self._barrier_seq % self.checkpoint_frequency == 0
         )
         tr = self._begin_trace(is_ckpt)
+        with bind(tr), span("barrier", seq=self._barrier_seq):
+            outs = self._barrier_walk(tr, prev, is_ckpt)
+        ms = (time.perf_counter() - t0) * 1e3
+        self.barrier_latencies_ms.append(ms)
+        REGISTRY.histogram("barrier_latency_ms").observe(ms)
+        REGISTRY.counter("barriers_total").inc()
+        if PROFILER.enabled:
+            # slow-barrier auto-capture: a barrier over the profile
+            # threshold leaves a PROFILE_* artifact + forensic dump
+            PROFILER.observe_barrier(ms, runtime=self)
+        return outs
+
+    def _barrier_walk(
+        self, tr: EpochTrace, prev: int, is_ckpt: bool
+    ) -> Dict[str, List[StreamChunk]]:
+        """One synchronous barrier under its root span: the fragment
+        walk, the checkpoint's staging, the governors, the trace's end."""
         outs = {}
         pending = self._pending_partial
         # registration order is topological (downstreams register after
@@ -1312,41 +1326,30 @@ class StreamingRuntime:
                 # durability); the runtime's epoch is passed down so
                 # held sink batches key by the exact epoch
                 # _commit/_on_epoch_durable will use
-                tf = time.perf_counter()
                 with span(
-                    "barrier.fragment", fragment=name, epoch=self._epoch
-                ), PROFILER.barrier_window(fragment=name):
-                    outs[name] = p.barrier(
-                        checkpoint=is_ckpt, epoch=self._epoch
-                    )
-                self._route(name, outs[name])
-                # replay-buffer epoch fence: everything recorded before
-                # this marker belongs to epochs <= self._epoch for this
-                # fragment
-                self._record_barrier(name, self._epoch, is_ckpt)
-                tr.add_stage(
-                    "dispatch",
-                    (time.perf_counter() - tf) * 1e3,
-                    fragment=name,
-                )
+                    "barrier.fragment", stage="dispatch", fragment=name
+                ):
+                    with PROFILER.barrier_window(fragment=name):
+                        outs[name] = p.barrier(
+                            checkpoint=is_ckpt, epoch=self._epoch
+                        )
+                    with span("barrier.route", fragment=name):
+                        self._route(name, outs[name])
+                    # replay-buffer epoch fence: everything recorded
+                    # before this marker belongs to epochs <=
+                    # self._epoch for this fragment
+                    self._record_barrier(name, self._epoch, is_ckpt)
         if is_ckpt:
             self._commit(self._epoch, tr)
-        if self.memory_budget_bytes is not None:
-            self._enforce_memory_budget()
-        # recompile-storm governor: consume this barrier's hazard
-        # deltas; over budget (or SLOW device) → pin to max bucket.
-        # One attribute check while SignatureWatch is disarmed.
-        self._shape_watch_tick()
-        self.shape_governor.observe_barrier(self)
+        with span("barrier.bookkeeping", stage="bookkeeping"):
+            if self.memory_budget_bytes is not None:
+                self._enforce_memory_budget()
+            # recompile-storm governor: consume this barrier's hazard
+            # deltas; over budget (or SLOW device) → pin to max bucket.
+            # One attribute check while SignatureWatch is disarmed.
+            self._shape_watch_tick()
+            self.shape_governor.observe_barrier(self)
         self._end_trace(tr)
-        ms = (time.perf_counter() - t0) * 1e3
-        self.barrier_latencies_ms.append(ms)
-        REGISTRY.histogram("barrier_latency_ms").observe(ms)
-        REGISTRY.counter("barriers_total").inc()
-        if PROFILER.enabled:
-            # slow-barrier auto-capture: a barrier over the profile
-            # threshold leaves a PROFILE_* artifact + forensic dump
-            PROFILER.observe_barrier(ms, runtime=self)
         return outs
 
     def _shape_watch_tick(self) -> None:
@@ -1367,10 +1370,26 @@ class StreamingRuntime:
         # commit->visible anchor (freshness.py): wall clock at barrier
         # open; _end_trace measures to the post-publish visible point
         tr.barrier_open_wall = time.time()
+        # the pushes' spans waited on this thread for their epoch
+        close_epoch(tr.epoch)
+        # what this path instruments reads 0.0 where its span does not
+        # run (no permit waited for, no string new), never absent
+        tr.declare("ingest", "ingest.permit_wait", "publish", "bookkeeping")
+        if self.in_flight_barriers <= 1:
+            tr.declare("dispatch")
+        if is_ckpt:
+            tr.declare(
+                "checkpoint_stage", "checkpoint_stage.pull",
+                "checkpoint_stage.dictionary",
+            )
         # charge accumulated push() time/bytes to this epoch's ingest
-        tr.add_stage("ingest", self._ingest_s * 1e3)
+        sums: Dict[str, float] = {}
+        for (stage, _frag), ms in self._open_stages.take().items():
+            sums[stage] = sums.get(stage, 0.0) + ms
+        for stage, ms in sums.items():
+            tr.add_stage(stage, ms)
         tr.chunk_bytes = self._ingest_bytes
-        self._ingest_s, self._ingest_bytes = 0.0, 0
+        self._ingest_bytes = 0
         self._traces_by_epoch[tr.epoch] = tr
         # bound the pending map (async commits resolve FIFO)
         while len(self._traces_by_epoch) > 512:
@@ -1378,19 +1397,33 @@ class StreamingRuntime:
         return tr
 
     def _end_trace(self, tr: EpochTrace) -> None:
-        state_bytes = self.state_nbytes()
-        tr.finalize(state_bytes, self._prev_state_bytes)
-        self._prev_state_bytes = state_bytes
-        self.epoch_traces.append(tr)
-        self.last_epoch_trace = tr
+        """Close the barrier's trace. ``wall_ms`` closes in ``finalize``
+        (the flight recorder, itself bookkeeping, reads it), so the
+        stages ``publish`` and ``bookkeeping`` lie after it."""
+        with span("barrier.bookkeeping", stage="bookkeeping"):
+            with span("bookkeeping.state_nbytes"):
+                state_bytes = self.state_nbytes()
+            with span("bookkeeping.finalize"):
+                tr.finalize(state_bytes, self._prev_state_bytes)
+            self._prev_state_bytes = state_bytes
+            self.epoch_traces.append(tr)
+            self.last_epoch_trace = tr
         # shared arrangements: swap in this barrier's published version
         # (pointer swap; materializes only under active read demand)
-        self.arrangements.publish(tr.epoch)
+        with span("barrier.publish", stage="publish"):
+            self.arrangements.publish(tr.epoch)
+        with span("barrier.bookkeeping", stage="bookkeeping"):
+            self._observe_barrier(tr)
+
+    def _observe_barrier(self, tr: EpochTrace) -> None:
+        """The per-barrier collectors, on the barrier's thread, each
+        under a span of its own so that the ring names the dearest."""
         # freshness + backpressure attribution (ISSUE 16): NOW the
         # epoch's snapshots are what a reader sees — measure to here.
         # Host timestamps and dict folds only; never faults a barrier.
         try:
-            self._observe_freshness(tr)
+            with span("bookkeeping.freshness"):
+                self._observe_freshness(tr)
         except Exception:  # noqa: BLE001 — accounting never faults
             pass
         # memory governor + overload ladder: consumes the fresh state
@@ -1398,17 +1431,20 @@ class StreamingRuntime:
         # spill/ladder/credit actions. Runs on BOTH barrier paths (the
         # pipelined closer lane finalizes traces here too); dormant =
         # one attribute check. Never faults a barrier (self-guarded).
-        self.memory_governor.observe_barrier(self, tr)
+        with span("bookkeeping.memory_governor"):
+            self.memory_governor.observe_barrier(self, tr)
         # mesh observability: fold the per-pipeline shard windows closed
         # this barrier into one mesh doc on the trace (per-shard stage
         # lanes + exchange matrix + skew verdict). Dormant = one
         # attribute check; self-guarded, never faults a barrier.
         from risingwave_tpu.parallel.meshprof import MESHPROF
 
-        MESHPROF.observe_barrier(self, tr)
+        with span("bookkeeping.meshprof"):
+            MESHPROF.observe_barrier(self, tr)
         # flight recorder: the finalized trace is exactly one black-box
         # record (ring always; segment file when a dir is configured)
-        blackbox.RECORDER.record_barrier(tr, runtime=self)
+        with span("bookkeeping.recorder"):
+            blackbox.RECORDER.record_barrier(tr, runtime=self)
         if tr.checkpoint:
             EVENT_LOG.record(
                 "barrier_commit",
@@ -1627,26 +1663,35 @@ class StreamingRuntime:
         # stage on the main thread (device pull + eager mark flips, with
         # the duplicate-table_id check) — ONE code path with the sync
         # commit (CheckpointManager.stage / commit_staged)
-        t_staged = time.perf_counter()
-        with span("checkpoint.stage"):
-            staged = self.mgr.stage(self._staging_executors())
-        if tr is not None:
-            tr.add_stage(
-                "checkpoint_stage", (time.perf_counter() - t_staged) * 1e3
-            )
-        REGISTRY.counter("checkpoints_total").inc()
-        REGISTRY.gauge("checkpoint_staged_tables").set(len(staged))
+        staged, t_staged = self._stage()
         if not self.async_checkpoint:
-            if self._commit_or_degrade(epoch, staged, tr):
+            with span("checkpoint.commit"):
+                durable = self._commit_or_degrade(epoch, staged, tr)
+            if durable:
                 self.checkpoint_sync_ms.append(
                     (time.perf_counter() - t_staged) * 1e3
                 )
                 self._on_epoch_durable(epoch)
                 self._kick_compactor()
             return
+        self._enqueue_commit(epoch, staged, t_staged, tr)
+
+    def _stage(self):
+        """(staged deltas, when staging began), on the calling thread."""
+        t_staged = time.perf_counter()
+        with span("checkpoint.stage", stage="checkpoint_stage"):
+            staged = self.mgr.stage(self._staging_executors())
+        REGISTRY.counter("checkpoints_total").inc()
+        REGISTRY.gauge("checkpoint_staged_tables").set(len(staged))
+        return staged, t_staged
+
+    def _enqueue_commit(self, epoch, staged, t_staged, tr) -> None:
+        """Hand staged deltas to the async checkpoint worker."""
         with self._inflight_lock:
             self._inflight += 1
-        self._work_q.append((epoch, staged, t_staged, tr))
+        self._work_q.append(
+            (epoch, staged, t_staged, time.perf_counter(), tr)
+        )
         self._ensure_worker()
         self._work_event.set()
 
@@ -1662,7 +1707,9 @@ class StreamingRuntime:
             self._work_event.wait(timeout=0.5)
             self._work_event.clear()
             while self._work_q:
-                epoch, staged, t_staged, tr = self._work_q.popleft()
+                epoch, staged, t_staged, t_queued, tr = (
+                    self._work_q.popleft()
+                )
                 try:
                     if self._work_err or self._work_abort.is_set():
                         # a prior epoch failed to commit (or recovery is
@@ -1675,10 +1722,16 @@ class StreamingRuntime:
                     # single-worker FIFO queue -> epoch order holds;
                     # store-unavailable failures degrade (spill) rather
                     # than poisoning the lane — the stream keeps going
-                    with span("checkpoint.commit", epoch=epoch):
-                        durable = self._commit_or_degrade(
-                            epoch, staged, tr
+                    with bind(tr, epoch):
+                        TRACER.record(
+                            "checkpoint.queue_wait",
+                            t_queued,
+                            time.perf_counter() - t_queued,
                         )
+                        with span("checkpoint.commit"):
+                            durable = self._commit_or_degrade(
+                                epoch, staged, tr
+                            )
                     if durable:
                         self.checkpoint_sync_ms.append(
                             (time.perf_counter() - t_staged) * 1e3

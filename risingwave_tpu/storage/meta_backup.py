@@ -23,7 +23,9 @@ import json
 from typing import List, Optional
 
 
+from risingwave_tpu.metrics import REGISTRY
 from risingwave_tpu.storage.object_store import ObjectStore
+from risingwave_tpu.trace import span
 
 DDL_PATH = "meta/ddl.json"
 STRINGS_PATH = "meta/strings.json"
@@ -46,8 +48,13 @@ class MetaStore:
     def ddl(self) -> List[str]:
         return list(self._ddl)
 
-    def save_strings(self, dump: List[str]) -> None:
-        self.store.put(STRINGS_PATH, json.dumps(dump).encode())
+    def save_strings(self, dump: List[str]):
+        """Persist the dictionary; (strings, bytes) written."""
+        with span("dictionary.json", strings=len(dump)):
+            blob = json.dumps(dump).encode()
+        with span("dictionary.put", bytes=len(blob)):
+            self.store.put(STRINGS_PATH, blob)
+        return len(dump), len(blob)
 
     def load_strings(self) -> Optional[List[str]]:
         if not self.store.exists(STRINGS_PATH):
@@ -75,8 +82,21 @@ class DictionaryPersistor(Checkpointable):
         return ()
 
     def checkpoint_delta(self):
-        if len(self.strings) != self._persisted_len:
-            self.meta.save_strings(self.strings.dump())
+        new = len(self.strings) - self._persisted_len
+        if new:
+            # the dictionary is written whole whenever a string is new:
+            # the span says what that costs, by strings and bytes
+            with span(
+                "checkpoint.dictionary",
+                stage="checkpoint_stage.dictionary",
+                new_strings=new,
+            ) as sp:
+                with span("dictionary.dump"):
+                    dump = self.strings.dump()
+                n, nbytes = self.meta.save_strings(dump)
+                sp.args.update(strings=n, bytes=nbytes)
+            REGISTRY.counter("checkpoint_dictionary_strings_total").inc(n)
+            REGISTRY.counter("checkpoint_dictionary_bytes_total").inc(nbytes)
             self._persisted_len = len(self.strings)
         return []
 

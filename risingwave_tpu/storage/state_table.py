@@ -27,6 +27,7 @@ from __future__ import annotations
 import json
 import threading
 from collections import deque as _deque
+from contextlib import nullcontext
 from dataclasses import dataclass
 from typing import Dict, List, Optional, Sequence, Tuple
 
@@ -45,6 +46,7 @@ from risingwave_tpu.integrity import (
     quarantine,
     raise_corruption,
 )
+from risingwave_tpu.metrics import REGISTRY
 from risingwave_tpu.resilience import (
     STORE_UNAVAILABLE,
     CircuitBreaker,
@@ -52,6 +54,7 @@ from risingwave_tpu.resilience import (
     RetryPolicy,
 )
 from risingwave_tpu.storage.object_store import ObjectStore
+from risingwave_tpu.trace import bind, span
 from risingwave_tpu.storage.block_sst import (
     BlockSst,
     build_block_sst,
@@ -146,15 +149,48 @@ def lanes_from_host_keys(key_tuples, dtypes) -> Dict[str, np.ndarray]:
     return out
 
 
-def pull_rows(device_lanes: Dict[str, object], sel: np.ndarray) -> Dict[str, np.ndarray]:
+# the table whose checkpoint delta this thread is pulling (set by
+# Checkpointable._pull_delta): a pull_rows under it is a checkpoint pull
+_STAGING = threading.local()
+
+
+def pull_rows(
+    device_lanes: Dict[str, object],
+    sel: np.ndarray,
+    table_id: Optional[str] = None,
+) -> Dict[str, np.ndarray]:
     """Device->host transfer of SELECTED rows only (checkpoint staging
     must be O(changed rows), not O(capacity)). ``sel`` is padded to a
     power-of-two bucket so jit caches one gather program per bucket
-    size instead of recompiling per distinct count."""
+    size instead of recompiling per distinct count.
+
+    While an executor's checkpoint delta is being pulled (``table_id``
+    given, or the executor's own under ``_pull_delta``) the gather's
+    dispatch and the device->host copy it waits for are the span
+    ``checkpoint.pull``, with the rows and the padded rows it moved."""
     n = len(sel)
     if n == 0:
         return {k: np.asarray(a)[:0] for k, a in device_lanes.items()}
     pad = 1 << (n - 1).bit_length()
+    if table_id is None:
+        table_id = getattr(_STAGING, "table_id", None)
+    if table_id is None:  # a read, not a checkpoint
+        return _pull(device_lanes, sel, n, pad)
+    REGISTRY.counter("checkpoint_pull_rows_total").inc(n, table_id=table_id)
+    REGISTRY.counter("checkpoint_pull_padded_rows_total").inc(
+        pad, table_id=table_id
+    )
+    with span(
+        "checkpoint.pull",
+        stage="checkpoint_stage.pull",
+        table_id=table_id,
+        rows=n,
+        padded_rows=pad,
+    ):
+        return _pull(device_lanes, sel, n, pad)
+
+
+def _pull(device_lanes, sel, n: int, pad: int) -> Dict[str, np.ndarray]:
     idx = np.zeros(pad, np.int32)
     idx[:n] = sel
     gathered = _gather(dict(device_lanes), jnp.asarray(idx))
@@ -190,17 +226,33 @@ class Checkpointable:
     # checkpoint manager later consumes captures in epoch order.
     _captured_deltas = None
 
+    def _pull_delta(self) -> List[StateDelta]:
+        """``checkpoint_delta`` under the span ``checkpoint.marks``: what
+        an executor's staging does outside its row pull — reading the
+        dirty/live/stored marks off the device, classifying them,
+        flipping them. Its ``pull_rows`` nest inside as
+        ``checkpoint.pull``, carrying this table's id."""
+        tid = self.table_id or ",".join(self.checkpoint_table_ids())
+        if not tid:  # state that is no table (the session dictionary)
+            return self.checkpoint_delta()
+        _STAGING.table_id = tid
+        try:
+            with span("checkpoint.marks", table_id=tid):
+                return self.checkpoint_delta()
+        finally:
+            _STAGING.table_id = None
+
     def capture_checkpoint(self) -> None:
         if self._captured_deltas is None:
             self._captured_deltas = _deque()
-        self._captured_deltas.append(self.checkpoint_delta())
+        self._captured_deltas.append(self._pull_delta())
 
     def staged_or_live_delta(self) -> List[StateDelta]:
         """Oldest captured delta if any (pipelined mode), else a live
         pull (synchronous mode)."""
         if self._captured_deltas:
             return self._captured_deltas.popleft()
-        return self.checkpoint_delta()
+        return self._pull_delta()
 
     def discard_captured(self) -> None:
         """Recovery: captured deltas of rolled-back epochs are stale."""
@@ -428,10 +480,11 @@ class CheckpointManager:
             # state whose downstream emissions were not yet durable)
             wm_fn = getattr(ex, "cleaning_watermarks", None)
             if wm_fn is not None:
-                for tid, key, val in wm_fn():
-                    cur = self._pending_watermarks.get(tid)
-                    if cur is None or cur[0] != key or cur[1] < val:
-                        self._pending_watermarks[tid] = (key, int(val))
+                with span("checkpoint.watermarks", table_id=ex.table_id):
+                    for tid, key, val in wm_fn():
+                        cur = self._pending_watermarks.get(tid)
+                        if cur is None or cur[0] != key or cur[1] < val:
+                            self._pending_watermarks[tid] = (key, int(val))
             for delta in ex.staged_or_live_delta():
                 if delta.table_id in seen_ids:
                     raise ValueError(
@@ -454,30 +507,43 @@ class CheckpointManager:
         ``trace`` (an EpochTrace) receives the upload / manifest_commit
         stage attribution; without one the stages still land in the
         ``barrier_stage_ms`` histogram."""
-        import time as _time
-
         with self._lock:
             if epoch <= int(self.version["max_committed_epoch"]):
                 raise ValueError(
                     f"epoch {epoch} <= committed "
                     f"{self.version['max_committed_epoch']}"
                 )
-        t_upload = _time.perf_counter()
+        # the caller binds ``trace`` as its thread's stage sink where it
+        # is not already (the runtime's worker does; a direct caller
+        # with a trace of its own gets the same stamps)
+        with bind(trace) if trace is not None else nullcontext():
+            return self._commit_staged(epoch, staged)
+
+    def _commit_staged(self, epoch: int, staged: Sequence[StateDelta]) -> int:
         n = 0
         new_entries = []  # (table_id, entry) — registered under lock below
         for delta in staged:
             if len(delta.tombstone) == 0:
                 continue
-            blob = build_sst(
-                delta.table_id,
-                epoch,
-                delta.key_cols,
-                delta.value_cols,
-                delta.tombstone,
-                delta.key_order,
+            with span(
+                "checkpoint.upload", stage="upload", table_id=delta.table_id
+            ) as up:
+                blob = build_sst(
+                    delta.table_id,
+                    epoch,
+                    delta.key_cols,
+                    delta.value_cols,
+                    delta.tombstone,
+                    delta.key_order,
+                )
+                path = (
+                    f"{self.prefix}/sst/{delta.table_id}/{epoch:020d}.sst"
+                )
+                self.store.put(path, blob)
+                up.args["bytes"] = len(blob)
+            REGISTRY.counter("checkpoint_upload_bytes_total").inc(
+                len(blob), table_id=delta.table_id
             )
-            path = f"{self.prefix}/sst/{delta.table_id}/{epoch:020d}.sst"
-            self.store.put(path, blob)
             new_entries.append(
                 (
                     delta.table_id,
@@ -490,13 +556,13 @@ class CheckpointManager:
             n += 1
         from risingwave_tpu import utils_sync_point as sync_point
 
-        upload_ms = (_time.perf_counter() - t_upload) * 1e3
         # SSTs are uploaded but the manifest is NOT yet written: the
         # classic crash window (recovery must land on the previous
         # epoch); tests inject crashes here (utils_sync_point)
         sync_point.hit("before_manifest_commit")
-        t_manifest = _time.perf_counter()
-        with self._lock:
+        with span(
+            "checkpoint.manifest", stage="manifest_commit"
+        ), self._lock:
             # re-validate under the lock: a concurrent commit may have
             # advanced the epoch while our SSTs uploaded; publishing
             # unconditionally could regress max_committed_epoch
@@ -531,15 +597,6 @@ class CheckpointManager:
                     )
             self._persist_version()
         sync_point.hit("after_manifest_commit")
-        manifest_ms = (_time.perf_counter() - t_manifest) * 1e3
-        if trace is not None:
-            trace.add_stage("upload", upload_ms)
-            trace.add_stage("manifest_commit", manifest_ms)
-        else:
-            from risingwave_tpu.epoch_trace import record_stage
-
-            record_stage("upload", upload_ms)
-            record_stage("manifest_commit", manifest_ms)
         return n
 
     def commit_epoch(self, epoch: int, executors: Sequence[object]) -> int:

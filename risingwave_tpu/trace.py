@@ -1,11 +1,33 @@
-"""Tracing — lightweight spans with chrome-trace / Perfetto export.
+"""Tracing — the one span call the program times host work with.
 
 Reference: the reference threads `tracing` spans through every actor/
 executor and exports via opentelemetry (src/utils/runtime/src/, await
-tree dumps). Here spans are host-side (device work is opaque inside
-XLA programs anyway): a context manager records (name, start, dur,
-args) per thread into a bounded ring, renders chrome://tracing JSON,
-and mirrors durations into the metrics registry.
+tree dumps). Here ``span(name, *, stage=None, **args)`` is that call:
+
+- every span records name, start, duration, thread, its **parent** (the
+  span open on the same thread when it began) and the **epoch** it
+  belongs to, into a bounded ring (chrome://tracing / Perfetto export);
+- a span whose epoch is not known when it closes (a push or an actor's
+  chunk, which belong to the epoch whose barrier closes them) waits on
+  its thread until that thread calls ``close_epoch(epoch)``;
+- with ``stage=`` its duration is reported to the thread's bound stage
+  sink (``bind(sink)``: the epoch's EpochTrace on the barrier's thread
+  and the checkpoint worker, an accumulator on a pushing or an actor
+  thread), which feeds ``EpochTrace.stages_ms`` and the
+  ``barrier_stage_ms{stage,fragment}`` histogram — one stamp per stage;
+- while a profiler session runs (``jax.profiler.start_trace``), every
+  span is also a ``TraceAnnotation("rw/<name>", epoch=..., ...)``, so
+  the program's spans lie in the same ``.xplane.pb`` as ``XLA Ops``, on
+  its clock, each on its own thread. With no session an annotation is a
+  flag test: "tracing off" means no session, the ring and the stage
+  stamps are always on;
+- jax's ``/jax/core/compile/*`` events become ``compile`` spans (stage
+  ``compile``), children of whatever span was open on the compiling
+  thread, and a finished backend compile under an open span becomes one
+  ``compile`` entry of the event log.
+
+The live span stack is per thread (``active_spans`` reads every
+thread's own list), so spans on different actors share no lock.
 
 Perfetto niceties (dispatch-wall profiler):
 - stable per-thread tids (a small registry id, never ``tid % 1e6``
@@ -13,57 +35,85 @@ Perfetto niceties (dispatch-wall profiler):
   metadata, so the flame view shows actor names;
 - per-fragment pid lanes: spans carrying a ``fragment`` arg render in
   that fragment's own process track (named via process_name metadata);
-- epoch flow events: spans carrying an ``epoch`` arg are linked with
-  ``ph:"s"/"t"`` flow arrows, so one barrier is traceable across every
-  actor thread it crossed.
+- epoch flow events: spans of one epoch are linked with ``ph:"s"/"t"``
+  flow arrows, so one barrier is traceable across every actor thread it
+  crossed.
 """
 
 from __future__ import annotations
 
+import itertools
 import json
 import threading
 import time
+import weakref
 from collections import deque
 from contextlib import contextmanager
 
-from risingwave_tpu.metrics import REGISTRY
+import jax
+from jax.profiler import TraceAnnotation
+
+from risingwave_tpu.epoch_trace import record_stage
+from risingwave_tpu.event_log import EVENT_LOG
 
 _MAX_EVENTS = 65_536
+_MAX_PENDING = 8_192  # spans of one thread waiting for their epoch
 
-# live span stacks per thread (the await-tree analogue: the reference
-# dumps every actor's pending await tree on stall; here every thread's
-# currently-open span stack is snapshotable via active_spans())
-_ACTIVE_LOCK = threading.Lock()
-_ACTIVE: dict = {}  # tid -> (thread_name, [ {span, t0, args}, ... ])
+_SIDS = itertools.count(1)  # span ids; next() is atomic under the GIL
+_profiling = TraceAnnotation.is_enabled  # is a profiler session running
+
+# per-thread state: the live span stack (the await-tree analogue: the
+# reference dumps every actor's pending await tree on stall; here every
+# thread's currently-open spans are snapshotable via active_spans()),
+# the thread's stable small tid, the spans waiting for their epoch, and
+# what ``bind`` set: the stage sink and the epoch
+_TLS = threading.local()
 
 # stable small tids: python thread idents are reused after thread death
-# and collide under ``% 1_000_000`` — assign each (ident, name) its own
-# monotonic id. Names live in a SEPARATE {small_tid: name} map that is
-# append-only: a recycled ident gets a fresh small tid, and the dead
-# thread's tid keeps its name (post-recovery traces still label the
-# pre-fault actor's lane correctly).
-_TID_LOCK = threading.Lock()
-_TIDS: dict = {}  # python ident -> (small_tid, thread_name)
+# and collide under ``% 1_000_000`` — each thread gets its own monotonic
+# id the first time it opens a span. Names live in a SEPARATE
+# {small_tid: name} map that is append-only: the dead thread's tid keeps
+# its name (post-recovery traces still label the pre-fault actor's lane
+# correctly). Taken once per thread, never per span.
+_THREADS_LOCK = threading.Lock()
+# small_tid -> (weakref to the thread, its name, its live stack): an
+# actor thread holds its executors, and a ring must not keep them alive
+_THREADS: dict = {}
 _TID_NAMES: dict = {}  # small_tid -> thread_name (never overwritten)
 _NEXT_TID = [1]
 
 
-def _stable_tid() -> int:
-    ident = threading.get_ident()
-    with _TID_LOCK:
-        entry = _TIDS.get(ident)
-        name = threading.current_thread().name
-        if entry is None or entry[1] != name:
-            # new thread, or the ident was recycled by a new thread
-            entry = (_NEXT_TID[0], name)
+class _ThreadState:
+    __slots__ = ("tid", "stack", "pending", "sink", "epoch", "closed")
+
+    def __init__(self):
+        me = threading.current_thread()
+        self.stack: list = []
+        self.pending: deque = deque(maxlen=_MAX_PENDING)
+        self.sink = None
+        self.epoch = None
+        self.closed = None  # the newest epoch close_epoch was told of
+        with _THREADS_LOCK:
+            self.tid = _NEXT_TID[0]
             _NEXT_TID[0] += 1
-            _TIDS[ident] = entry
-            _TID_NAMES[entry[0]] = name
-        return entry[0]
+            _TID_NAMES[self.tid] = me.name
+            for tid in [
+                t for t, (th, _n, st) in _THREADS.items()
+                if not st and (th() is None or not th().is_alive())
+            ]:
+                del _THREADS[tid]
+            _THREADS[self.tid] = (weakref.ref(me), me.name, self.stack)
+
+
+def _state() -> _ThreadState:
+    st = getattr(_TLS, "st", None)
+    if st is None:
+        st = _TLS.st = _ThreadState()
+    return st
 
 
 def _thread_names() -> dict:
-    with _TID_LOCK:
+    with _THREADS_LOCK:
         return dict(_TID_NAMES)
 
 
@@ -72,69 +122,186 @@ def active_spans() -> dict:
     actor/worker is doing RIGHT NOW (outermost first), with elapsed
     seconds. The stall-dump surface (reference: await-tree dumps)."""
     now = time.perf_counter()
+    with _THREADS_LOCK:
+        threads = [(t, n, list(st)) for t, (_th, n, st) in _THREADS.items()]
     out = {}
-    with _ACTIVE_LOCK:
-        for tid, (tname, stack) in _ACTIVE.items():
+    for tid, tname, stack in threads:
+        if stack:
             out[f"{tname}({tid})"] = [
                 {
-                    "span": fr["span"],
-                    "elapsed_s": round(now - fr["t0"], 4),
-                    **({"args": fr["args"]} if fr["args"] else {}),
+                    "span": sp.name,
+                    "elapsed_s": round(now - sp.t0, 4),
+                    **({"args": dict(sp.args)} if sp.args else {}),
                 }
-                for fr in stack
+                for sp in stack
             ]
     return out
+
+
+def add_stage(stage: str, ms: float, fragment: str = "-") -> None:
+    """A stage duration -> the thread's bound sink, else straight into
+    the ``barrier_stage_ms`` histogram (work outside any epoch). Spans
+    with ``stage=`` report through it; so does a thread that hands on
+    sums measured on another (a graph's actors, at their barrier)."""
+    sink = _state().sink
+    if sink is not None:
+        sink.add_stage(stage, ms, fragment)
+    else:
+        record_stage(stage, ms, fragment)
+
+
+
+class Span:
+    """One span: the ring's record and, while open, the live frame.
+    ``args`` may be added to while it is open (``sp.args["rows"] = n``)."""
+
+    __slots__ = (
+        "tracer", "name", "stage", "args", "tid", "sid", "parent",
+        "epoch", "t0", "dur", "_ann",
+    )
+
+    def __init__(self, tracer, name, stage, args):
+        self.tracer = tracer
+        self.name = name
+        self.stage = stage
+        self.args = args
+        self.dur = None
+        self._ann = None
+
+    def _adopt(self, st: _ThreadState):
+        """Take thread, id, parent (the span open on this thread now)
+        and epoch (given, else the parent's, else the thread's bound
+        one); the parent is handed back."""
+        up = st.stack[-1] if st.stack else None
+        self.tid = st.tid
+        self.sid = next(_SIDS)
+        self.parent = up.sid if up is not None else None
+        epoch = self.args.get("epoch")
+        if epoch is None:
+            epoch = up.epoch if up is not None else None
+            if epoch is None:
+                epoch = st.epoch
+        self.epoch = epoch
+        return up
+
+    def __enter__(self):
+        st = _state()
+        stack = st.stack
+        self._adopt(st)
+        epoch = self.epoch
+        if _profiling():
+            kw = {
+                k: v for k, v in self.args.items()
+                if isinstance(v, (str, int, float))
+            }
+            if epoch is not None:
+                kw["epoch"] = epoch
+            elif st.closed is not None:
+                # open epoch, number not known yet: the one after this
+                kw["after"] = st.closed
+            self._ann = TraceAnnotation("rw/" + self.name, **kw)
+            self._ann.__enter__()
+        stack.append(self)
+        self.t0 = time.perf_counter()
+        return self
+
+    def __exit__(self, *exc):
+        self.dur = time.perf_counter() - self.t0
+        st = _state()
+        stack = st.stack
+        if stack and stack[-1] is self:
+            stack.pop()
+        elif self in stack:
+            stack.remove(self)
+        if self._ann is not None:
+            self._ann.__exit__(*exc)
+            self._ann = None
+        if self.stage is not None:
+            add_stage(
+                self.stage, self.dur * 1e3, self.args.get("fragment", "-")
+            )
+        self.tracer._record(self, st)
+        return False
+
+    def as_event(self):
+        """(name, tid, t0, dur, args) as ``render_chrome_trace`` takes
+        it, parent and epoch among the args."""
+        args = dict(self.args)
+        args["sid"] = self.sid
+        if self.parent is not None:
+            args["parent"] = self.parent
+        if self.epoch is not None:
+            args["epoch"] = self.epoch
+        return (self.name, self.tid, self.t0, self.dur, args)
+
+
+class _Off:
+    """What ``span`` hands out while the tracer is disabled."""
+
+    args: dict = {}
+    epoch = None
+    dur = 0.0
+
+    def __enter__(self):
+        self.args = {}
+        return self
+
+    def __exit__(self, *exc):
+        return False
 
 
 class Tracer:
     def __init__(self, max_events: int = _MAX_EVENTS):
         self._events: deque = deque(maxlen=max_events)
-        self._lock = threading.Lock()
         self.enabled = True
 
-    @contextmanager
-    def span(self, name: str, **args):
+    def span(self, name: str, *, stage=None, **args):
         if not self.enabled:
-            yield
-            return
-        t0 = time.perf_counter()
-        tid = threading.get_ident()
-        frame = {"span": name, "t0": t0, "args": args or None}
-        with _ACTIVE_LOCK:
-            if tid not in _ACTIVE:
-                _ACTIVE[tid] = (threading.current_thread().name, [])
-            _ACTIVE[tid][1].append(frame)
-        stid = _stable_tid()
-        try:
-            yield
-        finally:
-            dur = time.perf_counter() - t0
-            with _ACTIVE_LOCK:
-                entry = _ACTIVE.get(tid)
-                if entry is not None:
-                    stack = entry[1]
-                    if frame in stack:
-                        stack.remove(frame)
-                    if not stack:
-                        del _ACTIVE[tid]
-            with self._lock:
-                self._events.append(
-                    (
-                        name,
-                        stid,
-                        t0,
-                        dur,
-                        args or None,
-                    )
-                )
-            REGISTRY.histogram("span_ms").observe(dur * 1e3, span=name)
+            return _Off()
+        return Span(self, name, stage, args)
+
+    def _record(self, sp: Span, st: _ThreadState) -> None:
+        self._events.append(sp)  # deque.append is atomic
+        if sp.epoch is None:
+            st.pending.append(sp)
+
+    def record(self, name: str, t0: float, dur: float, *, stage=None, **args):
+        """A span that is only known once it is over (jax reports a
+        compile when it has finished): child of the span open on this
+        thread now, stamped and recorded like any other."""
+        if not self.enabled:
+            return None
+        st = _state()
+        sp = Span(self, name, stage, args)
+        up = sp._adopt(st)
+        # jax times a compile on the wall clock: keep the span inside
+        # the parent it fell in (the stage is stamped with jax's figure)
+        ms = dur * 1e3
+        if up is not None and t0 < up.t0:
+            t0, dur = up.t0, max(t0 + dur - up.t0, 0.0)
+        sp.t0, sp.dur = t0, dur
+        if _profiling():
+            # the profiler cannot be told of the past: a marker at the
+            # end says what it was and how long it took
+            with TraceAnnotation(
+                "rw/" + name, ms=round(ms, 3),
+                **{k: v for k, v in args.items() if isinstance(v, (str, int))},
+            ):
+                pass
+        if stage is not None:
+            add_stage(stage, ms, args.get("fragment", "-"))
+        self._record(sp, st)
+        return sp
+
+    def spans(self) -> list:
+        """The ring's closed spans, oldest first."""
+        return list(self._events)
 
     def chrome_trace(self) -> str:
         """chrome://tracing / Perfetto 'traceEvents' JSON: named threads
         (ph:"M" thread_name), per-fragment pid lanes, and epoch flow
         events (ph:"s"/"t") linking one barrier across actor threads."""
-        with self._lock:
-            events = list(self._events)
+        events = [sp.as_event() for sp in list(self._events)]
         return render_chrome_trace(events, _thread_names())
 
     def dump(self, path: str) -> None:
@@ -142,8 +309,94 @@ class Tracer:
             f.write(self.chrome_trace())
 
     def clear(self) -> None:
-        with self._lock:
-            self._events.clear()
+        self._events.clear()
+
+
+@contextmanager
+def bind(sink=None, epoch=None):
+    """For the enclosed block, this thread's spans report their
+    ``stage=`` durations to ``sink`` (anything with ``add_stage(stage,
+    ms, fragment)``) and belong to ``epoch`` (default: the sink's own
+    ``epoch``, where it has one)."""
+    st = _state()
+    old = (st.sink, st.epoch)
+    st.sink = sink
+    st.epoch = epoch if epoch is not None else getattr(sink, "epoch", None)
+    try:
+        yield sink
+    finally:
+        st.sink, st.epoch = old
+
+
+def bound() -> bool:
+    """Whether this thread's spans report to a stage sink (an epoch's
+    owner is driving) or stand alone."""
+    return _state().sink is not None
+
+
+def close_epoch(epoch: int) -> None:
+    """The barrier that closes this thread's open epoch has come: the
+    spans that waited for it (pushes, an actor's chunks and waits)
+    belong to ``epoch``."""
+    st = _state()
+    st.closed = epoch
+    pending = st.pending
+    while pending:
+        sp = pending.popleft()
+        sp.epoch = epoch
+        if sp.name == "compile" and sp.args.get("event") == _COMPILED:
+            _log_compile(sp)
+
+
+def _enclosing_stage(st: _ThreadState):
+    for sp in reversed(st.stack):
+        if sp.stage is not None:
+            return sp.stage
+    return st.stack[-1].name if st.stack else None
+
+
+def _log_compile(sp: Span) -> None:
+    """/events names the program that compiled, once per executable, and
+    only where the program was at work (a span open): a start's hundreds
+    of compiles outside any epoch would flush the log."""
+    if "within" not in sp.args:
+        return
+    EVENT_LOG.record(
+        "compile",
+        epoch=sp.epoch,
+        stage=sp.args["within"],
+        fun_name=sp.args["fun_name"],
+        ms=round(sp.dur * 1e3, 3),
+    )
+
+
+_COMPILE_PREFIX = "/jax/core/compile/"
+_COMPILED = "backend_compile_duration"
+
+
+def _on_compile(event: str, start: float, end: float, **kw) -> None:
+    """jax.monitoring listener: one ``compile`` span per program traced,
+    lowered or compiled, on the compiling thread. jax times them on the
+    wall clock; the span keeps the duration and ends now."""
+    if not event.startswith(_COMPILE_PREFIX) or not TRACER.enabled:
+        return
+    args = {
+        "fun_name": str(kw.get("fun_name", "?")),
+        "event": event[len(_COMPILE_PREFIX):],
+    }
+    within = _enclosing_stage(_state())
+    if within is not None:
+        args["within"] = within
+    dur = max(end - start, 0.0)
+    sp = TRACER.record(
+        "compile", time.perf_counter() - dur, dur, stage="compile", **args
+    )
+    # a compile whose epoch is still open is logged by close_epoch
+    if sp.epoch is not None and args["event"] == _COMPILED:
+        _log_compile(sp)
+
+
+jax.monitoring.register_event_time_span_listener(_on_compile)
 
 
 def render_chrome_trace(events, thread_names=None) -> str:
