@@ -87,3 +87,11 @@ class StringDictionary:
     def dump(self) -> List[str]:
         """Code-ordered string list; feed back to __init__ to restore."""
         return list(self._strings)
+
+    def tail(self, start: int) -> List[str]:
+        """The strings of codes ``[start, n)``, ``n`` the length when
+        called — read BEFORE the slice, so a string a concurrent encode
+        appends meanwhile is in this tail or in the next one
+        (``tail(start + len(result))``), never in neither."""
+        n = len(self._strings)
+        return self._strings[start:n]

@@ -175,7 +175,8 @@ class EpochTrace:
       checkpoint_stage.pull  — [checkpoint.pull] pull_rows: gather
                                dispatch + the device->host copy
       checkpoint_stage.dictionary — [checkpoint.dictionary] the session
-                               dictionary's persistence
+                               dictionary's new strings, put as one
+                               segment (0.0: none was new)
       upload                 — [checkpoint.upload] SST build + put, on
       manifest_commit          the checkpoint worker; [checkpoint.
                                manifest] version write (the durability

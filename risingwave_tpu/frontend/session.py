@@ -167,7 +167,7 @@ class SqlSession:
             install_sys_tables(self)
         self.meta = None
         if getattr(self.runtime, "mgr", None) is not None:
-            # durable meta: DDL log + dictionary snapshots ride the
+            # durable meta: DDL log + dictionary segments ride the
             # same object store as Hummock state (storage/meta_backup)
             from risingwave_tpu.storage.meta_backup import (
                 DictionaryPersistor,
@@ -175,10 +175,6 @@ class SqlSession:
             )
 
             self.meta = MetaStore(self.runtime.mgr.store)
-            dump = self.meta.load_strings()
-            if dump:
-                for t in dump:
-                    self.strings.encode_one(t)
             self.runtime.register_state(
                 DictionaryPersistor(self.strings, self.meta)
             )

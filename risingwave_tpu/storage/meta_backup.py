@@ -11,6 +11,25 @@ Reference roles:
   manifest, and every SST the manifest references — restorable into an
   empty store.
 
+The dictionary is append-only (a code is a position and never moves),
+so it is persisted as append-only SEGMENTS, one object per checkpoint
+that has new strings::
+
+    meta/strings/0000000000.json   {"first": 0,   "strings": [...]}
+    meta/strings/0000000150.json   {"first": 150, "strings": [...]}
+    meta/strings.json              legacy whole-list blob of an earlier
+                                   tree: read as codes [0, n), never
+                                   written
+
+A segment is named by its first code, zero-padded: listing order is
+code order, and the retry of a failed put writes the same name again
+(puts are atomic). A checkpoint's cost is that of its new strings,
+not of everything ingested so far. The write stays at checkpoint STAGE
+time, on the barrier's thread (see ``DictionaryPersistor``); only
+opening a store does O(dictionary) work: ``load_strings`` reads the
+segments in order, fails on a gap, and merges them into one, so their
+number is bounded by the barriers since the last start.
+
 Restart flow (the reference's cluster bootstrap): replay the DDL log
 with backfill/barriers suppressed (structure only), then
 ``runtime.recover()`` restores every executor's state from the last
@@ -20,20 +39,26 @@ committed epoch — tables, MVs, source offsets, dictionary.
 from __future__ import annotations
 
 import json
-from typing import List, Optional
+from typing import List
 
-
+from risingwave_tpu.integrity import StateCorruption
 from risingwave_tpu.metrics import REGISTRY
 from risingwave_tpu.storage.object_store import ObjectStore
 from risingwave_tpu.trace import span
 
 DDL_PATH = "meta/ddl.json"
-STRINGS_PATH = "meta/strings.json"
+STRINGS_PREFIX = "meta/strings/"
+LEGACY_STRINGS_PATH = "meta/strings.json"
 BACKUP_PREFIX = "backup"
 
 
+def _segment_path(first: int) -> str:
+    # ten digits hold every int32 code
+    return f"{STRINGS_PREFIX}{first:010d}.json"
+
+
 class MetaStore:
-    """Durable DDL log + dictionary snapshot."""
+    """Durable DDL log + dictionary segments."""
 
     def __init__(self, store: ObjectStore):
         self.store = store
@@ -48,56 +73,104 @@ class MetaStore:
     def ddl(self) -> List[str]:
         return list(self._ddl)
 
-    def save_strings(self, dump: List[str]):
-        """Persist the dictionary; (strings, bytes) written."""
-        with span("dictionary.json", strings=len(dump)):
-            blob = json.dumps(dump).encode()
+    def append_strings(self, first: int, strings: List[str]) -> int:
+        """Persist codes ``[first, first + len(strings))`` as the
+        segment named by ``first``; bytes written."""
+        with span("dictionary.json", strings=len(strings)):
+            blob = json.dumps({"first": first, "strings": strings}).encode()
         with span("dictionary.put", bytes=len(blob)):
-            self.store.put(STRINGS_PATH, blob)
-        return len(dump), len(blob)
+            self.store.put(_segment_path(first), blob)
+        return len(blob)
 
-    def load_strings(self) -> Optional[List[str]]:
-        if not self.store.exists(STRINGS_PATH):
-            return None
-        return json.loads(self.store.read(STRINGS_PATH))
+    def load_strings(self) -> List[str]:
+        """The persisted dictionary in code order: the legacy blob as
+        the base, then every segment, each starting where the strings
+        read so far end. A gap raises ``StateCorruption`` (shifted codes
+        would silently change every VARCHAR in committed state); what a
+        segment repeats of the strings already read is skipped (a merge
+        that did not finish leaves such segments).
+
+        More than one object read is merged into one (opening a store
+        is the only place that does O(dictionary) work): the merged
+        segment is put first, so a crash before the deletes leaves only
+        objects it covers."""
+        out: List[str] = []
+        paths = []
+        if self.store.exists(LEGACY_STRINGS_PATH):
+            out = json.loads(self.store.read(LEGACY_STRINGS_PATH))
+            paths.append(LEGACY_STRINGS_PATH)
+        for path in self.store.list(STRINGS_PREFIX):
+            seg = json.loads(self.store.read(path))
+            first = seg["first"]
+            if first > len(out):
+                raise StateCorruption(
+                    path,
+                    "dictionary_gap",
+                    f"no segment holds the codes [{len(out)}, {first})",
+                    expected=len(out),
+                    actual=first,
+                )
+            out.extend(seg["strings"][len(out) - first :])
+            paths.append(path)
+        if len(paths) > 1:
+            self.append_strings(0, out)
+            for path in paths:
+                if path != _segment_path(0):
+                    self.store.delete(path)
+        return out
 
 
 from risingwave_tpu.storage.state_table import Checkpointable
 
 
 class DictionaryPersistor(Checkpointable):
-    """Aux state object: persists the session dictionary at checkpoint
-    STAGE time — strictly BEFORE the manifest that references its codes
-    becomes durable (persisting after the commit left a crash window
-    where committed state held codes the persisted dictionary lacked).
-    A dictionary persisted ahead of a failed commit is harmless: extra
-    codes decode nothing."""
+    """Aux state object: persists the session dictionary's new strings
+    at checkpoint STAGE time, on the barrier's thread — strictly BEFORE
+    the manifest that references their codes becomes durable
+    (persisting after the commit left a crash window where committed
+    state held codes the persisted dictionary lacked). A segment
+    persisted ahead of a failed commit is harmless: extra codes decode
+    nothing, and after a restore the next new string takes the code
+    after them, where the next segment starts.
+
+    Opening restores the persisted strings into ``strings`` (empty
+    until then: a code is a position)."""
 
     def __init__(self, strings, meta: MetaStore):
         self.strings = strings
         self.meta = meta
-        self._persisted_len = 0
+        for s in meta.load_strings():
+            strings.encode_one(s)
+        self._persisted_len = len(strings)
 
     def checkpoint_table_ids(self):
         return ()
 
     def checkpoint_delta(self):
-        new = len(self.strings) - self._persisted_len
+        first = self._persisted_len
+        new = len(self.strings) - first
         if new:
-            # the dictionary is written whole whenever a string is new:
-            # the span says what that costs, by strings and bytes
+            # strings / total_strings is the share of the dictionary
+            # this checkpoint wrote
             with span(
                 "checkpoint.dictionary",
                 stage="checkpoint_stage.dictionary",
                 new_strings=new,
             ) as sp:
-                with span("dictionary.dump"):
-                    dump = self.strings.dump()
-                n, nbytes = self.meta.save_strings(dump)
-                sp.args.update(strings=n, bytes=nbytes)
-            REGISTRY.counter("checkpoint_dictionary_strings_total").inc(n)
+                tail = self.strings.tail(first)
+                nbytes = self.meta.append_strings(first, tail)
+                sp.args.update(
+                    strings=len(tail),
+                    bytes=nbytes,
+                    total_strings=first + len(tail),
+                )
+            REGISTRY.counter("checkpoint_dictionary_strings_total").inc(
+                len(tail)
+            )
             REGISTRY.counter("checkpoint_dictionary_bytes_total").inc(nbytes)
-            self._persisted_len = len(self.strings)
+            # only after the put returned: a put that raised leaves the
+            # next barrier to write the same segment name again
+            self._persisted_len = first + len(tail)
         return []
 
     def state_digest(self) -> int:
@@ -156,7 +229,9 @@ def create_backup(store: ObjectStore, backup_id: str) -> dict:
                     verify_sst_entry(store, e),
                 )
                 ssts += 1
-    for p in (DDL_PATH, STRINGS_PATH):
+    # the dictionary AFTER the manifests: its segments then hold every
+    # code the copied manifests reference
+    for p in (DDL_PATH, LEGACY_STRINGS_PATH, *store.list(STRINGS_PREFIX)):
         if store.exists(p):
             store.put(f"{BACKUP_PREFIX}/{backup_id}/{p}", store.read(p))
             copied.append(p)

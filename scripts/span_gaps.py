@@ -241,7 +241,8 @@ def ring_epochs() -> list:
             name = f"{name}[{sp.args.get('actor')}]"
         ep["ms"][name] = ep["ms"].get(name, 0.0) + sp.dur * 1e3
         ep["n"][name] = ep["n"].get(name, 0) + 1
-        for k in ("rows", "padded_rows", "strings", "bytes", "permits"):
+        for k in ("rows", "padded_rows", "strings", "total_strings", "bytes",
+                  "permits"):
             v = sp.args.get(k)
             if isinstance(v, (int, float)):
                 c = ep["count"].setdefault(name, {})

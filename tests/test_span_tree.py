@@ -187,15 +187,16 @@ def test_three_barriers_every_span_has_epoch_parent_and_stage(q8):
         if want is not None:
             assert parent_name(sp) == want, (sp.name, parent_name(sp))
     # counts at the same places: rows a pull moved, strings and bytes
-    # the dictionary wrote, bytes an upload put, rows against lanes
+    # the dictionary wrote (its new ones, of all it holds), bytes an
+    # upload put, rows against lanes
     pulls = [sp for sp in mine if sp.name == "checkpoint.pull"]
     assert all(
         0 < sp.args["rows"] <= sp.args["padded_rows"] and sp.args["table_id"]
         for sp in pulls
     )
     (dic,) = [sp for sp in mine if sp.name == "checkpoint.dictionary"]
-    assert dic.args["new_strings"] == 50
-    assert dic.args["strings"] >= 150 and dic.args["bytes"] > 0
+    assert dic.args["strings"] == dic.args["new_strings"] == 50
+    assert dic.args["total_strings"] >= 150 and dic.args["bytes"] > 0
     assert all(
         sp.args["bytes"] > 0 for sp in mine if sp.name == "checkpoint.upload"
     )
