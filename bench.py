@@ -1,5 +1,7 @@
 #!/usr/bin/env python
-"""Nexmark q5/q7/q8 throughput bench (the BASELINE.md headline path).
+"""Nexmark q5 counts (q5-lite)/q7/q8 throughput bench (the BASELINE.md
+headline path; "q5" below is the hop-window bid count core, not the whole
+"hot items" query).
 
 Measures the streaming pipelines in events/sec on the TPU (``--smoke``
 runs a small tier on the CPU backend), against vectorized single-core
@@ -737,7 +739,7 @@ Q5_SQL = (
 
 
 def bench_q5_unified(epochs, events_per_epoch, chunk_events):
-    """The SAME q5 as SQL through the UNIFIED path: planner -> actor
+    """The SAME q5 counts (q5-lite) as SQL through the UNIFIED path: planner -> actor
     graph (dispatchers, permit channels, FragmentActor threads) — the
     one-path-from-SQL-to-execution evidence, measured."""
     import numpy as np

@@ -5,7 +5,8 @@ One process, one chip. Drives the system's main path once at the size
 its bench tiers call real, and checks every result against a plain
 numpy recompute over the same events:
 
-  stage "served": NEXmark q5 and q8 through SqlSession(exec_mode="graph")
+  stage "served": NEXmark q5 counts (q5-lite: the hop-window bid count,
+    not the whole "hot items" query) and q8 through SqlSession(exec_mode="graph")
     — CREATE TABLE x3 + CREATE MATERIALIZED VIEW, chunks routed to
     ``session.dml._targets`` exactly as ``pump_sources`` routes them, a
     checkpoint committed on every barrier (checkpoint_frequency=1 over a
@@ -215,7 +216,7 @@ def _assert_checkpointed(runtime, barriers: int) -> None:
 
 
 def stage_served(seed, barriers, shape, state_dir, meter) -> dict:
-    """q5 + q8 through SQL -> planner -> graph runtime -> fused barrier
+    """q5 counts (q5-lite) + q8 through SQL -> planner -> graph runtime -> fused barrier
     program, checkpointed every barrier, read back over pgwire."""
     from __graft_entry__ import Q5_SQL, Q8_SQL
     from bench import _state_cap, cpu_actor_baseline, cpu_actor_q8

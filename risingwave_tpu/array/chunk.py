@@ -179,15 +179,18 @@ class DataChunk:
         """(valid_prefix, pad): transfer the 1-byte valid lane first,
         then move only the prefix holding live rows — emission chunks
         compact valid rows to the front (compact_pairs / agg flush), so
-        this turns O(capacity) device->host copies into O(live). The
-        pow2 pad bounds distinct slice programs; scattered-valid chunks
-        degrade to the full copy, never worse."""
+        this turns O(capacity) device->host copies into O(live) for a
+        large chunk. A chunk of up to 2^16 lanes is copied whole
+        (bucketing.prefix_pad): its slice program does not depend on
+        what an epoch holds; scattered-valid chunks degrade to the full
+        copy, never worse."""
+        from risingwave_tpu.runtime.bucketing import prefix_pad
+
         valid = np.asarray(self.valid)
         nz = np.flatnonzero(valid)
         if len(nz) == 0:
             return valid[:0], 0
-        k = int(nz[-1]) + 1
-        pad = min(len(valid), max(2, 1 << (k - 1).bit_length()))
+        pad = prefix_pad(int(nz[-1]) + 1, len(valid))
         return valid[:pad], pad
 
     def to_numpy(self) -> Dict[str, np.ndarray]:

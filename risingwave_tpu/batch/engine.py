@@ -31,6 +31,16 @@ DISTINCT_AGG_NAMES = ("approx_count_distinct",)
 COLLECT_AGGS = ("string_agg", "array_agg")
 
 
+def _scan_cap(n: int) -> int:
+    """Lanes of the chunk a scan of ``n`` rows is evaluated in: a power
+    of two, never under the delta lattice's small size, so that a view
+    that grows from 20 rows to 80 inside a run (a dashboard's probe of
+    it, every 50 ms) does not meet a new program at 32 and at 64."""
+    from risingwave_tpu.runtime.bucketing import DELTA_SMALL, pow2_at_least
+
+    return pow2_at_least(max(n, DELTA_SMALL))
+
+
 def _is_batch_agg(fc) -> bool:
     return isinstance(fc, P.FuncCall) and (
         fc.name in AGG_FUNCS
@@ -216,7 +226,7 @@ class BatchQueryEngine:
         schema = {k: v.dtype for k, v in cols.items()}
         binder = Binder(schema, alias)
         if n and stmt.where is not None:
-            cap = max(1, 1 << (n - 1).bit_length())
+            cap = _scan_cap(n)
             chunk = self._chunk_from_cols(cols, cap)
             keep_v, keep_n = compile_scalar(stmt.where, binder).eval(chunk)
             keep = np.asarray(keep_v).astype(bool)
@@ -311,7 +321,7 @@ class BatchQueryEngine:
         hb = Binder(
             {k: np.asarray(v).dtype for k, v in value_cols.items()}, None
         )
-        cap = max(1, 1 << (n - 1).bit_length())
+        cap = _scan_cap(n)
         chunk = self._chunk_from_cols(value_cols, cap, nulls=null_masks or None)
         kv, kn = compile_scalar(having, hb).eval(chunk)
         keep = np.asarray(kv).astype(bool)[:n]
@@ -674,7 +684,7 @@ class BatchQueryEngine:
         if n == 0:
             return np.zeros(0, bool)
         stripped = _strip_quals(on, set(cols))
-        cap = max(1, 1 << (n - 1).bit_length())
+        cap = _scan_cap(n)
         # float NaN is this engine's outer-join NULL encoding: a NaN
         # cell must make the predicate NULL (drop), not compare as a
         # value (NaN != x is True in IEEE, NULL != x is NULL in SQL)
@@ -700,7 +710,7 @@ class BatchQueryEngine:
         select's items — the object-lane None-scan is O(rows*cols)."""
         if isinstance(ast, P.Ident):
             return cols[binder.resolve(ast)], None
-        cap = max(1, 1 << max(0, (n - 1)).bit_length()) if n else 1
+        cap = _scan_cap(n) if n else 1
         if chunk_cache is not None and chunk_cache[0] is not None:
             chunk = chunk_cache[0]
         else:

@@ -47,6 +47,7 @@ from risingwave_tpu.ops import minput as mi_ops
 from risingwave_tpu.ops.agg import AggCall, AggState
 from risingwave_tpu.ops.hash_table import HashTable, lookup, lookup_or_insert, stage_scalars, set_live
 from risingwave_tpu.runtime.bucketing import BucketAllocator, BucketPolicy
+from risingwave_tpu.trace import span
 
 GROW_AT = 0.5  # rehash when claimed slots may exceed this load factor
 # mid-epoch rebuild only when the HOST insert bound nears the table
@@ -645,6 +646,13 @@ class HashAggExecutor(Executor, Checkpointable):
         self._maybe_grow(chunk.capacity)
         self._insert_bound += chunk.capacity
         self._dirty_bound += chunk.capacity
+        # the step's enqueue (the device runs it asynchronously), as
+        # actor.join_step is for a join
+        with span("actor.agg_step", table_id=self.table_id):
+            self._step(chunk)
+        return []
+
+    def _step(self, chunk: StreamChunk) -> None:
         if self.minput:
             (
                 self.table,
@@ -673,7 +681,6 @@ class HashAggExecutor(Executor, Checkpointable):
                 self.group_keys,
                 self.nullable,
             )
-        return []
 
     def apply_stacked(
         self, stacked: StreamChunk, pre=None, mode: str = "reduce"
@@ -706,6 +713,13 @@ class HashAggExecutor(Executor, Checkpointable):
         self._maybe_grow(n_chunks * probe.valid.shape[0])
         self._insert_bound += n_chunks * probe.valid.shape[0]
         self._dirty_bound += n_chunks * probe.valid.shape[0]
+        with span(
+            "actor.agg_step", table_id=self.table_id, chunks=int(n_chunks)
+        ):
+            self._step_stacked(stacked, pre, mode)
+        return []
+
+    def _step_stacked(self, stacked, pre, mode) -> None:
         if self.minput:
             if mode != "reduce":
                 raise ValueError(
@@ -730,7 +744,7 @@ class HashAggExecutor(Executor, Checkpointable):
                 self.minput,
                 self.mi_bad,
             )
-            return []
+            return
         step = _agg_epoch_reduced if mode == "reduce" else _agg_scan
         self.table, self.state, self.dropped = step(
             self.table,
@@ -742,7 +756,6 @@ class HashAggExecutor(Executor, Checkpointable):
             self.nullable,
             pre,
         )
-        return []
 
     def _survivor_count(self):
         """Device scalar: what a rebuild keeps (live | emitted | dirty |
@@ -814,13 +827,29 @@ class HashAggExecutor(Executor, Checkpointable):
             self.state.minmax_retracted,
             self.mi_bad,
             self.table.occupancy(),
+            # distinct values the materialized MIN/MAX calls hold
+            sum(
+                (jnp.sum(cnt > 0) for _, cnt in self.minput.values()),
+                jnp.zeros((), jnp.int64),
+            ),
         )
         if barrier is None:  # direct drive: checks fire inline
             self.finish_barrier()
         return outs
 
     def _on_barrier_scalars(self, vals) -> None:
-        dropped, mret, mi_bad, claimed = vals
+        # (the fused program hands its four latches and no count)
+        dropped, mret, mi_bad, claimed, *held = vals
+        if self.minput and held:
+            from risingwave_tpu.metrics import REGISTRY
+
+            REGISTRY.gauge("minput_values").set(
+                held[0], table_id=self.table_id
+            )
+            if mi_bad:
+                REGISTRY.counter("minput_overflows_total").inc(
+                    table_id=self.table_id
+                )
         # occupancy refreshes _insert_bound so the NEXT epoch's
         # _maybe_grow decides without any round-trip (the allocator's
         # occupancy note), and feeds the lazy-shrink streak

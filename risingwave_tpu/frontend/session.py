@@ -268,6 +268,10 @@ class SqlSession:
         if self.hub is not None and self._hub_oid is not None:
             self.hub.unsubscribe(self._hub_oid)
             self._hub_oid = None
+        # whoever closes a session may remove the store's directory
+        # next: a compaction pass still writing SSTs there would race it
+        # (the pass runs on a daemon thread nothing else joins)
+        self.runtime.wait_compaction()
 
     def _fresh_planner(self) -> StreamPlanner:
         """A fresh planner per graph-mode instance: deterministic

@@ -33,7 +33,7 @@ an extra int lane when those join types land.
 
 from __future__ import annotations
 
-from dataclasses import dataclass
+from dataclasses import dataclass, replace
 from functools import partial
 from typing import Dict, Optional, Sequence, Tuple
 
@@ -518,4 +518,337 @@ def expire_keys(side: JoinSide, key_index: int, cutoff: jnp.ndarray) -> JoinSide
     return JoinSide(
         table, side.rows, side.row_nulls, row_valid, side.overflow,
         side.inconsistent, side.sdirty | expired, side.stored, degree,
+    )
+
+
+# ---------------------------------------------------------------------------
+# Flat sides: one lane a row, under the side's own stream key
+# ---------------------------------------------------------------------------
+# The bucket layout above bounds the rows of one join key by ``fanout``.
+# An updating stream joined on a coarser key than its own (counts per
+# (window, auction) joined on the window alone) has neither a bound on
+# the rows of one key nor any use for buckets: an update names its row
+# by the stream key, so the row is rewritten in place by a fan-out-1
+# lookup. A ``FlatSide`` is a HashTable over the stream key with every
+# column of the row beside it, and a lane of NULL flags for each column
+# that has been seen to carry one. A side that is unique per join key (its
+# stream key lies within the join key) is probed by the same lookup;
+# the many side is probed by a masked scan of its lanes, one pass per
+# changed row of the unique side.
+
+
+@jax.tree_util.register_pytree_node_class
+@dataclass
+class FlatSide:
+    table: HashTable  # over the stream key; ``live`` marks stored rows
+    rows: Dict[str, jnp.ndarray]  # column -> (capacity,)
+    row_nulls: Dict[str, jnp.ndarray]  # nullable column -> (capacity,) NULL
+    sdirty: jnp.ndarray  # changed since the last checkpoint
+    stored: jnp.ndarray  # in the checkpoint store
+    dropped: jnp.ndarray  # () latch: the table overflowed MAX_PROBE
+
+    def tree_flatten(self):
+        names = tuple(sorted(self.rows))
+        null_names = tuple(sorted(self.row_nulls))
+        return (
+            (self.table, tuple(self.rows[n] for n in names),
+             tuple(self.row_nulls[n] for n in null_names), self.sdirty,
+             self.stored, self.dropped),
+            (names, null_names),
+        )
+
+    @classmethod
+    def tree_unflatten(cls, aux, children):
+        names, null_names = aux
+        table, rows, nulls, sdirty, stored, dropped = children
+        return cls(
+            table, dict(zip(names, rows)), dict(zip(null_names, nulls)),
+            sdirty, stored, dropped,
+        )
+
+    @property
+    def capacity(self) -> int:
+        return self.table.capacity
+
+    @staticmethod
+    def create(
+        capacity: int, pk_dtypes, row_dtypes: Dict[str, object], nullable=()
+    ):
+        return FlatSide(
+            HashTable.create(capacity, pk_dtypes),
+            {n: jnp.zeros(capacity, d) for n, d in row_dtypes.items()},
+            {n: jnp.zeros(capacity, jnp.bool_) for n in nullable},
+            jnp.zeros(capacity, jnp.bool_),
+            jnp.zeros(capacity, jnp.bool_),
+            jnp.zeros((), jnp.bool_),
+        )
+
+    def with_null_lanes(self, names) -> "FlatSide":
+        """Null lanes (no row NULL yet) for columns that had none."""
+        lanes = {n: jnp.zeros(self.capacity, jnp.bool_) for n in names}
+        return replace(self, row_nulls={**lanes, **self.row_nulls})
+
+
+def _not_null(mask, nulls, names):
+    """``mask`` without the lanes where one of ``names`` is NULL."""
+    for n in names:
+        null = nulls.get(n)
+        if null is not None:
+            mask = mask & ~null
+    return mask
+
+
+def _flat_upsert(side: FlatSide, chunk, pk: Tuple[str, ...]):
+    """The chunk's rows applied to their lanes in order: the last row
+    of a stream key wins (an insert stores it, a delete clears it). A
+    row with a NULL in its stream key is not stored. Returns (side',
+    slots, ok)."""
+    from risingwave_tpu.ops.hash_table import last_occurrence_mask
+
+    valid = _not_null(chunk.valid, chunk.nulls, pk)
+    table, slots, _, _ = lookup_or_insert(
+        side.table, tuple(chunk.col(k) for k in pk), valid
+    )
+    ok = valid & (slots >= 0)
+    dropped = side.dropped | jnp.any(valid & (slots < 0))
+    last = last_occurrence_mask(slots, ok)
+    idx = jnp.where(last, slots, jnp.int32(table.capacity))
+    rows = {
+        n: a.at[idx].set(chunk.col(n).astype(a.dtype), mode="drop")
+        for n, a in side.rows.items()
+    }
+    no_null = jnp.zeros(chunk.valid.shape, jnp.bool_)
+    row_nulls = {
+        n: a.at[idx].set(chunk.nulls.get(n, no_null), mode="drop")
+        for n, a in side.row_nulls.items()
+    }
+    table = set_live(table, jnp.where(last, slots, -1), chunk.signs() > 0)
+    sdirty = side.sdirty.at[idx].set(True, mode="drop")
+    return (
+        FlatSide(table, rows, row_nulls, sdirty, side.stored, dropped),
+        slots, ok,
+    )
+
+
+def _keep_pairs(cond, cols, nulls, hit):
+    """The pairs among ``hit`` that the residual predicate keeps (a
+    predicate that is NULL keeps nothing)."""
+    if cond is None:
+        return hit
+    from risingwave_tpu.array.chunk import DataChunk
+
+    v, null = cond.value.eval(DataChunk(cols, hit, nulls))
+    keep = hit & v.astype(jnp.bool_)
+    return keep if null is None else keep & ~null
+
+
+def _first_set(mask, size: int):
+    """(lanes of the first ``size`` set bits of ``mask``, in order; how
+    many bits are set). A prefix sum and ``size`` binary searches:
+    ``jnp.nonzero`` scatter-adds every lane of the mask, which on the
+    chip cost 0.25 s for 2^22 lanes where this costs a few ms."""
+    csum = jnp.cumsum(mask.astype(jnp.int32))
+    at = jnp.searchsorted(
+        csum, jnp.arange(1, size + 1, dtype=jnp.int32), side="left"
+    )
+    return jnp.minimum(at, mask.shape[0] - 1).astype(jnp.int32), csum[-1]
+
+
+def _compact(cols, nulls, ops, keep):
+    """Kept rows moved to the front in their order (a host pull then
+    copies a prefix, not the capacity)."""
+    n = keep.shape[0]
+    at, count = _first_set(keep, n)
+    valid = jnp.arange(n, dtype=jnp.int32) < count
+    return (
+        {k: a[at] for k, a in cols.items()},
+        {k: a[at] for k, a in nulls.items()},
+        ops[at], valid,
+    )
+
+
+def flat_many_step(
+    many: FlatSide,
+    unique: FlatSide,
+    chunk,
+    many_pk: Tuple[str, ...],
+    unique_pk_from: Tuple[str, ...],
+    key_pairs: Tuple[Tuple[str, str], ...],
+    cond,
+):
+    """A chunk of the many side: every row looks its join key up on the
+    unique side (at most one match), the pair passes the residual or
+    not, and the row is stored under its own stream key.
+    ``unique_pk_from`` names, per column of the unique side's stream
+    key, the many side's column it is joined to; ``key_pairs`` is every
+    (many column, unique column) of the equi key. Returns (many',
+    columns, nulls, ops, valid, [pairs matched, pairs kept])."""
+    from risingwave_tpu.types import mend_update_pairs
+
+    # a NULL key matches nothing
+    key_ok = _not_null(chunk.valid, chunk.nulls, [mc for mc, _ in key_pairs])
+    uslots, found = lookup(
+        unique.table, tuple(chunk.col(c) for c in unique_pk_from), key_ok
+    )
+    g = jnp.maximum(uslots, 0)
+    hit = found & key_ok
+    for mc, uc in key_pairs:
+        hit &= unique.rows[uc][g] == chunk.col(mc)
+        if uc in unique.row_nulls:
+            hit &= ~unique.row_nulls[uc][g]
+    cols = {n: chunk.col(n) for n in many.rows}
+    cols.update({n: a[g] for n, a in unique.rows.items()})
+    nulls = {n: chunk.nulls[n] for n in many.rows if n in chunk.nulls}
+    nulls.update({n: a[g] for n, a in unique.row_nulls.items()})
+    keep = _keep_pairs(cond, cols, nulls, hit)
+    # a U-/U+ pair of which one half is not emitted is a bare op
+    ops = mend_update_pairs(chunk.ops, keep)
+    out_cols, out_nulls, out_ops, out_valid = _compact(cols, nulls, ops, keep)
+    many, _, _ = _flat_upsert(many, chunk, many_pk)
+    counts = jnp.stack([jnp.sum(hit), jnp.sum(keep)]).astype(jnp.int64)
+    return many, out_cols, out_nulls, out_ops, out_valid, counts
+
+
+def flat_unique_upsert(unique: FlatSide, chunk, pk: Tuple[str, ...]):
+    """A chunk of the unique side, reduced to its changed rows: per
+    distinct stream key the row it replaces and the row it leaves,
+    gathered to the front. Returns (unique', changed rows, old, new);
+    ``old`` / ``new`` = (live, values, NULL flags) of the row that went
+    and of the row that came."""
+    from risingwave_tpu.ops.hash_table import first_occurrence_mask
+
+    before = unique
+    unique, slots, ok = _flat_upsert(unique, chunk, pk)
+    rep = first_occurrence_mask(slots, ok)
+    n = rep.shape[0]
+    at, changed = _first_set(rep, n)
+    g = jnp.maximum(slots, 0)[at]
+    is_rep = jnp.arange(n, dtype=jnp.int32) < changed
+    old_live = before.table.live[g] & is_rep
+    new_live = unique.table.live[g] & is_rep
+    old_vals = {k: a[g] for k, a in before.rows.items()}
+    new_vals = {k: a[g] for k, a in unique.rows.items()}
+    old_nulls = {k: a[g] for k, a in before.row_nulls.items()}
+    new_nulls = {k: a[g] for k, a in unique.row_nulls.items()}
+    same = old_live & new_live
+    for k in old_vals:
+        eq = old_vals[k] == new_vals[k]
+        if k in old_nulls:
+            either = old_nulls[k] | new_nulls[k]
+            eq = jnp.where(either, old_nulls[k] == new_nulls[k], eq)
+        same &= eq
+    return (
+        unique, changed,
+        (old_live & ~same, old_vals, old_nulls),
+        (new_live & ~same, new_vals, new_nulls),
+    )
+
+
+def flat_scan(
+    many: FlatSide,
+    changed,
+    old,
+    new,
+    key_pairs: Tuple[Tuple[str, str], ...],
+    cond,
+):
+    """The many side's lanes under a mask, one pass a changed row of
+    the unique side: which lanes pair with the row that went (to be
+    retracted) and with the row that came (to be inserted), after the
+    residual. A lane has one join key, so at most one changed row each
+    way. Returns (d_src, i_src, [pairs matched, pairs kept]): per lane
+    the changed row's index, or -1."""
+    cap = many.capacity
+    live = _not_null(
+        many.table.live, many.row_nulls, [mc for mc, _ in key_pairs]
+    )
+
+    def side(j, row):
+        alive, vals, vnulls = row
+        hit = live & alive[j]
+        for mc, uc in key_pairs:
+            hit &= many.rows[mc] == vals[uc][j]
+            if uc in vnulls:
+                hit &= ~vnulls[uc][j]
+        cols = dict(many.rows)
+        cols.update(
+            {n: jnp.broadcast_to(a[j], (cap,)) for n, a in vals.items()}
+        )
+        nulls = dict(many.row_nulls)
+        nulls.update(
+            {n: jnp.broadcast_to(a[j], (cap,)) for n, a in vnulls.items()}
+        )
+        return hit, _keep_pairs(cond, cols, nulls, hit)
+
+    def body(j, carry):
+        d_src, i_src, counts = carry
+        d_hit, d_keep = side(j, old)
+        i_hit, i_keep = side(j, new)
+        counts = counts + jnp.stack([
+            jnp.sum(d_hit) + jnp.sum(i_hit),
+            jnp.sum(d_keep) + jnp.sum(i_keep),
+        ]).astype(jnp.int64)
+        return (
+            jnp.where(d_keep, j, d_src),
+            jnp.where(i_keep, j, i_src),
+            counts,
+        )
+
+    none = jnp.full(cap, -1, jnp.int32)
+    return jax.lax.fori_loop(
+        0, changed, body, (none, none, jnp.zeros(2, jnp.int64))
+    )
+
+
+def flat_emit(many: FlatSide, d_src, i_src, old, new, out_cap: int):
+    """The scan's pairs as one chunk: the retractions, then the
+    inserts, lanes in order. Returns (columns, nulls, ops, valid,
+    overflow); ``overflow`` when either kind has more than ``out_cap``
+    pairs."""
+    from risingwave_tpu.types import Op
+
+    def pick(src, row):
+        _, vals, vnulls = row
+        at, count = _first_set(src >= 0, out_cap)
+        cols = {n: a[at] for n, a in many.rows.items()}
+        cols.update({n: a[src[at]] for n, a in vals.items()})
+        nulls = {n: a[at] for n, a in many.row_nulls.items()}
+        nulls.update({n: a[src[at]] for n, a in vnulls.items()})
+        return cols, nulls, jnp.arange(out_cap, dtype=jnp.int32) < count, count
+
+    d_cols, d_nulls, d_valid, d_n = pick(d_src, old)
+    i_cols, i_nulls, i_valid, i_n = pick(i_src, new)
+    cols = {n: jnp.concatenate([d_cols[n], i_cols[n]]) for n in d_cols}
+    nulls = {n: jnp.concatenate([d_nulls[n], i_nulls[n]]) for n in d_nulls}
+    ops = jnp.concatenate([
+        jnp.full(out_cap, Op.DELETE, jnp.int32),
+        jnp.full(out_cap, Op.INSERT, jnp.int32),
+    ])
+    cols, nulls, ops, valid = _compact(
+        cols, nulls, ops, jnp.concatenate([d_valid, i_valid])
+    )
+    return cols, nulls, ops, valid, (d_n > out_cap) | (i_n > out_cap)
+
+
+def flat_regrow(side: FlatSide, new_cap: int) -> FlatSide:
+    """Rebuild at ``new_cap`` keeping the rows that are stored or owe
+    the checkpoint a tombstone."""
+    keep = side.table.live | side.sdirty
+    fresh = FlatSide.create(
+        new_cap,
+        tuple(k.dtype for k in side.table.keys),
+        {n: a.dtype for n, a in side.rows.items()},
+        tuple(side.row_nulls),
+    )
+    table, slots, _, _ = lookup_or_insert(fresh.table, side.table.keys, keep)
+    idx = jnp.where(keep & (slots >= 0), slots, jnp.int32(new_cap))
+    table = set_live(table, jnp.where(keep, slots, -1), side.table.live)
+    move = lambda dst, src: dst.at[idx].set(src, mode="drop")  # noqa: E731
+    return FlatSide(
+        table,
+        {n: move(fresh.rows[n], a) for n, a in side.rows.items()},
+        {n: move(fresh.row_nulls[n], a) for n, a in side.row_nulls.items()},
+        move(fresh.sdirty, side.sdirty),
+        move(fresh.stored, side.stored),
+        side.dropped | jnp.any(keep & (slots < 0)),
     )

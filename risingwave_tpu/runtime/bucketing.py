@@ -59,6 +59,8 @@ __all__ = [
     "plan_capacity",
     "pow2_at_least",
     "validate_lattice",
+    "delta_blocks",
+    "prefix_pad",
 ]
 
 # lattice span above the configured capacity: initial_cap << STEPS is
@@ -94,6 +96,37 @@ def emission_bucket(n: int, floor: int = 2) -> int:
     Downstream programs then see at most log2(max_delta) distinct
     shapes instead of one per distinct count."""
     return pow2_at_least(max(int(n), floor))
+
+
+# The delta lattice: what a barrier moves between device and host (the
+# rows a checkpoint stages, the live prefix of a chunk a host-map MV
+# pulls) follows the rows the epoch changed, which no two epochs share.
+# Padding those to the next power of two compiled one eager program per
+# size the first time an epoch's count crossed one (PERF.md 6, R-m3).
+# Staged rows go in pieces of two sizes instead, SMALL lanes or whole
+# blocks of BLOCK lanes, and a chunk of up to PREFIX_WHOLE lanes is
+# copied whole (half a megabyte a column at most). A program then exists
+# per size, not per count: once an epoch has run, no later epoch of the
+# same chunk shapes compiles.
+DELTA_SMALL = 256
+DELTA_BLOCK = 4096
+PREFIX_WHOLE = 1 << 16
+
+
+def delta_blocks(n: int) -> Tuple[int, int]:
+    """(lanes a transfer, transfers) for ``n`` rows off the device."""
+    if n <= DELTA_SMALL:
+        return DELTA_SMALL, 1
+    return DELTA_BLOCK, -(-int(n) // DELTA_BLOCK)
+
+
+def prefix_pad(k: int, capacity: int) -> int:
+    """Lanes to copy of a chunk whose live rows lie in its first ``k``
+    lanes: all of them, or for a chunk past PREFIX_WHOLE lanes a power
+    of two of blocks."""
+    if capacity <= PREFIX_WHOLE:
+        return capacity
+    return min(capacity, pow2_at_least(max(k, DELTA_BLOCK)))
 
 
 def flush_pad(out_cap: int, emitted_bound: int) -> int:

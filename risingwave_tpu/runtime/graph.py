@@ -69,6 +69,19 @@ CHUNK, BARRIER, WATERMARK, STOP = "chunk", "barrier", "watermark", "stop"
 _PER_ACTOR = ("actor_busy", "actor_idle", "actor_blocked", "actor_fence")
 
 
+@jax.jit
+def _add_edge_rows(acc, valid, ops):
+    """[rows inserted, rows retracted] of one chunk, added to ``acc``."""
+    from risingwave_tpu.types import op_sign
+
+    retract = valid & (op_sign(ops) < 0)
+    rows = jnp.stack([
+        jnp.sum(valid & ~retract).astype(jnp.int64),
+        jnp.sum(retract).astype(jnp.int64),
+    ])
+    return rows if acc is None else acc + rows
+
+
 class PermitChannel:
     """Bounded in-process exchange edge (permit.rs:35).
 
@@ -353,10 +366,40 @@ class FragmentActor(threading.Thread):
         self._wm_seen: Dict[Tuple[int, str], int] = {}
         self._wm_sent: Dict[str, int] = {}
         self._stopped: List[bool] = [False] * len(self.inputs)
+        # [rows inserted, rows retracted] on this actor's operator
+        # edges since the last barrier, on the device; None = no chunk
+        self._edge_rows = None
 
     # -- chain plumbing ---------------------------------------------------
     def _through(self, chain, chunks, barrier=None):
-        return walk_chain(chain, chunks, barrier)
+        return walk_chain(chain, chunks, barrier, tap=self._tap)
+
+    def _tap(self, chunk: StreamChunk) -> None:
+        """An operator edge inside this actor: the rows a chunk inserts
+        and the rows it retracts, summed on the device and read once a
+        barrier (``_note_edge_rows``): one small dispatch a chunk and
+        edge, for every plan."""
+        self._edge_rows = _add_edge_rows(
+            self._edge_rows, chunk.valid, chunk.ops
+        )
+
+    def _note_edge_rows(self, fence) -> None:
+        """The epoch's edge rows: onto the counters, and into the args
+        of the barrier's ``actor.fence`` span (a counter has no epoch;
+        the span has)."""
+        if self._edge_rows is None:
+            return
+        inserted, retracted = jax.device_get(self._edge_rows).tolist()
+        self._edge_rows = None
+        fence.args.update(
+            actor=self.label, insert_rows=inserted, retract_rows=retracted
+        )
+        REGISTRY.counter("actor_chunk_insert_rows_total").inc(
+            inserted, actor=self.label
+        )
+        REGISTRY.counter("actor_chunk_retract_rows_total").inc(
+            retracted, actor=self.label
+        )
 
     def _emit(self, chunks: Sequence[StreamChunk]) -> None:
         for c in chunks:
@@ -376,6 +419,8 @@ class FragmentActor(threading.Thread):
             # the join step's enqueue (the device runs it asynchronously)
             with span("actor.join_step", side=side):
                 outs.extend(_pcall(self.join_exec, "apply", feed, c))
+        for c in outs:
+            self._tap(c)
         self._emit(self._through(self.tail, outs))
 
     def _process_barrier(self, b: Barrier) -> None:
@@ -401,9 +446,12 @@ class FragmentActor(threading.Thread):
             # flush + emit happened above; finish_barrier below is the
             # barrier-only device fence (staged-scalar materialization);
             # transfer_guard (when armed) rejects implicit transfers here
-            with span("actor.fence", stage="actor_fence"), transfer_guard():
+            with span(
+                "actor.fence", stage="actor_fence"
+            ) as fence, transfer_guard():
                 for ex in self.executors:
                     ex.finish_barrier()
+                self._note_edge_rows(fence)
             if b.checkpoint and self.mgr.capture_deltas:
                 # pipelined barriers: seal this epoch's delta NOW, before
                 # any next-epoch chunk in the input queue mutates state
@@ -454,6 +502,8 @@ class FragmentActor(threading.Thread):
             joined.extend(
                 _pcall(self.join_exec, "flush", self.join_exec.on_barrier, b)
             )
+            for c in joined:
+                self._tap(c)
             outs = self._through(self.tail, joined, barrier=b)
             gen, gwms = self._generated_watermarks_join()
             wms.extend(gwms)
@@ -1166,7 +1216,12 @@ class GraphRuntime:
                         epoch,
                     )
                 if self._failure is not None:
-                    raise RuntimeError("actor failed") from self._failure
+                    # the cause's own words: a caller that keeps only
+                    # str(e) (a benchmark's result line) still says why
+                    raise RuntimeError(
+                        f"actor failed: {type(self._failure).__name__}: "
+                        f"{self._failure}"
+                    ) from self._failure
                 if not ok:
                     got = self._collected.get(epoch, set())
                     stuck = sorted(

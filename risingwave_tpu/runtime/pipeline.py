@@ -87,11 +87,12 @@ class FreshnessSurface:
         return s
 
 
-def walk_chain(chain: Sequence[Executor], chunks, barrier=None):
+def walk_chain(chain: Sequence[Executor], chunks, barrier=None, tap=None):
     """Feed chunks (then optionally a barrier) down an executor chain;
     every executor's output — including its barrier flush — is data for
     the executors below it. The single chain-walking loop shared by
-    Pipeline, TwoInputPipeline and the graph runtime's FragmentActor."""
+    Pipeline, TwoInputPipeline and the graph runtime's FragmentActor.
+    ``tap`` sees every chunk an executor hands on (an operator edge)."""
     pending = list(chunks)
     # recompile-hazard fingerprinting (analysis/jax_sanitizer) and the
     # dispatch-wall profiler: one attribute check each when disarmed —
@@ -112,6 +113,9 @@ def walk_chain(chain: Sequence[Executor], chunks, barrier=None):
                 nxt.extend(ex.on_barrier(barrier))
             else:
                 nxt.extend(prof.run(ex, "flush", ex.on_barrier, barrier))
+        if tap is not None:
+            for c in nxt:
+                tap(c)
         pending = nxt
     return pending
 

@@ -210,6 +210,14 @@ class BoundRel:
     # relation: downstream grouped aggs keyed on it clean closed
     # windows (window_key state cleaning)
     window_col: Optional[str] = None
+    # whether the chain's change stream carries inserts only. A base
+    # table scan and what filters, projects, windows or dedups it is;
+    # an aggregate's, an over-window's or a top-n's output is not
+    # (every input row turns a group's row into U-/U+). Downstream
+    # state is chosen from it: MIN/MAX over an updating stream keep
+    # their inputs (materialized), a join side fed by one is stored
+    # under its own stream key.
+    append_only: bool = True
 
 
 def _join_inputs(lsrc: str, rsrc: str) -> Dict[str, str]:
@@ -233,6 +241,10 @@ class PlannedMV:
     # reference fragments an n-way join into a tree of 2-way
     # StreamHashJoins the same way)
     aux: Tuple["PlannedMV", ...] = ()
+    # the MV's own change stream carries inserts only: what a scan of
+    # it inherits. Plans that do not derive it (unions, temporal and
+    # delta joins) keep the planner's old assumption
+    append_only: bool = True
 
 
 class Catalog:
@@ -649,7 +661,8 @@ class StreamPlanner:
         mview = self._make_mview(name, rel)
         pipeline = Pipeline(rel.chain + [mview])
         return PlannedMV(
-            name, pipeline, mview, {rel.source: "single"}, schema=rel.schema
+            name, pipeline, mview, {rel.source: "single"}, schema=rel.schema,
+            append_only=rel.append_only,
         )
 
     def _make_mview(self, name: str, rel):
@@ -706,7 +719,8 @@ class StreamPlanner:
         if isinstance(src, P.SubQuery):
             inner = self._plan_rel(name, src.select)
             return BoundRel(
-                inner.chain, inner.schema, inner.pk, inner.source, src.alias
+                inner.chain, inner.schema, inner.pk, inner.source, src.alias,
+                append_only=inner.append_only,
             )
         if isinstance(src, P.WindowTVF):
             source = src.table.name
@@ -731,6 +745,7 @@ class StreamPlanner:
             return BoundRel(
                 chain, schema, (), source, src.alias,
                 window_col=window_col,
+                append_only=self._scan_append_only(source),
             )
         if isinstance(src, P.TableRef):
             source = src.name
@@ -743,8 +758,20 @@ class StreamPlanner:
                 if self.catalog.is_mv(source)
                 else ()
             )
-            return BoundRel(chain, schema, pk, source, src.alias)
+            return BoundRel(
+                chain, schema, pk, source, src.alias,
+                append_only=self._scan_append_only(source),
+            )
         raise TypeError(f"unsupported FROM {src!r}")
+
+    def _scan_append_only(self, source: str) -> bool:
+        """A base table's stream is taken as inserts only (what every
+        plan assumed before the flag existed); an MV's is what its own
+        plan derived."""
+        if self.catalog.is_mv(source):
+            # (an attached shared MV carries no plan of its own)
+            return getattr(self.catalog.mvs[source], "append_only", True)
+        return True
 
     def _maybe_watermark_filter(
         self, chain: List[Executor], source: str, schema
@@ -774,6 +801,7 @@ class StreamPlanner:
         pk = bound.pk
         source = bound.source
         alias = bound.alias
+        append_only = bound.append_only
 
         binder = Binder(schema, alias)
         if select.where is not None:
@@ -791,7 +819,8 @@ class StreamPlanner:
             chain.extend(chain2)
             return self._maybe_topn(
                 name, select, binder,
-                BoundRel(chain, out_schema, pk, source, alias),
+                BoundRel(chain, out_schema, pk, source, alias,
+                         append_only=False),
             )
 
         if select.group_by:
@@ -802,10 +831,15 @@ class StreamPlanner:
             # build also emits intermediate updates before the close)
             wcol = bound.window_col
             chain2, out_schema, pk = self._plan_groupby(
-                name, select, binder, schema, retractable=False,
-                window_col=wcol,
+                name, select, binder, schema,
+                retractable=not append_only, window_col=wcol,
             )
             chain.extend(chain2)
+            # an aggregate rewrites its group's row on every input row;
+            # a bare DISTINCT (a dedup) only ever inserts
+            grouped_append_only = append_only and not any(
+                isinstance(ex, HashAggExecutor) for ex in chain2
+            )
             if select.having is not None:
                 # HAVING filters the agg's OUTPUT stream (group keys +
                 # agg aliases) — never pushed below the agg
@@ -818,7 +852,8 @@ class StreamPlanner:
                 )
             return self._maybe_topn(
                 name, select, binder,
-                BoundRel(chain, out_schema, pk, source, alias),
+                BoundRel(chain, out_schema, pk, source, alias,
+                         append_only=grouped_append_only),
             )
 
         if any(_is_agg(it.expr) for it in select.items):
@@ -909,7 +944,8 @@ class StreamPlanner:
                         }
                     )
                 )
-            return BoundRel(chain, out_schema, (), source, alias)
+            return BoundRel(chain, out_schema, (), source, alias,
+                            append_only=False)
 
         # no GROUP BY: projection (+ hidden row id when no pk exists)
         outputs: Dict[str, E.Expr] = {}
@@ -942,7 +978,8 @@ class StreamPlanner:
         chain.append(ProjectExecutor(outputs))
         return self._maybe_topn(
             name, select, binder,
-            BoundRel(chain, out_schema2, pk, source, alias),
+            BoundRel(chain, out_schema2, pk, source, alias,
+                     append_only=append_only),
         )
 
     def _try_over_window_to_topn(
@@ -1298,6 +1335,7 @@ class StreamPlanner:
                 table_id=self._tid(name, "topn"),
             )
         )
+        rel.append_only = False  # a row leaves the top n when another enters
         return rel
 
     def _plan_groupby(
@@ -1438,18 +1476,26 @@ class StreamPlanner:
                         }
                     )
                 )
+            minput_k = 256
+            capacity = self.capacity
+            if any(a.materialized for a in aggs):
+                # materialized state is (groups, minput_k) lanes a call:
+                # it starts at no more than 2^24 value lanes (200 MB),
+                # not at ``capacity`` groups of minput_k each (6 GB at
+                # 2^21); the group table grows like any other
+                capacity = min(capacity, max(1 << 10, (1 << 24) // minput_k))
             chain.append(
                 HashAggExecutor(
                     group_keys=keys,
                     calls=tuple(aggs),
                     schema_dtypes=agg_schema,
-                    capacity=self.capacity,
+                    capacity=capacity,
                     nullable_keys=tuple(k for k in keys if k in nullable_cols),
                     table_id=self._tid(name, "agg"),
                     # materialized extremes hold DISTINCT values per
                     # group; SQL plans can't bound that statically, so
                     # size generously (the overflow latch still guards)
-                    minput_k=256,
+                    minput_k=minput_k,
                     # watermark-driven state cleaning for windowed
                     # group keys (retention 0, finalize silently: the
                     # MV keeps the closed windows' final rows)
@@ -1787,7 +1833,8 @@ class StreamPlanner:
         )
         pipeline = Pipeline(rel.chain + [mview])
         return PlannedMV(
-            name, pipeline, mview, {rel.source: "single"}, schema=rel.schema
+            name, pipeline, mview, {rel.source: "single"}, schema=rel.schema,
+            append_only=rel.append_only,
         )
 
     def _plan_join(self, name: str, select: P.Select) -> PlannedMV:
@@ -1855,6 +1902,7 @@ class StreamPlanner:
                 tuple(inner.mview.pk),
                 inner_name,
                 frozenset(quals | {inner_name}),
+                append_only=inner.append_only,
             ),
             inner_name,
         )
@@ -1886,15 +1934,46 @@ class StreamPlanner:
             )
 
         jt = join.join_type
-        lkeys, rkeys = self._equi_keys(join.on, left, right)
-        hj = HashJoinExecutor(
-            left_keys=lkeys,
-            right_keys=rkeys,
-            left_dtypes=left.schema,
-            right_dtypes=right.schema,
-            capacity=self.capacity,
-            join_type=jt,
-            table_id=self._tid(name, "join"),
+        lkeys, rkeys, residual = self._equi_keys(join.on, left, right)
+        if residual is not None and jt != "inner":
+            # sigma(A JOIN B) is a filter over the change stream only
+            # for an inner join: an outer/semi/anti join's NULL-padded
+            # and bare rows depend on which pairs the predicate keeps
+            raise ValueError(
+                f"a {jt} join's ON must be AND-ed equality conditions: a "
+                "residual predicate is supported on INNER joins only"
+            )
+        cond = (
+            compile_scalar(
+                residual, Binder({**left.schema, **right.schema}, None)
+            )
+            if residual is not None
+            else None
+        )
+        join_tid = self._tid(name, "join")
+        hj = self._keyed_join(
+            jt, lkeys, rkeys, left, right, cond, join_tid
+        )
+        post_join: List[Executor] = []
+        if hj is None:
+            hj = HashJoinExecutor(
+                left_keys=lkeys,
+                right_keys=rkeys,
+                left_dtypes=left.schema,
+                right_dtypes=right.schema,
+                capacity=self.capacity,
+                join_type=jt,
+                table_id=join_tid,
+            )
+            if cond is not None:
+                # the residual filters the equi join's change stream
+                from risingwave_tpu.executors.filter import (
+                    ResidualFilterExecutor,
+                )
+
+                post_join.append(ResidualFilterExecutor(cond, join_tid))
+        out_append_only = (
+            jt == "inner" and left.append_only and right.append_only
         )
         # output column set per join type (hash_join.rs:129 variants):
         # semi/anti emit only the driving side; outer joins emit both
@@ -1906,7 +1985,7 @@ class StreamPlanner:
         else:
             visible = set(left.schema) | set(right.schema)
         binder = Binder({**left.schema, **right.schema}, None)
-        tail: List[Executor] = []
+        tail: List[Executor] = post_join
         if select.where is not None:
             for ident in _idents_in(select.where):
                 n = self._join_resolve(ident, left, right)
@@ -2097,7 +2176,46 @@ class StreamPlanner:
             mview,
             _join_inputs(left.source, right.source),
             schema=out_schema,
+            append_only=out_append_only,
         )
+
+    def _keyed_join(self, jt, lkeys, rkeys, left, right, cond, table_id):
+        """The join for an INNER join that has a side unique per join
+        key (that side's stream key lies within the equi key: an
+        aggregate joined back on its own group key) while the other,
+        the many side, is an updating stream with a stream key of its
+        own. Key buckets of a fixed fan-out fit neither fact: the many
+        side can hold any number of rows under one key and rewrites
+        them in place. Both sides are then stored flat, one lane a
+        row, under their stream keys (executors/keyed_join.py). An
+        append-only many side keeps the bucket layout (None)."""
+        if jt != "inner":
+            return None
+        from risingwave_tpu.executors.keyed_join import KeyedJoinExecutor
+
+        for unique, many, ukeys, uleft in (
+            (right, left, rkeys, False),
+            (left, right, lkeys, True),
+        ):
+            if (
+                unique.pk
+                and set(unique.pk) <= set(ukeys)
+                and many.pk
+                and not many.append_only
+            ):
+                return KeyedJoinExecutor(
+                    left_keys=lkeys,
+                    right_keys=rkeys,
+                    left_dtypes=left.schema,
+                    right_dtypes=right.schema,
+                    left_pk=tuple(left.pk),
+                    right_pk=tuple(right.pk),
+                    unique_side="left" if uleft else "right",
+                    condition=cond,
+                    capacity=self.capacity,
+                    table_id=table_id,
+                )
+        return None
 
     def _rel_of(self, name: str, rel) -> BoundRel:
         if isinstance(rel, P.SubQuery):
@@ -2421,6 +2539,7 @@ class StreamPlanner:
             tuple(ren.get(p, p) for p in rel.pk),
             rel.source,
             rel.alias,
+            append_only=rel.append_only,
         )
 
     @staticmethod
@@ -2449,8 +2568,12 @@ class StreamPlanner:
         raise KeyError(f"cannot resolve {ident.qualifier}.{ident.name}")
 
     def _equi_keys(self, on, left: BoundRel, right: BoundRel):
-        """Flatten AND-ed equality conditions into positional key lists."""
+        """Split an AND-ed ON clause into its equi conjuncts, as
+        positional key lists, and the residual: what is left of the
+        clause (any other predicate over the two sides' columns), or
+        None."""
         pairs: List[Tuple[str, str]] = []
+        residual: List[object] = []
 
         def walk(e):
             if isinstance(e, P.BinaryOp) and e.op == "and":
@@ -2468,14 +2591,23 @@ class StreamPlanner:
                 bn = self._join_resolve(b, left, right)
                 if an in left.schema and bn in right.schema:
                     pairs.append((an, bn))
-                elif bn in left.schema and an in right.schema:
+                    return
+                if bn in left.schema and an in right.schema:
                     pairs.append((bn, an))
-                else:
-                    raise ValueError("join condition must cross sides")
-                return
-            raise ValueError("ON must be AND-ed equality conditions")
+                    return
+            # not a cross-side column equality: a predicate over the pair
+            for ident in _idents_in(e):
+                self._join_resolve(ident, left, right)
+            residual.append(e)
 
         walk(on)
         if not pairs:
-            raise ValueError("no equi-join keys found")
-        return tuple(p[0] for p in pairs), tuple(p[1] for p in pairs)
+            raise ValueError(
+                "no equi-join keys found: ON needs at least one "
+                "cross-side column equality"
+            )
+        return (
+            tuple(p[0] for p in pairs),
+            tuple(p[1] for p in pairs),
+            _and_all(residual) if residual else None,
+        )

@@ -160,22 +160,26 @@ def pull_rows(
     table_id: Optional[str] = None,
 ) -> Dict[str, np.ndarray]:
     """Device->host transfer of SELECTED rows only (checkpoint staging
-    must be O(changed rows), not O(capacity)). ``sel`` is padded to a
-    power-of-two bucket so jit caches one gather program per bucket
-    size instead of recompiling per distinct count.
+    must be O(changed rows), not O(capacity)). ``sel`` goes in pieces of
+    one of two sizes (bucketing.delta_blocks), so jit caches two gather
+    programs per lane set whatever an epoch changed, instead of one per
+    power of two its count ever crossed.
 
     While an executor's checkpoint delta is being pulled (``table_id``
-    given, or the executor's own under ``_pull_delta``) the gather's
-    dispatch and the device->host copy it waits for are the span
+    given, or the executor's own under ``_pull_delta``) the gathers'
+    dispatch and the device->host copies they wait for are the span
     ``checkpoint.pull``, with the rows and the padded rows it moved."""
+    from risingwave_tpu.runtime.bucketing import delta_blocks
+
     n = len(sel)
     if n == 0:
         return {k: np.asarray(a)[:0] for k, a in device_lanes.items()}
-    pad = 1 << (n - 1).bit_length()
+    block, blocks = delta_blocks(n)
+    pad = block * blocks
     if table_id is None:
         table_id = getattr(_STAGING, "table_id", None)
     if table_id is None:  # a read, not a checkpoint
-        return _pull(device_lanes, sel, n, pad)
+        return _pull(device_lanes, sel, n, block)
     REGISTRY.counter("checkpoint_pull_rows_total").inc(n, table_id=table_id)
     REGISTRY.counter("checkpoint_pull_padded_rows_total").inc(
         pad, table_id=table_id
@@ -187,14 +191,24 @@ def pull_rows(
         rows=n,
         padded_rows=pad,
     ):
-        return _pull(device_lanes, sel, n, pad)
+        return _pull(device_lanes, sel, n, block)
 
 
-def _pull(device_lanes, sel, n: int, pad: int) -> Dict[str, np.ndarray]:
-    idx = np.zeros(pad, np.int32)
+def _pull(device_lanes, sel, n: int, block: int) -> Dict[str, np.ndarray]:
+    lanes = dict(device_lanes)
+    idx = np.zeros(-(-n // block) * block, np.int32)
     idx[:n] = sel
-    gathered = _gather(dict(device_lanes), jnp.asarray(idx))
-    return {k: np.asarray(a)[:n] for k, a in gathered.items()}
+    # every block's gather is enqueued before the first copy is awaited
+    parts = [
+        _gather(lanes, jnp.asarray(idx[a : a + block]))
+        for a in range(0, len(idx), block)
+    ]
+    if len(parts) == 1:
+        return {k: np.asarray(a)[:n] for k, a in parts[0].items()}
+    return {
+        k: np.concatenate([np.asarray(p[k]) for p in parts])[:n]
+        for k in parts[0]
+    }
 
 
 @jax.jit

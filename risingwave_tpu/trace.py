@@ -20,7 +20,9 @@ tree dumps). Here ``span(name, *, stage=None, **args)`` is that call:
   the program's spans lie in the same ``.xplane.pb`` as ``XLA Ops``, on
   its clock, each on its own thread. With no session an annotation is a
   flag test: "tracing off" means no session, the ring and the stage
-  stamps are always on;
+  stamps are always on; ``Span.traced`` says whether the session held
+  the span from start to end (whether the xplane has its event), so a
+  reader of the ring can tell the epochs a device trace covers;
 - jax's ``/jax/core/compile/*`` events become ``compile`` spans (stage
   ``compile``), children of whatever span was open on the compiling
   thread, and a finished backend compile under an open span becomes one
@@ -157,7 +159,7 @@ class Span:
 
     __slots__ = (
         "tracer", "name", "stage", "args", "tid", "sid", "parent",
-        "epoch", "t0", "dur", "_ann",
+        "epoch", "t0", "dur", "_ann", "traced",
     )
 
     def __init__(self, tracer, name, stage, args):
@@ -167,6 +169,9 @@ class Span:
         self.args = args
         self.dur = None
         self._ann = None
+        # whether a profiler session held this span from start to end,
+        # i.e. whether the xplane has its ``rw/`` event
+        self.traced = False
 
     def _adopt(self, st: _ThreadState):
         """Take thread, id, parent (the span open on this thread now)
@@ -214,6 +219,7 @@ class Span:
         elif self in stack:
             stack.remove(self)
         if self._ann is not None:
+            self.traced = _profiling()
             self._ann.__exit__(*exc)
             self._ann = None
         if self.stage is not None:

@@ -50,6 +50,23 @@ def op_sign(ops: jnp.ndarray) -> jnp.ndarray:
     return jnp.where(retract, jnp.int32(-1), jnp.int32(1))
 
 
+def mend_update_pairs(ops: jnp.ndarray, alive: jnp.ndarray) -> jnp.ndarray:
+    """Ops with torn update pairs downgraded: U- at row i pairs with U+
+    at row i+1 (chunk construction invariant, stream_chunk.rs:45); where
+    only one half is still ``alive`` (a filter or a join's residual
+    dropped the other), that half is a plain Delete / Insert."""
+    is_ud = ops == Op.UPDATE_DELETE
+    is_ui = ops == Op.UPDATE_INSERT
+    ops = jnp.where(
+        is_ud & alive & ~(jnp.roll(alive, -1) & jnp.roll(is_ui, -1)),
+        jnp.int32(Op.DELETE), ops,
+    )
+    return jnp.where(
+        is_ui & alive & ~(jnp.roll(alive, 1) & jnp.roll(is_ud, 1)),
+        jnp.int32(Op.INSERT), ops,
+    )
+
+
 class DataType(enum.Enum):
     """Logical column types at the SQL/host edge.
 

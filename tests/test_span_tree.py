@@ -66,17 +66,19 @@ class Q8:
             for frag, side in self.session.dml._targets.get(stream, ()):
                 self.rt.push(frag, chunk, side)
 
-    def epoch(self, rows=50):
-        """One epoch of ``rows`` new persons, each selling one auction."""
-        ids = np.arange(self.next_id, self.next_id + rows, dtype=np.int64)
-        self.next_id += rows
-        ts = ids * 100_000
-        self._push("person", {
-            "id": ids, "name": [f"n{i}" for i in ids], "date_time": ts,
-        })
-        self._push("auction", {
-            "id": ids + 1_000_000, "seller": ids, "date_time": ts,
-        })
+    def epoch(self, rows=50, pushes=1):
+        """One epoch of ``pushes`` x ``rows`` new persons, each selling
+        one auction."""
+        for _ in range(pushes):
+            ids = np.arange(self.next_id, self.next_id + rows, dtype=np.int64)
+            self.next_id += rows
+            ts = ids * 100_000
+            self._push("person", {
+                "id": ids, "name": [f"n{i}" for i in ids], "date_time": ts,
+            })
+            self._push("auction", {
+                "id": ids + 1_000_000, "seller": ids, "date_time": ts,
+            })
         self.rt.barrier()
         self.rt.wait_checkpoints()
         return self.rt.last_epoch_trace
@@ -373,10 +375,10 @@ def test_compile_in_a_barrier_is_a_span_a_stage_and_an_event(q8):
     q8.epoch()
     TRACER.clear()
     seen = {e["seq"] for e in EVENT_LOG.events(limit=100_000)}
-    # an epoch that meets a new padded size: 200 changed rows where the
-    # epochs before staged 50 (pull_rows pads to a power of two and
-    # compiles one gather per size)
-    tr = q8.epoch(rows=200)
+    # an epoch that meets the other padded size: 300 changed rows where
+    # the epochs before staged 50 (pull_rows moves rows in pieces of 256
+    # or of 4,096 lanes and compiles one gather per size)
+    tr = q8.epoch(rows=150, pushes=2)
     assert tr.stages_ms.get("compile", 0.0) > 0.0
     compiles = [sp for sp in TRACER.spans() if sp.name == "compile"]
     assert compiles and all(sp.epoch == tr.epoch for sp in compiles)
