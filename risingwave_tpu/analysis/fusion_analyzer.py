@@ -730,7 +730,7 @@ def analyze_pipeline(
             # join+tail section re-anchor through lint_info emits
             schema = (
                 source_schemas.get(side)
-                if side in ("single", "left", "right")
+                if side in ("single", "left", "right", "both")
                 else None
             )
             label = frag if side in ("single", "chain") else f"{frag}/{side}"

@@ -766,7 +766,7 @@ def analyze_sharded_pipeline(
                 continue
             schema = (
                 source_schemas.get(side)
-                if side in ("single", "left", "right")
+                if side in ("single", "left", "right", "both")
                 else None
             )
             spec = (

@@ -445,12 +445,13 @@ def verify_serial_pipeline(
     if hasattr(pipeline, "join") and hasattr(pipeline, "left"):
         ls = source_schemas.get("left")
         rs = source_schemas.get("right")
-        lschema, lwm = _walk_chain(
-            pipeline.left, ls, set(ls) if ls else None, name, rep, tids
-        )
-        rschema, rwm = _walk_chain(
-            pipeline.right, rs, set(rs) if rs else None, name, rep, tids
-        )
+        lwm, rwm = set(ls) if ls else None, set(rs) if rs else None
+        if getattr(pipeline, "head", None):
+            # one stream through the shared sub-plan, then into both sides
+            ls, lwm = _walk_chain(pipeline.head, ls, lwm, name, rep, tids)
+            rs, rwm = ls, lwm
+        lschema, lwm = _walk_chain(pipeline.left, ls, lwm, name, rep, tids)
+        rschema, rwm = _walk_chain(pipeline.right, rs, rwm, name, rep, tids)
         schema, wm = _verify_join(
             pipeline.join, lschema, rschema, lwm, rwm, name, rep, tids
         )
@@ -608,6 +609,17 @@ def verify_graph_specs(
                     else:
                         port_wm[port] = None
         if isinstance(built, dict):
+            if built.get("head"):
+                # the shared sub-plan's output feeds both sides
+                port_schema[0], port_wm[0] = _walk_chain(
+                    built["head"],
+                    port_schema.get(0),
+                    port_wm.get(0),
+                    name,
+                    rep,
+                    tids,
+                )
+                port_schema[1], port_wm[1] = port_schema[0], port_wm[0]
             lschema, lwm = _walk_chain(
                 built.get("left", []),
                 port_schema.get(0),
@@ -669,7 +681,9 @@ def verify_graph_specs(
             port_of = dict((up, p) for up, p in ok_edges[down])
             port = port_of.get(name, 0)
             if isinstance(built, dict):
-                chain = built.get("left" if port == 0 else "right", [])
+                chain = list(built.get("head", ())) + list(
+                    built.get("left" if port == 0 else "right", [])
+                )
                 jinfo = _join_info(built.get("join"))
                 state_keys = (
                     tuple(
@@ -772,7 +786,8 @@ def _check_rebuildable(
             continue  # builder needs live inputs: nothing provable
         if isinstance(built, dict):
             chains = (
-                list(built.get("left", ()))
+                list(built.get("head", ()))
+                + list(built.get("left", ()))
                 + list(built.get("right", ()))
                 + ([built["join"]] if built.get("join") is not None else [])
                 + list(built.get("tail", ()))
@@ -812,7 +827,9 @@ def verify_planned(
                 if src not in getattr(catalog, "tables", {}):
                     continue
                 sch = catalog.schema_dtypes(src)
-                sides = ("left", "right") if side == "both" else (side,)
+                sides = (
+                    ("left", "right", "both") if side == "both" else (side,)
+                )
                 for s in sides:
                     source_schemas[s] = dict(sch)
     if hasattr(pipeline, "_specs") and hasattr(pipeline, "graph"):

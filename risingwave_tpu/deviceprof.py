@@ -589,7 +589,7 @@ def analyze_nexmark(
                     continue
                 spec = _spec_from_schema(
                     schemas.get(side)
-                    if side in ("single", "left", "right")
+                    if side in ("single", "left", "right", "both")
                     else None
                 )
                 rep.update(

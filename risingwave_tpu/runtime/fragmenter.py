@@ -294,7 +294,8 @@ class GraphPipeline(FreshnessSurface):
     def __init__(
         self,
         specs: Sequence[FragmentSpec],
-        source_map: Dict[str, str],  # side ("single"/"left"/"right") -> frag
+        # side ("single" / "left" / "right" / "both") -> source fragment
+        source_map: Dict[str, str],
         out_fragment: str,
         ckpt_executors: Sequence[object],
         epoch_batch: bool = True,
@@ -447,6 +448,16 @@ class GraphPipeline(FreshnessSurface):
     def push_right(self, chunk: StreamChunk) -> List[StreamChunk]:
         self._note_ingest()
         self.graph.inject_chunk(self._sources["right"], chunk)
+        return []
+
+    def push_both(self, chunk: StreamChunk) -> List[StreamChunk]:
+        """A chunk of one stream that feeds both join inputs: into each
+        side's source, or once into the source of the sub-plan the two
+        sides share."""
+        if "both" not in self._sources:
+            return self.push_left(chunk) + self.push_right(chunk)
+        self._note_ingest()
+        self.graph.inject_chunk(self._sources["both"], chunk)
         return []
 
     def watermark(self, column: str, value: int) -> List[StreamChunk]:
@@ -730,8 +741,8 @@ def sharded_planned_mv(planner_factory, sql: str, n_shards: int):
     mview = proto.mview
     if isinstance(proto.pipeline, TwoInputPipeline):
         tp = proto.pipeline
-        left = _shard_side_chain(tp.left, mesh)
-        right = _shard_side_chain(tp.right, mesh)
+        left = _shard_side_chain(tp.left, mesh, tp.head)
+        right = _shard_side_chain(tp.right, mesh, tp.head)
         if left is None or right is None:
             gp = _two_input_graph([proto], None)
         else:
@@ -845,8 +856,10 @@ def _shard_single_tail(chain, mesh):
     return list(chain[: agg_idx + 1]) + tail_chain, smv
 
 
-def _shard_side_chain(chain, mesh):
-    """A join side shards when it is stateless* + optional ONE keyed op
+def _shard_side_chain(chain, mesh, head=()):
+    """A join side shards when it starts at its source (``head``, a
+    sub-plan shared with the other side, has no sharded form: None) and
+    is stateless* + optional ONE keyed op
     (append-only dedup -> ShardedDedup; windowless non-materialized
     HashAgg -> ShardedHashAgg whose barrier flush stays STACKED and
     feeds the join directly — the q7 per-window-MAX side) + rename-only
@@ -854,6 +867,8 @@ def _shard_side_chain(chain, mesh):
     chain or None."""
     from risingwave_tpu.executors.row_id_gen import RowIdGenExecutor
 
+    if head:
+        return None
     out = []
     seen_keyed = False
     for ex in chain:
@@ -902,8 +917,10 @@ def fragment_chains(pipeline) -> Dict[str, Dict[str, List[object]]]:
     """Normalize ANY pipeline shape into ``{fragment: {section:
     executor chain}}`` for static analysis (plan verifier / fusion
     analyzer). Sections name the input side feeding the chain:
-    ``single``/``left``/``right`` (source-fed — the analyzer can seed
-    an abstract schema), ``join_tail`` (the join executor + tail of a
+    ``single``/``left``/``right``/``both`` (source-fed — the analyzer
+    can seed an abstract schema; ``both`` is the sub-plan a join's two
+    sides share, and the sides it feeds are then ``head_left`` /
+    ``head_right``), ``join_tail`` (the join executor + tail of a
     two-input shape), or ``chain`` (a graph fragment fed by other
     fragments — schema threads through lint_info, not sources).
 
@@ -924,16 +941,13 @@ def fragment_chains(pipeline) -> Dict[str, Dict[str, List[object]]]:
             except Exception:  # noqa: BLE001 — builder needs live inputs
                 built = None
             if isinstance(built, dict):
-                out[s.name] = {
-                    "left": list(built.get("left", ())),
-                    "right": list(built.get("right", ())),
-                    "join_tail": (
-                        [built["join"]]
-                        if built.get("join") is not None
-                        else []
-                    )
-                    + list(built.get("tail", ())),
-                }
+                out[s.name] = _two_input_sections(
+                    built.get("head", ()),
+                    built.get("left", ()),
+                    built.get("right", ()),
+                    built.get("join"),
+                    built.get("tail", ()),
+                )
             elif isinstance(built, (list, tuple)):
                 side = frag_side.get(s.name)
                 key = side or ("single" if not s.inputs else "chain")
@@ -942,16 +956,37 @@ def fragment_chains(pipeline) -> Dict[str, Dict[str, List[object]]]:
                 out[s.name] = {}
         return out
     if hasattr(pipeline, "join") and hasattr(pipeline, "left"):
+        sections = _two_input_sections(
+            getattr(pipeline, "head", ()),
+            pipeline.left,
+            pipeline.right,
+            pipeline.join,
+            pipeline.tail,
+        )
+        frag = {
+            "both": "head",
+            "head_left": "left",
+            "head_right": "right",
+            "join_tail": "out",
+        }
         return {
-            "left": {"left": list(pipeline.left)},
-            "right": {"right": list(pipeline.right)},
-            "out": {
-                "join_tail": [pipeline.join] + list(pipeline.tail)
-            },
+            frag.get(sec, sec): {sec: chain}
+            for sec, chain in sections.items()
         }
     if hasattr(pipeline, "executors"):
         return {"mv": {"single": list(pipeline.executors)}}
     return {}
+
+
+def _two_input_sections(head, left, right, join, tail):
+    """A two-input shape's chains by section (``fragment_chains``): the
+    sides are source-fed unless a shared head feeds them."""
+    lname, rname = ("head_left", "head_right") if head else ("left", "right")
+    sections = {"both": list(head)} if head else {}
+    sections[lname] = list(left)
+    sections[rname] = list(right)
+    sections["join_tail"] = ([join] if join is not None else []) + list(tail)
+    return sections
 
 
 def is_mesh_executor(ex) -> bool:
@@ -1130,23 +1165,31 @@ def _two_input_graph(plans, sides, epoch_batch=True) -> GraphPipeline:
     n = len(plans)
     if sides is None or n == 1:
         build = {
+            "head": tp0.head,
             "left": tp0.left,
             "right": tp0.right,
             "join": tp0.join,
             "tail": tp0.tail,
         }
-        specs = [
-            FragmentSpec("left_src", lambda i: []),
-            FragmentSpec("right_src", lambda i: []),
+        # a shared head is the join actor's one input: one source
+        sources = (
+            {"both": "src"}
+            if tp0.head
+            else {"left": "left_src", "right": "right_src"}
+        )
+        specs = [FragmentSpec(src, lambda i: []) for src in sources.values()]
+        specs.append(
             FragmentSpec(
                 "join",
                 lambda i, b=build: dict(b),
-                inputs=[("left_src", 0), ("right_src", 1)],
-            ),
-        ]
+                inputs=[
+                    (src, port) for port, src in enumerate(sources.values())
+                ],
+            )
+        )
         return GraphPipeline(
             specs,
-            {"left": "left_src", "right": "right_src"},
+            sources,
             "join",
             tp0.executors,
             epoch_batch=epoch_batch,
@@ -1213,6 +1256,8 @@ def _split_join(tp):
     """Partitionability of a two-input join fragment. Returns
     (left dispatch cols, right dispatch cols, join table positions,
     {(side, idx) -> table positions}) or None."""
+    if tp.head:
+        return None  # one chain feeding both sides: no per-side dispatch
     join = tp.join
     lkeys = tuple(join.left_keys)
     rkeys = tuple(join.right_keys)

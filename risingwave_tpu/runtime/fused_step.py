@@ -2054,6 +2054,7 @@ def fuse_two_input(
     provenance (never a silent interpret fallback). Requirements, each
     refused with provenance when unmet:
 
+    - the sides do not start with a shared sub-plan (``head``);
     - the join is a bucketed HashJoin whose trace contract declares
       ``two_input_fusible`` (both sides' capacities on the declared
       pow2 lattice — flush lanes pad to lattice buckets with masks,
@@ -2071,6 +2072,13 @@ def fuse_two_input(
             label,
             "two-input executor is not a HashJoin",
             executor=type(join).__name__,
+        )
+    if getattr(pipeline, "head", None):
+        return _refuse(
+            label,
+            "the sides start with a shared sub-plan (one input fanned "
+            "into both): the two-input program has an arrival batch a side",
+            executor=type(pipeline.head[-1]).__name__,
         )
     contract = join.trace_contract()
     if not contract.get("two_input_fusible"):
@@ -2338,6 +2346,7 @@ def fuse_pipeline(
             if w is not None:
                 pipeline._fused = w
                 return [w]
+        pipeline.head = rewrite(pipeline.head, f"{label}/head")
         pipeline.left = rewrite(pipeline.left, f"{label}/left")
         pipeline.right = rewrite(pipeline.right, f"{label}/right")
         pipeline.tail = rewrite(

@@ -48,7 +48,12 @@ from risingwave_tpu.executors.base import Barrier, Epoch, Executor, Watermark
 from risingwave_tpu.ops.hashing import VNODE_COUNT, hash_columns
 from risingwave_tpu.metrics import REGISTRY
 from risingwave_tpu.profiler import PROFILER
-from risingwave_tpu.runtime.pipeline import _pcall, _walk_watermark, walk_chain
+from risingwave_tpu.runtime.pipeline import (
+    _pcall,
+    _side_watermark,
+    _walk_watermark,
+    walk_chain,
+)
 from risingwave_tpu.trace import add_stage, bind, close_epoch, span
 
 
@@ -323,7 +328,11 @@ class FragmentActor(threading.Thread):
     (actor.rs:165 run / :181 run_consumer).
 
     ``inputs`` is [(port, channel)]: port 0 feeds the main (or left)
-    chain, port 1 the right chain of a two-input fragment. Barrier
+    chain, port 1 the right chain of a two-input fragment. A join
+    whose sides start with one shared sub-plan (``head``; ``shared`` =
+    the executors planned once in it) has port 0 only: a chunk goes
+    through the head once and on into the left, then the right chain,
+    as in ``TwoInputPipeline``. Barrier
     alignment: a channel that has yielded the current barrier is parked
     (not polled) until every channel reaches it — Chandy-Lamport
     alignment exactly as MergeExecutor/BarrierAligner do."""
@@ -339,6 +348,8 @@ class FragmentActor(threading.Thread):
         right_chain: Sequence[Executor] = (),
         tail: Sequence[Executor] = (),
         halt: Optional[threading.Event] = None,
+        head: Sequence[Executor] = (),
+        shared: int = 0,
     ):
         super().__init__(name=f"actor-{name}", daemon=True)
         self.actor_name = name
@@ -347,6 +358,8 @@ class FragmentActor(threading.Thread):
         self.label = f"{mgr.label}/{name}" if mgr.label else name
         # stage sink of this thread's spans; handed over at each barrier
         self._sums = StageSums()
+        self.head = list(head)
+        self.shared = shared
         self.chain = list(chain)
         self.join_exec = join
         self.right_chain = list(right_chain)
@@ -405,20 +418,29 @@ class FragmentActor(threading.Thread):
         for c in chunks:
             self.dispatcher.dispatch(c)
 
+    def _sides(self):
+        """A join's inputs in the order they are fed (port order)."""
+        return (
+            ("left", self.chain, self.join_exec.apply_left),
+            ("right", self.right_chain, self.join_exec.apply_right),
+        )
+
     def _process_chunk(self, port: int, chunk: StreamChunk) -> None:
         if self.join_exec is None:
             self._emit(self._through(self.chain, [chunk]))
             return
-        if port == 0:
-            side, chain, feed = "left", self.chain, self.join_exec.apply_left
+        sides = self._sides()
+        if self.head:
+            chunks = self._through(self.head, [chunk])
         else:
-            side, chain = "right", self.right_chain
-            feed = self.join_exec.apply_right
+            chunks, sides = [chunk], sides[port : port + 1]
         outs = []
-        for c in self._through(chain, [chunk]):
-            # the join step's enqueue (the device runs it asynchronously)
-            with span("actor.join_step", side=side):
-                outs.extend(_pcall(self.join_exec, "apply", feed, c))
+        for side, chain, feed in sides:
+            for c in self._through(chain, chunks):
+                # the join step's enqueue (the device runs it
+                # asynchronously)
+                with span("actor.join_step", side=side):
+                    outs.extend(_pcall(self.join_exec, "apply", feed, c))
         for c in outs:
             self._tap(c)
         self._emit(self._through(self.tail, outs))
@@ -441,6 +463,7 @@ class FragmentActor(threading.Thread):
             epoch=b.epoch.curr,
             fragment=self.actor_name,
             actor=self.actor_name,
+            **({} if self.join_exec is None else {"shared": self.shared}),
         ), PROFILER.barrier_window(fragment=self.actor_name):
             self._process_barrier_inner(b)
             # flush + emit happened above; finish_barrier below is the
@@ -490,15 +513,11 @@ class FragmentActor(threading.Thread):
                         wms.append(down)
             self._emit(outs + gen)
         else:
+            shared = self._through(self.head, [], barrier=b)
             joined: List[StreamChunk] = []
-            for c in self._through(self.chain, [], barrier=b):
-                joined.extend(
-                    _pcall(self.join_exec, "apply", self.join_exec.apply_left, c)
-                )
-            for c in self._through(self.right_chain, [], barrier=b):
-                joined.extend(
-                    _pcall(self.join_exec, "apply", self.join_exec.apply_right, c)
-                )
+            for _side, chain, feed in self._sides():
+                for c in self._through(chain, shared, barrier=b):
+                    joined.extend(_pcall(self.join_exec, "apply", feed, c))
             joined.extend(
                 _pcall(self.join_exec, "flush", self.join_exec.on_barrier, b)
             )
@@ -520,22 +539,29 @@ class FragmentActor(threading.Thread):
         outs: List[StreamChunk] = []
         wms: List[Watermark] = []
         aligned: Optional[Watermark] = None
-        for chain, feed in (
-            (self.chain, self.join_exec.apply_left),
-            (self.right_chain, self.join_exec.apply_right),
-        ):
+        for i, ex in enumerate(self.head):
+            wm = ex.emit_watermark()
+            if wm is None:
+                continue
+            wm, pending = _walk_watermark(self.head[i + 1 :], wm)
+            for _side, chain, feed in self._sides():
+                aligned = (
+                    _side_watermark(
+                        self.join_exec, chain, feed, wm, pending, outs
+                    )
+                    or aligned
+                )
+        for _side, chain, feed in self._sides():
             for i, ex in enumerate(chain):
                 wm = ex.emit_watermark()
                 if wm is None:
                     continue
-                wm, pending = _walk_watermark(chain[i + 1 :], wm)
-                for c in pending:
-                    outs.extend(feed(c))
-                if wm is not None:
-                    down, flushed = self.join_exec.on_watermark(wm)
-                    outs.extend(flushed)
-                    if down is not None:
-                        aligned = down
+                aligned = (
+                    _side_watermark(
+                        self.join_exec, chain[i + 1 :], feed, wm, (), outs
+                    )
+                    or aligned
+                )
         outs = self._through(self.tail, outs)
         if aligned is not None:
             dt, touts = _walk_watermark(self.tail, aligned)
@@ -589,18 +615,14 @@ class FragmentActor(threading.Thread):
             return
         outs: List[StreamChunk] = []
         down_join: Optional[Watermark] = None
-        for side_chain, feed in (
-            (self.chain, self.join_exec.apply_left),
-            (self.right_chain, self.join_exec.apply_right),
-        ):
-            swm, pending = _walk_watermark(side_chain, awm)
-            for c in pending:
-                outs.extend(feed(c))
-            if swm is not None:
-                dj, flushed = self.join_exec.on_watermark(swm)
-                outs.extend(flushed)
-                if dj is not None:
-                    down_join = dj
+        hwm, pending = _walk_watermark(self.head, awm)
+        for _side, chain, feed in self._sides():
+            down_join = (
+                _side_watermark(
+                    self.join_exec, chain, feed, hwm, pending, outs
+                )
+                or down_join
+            )
         self._emit(self._through(self.tail, outs))
         if down_join is not None:
             dt, touts = _walk_watermark(self.tail, down_join)
@@ -710,7 +732,7 @@ class FragmentActor(threading.Thread):
 
     @property
     def executors(self) -> List[Executor]:
-        exs = list(self.chain) + list(self.right_chain)
+        exs = list(self.head) + list(self.chain) + list(self.right_chain)
         if self.join_exec is not None:
             exs.append(self.join_exec)
         exs.extend(self.tail)
@@ -728,7 +750,9 @@ class FragmentSpec:
 
     ``build(instance_idx)`` returns either a list of executors
     (single-input chain) or a dict ``{"left": [...], "right": [...],
-    "join": ex, "tail": [...]}``. ``inputs`` names upstream fragments
+    "join": ex, "tail": [...]}``, with ``"head": [...]`` where both
+    sides start with one shared sub-plan (its one upstream is port 0).
+    ``inputs`` names upstream fragments
     as (fragment_name, port). ``dispatch`` is "simple" | "broadcast" |
     "round_robin" | ("hash", [dist_keys]). ``parallelism`` instantiates
     N actors; hash-dispatching upstreams route vnodes across them
@@ -874,6 +898,8 @@ class GraphRuntime:
 
     def _spawn_actor(self, s: FragmentSpec, inst: int) -> FragmentActor:
         built = s.build(inst)
+        # executors a join's sides share, counted before fusing merges them
+        shared = len(built.get("head", ())) if isinstance(built, dict) else 0
         if self._epoch_batch:
             # collapse each chain's maximal fusible run into ONE
             # donated device program per barrier (runtime/fused_step);
@@ -909,6 +935,7 @@ class GraphRuntime:
                     tail = fuse(built.get("tail", []), f"{s.name}/tail")
                 built = dict(
                     built,
+                    head=fuse(built.get("head", []), f"{s.name}/head"),
                     left=fuse(built.get("left", []), f"{s.name}/left"),
                     right=fuse(built.get("right", []), f"{s.name}/right"),
                     tail=tail,
@@ -946,6 +973,8 @@ class GraphRuntime:
                 right_chain=built.get("right", []),
                 tail=built.get("tail", []),
                 halt=self._halts[(s.name, inst)],
+                head=built.get("head", []),
+                shared=shared,
             )
         else:
             actor = FragmentActor(

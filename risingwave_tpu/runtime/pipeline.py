@@ -218,11 +218,14 @@ class Pipeline(FreshnessSurface):
         return self._epoch
 
 
-def _walk_watermark(chain: Sequence[Executor], wm: Optional[Watermark]):
-    """Walk a watermark down an executor chain, feeding each executor's
-    flushed output chunks through the rest of the chain as data.
+def _walk_watermark(
+    chain: Sequence[Executor], wm: Optional[Watermark], chunks=()
+):
+    """Walk a watermark down an executor chain, behind ``chunks``,
+    feeding each executor's flushed output chunks through the rest of
+    the chain as data.
     Returns (surviving watermark | None, chunks exiting the chain)."""
-    pending: List[StreamChunk] = []
+    pending: List[StreamChunk] = list(chunks)
     for ex in chain:
         nxt: List[StreamChunk] = []
         for c in pending:
@@ -234,6 +237,21 @@ def _walk_watermark(chain: Sequence[Executor], wm: Optional[Watermark]):
     return wm, pending
 
 
+def _side_watermark(join, chain, feed, wm, chunks, outs):
+    """``chunks`` then ``wm`` down one side's chain into ``join``
+    (``feed`` = its apply of that side): what comes out joined is
+    appended to ``outs``; returns the join's aligned downstream
+    watermark, if this side produced one."""
+    wm, pending = _walk_watermark(chain, wm, chunks)
+    for c in pending:
+        outs.extend(feed(c))
+    if wm is None:
+        return None
+    down, flushed = join.on_watermark(wm)
+    outs.extend(flushed)
+    return down
+
+
 class TwoInputPipeline(FreshnessSurface):
     """Two upstream chains joined by a two-input executor, then a tail.
 
@@ -241,6 +259,12 @@ class TwoInputPipeline(FreshnessSurface):
     barriers (executor/barrier_align.rs) — the host driver is the
     aligner: it feeds each side's chunks in arrival order and calls
     ``barrier`` only when both sides reached it.
+
+    ``head`` is a sub-plan both sides start with over one stream (the
+    planner's shared sub-plan; empty for every other join): it runs
+    once, and what it hands on, its barrier flush included, goes down
+    ``left`` into the join and then down ``right``. Such a pipeline has
+    one input, ``push_both``.
     """
 
     def __init__(
@@ -249,7 +273,9 @@ class TwoInputPipeline(FreshnessSurface):
         right: Sequence[Executor],
         join,
         tail: Sequence[Executor],
+        head: Sequence[Executor] = (),
     ):
+        self.head = list(head)
         self.left = list(left)
         self.right = list(right)
         self.join = join
@@ -266,22 +292,51 @@ class TwoInputPipeline(FreshnessSurface):
     def _through(self, chain, chunks, barrier=None):
         return walk_chain(chain, chunks, barrier)
 
+    def _sides(self):
+        """The join's inputs in the order they are fed."""
+        return (
+            (self.left, self.join.apply_left),
+            (self.right, self.join.apply_right),
+        )
+
+    def _join_side(self, chain, feed, chunks, barrier=None):
+        outs = []
+        for c in self._through(chain, chunks, barrier):
+            outs.extend(_pcall(self.join, "apply", feed, c))
+        return outs
+
+    def _push_side(self, chain, feed, chunk):
+        if self.head:
+            raise ValueError(
+                "both sides of this join start with one shared sub-plan: "
+                "its stream goes in through push_both"
+            )
+        return self._through(
+            self.tail, self._join_side(chain, feed, [chunk])
+        )
+
     def push_left(self, chunk: StreamChunk) -> List[StreamChunk]:
         self._note_ingest()
         if self._fused is not None:
             return self._fused.buffer_left(chunk)
-        outs = []
-        for c in self._through(self.left, [chunk]):
-            outs.extend(_pcall(self.join, "apply", self.join.apply_left, c))
-        return self._through(self.tail, outs)
+        return self._push_side(self.left, self.join.apply_left, chunk)
 
     def push_right(self, chunk: StreamChunk) -> List[StreamChunk]:
         self._note_ingest()
         if self._fused is not None:
             return self._fused.buffer_right(chunk)
+        return self._push_side(self.right, self.join.apply_right, chunk)
+
+    def push_both(self, chunk: StreamChunk) -> List[StreamChunk]:
+        """A chunk of ONE stream that feeds both inputs (a self-join):
+        through the head once, then left before right."""
+        if not self.head:
+            return self.push_left(chunk) + self.push_right(chunk)
+        self._note_ingest()
+        shared = self._through(self.head, [chunk])
         outs = []
-        for c in self._through(self.right, [chunk]):
-            outs.extend(_pcall(self.join, "apply", self.join.apply_right, c))
+        for chain, feed in self._sides():
+            outs.extend(self._join_side(chain, feed, shared))
         return self._through(self.tail, outs)
 
     def barrier(
@@ -304,18 +359,11 @@ class TwoInputPipeline(FreshnessSurface):
                         self._fused, "flush", self._fused.on_barrier, b
                     )
                 else:
+                    shared = self._through(self.head, [], barrier=b)
                     joined: List[StreamChunk] = []
-                    for c in self._through(self.left, [], barrier=b):
+                    for chain, feed in self._sides():
                         joined.extend(
-                            _pcall(
-                                self.join, "apply", self.join.apply_left, c
-                            )
-                        )
-                    for c in self._through(self.right, [], barrier=b):
-                        joined.extend(
-                            _pcall(
-                                self.join, "apply", self.join.apply_right, c
-                            )
+                            self._join_side(chain, feed, shared, barrier=b)
                         )
                     joined.extend(
                         _pcall(self.join, "flush", self.join.on_barrier, b)
@@ -345,23 +393,29 @@ class TwoInputPipeline(FreshnessSurface):
         the tail (the same route a driver-injected one takes)."""
         outs: List[StreamChunk] = []
         aligned: Optional[Watermark] = None
-        for chain, feed in (
-            (self.left, self.join.apply_left),
-            (self.right, self.join.apply_right),
-        ):
+        for i, ex in enumerate(self.head):
+            wm = ex.emit_watermark()
+            if wm is None:
+                continue
+            self._note_watermark(wm.value)
+            wm, pending = _walk_watermark(self.head[i + 1 :], wm)
+            for chain, feed in self._sides():
+                aligned = (
+                    _side_watermark(self.join, chain, feed, wm, pending, outs)
+                    or aligned
+                )
+        for chain, feed in self._sides():
             for i, ex in enumerate(chain):
                 wm = ex.emit_watermark()
                 if wm is None:
                     continue
                 self._note_watermark(wm.value)
-                wm, pending = _walk_watermark(chain[i + 1 :], wm)
-                for c in pending:
-                    outs.extend(feed(c))
-                if wm is not None:
-                    down, flushed = self.join.on_watermark(wm)
-                    outs.extend(flushed)
-                    if down is not None:
-                        aligned = down
+                aligned = (
+                    _side_watermark(
+                        self.join, chain[i + 1 :], feed, wm, (), outs
+                    )
+                    or aligned
+                )
         outs = self._through(self.tail, outs)
         _, tail_outs = _walk_watermark(self.tail, aligned)
         outs.extend(tail_outs)
@@ -389,18 +443,12 @@ class TwoInputPipeline(FreshnessSurface):
             self._fused.flush_data()
         outs: List[StreamChunk] = []
         aligned: Optional[Watermark] = None
-        for side_chain, feed in (
-            (self.left, self.join.apply_left),
-            (self.right, self.join.apply_right),
-        ):
-            wm, pending = _walk_watermark(side_chain, Watermark(column, value))
-            for c in pending:
-                outs.extend(feed(c))
-            if wm is not None:
-                down, flushed = self.join.on_watermark(wm)
-                outs.extend(flushed)
-                if down is not None:
-                    aligned = down
+        wm, pending = _walk_watermark(self.head, Watermark(column, value))
+        for chain, feed in self._sides():
+            aligned = (
+                _side_watermark(self.join, chain, feed, wm, pending, outs)
+                or aligned
+            )
         # data chunks enter the tail BEFORE the aligned watermark closes
         # anything they belong to
         data_outs = self._through(self.tail, outs)
@@ -410,7 +458,7 @@ class TwoInputPipeline(FreshnessSurface):
     @property
     def executors(self) -> List[Executor]:
         """Every executor in the fragment, for checkpoint enumeration."""
-        return self.left + self.right + [self.join] + self.tail
+        return self.head + self.left + self.right + [self.join] + self.tail
 
     @property
     def epoch(self) -> int:

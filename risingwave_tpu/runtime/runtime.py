@@ -639,10 +639,9 @@ class StreamingRuntime:
             # self-join: ONE base stream feeds both join inputs (the
             # Nexmark q7 shape — bid joined against its own per-window
             # max); the reference realizes this as two upstream edges
-            # from the same fragment
-            outs = p.push_left(chunk)
-            outs.extend(p.push_right(chunk))
-            return outs
+            # from the same fragment, or as one into the sub-plan the
+            # two sides share (StreamShare)
+            return p.push_both(chunk)
         return p.push(chunk)
 
     def push(self, name: str, chunk: StreamChunk, side: str = "single"):
@@ -1067,8 +1066,7 @@ class StreamingRuntime:
                     elif side == "right":
                         p.push_right(chunk)
                     elif side == "both":
-                        p.push_left(chunk)
-                        p.push_right(chunk)
+                        p.push_both(chunk)
                     else:
                         p.push(chunk)
                     replayed += 1

@@ -692,4 +692,24 @@ def explain_sql(sql: str, catalog=None) -> str:
         + explain(before)
         + "-- optimized\n"
         + explain(after)
+        + _explain_shared(emit(after), catalog)
+    )
+
+
+def _explain_shared(select: P.Select, catalog) -> str:
+    """The sub-plan a join's two sides share (sql/planner.py plans it
+    once and feeds both from it), shown once with its two readers."""
+    from risingwave_tpu.sql.planner import shared_subplan
+
+    shared = (
+        shared_subplan(select.from_)
+        if isinstance(select.from_, P.Join)
+        else None
+    )
+    if shared is None:
+        return ""
+    return (
+        f"-- shared sub-plan, planned once: read by {shared.left.alias} "
+        f"and {shared.right.alias}\n"
+        + explain(build(shared.select, catalog=catalog))
     )

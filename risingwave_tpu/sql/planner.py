@@ -22,6 +22,7 @@ stream name(s) the driver feeds.
 
 from __future__ import annotations
 
+import dataclasses
 from dataclasses import dataclass
 from typing import Dict, List, Optional, Sequence, Tuple, Union
 
@@ -40,6 +41,7 @@ from risingwave_tpu.executors import (
 from risingwave_tpu.executors.materialize import DeviceMaterializeExecutor
 from risingwave_tpu.executors.row_id_gen import RowIdGenExecutor
 from risingwave_tpu.expr import expr as E
+from risingwave_tpu.metrics import REGISTRY
 from risingwave_tpu.ops.agg import AggCall
 from risingwave_tpu.runtime import Pipeline, TwoInputPipeline
 from risingwave_tpu.sql import parser as P
@@ -466,6 +468,157 @@ def _idents_in(ast):
                 yield from _idents_in(a)
 
 
+# -- shared sub-plans ---------------------------------------------------------
+# The reference's optimizer merges equal sub-plans under a StreamShare
+# node (NEXmark q5 writes its hop count twice). Here: when both sides
+# of a join begin with the same grouped sub-select over the same
+# relation, it is planned once, as the join pipeline's head, and each
+# side continues from its output.
+
+
+def _unqualified(ast):
+    """``ast`` with every column reference's qualifier dropped: inside a
+    select over ONE relation a qualifier can only name that relation."""
+    if isinstance(ast, P.Ident):
+        return P.Ident(ast.name)
+    if isinstance(ast, tuple):
+        return tuple(_unqualified(a) for a in ast)
+    if dataclasses.is_dataclass(ast):
+        return dataclasses.replace(
+            ast,
+            **{
+                f.name: _unqualified(getattr(ast, f.name))
+                for f in dataclasses.fields(ast)
+            },
+        )
+    return ast
+
+
+def _share_key(select: P.Select):
+    """What two sub-selects must agree on to be one sub-plan: the
+    relation, the WHERE, the SET of GROUP BY columns and the aggregate
+    calls by (function, argument). Output aliases, the order of the
+    GROUP BY and which keys the select list names are not in it (each
+    consumer renames). None: not a grouped select of keys and
+    aggregates over one relation, nothing worth planning once."""
+    if (
+        not select.group_by
+        or select.distinct
+        or select.having is not None
+        or select.order_by
+        or select.limit is not None
+        or select.grouping_sets
+    ):
+        return None
+    rel = select.from_
+    if isinstance(rel, (P.TableRef, P.WindowTVF)):
+        rel = dataclasses.replace(rel, alias=None)
+    elif isinstance(rel, P.SubQuery):
+        rel = rel.select  # a nested derived table: equal as written
+    else:
+        return None
+    keys = frozenset(g.name for g in select.group_by)
+    aggs = set()
+    for item in select.items:
+        if _is_agg(item.expr):
+            aggs.add(_unqualified(item.expr))
+        elif not (
+            isinstance(item.expr, P.Ident) and item.expr.name in keys
+        ):
+            return None
+    return rel, _unqualified(select.where), keys, frozenset(aggs)
+
+
+def _agg_outputs(select: P.Select):
+    """[(output column, aggregate call)] as ``_plan_groupby`` names them."""
+    return [
+        (item.alias or f"{item.expr.name}_{i}", _unqualified(item.expr))
+        for i, item in enumerate(select.items)
+        if _is_agg(item.expr)
+    ]
+
+
+@dataclass(frozen=True)
+class SharedSubplan:
+    """A sub-select that occurs on both sides of a join."""
+
+    # planned once: the left occurrence, its key columns unrenamed
+    select: P.Select
+    # the derived tables it stands for, one a side (the nodes themselves)
+    left: P.SubQuery
+    right: P.SubQuery
+    # per consumer, (its column, the head's column): the GROUP BY
+    # columns in the head's order, then its aggregates
+    left_names: Tuple[Tuple[str, str], ...]
+    right_names: Tuple[Tuple[str, str], ...]
+
+
+def shared_subplan(join: P.Join) -> Optional[SharedSubplan]:
+    """The largest sub-select both sides of ``join`` start with: a side
+    as a whole, or a derived table nested in its FROM."""
+
+    def derived(rel):
+        while isinstance(rel, P.SubQuery):
+            yield rel
+            rel = rel.select.from_
+
+    for lo in derived(join.left):
+        key = _share_key(lo.select)
+        if key is None:
+            continue
+        for ro in derived(join.right):
+            if _share_key(ro.select) == key:
+                return _shared(lo, ro)
+    return None
+
+
+def _shared(lo: P.SubQuery, ro: P.SubQuery) -> SharedSubplan:
+    head = dataclasses.replace(
+        lo.select,
+        items=tuple(
+            it if _is_agg(it.expr) else P.SelectItem(it.expr, None)
+            for it in lo.select.items
+        ),
+    )
+    column_of = {call: out for out, call in reversed(_agg_outputs(head))}
+
+    def names(select):
+        alias = {
+            it.expr.name: it.alias
+            for it in select.items
+            if isinstance(it.expr, P.Ident) and it.alias
+        }
+        return tuple(
+            (alias.get(g.name, g.name), g.name) for g in head.group_by
+        ) + tuple(
+            (out, column_of[call]) for out, call in _agg_outputs(select)
+        )
+
+    return SharedSubplan(head, lo, ro, names(lo.select), names(ro.select))
+
+
+@dataclass(frozen=True)
+class _Planned:
+    """A FROM item that is planned already: where a shared sub-plan's
+    occurrence stood, the consumer's view of the head's output."""
+
+    bound: "BoundRel"
+
+
+def _substitute(rel: P.SubQuery, occurrence: P.SubQuery, planned: _Planned):
+    """``rel`` with the derived table ``occurrence`` (found down its
+    FROM nesting) replaced by ``planned``."""
+    if rel is occurrence:
+        return planned
+    inner = rel.select
+    return dataclasses.replace(
+        rel,
+        select=dataclasses.replace(
+            inner, from_=_substitute(inner.from_, occurrence, planned)
+        ),
+    )
+
+
 class StreamPlanner:
     def __init__(self, catalog: Catalog, capacity: int = 1 << 14):
         self.catalog = catalog
@@ -714,6 +867,8 @@ class StreamPlanner:
     def _from_bound(self, name: str, src) -> BoundRel:
         """FROM clause -> BoundRel (source chain + schema, no select
         logic applied yet)."""
+        if isinstance(src, _Planned):
+            return src.bound
         chain: List[Executor] = []
         alias = None
         if isinstance(src, P.SubQuery):
@@ -1910,7 +2065,7 @@ class StreamPlanner:
     def _plan_join_core(
         self, name: str, select: P.Select, aux: List[PlannedMV]
     ) -> PlannedMV:
-        join: P.Join = select.from_
+        head, join = self._plan_shared_head(name, select.from_)
         if isinstance(join.left, P.Join):
             left = self._lower_nested_join(name, join.left, aux)
         else:
@@ -2029,7 +2184,9 @@ class StreamPlanner:
                 table_id=f"{name}.mview",
             )
             tail.append(mview)
-            pipeline = TwoInputPipeline(left.chain, right.chain, hj, tail)
+            pipeline = TwoInputPipeline(
+                left.chain, right.chain, hj, tail, head=head
+            )
             return PlannedMV(
                 name,
                 pipeline,
@@ -2128,7 +2285,9 @@ class StreamPlanner:
                 table_id=f"{name}.mview",
             )
             tail.append(mview)
-            pipeline = TwoInputPipeline(left.chain, right.chain, hj, tail)
+            pipeline = TwoInputPipeline(
+                left.chain, right.chain, hj, tail, head=head
+            )
             return PlannedMV(
                 name,
                 pipeline,
@@ -2165,7 +2324,9 @@ class StreamPlanner:
             table_id=f"{name}.mview",
         )
         tail.append(mview)
-        pipeline = TwoInputPipeline(left.chain, right.chain, hj, tail)
+        pipeline = TwoInputPipeline(
+            left.chain, right.chain, hj, tail, head=head
+        )
         merged = {**left.schema, **right.schema}
         out_schema = {alias or n: merged[n] for n, alias in out_names}
         for p in pk:
@@ -2177,6 +2338,49 @@ class StreamPlanner:
             _join_inputs(left.source, right.source),
             schema=out_schema,
             append_only=out_append_only,
+        )
+
+    def _plan_shared_head(self, name: str, join: P.Join):
+        """Plan the sub-select both sides of ``join`` start with, if
+        there is one, once. Returns (its executors, ``join`` with each
+        occurrence replaced by that side's view of its output): the
+        head's key, append-only flag and source, its columns under the
+        side's own names (a projection in front of the side's rest,
+        where it renames any). No shared sub-select: ([], ``join``)."""
+        shared = shared_subplan(join)
+        head = self._plan_rel(name, shared.select) if shared else None
+        # executors planned once and read twice (0: the sides share none)
+        REGISTRY.counter("plan_shared_subplans_total").inc(
+            len(head.chain) if head else 0, mv=name
+        )
+        if head is None:
+            return [], join
+
+        def view(occurrence, names):
+            chain: List[Executor] = []
+            if any(out != col for out, col in names):
+                chain.append(
+                    ProjectExecutor({out: E.col(col) for out, col in names})
+                )
+            return _Planned(
+                BoundRel(
+                    chain,
+                    {out: head.schema[col] for out, col in names},
+                    tuple(out for out, _ in names[: len(head.pk)]),
+                    head.source,
+                    occurrence.alias,
+                    append_only=head.append_only,
+                )
+            )
+
+        return head.chain, dataclasses.replace(
+            join,
+            left=_substitute(
+                join.left, shared.left, view(shared.left, shared.left_names)
+            ),
+            right=_substitute(
+                join.right, shared.right, view(shared.right, shared.right_names)
+            ),
         )
 
     def _keyed_join(self, jt, lkeys, rkeys, left, right, cond, table_id):
@@ -2218,6 +2422,8 @@ class StreamPlanner:
         return None
 
     def _rel_of(self, name: str, rel) -> BoundRel:
+        if isinstance(rel, _Planned):
+            return rel.bound
         if isinstance(rel, P.SubQuery):
             bound = self._plan_rel(name, rel.select)
             bound.alias = rel.alias

@@ -188,7 +188,17 @@ def test_the_planned_agg_calls_of_q5_q7_and_the_sql_tests_plans():
     assert isinstance(q5.pipeline.join, KeyedJoinExecutor)
     assert q5.pipeline.join.unique_side == "right"
     assert q5.pipeline.join.condition is not None
+    # one stream feeds both inputs, through the hop count the two sides
+    # share: planned once, the head, which ``executors`` (and so
+    # ``_calls``) walks once
     assert q5.inputs == {"bid": "both"}
+    assert [type(ex).__name__ for ex in q5.pipeline.head] == [
+        "HopWindowExecutor", "HashAggExecutor",
+    ]
+    assert [
+        [c.output for c in ex.calls] for ex in q5.pipeline.executors
+        if isinstance(ex, HashAggExecutor)
+    ] == [["num"], ["maxn"]]
     # q7: MAX(price) over the bid table's tumbling window keeps the latch
     # and the bucket join; so does tests/test_sql.py's q5-lite count
     q7 = planner.plan(
@@ -201,6 +211,7 @@ def test_the_planned_agg_calls_of_q5_q7_and_the_sql_tests_plans():
     )
     assert not _calls(q7)["maxprice"].materialized
     assert type(q7.pipeline.join) is HashJoinExecutor
+    assert q7.inputs == {"bid": "both"} and q7.pipeline.head == []
     lite = planner.plan(
         "CREATE MATERIALIZED VIEW l AS SELECT auction, window_start, "
         "count(*) AS num FROM HOP(bid, date_time, INTERVAL '2' SECOND, "
@@ -506,6 +517,10 @@ def test_sum_over_nulls_on_the_many_side_comes_out_null(tmp_path, residual):
             assert got == _want_null_join(seen, residual)
     finally:
         session.close()
+        for p in rt.fragments.values():  # the graph's actor threads
+            close = getattr(p, "close", None)
+            if close is not None:
+                close()
 
 
 def test_keyed_join_keeps_null_lanes_across_checkpoint_and_restore():
