@@ -187,7 +187,7 @@ def _shape_watch_stable():
 
 def _shape_fields(prefix, executors):
     """Steady-state shape evidence for the BENCH JSON: post-warmup
-    recompile-hazard count (perf_gate budget: zero), governor actions,
+    recompile-hazard count (zero in steady state), governor actions,
     and the padding overhead of the bucketed state buffers
     (wasted-lane fraction — the price paid for shape stability)."""
     from risingwave_tpu.analysis.jax_sanitizer import SIGNATURES
@@ -236,9 +236,7 @@ def _freshness_fields(prefix, pipeline):
     """Every BENCH JSON carries ``{q}_freshness``: p50/p99/n per lane
     (commit->visible, source->visible, event-time lag) summarized from
     the pipeline's own per-barrier FreshnessSurface samples — the
-    artifact records how fresh the MV actually was while the bench ran,
-    and perf_gate holds the commit->visible p99 to the SLO budget
-    (``bench_commit_to_visible_p99_ms_max``)."""
+    artifact records how fresh the MV actually was while the bench ran."""
     samples = list(getattr(pipeline, "freshness_samples", ()) or ())
     out = {}
     for lane in (
@@ -304,63 +302,44 @@ def _roofline_fields(prefix, n_barriers, seconds):
 
 
 def _provenance_fields():
-    """git_sha / pr_tag / engine_generation for every artifact —
-    perf_gate warns when ratcheting against an older generation."""
+    """git_sha / pr_tag / engine_generation for every artifact."""
     from risingwave_tpu.provenance import stamp
 
     return stamp()
 
 
 def _profile_begin():
-    """Arm the dispatch-wall profiler for the measured run: every BENCH
-    JSON carries the per-executor decomposition of the dispatch stage
-    (executor_ms + device-wait), dispatches-per-barrier/row, and
-    host<->device transfer counts — the ranked fusion worklist for
-    ROADMAP open item 1. Fencing (per-call block_until_ready — the
-    host/device split) is OFF by default on every backend: it
-    serializes the async dispatch the fused step exists to exploit,
-    re-attributing device compute into the walk and poisoning the
-    ``barrier_stage_ms`` dispatch/device_step split the perf gate
-    ratchets. Force it with RW_BENCH_PROFILE_FENCE=1 when the per-
-    executor device-wait decomposition matters more than honest stage
-    attribution; opt out of profiling entirely with
-    RW_BENCH_PROFILE=0."""
+    """Arm the dispatch/transfer counters for the measured run: every
+    BENCH JSON carries dispatches-per-barrier/row per executor and
+    host<->device transfer counts. Opt out with RW_BENCH_PROFILE=0."""
     import os
 
     if os.environ.get("RW_BENCH_PROFILE", "1") == "0":
         return None
     from risingwave_tpu.profiler import PROFILER
 
-    fence = os.environ.get("RW_BENCH_PROFILE_FENCE") == "1"
     PROFILER.reset()
-    return PROFILER.enable(fence=fence)
+    return PROFILER.enable()
 
 
 def _profile_fields(prefix, prof, n_barriers, rows):
-    """Collect the profiler's surfaces into BENCH-JSON fields, print
-    the operator-readable top-5 dispatch-cost executors, and disarm."""
+    """Collect the counters into BENCH-JSON fields, print the
+    operator-readable top-5 dispatching executors, and disarm."""
     if prof is None:
         return {}
     total = prof.total_dispatches()
-    top = prof.top_executors()
+    counts = prof.dispatch_counts()
     fields = {
-        f"{prefix}_executor_ms": prof.executor_summary(),
-        f"{prefix}_device_dispatches": prof.dispatch_counts(),
+        f"{prefix}_device_dispatches": counts,
         f"{prefix}_dispatches_per_barrier": round(
             total / max(n_barriers, 1), 2
         ),
         f"{prefix}_dispatches_per_row": round(total / max(rows, 1), 6),
         f"{prefix}_transfers": prof.transfer_counts(),
-        f"{prefix}_top_executors": top,
     }
-    print(f"[{prefix}] top dispatch-cost executors:", file=sys.stderr)
-    for d in top:
-        print(
-            f"  {d['executor']:<28} host {d.get('host_ms', 0.0):>9.1f}ms  "
-            f"device-wait {d.get('device_wait_ms', 0.0):>7.1f}ms  "
-            f"dispatches {d.get('dispatches', 0.0):>6.0f}",
-            file=sys.stderr,
-        )
+    print(f"[{prefix}] top dispatching executors:", file=sys.stderr)
+    for ex, n in sorted(counts.items(), key=lambda kv: -kv[1])[:5]:
+        print(f"  {ex:<28} dispatches {n:>6.0f}", file=sys.stderr)
     prof.disable()
     return fields
 
@@ -968,9 +947,9 @@ def bench_q5(args_epochs, events_per_epoch, chunk_events, agg_mode):
         for stacked in epochs_chunks:
             if PROFILER.enabled:
                 # apply_stacked bypasses the chain walk — attribute its
-                # host time to the agg executor explicitly
+                # dispatches to the agg executor explicitly
                 PROFILER.run(
-                    q5.agg, "apply", q5.agg.apply_stacked,
+                    q5.agg, q5.agg.apply_stacked,
                     stacked, pre=pre, mode=agg_mode,
                 )
             else:
@@ -1066,7 +1045,7 @@ TIERS = {
     "smoke_dev": (2, 10_000, 2_048, 240),
 }
 TIER_ORDER = ["smoke_dev", "mid", "full"]  # breadth-first escalation
-PARTIAL_PATH = "BENCH_partial.json"  # scripts/perf_gate.py's default input
+PARTIAL_PATH = "BENCH_partial.json"
 
 
 def _write_artifact(merged: dict) -> None:
@@ -1245,8 +1224,8 @@ def main():
     if args.multichip:
         # the sharded dryrun is self-contained (forces virtual CPU
         # devices + arms MESHPROF internally); the artifact carries
-        # the structured mesh doc so perf_trend can chart per-shard
-        # attribution and skew across rounds. A failing dryrun raises:
+        # the structured mesh doc (per-shard attribution and skew).
+        # A failing dryrun raises:
         # no artifact, non-zero exit.
         import os
 

@@ -27,10 +27,10 @@ provenance — three questions:
    - RW-E804  state not donation-safe for a fused step
    - RW-E805  jaxpr signature count over the bucket lattice exceeds
      the recompile budget
-3. **What is it worth?** With PR 6's measured ``executor_ms`` /
-   ``device_dispatches_total`` attached, blockers rank by measured
-   dispatch cost — the committed FUSION_REPORT.json is the worklist
-   the fusion refactor burns down PR by PR.
+3. **What is it worth?** With measured ``device_dispatches_total``
+   counts attached, blockers rank by the dispatches fusing them would
+   collapse — the committed FUSION_REPORT.json is the worklist the
+   fusion refactor burns down PR by PR.
 
 The same role Shared Arrangements' static dataflow invariants play for
 sharing (PAPERS.md), applied to compilability: the TiLT direction
@@ -335,8 +335,7 @@ class ExecutorClass:
     # for those methods, so their reads exist only on the interpreted
     # fallback path — reported, never a fusibility blocker
     fallback_sync_points: List[SyncPoint] = field(default_factory=list)
-    est_cost_ms: Optional[float] = None  # measured, when profile given
-    est_dispatches: Optional[float] = None  # measured device dispatches
+    est_dispatches: Optional[float] = None  # measured, when counts given
 
     def to_json(self) -> dict:
         return {
@@ -349,7 +348,6 @@ class ExecutorClass:
             "fallback_sync_points": [
                 s.render() for s in self.fallback_sync_points
             ],
-            "est_cost_ms": self.est_cost_ms,
             "est_dispatches": self.est_dispatches,
             "blockers": [
                 {
@@ -453,8 +451,9 @@ def classify_executor(
     # one step" — not a promise the runtime fuses it: fuse_chain may
     # still pick the interpreted/epoch-batched fallback (e.g. an agg
     # feeding an interpreted join), where these reads DO run per
-    # barrier. They stay visible as ``fallback_sync_points`` and
-    # perf_gate ratchets them (must never grow vs the baseline).
+    # barrier. They stay visible as ``fallback_sync_points`` and the
+    # fusion ratchet (scripts/lint_all.py) holds them against the
+    # baseline (must never grow).
     fallback = tuple(contract.get("fallback_syncs", ()))
     ec.sync_points = scan_host_syncs(
         ex,
@@ -644,13 +643,6 @@ class FragmentReport:
     diagnostics: List[Diagnostic] = field(default_factory=list)
 
     def to_json(self) -> dict:
-        # what fusing this fragment reclaims: the measured host-python
-        # ms of every blocked executor (None without profile data)
-        blocked = [
-            e.est_cost_ms
-            for e in self.executors
-            if not e.fusible and e.est_cost_ms is not None
-        ]
         return {
             "fragment": self.fragment,
             "fusible_prefix": self.fusible_prefix,
@@ -659,9 +651,6 @@ class FragmentReport:
             "host_sync_points": self.host_sync_points,
             "fallback_sync_points": sum(
                 len(e.fallback_sync_points) for e in self.executors
-            ),
-            "est_savings_ms": (
-                round(sum(blocked), 3) if blocked else None
             ),
             "executors": [e.to_json() for e in self.executors],
             "blockers": [
@@ -755,55 +744,35 @@ def analyze_planned(planned, deep: bool = False) -> List[FragmentReport]:
 
 
 # ---------------------------------------------------------------------------
-# measured-cost ranking + report assembly
+# measured-dispatch ranking + report assembly
 # ---------------------------------------------------------------------------
 
 
-def _executor_cost_ms(profile: dict, name: str) -> Optional[float]:
-    """Sum of executor_ms across phases for one executor label in a
-    PR 6 profile block ({'executor_ms': {label: {...,'sum': s}}})."""
-    total, seen = 0.0, False
-    for hist in ("executor_ms", "executor_device_wait_ms"):
-        for lbl, row in (profile.get(hist) or {}).items():
-            if f"executor={name}" in lbl and isinstance(row, dict):
-                total += float(row.get("sum", 0.0))
-                seen = True
-    return total if seen else None
-
-
 def attach_costs(
-    reports: Sequence[FragmentReport],
-    profile: Optional[dict],
-    dispatches: Optional[dict] = None,
+    reports: Sequence[FragmentReport], dispatches: Optional[dict]
 ) -> None:
-    """Annotate executor classes with measured dispatch-wall cost
-    (``executor_ms``) and device-dispatch counts
-    (``device_dispatches_total``) from a PR 6 profiler capture —
-    turning the static blocker list into a RANKED worklist (highest
-    measured cost first): fusing a fragment reclaims the summed
-    host-python ms of its blocked executors and collapses their
-    dispatches into one program launch."""
-    if not profile:
+    """Annotate executor classes with measured device-dispatch counts
+    (``device_dispatches_total``, as ``PROFILER.dispatch_counts()``
+    returns them) — turning the static blocker list into a RANKED
+    worklist (most dispatches first): fusing a fragment collapses its
+    blocked executors' dispatches into one program launch."""
+    if not dispatches:
         return
     for rep in reports:
         for ec in rep.executors:
-            ec.est_cost_ms = _executor_cost_ms(profile, ec.name)
-            if dispatches:
-                for lbl, n in dispatches.items():
-                    # the profiler emits bare executor names; labeled
-                    # histograms use executor=NAME
-                    if lbl == ec.name or f"executor={ec.name}" in lbl:
-                        ec.est_dispatches = (
-                            ec.est_dispatches or 0.0
-                        ) + float(n)
+            for lbl, n in dispatches.items():
+                # the profiler emits bare executor names; labeled
+                # counters use executor=NAME
+                if lbl == ec.name or f"executor={ec.name}" in lbl:
+                    ec.est_dispatches = (ec.est_dispatches or 0.0) + float(n)
         rep.diagnostics.sort(
             key=lambda d: -(
                 next(
                     (
-                        e.est_cost_ms
+                        e.est_dispatches
                         for e in rep.executors
                         if d.executor == f"{e.index}:{e.name}"
-                        and e.est_cost_ms is not None
+                        and e.est_dispatches is not None
                     ),
                     0.0,
                 )
@@ -841,7 +810,7 @@ def analyze_nexmark(
 ) -> Dict[str, dict]:
     """Fusion reports for the built-in Nexmark corpus (the committed
     FUSION_REPORT.json shape). ``profile_bench``: a BENCH JSON dict —
-    each query's ``{q}_executor_ms`` block ranks its blockers."""
+    each query's ``{q}_device_dispatches`` block ranks its blockers."""
     from risingwave_tpu.analysis.lint import (
         NEXMARK_SOURCE_SCHEMAS,
         build_nexmark_corpus,
@@ -852,19 +821,16 @@ def analyze_nexmark(
         reports = analyze_pipeline(
             q.pipeline, NEXMARK_SOURCE_SCHEMAS[qname], qname, deep=deep
         )
-        prof, disp = None, None
+        disp = None
         if profile_bench:
-            key = qname
-            if qname == "q5" and f"{qname}_executor_ms" not in (
-                profile_bench or {}
-            ):
-                key = "q5u"  # the unified-path capture covers q5
-            prof = profile_bench.get(f"{key}_executor_ms")
-            disp = profile_bench.get(f"{key}_device_dispatches")
-        attach_costs(reports, prof, disp)
+            disp = profile_bench.get(f"{qname}_device_dispatches")
+            if disp is None and qname == "q5":
+                # the unified-path capture covers q5
+                disp = profile_bench.get("q5u_device_dispatches")
+        attach_costs(reports, disp)
         out[qname] = report_to_json(reports)
     # provenance rides every regenerated FUSION report ("_"-prefixed:
-    # the perf_gate ratchet skips it; the generation check reads it)
+    # the fusion ratchet in scripts/lint_all.py skips it)
     try:
         from risingwave_tpu.provenance import stamp
 

@@ -266,30 +266,12 @@ def fusion_findings_for_ddl(planned) -> List[Diagnostic]:
     return out
 
 
-def _committed_profile() -> Optional[dict]:
-    """The committed BENCH artifact's profiler blocks, when present —
-    ranks fusion blockers by measured dispatch-wall cost."""
-    import json
-    import os
-
-    path = os.path.join(
-        os.path.dirname(os.path.dirname(os.path.dirname(__file__))),
-        "BENCH_partial.json",
-    )
-    try:
-        with open(path) as f:
-            return json.load(f)
-    except (OSError, ValueError):
-        return None
-
-
 def run_fusion_report() -> dict:
     """``lint --fusion-report --all-nexmark``: per-query fusion
-    reports, blockers ranked by the committed profile when one
-    exists."""
+    reports."""
     from risingwave_tpu.analysis.fusion_analyzer import analyze_nexmark
 
-    return analyze_nexmark(deep=True, profile_bench=_committed_profile())
+    return analyze_nexmark(deep=True)
 
 
 # ---------------------------------------------------------------------------
@@ -539,7 +521,7 @@ def run_cli(args) -> int:
                 )
         # the report is an inventory, not a gate: blockers are the
         # expected state until the collective-exchange arc lands —
-        # perf_gate --mesh-static owns the ratchet
+        # scripts/lint_all.py's mesh-static ratchet holds the counts
         return 0
 
     fusion_report = getattr(args, "fusion_report", False)

@@ -55,6 +55,7 @@ COLLECTIVE_PRIMITIVES = frozenset(
         "all_gather",
         "psum",
         "psum2",  # shard_map's check_rep rewrite of psum
+        "psum_invariant",  # the same rewrite's name since jax 0.9
         "pmax",
         "pmin",
         "ppermute",
@@ -62,6 +63,8 @@ COLLECTIVE_PRIMITIVES = frozenset(
         "axis_index",
     }
 )
+# shard_map's rewrites of psum report as psum
+_PSUM_ALIASES = {"psum2": "psum", "psum_invariant": "psum"}
 
 
 class MeshUnavailable(RuntimeError):
@@ -226,7 +229,7 @@ def mesh_trace_signature(step, *abstract_args) -> MeshSignature:
             name = eqn.primitive.name
             prims.append(name)
             if name in COLLECTIVE_PRIMITIVES:
-                colls.append("psum" if name == "psum2" else name)
+                colls.append(_PSUM_ALIASES.get(name, name))
             if name in HOST_PRIMITIVES:
                 hosts.append(name)
             if name in TRANSFER_PRIMITIVES:

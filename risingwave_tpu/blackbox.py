@@ -30,12 +30,11 @@ just the barrier:
   process crash.
 
 Hot-path contract (same as profiler.py): everything is gated on one
-``enabled``/``running`` attribute check; recorder-on overhead is
-budgeted <1% of a steady-state barrier (asserted in
-tests/test_blackbox.py and enforced by ``perf_gate --blackbox``).
+``enabled``/``running`` attribute check; the recorder's per-barrier
+cost is the ``bookkeeping.recorder`` span.
 
 This module must stay importable without touching jax (the reader CLI
-and the perf-gate reader smoke parse segments from plain processes):
+parses segments from plain processes):
 jax is imported lazily inside the default heartbeat / forensics only.
 """
 
@@ -48,6 +47,7 @@ import time
 from collections import deque
 from typing import Callable, Dict, List, Optional
 
+from risingwave_tpu.config import env_float
 from risingwave_tpu.metrics import REGISTRY
 
 __all__ = [
@@ -65,10 +65,6 @@ __all__ = [
 # sentinel device states (also the `device_state` gauge encoding)
 ALIVE, SLOW, WEDGED, UNKNOWN = "ALIVE", "SLOW", "WEDGED", "UNKNOWN"
 _STATE_GAUGE = {ALIVE: 0.0, SLOW: 1.0, WEDGED: 2.0, UNKNOWN: -1.0}
-
-
-# parse-with-fallback env helper shared with the profiler (one copy)
-from risingwave_tpu.profiler import _env_float
 
 
 def _env_int(name: str, default: int) -> int:
@@ -566,7 +562,7 @@ class DeviceSentinel:
     raises the armed DeviceWedged when not. Recovery calls
     ``clear_wedge()`` (treat-like-an-actor-fault contract: recover,
     don't crash) and ``abort_capture()`` closes an in-flight bundle
-    window the way PROFILER.abort_captures closes profile windows."""
+    window."""
 
     def __init__(self):
         self.interval_s = 5.0
@@ -659,9 +655,8 @@ class DeviceSentinel:
         self._wedged = None
 
     def abort_capture(self) -> int:
-        """Close an in-flight wedge-capture window (recovery hygiene,
-        the PROFILER.abort_captures analogue). Returns 1 if a window
-        was open."""
+        """Close an in-flight wedge-capture window (recovery hygiene).
+        Returns 1 if a window was open."""
         with self._lock:
             was = self._capture_open
             self._capture_open = False
@@ -956,8 +951,8 @@ SENTINEL = DeviceSentinel()
 def from_env() -> None:
     """Honor RW_BLACKBOX_* on the process singletons (the operator's
     no-restart escape hatch; env wins over the [blackbox] config
-    section, same precedence as RW_PROFILE/RW_RETRY). No-op when
-    nothing is set — runtimes call this on every construction path."""
+    section, same precedence as RW_RETRY). No-op when nothing is set —
+    runtimes call this on every construction path."""
     raw = os.environ.get("RW_BLACKBOX")
     if raw is not None and raw.strip().lower() in ("0", "off", "false"):
         RECORDER.configure(enabled=False)
@@ -968,7 +963,7 @@ def from_env() -> None:
         RECORDER.configure(
             dir=d,
             ring=_env_int("RW_BLACKBOX_RING", RECORDER.ring.maxlen),
-            fsync_interval_s=_env_float(
+            fsync_interval_s=env_float(
                 "RW_BLACKBOX_FSYNC_S", RECORDER.fsync_interval_s
             ),
             segment_max_bytes=_env_int(
@@ -977,11 +972,11 @@ def from_env() -> None:
         )
     if os.environ.get("RW_BLACKBOX_SENTINEL") == "1" and not SENTINEL.running:
         SENTINEL.start(
-            interval_s=_env_float(
+            interval_s=env_float(
                 "RW_BLACKBOX_HEARTBEAT_S", SENTINEL.interval_s
             ),
-            slow_ms=_env_float("RW_BLACKBOX_SLOW_MS", SENTINEL.slow_ms),
-            deadline_s=_env_float(
+            slow_ms=env_float("RW_BLACKBOX_SLOW_MS", SENTINEL.slow_ms),
+            deadline_s=env_float(
                 "RW_BLACKBOX_DEADLINE_S", SENTINEL.deadline_s
             ),
             dir=d or None,

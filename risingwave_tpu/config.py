@@ -20,6 +20,15 @@ from dataclasses import dataclass, field, fields
 from typing import Any, Dict, Optional
 
 
+def env_float(name: str, default: float) -> float:
+    """An ``RW_*`` float from the environment; ``default`` when unset
+    or unparsable."""
+    try:
+        return float(os.environ.get(name, default))
+    except (TypeError, ValueError):
+        return default
+
+
 @dataclass
 class StreamingConfig:
     """config.rs:546 StreamingConfig (the knobs our runtime consumes)."""
@@ -68,24 +77,6 @@ class ResilienceConfig:
 
 
 @dataclass
-class ProfilerConfig:
-    """Dispatch-wall profiler knobs (profiler.py). ``enabled`` turns on
-    per-executor attribution + dispatch/transfer counting;
-    ``slow_barrier_capture_ms`` auto-emits a PROFILE_* artifact (and a
-    forensic stall dump) when a barrier exceeds it; ``jax_trace`` arms
-    a real ``jax.profiler.trace`` window inside captures (heavy — the
-    JSON artifact is always written regardless). Env knobs
-    (RW_PROFILE, RW_PROFILE_SLOW_MS, RW_PROFILE_DIR,
-    RW_PROFILE_JAX_TRACE, RW_PROFILE_FENCE) win over the file."""
-
-    enabled: bool = False
-    fence: bool = True
-    slow_barrier_capture_ms: float = 0.0  # 0 = no auto-capture
-    capture_dir: str = ""
-    jax_trace: bool = False
-
-
-@dataclass
 class BlackboxConfig:
     """Black-box flight recorder + device-wedge sentinel knobs
     (blackbox.py). The in-memory ring is always on (``enabled``
@@ -115,7 +106,6 @@ class RwConfig:
     storage: StorageConfig = field(default_factory=StorageConfig)
     system: SystemParams = field(default_factory=SystemParams)
     resilience: ResilienceConfig = field(default_factory=ResilienceConfig)
-    profiler: ProfilerConfig = field(default_factory=ProfilerConfig)
     blackbox: BlackboxConfig = field(default_factory=BlackboxConfig)
     unrecognized: Dict[str, Any] = field(default_factory=dict)
 
@@ -139,8 +129,7 @@ def load_config(
         with open(path, "rb") as f:
             data = tomllib.load(f)
         for section in (
-            "streaming", "storage", "system", "resilience", "profiler",
-            "blackbox",
+            "streaming", "storage", "system", "resilience", "blackbox",
         ):
             if section in data:
                 _apply(

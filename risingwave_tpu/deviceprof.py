@@ -33,8 +33,8 @@ three legs:
    ``padding_bytes_frac{fragment}``.
 3. **Fused-stage attribution** (:func:`parse_fused_stages`): the fused
    program's apply / flush / mv_write / scalar_pack phases are wrapped
-   in ``jax.named_scope`` (runtime/fused_step), so a ``jax_trace``
-   capture segments the ONE program; the offline parser aggregates
+   in ``jax.named_scope`` (runtime/fused_step), so a ``jax.profiler``
+   trace segments the ONE program; the offline parser aggregates
    trace events back into ``fused_stage_ms{fragment,stage}`` — the
    68/31-style stage split that ranked the original fusion worklist,
    now measured INSIDE the device program.
@@ -43,8 +43,8 @@ Hot-path contract (profiler.py/blackbox.py discipline): program
 analysis is gated on ONE ``DEVICEPROF.enabled`` check (an analysis is
 one extra AOT compile per distinct program bucket — arm it in bench /
 tests, not in the steady serve path); telemetry recording always rides
-(a dict build + a few gauge sets per barrier, budgeted <1% of a steady
-barrier by ``perf_gate --roofline``). Module import stays jax-free so
+(a dict build + a few gauge sets per barrier, no device dispatch of
+its own). Module import stays jax-free so
 reader CLIs can parse traces from plain processes; jax is imported
 lazily inside the analysis path only.
 """
@@ -126,7 +126,6 @@ class DeviceProfiler:
         self.programs: Dict[tuple, Dict] = {}
         self.fragments: Dict[str, Dict] = {}
         self.telemetry: Dict[str, Dict] = {}
-        self.telemetry_host_ms = 0.0  # cumulative note_telemetry cost
         self.analysis_errors = 0
         # analyses DEFERRED off the dispatch path: ensure_program only
         # enqueues the (abstract) lower thunk; the AOT compile runs at
@@ -155,12 +154,11 @@ class DeviceProfiler:
             self.telemetry.clear()
             self._pending.clear()
             self._dispatched.clear()
-            self.telemetry_host_ms = 0.0
             self.analysis_errors = 0
 
     def from_env(self) -> "DeviceProfiler":
         """RW_DEVICEPROF=1 arms analysis; =0 disarms (env wins in both
-        directions, the RW_PROFILE precedence)."""
+        directions)."""
         raw = os.environ.get("RW_DEVICEPROF")
         if raw is None:
             return self
@@ -172,7 +170,7 @@ class DeviceProfiler:
 
     def on_recovery(self) -> None:
         """Recovery/rebuild hook (runtime calls this next to
-        PROFILER.abort_captures): drop per-barrier telemetry — the
+        SENTINEL.abort_capture): drop per-barrier telemetry — the
         rebuilt fragments' first barrier repopulates it — but KEEP the
         program analyses: recovery re-fuses into the same compiled
         programs (FusedPlan is value-hashable), so the roofline stays
@@ -265,7 +263,6 @@ class DeviceProfiler:
         ``lanes_total``/``rows_in`` (masked-lane fill), and
         ``padding_bytes_frac`` (live-vs-capacity over the members'
         state lanes, weighted by state bytes)."""
-        t0 = time.perf_counter()
         with self._lock:
             self.telemetry[fragment] = tel
             self._dispatched.add(fragment)
@@ -284,7 +281,6 @@ class DeviceProfiler:
             REGISTRY.gauge("padding_bytes_frac").set(
                 tel["padding_bytes_frac"], fragment=fragment
             )
-        self.telemetry_host_ms += (time.perf_counter() - t0) * 1e3
 
     # -- read surfaces ----------------------------------------------------
     def barrier_model(self, consume: bool = False) -> Dict:
@@ -382,7 +378,6 @@ class DeviceProfiler:
             "programs": programs,
             "fragments": fragments,
             "telemetry": telemetry,
-            "telemetry_host_ms": round(self.telemetry_host_ms, 3),
             "analysis_errors": self.analysis_errors,
         }
 
@@ -415,7 +410,6 @@ class DeviceProfiler:
                 "hbm_peak_gbps": peak,
                 "programs": rep["programs"],
                 "telemetry": rep["telemetry"],
-                "telemetry_host_ms": round(self.telemetry_host_ms, 3),
             }
         }
 

@@ -26,9 +26,6 @@ Recording sites (grow as subsystems need them):
 - ``stall_dump``     — epoch_trace.dump_stalls artifacts
 - ``stall_dump_fallback`` — RW_STALL_DIR was unwritable; the dump
                        landed in the system temp dir instead
-- ``profile_capture`` — profiler.py capture window closed (on-demand
-                       or slow-barrier auto-trigger), with the
-                       PROFILE_* artifact path
 - ``breaker``        — resilience.CircuitBreaker state transitions
                        (closed/open/half_open, with the breaker name)
 - ``degraded``       — runtime entered degraded mode: store breaker

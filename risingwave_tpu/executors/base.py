@@ -211,24 +211,14 @@ class Executor:
         so their latch checks still fire per epoch."""
         if self._staged_scalars is None:
             return
-        import time
-
         from risingwave_tpu.ops.hash_table import finish_scalars
-        from risingwave_tpu.profiler import PROFILER
         from risingwave_tpu.trace import span
 
         # the materialization below is the barrier's device fence: the
         # span attributes per-executor device wait to the epoch trace
-        # (and leaves a frame on the live stack for stall dumps); in
-        # profile mode the wait also lands in
-        # executor_device_wait_ms{executor,phase=finish}
-        t0 = time.perf_counter()
+        # (and leaves a frame on the live stack for stall dumps)
         with span("executor.device_step", executor=type(self).__name__):
             vals = finish_scalars(self._staged_scalars)
-        if PROFILER.enabled:
-            PROFILER.record_device_wait(
-                self, (time.perf_counter() - t0) * 1e3
-            )
         self._staged_scalars = None
         self._on_barrier_scalars(vals)
 

@@ -19,9 +19,9 @@ windowed histograms and a latest-row table:
                                low-watermark frontier (event time).
 
 Everything here is host timestamps and dict updates: ZERO added device
-dispatches, and the accumulated host cost is self-measured
-(``host_ms``) so perf_gate --freshness can hold the <1% -of-steady-
-barrier budget the blackbox ring already lives under.
+dispatches (``tests/test_fused_step.py::test_dispatches_per_barrier
+[q5-fused-freshness]``); its host cost is the ``bookkeeping.freshness``
+span.
 
 ``attribute_backpressure`` is the companion verdict: per-fragment
 dispatch walls (EpochTrace.fragment_ms) + per-channel depth and
@@ -50,7 +50,6 @@ class FreshnessTracker:
         self._lock = threading.Lock()
         self._latest: Dict[str, dict] = {}
         self._history: deque = deque(maxlen=self.HISTORY)
-        self.host_ms = 0.0  # self-measured tracking cost (perf_gate)
 
     def observe(
         self,
@@ -61,7 +60,6 @@ class FreshnessTracker:
         source_to_visible_ms: Optional[float] = None,
         event_time_lag_ms: Optional[float] = None,
     ) -> dict:
-        t0 = time.perf_counter()
         row = {
             "mv": mv,
             "epoch": int(epoch),
@@ -91,7 +89,6 @@ class FreshnessTracker:
             row["barriers"] = (prev["barriers"] + 1) if prev else 1
             self._latest[mv] = row
             self._history.append(row)
-        self.host_ms += (time.perf_counter() - t0) * 1e3
         return row
 
     def snapshot(self) -> List[dict]:
@@ -112,7 +109,6 @@ class FreshnessTracker:
         with self._lock:
             self._latest.clear()
             self._history.clear()
-        self.host_ms = 0.0
 
 
 # the process-default tracker (like metrics.REGISTRY / event_log.EVENT_LOG)
@@ -130,7 +126,6 @@ def attribute_backpressure(runtime, trace) -> dict:
     own dispatch dominated the barrier or because work has been sitting
     unconsumed in front of it since an old epoch.
     """
-    t0 = time.perf_counter()
     detail: Dict[str, dict] = {}
     for name, p in getattr(runtime, "fragments", {}).items():
         ent = {
@@ -170,5 +165,4 @@ def attribute_backpressure(runtime, trace) -> dict:
     ms = score(detail[frag]) if frag else 0.0
     if frag is not None:
         REGISTRY.histogram("backpressure_ms").observe(ms, fragment=frag)
-    FRESHNESS.host_ms += (time.perf_counter() - t0) * 1e3
     return {"fragment": frag, "ms": round(ms, 3), "detail": detail}

@@ -43,7 +43,6 @@ from __future__ import annotations
 
 import json
 import os
-import time
 import zlib
 from typing import Dict, Iterable, Optional, Tuple
 
@@ -101,29 +100,18 @@ class StateCorruption(RuntimeError):
 
 
 # ---------------------------------------------------------------------------
-# host-cost accounting (the <1%-of-barrier budget perf_gate asserts)
+# corruption accounting
 # ---------------------------------------------------------------------------
 
-_HOST = {"ms": 0.0, "checks": 0, "corruptions": 0}
-
-
-def host_ms() -> float:
-    """Cumulative host milliseconds spent verifying crcs + folding
-    digests since the last ``reset_host_ms()``."""
-    return _HOST["ms"]
-
-
-def reset_host_ms() -> None:
-    _HOST["ms"] = 0.0
-    _HOST["checks"] = 0
+_COUNTS = {"corruptions": 0}
 
 
 def corruption_count() -> int:
-    return _HOST["corruptions"]
+    return _COUNTS["corruptions"]
 
 
 def note_corruption(exc: "StateCorruption") -> None:
-    _HOST["corruptions"] += 1
+    _COUNTS["corruptions"] += 1
     try:
         from risingwave_tpu.event_log import EVENT_LOG
 
@@ -157,10 +145,7 @@ def verify_crc(
 ) -> None:
     """Verify ``data`` against a build-time crc; raise StateCorruption
     (NOT quarantined here — the caller owns the store handle)."""
-    t0 = time.perf_counter()
     got = crc32_bytes(data)
-    _HOST["ms"] += (time.perf_counter() - t0) * 1e3
-    _HOST["checks"] += 1
     if got != (expected & 0xFFFFFFFF):
         raise StateCorruption(
             artifact, kind, expected=expected, actual=got
@@ -237,10 +222,7 @@ def decode_manifest(raw: bytes, artifact: str = "MANIFEST") -> dict:
     ):
         payload = doc["payload"]
         want = doc.get("crc32")
-        t0 = time.perf_counter()
         got = crc32_bytes(json.dumps(payload, sort_keys=True).encode())
-        _HOST["ms"] += (time.perf_counter() - t0) * 1e3
-        _HOST["checks"] += 1
         if got != want:
             raise StateCorruption(
                 artifact, "manifest-crc", expected=want, actual=got
@@ -290,7 +272,6 @@ def _np_mix(h: np.ndarray, w) -> np.ndarray:
 def host_digest(lanes: Dict[str, np.ndarray], live=None) -> int:
     """The numpy fold: returns the packed ``(sum<<32)|xor`` digest as a
     python int in [0, 2**64). Bit-identical to ``device_digest``."""
-    t0 = time.perf_counter()
     names = sorted(lanes)
     if not names:
         return 0
@@ -306,7 +287,6 @@ def host_digest(lanes: Dict[str, np.ndarray], live=None) -> int:
         h = np.where(np.asarray(live, dtype=bool), h, np.uint32(0))
     s = int(h.astype(np.uint64).sum()) & 0xFFFFFFFF
     x = int(np.bitwise_xor.reduce(h)) if n else 0
-    _HOST["ms"] += (time.perf_counter() - t0) * 1e3
     return (s << 32) | x
 
 
@@ -448,11 +428,9 @@ def host_obj_digest(obj) -> int:
     JSON bytes (sort_keys, default=str). For executors whose state is
     python dicts/scalars rather than device lanes — deterministic, but
     NOT the lane fold (lint's RW-E709 accepts either contract)."""
-    t0 = time.perf_counter()
     blob = json.dumps(obj, sort_keys=True, default=str).encode()
     c = crc32_bytes(blob)
     c2 = crc32_bytes(blob[::-1])
-    _HOST["ms"] += (time.perf_counter() - t0) * 1e3
     return (c << 32) | c2
 
 

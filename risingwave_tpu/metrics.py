@@ -242,22 +242,6 @@ class MetricsRegistry:
                 f"<td>{s['p99']}</td><td>{s['count']}</td></tr>"
                 for lbl, s in sorted(h.summary().items())
             )
-        # dispatch-wall profile (profiler.py): the ranked per-executor
-        # cost table, when the profiler has been armed this process
-        prof_rows = ""
-        if "executor_ms" in self.histograms:
-            try:
-                from risingwave_tpu.profiler import PROFILER
-
-                prof_rows = "".join(
-                    f"<tr><td>{escape(str(d.get('executor', '-')))}</td>"
-                    f"<td>{d.get('host_ms', 0.0)}</td>"
-                    f"<td>{d.get('device_wait_ms', 0.0)}</td>"
-                    f"<td>{d.get('dispatches', 0.0):g}</td></tr>"
-                    for d in PROFILER.top_executors(10)
-                )
-            except Exception:
-                prof_rows = ""
         # black box + device sentinel (blackbox.py): the device-health
         # classification and flight-recorder state — the first look
         # when a barrier stalls or the device goes quiet
@@ -408,7 +392,6 @@ class MetricsRegistry:
                     ("grow vetoes", snap["vetoes"]),
                     ("spills", snap["spills"]),
                     ("parked polls", adm["parked_polls"]),
-                    ("governor host ms", snap["host_ms"]),
                 ):
                     mem_rows += (
                         f"<tr><td>{escape(str(k))}</td>"
@@ -459,7 +442,6 @@ class MetricsRegistry:
                         "last skew verdict",
                         lb.get("skew") or "-",
                     ),
-                    ("mesh host ms", msnap.get("host_ms", 0.0)),
                     (
                         "calibration ms",
                         msnap.get("calibration_ms", 0.0),
@@ -497,7 +479,6 @@ td,th{{border:1px solid #999;padding:2px 8px}}h2{{margin-top:1.5em}}</style></he
 <h2>fragments &rarr; subscribers</h2><table>{frag_rows or '<tr><td>none</td></tr>'}</table>
 <h2>device state (top 40)</h2><table><tr><th>executor</th><th>table</th><th>bytes</th></tr>{state_rows}</table>
 <h2>barrier stages (ms)</h2><table><tr><th>stage</th><th>p50</th><th>p99</th><th>n</th></tr>{stage_rows or '<tr><td>no barriers traced</td></tr>'}</table>
-<h2>dispatch profile (top executors)</h2><table><tr><th>executor</th><th>host ms</th><th>device-wait ms</th><th>dispatches</th></tr>{prof_rows or '<tr><td>profiler not armed (RW_PROFILE=1)</td></tr>'}</table>
 <h2>black box &amp; device sentinel</h2><table>{bb_rows or '<tr><td>blackbox unavailable</td></tr>'}</table>
 <h2>device roofline (compiled programs)</h2><table><tr><th>program|bucket</th><th>compile ms</th><th>bytes accessed</th><th>flops</th><th>temp bytes</th></tr>{dp_rows or '<tr><td>deviceprof not armed (RW_DEVICEPROF=1)</td></tr>'}</table>
 <h2>fused telemetry (last barrier)</h2><table><tr><th>fragment</th><th>rows in</th><th>dirty groups</th><th>mv rows</th><th>lane fill</th><th>padding frac</th></tr>{tel_rows or '<tr><td>no fused barriers yet</td></tr>'}</table>

@@ -235,7 +235,6 @@ class MeshProfiler:
         self._lock = threading.RLock()
         self.enabled = False
         self.probes_enabled = True
-        self.host_ms = 0.0  # steady-path self-measured bookkeeping
         self.calibration_ms = 0.0  # one-time probe compiles/timing
         self.errors = 0
         self.barrier_count = 0
@@ -272,7 +271,6 @@ class MeshProfiler:
     def reset_stats(self) -> None:
         """Zero the meters (gates measure deltas across a run)."""
         with self._lock:
-            self.host_ms = 0.0
             self.calibration_ms = 0.0
             self.errors = 0
             self.barrier_count = 0
@@ -350,7 +348,6 @@ class MeshProfiler:
                 prof._record(info, t0, t1, chunk, arrival)
             except Exception:
                 prof.errors += 1
-            prof.host_ms += (time.perf_counter() - t1) * 1e3
             return ret
 
         setattr(ex, method, wrapped)
@@ -405,7 +402,6 @@ class MeshProfiler:
         idle). Never faults the barrier."""
         if not self.enabled:
             return None
-        t0 = time.perf_counter()
         with self._lock:
             picked = [
                 self._window.pop(k)
@@ -431,7 +427,6 @@ class MeshProfiler:
                 self.barriers.append(doc)
                 self._pending.append(doc)
         self.calibration_ms += cal_ms
-        self.host_ms += (time.perf_counter() - t0) * 1e3 - cal_ms
         return doc
 
     def _calibrate(self, entry: dict) -> float:
@@ -675,10 +670,9 @@ class MeshProfiler:
         sharded pipeline that closed since the last trace) into ONE
         ``tr.mesh`` block + ``barrier_stage_ms`` mesh/per-shard stages.
         Mirrors MemoryGovernor.observe_barrier: enabled-gated,
-        exception-proof, self-timed."""
+        exception-proof."""
         if not self.enabled:
             return
-        t0 = time.perf_counter()
         try:
             with self._lock:
                 pend = list(self._pending)
@@ -694,8 +688,6 @@ class MeshProfiler:
                     tr.add_stage("shard_local", ms, fragment=f"shard{i}")
         except Exception:
             self.errors += 1
-        finally:
-            self.host_ms += (time.perf_counter() - t0) * 1e3
 
     @staticmethod
     def fold(docs: List[dict]) -> dict:
@@ -808,7 +800,6 @@ class MeshProfiler:
                 "exchange": ex,
                 "last_barrier": last,
                 "barriers": self.barrier_count,
-                "host_ms": round(self.host_ms, 3),
                 "calibration_ms": round(self.calibration_ms, 3),
                 "errors": self.errors,
             }

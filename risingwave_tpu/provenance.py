@@ -13,12 +13,11 @@ profile artifact now carries three fields:
   (RW_PR_TAG, default ``genN``);
 - ``engine_generation`` — a MONOTONIC integer bumped whenever a PR
   changes what the numbers MEAN (dispatch model, shape layer, byte
-  accounting). ``perf_gate`` warns when it ratchets against an
-  artifact from an older generation — stale-artifact confusion becomes
-  mechanically detectable instead of a forensic exercise.
+  accounting). Every artifact carries it, so one written by an older
+  generation is mechanically detectable instead of a forensic exercise.
 
-No jax import, ever: the pure-JSON perf_gate mode and the blackbox
-reader CLI stamp/compare provenance from plain processes.
+No jax import, ever: the blackbox reader CLI stamps provenance from
+plain processes.
 """
 
 from __future__ import annotations

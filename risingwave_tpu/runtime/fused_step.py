@@ -1485,23 +1485,16 @@ class FusedTwoInputExecutor(Executor):
             return
         if not force and (self._barriers % self.depth) != 0:
             return
-        import time
-
         from risingwave_tpu.ops.hash_table import finish_scalars
 
         pending, self._pending = self._pending, []
         retired, self._retired = self._retired, []
         try:
             for i, packed in enumerate(pending):
-                t0 = time.perf_counter()
                 with span(
                     "executor.device_step", executor=type(self).__name__
                 ):
                     vals = finish_scalars(packed)
-                if PROFILER.enabled:
-                    PROFILER.record_device_wait(
-                        self, (time.perf_counter() - t0) * 1e3
-                    )
                 # member scalars decode from the LAST pack only: the
                 # latch lanes are monotonic and CARRIED through the
                 # chained programs (each barrier's latches_in are the

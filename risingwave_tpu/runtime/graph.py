@@ -47,7 +47,6 @@ from risingwave_tpu.epoch_trace import StageSums
 from risingwave_tpu.executors.base import Barrier, Epoch, Executor, Watermark
 from risingwave_tpu.ops.hashing import VNODE_COUNT, hash_columns
 from risingwave_tpu.metrics import REGISTRY
-from risingwave_tpu.profiler import PROFILER
 from risingwave_tpu.runtime.pipeline import (
     _pcall,
     _side_watermark,
@@ -464,7 +463,7 @@ class FragmentActor(threading.Thread):
             fragment=self.actor_name,
             actor=self.actor_name,
             **({} if self.join_exec is None else {"shared": self.shared}),
-        ), PROFILER.barrier_window(fragment=self.actor_name):
+        ):
             self._process_barrier_inner(b)
             # flush + emit happened above; finish_barrier below is the
             # barrier-only device fence (staged-scalar materialization);
@@ -1337,13 +1336,8 @@ class GraphRuntime:
         collected it. ``epoch`` pins the barrier's curr epoch (a
         runtime passes its own clock so the graph's epochs line up with
         checkpoint manifests)."""
-        t0 = time.perf_counter()
         b = self.inject_barrier_nowait(checkpoint=checkpoint, epoch=epoch)
         self.wait_barrier(b.epoch.curr, timeout=timeout)
-        if PROFILER.enabled:
-            # slow-barrier auto-capture for graph-only drivers (the
-            # StreamingRuntime hooks its own barrier clock separately)
-            PROFILER.observe_barrier((time.perf_counter() - t0) * 1e3)
         return b
 
     def stop(self, timeout: float = 30.0) -> None:

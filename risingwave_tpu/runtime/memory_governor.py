@@ -39,9 +39,8 @@ exchange (permits.rs), rebuilt for the host-pumped TPU model:
 
 The governor rides ``StreamingRuntime._end_trace`` (both the serial
 and the pipelined closer path), is dormant unless armed (a budget via
-env/ctor, or ``RW_OVERLOAD_LADDER=1``), self-measures its host cost
-(``host_ms`` — the same <1% budget class as freshness tracking and
-the blackbox ring) and never faults a barrier.
+env/ctor, or ``RW_OVERLOAD_LADDER=1``), runs inside the
+``bookkeeping.memory_governor`` span and never faults a barrier.
 """
 
 from __future__ import annotations
@@ -49,6 +48,8 @@ from __future__ import annotations
 import os
 import time
 from typing import Dict, List, Optional
+
+from risingwave_tpu.config import env_float
 
 __all__ = [
     "NORMAL",
@@ -75,13 +76,6 @@ _BASE_CREDIT = {
     SHEDDING: 0.25,
     DEGRADED: 0.0,  # parked at the anchored offsets
 }
-
-
-def _env_float(name: str, default: float) -> float:
-    try:
-        return float(os.environ.get(name, default))
-    except ValueError:
-        return default
 
 
 def _env_int(name: str, default: int) -> int:
@@ -117,7 +111,8 @@ class OverloadLadder:
     ``exit_margin``) — the sticky cool-down that keeps a boundary-
     riding load from flapping the ladder. ``flaps`` counts
     re-escalations that land within ``cooldown`` barriers of a
-    de-escalation (the throttle-flap budget perf_gate holds)."""
+    de-escalation (bounded by ``tests/test_overload.py``'s chaos
+    storm)."""
 
     def __init__(
         self,
@@ -130,17 +125,17 @@ class OverloadLadder:
         self.throttle_at = (
             throttle_at
             if throttle_at is not None
-            else _env_float("RW_OVERLOAD_THROTTLE_AT", 0.75)
+            else env_float("RW_OVERLOAD_THROTTLE_AT", 0.75)
         )
         self.shed_at = (
             shed_at
             if shed_at is not None
-            else _env_float("RW_OVERLOAD_SHED_AT", 0.90)
+            else env_float("RW_OVERLOAD_SHED_AT", 0.90)
         )
         self.degrade_at = (
             degrade_at
             if degrade_at is not None
-            else _env_float("RW_OVERLOAD_DEGRADE_AT", 0.98)
+            else env_float("RW_OVERLOAD_DEGRADE_AT", 0.98)
         )
         self.cooldown = (
             cooldown
@@ -334,7 +329,7 @@ class MemoryGovernor:
             limit = (st or {}).get("bytes_limit")
             if limit:
                 budget_bytes = int(
-                    _env_float("RW_HBM_BUDGET_FRAC", 0.8) * limit
+                    env_float("RW_HBM_BUDGET_FRAC", 0.8) * limit
                 )
         self.budget_bytes = budget_bytes
         self.enabled = budget_bytes is not None or os.environ.get(
@@ -342,9 +337,9 @@ class MemoryGovernor:
         ).strip().lower() in ("1", "on", "true")
         # spill watermark: relieve (cold-tier spill) above this budget
         # fraction, BEFORE the hard veto wall at 1.0
-        self.spill_at = _env_float("RW_HBM_SPILL_AT", 0.85)
+        self.spill_at = env_float("RW_HBM_SPILL_AT", 0.85)
         # queue-age budget for the pressure score's second component
-        self.queue_ms_budget = _env_float("RW_OVERLOAD_QUEUE_MS", 2000.0)
+        self.queue_ms_budget = env_float("RW_OVERLOAD_QUEUE_MS", 2000.0)
         self.sample_every = max(1, _env_int("RW_HBM_SAMPLE_EVERY", 16))
         self.ladder = OverloadLadder()
         self.admission = AdmissionController()
@@ -363,7 +358,6 @@ class MemoryGovernor:
         self._barriers = 0
         self.vetoes = 0
         self.spills = 0
-        self.host_ms = 0.0
         self._relief_wanted = False
         self._gated: set = set()
         # DEGRADED bookkeeping: original fused depths + whether WE
@@ -377,13 +371,10 @@ class MemoryGovernor:
     def observe_barrier(self, runtime, tr=None) -> None:
         if not self.enabled:
             return
-        t0 = time.perf_counter()
         try:
             self._observe(runtime, tr)
         except Exception:  # noqa: BLE001 — governance never faults a barrier
             pass
-        finally:
-            self.host_ms += (time.perf_counter() - t0) * 1e3
 
     def _observe(self, runtime, tr) -> None:
         self._barriers += 1
@@ -721,7 +712,6 @@ class MemoryGovernor:
             ),
             "vetoes": self.vetoes,
             "spills": self.spills,
-            "host_ms": round(self.host_ms, 4),
             "barriers": self._barriers,
             "ladder": self.ladder.snapshot(),
             "admission": self.admission.snapshot(),

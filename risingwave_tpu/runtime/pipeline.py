@@ -95,8 +95,8 @@ def walk_chain(chain: Sequence[Executor], chunks, barrier=None, tap=None):
     ``tap`` sees every chunk an executor hands on (an operator edge)."""
     pending = list(chunks)
     # recompile-hazard fingerprinting (analysis/jax_sanitizer) and the
-    # dispatch-wall profiler: one attribute check each when disarmed —
-    # the hot path stays flat
+    # dispatch counters: one attribute check each when disarmed — the
+    # hot path stays flat
     watch = SIGNATURES if SIGNATURES.enabled else None
     prof = PROFILER if PROFILER.enabled else None
     for ex in chain:
@@ -107,12 +107,12 @@ def walk_chain(chain: Sequence[Executor], chunks, barrier=None, tap=None):
             if prof is None:
                 nxt.extend(ex.apply(c))
             else:
-                nxt.extend(prof.run(ex, "apply", ex.apply, c))
+                nxt.extend(prof.run(ex, ex.apply, c))
         if barrier is not None:
             if prof is None:
                 nxt.extend(ex.on_barrier(barrier))
             else:
-                nxt.extend(prof.run(ex, "flush", ex.on_barrier, barrier))
+                nxt.extend(prof.run(ex, ex.on_barrier, barrier))
         if tap is not None:
             for c in nxt:
                 tap(c)
@@ -121,15 +121,16 @@ def walk_chain(chain: Sequence[Executor], chunks, barrier=None, tap=None):
 
 
 def _pcall(ex, phase, fn, *args):
-    """Profiler-gated call for executor entry points OUTSIDE walk_chain
-    (join apply_left/right, on_barrier in two-input shapes) — also the
+    """Dispatch-attributed call for executor entry points OUTSIDE
+    walk_chain (join apply_left/right, on_barrier in two-input shapes)
+    — also the
     recompile-hazard fingerprint tap for those paths: serial AND
     graph-mode join executors feed SignatureWatch here, so two-input
     shapes get the same shape-stability coverage as chain executors."""
     if SIGNATURES.enabled and phase == "apply" and args:
         SIGNATURES.observe(ex, args[0])
     if PROFILER.enabled:
-        return PROFILER.run(ex, phase, fn, *args)
+        return PROFILER.run(ex, fn, *args)
     return fn(*args)
 
 
@@ -165,30 +166,29 @@ class Pipeline(FreshnessSurface):
         # stage attribution (EpochTrace lifecycle): the walk is host
         # dispatch; the scalar materialization is the barrier-only
         # device fence
-        with PROFILER.barrier_window():
-            with _walk_span() as walk:
-                pending = walk_chain(self.executors, [], barrier=b)
-                # executor-GENERATED watermarks (watermark_filter.rs)
-                # walk the rest of the chain after the barrier flushes
-                for i, ex in enumerate(self.executors):
-                    wm = ex.emit_watermark()
-                    if wm is not None:
-                        self._note_watermark(wm.value)
-                        _, outs = _walk_watermark(
-                            self.executors[i + 1 :], wm
-                        )
-                        pending.extend(outs)
-            # materialize every executor's staged barrier scalars AFTER
-            # the walk: the async transfers overlapped, so the chain
-            # pays ~one round-trip; raises still precede the runtime's
-            # epoch commit. transfer_guard: when armed
-            # (RW_TRANSFER_GUARD, tests) any IMPLICIT host<->device
-            # transfer here raises at the offender
-            with span(
-                "pipeline.fence", stage="dispatch.fence"
-            ) as fence, transfer_guard():
-                for ex in self.executors:
-                    ex.finish_barrier()
+        with _walk_span() as walk:
+            pending = walk_chain(self.executors, [], barrier=b)
+            # executor-GENERATED watermarks (watermark_filter.rs)
+            # walk the rest of the chain after the barrier flushes
+            for i, ex in enumerate(self.executors):
+                wm = ex.emit_watermark()
+                if wm is not None:
+                    self._note_watermark(wm.value)
+                    _, outs = _walk_watermark(
+                        self.executors[i + 1 :], wm
+                    )
+                    pending.extend(outs)
+        # materialize every executor's staged barrier scalars AFTER
+        # the walk: the async transfers overlapped, so the chain
+        # pays ~one round-trip; raises still precede the runtime's
+        # epoch commit. transfer_guard: when armed
+        # (RW_TRANSFER_GUARD, tests) any IMPLICIT host<->device
+        # transfer here raises at the offender
+        with span(
+            "pipeline.fence", stage="dispatch.fence"
+        ) as fence, transfer_guard():
+            for ex in self.executors:
+                ex.finish_barrier()
         walk_ms, fence_ms = walk.dur * 1e3, fence.dur * 1e3
         self._sample_freshness(walk_ms + fence_ms)
         # standalone pipelines (bench drivers, tests) feed the black
@@ -349,35 +349,34 @@ class TwoInputPipeline(FreshnessSurface):
             else max(int(time.time() * 1000) << 16, prev + 1)
         )
         b = Barrier(Epoch(prev, self._epoch), checkpoint)
-        with PROFILER.barrier_window():
-            with _walk_span() as walk:
-                if self._fused is not None:
-                    # ONE donated device program for the whole fragment
-                    # barrier; finish defers to the K-boundary under
-                    # RW_FUSED_PIPELINE_DEPTH (the wrapper decides)
-                    outs = _pcall(
-                        self._fused, "flush", self._fused.on_barrier, b
-                    )
-                else:
-                    shared = self._through(self.head, [], barrier=b)
-                    joined: List[StreamChunk] = []
-                    for chain, feed in self._sides():
-                        joined.extend(
-                            self._join_side(chain, feed, shared, barrier=b)
-                        )
+        with _walk_span() as walk:
+            if self._fused is not None:
+                # ONE donated device program for the whole fragment
+                # barrier; finish defers to the K-boundary under
+                # RW_FUSED_PIPELINE_DEPTH (the wrapper decides)
+                outs = _pcall(
+                    self._fused, "flush", self._fused.on_barrier, b
+                )
+            else:
+                shared = self._through(self.head, [], barrier=b)
+                joined: List[StreamChunk] = []
+                for chain, feed in self._sides():
                     joined.extend(
-                        _pcall(self.join, "flush", self.join.on_barrier, b)
+                        self._join_side(chain, feed, shared, barrier=b)
                     )
-                    outs = self._through(self.tail, joined, barrier=b)
-                outs.extend(self._generated_watermarks())
-            with span(
-                "pipeline.fence", stage="dispatch.fence"
-            ) as fence, transfer_guard():
-                if self._fused is not None:
-                    self._fused.finish_barrier()
-                else:
-                    for ex in self.executors:
-                        ex.finish_barrier()
+                joined.extend(
+                    _pcall(self.join, "flush", self.join.on_barrier, b)
+                )
+                outs = self._through(self.tail, joined, barrier=b)
+            outs.extend(self._generated_watermarks())
+        with span(
+            "pipeline.fence", stage="dispatch.fence"
+        ) as fence, transfer_guard():
+            if self._fused is not None:
+                self._fused.finish_barrier()
+            else:
+                for ex in self.executors:
+                    ex.finish_barrier()
         walk_ms, fence_ms = walk.dur * 1e3, fence.dur * 1e3
         self._sample_freshness(walk_ms + fence_ms)
         RECORDER.record_pipeline_barrier(self._epoch, walk_ms, fence_ms)

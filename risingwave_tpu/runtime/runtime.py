@@ -59,7 +59,6 @@ from risingwave_tpu.resilience import (
     RetryingObjectStore,
     RetryPolicy,
 )
-from risingwave_tpu.profiler import PROFILER
 from risingwave_tpu.trace import TRACER, bind, close_epoch, span
 from risingwave_tpu.storage.object_store import ObjectStore
 from risingwave_tpu.storage.state_table import CheckpointManager
@@ -101,11 +100,6 @@ class StreamingRuntime:
                 failure_threshold=res.breaker_threshold,
                 cooldown_s=res.breaker_cooldown_s,
             )
-        prof = getattr(cfg, "profiler", None)
-        if prof is not None:
-            # [profiler] section arms the dispatch-wall profiler for
-            # the process (env RW_PROFILE_* wins inside configure)
-            PROFILER.configure(prof)
         bb = getattr(cfg, "blackbox", None)
         if bb is not None:
             # [blackbox] section arms the flight recorder's segment
@@ -141,11 +135,9 @@ class StreamingRuntime:
         # epoch, roll source offsets back so the pump replays
         self.auto_recover = auto_recover
         self.auto_recoveries = 0
-        # RW_PROFILE env arming must work on EVERY construction path
+        # RW_BLACKBOX_* env arming must work on EVERY construction path
         # (serve without --config, compute_node, direct construction),
-        # not only from_config; a no-op when the env var is unset
-        PROFILER.from_env()
-        # same contract for the black box (RW_BLACKBOX_*)
+        # not only from_config; a no-op when the env vars are unset
         blackbox.from_env()
         # recompile-storm governor (runtime/bucketing.py): per-barrier
         # SignatureWatch hazard deltas vs RW_FUSION_RECOMPILE_BUDGET;
@@ -774,10 +766,6 @@ class StreamingRuntime:
         self.last_failure = cause
         REGISTRY.counter("auto_recoveries_total").inc()
         self.auto_recoveries += 1
-        # close any open profiler capture window FIRST: an orphaned
-        # jax.profiler session surviving a recovery would hold the
-        # device and poison the next capture (watchdog-orphan audit)
-        PROFILER.abort_captures()
         # deviceprof re-arms across the rebuild: stale per-barrier
         # telemetry drops, program analyses survive (the rebuilt
         # fragments re-fuse into the SAME compiled programs), and no
@@ -1296,10 +1284,6 @@ class StreamingRuntime:
         self.barrier_latencies_ms.append(ms)
         REGISTRY.histogram("barrier_latency_ms").observe(ms)
         REGISTRY.counter("barriers_total").inc()
-        if PROFILER.enabled:
-            # slow-barrier auto-capture: a barrier over the profile
-            # threshold leaves a PROFILE_* artifact + forensic dump
-            PROFILER.observe_barrier(ms, runtime=self)
         return outs
 
     def _barrier_walk(
@@ -1327,10 +1311,9 @@ class StreamingRuntime:
                 with span(
                     "barrier.fragment", stage="dispatch", fragment=name
                 ):
-                    with PROFILER.barrier_window(fragment=name):
-                        outs[name] = p.barrier(
-                            checkpoint=is_ckpt, epoch=self._epoch
-                        )
+                    outs[name] = p.barrier(
+                        checkpoint=is_ckpt, epoch=self._epoch
+                    )
                     with span("barrier.route", fragment=name):
                         self._route(name, outs[name])
                     # replay-buffer epoch fence: everything recorded
@@ -1868,7 +1851,6 @@ class StreamingRuntime:
         if not self.mgr:
             raise RuntimeError("no object store configured")
         # manual recovery mirrors the auto path's capture hygiene
-        PROFILER.abort_captures()
         blackbox.SENTINEL.abort_capture()
         blackbox.SENTINEL.clear_wedge()
         from risingwave_tpu.deviceprof import DEVICEPROF

@@ -1,6 +1,6 @@
 #!/usr/bin/env python
-"""The one-liner CI gate: host-language lint + rwlint over every
-built-in query.
+"""The one-liner CI gate: ruff + rwlint over every built-in query +
+the static ratchets.
 
     python scripts/lint_all.py
 
@@ -19,32 +19,22 @@ Stages (all must pass; exit code is the OR of their failures):
    readiness analyzer over the sharded q5/q7/q8 corpus (fresh
    subprocess owning the 8-virtual-device sim mesh): per-fragment
    SPMD-fusibility proofs + RW-E9xx blockers with provenance.
-4. ``python scripts/perf_gate.py --smoke --blackbox --roofline
-   --serving --freshness --overload --mesh --fusion
-   --mesh-static`` — the
-   dispatch-cost regression gate: committed BENCH artifacts vs
-   scripts/perf_budgets.json, the CPU q5 steady-state microbench
-   (bounded device dispatches/barrier + host-python ms/row), the
-   black-box recorder gate (host ms/barrier + fsync-stall budgets, and
-   the write-ring -> SIGKILL -> reader-CLI crash-survival smoke), the
-   shared-arrangement serving gate (CI-scale registration storm with
-   O(families) compile count + concurrent pgwire readers under
-   budget), the overload-protection gate (seeded chaos storm against
-   the memory-governed runtime: zero OOM/wedge, twin bit-identity,
-   bounded flaps + recovery, governor overhead < 1%), the mesh-
-   observability gate (8-virtual-device child: per-shard attribution
-   covers >=90% of the sharded q5/q8 barrier wall, armed-vs-unarmed
-   bit-identity, seeded hot-shard skew verdict names the right shard,
-   mesh telemetry host overhead < 1%), the fusion ratchet vs
-   FUSION_REPORT.json (fusible prefixes must not shrink, host-sync
-   counts must not grow), and the mesh-static ratchet vs
-   MESH_REPORT.json (host-routed exchange edges and per-code E9xx
-   blocker counts must not grow, SPMD proofs must not shrink).
+4. The two static ratchets, in-process, against the reports stages
+   3/3b just wrote: the fusion ratchet vs FUSION_REPORT.json (fusible
+   prefixes must not shrink, host-sync and fallback-sync counts must
+   not grow, per-code blocker counts must not grow, whole-chain proofs
+   must not be lost) and the mesh-static ratchet vs MESH_REPORT.json
+   (host-routed exchange edges and per-code E9xx blocker counts must
+   not grow, SPMD proofs must not shrink).
+
+No stage holds a clock: how fast the system runs is the driver's
+measurement on the chip (``PERF_LEDGER.jsonl``).
 """
 
 from __future__ import annotations
 
 import ast
+import json
 import os
 import py_compile
 import shutil
@@ -158,9 +148,9 @@ def stage_rwlint() -> int:
 
 
 def stage_fusion_report(out_path: str) -> int:
-    """Produce the fusion analysis ONCE (JSON to ``out_path``); stage
-    4's perf_gate consumes it via --fusion-current instead of paying
-    for a second corpus build + jaxpr trace."""
+    """Produce the fusion analysis ONCE (JSON to ``out_path``); the
+    fusion ratchet reads it instead of paying for a second corpus
+    build + jaxpr trace."""
     print("[lint_all] rwlint --fusion-report (fusion feasibility)")
     env = dict(os.environ, JAX_PLATFORMS="cpu")
     try:
@@ -177,8 +167,6 @@ def stage_fusion_report(out_path: str) -> int:
         return 1
     if rc == 0:
         try:
-            import json
-
             with open(out_path) as f:
                 fus = json.load(f).get("__fusion__", {})
             for q in sorted(fus):
@@ -201,8 +189,7 @@ def stage_mesh_report(out_path: str) -> int:
     """Produce the mesh-readiness analysis ONCE (JSON to ``out_path``)
     in a fresh subprocess — ``lint --mesh-report`` claims its own
     8-virtual-device mesh, which cannot be conjured in a process that
-    already initialized jax. Stage 4's perf_gate consumes it via
-    --mesh-current (the --mesh-static ratchet vs MESH_REPORT.json)."""
+    already initialized jax. The mesh-static ratchet reads it."""
     print("[lint_all] rwlint --mesh-report (SPMD mesh readiness)")
     env = dict(os.environ, JAX_PLATFORMS="cpu")
     env.pop("XLA_FLAGS", None)  # the child claims its own mesh
@@ -220,8 +207,6 @@ def stage_mesh_report(out_path: str) -> int:
         return 1
     if rc == 0:
         try:
-            import json
-
             with open(out_path) as f:
                 rep = json.load(f)
             for q in sorted(rep):
@@ -245,28 +230,282 @@ def stage_mesh_report(out_path: str) -> int:
     return rc
 
 
-def stage_perf_gate(
+# ---------------------------------------------------------------------------
+# the two static ratchets (no clock: counts against committed reports)
+# ---------------------------------------------------------------------------
+
+DEFAULT_FUSION_BASELINE = os.path.join(ROOT, "FUSION_REPORT.json")
+DEFAULT_MESH_BASELINE = os.path.join(ROOT, "MESH_REPORT.json")
+# absolute ceilings on top of the no-regression compare: q5/q7/q8 are
+# pinned at ZERO host syncs (every fragment whole_chain_fusible), and
+# the shape-stability wedge class (RW-E803/E806) at ZERO for the corpus
+MAX_HOST_SYNC_POINTS = {"q5": 0, "q7": 0, "q8": 0}
+MAX_BLOCKER_CODES = {"RW-E803": 0, "RW-E806": 0}
+
+
+def _load(path: str):
+    with open(path) as f:
+        return json.load(f)
+
+
+def run_fusion_gate(baseline_path: str = None, current_path: str = None):
+    """Re-run the fusion analyzer over the Nexmark corpus and compare
+    against the committed FUSION_REPORT.json baseline: per fragment,
+    the fusible executor prefix must not SHRINK and the host-sync
+    count must not GROW (plus the absolute per-query
+    ``MAX_HOST_SYNC_POINTS`` ceiling). This is the ratchet for ROADMAP
+    item 1 — every fusion PR moves prefixes up and sync counts down,
+    and nothing moves them back silently. Returns (violations,
+    skipped)."""
+    baseline_path = baseline_path or DEFAULT_FUSION_BASELINE
+    try:
+        baseline = _load(baseline_path)
+    except (OSError, json.JSONDecodeError) as e:
+        return [], [f"fusion baseline unreadable ({e}) — gate skipped"]
+    if current_path:
+        # reuse an analysis another CI stage already paid for (the
+        # `lint --fusion-report --json` output, or its __fusion__ key)
+        try:
+            current = _load(current_path)
+        except (OSError, json.JSONDecodeError) as e:
+            return [f"fusion current-report unreadable: {e}"], []
+        current = current.get("__fusion__", current)
+    else:
+        os.environ.setdefault("JAX_PLATFORMS", "cpu")
+        if ROOT not in sys.path:
+            sys.path.insert(0, ROOT)
+        import jax
+
+        jax.config.update("jax_platforms", "cpu")
+        from risingwave_tpu.analysis.fusion_analyzer import (
+            analyze_nexmark,
+        )
+
+        current = analyze_nexmark(deep=True)
+    violations, skipped = [], []
+    for q, base_rep in baseline.items():
+        if q.startswith("_"):
+            continue
+        if q not in current:
+            # a vanished query loses ALL its ratchet coverage — that
+            # is a regression, not a skip (fragments two checks below
+            # get the same treatment)
+            violations.append(
+                f"fusion: query {q!r} vanished from the analysis "
+                "(baseline still lists it)"
+            )
+            continue
+        base_frags = {
+            f["fragment"]: f for f in base_rep.get("fragments", ())
+        }
+        cur_frags = {
+            f["fragment"]: f for f in current[q]["fragments"]
+        }
+        for name, bf in base_frags.items():
+            cf = cur_frags.get(name)
+            if cf is None:
+                violations.append(
+                    f"fusion {q}: fragment {name!r} vanished from the "
+                    "analysis (baseline still lists it)"
+                )
+                continue
+            if cf["fusible_prefix"] < bf["fusible_prefix"]:
+                violations.append(
+                    f"fusion {q}/{name}: fusible prefix regressed "
+                    f"{bf['fusible_prefix']} -> {cf['fusible_prefix']}"
+                )
+            if cf["host_sync_points"] > bf["host_sync_points"]:
+                violations.append(
+                    f"fusion {q}/{name}: host-sync points grew "
+                    f"{bf['host_sync_points']} -> "
+                    f"{cf['host_sync_points']}"
+                )
+            # fallback syncs are outside the fusibility verdict (the
+            # fused step compiles them away) but still run per barrier
+            # wherever the fallback path executes (e.g. an epoch-
+            # batched agg feeding a join) — a regression adding reads
+            # there must not slip past the ratchet
+            if cf.get("fallback_sync_points", 0) > bf.get(
+                "fallback_sync_points", 0
+            ):
+                violations.append(
+                    f"fusion {q}/{name}: fallback-sync points grew "
+                    f"{bf.get('fallback_sync_points', 0)} -> "
+                    f"{cf.get('fallback_sync_points', 0)}"
+                )
+            if bf.get("whole_chain_fusible") and not cf.get(
+                "whole_chain_fusible"
+            ):
+                violations.append(
+                    f"fusion {q}/{name}: whole-chain fusible proof lost"
+                )
+        mx = MAX_HOST_SYNC_POINTS.get(q)
+        if mx is not None:
+            total = current[q]["summary"]["host_sync_points"]
+            if total > mx:
+                violations.append(
+                    f"fusion {q}: {total} host-sync points > ceiling {mx}"
+                )
+        # shape-stability ratchet (PR 9): per-code blocker ceilings —
+        # RW-E803/E806 are pinned at ZERO for the whole corpus (q7's
+        # wedge class must never return), and no code may regress
+        # above its committed-baseline count
+        cur_codes = current[q]["summary"].get("blockers_by_code", {})
+        base_codes = base_rep.get("summary", {}).get(
+            "blockers_by_code", {}
+        )
+        for code, mx in MAX_BLOCKER_CODES.items():
+            got = int(cur_codes.get(code, 0))
+            if got > mx:
+                violations.append(
+                    f"fusion {q}: {got} {code} finding(s) > ceiling {mx}"
+                    + (
+                        " (the q7 wedge class regressed: an executor "
+                        "lost its window_buckets lattice)"
+                        if code in ("RW-E803", "RW-E806")
+                        else ""
+                    )
+                )
+        for code, n in cur_codes.items():
+            if int(n) > int(base_codes.get(code, 0)):
+                violations.append(
+                    f"fusion {q}: blocker {code} count grew "
+                    f"{base_codes.get(code, 0)} -> {n} vs baseline"
+                )
+    return violations, skipped
+
+
+def run_mesh_static_gate(
+    baseline_path: str = None, current_path: str = None
+):
+    """Re-run the mesh analyzer over the sharded corpus and compare
+    against the committed MESH_REPORT.json baseline: per fragment, the
+    host-routed exchange-edge count (RW-E901 + RW-E907) must not GROW
+    and an SPMD-fusibility proof must not be LOST; per query, no E9xx
+    code's blocker count may grow past its committed count. This is
+    the ratchet for ROADMAP item 3 — the collective-exchange arc moves
+    edge counts down and proofs up, and nothing moves them back
+    silently. Without ``current_path`` the analysis runs in a fresh
+    subprocess (``lint --mesh-report`` owns its 8-virtual-device
+    mesh, which cannot be conjured after this process touched jax).
+    Returns (violations, skipped)."""
+    baseline_path = baseline_path or DEFAULT_MESH_BASELINE
+    try:
+        baseline = _load(baseline_path)
+    except (OSError, json.JSONDecodeError) as e:
+        return [], [f"mesh baseline unreadable ({e}) — gate skipped"]
+    if current_path:
+        try:
+            current = _load(current_path)
+        except (OSError, json.JSONDecodeError) as e:
+            return [f"mesh current-report unreadable: {e}"], []
+        current = current.get("__mesh__", current)
+    else:
+        import subprocess
+
+        env = dict(os.environ)
+        env.pop("XLA_FLAGS", None)  # the child claims its own mesh
+        proc = subprocess.run(
+            [
+                sys.executable,
+                "-m",
+                "risingwave_tpu",
+                "lint",
+                "--mesh-report",
+                "--json",
+            ],
+            capture_output=True,
+            text=True,
+            cwd=ROOT,
+            env=env,
+        )
+        if proc.returncode != 0:
+            return [
+                "mesh: `lint --mesh-report` failed "
+                f"(exit {proc.returncode}): "
+                f"{(proc.stderr or proc.stdout).strip()[-400:]}"
+            ], []
+        try:
+            current = json.loads(proc.stdout)
+        except json.JSONDecodeError as e:
+            return [f"mesh: analyzer emitted unparsable JSON: {e}"], []
+    violations, skipped = [], []
+    for q, base_rep in baseline.items():
+        if q.startswith("_") or q in ("ranking", "top_cost"):
+            continue
+        if q not in current:
+            violations.append(
+                f"mesh: query {q!r} vanished from the analysis "
+                "(baseline still lists it)"
+            )
+            continue
+        base_frags = {
+            f["fragment"]: f for f in base_rep.get("fragments", ())
+        }
+        cur_frags = {
+            f["fragment"]: f for f in current[q]["fragments"]
+        }
+        for name, bf in base_frags.items():
+            cf = cur_frags.get(name)
+            if cf is None:
+                violations.append(
+                    f"mesh {q}: fragment {name!r} vanished from the "
+                    "analysis (baseline still lists it)"
+                )
+                continue
+            if cf["host_routed_edges"] > bf["host_routed_edges"]:
+                violations.append(
+                    f"mesh {q}/{name}: host-routed exchange edges grew "
+                    f"{bf['host_routed_edges']} -> "
+                    f"{cf['host_routed_edges']}"
+                )
+            if bf.get("spmd_fusible") and not cf.get("spmd_fusible"):
+                violations.append(
+                    f"mesh {q}/{name}: SPMD-fusibility proof lost"
+                )
+        # per-code ratchet: no E9xx class may grow past its committed
+        # count (the committed blockers are the worklist, not a quota)
+        cur_codes = current[q]["summary"].get("blockers_by_code", {})
+        base_codes = base_rep.get("summary", {}).get(
+            "blockers_by_code", {}
+        )
+        for code, n in cur_codes.items():
+            if int(n) > int(base_codes.get(code, 0)):
+                violations.append(
+                    f"mesh {q}: blocker {code} count grew "
+                    f"{base_codes.get(code, 0)} -> {n} vs baseline"
+                )
+        bsum = base_rep.get("summary", {})
+        csum = current[q]["summary"]
+        if csum.get("spmd_fusible_fragments", 0) < bsum.get(
+            "spmd_fusible_fragments", 0
+        ):
+            violations.append(
+                f"mesh {q}: SPMD-fusible fragments shrank "
+                f"{bsum.get('spmd_fusible_fragments', 0)} -> "
+                f"{csum.get('spmd_fusible_fragments', 0)}"
+            )
+    return violations, skipped
+
+
+def stage_ratchets(
     fusion_current: str = None, mesh_current: str = None
 ) -> int:
-    print("[lint_all] perf_gate --smoke --blackbox --roofline --serving "
-          "--freshness --overload --mesh --integrity + fusion ratchet + "
-          "mesh-static ratchet (dispatch-cost + recorder/fsync + device-"
-          "roofline + shared-arrangement serving + freshness SLO + "
-          "overload-protection + mesh-observability + state-integrity + "
-          "fusion-regression + mesh-readiness budgets)")
-    env = dict(os.environ, JAX_PLATFORMS="cpu")
-    cmd = [sys.executable, os.path.join(ROOT, "scripts", "perf_gate.py"),
-           "--smoke", "--blackbox", "--roofline", "--serving",
-           "--freshness", "--overload", "--mesh", "--integrity"]
-    if fusion_current and os.path.exists(fusion_current):
-        cmd += ["--fusion-current", fusion_current]
-    else:
-        cmd += ["--fusion"]
-    if mesh_current and os.path.exists(mesh_current):
-        cmd += ["--mesh-current", mesh_current]
-    else:
-        cmd += ["--mesh-static"]
-    return subprocess.call(cmd, cwd=ROOT, env=env)
+    """Both ratchets against the reports stages 3/3b just wrote (a
+    failed stage passes None: the ratchet re-analyzes)."""
+    print("[lint_all] fusion ratchet vs FUSION_REPORT.json + "
+          "mesh-static ratchet vs MESH_REPORT.json")
+    violations = []
+    for name, (v, skipped) in (
+        ("fusion", run_fusion_gate(current_path=fusion_current)),
+        ("mesh", run_mesh_static_gate(current_path=mesh_current)),
+    ):
+        for s in skipped:
+            print(f"[lint_all] {name} ratchet skip: {s}")
+        violations += v
+    for v in violations:
+        print(f"[lint_all] REGRESSION: {v}", file=sys.stderr)
+    return 1 if violations else 0
 
 
 def main() -> int:
@@ -281,7 +520,7 @@ def main() -> int:
         mesh_json = os.path.join(tmp, "mesh_report.json")
         mrc = stage_mesh_report(mesh_json)
         rc |= mrc
-        rc |= stage_perf_gate(
+        rc |= stage_ratchets(
             fusion_json if frc == 0 else None,
             mesh_json if mrc == 0 else None,
         )

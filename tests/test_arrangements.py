@@ -459,6 +459,38 @@ def test_parameter_variants_share_fused_programs():
     assert out == {"k": [1], "c": [3]}
 
 
+def test_registration_storm_counts():
+    """A CI-scale registration storm in graph mode: 48 CREATE MVs over
+    3 plan-shape families build 3 arrangements holding 48 refs (every
+    further CREATE attaches), compile a number of fused programs bounded
+    by the families (at most 10), not by the MVs, and every subscriber
+    reads what its family's owner reads — zero reader errors."""
+    from risingwave_tpu.runtime.fused_step import fused_cache_stats
+
+    mvs, families = 48, (10, 250, 500)
+    s = _mk(exec_mode="graph")
+    _base(s)
+    cache0 = fused_cache_stats()["compiled_programs"]
+    assert cache0 >= 0, "jit cache size unreadable"
+    for i in range(mvs):
+        s.execute(
+            MV_SQL.format(name=f"storm{i}", thr=families[i % len(families)])
+        )
+    s.execute("INSERT INTO t VALUES (1, 999), (2, 1), (3, 260)")
+    st = s.runtime.arrangements.stats()
+    assert st["arrangements"] == len(families) and st["refs"] == mvs
+    compiled = fused_cache_stats()["compiled_programs"] - cache0
+    assert 0 <= compiled <= 10, compiled
+    reads = [
+        _cols(s.execute(f"SELECT k, c FROM storm{i} ORDER BY k")[0])
+        for i in range(mvs)
+    ]
+    for i, got in enumerate(reads):
+        assert got == reads[i % len(families)], f"storm{i}"
+    assert reads[0] == {"k": [1, 2, 3], "c": [3, 1, 2]}
+    assert reads[2] == {"k": [1], "c": [1]}
+
+
 def test_lift_rejected_plans_fall_back_to_baked_literals():
     """RW_FUSED_LIFT=0 keeps the baked-literal behavior (the kill
     switch contract) — results identical, no lifted plans."""

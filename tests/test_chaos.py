@@ -283,15 +283,13 @@ def test_actor_kill_chaos_converges_to_undisturbed():
         twin.feed(i)
     want = twin.snapshots()
 
-    # profiler armed with an open capture across the storm: partial
-    # recovery must close it (orphan-window audit, extends the PR-5
-    # watchdog audit to profiler capture sessions); the blackbox
-    # sentinel rides the same storm — actor kills must neither arm a
-    # spurious wedge nor orphan its capture window (PR 8 audit)
+    # dispatch counters armed across the storm (recoveries rebuild
+    # under the kernel interposer); the blackbox sentinel rides the
+    # same storm — actor kills must neither arm a spurious wedge nor
+    # orphan its capture window (PR 8 audit)
     from risingwave_tpu import blackbox
 
-    PROFILER.enable(fence=False)
-    PROFILER.start_capture(tag="chaos-audit")
+    PROFILER.enable()
     saved_sentinel = blackbox.SENTINEL  # fresh instance: no config leak
     blackbox.SENTINEL = blackbox.DeviceSentinel()
     blackbox.SENTINEL.start(
@@ -303,8 +301,6 @@ def test_actor_kill_chaos_converges_to_undisturbed():
             _ActorKillWorkload, seed=seed, kill_prob=0.45, kill_site="mixed"
         )
         obj = runner.run(n_epochs)
-        # no orphaned profiler capture windows survived the recoveries
-        assert PROFILER.active_captures == []
         # actor faults are NOT device wedges: nothing armed, no window
         assert blackbox.SENTINEL.wedged_error() is None
         assert blackbox.SENTINEL.abort_capture() == 0

@@ -127,7 +127,7 @@ def test_telemetry_armed_adds_zero_dispatches_and_syncs():
     epoch()
     epoch()  # warm: compiles + analyses land before counting
     PROFILER.reset()
-    PROFILER.enable(fence=False)
+    PROFILER.enable()
     try:
         per = []
         for _ in range(3):
@@ -413,7 +413,6 @@ def test_rebuild_rearms_deviceprof_without_orphans():
         assert DEVICEPROF.telemetry, "rebuilt fragment lost telemetry"
         assert set(DEVICEPROF.report()["programs"]) >= programs_before
         assert DEVICEPROF.report()["analysis_errors"] == 0
-        assert PROFILER.active_captures == []
     finally:
         mv.pipeline.close()
 
@@ -434,26 +433,10 @@ def test_padding_fraction_weighted():
     assert padding_fraction([(64, 1000, 8)]) == 0.0
 
 
-def test_provenance_stamp_and_generation_warning():
+def test_provenance_stamp():
     from risingwave_tpu.provenance import ENGINE_GENERATION, stamp
 
     s = stamp()
     assert s["engine_generation"] == ENGINE_GENERATION >= 11
     assert isinstance(s["git_sha"], str) and s["git_sha"]
     assert isinstance(s["pr_tag"], str)
-    import sys
-
-    sys.path.insert(0, "scripts")
-    try:
-        from perf_gate import generation_warnings
-    finally:
-        sys.path.pop(0)
-    assert generation_warnings(dict(s), "x") == []
-    old = dict(s, engine_generation=ENGINE_GENERATION - 1)
-    assert any("generation" in w for w in generation_warnings(old, "x"))
-    assert any(
-        "no engine_generation" in w for w in generation_warnings({}, "x")
-    )
-    # fusion-report shape: provenance under the "_"-prefixed key
-    nested = {"_provenance": dict(s), "q5": {}}
-    assert generation_warnings(nested, "x") == []

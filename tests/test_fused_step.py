@@ -11,10 +11,13 @@ latch checks still raise at finish_barrier, and RW_FUSED_STEP=0 falls
 back to the epoch-batched interpreted path.
 """
 
+import itertools
+
 import jax
 import jax.numpy as jnp
 import pytest
 
+from risingwave_tpu.analysis.jax_sanitizer import RecompileWatch
 from risingwave_tpu.connectors.nexmark import (
     BID_SCHEMA,
     NexmarkConfig,
@@ -32,6 +35,7 @@ from risingwave_tpu.runtime.fused_step import (
     expand_fused,
     fuse_chain,
     fuse_pipeline,
+    fused_cache_stats,
     fused_fragments,
     fusion_refusals,
 )
@@ -163,9 +167,19 @@ def test_q8_fused_bit_identical_to_interpreted_twin():
 # ---------------------------------------------------------------------------
 
 
-def test_fused_q5_one_dispatch_per_barrier_with_fused_label():
+def _nothing():
+    return None
+
+
+def _q5_leg(*, fused, digests=False, runtime=False):
+    """q5 steady state: one fixed chunk an epoch (fresh keys would grow
+    the table: a legitimate recompile, not what these counts hold).
+    Returns (prepare, counted, after, check)."""
     q5 = build_q5_lite(capacity=1 << 12, state_cleaning=False)
-    fuse_pipeline(q5.pipeline, label="q5")
+    wrappers = []
+    if fused:
+        wrappers = fuse_pipeline(q5.pipeline, label="q5")
+        assert len(wrappers) == 1 and wrappers[0].covers_whole_chain
     gen = NexmarkGenerator(NexmarkConfig(first_event_rate=50_000))
     bid = gen.next_chunks(2000, 1 << 11)["bid"].select(
         ["auction", "date_time"]
@@ -175,24 +189,154 @@ def test_fused_q5_one_dispatch_per_barrier_with_fused_label():
         q5.pipeline.push(bid)
         q5.pipeline.barrier()
 
-    epoch()
-    epoch()  # warm: compiles + growth transitions
+    if digests:
+        # the digest lanes ride the staged scalar read; the commit
+        # (host crc + digests) sits outside the counted barrier
+        from risingwave_tpu.storage.object_store import MemObjectStore
+        from risingwave_tpu.storage.state_table import CheckpointManager
+
+        mgr = CheckpointManager(MemObjectStore())
+        commits = itertools.count(1)
+
+        def commit():
+            mgr.commit_staged(
+                next(commits) << 16, mgr.stage(wrappers[0].members)
+            )
+
+        def check():
+            assert {"agg", "mv"} <= set(wrappers[0].last_digests)
+
+        return _nothing, epoch, commit, check
+    if runtime:
+        # the whole _begin_trace -> dispatch -> publish ->
+        # _observe_freshness lifecycle, with the frontier threaded; the
+        # watermark walk is the hop executor's own dispatch, identical
+        # with tracking off, so only rt.barrier() is counted
+        import time
+
+        from risingwave_tpu.freshness import FRESHNESS
+        from risingwave_tpu.runtime import StreamingRuntime
+
+        rt = StreamingRuntime(store=None)
+        rt.register("q5_mv", q5.pipeline)
+        FRESHNESS.reset()
+
+        def prepare():
+            rt.push("q5_mv", bid)
+            q5.pipeline.watermark("date_time", int(time.time() * 1000))
+
+        def check():
+            rows = [r for r in FRESHNESS.history() if r["mv"] == "q5_mv"]
+            assert len(rows) >= 3  # a sample every counted barrier
+            assert all(r["event_time_lag_ms"] is not None for r in rows[-3:])
+            assert rt.last_epoch_trace.backpressure_fragment is not None
+
+        return prepare, rt.barrier, _nothing, check
+    return _nothing, epoch, _nothing, _nothing
+
+
+def _two_input_leg(query):
+    """q7 / q8 fused whole: side chains x join x MV in one program; the
+    pushes buffer, q7's watermark walk follows the counted barrier."""
+    gen = NexmarkGenerator(NexmarkConfig(first_event_rate=10_000))
+    after = _nothing
+    if query == "q7":
+        q = build_q7(
+            capacity=1 << 13,
+            agg_capacity=1 << 11,
+            filter_capacity=1 << 11,
+            out_cap=1 << 11,
+        )
+        seen = {}
+
+        def prepare():
+            bid = None
+            while bid is None:
+                bid = gen.next_chunks(1000, 1024)["bid"]
+            bid = bid.select(["auction", "bidder", "price", "date_time"])
+            q.pipeline.push_left(bid)
+            q.pipeline.push_right(bid)
+            seen["mx"] = int(bid.to_numpy()["date_time"].max())
+
+        def after():
+            q.pipeline.watermark("date_time", seen["mx"])
+    else:
+        q = build_q8(capacity=1 << 12, out_cap=1 << 11)
+
+        def prepare():
+            ev = gen.next_chunks(2000, 4096)
+            p, a = ev["person"], ev["auction"]
+            if p is not None:
+                q.pipeline.push_left(p.select(["id", "name", "date_time"]))
+            if a is not None:
+                q.pipeline.push_right(a.select(["seller", "date_time"]))
+
+    wrappers = fuse_pipeline(q.pipeline, label=query)
+    assert len(wrappers) == 1 and wrappers[0].covers_whole_chain
+    assert q.pipeline._fused is not None
+    return prepare, q.pipeline.barrier, after, _nothing
+
+
+# id -> (leg builder, warm epochs, dispatches per steady barrier, label)
+_DISPATCH_CASES = {
+    "q5-unfused": (lambda: _q5_leg(fused=False), 2, 4, None),
+    "q5-fused": (lambda: _q5_leg(fused=True), 2, 1, "fused:q5"),
+    "q7-fused": (lambda: _two_input_leg("q7"), 4, 1, "fused:q7"),
+    "q8-fused": (lambda: _two_input_leg("q8"), 4, 1, "fused:q8"),
+    "q5-fused-digests": (
+        lambda: _q5_leg(fused=True, digests=True), 2, 1, "fused:q5",
+    ),
+    "q5-fused-freshness": (
+        lambda: _q5_leg(fused=True, runtime=True), 2, 1, "fused:q5",
+    ),
+}
+
+
+@pytest.mark.parametrize("case", list(_DISPATCH_CASES))
+def test_dispatches_per_barrier(case, monkeypatch):
+    """Steady state, exact: the interpreted q5 walk costs 4 device
+    dispatches a barrier; every fused shape — q5's hop->agg->flush->MV,
+    q7's and q8's side chains x join x MV — costs ONE, attributed
+    ``fused:<fragment>``, and neither the digest lanes
+    (``RW_STATE_DIGEST``) nor freshness tracking adds one. A fragment
+    that silently de-fuses (q7 interpreted costs ~31) fails here, and
+    so does a steady epoch that compiles anything."""
+    build, warm, want, label = _DISPATCH_CASES[case]
+    if case == "q5-fused-digests":
+        monkeypatch.setenv("RW_STATE_DIGEST", "1")
+    prepare, counted, after, check = build()
+
+    def epoch(per=None):
+        prepare()
+        base = PROFILER.total_dispatches()
+        counted()
+        if per is not None:
+            per.append(PROFILER.total_dispatches() - base)
+        after()
+
+    for _ in range(warm):
+        epoch()  # compiles + growth transitions
+    recompiles = RecompileWatch()
+    recompiles.snapshot()
+    programs = fused_cache_stats()["compiled_programs"]
     PROFILER.reset()
-    PROFILER.enable(fence=False)
+    PROFILER.enable()
     try:
         per = []
         for _ in range(3):
-            base = PROFILER.total_dispatches()
-            epoch()
-            per.append(PROFILER.total_dispatches() - base)
+            epoch(per)
         counts = PROFILER.dispatch_counts()
     finally:
         PROFILER.disable()
         PROFILER.reset()
-    # steady state: the whole hop->agg->flush->MV barrier is ONE
-    # Python-level device dispatch, attributed to the fused fragment
-    assert per == [1.0, 1.0, 1.0], per
-    assert counts.get("fused:q5", 0) >= 3, counts
+    assert per == [float(want)] * 3, per
+    if label is not None:
+        assert counts.get(label, 0) >= 3, counts
+    # and the steady epochs compiled nothing: no step kernel and no
+    # fused program re-traced (zero recompile hazards per query)
+    assert recompiles.deltas(record=False) == {}
+    assert fused_cache_stats()["compiled_programs"] == programs
+    check()
 
 
 def test_fused_fragments_report_shapes():
@@ -675,41 +819,6 @@ def test_two_input_flush_rounds_exact_and_one_over():
         b = drive(True, n_windows)
         assert a == b, f"{n_windows} windows: fused flush diverged"
         assert len(a[-1]) == n_windows
-
-
-def test_fused_two_input_one_dispatch_per_barrier():
-    """Steady state: the whole q8 barrier — dedup x join x MV — is ONE
-    device dispatch, attributed ``fused:<fragment>`` (q7's twin check
-    lives in perf_gate --smoke; 31 -> 1 on this image)."""
-    q8 = build_q8(capacity=1 << 12, out_cap=1 << 11)
-    fuse_pipeline(q8.pipeline, label="q8")
-    gen = NexmarkGenerator(NexmarkConfig(first_event_rate=10_000))
-
-    def epoch():
-        ev = gen.next_chunks(2000, 4096)
-        p, a = ev["person"], ev["auction"]
-        if p is not None:
-            q8.pipeline.push_left(p.select(["id", "name", "date_time"]))
-        if a is not None:
-            q8.pipeline.push_right(a.select(["seller", "date_time"]))
-        q8.pipeline.barrier()
-
-    for _ in range(4):
-        epoch()  # warm: compiles + growth transitions
-    PROFILER.reset()
-    PROFILER.enable(fence=False)
-    try:
-        per = []
-        for _ in range(3):
-            base = PROFILER.total_dispatches()
-            epoch()
-            per.append(PROFILER.total_dispatches() - base)
-        counts = PROFILER.dispatch_counts()
-    finally:
-        PROFILER.disable()
-        PROFILER.reset()
-    assert per == [1.0, 1.0, 1.0], per
-    assert counts.get("fused:q8", 0) >= 3, counts
 
 
 def test_two_input_donation_census_flat():

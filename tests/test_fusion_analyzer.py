@@ -255,17 +255,16 @@ def test_report_json_shape_and_summary():
     json.dumps(rep)  # JSON-serializable end to end
 
 
-def test_perf_gate_fusion_clean_and_regression(tmp_path):
+def test_fusion_ratchet_clean_and_regression(tmp_path):
     import sys
 
     sys.path.insert(0, "scripts")
     try:
-        from perf_gate import _load, run_fusion_gate
+        from lint_all import _load, run_fusion_gate
     finally:
         sys.path.pop(0)
 
-    budgets = _load("scripts/perf_budgets.json")
-    v, skipped = run_fusion_gate(budgets, "FUSION_REPORT.json")
+    v, skipped = run_fusion_gate("FUSION_REPORT.json")
     assert v == [], v  # committed baseline is green
     # injected regression: baseline claims a longer fusible prefix
     # (q5, already whole-chain) and fewer fallback sync points than
@@ -284,11 +283,11 @@ def test_perf_gate_fusion_clean_and_regression(tmp_path):
     synced["fallback_sync_points"] = 0
     p = tmp_path / "base.json"
     p.write_text(json.dumps(base))
-    v, _ = run_fusion_gate(budgets, str(p))
+    v, _ = run_fusion_gate(str(p))
     assert any("fusible prefix regressed" in x for x in v), v
     assert any("fallback-sync points grew" in x for x in v), v
     # unreadable baseline skips, never crashes CI
-    v, skipped = run_fusion_gate(budgets, str(tmp_path / "nope.json"))
+    v, skipped = run_fusion_gate(str(tmp_path / "nope.json"))
     assert v == [] and skipped
 
 

@@ -204,6 +204,30 @@ def test_overload_chaos_full_ladder_and_bit_identity():
     assert runner.report["spills"] > 0, runner.report
 
 
+def test_overload_chaos_bounded_flaps_and_recovery():
+    """HOW WELL the ladder degrades, at CI scale (a 4k-row storm in 1k
+    bursts): hysteresis must not thrash the rungs (at most 3
+    re-escalations within a cooldown of a descent) and relief + lazy
+    shrink must converge (NORMAL within 60 post-storm barriers; the
+    runner itself enforces zero OOM, zero wedge and the descent)."""
+    seed = chaos_seed(11)
+    runner = OverloadChaosRunner(
+        make=lambda: _GovernedAgg(seed),
+        seed=seed,
+        storm_rows=4_000,
+        burst_rows=1_000,
+        drain_epochs=40,
+        max_epochs=300,
+        # how deep the ladder stacks before relief lands depends on
+        # scale; it must BITE (>= 2 states, runner-enforced) and recover
+        require_full_ladder=False,
+    )
+    got, want = runner.run()
+    assert got == want, runner.report
+    assert runner.report["flaps"] <= 3, runner.report
+    assert runner.report["drain_barriers"] <= 60, runner.report
+
+
 def test_overload_chaos_deterministic_replay():
     """Same seed -> same ladder walk and same report shape (the replay
     contract RW_CHAOS_SEED rests on)."""

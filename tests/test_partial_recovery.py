@@ -662,8 +662,7 @@ def test_no_orphan_stall_watchdog_timers_across_recoveries():
         MemObjectStore(), async_checkpoint=False, auto_recover=True
     )
     rt.stall_dump_after_s = 30.0  # real timers, armed per barrier
-    PROFILER.enable(fence=False)
-    PROFILER.start_capture(tag="orphan-audit")  # open across the faults
+    PROFILER.enable()  # counters armed across the faults
     crash = CrashingExecutor("boom")
     gpa, _ = build_singleton_mv("mv_a")
     gpb, _ = build_singleton_mv("mv_b", crash=crash)
@@ -693,9 +692,6 @@ def test_no_orphan_stall_watchdog_timers_across_recoveries():
         while time.time() < deadline and _watchdog_threads():
             time.sleep(0.05)  # canceled Timers exit, not at expiry
         assert _watchdog_threads() == []
-        # no orphaned profiler capture windows either: the first
-        # recovery closed the pre-fault window, none re-opened
-        assert PROFILER.active_captures == []
         # blackbox sentinel audit: recoveries never left a wedge-
         # capture window open, no spurious wedge was armed, and the
         # sentinel kept beating across every recovery

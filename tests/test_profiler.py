@@ -1,18 +1,14 @@
-"""Dispatch-wall profiler: per-executor attribution, device-dispatch /
-transfer accounting, Perfetto export (named threads, epoch flows),
-slow-barrier auto-capture, stall-dump fallback, and the perf gate."""
+"""Dispatch counters: device-dispatch / transfer accounting per
+executor, Perfetto export (named threads, epoch flows), stall-dump
+fallback and device forensics."""
 
-import glob
 import json
 import os
-import subprocess
-import sys
 import threading
 import time
 
 import pytest
 
-from risingwave_tpu import utils_sync_point as sync_point
 from risingwave_tpu.connectors.nexmark import NexmarkConfig, NexmarkGenerator
 from risingwave_tpu.event_log import EVENT_LOG
 from risingwave_tpu.metrics import REGISTRY
@@ -21,18 +17,12 @@ from risingwave_tpu.queries.nexmark_q import build_q5_lite
 from risingwave_tpu.runtime import StreamingRuntime
 from risingwave_tpu.storage.object_store import MemObjectStore
 
-ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-
 
 @pytest.fixture(autouse=True)
 def _clean():
     yield
     PROFILER.disable()
     PROFILER.reset()
-    PROFILER.slow_barrier_ms = None
-    PROFILER.capture_dir = None
-    PROFILER._auto_captures = 0
-    sync_point.reset()
     EVENT_LOG.clear()
 
 
@@ -55,48 +45,6 @@ def _steady_chunk(events=2_000):
 # ---------------------------------------------------------------------------
 
 
-def test_executor_attribution_covers_dispatch_stage():
-    """The dispatch stage decomposes into per-executor executor_ms
-    entries (flush + barrier_apply + device wait) summing to within ε
-    of the parent stage total — attribution, not decoration."""
-    rt, q5 = _rt_with_q5()
-    bid = _steady_chunk()
-    rt.push("q5", bid)
-    rt.barrier()  # warmup (compiles) stays unprofiled
-    REGISTRY.histograms.pop("barrier_stage_ms", None)
-    PROFILER.reset()
-    PROFILER.enable(fence=True)
-    for _ in range(3):
-        rt.push("q5", bid)
-        rt.barrier()
-    PROFILER.disable()
-    bd = REGISTRY.histograms["barrier_stage_ms"].summary()
-    disp = sum(
-        v["sum"]
-        for k, v in bd.items()
-        if "stage=dispatch" in k and "fragment=q5" in k
-    )
-    assert disp > 0
-    h = REGISTRY.histograms["executor_ms"]
-    covered = sum(
-        v
-        for k, v in h._sum.items()
-        if dict(k)["phase"] in ("flush", "barrier_apply")
-    )
-    dw = REGISTRY.histograms.get("executor_device_wait_ms")
-    if dw is not None:
-        covered += sum(
-            v
-            for k, v in dw._sum.items()
-            if dict(k)["phase"] in ("flush", "barrier_apply")
-        )
-    assert covered >= 0.85 * disp, (covered, disp, bd)
-    assert covered <= disp * 1.05 + 1.0  # cannot exceed its parent
-    # every label set carries the full (executor, fragment, phase) key
-    for labels in h._sum:
-        assert {k for k, _ in labels} == {"executor", "fragment", "phase"}
-
-
 def test_dispatch_and_transfer_counters():
     """Kernel interposer: jitted-kernel calls land in
     device_dispatches_total{executor} with per-kernel detail; the
@@ -106,7 +54,7 @@ def test_dispatch_and_transfer_counters():
     rt.push("q5", bid)
     rt.barrier()
     PROFILER.reset()
-    PROFILER.enable(fence=False)
+    PROFILER.enable()
     rt.push("q5", bid)
     rt.barrier()
     PROFILER.disable()
@@ -134,7 +82,7 @@ def test_dispatch_counts_deterministic_and_flat_in_steady_state():
         q5.pipeline.push(bid)
         q5.pipeline.barrier()  # warm: compiles + first flush
         PROFILER.reset()
-        PROFILER.enable(fence=False)
+        PROFILER.enable()
         per_epoch = []
         for _ in range(3):
             base = PROFILER.total_dispatches()
@@ -148,52 +96,47 @@ def test_dispatch_counts_deterministic_and_flat_in_steady_state():
     assert a == b, (a, b)
     assert len(set(a)) == 1, f"steady-state dispatch count drifted: {a}"
 
+def test_enabled_profiler_counts_and_holds_no_clock():
+    """An armed PROFILER leaves REGISTRY with its three counters and
+    no histogram of its own (time is ``trace.span``'s), and ``disable``
+    leaves no counting proxy on any module or on ``jax``."""
+    import sys
 
-def test_profile_mode_off_overhead_under_1pct():
-    """Profile-mode-off is one attribute check per call site: its
-    measured unit cost times a generous per-barrier call count must be
-    <1% of the steady-state barrier wall. And nothing may be recorded
-    while off."""
+    import jax
+
+    from risingwave_tpu.profiler import _KernelProxy
+
     rt, q5 = _rt_with_q5()
     bid = _steady_chunk()
     rt.push("q5", bid)
-    rt.barrier()  # warm
-    REGISTRY.histograms.pop("executor_ms", None)
-    t0 = time.perf_counter()
-    n = 3
-    for _ in range(n):
-        rt.push("q5", bid)
-        rt.barrier()
-    steady_ms = (time.perf_counter() - t0) / n * 1e3
-    assert "executor_ms" not in REGISTRY.histograms  # off records nothing
-    # unit cost of the disabled hook (the _pcall branch)
-    from risingwave_tpu.runtime.pipeline import _pcall
-
-    ex = q5.pipeline.executors[0]
-    sink = []
-
-    def f(x=None):
-        sink.append(None)
-        sink.clear()
-        return ()
-
-    loops = 20_000
-    t0 = time.perf_counter()
-    for _ in range(loops):
-        f(None)
-    raw_s = time.perf_counter() - t0
-    t0 = time.perf_counter()
-    for _ in range(loops):
-        _pcall(ex, "apply", f, None)
-    hook_s = time.perf_counter() - t0
-    per_call_ms = max(hook_s - raw_s, 0.0) / loops * 1e3
-    # ~4 hook sites per executor per barrier is well above reality
-    calls = 4 * len(q5.pipeline.executors)
-    assert per_call_ms * calls < 0.01 * steady_ms, (
-        per_call_ms,
-        calls,
-        steady_ms,
-    )
+    rt.barrier()
+    get, put = jax.device_get, jax.device_put
+    hists = set(REGISTRY.histograms)
+    PROFILER.reset()
+    PROFILER.enable()
+    assert jax.device_get is not get and jax.device_put is not put
+    rt.push("q5", bid)
+    rt.barrier()
+    PROFILER.disable()
+    for c in (
+        "device_dispatches_total",
+        "device_dispatch_kernels_total",
+        "host_device_transfers_total",
+    ):
+        assert c in REGISTRY.counters, c
+    assert set(REGISTRY.histograms) == hists
+    assert jax.device_get is get and jax.device_put is put
+    proxies = [
+        (name, attr)
+        for name, mod in sys.modules.items()
+        if name.startswith("risingwave_tpu") and mod is not None
+        for attr, v in vars(mod).items()
+        if isinstance(v, _KernelProxy)
+    ]
+    assert proxies == []
+    assert set(PROFILER.snapshot()) == {
+        "enabled", "dispatches", "kernels", "transfers",
+    }
 
 
 # ---------------------------------------------------------------------------
@@ -296,63 +239,8 @@ def test_stable_tids_no_collisions_across_threads():
 
 
 # ---------------------------------------------------------------------------
-# capture windows + forensics
+# forensics
 # ---------------------------------------------------------------------------
-
-
-def test_slow_barrier_auto_capture_and_forensic_dump(tmp_path, monkeypatch):
-    """A barrier over the profile threshold auto-emits a PROFILE_*
-    artifact (executor breakdown + device forensics) and a stall dump
-    carrying device memory stats — the q7-wedge evidence path."""
-    monkeypatch.setenv("RW_STALL_DIR", str(tmp_path))
-    rt, q5 = _rt_with_q5()
-    bid = _steady_chunk()
-    rt.push("q5", bid)
-    rt.barrier()
-    PROFILER.reset()
-    PROFILER.enable(
-        fence=True, slow_barrier_ms=10.0, capture_dir=str(tmp_path)
-    )
-    sync_point.activate(
-        "before_manifest_commit", lambda: time.sleep(0.05)
-    )
-    rt.push("q5", bid)
-    rt.barrier()  # slow: over the 10ms threshold
-    profs = glob.glob(str(tmp_path / "PROFILE_slow_barrier_*.json"))
-    assert profs, "no PROFILE_* artifact"
-    doc = json.loads(open(profs[-1]).read())
-    assert doc["barrier_wall_ms"] >= 10.0
-    assert "executor_ms" in doc and doc["device_dispatches_total"]
-    assert "memory_stats" in doc["device"]  # None on CPU, key present
-    assert doc["device"]["live_arrays"]["total_count"] > 0
-    dumps = glob.glob(str(tmp_path / "STALL_DUMP_*.json"))
-    assert dumps, "no forensic stall dump"
-    sdoc = json.loads(open(dumps[-1]).read())
-    assert "memory_stats" in sdoc["device"]
-    assert "profiler" in sdoc["device"]
-    # window bookkeeping: capture closed, event recorded
-    assert PROFILER.active_captures == []
-    assert EVENT_LOG.events(kind="profile_capture")
-    # bounded: a persistently slow run cannot flood the dir, and
-    # manual captures never consume the auto budget
-    assert PROFILER._auto_captures <= PROFILER.max_auto_captures
-    before = PROFILER._auto_captures
-    PROFILER.end_capture(PROFILER.start_capture(tag="manual"))
-    assert PROFILER._auto_captures == before
-
-
-def test_recovery_aborts_open_capture_windows():
-    """PR-5 orphan-audit extension: a recovery mid-capture must close
-    the profiler window (an orphaned jax.profiler session would hold
-    the device)."""
-    rt, q5 = _rt_with_q5()
-    rt.push("q5", _steady_chunk())
-    rt.barrier()
-    PROFILER.enable(fence=False)
-    PROFILER.start_capture(tag="unit")
-    assert len(PROFILER.active_captures) == 1
-    rt.recover()
-    assert PROFILER.active_captures == []
 
 
 def test_stall_dump_falls_back_to_tempdir(tmp_path, monkeypatch):
@@ -392,104 +280,3 @@ def test_device_forensics_shape():
     assert d["platform"] == "cpu"
     assert "memory_stats" in d and "live_arrays" in d
     assert "state_tables" in d and "profiler" in d
-
-
-# ---------------------------------------------------------------------------
-# perf gate
-# ---------------------------------------------------------------------------
-
-
-def _gate(args):
-    return subprocess.run(
-        [sys.executable, os.path.join(ROOT, "scripts", "perf_gate.py"),
-         *args],
-        capture_output=True,
-        text=True,
-        cwd=ROOT,
-    )
-
-
-def test_perf_gate_clean_on_committed_baseline():
-    """The committed BENCH artifact must pass the committed budgets —
-    the gate's green state is reproducible from the repo alone."""
-    r = _gate(["--bench", os.path.join(ROOT, "BENCH_partial.json")])
-    assert r.returncode == 0, r.stdout + r.stderr
-
-
-def test_perf_gate_fails_on_injected_dispatch_regression(tmp_path):
-    bench = json.load(open(os.path.join(ROOT, "BENCH_partial.json")))
-    bench["q5u_dispatches_per_row"] = 99.0  # per-op dispatch storm
-    bad = tmp_path / "bad.json"
-    bad.write_text(json.dumps(bench))
-    r = _gate(["--bench", str(bad)])
-    assert r.returncode == 1
-    assert "dispatches/row" in r.stderr
-    # and a blown stage p99 also trips it
-    bench = json.load(open(os.path.join(ROOT, "BENCH_partial.json")))
-    bench.setdefault("barrier_stage_ms", {})[
-        "fragment=mv#0,stage=dispatch"
-    ] = {"p50": 9000.0, "p99": 9000.0, "count": 2, "sum": 18000.0}
-    bad.write_text(json.dumps(bench))
-    r = _gate(["--bench", str(bad)])
-    assert r.returncode == 1
-
-
-def test_perf_gate_smoke_budgets_in_process():
-    """The CI smoke microbench (in-process here to skip a cold jax
-    import): steady-state dispatches/barrier and host-python ms/row
-    within committed budgets, dispatch count stable across epochs."""
-    sys.path.insert(0, os.path.join(ROOT, "scripts"))
-    try:
-        import perf_gate
-    finally:
-        sys.path.pop(0)
-    budgets = json.load(
-        open(os.path.join(ROOT, "scripts", "perf_budgets.json"))
-    )
-    violations, report = perf_gate.run_smoke(budgets, epochs=3)
-    assert violations == [], (violations, report)
-    assert report["smoke_dispatches_per_barrier"]
-    assert (
-        max(report["smoke_dispatches_per_barrier"])
-        <= budgets["smoke"]["dispatches_per_barrier_max"]
-    )
-    # the fused leg: one donated program per barrier, actually fused
-    assert report["fused_whole_chain"] is True
-    assert (
-        max(report["fused_dispatches_per_barrier"])
-        <= budgets["smoke"]["fused_dispatches_per_barrier_max"]
-    )
-
-
-def test_profiler_config_section():
-    """[profiler] TOML section parses into ProfilerConfig and unknown
-    keys stay non-fatal."""
-    from risingwave_tpu.config import load_config
-
-    import tempfile
-
-    with tempfile.NamedTemporaryFile(
-        "w", suffix=".toml", delete=False
-    ) as f:
-        f.write(
-            "[profiler]\nenabled = false\nslow_barrier_capture_ms = 250.0\n"
-            "jax_trace = false\nmystery = 1\n"
-        )
-        p = f.name
-    try:
-        cfg = load_config(p)
-        assert cfg.profiler.enabled is False
-        assert cfg.profiler.slow_barrier_capture_ms == 250.0
-        assert cfg.unrecognized.get("profiler.mystery") == 1
-    finally:
-        os.remove(p)
-
-
-def test_env_rw_profile_0_disables_config_enabled_profiler(monkeypatch):
-    """The env knob wins in BOTH directions: RW_PROFILE=0 disarms a
-    config-enabled profiler (the operator's no-restart escape hatch)."""
-    from risingwave_tpu.config import ProfilerConfig
-
-    monkeypatch.setenv("RW_PROFILE", "0")
-    PROFILER.configure(ProfilerConfig(enabled=True, fence=False))
-    assert PROFILER.enabled is False
