@@ -18,7 +18,7 @@ XLA programs per chunk.
 from __future__ import annotations
 
 from dataclasses import dataclass
-from typing import List
+from typing import List, Optional, Sequence
 
 from risingwave_tpu.array.chunk import StreamChunk
 
@@ -188,6 +188,29 @@ class Executor:
         instances of the same plan shape, or every graph rebuild
         recompiles the fused program."""
         return None
+
+    # -- the warm-up pass (runtime/graph.FragmentActor.warm_flush_lattice)
+    def warm_emissions(self) -> Sequence[StreamChunk]:
+        """One chunk with no valid row of every size this executor's
+        barrier flush may hand on — its declared ``emission_caps`` —
+        or nothing where what it hands on follows its input. When a
+        view is created the actor sends them down what follows the
+        executor, so that every program of every size exists before a
+        stream first meets the size (HashAggExecutor)."""
+        return ()
+
+    def warm(self, chunk: StreamChunk) -> Optional[List[StreamChunk]]:
+        """``apply`` for a chunk of the warm-up pass: run (so compile,
+        or load from the persistent cache) what ``apply`` runs for a
+        chunk of this shape, and LEAVE NO MARK: nothing stored or
+        dirtied, no host bound advanced, no table grown, nothing for a
+        checkpoint to stage. The chunk has no valid row, which the
+        device steps treat as inert; the host side of a stateful
+        ``apply`` (bounds, growth) is what an override leaves out.
+        None = not known to be safe, the pass stops here: the default
+        for everything but the stateless-pure executors, whose
+        ``apply`` is their whole step."""
+        return self.apply(chunk) if self.pure_step() is not None else None
 
     # -- overlapped barrier scalar reads ---------------------------------
     # Executors that must read device scalars at the barrier (overflow

@@ -194,6 +194,17 @@ class EpochBatchedAggExecutor(Executor):
             stack_chunks(buf), pre=self._pre, mode=self.mode
         )
 
+    # -- the warm-up pass (Executor.warm) ----------------------------------
+    def warm_emissions(self):
+        return self.agg.warm_emissions()
+
+    def warm(self, chunk: StreamChunk) -> List[StreamChunk]:
+        """The one-chunk epoch program for a chunk of this shape (an
+        upstream aggregate's flush chunk comes one a round), not
+        buffered: nothing is left for a barrier to apply."""
+        self.agg.warm_stacked(stack_chunks([chunk]), self._pre, self.mode)
+        return []
+
     # -- control path -----------------------------------------------------
     def on_barrier(self, barrier: Barrier) -> List[StreamChunk]:
         self.flush()

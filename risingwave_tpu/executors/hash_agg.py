@@ -46,7 +46,13 @@ from risingwave_tpu.ops import agg as agg_ops
 from risingwave_tpu.ops import minput as mi_ops
 from risingwave_tpu.ops.agg import AggCall, AggState
 from risingwave_tpu.ops.hash_table import HashTable, lookup, lookup_or_insert, stage_scalars, set_live
-from risingwave_tpu.runtime.bucketing import BucketAllocator, BucketPolicy
+from risingwave_tpu.metrics import REGISTRY
+from risingwave_tpu.runtime.bucketing import (
+    BucketAllocator,
+    BucketPolicy,
+    flush_lattice,
+    flush_lattice_pad,
+)
 from risingwave_tpu.trace import span
 
 GROW_AT = 0.5  # rehash when claimed slots may exceed this load factor
@@ -497,6 +503,8 @@ class HashAggExecutor(Executor, Checkpointable):
         # old status-read loop was RW-E801 at the top of the fusion
         # worklist).
         self._dirty_bound = 0
+        # lanes a chunk holds after the traced-in prefix, per chunk shape
+        self._lanes_after_pre: Dict[tuple, int] = {}
         # shape-stability: capacity walks the allocator's pow2 lattice;
         # growth decisions consume the occupancy note staged at the
         # previous barrier (see _maybe_grow) instead of a synchronous
@@ -575,11 +583,11 @@ class HashAggExecutor(Executor, Checkpointable):
         }
 
     def trace_contract(self):
-        # flush quantizes every delta chunk to exactly two capacities
-        # (_delta_to_chunk: small | full) — that pair IS the declared
-        # bucket lattice that keeps the windowed agg shape-stable
-        full = 2 * self.out_cap
-        caps = tuple(sorted({min(256, full), full}))
+        # the interpreted flush cuts every delta chunk to one of these
+        # sizes (_delta_to_chunk) — they ARE the declared bucket
+        # lattice that keeps the windowed agg shape-stable; the fused
+        # programs use its two ends (bucketing.flush_pad)
+        caps = self.flush_sizes()
         contract = {
             "kind": "device",
             "trace_step": lambda c: _agg_step(
@@ -614,6 +622,16 @@ class HashAggExecutor(Executor, Checkpointable):
                 "_expire_evicted",
             )
         return contract
+
+    def _round_cap(self) -> int:
+        """Groups one flush round drains at most: ``out_cap``, or the
+        whole table where that is smaller (the delta has twice as many
+        lanes)."""
+        return min(self.out_cap, self.table.capacity)
+
+    def flush_sizes(self) -> Tuple[int, ...]:
+        """The sizes a flush chunk of this aggregate can have."""
+        return flush_lattice(self._round_cap())
 
     def pin_max_bucket(self):
         """ShapeGovernor hook: freeze the group table at its high-water
@@ -705,19 +723,37 @@ class HashAggExecutor(Executor, Checkpointable):
             # the fused program runs (pre is traced in): restore every
             # evicted group up front — correct, if conservative
             self._cold_stacked_hook()
-        n_chunks, cap = stacked.valid.shape[:2]
-        probe = jax.eval_shape(
-            pre if pre is not None else (lambda c: c),
-            jax.tree.map(lambda a: jax.ShapeDtypeStruct(a.shape[1:], a.dtype), stacked),
-        )
-        self._maybe_grow(n_chunks * probe.valid.shape[0])
-        self._insert_bound += n_chunks * probe.valid.shape[0]
-        self._dirty_bound += n_chunks * probe.valid.shape[0]
+        lanes = self._stacked_lanes(stacked, pre)
+        self._maybe_grow(lanes)
+        self._insert_bound += lanes
+        self._dirty_bound += lanes
         with span(
-            "actor.agg_step", table_id=self.table_id, chunks=int(n_chunks)
+            "actor.agg_step",
+            table_id=self.table_id,
+            chunks=int(stacked.valid.shape[0]),
         ):
             self._step_stacked(stacked, pre, mode)
         return []
+
+    def _stacked_lanes(self, stacked: StreamChunk, pre) -> int:
+        """Lanes the batch holds once ``pre`` has run (a hop multiplies
+        them): the host's bound on what the step can insert. One
+        abstract trace of ``pre`` a chunk shape, not one an epoch."""
+        n_chunks, cap = stacked.valid.shape[:2]
+        if pre is None:
+            return n_chunks * cap
+        key = (pre, jax.tree.structure(stacked), cap)
+        lanes = self._lanes_after_pre.get(key)
+        if lanes is None:
+            probe = jax.eval_shape(
+                pre,
+                jax.tree.map(
+                    lambda a: jax.ShapeDtypeStruct(a.shape[1:], a.dtype),
+                    stacked,
+                ),
+            )
+            lanes = self._lanes_after_pre[key] = probe.valid.shape[0]
+        return n_chunks * lanes
 
     def _step_stacked(self, stacked, pre, mode) -> None:
         if self.minput:
@@ -841,8 +877,6 @@ class HashAggExecutor(Executor, Checkpointable):
         # (the fused program hands its four latches and no count)
         dropped, mret, mi_bad, claimed, *held = vals
         if self.minput and held:
-            from risingwave_tpu.metrics import REGISTRY
-
             REGISTRY.gauge("minput_values").set(
                 held[0], table_id=self.table_id
             )
@@ -1056,30 +1090,87 @@ class HashAggExecutor(Executor, Checkpointable):
         return max(1, -(-bound // self.out_cap))
 
     def _flush_all(self) -> List[StreamChunk]:
-        """INTERPRETED-path flush: exact-sliced delta chunks, one
-        packed status read per round. The fused step replaces this
-        whole method with device-side delta extraction (its program
-        flushes, slices by the host dirty bound and feeds the device
-        MV without any host read) — the contract declares it under
-        ``fallback_syncs`` so the fusion analyzer scores the read as
-        fallback-only, not a fusibility blocker. Interpreted consumers
-        (joins, host materializers) keep the tight exact slices: a
-        bound-quantized pad here would hand them padded 2*out_cap
-        chunks and multiply their per-barrier compute."""
+        """INTERPRETED-path flush: delta chunks cut to the declared
+        lattice (``_delta_to_chunk``), one packed status read per
+        round. The fused step replaces this whole method with
+        device-side delta extraction (its program flushes, slices by
+        the host dirty bound and feeds the device MV without any host
+        read) — the contract declares it under ``fallback_syncs`` so
+        the fusion analyzer scores the read as fallback-only, not a
+        fusibility blocker. Interpreted consumers (joins, host
+        materializers) pay for every lane they are handed, so the
+        slice follows the exact count the read brings anyway: the
+        span's rows / lanes is the filled share of what is handed on
+        (rows = the 2 lanes a drained group, its U-/U+ pair; a group's
+        first emission leaves its U- lane masked)."""
         outs = []
         while True:
-            self.state, delta = agg_ops.flush(
-                self.state,
-                self.table.keys,
-                self.out_cap,
-                self._float_extremes,
+            with span("agg.flush", table_id=self.table_id) as sp:
+                self.state, delta = agg_ops.flush(
+                    self.state,
+                    self.table.keys,
+                    self.out_cap,
+                    self._float_extremes,
+                )
+                n_take, overflow = np.asarray(delta["status"]).tolist()
+                chunk = self._delta_to_chunk(delta, n_take)
+                sp.args.update(rows=2 * n_take, lanes=chunk.capacity)
+            REGISTRY.counter("agg_flush_chunks_total").inc(
+                table_id=self.table_id, lanes=str(chunk.capacity)
             )
-            n_take, overflow = np.asarray(delta["status"]).tolist()
-            outs.append(self._delta_to_chunk(delta, n_take))
+            REGISTRY.counter("agg_flush_rows_total").inc(
+                2 * n_take, table_id=self.table_id
+            )
+            outs.append(chunk)
             if not overflow:
                 break
         self._dirty_bound = 0
         return outs
+
+    # -- the lattice, before it is met -----------------------------------
+    def warm_emissions(self) -> List[StreamChunk]:
+        """One all-invalid delta chunk of every lattice size, for the
+        actor's warm-up pass. A flush of a state with nothing dirty:
+        it drains no group and snapshots nothing, and the chunks are
+        what ``_flush_all`` would hand on, lane for lane."""
+        if self._dirty_bound:
+            raise RuntimeError(
+                f"{self.table_id}: warm-up after rows arrived"
+            )
+        self.state, delta = agg_ops.flush(
+            self.state, self.table.keys, self.out_cap, self._float_extremes
+        )
+        return [
+            delta_to_chunk(
+                delta, self.group_keys, self.nullable, self.calls, pad
+            )
+            for pad in self.flush_sizes()
+        ]
+
+    def warm(self, chunk: StreamChunk) -> List[StreamChunk]:
+        """``apply`` for the warm-up pass: the step's program over a
+        chunk with no valid row, which claims no slot and dirties no
+        group; no host bound moves and nothing grows."""
+        if self._would_grow(chunk.capacity):
+            return []
+        self._step(chunk)
+        return []
+
+    def warm_stacked(self, stacked: StreamChunk, pre, mode) -> None:
+        """``apply_stacked`` likewise (the epoch-batched path)."""
+        if self._would_grow(self._stacked_lanes(stacked, pre)):
+            return
+        self._step_stacked(stacked, pre, mode)
+
+    def _would_grow(self, incoming: int) -> bool:
+        """Whether ``_maybe_grow`` would rebuild the table before a
+        chunk of ``incoming`` lanes (its bound counts lanes, masked or
+        not): the step then never runs at this capacity, so the
+        warm-up does not compile it here."""
+        return (
+            self._insert_bound + incoming
+            > self.table.capacity * HARD_GROW_AT
+        )
 
     def cleaning_watermarks(self):
         """[(table_id, storage key name, cutoff)] — consumed by the
@@ -1170,18 +1261,18 @@ class HashAggExecutor(Executor, Checkpointable):
             pad = None
         else:
             # every emitted row sits in the first 2*n_take slots (dirty
-            # slots compact to the front); slice before transfer so the
-            # device->host copy is O(emitted). Quantized to exactly TWO
-            # capacities (small | full) by the shared flush-lane lattice
-            # (runtime/bucketing.flush_pad): every DOWNSTREAM device
-            # program (device MV step, join step) compiles once per
-            # distinct input capacity — pow2 bucketing here caused a
-            # recompile (~30s on TPU) on first sight of each bucket,
-            # and the fused programs' pads MUST agree with this slicer
-            # or the two paths mint disjoint compile sets.
-            from risingwave_tpu.runtime.bucketing import flush_pad
-
-            pad = flush_pad(self.out_cap, n_take)
+            # slots compact to the front); slice before handing on, to
+            # the smallest size of the declared lattice that holds
+            # them (bucketing.flush_lattice: 256, a quarter, the full 2*out_cap).
+            # Every DOWNSTREAM device program (join step, aggregate
+            # step, MV) compiles once per input capacity and walks
+            # every lane of it, masked or not: a bare pow2 of the
+            # count compiled on first sight of each size, the old
+            # {256, full} pair made 5,000 rows cost what 32,768 do.
+            # The lattice is closed, declared (trace_contract) and
+            # compiled when the view is created (the actor's
+            # warm_flush_lattice), so neither happens.
+            pad = flush_lattice_pad(self._round_cap(), n_take)
         return delta_to_chunk(
             delta, self.group_keys, self.nullable, self.calls, pad
         )

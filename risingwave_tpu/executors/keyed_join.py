@@ -255,6 +255,35 @@ class KeyedJoinExecutor(Executor, Checkpointable):
         self._admit_nulls(name, chunk)
         if name == self.unique_side:
             return self._apply_unique(name, chunk)
+        out, counts = self._many_step(name, chunk)
+        self._counts = self._counts.at[:2].add(counts)
+        return [out]
+
+    def warm_side(self, name: str, chunk: StreamChunk) -> List[StreamChunk]:
+        """``Executor.warm`` for one side's chunk. The MANY side: its
+        step over a chunk with no valid row, which stores, rewrites and
+        pairs nothing; no bound moves, nothing grows, no count or latch
+        is kept. The UNIQUE side runs nothing: what a chunk costs there
+        is a scan pass a changed row, not its lanes, and its three
+        programs a width are the slowest to build (1.3 s a width from
+        the cache, 15 s cold at 2^22 lanes, against a start: PERF.md 6,
+        PR 30), so a width that side first meets compiles then. Either
+        side admits a lane of NULL flags the chunk brings, as by any
+        chunk (the side has then seen the column: schema, not rows), so
+        that the many side's steps compiled here are the ones the
+        stream runs once the unique side has seen its own."""
+        self._admit_nulls(name, chunk)
+        side = getattr(self, name)
+        if (
+            name == self.unique_side
+            or self._bound[name] + chunk.capacity > side.capacity * GROW_AT
+        ):
+            # (_maybe_grow would read, and with this many lanes regrow
+            # the side first: its step never runs at this capacity)
+            return []
+        return [self._many_step(name, chunk)[0]]
+
+    def _many_step(self, name: str, chunk: StreamChunk):
         other = "left" if name == "right" else "right"
         many, cols, nulls, ops, valid, counts = _many_step(
             getattr(self, name),
@@ -266,8 +295,7 @@ class KeyedJoinExecutor(Executor, Checkpointable):
             cond=self._cond,
         )
         setattr(self, name, many)
-        self._counts = self._counts.at[:2].add(counts)
-        return [StreamChunk(columns=cols, valid=valid, nulls=nulls, ops=ops)]
+        return StreamChunk(columns=cols, valid=valid, nulls=nulls, ops=ops), counts
 
     def _apply_unique(self, name: str, chunk: StreamChunk) -> List[StreamChunk]:
         other = "left" if name == "right" else "right"

@@ -133,6 +133,11 @@ class MaterializeExecutor(Executor, Checkpointable):
         with span("mv.apply", stage="actor.mv_apply", table_id=self.table_id):
             return self._apply(chunk)
 
+    def warm(self, chunk: StreamChunk) -> List[StreamChunk]:
+        """Executor.warm: the chunk's copy to the host; with no valid
+        row ``_apply`` returns before it touches the map."""
+        return self._apply(chunk)
+
     def _apply(self, chunk: StreamChunk) -> List[StreamChunk]:
         with span("mv.to_numpy"):
             data = chunk.to_numpy(with_ops=True)
@@ -735,6 +740,18 @@ class DeviceMaterializeExecutor(MvDeviceReadMixin, Executor, Checkpointable):
         self.table, self.state = _mv_step(
             self.table, self.state, chunk, self.pk, self.columns
         )
+        return [chunk]
+
+    def warm(self, chunk: StreamChunk):
+        """Executor.warm: the step alone — no bound, no growth; not
+        even the step where ``_maybe_grow`` would rebuild the table
+        before a chunk of these lanes (it never runs at this
+        capacity)."""
+        cap = self.table.capacity
+        if min(self._bound, cap) + chunk.capacity <= cap * HARD_GROW_AT:
+            self.table, self.state = _mv_step(
+                self.table, self.state, chunk, self.pk, self.columns
+            )
         return [chunk]
 
     def _maybe_grow(self, incoming: int) -> None:

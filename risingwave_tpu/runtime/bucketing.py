@@ -50,6 +50,8 @@ __all__ = [
     "BucketPolicy",
     "ShapeGovernor",
     "emission_bucket",
+    "flush_lattice",
+    "flush_lattice_pad",
     "flush_pad",
     "flush_pad_schedule",
     "lattice_between",
@@ -129,17 +131,60 @@ def prefix_pad(k: int, capacity: int) -> int:
     return min(capacity, pow2_at_least(max(k, DELTA_BLOCK)))
 
 
-def flush_pad(out_cap: int, emitted_bound: int) -> int:
-    """The agg-flush emission lattice: one delta chunk's capacity,
-    quantized to exactly TWO buckets (small | full) from a bound on
-    its emitted rows. Every consumer of a flush lane — the interpreted
-    exact slicer (hash_agg._delta_to_chunk), the fused single-input
-    program and the fused two-input join programs — draws pads from
-    THIS function, so the flush-lane shape family is one closed
-    {small, full} pair per out_cap and the downstream compile set
-    cannot drift apart between paths."""
+FLUSH_SMALL = 256
+
+
+def flush_lattice(out_cap: int) -> Tuple[int, ...]:
+    """The interpreted flush's declared chunk sizes (PR 30): 256 lanes
+    (the empty and the near-empty barrier), a quarter of the full size,
+    and the full ``2 * out_cap`` — 256 / 16,384 / 65,536 at ``out_cap``
+    2^15. It is what ``HashAggExecutor`` declares as ``emission_caps``
+    / ``window_buckets``, and every size of it is compiled when a
+    graph-mode view is created (the actor's ``warm_flush_lattice``), so
+    a size first met inside a stream opens no compile.
+
+    One x4 step down from the full size, not the whole ladder to 256
+    (the issue's 1,024 and 4,096): the chip priced a declared size at
+    about 2.3 s of every start of a q5-like view (some eleven programs,
+    traced, lowered and loaded, the sub-second ones compiled again:
+    PERF.md 6, PR 30) against a bound of a quarter of a 34 s start,
+    and of one extra size the quarter wastes least: at most 4x padding
+    from 2,049 groups up, and under that the steps behind a
+    16,384-lane chunk cost a fraction of what the full one's did."""
     full = 2 * int(out_cap)
-    small = min(256, full)
+    small = min(FLUSH_SMALL, full)
+    return tuple(sorted({small, max(small, full // 4), full}))
+
+
+def flush_lattice_pad(out_cap: int, n_take: int) -> int:
+    """Lanes the interpreted slicer (hash_agg._delta_to_chunk) cuts a
+    flush round's delta to: the smallest size of ``flush_lattice`` that
+    holds the round's ``2 * n_take`` head lanes (``agg_ops.flush``
+    interleaves (old, new) rows at the front). ``n_take`` is the exact
+    count the round's status read brings to the host anyway; a round
+    that overflowed took ``out_cap`` groups and so the full size."""
+    need = 2 * int(n_take)
+    return next(s for s in flush_lattice(out_cap) if s >= need)
+
+
+def flush_pad(out_cap: int, emitted_bound: int) -> int:
+    """The FUSED barrier programs' flush pad: one delta chunk's
+    capacity, quantized to exactly TWO buckets (small | full) from a
+    BOUND on its emitted rows. The fused single-input program and the
+    fused two-input join programs draw their pads from this pair
+    (``flush_pad_schedule``; fused_step's single-input schedule spells
+    the same rule out), because they know only the host dirty bound,
+    which is too loose to pick a small size, and bake every round's
+    pad into one executable: each extra size would multiply those.
+
+    The interpreted slicer knows the exact count and follows
+    ``flush_lattice`` instead (PR 30). The two used to share this pair
+    so that "the downstream compile set cannot drift apart between
+    paths"; a fragment is either fused or interpreted, so the two
+    compile sets were never shared, and the separation is deliberate
+    (tests/test_shape_stability.py pins both)."""
+    full = 2 * int(out_cap)
+    small = min(FLUSH_SMALL, full)
     return small if 2 * int(emitted_bound) <= small else full
 
 

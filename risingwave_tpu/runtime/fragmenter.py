@@ -304,9 +304,14 @@ class GraphPipeline(FreshnessSurface):
         self._specs = list(specs)
         self._epoch_batch = epoch_batch
         self._label: Optional[str] = None
-        self.graph = GraphRuntime(
-            self._specs, epoch_batch=epoch_batch
-        ).start()
+        # a new view: every aggregate's flush lattice is compiled here,
+        # inside its creation (a rebuild finds the programs in the
+        # process and its executors mid-stream: it does not warm)
+        self.graph = (
+            GraphRuntime(self._specs, epoch_batch=epoch_batch)
+            .warm_flush_lattices()
+            .start()
+        )
         self._sources = dict(source_map)
         self._out = out_fragment
         self._executors = list(ckpt_executors)

@@ -894,8 +894,14 @@ class FusedChainExecutor(Executor):
             out_cap = self.plan.agg.out_cap
             bound = min(self.agg._dirty_bound, self.agg.table.capacity)
             flush_rounds = max(1, -(-bound // out_cap))
-            # the SAME two-bucket slice quantization the interpreted
-            # _flush_all applies, from the same host dirty bound
+            # the fused pads: {small, full} (bucketing.flush_pad's
+            # rule), from the host dirty bound. The interpreted
+            # _flush_all used to share this pair; since PR 30 it cuts
+            # to bucketing.flush_lattice from the exact count it
+            # reads. This program knows only the bound, too loose to
+            # pick a small size, and bakes every round's pad into one
+            # executable, so it keeps the pair: a fragment is either
+            # fused or interpreted, the two compile sets never meet
             full = 2 * out_cap
             small = min(256, full)
             pads = tuple(

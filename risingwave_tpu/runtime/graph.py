@@ -52,6 +52,7 @@ from risingwave_tpu.runtime.pipeline import (
     _side_watermark,
     _walk_watermark,
     walk_chain,
+    warm_chain,
 )
 from risingwave_tpu.trace import add_stage, bind, close_epoch, span
 
@@ -416,6 +417,83 @@ class FragmentActor(threading.Thread):
     def _emit(self, chunks: Sequence[StreamChunk]) -> None:
         for c in chunks:
             self.dispatcher.dispatch(c)
+
+    # -- the flush lattice, before a stream meets it ----------------------
+    def warm_flush_lattice(self) -> None:
+        """Compile every size of every aggregate's flush lattice now,
+        while the view is being created: each executor that declares
+        one (``Executor.warm_emissions``: a chunk with no valid row of
+        each ``emission_caps`` size) has them sent down what follows it
+        inside this actor, the way its barrier flush goes — the rest of
+        its chain, for a join's side the join and the tail, for the
+        shared head both sides — through ``Executor.warm``, which runs
+        a step's programs and leaves no mark: no row stored, no group
+        dirtied, no host bound advanced, no table grown, nothing for a
+        checkpoint to stage. A size first met inside a stream (a thin
+        epoch, a fat one) then opens no compile, which on the chip is
+        a barrier of tens of seconds. Innermost executors first, so
+        that a join has seen the NULL flags of a later aggregate's
+        output before an earlier one's chunks compile its steps.
+
+        Not reached: what lies past the dispatcher (another actor's
+        chain), past an executor that does not know ``warm``, a keyed
+        join's unique side (``KeyedJoinExecutor.warm_side`` says why),
+        and the programs a grown table compiles anew. A method and no
+        setting: nothing but ``GraphPipeline``'s construction calls it,
+        and a test that wants the plan without it skips the call."""
+        if self.join_exec is None:
+            sections = [("tail", self.chain)]
+        else:
+            sections = [
+                ("tail", self.tail),
+                ("right", self.right_chain),
+                ("left", self.chain),
+                ("head", self.head),
+            ]
+        for section, chain in sections:
+            for i in reversed(range(len(chain))):
+                # (a chain may hold duck-typed executors: no Executor base)
+                emissions = getattr(chain[i], "warm_emissions", None)
+                chunks = emissions() if emissions is not None else ()
+                if not chunks:
+                    continue
+                with span(
+                    "actor.warm",
+                    actor=self.actor_name,
+                    executor=type(chain[i]).__name__,
+                    lanes=[c.capacity for c in chunks],
+                ):
+                    for c in chunks:
+                        self._warm_tap(c)
+                    self._warm_from(section, chain[i + 1 :], chunks)
+
+    def _warm_from(self, section: str, chain, chunks) -> None:
+        """Warm-up chunks down the rest of a chain and on through what
+        the section feeds inside this actor."""
+        outs = warm_chain(chain, chunks, tap=self._warm_tap)
+        if outs is None or section == "tail":
+            return
+        if section == "head":
+            self._warm_from("left", self.chain, outs)
+            self._warm_from("right", self.right_chain, outs)
+            return
+        warm_side = getattr(self.join_exec, "warm_side", None)
+        if warm_side is None:
+            return
+        joined = [j for c in outs for j in warm_side(section, c)]
+        for c in joined:
+            self._warm_tap(c)
+        warm_chain(self.tail, joined, tap=self._warm_tap)
+
+    @staticmethod
+    def _warm_tap(chunk: StreamChunk) -> None:
+        """``_tap``'s two programs for a chunk of this shape (an epoch's
+        first chunk, and a later one); the sums are dropped."""
+        _add_edge_rows(
+            _add_edge_rows(None, chunk.valid, chunk.ops),
+            chunk.valid,
+            chunk.ops,
+        )
 
     def _sides(self):
         """A join's inputs in the order they are fed (port order)."""
@@ -1167,6 +1245,14 @@ class GraphRuntime:
                     self._edge_disp[(up_name, ui, s.name, o)].outputs = (
                         list(chans)
                     )
+
+    def warm_flush_lattices(self) -> "GraphRuntime":
+        """Every actor's ``warm_flush_lattice``, on the caller's thread
+        and before ``start``: the view's creation pays for the compiles
+        and no actor thread has met a chunk yet."""
+        for a in self.actors:
+            a.warm_flush_lattice()
+        return self
 
     def start(self) -> "GraphRuntime":
         for a in self.actors:

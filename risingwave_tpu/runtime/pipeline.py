@@ -120,6 +120,27 @@ def walk_chain(chain: Sequence[Executor], chunks, barrier=None, tap=None):
     return pending
 
 
+def warm_chain(chain: Sequence[Executor], chunks, tap=None):
+    """``walk_chain`` for the warm-up pass: chunks with no valid row
+    through every executor's ``warm`` (``Executor.warm``: the programs
+    of ``apply``, and no mark). None once an executor does not know
+    how: the pass stops there."""
+    pending = list(chunks)
+    for ex in chain:
+        warm = getattr(ex, "warm", None)  # duck-typed executors have none
+        nxt: List[StreamChunk] = []
+        for c in pending:
+            out = warm(c) if warm is not None else None
+            if out is None:
+                return None
+            nxt.extend(out)
+        if tap is not None:
+            for c in nxt:
+                tap(c)
+        pending = nxt
+    return pending
+
+
 def _pcall(ex, phase, fn, *args):
     """Dispatch-attributed call for executor entry points OUTSIDE
     walk_chain (join apply_left/right, on_barrier in two-input shapes)

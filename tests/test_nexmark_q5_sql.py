@@ -64,13 +64,13 @@ def hot_items(auction, date_time):
 
 
 class Served:
-    def __init__(self, state_dir, chunk):
+    def __init__(self, state_dir, chunk, capacity=1 << 12):
         self.chunk = chunk
         self.rt = StreamingRuntime(
             LocalFsObjectStore(str(state_dir)), checkpoint_frequency=1
         )
         self.session = SqlSession(
-            Catalog({}), self.rt, capacity=1 << 12, exec_mode="graph"
+            Catalog({}), self.rt, capacity=capacity, exec_mode="graph"
         )
         self.session.execute(BID_DDL)
         self.session.execute(Q5)

@@ -19,6 +19,10 @@ from risingwave_tpu.runtime.bucketing import (
     BucketPolicy,
     ShapeGovernor,
     emission_bucket,
+    flush_lattice,
+    flush_lattice_pad,
+    flush_pad,
+    flush_pad_schedule,
     lattice_between,
     padding_stats,
     pow2_at_least,
@@ -305,6 +309,94 @@ def test_emission_mask_exactly_full_and_one_over():
     assert out5.capacity == 8
     assert int(np.asarray(out5.valid).sum()) == 5
     assert sorted(out5.to_numpy()["k"].tolist()) == [0, 1, 2, 3, 4]
+
+
+# ---------------------------------------------------------------------------
+# the flush lattice (PR 30): the interpreted slicer follows the count on
+# a declared lattice (256, a quarter of the full size, the full size); the
+# fused programs keep their {small, full} pair
+# ---------------------------------------------------------------------------
+
+_LATTICES = {
+    128: (256,),
+    1 << 15: (256, 16384, 65536),
+}
+
+
+def _edge_counts():
+    """(out_cap, n_take) on and either side of every edge of the
+    lattice, the empty round and the overflowed one."""
+    cases = []
+    for out_cap, lattice in _LATTICES.items():
+        ns = {0, 1, out_cap - 1, out_cap}
+        for size in lattice[:-1]:
+            ns |= {size // 2 - 1, size // 2, size // 2 + 1}
+        cases += [(out_cap, n) for n in sorted(ns) if 0 <= n <= out_cap]
+    return cases
+
+
+@pytest.mark.parametrize("out_cap,n_take", _edge_counts())
+def test_flush_pad_is_the_smallest_declared_size(out_cap, n_take):
+    lattice = flush_lattice(out_cap)
+    assert lattice == _LATTICES[out_cap]
+    assert validate_lattice(lattice) is None
+    pad = flush_lattice_pad(out_cap, n_take)
+    assert pad == min(s for s in lattice if s >= 2 * n_take)
+    if n_take == out_cap:  # an overflowed round is full, as before
+        assert pad == 2 * out_cap
+
+
+@pytest.mark.parametrize("out_cap,n_take", _edge_counts())
+def test_fused_pads_stay_small_or_full(out_cap, n_take):
+    """The fused barrier programs draw their pads from a BOUND and bake
+    them into one executable: they keep the {256, full} pair, on
+    purpose, whatever the interpreted slicer does with the count."""
+    full = 2 * out_cap
+    small = min(256, full)
+    assert flush_pad(out_cap, n_take) == (
+        small if 2 * n_take <= small else full
+    )
+    capacity = 4 * out_cap
+    for bound in (n_take, out_cap + n_take, 10 * capacity):
+        left = min(bound, capacity)
+        want = []
+        while True:
+            want.append(flush_pad(out_cap, min(left, out_cap)))
+            left -= out_cap
+            if left <= 0:
+                break
+        pads = flush_pad_schedule(bound, capacity, out_cap)
+        assert pads == tuple(want) and set(pads) <= {small, full}
+
+
+@pytest.mark.parametrize("out_cap", sorted(_LATTICES))
+def test_agg_declares_and_cuts_to_the_flush_lattice(out_cap):
+    from risingwave_tpu.executors.hash_agg import HashAggExecutor
+    from risingwave_tpu.ops.agg import AggCall
+
+    agg = HashAggExecutor(
+        ("k",), (AggCall("count_star", None, "n"),), {"k": I64},
+        capacity=max(2 * out_cap, 1 << 10), out_cap=out_cap,
+    )
+    contract = agg.trace_contract()
+    assert contract["emission_caps"] == _LATTICES[out_cap]
+    assert contract["window_buckets"] == _LATTICES[out_cap]
+    assert [c.capacity for c in agg.warm_emissions()] == list(
+        _LATTICES[out_cap]
+    )
+    for groups in {1, 100, 129, min(8200, out_cap)}:
+        groups = min(groups, out_cap)
+        ks = np.arange(groups, dtype=np.int64)
+        agg.apply(StreamChunk.from_numpy({"k": ks}, pow2_at_least(groups)))
+        (out,) = agg.on_barrier(None)
+        assert out.capacity == flush_lattice_pad(out_cap, groups)
+        assert int(np.asarray(out.valid).sum()) >= groups
+    # a table smaller than out_cap drains at most itself a round
+    small = HashAggExecutor(
+        ("k",), (AggCall("count_star", None, "n"),), {"k": I64},
+        capacity=1 << 10, out_cap=1 << 15,
+    )
+    assert small.trace_contract()["emission_caps"] == (256, 512, 2048)
 
 
 def test_padding_stats_accounting():
