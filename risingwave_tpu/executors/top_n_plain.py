@@ -14,7 +14,7 @@ so per-barrier host traffic is O(n), not O(state).
 
 from __future__ import annotations
 
-from functools import partial
+from functools import partial, reduce
 from typing import Dict, List, Optional, Sequence, Tuple
 
 import jax
@@ -23,6 +23,7 @@ import numpy as np
 
 from risingwave_tpu.array.chunk import StreamChunk
 from risingwave_tpu.executors.base import Barrier, Executor
+from risingwave_tpu.metrics import REGISTRY
 from risingwave_tpu.ops.hash_table import (
     HashTable,
     lookup_or_insert,
@@ -44,6 +45,7 @@ from risingwave_tpu.storage.state_table import (
     pull_rows,
     stage_marks,
 )
+from risingwave_tpu.trace import span
 from risingwave_tpu.types import Op
 
 GROW_AT = 0.5
@@ -382,120 +384,290 @@ def _upsert_step_ed(table, rows, sdirty, epoch_dirty, chunk, pk, names):
     return table, rows, sdirty, epoch_dirty, dropped
 
 
-@partial(
-    jax.jit,
-    static_argnames=("k", "desc", "group_names", "order_col"),
-    donate_argnums=(),
-)
-def _group_topk_mask(
-    table: HashTable,
-    rows: Dict[str, jnp.ndarray],
-    epoch_dirty: jnp.ndarray,
-    k: int,
-    desc: bool,
-    group_names: Tuple[str, ...],
-    order_col: str,
-):
-    """Per-slot masks: is the row in its group's current top-k, and
-    does its group contain an epoch-dirty row (so its top-k must be
-    re-pulled)? One device sort over (group lanes, order key, pk)."""
+# The barrier of the retractable GroupTopN, on the device. A row store
+# that keeps every input row ranks all of its lanes once a barrier (one
+# sort over (group, liveness, order key, stream key)); what the barrier
+# then needs is the difference between the rows that are in their
+# group's top-k NOW and the rows the executor has handed on (the lane
+# ``emitted``), and that difference is two masks in the sorted order.
+# So the rows to retract and the rows to insert are compacted and
+# gathered on the device too (``_diff_gather``), into two chunks of
+# ``out_lanes`` lanes, and the host reads eight counts. ``shadow`` keeps
+# every column as it was when the row was last handed on: an UPDATE
+# overwrites a stored row in place, and its retraction has to carry the
+# old values.
+_SLOT_MASK = (1 << 27) - 1  # a slot (ABS_MAX_CAP is 2^26), under four flags
+_EMITTED_BIT, _DIRTY_BIT, _REDO_BIT, _LIVE_BIT = 30, 29, 28, 27
+_EMIT_FLOOR = 1 << 14  # the smallest emission size, x4 steps above it
+
+
+def _digits(lane) -> Tuple[jnp.ndarray, ...]:
+    """A key lane as unsigned 32-bit digits, least significant first,
+    whose lexicographic order (most significant first) is the lane's."""
+    if lane.dtype.itemsize <= 4 and not jnp.issubdtype(
+        lane.dtype, jnp.floating
+    ):
+        if jnp.issubdtype(lane.dtype, jnp.unsignedinteger) or (
+            lane.dtype == jnp.bool_
+        ):
+            return (lane.astype(jnp.uint32),)
+        return (
+            jax.lax.bitcast_convert_type(lane.astype(jnp.int32), jnp.uint32)
+            ^ jnp.uint32(1 << 31),
+        )
+    key = _order_key_u64(lane, False)
+    return (
+        (key & jnp.uint64(0xFFFFFFFF)).astype(jnp.uint32),
+        (key >> jnp.uint64(32)).astype(jnp.uint32),
+    )
+
+
+def _packed_words(digits):
+    """The digits (least significant first) as ONE integer a lane,
+    packed: each digit gives only the bits its values' range over the
+    lanes needs (none when every lane shares it: the high half of most
+    64-bit ids and of a run's event times), most significant digit on
+    top, cut into 32-bit words, least significant first. The order of
+    the lanes by that integer is their order by the digits, and it has
+    as many words that differ between lanes as the key has bits of
+    information, rounded up to 32: three for q18's nine digits.
+    Returns (as many words as digits; the bit each digit starts at)."""
+    low = [jnp.min(d) for d in digits]
+    offsets = [jnp.zeros((), jnp.int32)]
+    for d, lo in zip(digits, low):
+        width = 32 - jax.lax.clz(jnp.max(d) - lo).astype(jnp.int32)
+        offsets.append(offsets[-1] + width)
+    words = []
+    for w in range(len(digits)):
+        word = jnp.zeros_like(digits[0])
+        for d, lo, off in zip(digits, low, offsets):
+            at, shift = off // 32, (off % 32).astype(jnp.uint32)
+            v = d - lo
+            # the digit's low part in its own word, what the shift
+            # pushed out in the next
+            spill = jnp.where(shift > 0, v >> (32 - jnp.maximum(shift, 1)), 0)
+            word = word | jnp.where(at == w, v << shift, 0)
+            word = word | jnp.where(at + 1 == w, spill, 0)
+        words.append(word)
+    return tuple(words), offsets
+
+
+def _sort_by_words(words, payload):
+    """``words`` (least significant first) and ``payload`` in the
+    lanes' order by the words: one stable sort a word, keyed on it and
+    carrying every other word and the payload along, in a loop, so the
+    program holds ONE sort whatever the key is made of. (A sort keyed on
+    all of q18's key lanes at once, six operands and 64-bit compares,
+    took the TPU's compiler ten minutes at 2^21 lanes; a loop of
+    two-operand sorts that gathered each digit into the current order
+    compiled in half a minute and spent three quarters of its time in
+    the gathers: 40 ms a gather of 2^22 32-bit lanes against 12 ms a
+    sort.) A word every lane shares is skipped on the device. Returns
+    (words, payload, sorts made)."""
+    n = len(words)
+
+    def one(_, carry):
+        ops, passes = carry
+
+        def sort(ops):
+            return jax.lax.sort(ops, num_keys=1, is_stable=True)
+
+        shared = jnp.all(ops[0] == ops[0][0])
+        ops = jax.lax.cond(shared, lambda ops: ops, sort, ops)
+        passes = passes + (~shared).astype(jnp.int32)
+        # the next word to the front; after ``n`` turns all are back
+        return ops[1:n] + ops[:1] + ops[n:], passes
+
+    ops, passes = jax.lax.fori_loop(
+        0, n, one, (tuple(words) + (payload,), jnp.zeros((), jnp.int32))
+    )
+    return ops[:n], ops[n], passes
+
+
+def _rank_sorted(table: HashTable, order_lane, flags, k, desc, n_group):
+    """Every lane ranked by (group lanes, dead last, order key, the
+    rest of the stream key). Returns, in sorted order: each lane's slot
+    with ``flags`` (bits above ``_SLOT_MASK``) carried along, whether it
+    is in its group's top-k, the position its group starts at, and the
+    sorts the ranking took."""
     cap = table.capacity
     # liveness as its own sort key within the group (a dead-row
     # sentinel would collide with INT64-extreme order values)
-    live_last = (~table.live).astype(jnp.int32)
-    okey = _order_key_u64(rows[order_col], desc)
-    glanes = tuple(rows[g] for g in group_names)
-    sort_in = glanes + (live_last, okey) + tuple(table.keys) + (
-        jnp.arange(cap, dtype=jnp.int32),
+    live_last = (~table.live).astype(jnp.uint32)
+    okey = _order_key_u64(order_lane, desc)
+    digits: Tuple[jnp.ndarray, ...] = ()
+    for lane in reversed(table.keys[n_group:]):
+        digits += _digits(lane)
+    digits += _digits(okey) + (live_last,)
+    n_below = len(digits)  # the digits below the group's
+    for lane in reversed(table.keys[:n_group]):
+        digits += _digits(lane)
+    words, offsets = _packed_words(digits)
+    flags = flags | (table.live.astype(jnp.int32) << _LIVE_BIT)
+    words, packed_s, passes = _sort_by_words(
+        words, jnp.arange(cap, dtype=jnp.int32) | flags
     )
-    sorted_all = jax.lax.sort(
-        sort_in, num_keys=len(glanes) + 2 + len(table.keys)
-    )
-    slot_s = sorted_all[-1]
-    live_s = table.live[slot_s]
-    dirty_s = epoch_dirty[slot_s]
-    boundary = jnp.zeros(cap, jnp.bool_).at[0].set(True)
-    for lane in sorted_all[: len(glanes)]:
-        boundary = boundary | jnp.concatenate(
-            [jnp.ones(1, jnp.bool_), lane[1:] != lane[:-1]]
+    # a group starts where a bit of the group's digits differs from the
+    # lane before: the bits from ``offsets[n_below]`` up
+    group_bit = offsets[n_below]
+    differs = jnp.zeros(cap - 1, jnp.bool_)
+    for w, word in enumerate(words):
+        below = jnp.clip(group_bit - 32 * w, 0, 32).astype(jnp.uint32)
+        mask = jnp.where(
+            below >= 32,
+            jnp.uint32(0),
+            ~((jnp.uint32(1) << jnp.minimum(below, 31)) - jnp.uint32(1)),
         )
-    gid = jnp.cumsum(boundary.astype(jnp.int32)) - 1
-    idx = jnp.arange(cap, dtype=jnp.int32)
-    seg_start = jax.ops.segment_max(
-        jnp.where(boundary, idx, 0), gid, num_segments=cap
-    )[gid]
-    in_topk_s = live_s & ((idx - seg_start) < k)
-    gdirty_s = (
-        jax.ops.segment_max(
-            dirty_s.astype(jnp.int32), gid, num_segments=cap
-        )[gid]
-        > 0
-    )
-    in_topk = jnp.zeros(cap, jnp.bool_).at[slot_s].set(in_topk_s)
-    gdirty = jnp.zeros(cap, jnp.bool_).at[slot_s].set(gdirty_s)
-    return in_topk, gdirty
+        differs = differs | (((word[1:] ^ word[:-1]) & mask) != 0)
+    boundary = jnp.concatenate([jnp.ones(1, jnp.bool_), differs])
+    pos = jnp.arange(cap, dtype=jnp.int32)
+    seg_start = jax.lax.cummax(jnp.where(boundary, pos, 0))
+    live_s = ((packed_s >> _LIVE_BIT) & 1) > 0
+    in_topk_s = live_s & ((pos - seg_start) < k)
+    return packed_s, in_topk_s, seg_start, passes
 
 
-def _diff_touched_groups(
-    table, rows, in_topk, epoch_dirty, group_by, pk, names, gdirty,
-    emitted,
+def _compact(mask, out_lanes: int):
+    """Positions of the first ``out_lanes`` set lanes of ``mask`` (in
+    order), how many are set, and which output lanes hold one."""
+    csum = jnp.cumsum(mask.astype(jnp.int32))
+    n = csum[-1]
+    want = jnp.arange(1, out_lanes + 1, dtype=jnp.int32)
+    pos = jnp.searchsorted(csum, want, side="left").astype(jnp.int32)
+    valid = want <= n
+    return jnp.where(valid, pos, 0), n, valid
+
+
+@partial(jax.jit, static_argnames=("k", "desc", "n_group", "order_col"))
+def _rank(
+    table: HashTable,
+    rows: Dict[str, jnp.ndarray],
+    shadow: Dict[str, jnp.ndarray],
+    emitted: jnp.ndarray,
+    epoch_dirty: jnp.ndarray,
+    k: int,
+    desc: bool,
+    n_group: int,
+    order_col: str,
 ):
-    """Pull touched groups' top-k (+ the epoch-dirty rows naming
-    fully-emptied groups) and diff against the host mirror of what was
-    emitted; updates ``emitted`` in place. Shared by the single-chip
-    and the sharded executor (one shard = one call over its slices)."""
-    mask = np.asarray((gdirty & in_topk) | epoch_dirty)
-    sel = np.flatnonzero(mask)
-    lanes = {n: rows[n] for n in names}
-    lanes["__topk__"] = in_topk
-    lanes["__live__"] = table.live
-    pulled = pull_rows(lanes, sel)
-    new_top: Dict[Tuple, Dict[Tuple, Tuple]] = {}
-    changed: set = set()
-    for i in range(len(sel)):
-        g = tuple(pulled[c][i].item() for c in group_by)
-        changed.add(g)
-        if pulled["__topk__"][i] and pulled["__live__"][i]:
-            pkv = tuple(pulled[c][i].item() for c in pk)
-            new_top.setdefault(g, {})[pkv] = tuple(
-                pulled[n][i].item() for n in names
-            )
-    dels, ins = [], []
-    for g in changed:
-        old = emitted.get(g, {})
-        new = new_top.get(g, {})
-        dels.extend(v for p, v in old.items() if new.get(p) != v)
-        ins.extend(v for p, v in new.items() if old.get(p) != v)
-        if new:
-            emitted[g] = new
-        else:
-            emitted.pop(g, None)
-    return dels, ins
+    """The barrier's first program, one per store capacity: every lane
+    ranked, with what the diff needs carried along. Returns, in sorted
+    order, (slot | flags, in its group's top-k, where its group starts)
+    and the sorts made."""
+    redo = epoch_dirty & _any_differs(rows, shadow)
+    flags = (
+        (emitted.astype(jnp.int32) << _EMITTED_BIT)
+        | (epoch_dirty.astype(jnp.int32) << _DIRTY_BIT)
+        | (redo.astype(jnp.int32) << _REDO_BIT)
+    )
+    return _rank_sorted(table, rows[order_col], flags, k, desc, n_group)
 
 
-def _emit_diffs(dels, ins, names, dtypes, bucketed=True) -> List[StreamChunk]:
-    outs = []
-    for vals, op in ((dels, Op.DELETE), (ins, Op.INSERT)):
-        if not vals:
-            continue
-        cols = {
-            n: np.asarray([r[j] for r in vals], dtypes[n])
-            for j, n in enumerate(names)
-        }
-        outs.append(
-            StreamChunk.from_numpy(
-                cols,
-                # pow2-padded emission (masked lanes): downstream sees
-                # a log-bounded capacity set, not one per delta count;
-                # the bucketed=False twin keeps the legacy max(2, n)
-                # shape per distinct count (RW-E803 baseline behavior)
-                emission_bucket(len(vals))
-                if bucketed
-                else max(2, len(vals)),
-                ops=np.full(len(vals), int(op), np.int32),
-            )
+@partial(jax.jit, static_argnames=("out_lanes",), donate_argnums=(2, 3))
+def _diff_gather(
+    table: HashTable,
+    rows: Dict[str, jnp.ndarray],
+    shadow: Dict[str, jnp.ndarray],
+    emitted: jnp.ndarray,
+    ranked,
+    dropped: jnp.ndarray,
+    out_lanes: int,
+):
+    """The barrier's second program, one per emission size: the ranking
+    diffed against what was handed on, and both deltas gathered.
+
+    A row is retracted when it was handed on and is no longer in its
+    group's top-k, or was rewritten this epoch with other values
+    (``redo``: then it is inserted again too); a row is inserted when
+    it is in the top-k and was not handed on. Only a group an epoch
+    touched can differ, and a touched row displaces or promotes at most
+    one other, so neither delta passes the lanes the epoch's chunks
+    held: ``out_lanes`` is sized from that on the host, and ``status``
+    says if it ever did not hold (a raise, not a silent cut).
+
+    Returns (emitted, shadow, retractions, insertions, status) with
+    status = [retract rows, insert rows, touched groups, overflow,
+    dropped latch, slots claimed, live rows, sorts made]."""
+    cap = table.capacity
+    packed_s, in_topk_s, seg_start, passes = ranked
+    slot_s = packed_s & _SLOT_MASK
+    emitted_s = ((packed_s >> _EMITTED_BIT) & 1) > 0
+    dirty_s = ((packed_s >> _DIRTY_BIT) & 1) > 0
+    redo_s = ((packed_s >> _REDO_BIT) & 1) > 0
+    ret_s = emitted_s & (~in_topk_s | redo_s)
+    ins_s = in_topk_s & (~emitted_s | redo_s)
+    # groups that hold a dirty row: the dirty rows with no dirty row
+    # before them in their group
+    pos = jnp.arange(cap, dtype=jnp.int32)
+    last_dirty = jax.lax.cummax(jnp.where(dirty_s, pos, -1))
+    before = jnp.concatenate([jnp.full(1, -1, jnp.int32), last_dirty[:-1]])
+    groups = jnp.sum((dirty_s & (before < seg_start)).astype(jnp.int32))
+
+    ret_pos, n_ret, ret_valid = _compact(ret_s, out_lanes)
+    ins_pos, n_ins, ins_valid = _compact(ins_s, out_lanes)
+    ret_slot = jnp.where(ret_valid, slot_s[ret_pos], cap)
+    ins_slot = jnp.where(ins_valid, slot_s[ins_pos], cap)
+    ret_cols = {n: a.at[ret_slot].get(mode="fill", fill_value=0)
+                for n, a in shadow.items()}
+    ins_cols = {n: a.at[ins_slot].get(mode="fill", fill_value=0)
+                for n, a in rows.items()}
+    emitted = emitted.at[ret_slot].set(False, mode="drop")
+    emitted = emitted.at[ins_slot].set(True, mode="drop")
+    shadow = {
+        n: a.at[ins_slot].set(ins_cols[n], mode="drop")
+        for n, a in shadow.items()
+    }
+    status = jnp.stack(
+        [
+            n_ret,
+            n_ins,
+            groups,
+            ((n_ret > out_lanes) | (n_ins > out_lanes)).astype(jnp.int32),
+            dropped.astype(jnp.int32),
+            table.occupancy(),
+            table.num_live(),
+            passes,
+        ]
+    )
+    chunks = tuple(
+        StreamChunk(
+            columns=cols,
+            valid=valid,
+            nulls={},
+            ops=jnp.full(out_lanes, int(op), jnp.int32),
         )
-    return outs
+        for cols, valid, op in (
+            (ret_cols, ret_valid, Op.DELETE),
+            (ins_cols, ins_valid, Op.INSERT),
+        )
+    )
+    return emitted, shadow, chunks[0], chunks[1], status
+
+
+def _any_differs(rows, shadow):
+    """Per lane: does any column of ``rows`` differ from ``shadow``'s."""
+    return reduce(jnp.logical_or, (a != shadow[n] for n, a in rows.items()))
+
+
+@partial(jax.jit, static_argnames=("k", "desc", "n_group"))
+def _topk_mask(table: HashTable, order_lane, k: int, desc: bool, n_group: int):
+    """Per slot: is the row in its group's top-k (a restore's rebuild
+    of ``emitted``; the barrier never leaves the sorted order)."""
+    cap = table.capacity
+    packed_s, in_topk_s, _, _ = _rank_sorted(
+        table, order_lane, jnp.zeros(cap, jnp.int32), k, desc, n_group
+    )
+    return jnp.zeros(cap, jnp.bool_).at[packed_s & _SLOT_MASK].set(in_topk_s)
+
+
+def emission_lanes(epoch_lanes: int, capacity: int) -> int:
+    """Lanes of the two chunks a barrier hands on: the smallest of
+    ``_EMIT_FLOOR`` x 4^i that holds the lanes the epoch's chunks held
+    (what bounds either delta), and never more than the store."""
+    lanes = _EMIT_FLOOR
+    while lanes < epoch_lanes:
+        lanes *= 4
+    return min(lanes, capacity)
 
 
 class RetractableGroupTopNExecutor(Executor, Checkpointable):
@@ -504,11 +676,12 @@ class RetractableGroupTopNExecutor(Executor, Checkpointable):
     boundary re-emit the displaced/promoted rows exactly.
 
     TPU re-design: ONE pk-keyed row store holds every input row; the
-    barrier ranks rows within groups on device (one fused sort +
-    segmented scan), pulls only the top-k rows of groups TOUCHED this
-    epoch, and diffs them against a per-group host mirror of what was
-    emitted — per-barrier host traffic is O(changed groups x k), never
-    O(state)."""
+    barrier ranks rows within groups on device (one sort + segmented
+    scans), diffs the ranking against the lane of rows it has handed
+    on (``emitted``; their values as handed on in ``shadow``) and
+    gathers the rows to retract and to insert into two chunks of a
+    declared size, in two programs (``_rank``, ``_diff_gather``). The
+    host reads eight counts a barrier and walks no row."""
 
     def __init__(
         self,
@@ -553,6 +726,16 @@ class RetractableGroupTopNExecutor(Executor, Checkpointable):
         self.sdirty = jnp.zeros(capacity, jnp.bool_)
         self.stored = jnp.zeros(capacity, jnp.bool_)
         self.epoch_dirty = jnp.zeros(capacity, jnp.bool_)
+        # rows handed on and still standing downstream, and every
+        # column as it was handed on (neither is checkpointed: a
+        # restore ranks the restored rows)
+        self.emitted = jnp.zeros(capacity, jnp.bool_)
+        self.shadow = {
+            n: jnp.zeros(capacity, self._dtypes[n]) for n in self.names
+        }
+        # lanes of the chunks applied since the last barrier: what
+        # bounds either delta of the barrier (``_rank_diff``)
+        self._epoch_lanes = 0
         if window_key is not None and window_key[0] not in self.group_by:
             raise ValueError(
                 "window_key must be one of the group columns (a closed "
@@ -562,8 +745,6 @@ class RetractableGroupTopNExecutor(Executor, Checkpointable):
         self.table_id = table_id
         self._bound = 0
         self._dropped = jnp.zeros((), jnp.bool_)
-        # group tuple -> {pk tuple -> full row tuple} of EMITTED rows
-        self._emitted: Dict[Tuple, Dict[Tuple, Tuple]] = {}
 
     def lint_info(self):
         return {
@@ -590,25 +771,35 @@ class RetractableGroupTopNExecutor(Executor, Checkpointable):
             ),
             "state": (self.table, self.rows),
             "donate": True,
-            # the barrier ranks on device but diffs against a host
-            # mirror; emissions are pow2-padded (bucketed) and the row
-            # store walks the allocator's declared lattice (legacy
-            # data_dependent/None only on the unbucketed twin)
-            **(
-                {
-                    "emission": "bucketed",
-                    "emission_caps": lattice_between(
-                        2, self._buckets.policy.max_cap
-                    ),
-                    "window_buckets": self._buckets.lattice,
-                }
-                if self._buckets is not None
-                else {
-                    "emission": "data_dependent",
-                    "window_buckets": None,
-                }
+            # the barrier ranks, diffs and gathers on the device and
+            # hands on two chunks of a declared size (emission_lanes:
+            # the sizes below are compiled when a graph-mode view is
+            # created, larger x4 steps when an epoch first needs one);
+            # the row store walks the allocator's declared lattice
+            "emission": "bucketed",
+            "emission_caps": self.emission_sizes(),
+            "window_buckets": (
+                self._buckets.lattice if self._buckets is not None else None
             ),
         }
+
+    def emission_sizes(self) -> Tuple[int, ...]:
+        """The emission sizes a view's creation compiles: what epochs
+        of up to 2^16 lanes (8 chunks of 8,192) hand on."""
+        cap = self.table.capacity
+        return tuple(
+            sorted({emission_lanes(n, cap) for n in (1, 1 << 16)})
+        )
+
+    def state_nbytes(self) -> int:
+        """Device bytes held (host-side estimate; no sync)."""
+        return sum(
+            leaf.nbytes
+            for leaf in jax.tree.leaves(
+                (self.table, self.rows, self.shadow, self.emitted,
+                 self.epoch_dirty, self.sdirty, self.stored)
+            )
+        )
 
     def pin_max_bucket(self):
         """ShapeGovernor hook: freeze the row store at its high-water
@@ -632,6 +823,14 @@ class RetractableGroupTopNExecutor(Executor, Checkpointable):
                 raise ValueError(f"GroupTopN key column {c!r} cannot be NULL")
         self._maybe_grow(chunk.capacity)
         self._bound += chunk.capacity
+        self._epoch_lanes += chunk.capacity
+        # the step's enqueue (the device runs it asynchronously), as
+        # actor.agg_step is for an aggregate
+        with span("actor.topn_step", table_id=self.table_id):
+            self._step(chunk)
+        return []
+
+    def _step(self, chunk: StreamChunk) -> None:
         (
             self.table,
             self.rows,
@@ -648,6 +847,27 @@ class RetractableGroupTopNExecutor(Executor, Checkpointable):
             self.names,
         )
         self._dropped = self._dropped | dropped
+
+    # -- the sizes, before they are met ----------------------------------
+    def warm_emissions(self) -> List[StreamChunk]:
+        """One chunk with no valid row of every declared emission size,
+        for the actor's warm-up pass; each comes out of the barrier's
+        own program over a store nothing has dirtied, so that program
+        is compiled for the size too."""
+        if self._epoch_lanes:
+            raise RuntimeError(f"{self.table_id}: warm-up after rows arrived")
+        return [self._rank_diff(lanes)[0] for lanes in self.emission_sizes()]
+
+    def warm(self, chunk: StreamChunk) -> Optional[List[StreamChunk]]:
+        """``apply`` for the warm-up pass: the step's program over a
+        chunk with no valid row, which claims no slot and dirties no
+        row; no host bound moves and nothing grows."""
+        if needs_plan(
+            self._buckets, self.table.capacity, self._bound,
+            chunk.capacity, GROW_AT,
+        ):
+            return []
+        self._step(chunk)
         return []
 
     def _maybe_grow(self, incoming: int):
@@ -678,51 +898,96 @@ class RetractableGroupTopNExecutor(Executor, Checkpointable):
                 )
 
             self.rows = {n: move(a) for n, a in self.rows.items()}
+            self.shadow = {n: move(a) for n, a in self.shadow.items()}
             self.sdirty = move(self.sdirty)
             self.stored = move(self.stored)
             self.epoch_dirty = move(self.epoch_dirty)
+            self.emitted = move(self.emitted)
             self.table = new
             claimed = int(self.table.occupancy())
         self._bound = claimed
 
-    def on_barrier(self, barrier: Barrier) -> List[StreamChunk]:
-        from risingwave_tpu.ops.hash_table import read_scalars
-
-        # ONE packed read for the latch + the dirty short-circuit +
-        # occupancy (device round-trips dominate)
-        dropped, any_dirty, claimed = read_scalars(
-            self._dropped, jnp.any(self.epoch_dirty), self.table.occupancy()
-        )
-        self._bound = int(claimed)
-        if self._buckets is not None:
-            self._buckets.note_barrier(self.table.capacity, int(claimed))
-        if dropped:
-            raise RuntimeError("GroupTopN row store overflowed; grow capacity")
-        if not any_dirty:
-            return []
-        in_topk, gdirty = _group_topk_mask(
+    def _rank_diff(self, out_lanes: int):
+        """The barrier's two programs at one emission size: the rank
+        (one program a capacity), then the diff and the gathers (one an
+        emission size). Returns (retractions, insertions, status on the
+        device)."""
+        ranked = _rank(
             self.table,
             self.rows,
+            self.shadow,
+            self.emitted,
             self.epoch_dirty,
             self.limit,
             self.desc,
-            self.group_by,
+            len(self.group_by),
             self.order_col,
         )
-        # pull the top-k of touched groups PLUS the epoch-dirty rows
-        # themselves (deleted rows name fully-emptied groups)
-        dels, ins = _diff_touched_groups(
-            self.table, self.rows, in_topk, self.epoch_dirty,
-            self.group_by, self.pk, self.names, gdirty, self._emitted,
+        self.emitted, self.shadow, ret, ins, status = _diff_gather(
+            self.table,
+            self.rows,
+            self.shadow,
+            self.emitted,
+            ranked,
+            self._dropped,
+            out_lanes,
         )
-        self.epoch_dirty = jnp.zeros_like(self.epoch_dirty)
-        return _emit_diffs(
-            dels,
-            ins,
-            self.names,
-            self._dtypes,
-            bucketed=self._buckets is not None,
-        )
+        return ret, ins, status
+
+    def on_barrier(self, barrier: Barrier) -> List[StreamChunk]:
+        cap = self.table.capacity
+        if not self._epoch_lanes:
+            # no chunk since the last barrier: nothing is dirty, the
+            # latch and the occupancy stand as they were read then
+            if self._buckets is not None:
+                self._buckets.note_barrier(cap, self._bound)
+            return []
+        lanes = emission_lanes(self._epoch_lanes, cap)
+        with span(
+            "topn.rank", table_id=self.table_id, lanes=lanes, capacity=cap
+        ):
+            ret, ins, status = self._rank_diff(lanes)
+            self.epoch_dirty = jnp.zeros_like(self.epoch_dirty)
+            self._epoch_lanes = 0
+        # ONE read for the counts, the latch and the occupancy; it
+        # waits for the rank
+        with span("topn.pull", table_id=self.table_id) as sp:
+            (n_ret, n_ins, groups, overflow, dropped, claimed, live,
+             passes) = jax.device_get(status).tolist()
+            sp.args.update(rows=n_ret + n_ins, groups=groups, passes=passes)
+        with span(
+            "topn.diff",
+            stage="topn_diff",
+            table_id=self.table_id,
+            groups=groups,
+            retract_rows=n_ret,
+            insert_rows=n_ins,
+        ):
+            self._bound = int(claimed)
+            if self._buckets is not None:
+                self._buckets.note_barrier(cap, self._bound)
+            if dropped:
+                raise RuntimeError(
+                    "GroupTopN row store overflowed; grow capacity"
+                )
+            if overflow:
+                raise RuntimeError(
+                    f"{self.table_id}: a barrier's delta ({n_ret} "
+                    f"retractions, {n_ins} insertions) passed the "
+                    f"{lanes} lanes its epoch's chunks held"
+                )
+            REGISTRY.counter("group_topn_touched_groups_total").inc(
+                groups, table_id=self.table_id
+            )
+            emitted = REGISTRY.counter("group_topn_emitted_rows_total")
+            emitted.inc(n_ret, table_id=self.table_id, op="retract")
+            emitted.inc(n_ins, table_id=self.table_id, op="insert")
+            REGISTRY.gauge("group_topn_rows").set(
+                float(live), table_id=self.table_id
+            )
+            # retractions first: an UPDATE's old row leaves the view
+            # before its new one enters under the same key
+            return [c for c, n in ((ret, n_ret), (ins, n_ins)) if n]
 
     def on_watermark(self, watermark):
         """Window-bounded groups expire silently below the watermark
@@ -739,11 +1004,8 @@ class RetractableGroupTopNExecutor(Executor, Checkpointable):
         )
         self.table = set_live(self.table, slots, False)
         self.sdirty = self.sdirty | expired
-        # closed groups leave the mirror without emitting retractions
-        gi = self.group_by.index(self.window_key[0])
-        cut = int(watermark.value - self.window_key[1])
-        for g in [g for g in self._emitted if g[gi] < cut]:
-            del self._emitted[g]
+        # closed groups leave ``emitted`` without a retraction
+        self.emitted = self.emitted & ~expired
         return watermark, []
 
     # -- checkpoint/restore (pk-keyed row store, plain-TopN layout) -------
@@ -806,27 +1068,16 @@ class RetractableGroupTopNExecutor(Executor, Checkpointable):
         self.table = table
         self.rows = rows
         self._bound = int(n)
+        self._epoch_lanes = 0
         self._dropped = jnp.zeros((), jnp.bool_)
-        # rebuild the emitted mirror: every group's current top-k (the
-        # downstream MV restored to exactly this view)
-        self._emitted = {}
-        if n:
-            in_topk, _ = _group_topk_mask(
-                self.table,
-                self.rows,
-                jnp.ones(cap, jnp.bool_),
-                self.limit,
-                self.desc,
-                self.group_by,
-                self.order_col,
+        # every group's current top-k stands downstream (the MV was
+        # restored to exactly this view), with the values the rows hold
+        self.emitted = (
+            _topk_mask(
+                table, rows[self.order_col], self.limit, self.desc,
+                len(self.group_by),
             )
-            sel = np.flatnonzero(np.asarray(in_topk))
-            pulled = pull_rows(
-                {nm: self.rows[nm] for nm in self.names}, sel
-            )
-            for i in range(len(sel)):
-                g = tuple(pulled[c][i].item() for c in self.group_by)
-                pkv = tuple(pulled[c][i].item() for c in self.pk)
-                self._emitted.setdefault(g, {})[pkv] = tuple(
-                    pulled[nm][i].item() for nm in self.names
-                )
+            if n
+            else jnp.zeros(cap, jnp.bool_)
+        )
+        self.shadow = {nm: jnp.copy(a) for nm, a in rows.items()}

@@ -518,6 +518,10 @@ class SqlSession:
                     ),
                     mview,
                 ]
+            if stmt.pk:
+                self.catalog.table_pks[stmt.name] = tuple(stmt.pk)
+            else:  # (a name dropped and made again without a key)
+                self.catalog.table_pks.pop(stmt.name, None)
             self.runtime.register(stmt.name, Pipeline(chain))
             self.batch.register(stmt.name, mview)
             self.dml.add_target(stmt.name, stmt.name, "single")

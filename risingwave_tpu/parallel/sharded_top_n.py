@@ -17,6 +17,7 @@ other's checkpoint (cross-layout recovery)."""
 
 from __future__ import annotations
 
+from functools import partial
 from typing import Dict, List, Optional, Sequence, Tuple
 
 import jax
@@ -27,9 +28,7 @@ from jax.sharding import PartitionSpec as P
 from risingwave_tpu.array.chunk import StreamChunk
 from risingwave_tpu.executors.base import Barrier, Executor
 from risingwave_tpu.executors.top_n_plain import (
-    _diff_touched_groups,
-    _emit_diffs,
-    _group_topk_mask,
+    _order_key_u64,
     _upsert_step_ed,
 )
 from risingwave_tpu.ops.hash_table import (
@@ -43,6 +42,7 @@ from risingwave_tpu.parallel.sharded_join import (
     stacked_state_nbytes_per_shard,
     track_bucket_cap,
 )
+from risingwave_tpu.runtime.bucketing import emission_bucket
 from risingwave_tpu.storage.state_table import (
     Checkpointable,
     StateDelta,
@@ -50,8 +50,126 @@ from risingwave_tpu.storage.state_table import (
     pull_rows,
     stage_marks,
 )
+from risingwave_tpu.types import Op
 
 GROW_AT = 0.5
+
+
+@partial(
+    jax.jit,
+    static_argnames=("k", "desc", "group_names", "order_col"),
+    donate_argnums=(),
+)
+def _group_topk_mask(
+    table: HashTable,
+    rows: Dict[str, jnp.ndarray],
+    epoch_dirty: jnp.ndarray,
+    k: int,
+    desc: bool,
+    group_names: Tuple[str, ...],
+    order_col: str,
+):
+    """Per-slot masks: is the row in its group's current top-k, and
+    does its group contain an epoch-dirty row (so its top-k must be
+    re-pulled)? One device sort over (group lanes, order key, pk)."""
+    cap = table.capacity
+    # liveness as its own sort key within the group (a dead-row
+    # sentinel would collide with INT64-extreme order values)
+    live_last = (~table.live).astype(jnp.int32)
+    okey = _order_key_u64(rows[order_col], desc)
+    glanes = tuple(rows[g] for g in group_names)
+    sort_in = glanes + (live_last, okey) + tuple(table.keys) + (
+        jnp.arange(cap, dtype=jnp.int32),
+    )
+    sorted_all = jax.lax.sort(
+        sort_in, num_keys=len(glanes) + 2 + len(table.keys)
+    )
+    slot_s = sorted_all[-1]
+    live_s = table.live[slot_s]
+    dirty_s = epoch_dirty[slot_s]
+    boundary = jnp.zeros(cap, jnp.bool_).at[0].set(True)
+    for lane in sorted_all[: len(glanes)]:
+        boundary = boundary | jnp.concatenate(
+            [jnp.ones(1, jnp.bool_), lane[1:] != lane[:-1]]
+        )
+    gid = jnp.cumsum(boundary.astype(jnp.int32)) - 1
+    idx = jnp.arange(cap, dtype=jnp.int32)
+    seg_start = jax.ops.segment_max(
+        jnp.where(boundary, idx, 0), gid, num_segments=cap
+    )[gid]
+    in_topk_s = live_s & ((idx - seg_start) < k)
+    gdirty_s = (
+        jax.ops.segment_max(
+            dirty_s.astype(jnp.int32), gid, num_segments=cap
+        )[gid]
+        > 0
+    )
+    in_topk = jnp.zeros(cap, jnp.bool_).at[slot_s].set(in_topk_s)
+    gdirty = jnp.zeros(cap, jnp.bool_).at[slot_s].set(gdirty_s)
+    return in_topk, gdirty
+
+
+def _diff_touched_groups(
+    table, rows, in_topk, epoch_dirty, group_by, pk, names, gdirty,
+    emitted,
+):
+    """Pull touched groups' top-k (+ the epoch-dirty rows naming
+    fully-emptied groups) and diff against the host mirror of what was
+    emitted; updates ``emitted`` in place (one shard = one call over
+    its slices). The single-chip executor diffs on the device instead
+    (executors/top_n_plain._rank_diff)."""
+    mask = np.asarray((gdirty & in_topk) | epoch_dirty)
+    sel = np.flatnonzero(mask)
+    lanes = {n: rows[n] for n in names}
+    lanes["__topk__"] = in_topk
+    lanes["__live__"] = table.live
+    pulled = pull_rows(lanes, sel)
+    new_top: Dict[Tuple, Dict[Tuple, Tuple]] = {}
+    changed: set = set()
+    for i in range(len(sel)):
+        g = tuple(pulled[c][i].item() for c in group_by)
+        changed.add(g)
+        if pulled["__topk__"][i] and pulled["__live__"][i]:
+            pkv = tuple(pulled[c][i].item() for c in pk)
+            new_top.setdefault(g, {})[pkv] = tuple(
+                pulled[n][i].item() for n in names
+            )
+    dels, ins = [], []
+    for g in changed:
+        old = emitted.get(g, {})
+        new = new_top.get(g, {})
+        dels.extend(v for p, v in old.items() if new.get(p) != v)
+        ins.extend(v for p, v in new.items() if old.get(p) != v)
+        if new:
+            emitted[g] = new
+        else:
+            emitted.pop(g, None)
+    return dels, ins
+
+
+def _emit_diffs(dels, ins, names, dtypes, bucketed=True) -> List[StreamChunk]:
+    outs = []
+    for vals, op in ((dels, Op.DELETE), (ins, Op.INSERT)):
+        if not vals:
+            continue
+        cols = {
+            n: np.asarray([r[j] for r in vals], dtypes[n])
+            for j, n in enumerate(names)
+        }
+        outs.append(
+            StreamChunk.from_numpy(
+                cols,
+                # pow2-padded emission (masked lanes): downstream sees
+                # a log-bounded capacity set, not one per delta count;
+                # the bucketed=False twin keeps the legacy max(2, n)
+                # shape per distinct count (RW-E803 baseline behavior)
+                emission_bucket(len(vals))
+                if bucketed
+                else max(2, len(vals)),
+                ops=np.full(len(vals), int(op), np.int32),
+            )
+        )
+    return outs
 
 
 class ShardedGroupTopN(Executor, Checkpointable):

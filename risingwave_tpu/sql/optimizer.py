@@ -693,6 +693,30 @@ def explain_sql(sql: str, catalog=None) -> str:
         + "-- optimized\n"
         + explain(after)
         + _explain_shared(emit(after), catalog)
+        + _explain_topn(select)
+    )
+
+
+def _explain_topn(select: P.Select) -> str:
+    """A bound on ROW_NUMBER() over a partition is planned as the
+    retractable GroupTopN and not as a window (sql/planner.py,
+    ``_try_over_window_to_topn``): the executor chain behind the scan,
+    as the planner builds it."""
+    from risingwave_tpu.sql.planner import over_window_topn_shape
+
+    shape = over_window_topn_shape(select)
+    if shape is None:
+        return ""
+    inner = select.from_.select.from_
+    source = inner.table.name if isinstance(inner, P.WindowTVF) else inner.name
+    group = ", ".join(c.name for c in shape.partition_by)
+    order = shape.order.name + (" DESC" if shape.desc else "")
+    return (
+        f"-- {shape.rank_name} <= {shape.limit}: per-group top-n, not a "
+        "window\n"
+        f"StreamScan {source} -> RowIdGen (a source with no key) -> "
+        f"RetractableGroupTopN group=[{group}] order=[{order}, stream key] "
+        f"limit={shape.limit} -> Project -> Materialize\n"
     )
 
 

@@ -267,6 +267,9 @@ class Catalog:
         # WATERMARK FOR declarations: relation -> (column, lag_ms)
         # (reference: watermark definitions on sources/tables)
         self.watermarks: Dict[str, Tuple[str, int]] = {}
+        # CREATE TABLE ... PRIMARY KEY: the stream key of that table's
+        # change stream (its DELETEs and UPDATEs carry it)
+        self.table_pks: Dict[str, Tuple[str, ...]] = {}
 
     def schema_dtypes(self, name: str) -> Dict[str, object]:
         sch = self.tables[name]
@@ -617,6 +620,92 @@ def _substitute(rel: P.SubQuery, occurrence: P.SubQuery, planned: _Planned):
             inner, from_=_substitute(inner.from_, occurrence, planned)
         ),
     )
+
+
+@dataclass(frozen=True)
+class TopNShape:
+    """What ``over_window_topn_shape`` read off a select: the window's
+    partition and order, and the bound the outer WHERE puts on the
+    rank."""
+
+    partition_by: Tuple[P.Ident, ...]
+    order: P.Ident
+    desc: bool
+    limit: int
+    rank_name: str
+
+
+def over_window_topn_shape(select: P.Select) -> Optional[TopNShape]:
+    """The syntactic half of over_window_to_topn_rule.rs: is ``select``
+
+        SELECT cols FROM (SELECT cols | *, row_number() OVER
+          (PARTITION BY g ORDER BY o [DESC]) AS rn FROM t) AS x
+        WHERE rn <= k      (also rn < k, rn = 1)
+
+    with the rank itself not selected? The planner's rule and EXPLAIN
+    both ask; None = the window path."""
+    f = select.from_
+    if not (
+        isinstance(f, P.SubQuery)
+        and isinstance(f.select.from_, (P.TableRef, P.WindowTVF))
+        and select.where is not None
+        and not select.group_by
+        and not select.having
+        and select.limit is None
+    ):
+        return None
+    inner = f.select
+    if inner.where is not None or inner.group_by or inner.limit:
+        return None
+    wins = [
+        (i, it)
+        for i, it in enumerate(inner.items)
+        if isinstance(it.expr, P.WindowFuncCall)
+    ]
+    if len(wins) != 1:
+        return None
+    wi, witem = wins[0]
+    w = witem.expr
+    if (
+        w.func.name != "row_number"
+        or w.frame is not None
+        or len(w.order_by) != 1
+        or not w.partition_by
+    ):
+        return None
+    rn_name = witem.alias or f"row_number_{wi}"
+    # the outer WHERE must be exactly a bound on rn; rn must not be
+    # selected (GroupTopN emits rows without a rank column)
+    conjs = _split_and(select.where)
+    k = None
+    for c in conjs:
+        if not (
+            isinstance(c, P.BinaryOp)
+            and isinstance(c.left, P.Ident)
+            and c.left.name == rn_name
+            and c.left.qualifier in (None, f.alias)
+            and isinstance(c.right, P.Literal)
+        ):
+            return None
+        v = c.right.value
+        if not isinstance(v, int) or isinstance(v, bool):
+            return None  # float/str bounds: the window path filters
+        if c.op == "<=":
+            bound = v
+        elif c.op == "<":
+            bound = v - 1
+        elif c.op == "=" and v == 1:
+            bound = 1
+        else:
+            return None
+        k = bound if k is None else min(k, bound)
+    if k is None or k < 1:
+        return None
+    for it in select.items:
+        if not isinstance(it.expr, P.Ident) or it.expr.name == rn_name:
+            return None
+    oident, desc = w.order_by[0]
+    return TopNShape(tuple(w.partition_by), oident, bool(desc), k, rn_name)
 
 
 class StreamPlanner:
@@ -1150,75 +1239,26 @@ class StreamPlanner:
         maintenance is O(changed groups x k) per barrier where the
         general over-window recomputes whole partitions. Returns None
         when the shape doesn't match (the window path handles it)."""
-        f = select.from_
-        if not (
-            isinstance(f, P.SubQuery)
-            and isinstance(f.select.from_, (P.TableRef, P.WindowTVF))
-            and select.where is not None
-            and not select.group_by
-            and not select.having
-            and select.limit is None
-        ):
+        shape = over_window_topn_shape(select)
+        if shape is None:
             return None
+        f, k = select.from_, shape.limit
         inner = f.select
-        if inner.where is not None or inner.group_by or inner.limit:
-            return None
-        wins = [
-            (i, it)
-            for i, it in enumerate(inner.items)
-            if isinstance(it.expr, P.WindowFuncCall)
-        ]
-        if len(wins) != 1:
-            return None
-        wi, witem = wins[0]
-        w = witem.expr
-        if (
-            w.func.name != "row_number"
-            or w.frame is not None
-            or len(w.order_by) != 1
-            or not w.partition_by
-        ):
-            return None
-        rn_name = witem.alias or f"row_number_{wi}"
-        # the outer WHERE must be exactly a bound on rn; rn must not be
-        # selected (GroupTopN emits rows without a rank column)
-        conjs = _split_and(select.where)
-        k = None
-        for c in conjs:
-            if not (
-                isinstance(c, P.BinaryOp)
-                and isinstance(c.left, P.Ident)
-                and c.left.name == rn_name
-                and c.left.qualifier in (None, f.alias)
-                and isinstance(c.right, P.Literal)
-            ):
-                return None
-            v = c.right.value
-            if not isinstance(v, int) or isinstance(v, bool):
-                return None  # float/str bounds: the window path filters
-            if c.op == "<=":
-                bound = v
-            elif c.op == "<":
-                bound = v - 1
-            elif c.op == "=" and v == 1:
-                bound = 1
-            else:
-                return None
-            k = bound if k is None else min(k, bound)
-        if k is None or k < 1:
-            return None
-        for it in select.items:
-            if not isinstance(it.expr, P.Ident) or it.expr.name == rn_name:
-                return None
 
         bound_rel = self._from_bound(name, inner.from_)
         schema = dict(bound_rel.schema)
         binder = Binder(schema, bound_rel.alias)
-        part_cols = tuple(binder.resolve(c) for c in w.partition_by)
-        oident, desc = w.order_by[0]
-        ocol = binder.resolve(oident)
+        part_cols = tuple(binder.resolve(c) for c in shape.partition_by)
+        desc = shape.desc
+        ocol = binder.resolve(shape.order)
         chain = list(bound_rel.chain)
-        pk = bound_rel.pk
+        # the rows' identity: what an upstream plan keys its stream by,
+        # a table's declared primary key (its DELETEs and UPDATEs name
+        # the row by it, so they retract and rewrite the stored row),
+        # else a row id in arrival order
+        pk = bound_rel.pk or tuple(
+            self.catalog.table_pks.get(bound_rel.source, ())
+        )
         if not pk:
             chain.append(
                 RowIdGenExecutor(
@@ -1227,12 +1267,19 @@ class StreamPlanner:
             )
             schema["_row_id"] = jnp.dtype(jnp.int64)
             pk = ("_row_id",)
-        # resolve inner pass-through aliases for the outer projection
-        amap = {
-            (it.alias or (it.expr.name if isinstance(it.expr, P.Ident) else None)):
-                it.expr
-            for it in inner.items
-        }
+        # resolve inner pass-through aliases for the outer projection;
+        # a ``*`` beside the window call stands for the bound relation's
+        # user-visible columns (binder/select.rs star expansion)
+        amap = {}
+        for it in inner.items:
+            if isinstance(it.expr, P.Star):
+                amap.update(
+                    (c, P.Ident(c))
+                    for c in bound_rel.schema
+                    if not c.startswith("_")
+                )
+            elif it.alias or isinstance(it.expr, P.Ident):
+                amap[it.alias or it.expr.name] = it.expr
         from risingwave_tpu.executors.top_n_plain import (
             RetractableGroupTopNExecutor,
         )

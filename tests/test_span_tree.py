@@ -103,17 +103,27 @@ def _thread_name(sp):
 
 
 def _stage_keys_the_benchmark_reads():
-    """Every stage key an epoch_stage / barrier_residual metric file
-    names (``device_step`` is the one key nobody writes: it lies inside
+    """Every stage key an epoch_stage / barrier_residual metric file of
+    a ``nexmark_q8`` cell names: the plan of this file's sessions (a
+    metric of another plan's cells alone, as ``topn.diff_ms_per_barrier``
+    is q18's, names a stage only that plan's executors write).
+    ``device_step`` is the one key nobody writes: it lies inside
     dispatch's wall, and the outside metric that adds it keeps its
-    meaning until a benchmark PR drops the term)."""
+    meaning until a benchmark PR drops the term."""
+    with open(os.path.join(ROOT, "BENCHMARK.json")) as f:
+        q8_metrics = {
+            m["name"] for m in json.load(f)["per_layer"]
+            if any(w.startswith("nexmark_q8.") for w in m["workloads"])
+        }
     keys = set()
     for path in glob.glob(
         os.path.join(ROOT, "benchmarks", "layer_metrics", "*.json")
     ):
         with open(path) as f:
             spec = json.load(f)
-        if spec["reader"] in ("epoch_stage", "barrier_residual"):
+        if spec["name"] in q8_metrics and spec["reader"] in (
+            "epoch_stage", "barrier_residual"
+        ):
             keys.update(spec["args"]["stages"])
     return keys - {"device_step"}
 
