@@ -132,6 +132,21 @@ def bucket_lattice(
     return tuple(spec.with_capacity(c) for c in caps)
 
 
+def push_lattice_specs(
+    spec: ChunkSpec, executors: Sequence[object]
+) -> Tuple[ChunkSpec, ...]:
+    """The spec at every width a chain of executors takes a host-built
+    chunk built at ``spec.capacity`` lanes: the push lattice
+    (``runtime/bucketing.push_lattice``) where every executor declares
+    it (``Executor.push_widths``), the full width alone where one does
+    not. The same set ``StreamingRuntime.push`` cuts to: both read
+    ``pipeline.chain_push_widths``."""
+    # (deferred: the runtime package imports the analysis package)
+    from risingwave_tpu.runtime.pipeline import chain_push_widths
+
+    return bucket_lattice(spec, chain_push_widths(executors, spec.capacity))
+
+
 def capacity_bucket(capacity: int) -> int:
     """Pow2 bucket of a concrete chunk capacity — the dynamic twin
     (SignatureWatch records this per hazard so runtime events

@@ -27,6 +27,7 @@ multi-chip path.
 from __future__ import annotations
 
 from dataclasses import dataclass, field
+from functools import partial
 from typing import Dict, Mapping, Optional
 
 import jax
@@ -207,6 +208,13 @@ class DataChunk:
         return out
 
 
+@partial(jax.jit, static_argnames=("lanes",))
+def _leading_lanes(chunk, lanes: int):
+    """Every lane array of a chunk cut to its first ``lanes`` lanes:
+    one program a (schema, size)."""
+    return jax.tree_util.tree_map(lambda a: a[:lanes], chunk)
+
+
 @jax.tree_util.register_pytree_node_class
 @dataclass
 class StreamChunk(DataChunk):
@@ -283,6 +291,24 @@ class StreamChunk(DataChunk):
         )
         chunk.host_rows = rows
         return chunk
+
+    def leading(self, lanes: int) -> "StreamChunk":
+        """The chunk's first ``lanes`` lanes: every column, null lane,
+        ``valid`` and ``ops``, by one jitted program. For a chunk whose
+        rows lie in its leading lanes (``from_numpy`` packs them so)
+        and number at most ``lanes``, the same rows in a narrower
+        chunk; ``host_rows`` goes with them."""
+        out = _leading_lanes(self, lanes)
+        out.host_rows = self.host_rows
+        return out
+
+    def emptied(self) -> "StreamChunk":
+        """The same lanes with no valid row: inert to every device
+        step, so a chunk of it compiles a width's programs and stores
+        nothing (``Executor.warm``)."""
+        return StreamChunk(
+            self.columns, jnp.zeros_like(self.valid), self.nulls, self.ops
+        )
 
     # -- semantics ------------------------------------------------------
     def signs(self) -> jnp.ndarray:

@@ -18,7 +18,7 @@ XLA programs per chunk.
 from __future__ import annotations
 
 from dataclasses import dataclass
-from typing import List, Optional, Sequence
+from typing import List, Optional, Sequence, Tuple
 
 from risingwave_tpu.array.chunk import StreamChunk
 
@@ -188,6 +188,29 @@ class Executor:
         instances of the same plan shape, or every graph rebuild
         recompiles the fused program."""
         return None
+
+    # -- the push lattice (runtime/bucketing.push_lattice; PR 32) --------
+    # True on a stateful executor whose data path is one step a chunk
+    # at the chunk's own width, and which knows ``warm``
+    per_chunk_step = False
+
+    def push_widths(self, capacity: int) -> Tuple[int, ...]:
+        """The widths at which this executor takes a host-built chunk
+        that was built at ``capacity`` lanes. An executor whose whole
+        data path is one step a chunk — the stateless-pure ones, and
+        the stateful ones that say ``per_chunk_step`` — takes the push
+        lattice: ``StreamingRuntime.push`` may hand it the chunk cut to
+        the smallest declared size that holds its rows. One that keys
+        compiled programs on a uniform chunk width (an epoch-batched
+        head, a fused barrier program) takes the full width only. A
+        fragment takes what every executor of it takes
+        (``pipeline.chain_push_widths``)."""
+        # (deferred: the runtime package imports this module)
+        from risingwave_tpu.runtime.bucketing import push_lattice
+
+        if self.per_chunk_step or self.pure_step() is not None:
+            return push_lattice(capacity)
+        return (int(capacity),)
 
     # -- the warm-up pass (runtime/graph.FragmentActor.warm_flush_lattice)
     def warm_emissions(self) -> Sequence[StreamChunk]:

@@ -189,6 +189,25 @@ class AppendOnlyDedupExecutor(Executor, Checkpointable):
         self._dropped = self._dropped | dropped
         return [out]
 
+    # one step a chunk at the chunk's own width: takes the push lattice
+    per_chunk_step = True
+
+    def warm(self, chunk: StreamChunk) -> Optional[List[StreamChunk]]:
+        """``Executor.warm``: the step's program over a chunk with no
+        valid row, which claims no slot and passes no row on; no host
+        bound moves, nothing grows and no latch is kept. Where the
+        next chunk of this width would plan a growth first, its step
+        never runs at this capacity: the pass stops here."""
+        if any(k in chunk.nulls for k in self.keys) or needs_plan(
+            self._buckets, self.table.capacity, self._bound,
+            chunk.capacity, GROW_AT,
+        ):
+            return None
+        self.table, self.sdirty, out, _, _ = _dedup_step(
+            self.table, self.sdirty, chunk, self.keys
+        )
+        return [out]
+
     def _grow_hint(self, incoming: int):
         """The FUSED wrapper's pre-dispatch growth bookkeeping: ZERO
         device reads. The host bound counts padded chunk capacities —

@@ -33,8 +33,6 @@ from __future__ import annotations
 
 from typing import List, Sequence
 
-import jax.numpy as jnp
-
 from risingwave_tpu.array.chunk import StreamChunk
 from risingwave_tpu.executors.base import Barrier, Executor, Watermark
 from risingwave_tpu.executors.hash_agg import HashAggExecutor
@@ -185,11 +183,7 @@ class EpochBatchedAggExecutor(Executor):
         n = len(buf)
         target = 1 << (n - 1).bit_length() if n > 1 else 1
         if target > n:
-            c0 = buf[0]
-            empty = StreamChunk(
-                c0.columns, jnp.zeros_like(c0.valid), c0.nulls, c0.ops
-            )
-            buf = buf + [empty] * (target - n)
+            buf = buf + [buf[0].emptied()] * (target - n)
         self.agg.apply_stacked(
             stack_chunks(buf), pre=self._pre, mode=self.mode
         )

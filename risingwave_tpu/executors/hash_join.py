@@ -520,6 +520,17 @@ class HashJoinExecutor(Executor, Checkpointable):
             self._cold_apply_hook(side, chunk)
         own = self.left if side == "l" else self.right
         own = self._maybe_grow(side, own, chunk.capacity)
+        out, em_overflow = self._step(side, own, chunk)
+        self._bound[side] += chunk.capacity
+        # latch on device; checked once per barrier (a bool() here would
+        # force a host sync on every chunk and stall the pipeline)
+        self._em_overflow = self._em_overflow | em_overflow
+        return [out]
+
+    def _step(self, side: str, own: JoinSide, chunk: StreamChunk):
+        """One chunk's join step over ``own`` (this side, grown if it
+        had to be) and the other side; both sides are kept. Returns
+        (the pairs' chunk, the emission-overflow flag)."""
         other = self.right if side == "l" else self.left
         own_keys = self.left_keys if side == "l" else self.right_keys
         other_keys = self.right_keys if side == "l" else self.left_keys
@@ -543,11 +554,28 @@ class HashJoinExecutor(Executor, Checkpointable):
             self.left, self.right = own, other
         else:
             self.right, self.left = own, other
-        self._bound[side] += chunk.capacity
-        # latch on device; checked once per barrier (a bool() here would
-        # force a host sync on every chunk and stall the pipeline)
-        self._em_overflow = self._em_overflow | em_overflow
-        return [StreamChunk(columns=cols, valid=valid, nulls=nulls, ops=ops)]
+        out = StreamChunk(columns=cols, valid=valid, nulls=nulls, ops=ops)
+        return out, em_overflow
+
+    # one step a chunk at the chunk's own width: takes the push lattice
+    per_chunk_step = True
+
+    def warm_side(self, name: str, chunk: StreamChunk) -> List[StreamChunk]:
+        """``Executor.warm`` for one side's chunk ("left" / "right"):
+        the side's step over a chunk with no valid row, which stores,
+        clears and pairs nothing; no host bound moves, nothing grows,
+        no latch is kept, and no cold bucket is faulted in (the chunk
+        names no key). Nothing runs where the next chunk of this width
+        would first plan a growth: its step never runs at this
+        capacity."""
+        side = "l" if name == "left" else "r"
+        own = self.left if side == "l" else self.right
+        alloc = self._buckets[side] if self._buckets is not None else None
+        if needs_plan(
+            alloc, own.capacity, self._bound[side], chunk.capacity, GROW_AT
+        ):
+            return []
+        return [self._step(side, own, chunk)[0]]
 
     def _grow_hint(self, side: str, own: JoinSide, incoming: int) -> JoinSide:
         """The FUSED wrapper's pre-dispatch growth bookkeeping: ZERO

@@ -133,6 +133,10 @@ class MaterializeExecutor(Executor, Checkpointable):
         with span("mv.apply", stage="actor.mv_apply", table_id=self.table_id):
             return self._apply(chunk)
 
+    # one host apply a chunk at the chunk's own width: takes the push
+    # lattice (a table fragment copies a quarter of the lanes)
+    per_chunk_step = True
+
     def warm(self, chunk: StreamChunk) -> List[StreamChunk]:
         """Executor.warm: the chunk's copy to the host; with no valid
         row ``_apply`` returns before it touches the map."""

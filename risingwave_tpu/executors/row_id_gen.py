@@ -63,9 +63,23 @@ class RowIdGenExecutor(Executor, Checkpointable):
             # reassign (reference row_id_gen.rs only fills fresh
             # inserts; deletes carry the stored row)
             return [chunk]
-        ids = self._base + jnp.arange(chunk.capacity, dtype=jnp.int64)
+        out = self._with_ids(chunk)
         self._base += chunk.capacity
-        return [chunk.with_columns(**{self.out_col: ids})]
+        return [out]
+
+    def _with_ids(self, chunk: StreamChunk) -> StreamChunk:
+        ids = self._base + jnp.arange(chunk.capacity, dtype=jnp.int64)
+        return chunk.with_columns(**{self.out_col: ids})
+
+    # one step a chunk at the chunk's own width: takes the push lattice
+    per_chunk_step = True
+
+    def warm(self, chunk: StreamChunk) -> List[StreamChunk]:
+        """``Executor.warm``: the ids' program for a chunk of this
+        width; the counter stays where it is."""
+        if self.out_col in chunk.columns:
+            return [chunk]
+        return [self._with_ids(chunk)]
 
     # -- integrity --------------------------------------------------------
     def state_digest(self) -> int:

@@ -858,6 +858,10 @@ class RetractableGroupTopNExecutor(Executor, Checkpointable):
             raise RuntimeError(f"{self.table_id}: warm-up after rows arrived")
         return [self._rank_diff(lanes)[0] for lanes in self.emission_sizes()]
 
+    # one upsert step a chunk at the chunk's own width: takes the push
+    # lattice (the rank is sized by the lanes the epoch's chunks held)
+    per_chunk_step = True
+
     def warm(self, chunk: StreamChunk) -> Optional[List[StreamChunk]]:
         """``apply`` for the warm-up pass: the step's program over a
         chunk with no valid row, which claims no slot and dirties no

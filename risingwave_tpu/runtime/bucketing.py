@@ -60,6 +60,7 @@ __all__ = [
     "padding_stats",
     "plan_capacity",
     "pow2_at_least",
+    "push_lattice",
     "validate_lattice",
     "delta_blocks",
     "prefix_pad",
@@ -165,6 +166,32 @@ def flush_lattice_pad(out_cap: int, n_take: int) -> int:
     that overflowed took ``out_cap`` groups and so the full size."""
     need = 2 * int(n_take)
     return next(s for s in flush_lattice(out_cap) if s >= need)
+
+
+# the narrowest chunk ``push_lattice`` cuts to: under it a per-chunk
+# step costs what it costs at any width (PERF.md 6, PR 32), and the
+# few-row chunks of an INSERT keep the one shape they have
+PUSH_SMALL = 256
+
+
+def push_lattice(capacity: int) -> Tuple[int, ...]:
+    """The declared widths of a host-built chunk of ``capacity`` lanes
+    on its way into a fragment of per-chunk steps (PR 32): its own
+    capacity and one x4 step below it — 2,048 / 8,192 for a chunk built
+    at 8,192. ``StreamingRuntime.push`` cuts the chunk to the smallest
+    of them that holds its rows, the fragments that take such chunks
+    declare it (``Executor.push_widths``), and every size is compiled
+    before a stream meets it, so a size first met compiles nothing.
+
+    One step down and no ladder, for ``flush_lattice``'s reason: a
+    declared size is one more set of every program of the chain, paid
+    at every start. A capacity that is no power of two, or whose
+    quarter falls under ``PUSH_SMALL``, is its own whole lattice."""
+    capacity = int(capacity)
+    small = capacity // 4
+    if capacity & (capacity - 1) or small < PUSH_SMALL:
+        return (capacity,)
+    return (small, capacity)
 
 
 def flush_pad(out_cap: int, emitted_bound: int) -> int:

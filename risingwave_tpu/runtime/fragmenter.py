@@ -47,6 +47,7 @@ from risingwave_tpu.runtime.pipeline import (
     FreshnessSurface,
     Pipeline,
     TwoInputPipeline,
+    chain_push_widths,
 )
 from risingwave_tpu.storage.state_table import Checkpointable, StateDelta
 
@@ -463,6 +464,26 @@ class GraphPipeline(FreshnessSurface):
             return self.push_left(chunk) + self.push_right(chunk)
         self._note_ingest()
         self.graph.inject_chunk(self._sources["both"], chunk)
+        return []
+
+    # -- the push lattice (StreamingRuntime.push, PR 32) -------------------
+    def push_widths(self, capacity: int):
+        """The widths at which this view takes a pushed chunk: the push
+        lattice where every executor of every actor is a per-chunk
+        step, the full width alone where one is not (an epoch-batched
+        head keys its programs on a uniform chunk width)."""
+        return chain_push_widths(self.graph.executors, capacity)
+
+    def warm_push(self, chunk: StreamChunk, side: str = "single"):
+        """A chunk with no valid row into the source ``push`` feeds for
+        ``side``: each actor it reaches runs it through
+        ``Executor.warm`` on its own thread, in the channel's order."""
+        if side == "both" and "both" not in self._sources:
+            sources = (self._sources["left"], self._sources["right"])
+        else:
+            sources = (self._sources[side],)
+        for source in sources:
+            self.graph.inject_warm(source, chunk)
         return []
 
     def watermark(self, column: str, value: int) -> List[StreamChunk]:

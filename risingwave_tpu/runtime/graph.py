@@ -65,8 +65,11 @@ def _default_barrier_timeout() -> float:
     except ValueError:
         return 120.0
 
-# message kinds flowing through channels
+# message kinds flowing through channels; WARM carries a chunk with no
+# valid row of a width the fragment has not met (the push lattice, PR
+# 32): a control message, so it costs no permits and no actor counts it
 CHUNK, BARRIER, WATERMARK, STOP = "chunk", "barrier", "watermark", "stop"
+WARM = "warm"
 
 # an actor's sums of one epoch that are reported per actor, as
 # stages_ms["<key>.<actor label>"]: the operator that is busy while
@@ -467,23 +470,47 @@ class FragmentActor(threading.Thread):
                         self._warm_tap(c)
                     self._warm_from(section, chain[i + 1 :], chunks)
 
-    def _warm_from(self, section: str, chain, chunks) -> None:
+    def _warm_from(self, section: str, chain, chunks) -> List[StreamChunk]:
         """Warm-up chunks down the rest of a chain and on through what
-        the section feeds inside this actor."""
+        the section feeds inside this actor; what leaves the actor."""
         outs = warm_chain(chain, chunks, tap=self._warm_tap)
         if outs is None or section == "tail":
-            return
+            return outs or []
         if section == "head":
-            self._warm_from("left", self.chain, outs)
-            self._warm_from("right", self.right_chain, outs)
-            return
+            return self._warm_from("left", self.chain, outs) + (
+                self._warm_from("right", self.right_chain, outs)
+            )
         warm_side = getattr(self.join_exec, "warm_side", None)
         if warm_side is None:
-            return
+            return []
         joined = [j for c in outs for j in warm_side(section, c)]
         for c in joined:
             self._warm_tap(c)
-        warm_chain(self.tail, joined, tap=self._warm_tap)
+        return warm_chain(self.tail, joined, tap=self._warm_tap) or []
+
+    def _process_warm(self, port: int, chunk: StreamChunk) -> None:
+        """A chunk with no valid row of a width this port has not met
+        (``StreamingRuntime.push`` sends one of every size of the push
+        lattice ahead of the first chunk built at a capacity): down
+        the port's chain as a chunk goes, through ``Executor.warm``,
+        and on to the actors behind this one. On this thread, in the
+        channel's order, so no step of it meets a chunk's."""
+        with span(
+            "actor.warm", actor=self.actor_name, port=port,
+            lanes=[chunk.capacity],
+        ):
+            if self.join_exec is None:
+                outs = self._warm_from("tail", self.chain, [chunk])
+            elif self.head:
+                outs = self._warm_from("head", self.head, [chunk])
+            elif port == 0:
+                outs = self._warm_from("left", self.chain, [chunk])
+            else:
+                outs = self._warm_from("right", self.right_chain, [chunk])
+            # to every downstream, whatever the routing (a hash
+            # exchange's slice program is not among what this builds)
+            for c in outs:
+                self.dispatcher.control(WARM, c)
 
     @staticmethod
     def _warm_tap(chunk: StreamChunk) -> None:
@@ -763,6 +790,8 @@ class FragmentActor(threading.Thread):
                         sp.dur * 1e3
                         - (self._sums.get("actor_blocked") - blocked),
                     )
+                elif kind == WARM:
+                    self._process_warm(port, payload)
                 elif kind == WATERMARK:
                     self._process_watermark(i, payload)
                 elif kind == BARRIER:
@@ -1267,6 +1296,12 @@ class GraphRuntime:
             self._source_rr[source] = (rr + 1) % len(chans)
             instance = rr
         chans[instance].send_chunk(chunk)
+
+    def inject_warm(self, source: str, chunk: StreamChunk) -> None:
+        """A warm-up chunk (``FragmentActor._process_warm``) into every
+        instance of a source."""
+        for ch in self._source_channels[source]:
+            ch.send_control(WARM, chunk)
 
     def inject_watermark(
         self, column: str, value: int, source: Optional[str] = None

@@ -26,6 +26,7 @@ from risingwave_tpu.array.chunk import StreamChunk
 from risingwave_tpu.blackbox import RECORDER
 from risingwave_tpu.executors.base import Barrier, Epoch, Executor, Watermark
 from risingwave_tpu.profiler import PROFILER
+from risingwave_tpu.runtime.bucketing import push_lattice
 from risingwave_tpu.trace import bound, span
 
 
@@ -141,6 +142,18 @@ def warm_chain(chain: Sequence[Executor], chunks, tap=None):
     return pending
 
 
+def chain_push_widths(chain: Sequence[Executor], capacity: int):
+    """The widths at which every executor of ``chain`` takes a
+    host-built chunk built at ``capacity`` lanes: the push lattice
+    (``bucketing.push_lattice``) where each declares it
+    (``Executor.push_widths``), else the full width alone."""
+    widths = set(push_lattice(capacity))
+    for ex in chain:
+        takes = getattr(ex, "push_widths", None)  # duck-typed: no base
+        widths &= set(takes(capacity)) if takes is not None else {capacity}
+    return tuple(sorted(widths))
+
+
 def _pcall(ex, phase, fn, *args):
     """Dispatch-attributed call for executor entry points OUTSIDE
     walk_chain (join apply_left/right, on_barrier in two-input shapes)
@@ -168,6 +181,18 @@ class Pipeline(FreshnessSurface):
         """Feed one data chunk into the chain; returns what falls out."""
         self._note_ingest()
         return walk_chain(self.executors[start:], [chunk])
+
+    # -- the push lattice (StreamingRuntime.push, PR 32) -------------------
+    def push_widths(self, capacity: int):
+        """The widths at which this fragment takes a pushed chunk."""
+        return chain_push_widths(self.executors, capacity)
+
+    def warm_push(self, chunk: StreamChunk, side: str = "single"):
+        """A chunk with no valid row down the chain, the way ``push``
+        sends one, through every executor's ``warm``: the programs of a
+        chunk of this width exist afterwards and nothing is marked.
+        Returns what falls out, for the subscribers' own pass."""
+        return warm_chain(self.executors, [chunk]) or []
 
     def barrier(
         self, checkpoint: bool = True, epoch: Optional[int] = None

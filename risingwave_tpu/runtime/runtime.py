@@ -215,6 +215,10 @@ class StreamingRuntime:
         # upstream -> [(downstream, side)]; side targets one input of a
         # two-input fragment ("left"/"right") or "single"
         self._subs: Dict[str, List[Tuple[str, str]]] = {}
+        # (fragment, side, capacity) -> the widths a chunk built at
+        # that capacity may be cut to on its way in (_push_widths);
+        # emptied whenever the fragments or their edges change
+        self._push_plans: Dict[Tuple[str, str, int], Tuple[int, ...]] = {}
         self._aux_state: List[object] = []
         self.barrier_interval_ms = barrier_interval_ms
         self.checkpoint_frequency = checkpoint_frequency
@@ -394,6 +398,7 @@ class StreamingRuntime:
         if upstream is not None and upstream not in self.fragments:
             raise KeyError(f"unknown upstream fragment {upstream!r}")
         self.fragments[name] = pipeline
+        self._push_plans.clear()
         set_label = getattr(pipeline, "set_label", None)
         if set_label is not None:  # graph-backed: actors get unique keys
             set_label(name)
@@ -490,6 +495,7 @@ class StreamingRuntime:
                         f"{prev_up!r} exposes {sorted(prev_sig.items())}"
                     )
         self._subs.setdefault(upstream, []).append((name, side))
+        self._push_plans.clear()
         if backfill:
             from risingwave_tpu.runtime.backfill import snapshot_chunks
 
@@ -504,6 +510,7 @@ class StreamingRuntime:
         ddl_controller.rs + barrier/recovery.rs 'clean dirty jobs')."""
         self.fragments.pop(name, None)
         self._subs.pop(name, None)
+        self._push_plans.clear()
         FRESHNESS.drop(name)
         with self._replay_lock:
             self._replay.pop(name, None)
@@ -525,6 +532,7 @@ class StreamingRuntime:
             raise KeyError(f"unknown fragment {old!r}")
         if new in self.fragments:
             raise ValueError(f"fragment {new!r} already registered")
+        self._push_plans.clear()
         # rebuilt in place so the barrier walk's topological order holds
         self.fragments = {
             (new if k == old else k): v for k, v in self.fragments.items()
@@ -639,18 +647,83 @@ class StreamingRuntime:
     def push(self, name: str, chunk: StreamChunk, side: str = "single"):
         """Feed one chunk into a fragment and route its emitted deltas
         into every subscribed downstream fragment (the exchange edge an
-        MV-on-MV chain rides)."""
+        MV-on-MV chain rides). A host-built chunk goes in as wide as
+        what it holds (``_cut_to_rows``)."""
         # ingest attribution: the next barrier's EpochTrace charges this
         # host time + chunk bytes to its "ingest" stage
         with bind(self._open_stages), span(
             "push", stage="ingest", fragment=name, side=side,
             rows=chunk.host_rows,
-        ):
-            outs = self._push_into(name, chunk, side)
+        ) as sp:
+            cut = self._cut_to_rows(name, chunk, side)
+            sp.args["capacity"] = cut.capacity
+            outs = self._push_into(name, cut, side)
             REGISTRY.counter("chunks_pushed_total").inc(fragment=name)
+            REGISTRY.counter("push_chunks_total").inc(
+                fragment=name, lanes=str(cut.capacity)
+            )
             self._route(name, outs)
         self._ingest_bytes += chunk_nbytes(chunk)
         return outs
+
+    # -- the push lattice (bucketing.push_lattice, PR 32) ------------------
+    def _cut_to_rows(
+        self, name: str, chunk: StreamChunk, side: str
+    ) -> StreamChunk:
+        """A host-built chunk cut to the smallest declared width that
+        holds its rows, where the fragment and everything the chunk is
+        routed on to take such widths. The decision rests on what the
+        host already has — ``host_rows`` (``from_numpy`` packs the rows
+        into the leading lanes and counts them) and the capacity —
+        and reads nothing off the device; a chunk a device step
+        derived (``host_rows`` None) goes in as it is."""
+        rows = chunk.host_rows
+        if rows is None:
+            return chunk
+        widths = self._push_plans.get((name, side, chunk.capacity))
+        if widths is None:
+            widths = self._plan_push(name, chunk, side)
+        lanes = next(w for w in widths if w >= rows)
+        return chunk if lanes == chunk.capacity else chunk.leading(lanes)
+
+    def _push_widths(self, name: str, capacity: int) -> set:
+        """What ``name`` and every fragment subscribed behind it take
+        of a chunk built at ``capacity`` lanes: a fragment that does
+        not say takes the full width alone."""
+        takes = getattr(self.fragments[name], "push_widths", None)
+        widths = set(takes(capacity)) if takes is not None else {capacity}
+        for sub, _side in self._subs.get(name, ()):
+            widths &= self._push_widths(sub, capacity)
+        return widths
+
+    def _plan_push(
+        self, name: str, chunk: StreamChunk, side: str
+    ) -> Tuple[int, ...]:
+        """First chunk built at this capacity on this route: settle the
+        widths it may be cut to and, where there is more than one, send
+        a chunk with no valid row of EVERY one of them the same way
+        (``warm_push``), so that each width's programs exist before a
+        stream meets it and a size first met compiles nothing. Only
+        here does the runtime learn the width its feeder builds: the
+        capacity is the pusher's, no setting of the session."""
+        if self._pending_partial is not None:
+            return (chunk.capacity,)  # fenced fragments: settle later
+        widths = tuple(sorted(self._push_widths(name, chunk.capacity)))
+        self._push_plans[(name, side, chunk.capacity)] = widths
+        if len(widths) > 1:
+            for lanes in widths:
+                whole = lanes == chunk.capacity
+                self._warm_into(
+                    name,
+                    (chunk if whole else chunk.leading(lanes)).emptied(),
+                    side,
+                )
+        return widths
+
+    def _warm_into(self, name: str, chunk: StreamChunk, side: str) -> None:
+        for out in self.fragments[name].warm_push(chunk, side):
+            for sub, sub_side in self._subs.get(name, ()):
+                self._warm_into(sub, out, sub_side)
 
     def _route(self, upstream: str, chunks) -> None:
         for sub, side in self._subs.get(upstream, ()):

@@ -26,6 +26,7 @@ from risingwave_tpu.runtime.bucketing import (
     lattice_between,
     padding_stats,
     pow2_at_least,
+    push_lattice,
     validate_lattice,
 )
 
@@ -367,6 +368,51 @@ def test_fused_pads_stay_small_or_full(out_cap, n_take):
                 break
         pads = flush_pad_schedule(bound, capacity, out_cap)
         assert pads == tuple(want) and set(pads) <= {small, full}
+
+
+@pytest.mark.parametrize("capacity", [1 << 10, 1 << 12, 1 << 13, 1 << 16])
+def test_push_lattice_is_a_quarter_and_the_chunks_own_capacity(capacity):
+    """PR 32: the widths a pushed chunk may be cut to are one x4 step
+    below its capacity and the capacity: never wider than the chunk,
+    and a lattice the bucketing layer can satisfy."""
+    lattice = push_lattice(capacity)
+    assert lattice == (capacity // 4, capacity)
+    assert max(lattice) == capacity and validate_lattice(lattice) is None
+
+
+@pytest.mark.parametrize("capacity", [2, 4, 100, 512, 1000, 3 << 10])
+def test_push_lattice_leaves_small_and_odd_capacities_whole(capacity):
+    """An INSERT's few-row chunk, and a capacity that is no power of
+    two, have one width: their own."""
+    assert push_lattice(capacity) == (capacity,)
+
+
+def test_shape_domain_lists_the_push_lattice_for_q8s_join_ports():
+    """The analysis reads the set the runtime cuts to: the q8 join's
+    two input chains and the join take a chunk built at 8,192 lanes at
+    2,048 and at 8,192; a chain with an epoch-batched aggregate takes
+    8,192 alone."""
+    from risingwave_tpu.analysis.shape_domain import (
+        ChunkSpec,
+        push_lattice_specs,
+    )
+    from risingwave_tpu.executors.epoch_batch import EpochBatchedAggExecutor
+    from risingwave_tpu.queries.nexmark_q import build_q5_lite, build_q8
+
+    q8 = build_q8(capacity=1 << 10, fanout=4, out_cap=1 << 10).pipeline
+    spec = ChunkSpec.from_schema(
+        {"id": I64, "name": jnp.int32, "date_time": I64}, capacity=1 << 13
+    )
+    for chain in (q8.left, q8.right):
+        specs = push_lattice_specs(spec, list(chain) + [q8.join])
+        assert [s.capacity for s in specs] == [1 << 11, 1 << 13]
+        assert all(s.columns == spec.columns for s in specs)
+    agg = next(
+        ex for ex in build_q5_lite(capacity=1 << 10).pipeline.executors
+        if hasattr(ex, "apply_stacked")
+    )
+    batched = [EpochBatchedAggExecutor([], agg)]
+    assert [s.capacity for s in push_lattice_specs(spec, batched)] == [1 << 13]
 
 
 @pytest.mark.parametrize("out_cap", sorted(_LATTICES))
