@@ -306,6 +306,8 @@ class HashJoinExecutor(Executor, Checkpointable):
         a watermark on either clears state of both sides below it.
     """
 
+    layout = "bucket"
+
     def __init__(
         self,
         left_keys: Sequence[str],

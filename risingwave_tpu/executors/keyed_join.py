@@ -128,6 +128,7 @@ class KeyedJoinExecutor(Executor, Checkpointable):
     """
 
     join_type = "inner"
+    layout = "flat"
     window_cols = None
 
     def __init__(

@@ -37,13 +37,26 @@ def _build_and_load() -> Optional[ctypes.CDLL]:
         so = os.path.join(_BUILD_DIR, f"librw_native_{tag}.so")
         if not os.path.exists(so):
             os.makedirs(_BUILD_DIR, exist_ok=True)
-            tmp = so + ".tmp"
-            subprocess.run(
-                ["g++", "-O3", "-std=c++17", "-shared", "-fPIC", src, "-o", tmp],
-                check=True,
-                capture_output=True,
-            )
-            os.replace(tmp, so)
+            # a temporary of this process's own: several processes may
+            # build at once from a fresh checkout (pytest -n 6), and a
+            # shared name had one linker write into the file another
+            # had already moved into place, or find its output gone;
+            # the loser then fell back to Python for its whole life
+            tmp = f"{so}.{os.getpid()}.tmp"
+            try:
+                subprocess.run(
+                    ["g++", "-O3", "-std=c++17", "-shared", "-fPIC", src,
+                     "-o", tmp],
+                    check=True,
+                    capture_output=True,
+                )
+                os.replace(tmp, so)
+            except (OSError, subprocess.CalledProcessError):
+                if not os.path.exists(so):  # nobody else landed it either
+                    raise
+            finally:
+                if os.path.exists(tmp):
+                    os.remove(tmp)
             # only after the new build landed: drop artifacts of prior
             # source versions (a failed compile must not delete the
             # last working library)

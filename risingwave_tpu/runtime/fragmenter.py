@@ -769,18 +769,29 @@ def sharded_planned_mv(planner_factory, sql: str, n_shards: int):
         tp = proto.pipeline
         left = _shard_side_chain(tp.left, mesh, tp.head)
         right = _shard_side_chain(tp.right, mesh, tp.head)
-        if left is None or right is None:
+        join = tp.join
+        if (
+            left is None
+            or right is None
+            # a residual evaluated inside the join (the chained layout)
+            # has no sharded twin: one actor
+            or getattr(join, "condition", None) is not None
+        ):
             gp = _two_input_graph([proto], None)
         else:
-            join = tp.join
+            # the sharded join is the bucket layout across devices,
+            # whichever layout the planner chose for one device (the
+            # chained one declares no fan-out: the bucket layout's 16)
+            side_cap = join.left.table.capacity
+            fanout = getattr(join.left, "fanout", 16)
             sj = ShardedHashJoin(
                 mesh,
                 join.left_keys,
                 join.right_keys,
                 {n_: a.dtype for n_, a in join.left.rows.items()},
                 {n_: a.dtype for n_, a in join.right.rows.items()},
-                capacity=join.left.capacity,
-                fanout=join.left.fanout,
+                capacity=side_cap,
+                fanout=fanout,
                 out_cap=join.out_cap,
                 left_nullable=tuple(join.left.row_nulls),
                 right_nullable=tuple(join.right.row_nulls),
@@ -806,7 +817,7 @@ def sharded_planned_mv(planner_factory, sql: str, n_shards: int):
                     mesh,
                     out_dtypes,
                     out_nulls,
-                    capacity=join.left.capacity,
+                    capacity=side_cap,
                 )
             if tail is None:
                 tail_chain = [FlattenExecutor()] + list(tp.tail)

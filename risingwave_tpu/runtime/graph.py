@@ -449,6 +449,9 @@ class FragmentActor(threading.Thread):
         else:
             sections = [
                 ("tail", self.tail),
+                # a join that hands on its own sizes (a pair buffer cut
+                # to a lattice) and not its input's
+                ("join", [self.join_exec]),
                 ("right", self.right_chain),
                 ("left", self.chain),
                 ("head", self.head),
@@ -473,6 +476,8 @@ class FragmentActor(threading.Thread):
     def _warm_from(self, section: str, chain, chunks) -> List[StreamChunk]:
         """Warm-up chunks down the rest of a chain and on through what
         the section feeds inside this actor; what leaves the actor."""
+        if section == "join":
+            section, chain = "tail", self.tail
         outs = warm_chain(chain, chunks, tap=self._warm_tap)
         if outs is None or section == "tail":
             return outs or []
@@ -543,7 +548,10 @@ class FragmentActor(threading.Thread):
             for c in self._through(chain, chunks):
                 # the join step's enqueue (the device runs it
                 # asynchronously)
-                with span("actor.join_step", side=side):
+                with span(
+                    "actor.join_step", side=side,
+                    layout=getattr(self.join_exec, "layout", None),
+                ):
                     outs.extend(_pcall(self.join_exec, "apply", feed, c))
         for c in outs:
             self._tap(c)

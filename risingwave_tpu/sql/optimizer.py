@@ -694,6 +694,71 @@ def explain_sql(sql: str, catalog=None) -> str:
         + explain(after)
         + _explain_shared(emit(after), catalog)
         + _explain_topn(select)
+        + _explain_stream_join(sql, catalog)
+    )
+
+
+def _explain_stream_join(sql: str, catalog) -> str:
+    """A join planned onto the chained layout (sql/planner.py,
+    ``_join_core_rel``), as the planner builds it: each side's chain,
+    the join with its layout, keys and residual, and what follows it
+    inside the same actor — every aggregate with the state it keeps.
+    Planned on a copy of the catalog at a small capacity; nothing
+    registers."""
+    import copy
+
+    from risingwave_tpu.executors.hash_agg import HashAggExecutor
+    from risingwave_tpu.executors.stream_join import StreamJoinExecutor
+    from risingwave_tpu.sql.planner import StreamPlanner
+
+    if catalog is None:
+        return ""
+    scratch = copy.copy(catalog)
+    for attr in ("tables", "mvs", "indexes", "watermarks", "table_pks"):
+        setattr(scratch, attr, dict(getattr(catalog, attr)))
+    try:
+        planned = StreamPlanner(scratch, capacity=1 << 10).plan(sql)
+    except Exception:  # noqa: BLE001 - EXPLAIN of what does not plan
+        return ""
+    join = getattr(planned.pipeline, "join", None)
+    if not isinstance(join, StreamJoinExecutor):
+        return ""
+
+    def one(ex) -> str:
+        if isinstance(ex, HashAggExecutor):
+            calls = ", ".join(
+                f"{c.kind}({c.input or '*'}) AS {c.output}"
+                + (" [materialized input]" if c.materialized else "")
+                for c in ex.calls
+            )
+            return (
+                f"HashAgg group=[{', '.join(ex.group_keys)}] "
+                f"calls=[{calls}]"
+            )
+        pk = getattr(ex, "pk", None)
+        name = type(ex).__name__.replace("Executor", "")
+        return f"{name} pk=[{', '.join(pk)}]" if pk is not None else name
+
+    def chain(execs) -> str:
+        return " -> ".join(one(ex) for ex in execs) or "(the scan)"
+
+    keys = ", ".join(
+        f"{l} = {r}" for l, r in zip(join.left_keys, join.right_keys)
+    )
+    sides = ", ".join(
+        f"{n} {'inserts only' if not join._retract[n] else 'updating'}"
+        for n in ("left", "right")
+    )
+    pipe = planned.pipeline
+    return (
+        "-- stream plan: one two-input actor\n"
+        f"left  {chain(pipe.left)}\n"
+        f"right {chain(pipe.right)}\n"
+        f"StreamJoin layout={join.layout} type=inner keys=[{keys}] "
+        f"residual=[{join.condition if join.condition is not None else ''}] "
+        f"({sides}; stores {', '.join(join.left_names)} | "
+        f"{', '.join(join.right_names)})\n"
+        f"tail  {chain(pipe.tail)}\n"
     )
 
 

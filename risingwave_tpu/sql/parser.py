@@ -578,7 +578,31 @@ class Parser:
                     right = TableRef(right.name, alias)
             self.expect("kw", "on")
             rel = Join(rel, right, self.expr(), jt)
+        comma = False
+        if self.accept("op", ","):
+            # FROM a, b WHERE ...: an INNER join whose ON is the WHERE
+            # (its cross-side equalities become the join's keys, the
+            # rest the residual: sigma over a product)
+            if isinstance(rel, Join):
+                raise SyntaxError(
+                    "a comma in FROM after a JOIN is unsupported: write "
+                    "every side with JOIN ... ON"
+                )
+            right = self.relation()
+            if self.peek().kind == "op" and self.peek().value == ",":
+                raise SyntaxError(
+                    "a FROM list of more than two relations is "
+                    "unsupported: nest the joins with JOIN ... ON"
+                )
+            comma = True
         where = self.expr() if self.accept("kw", "where") else None
+        if comma:
+            if where is None:
+                raise SyntaxError(
+                    "a FROM list needs a WHERE with a cross-side "
+                    "equality (a cross product is unsupported)"
+                )
+            rel, where = Join(rel, right, where, "inner"), None
         group: Tuple[Ident, ...] = ()
         gsets: Tuple[Tuple[Ident, ...], ...] = ()
         if self.accept("kw", "group"):

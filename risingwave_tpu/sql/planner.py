@@ -497,6 +497,26 @@ def _unqualified(ast):
     return ast
 
 
+def _map_idents(ast, fn):
+    """``ast`` with every column reference of THIS select replaced by
+    ``fn(ident)``; a nested select is a scope of its own and is left."""
+    if isinstance(ast, P.Ident):
+        return fn(ast)
+    if isinstance(ast, (P.Select, P.SubQuery)):
+        return ast
+    if isinstance(ast, tuple):
+        return tuple(_map_idents(a, fn) for a in ast)
+    if dataclasses.is_dataclass(ast):
+        return dataclasses.replace(
+            ast,
+            **{
+                f.name: _map_idents(getattr(ast, f.name), fn)
+                for f in dataclasses.fields(ast)
+            },
+        )
+    return ast
+
+
 def _share_key(select: P.Select):
     """What two sub-selects must agree on to be one sub-plan: the
     relation, the WHERE, the SET of GROUP BY columns and the aggregate
@@ -755,9 +775,11 @@ class StreamPlanner:
             else:
                 planned = self._try_delta_join(name, select)
                 if planned is None:
-                    planned = self._plan_join(name, select)
+                    planned = self._plan_join(
+                        name, self._bare_join_sides(select)
+                    )
         elif planned is None:
-            planned = self._plan_single(name, select)
+            planned = self._plan_single(name, self._bare_join_sides(select))
         if eowc:
             # EMIT ON WINDOW CLOSE needs a watermark-cleaned windowed
             # plan — silently accepting it on ANY plan shape with no
@@ -899,6 +921,13 @@ class StreamPlanner:
 
     # -- single-input ----------------------------------------------------
     def _plan_single(self, name: str, select: P.Select) -> PlannedMV:
+        if isinstance(select.from_, P.SubQuery) and self._over_join(select):
+            aux: List[PlannedMV] = []
+            parts, rel = self._join_rel(name, select, aux)
+            planned = self._joined_mv(
+                name, parts, rel, self._make_mview(name, rel)
+            )
+            return dataclasses.replace(planned, aux=tuple(aux))
         rel = self._plan_rel(name, select)
         mview = self._make_mview(name, rel)
         pipeline = Pipeline(rel.chain + [mview])
@@ -1009,13 +1038,14 @@ class StreamPlanner:
         raise TypeError(f"unsupported FROM {src!r}")
 
     def _scan_append_only(self, source: str) -> bool:
-        """A base table's stream is taken as inserts only (what every
-        plan assumed before the flag existed); an MV's is what its own
-        plan derived."""
+        """A base table's stream is taken as inserts only unless it
+        declares a PRIMARY KEY; an MV's is what its own plan derived."""
         if self.catalog.is_mv(source):
             # (an attached shared MV carries no plan of its own)
             return getattr(self.catalog.mvs[source], "append_only", True)
-        return True
+        # a table with a declared key is one its owner updates and
+        # deletes from (DML names a row by it)
+        return source not in self.catalog.table_pks
 
     def _maybe_watermark_filter(
         self, chain: List[Executor], source: str, schema
@@ -1582,6 +1612,21 @@ class StreamPlanner:
         ext_acc = _ext_agg_acc()  # deduped hidden calls + pre inputs
         finishing: Dict[str, object] = {}  # visible out -> Expr over hidden
         for i, item in enumerate(select.items):
+            # a SUM / COUNT the select lists itself is the one an
+            # extended aggregate over the same column is made of
+            # (AVG(v), SUM(v), COUNT(v): two base calls, not four)
+            ast = item.expr
+            if (
+                _is_agg(ast)
+                and ast.name in ("sum", "count")
+                and not getattr(ast, "distinct", False)
+                and isinstance(ast.args[0], P.Ident)
+            ):
+                ext_acc["hidden"].setdefault(
+                    (AGG_FUNCS[ast.name], binder.resolve(ast.args[0])),
+                    item.alias or f"{ast.name}_{i}",
+                )
+        for i, item in enumerate(select.items):
             ast = item.expr
             if _is_agg(ast):
                 out = item.alias or f"{ast.name}_{i}"
@@ -2112,6 +2157,60 @@ class StreamPlanner:
     def _plan_join_core(
         self, name: str, select: P.Select, aux: List[PlannedMV]
     ) -> PlannedMV:
+        parts, rel = self._join_rel(name, select, aux)
+        return self._joined_mv(
+            name, parts, rel,
+            MaterializeExecutor(
+                pk=rel.pk,
+                columns=tuple(c for c in rel.schema if c not in rel.pk),
+                table_id=f"{name}.mview",
+            ),
+        )
+
+    @staticmethod
+    def _joined_mv(name, parts, rel: BoundRel, mview) -> PlannedMV:
+        left, right, hj, head = parts
+        return PlannedMV(
+            name,
+            TwoInputPipeline(
+                left.chain, right.chain, hj, rel.chain + [mview], head=head
+            ),
+            mview,
+            _join_inputs(left.source, right.source),
+            schema=rel.schema,
+            append_only=rel.append_only,
+        )
+
+    @staticmethod
+    def _over_join(select) -> bool:
+        """The select reads a join through derived tables alone."""
+        while isinstance(select, P.Select) and isinstance(
+            select.from_, P.SubQuery
+        ):
+            select = select.from_.select
+        return (
+            isinstance(select, P.Select)
+            and isinstance(select.from_, P.Join)
+            and not select.from_.join_type.startswith("temporal")
+        )
+
+    def _join_rel(self, name: str, select: P.Select, aux: List[PlannedMV]):
+        """A select FROM a join, or FROM a derived table that is one
+        (an aggregate over a join's aggregate: NEXmark q4), as ONE
+        two-input plan: ((left, right, join, shared head), what follows
+        the join as a BoundRel whose chain is the tail so far). Each
+        derived table's select goes on behind the inner one's tail
+        (``_plan_rel`` over an input already bound), so its aggregate
+        reads the inner one's change stream inside the same barrier."""
+        if isinstance(select.from_, P.Join):
+            return self._join_core_rel(name, select, aux)
+        parts, inner = self._join_rel(name, select.from_.select, aux)
+        inner.alias = select.from_.alias
+        return parts, self._plan_rel(name, select, pre=inner)
+
+    def _join_core_rel(
+        self, name: str, select: P.Select, aux: List[PlannedMV]
+    ):
         head, join = self._plan_shared_head(name, select.from_)
         if isinstance(join.left, P.Join):
             left = self._lower_nested_join(name, join.left, aux)
@@ -2157,6 +2256,49 @@ class StreamPlanner:
             jt, lkeys, rkeys, left, right, cond, join_tid
         )
         post_join: List[Executor] = []
+        grouped = bool(select.group_by) or any(
+            _contains_agg(it.expr) for it in select.items
+        )
+        if (
+            hj is None
+            and jt == "inner"
+            and not (
+                self._key_tied(left, lkeys) and self._key_tied(right, rkeys)
+            )
+        ):
+            # a side that holds as many rows under one join key as
+            # arrive for it: the chained layout (two groupings joined
+            # on their keys, as NEXmark q8's two dedups, keep their
+            # buckets and the programs they had)
+            from risingwave_tpu.executors.stream_join import (
+                StreamJoinExecutor,
+            )
+
+            if grouped:
+                # an aggregate reads what it names: the sides store that
+                read = set(lkeys) | set(rkeys)
+                for part in (
+                    residual, select.where, select.group_by,
+                    tuple(it.expr for it in select.items),
+                ):
+                    _map_idents(
+                        part,
+                        lambda i: read.add(self._join_resolve(i, left, right))
+                        or i,
+                    )
+                left = self._only(left, read)
+                right = self._only(right, read)
+            hj = StreamJoinExecutor(
+                left_keys=lkeys,
+                right_keys=rkeys,
+                left_dtypes=left.schema,
+                right_dtypes=right.schema,
+                condition=cond,
+                left_append_only=left.append_only,
+                right_append_only=right.append_only,
+                capacity=self.capacity,
+                table_id=join_tid,
+            )
         if hj is None:
             hj = HashJoinExecutor(
                 left_keys=lkeys,
@@ -2200,9 +2342,9 @@ class StreamPlanner:
             # GROUP BY over the joined stream (the q7 shape;
             # reference optimizer: StreamHashAgg over StreamHashJoin).
             # Join output can retract (deletes / NULL-pad transitions),
-            # so MIN/MAX escalate to materialized-input state; inner
-            # joins of append-only sides retract too (a dedup upstream
-            # or U- pairs), keep it on unconditionally.
+            # so MIN/MAX escalate to materialized-input state; an inner
+            # join of two insert-only sides only ever inserts pairs, and
+            # MIN/MAX over it keep one value a group.
             for ident in _idents_in_select(select):
                 n = self._join_resolve(ident, left, right)
                 if n not in visible:
@@ -2216,7 +2358,7 @@ class StreamPlanner:
                 padded |= frozenset(left.schema)
             gchain, gout, gpk = self._plan_groupby(
                 name, select, binder, {**left.schema, **right.schema},
-                retractable=True, nullable_cols=padded,
+                retractable=not out_append_only, nullable_cols=padded,
             )
             tail.extend(gchain)
             if select.having is not None:
@@ -2225,21 +2367,8 @@ class StreamPlanner:
                         compile_scalar(select.having, Binder(gout, None))
                     )
                 )
-            mview = MaterializeExecutor(
-                pk=gpk,
-                columns=tuple(c for c in gout if c not in gpk),
-                table_id=f"{name}.mview",
-            )
-            tail.append(mview)
-            pipeline = TwoInputPipeline(
-                left.chain, right.chain, hj, tail, head=head
-            )
-            return PlannedMV(
-                name,
-                pipeline,
-                mview,
-                _join_inputs(left.source, right.source),
-                schema=gout,
+            return (left, right, hj, head), BoundRel(
+                tail, gout, gpk, left.source, None, append_only=False
             )
         if not semi_anti and any(
             _contains_agg(it.expr) for it in select.items
@@ -2326,21 +2455,8 @@ class StreamPlanner:
                         jnp.float64 if _has_float_lit(orig) else jnp.int64
                     )
             tail.append(ProjectExecutor(outputs))
-            mview = MaterializeExecutor(
-                pk=(),
-                columns=tuple(gout),
-                table_id=f"{name}.mview",
-            )
-            tail.append(mview)
-            pipeline = TwoInputPipeline(
-                left.chain, right.chain, hj, tail, head=head
-            )
-            return PlannedMV(
-                name,
-                pipeline,
-                mview,
-                _join_inputs(left.source, right.source),
-                schema=gout,
+            return (left, right, hj, head), BoundRel(
+                tail, gout, (), left.source, None, append_only=False
             )
 
         out_names = []
@@ -2362,29 +2478,13 @@ class StreamPlanner:
             proj.setdefault(p, E.col(p))
         tail.append(ProjectExecutor(proj))
         rename = {n: (alias or n) for n, alias in out_names}
-        mview = MaterializeExecutor(
-            pk=tuple(rename.get(p, p) for p in pk),
-            columns=tuple(
-                alias or n for n, alias in out_names
-                if (alias or n) not in {rename.get(p, p) for p in pk}
-            ),
-            table_id=f"{name}.mview",
-        )
-        tail.append(mview)
-        pipeline = TwoInputPipeline(
-            left.chain, right.chain, hj, tail, head=head
-        )
         merged = {**left.schema, **right.schema}
         out_schema = {alias or n: merged[n] for n, alias in out_names}
         for p in pk:
             out_schema.setdefault(rename.get(p, p), merged[p])
-        return PlannedMV(
-            name,
-            pipeline,
-            mview,
-            _join_inputs(left.source, right.source),
-            schema=out_schema,
-            append_only=out_append_only,
+        return (left, right, hj, head), BoundRel(
+            tail, out_schema, tuple(rename.get(p, p) for p in pk),
+            left.source, None, append_only=out_append_only,
         )
 
     def _plan_shared_head(self, name: str, join: P.Join):
@@ -2467,6 +2567,33 @@ class StreamPlanner:
                     table_id=table_id,
                 )
         return None
+
+    @staticmethod
+    def _key_tied(rel: BoundRel, keys) -> bool:
+        """The rows a join key holds on this side are tied to a key of
+        the side's own: its stream key lies within its join columns
+        (one row a join key) or holds them all (a grouping joined on
+        part of its group key: as many rows as groups differ in the
+        rest). A stream of rows — keyed by the hidden row id alone —
+        or a keyed table joined on another column is tied to nothing."""
+        pk, keys = set(rel.pk), set(keys)
+        return bool(pk) and (pk <= keys or keys <= pk)
+
+    @staticmethod
+    def _only(rel: BoundRel, names) -> BoundRel:
+        """``rel`` cut to the columns among ``names``."""
+        keep = [c for c in rel.schema if c in names]
+        if len(keep) == len(rel.schema):
+            return rel
+        return BoundRel(
+            rel.chain + [ProjectExecutor({c: E.col(c) for c in keep})],
+            {c: rel.schema[c] for c in keep},
+            tuple(rel.pk) if set(rel.pk) <= set(keep) else (),
+            rel.source,
+            rel.alias,
+            window_col=rel.window_col if rel.window_col in keep else None,
+            append_only=rel.append_only,
+        )
 
     def _rel_of(self, name: str, rel) -> BoundRel:
         if isinstance(rel, _Planned):
@@ -2581,6 +2708,162 @@ class StreamPlanner:
                 rel.alias or rel.name,
             )
         return rel
+
+    def _bare_join_sides(self, select):
+        """A bare table (or view) as a join side -> a derived table of
+        the columns the select reads from it, so that a side stores
+        those and no others. A column both sides are read for (each
+        NEXmark table has a ``date_time``) is renamed
+        ``<alias>__<column>`` on a bare side and the select's references
+        follow; an item that was such a bare column keeps its name
+        through an alias. Applied to every select of the statement
+        that is FROM a join, derived tables included."""
+        if isinstance(select, P.UnionAll) or not isinstance(select, P.Select):
+            return select
+        from_ = select.from_
+        if isinstance(from_, P.SubQuery):
+            inner = self._bare_join_sides(from_.select)
+            if inner is not from_.select:
+                select = dataclasses.replace(
+                    select, from_=P.SubQuery(inner, from_.alias)
+                )
+            return select
+        if not isinstance(from_, P.Join):
+            return select
+        sides = []
+        for rel in (from_.left, from_.right):
+            if isinstance(rel, P.SubQuery):
+                rel = P.SubQuery(self._bare_join_sides(rel.select), rel.alias)
+            sides.append(rel)
+        bare = [
+            isinstance(r, P.TableRef) and r.name in self.catalog.tables
+            for r in sides
+        ]
+        if from_.join_type.startswith("temporal") or not any(bare):
+            if sides != [from_.left, from_.right]:
+                select = dataclasses.replace(
+                    select,
+                    from_=dataclasses.replace(
+                        from_, left=sides[0], right=sides[1]
+                    ),
+                )
+            return select
+
+        def columns(rel):
+            """The names a side offers, or None where they are not
+            written down (SELECT *, a nested join)."""
+            if isinstance(rel, P.TableRef):
+                return list(self.catalog.schema_dtypes(rel.name))
+            if isinstance(rel, P.SubQuery):
+                out = []
+                for it in rel.select.items:
+                    if it.alias is not None:
+                        out.append(it.alias)
+                    elif isinstance(it.expr, P.Ident):
+                        out.append(it.expr.name)
+                    elif isinstance(it.expr, P.Star):
+                        return None
+                return out
+            return None
+
+        offers = [columns(r) for r in sides]
+        quals = [
+            (r.alias or r.name) if isinstance(r, P.TableRef)
+            else getattr(r, "alias", None)
+            for r in sides
+        ]
+
+        def side_of(ident: P.Ident):
+            if ident.qualifier is not None:
+                hits = [i for i in (0, 1) if quals[i] == ident.qualifier]
+            else:
+                hits = [
+                    i for i in (0, 1)
+                    if offers[i] is not None and ident.name in offers[i]
+                ]
+            return hits[0] if len(hits) == 1 else None
+
+        scope = (
+            tuple(it.expr for it in select.items), select.where,
+            select.group_by, select.having, from_.on, select.grouping_sets,
+            tuple(i for i, _ in select.order_by),
+        )
+        read = [[], []]
+
+        def note(ident):
+            i = side_of(ident)
+            if i is not None and ident.name not in read[i]:
+                read[i].append(ident.name)
+            return ident
+
+        _map_idents(scope, note)
+        if any(isinstance(it.expr, P.Star) for it in select.items):
+            return select  # every column is read: the sides stay whole
+        # a bare side's column that the other side is read for too
+        taken = [
+            set(read[i]) if bare[i] else set(offers[i] or ())
+            for i in (0, 1)
+        ]
+        rename = [{}, {}]
+        for i in (0, 1):
+            if bare[i]:
+                for c in read[i]:
+                    if c in taken[1 - i]:
+                        rename[i][c] = f"{quals[i]}__{c}"
+        for i in (0, 1):
+            if not bare[i]:
+                continue
+            missing = [c for c in read[i] if c not in offers[i]]
+            if missing:
+                raise KeyError(
+                    f"unknown column {quals[i]}.{missing[0]}"
+                )
+            sides[i] = P.SubQuery(
+                P.Select(
+                    items=tuple(
+                        P.SelectItem(P.Ident(c), rename[i].get(c))
+                        for c in read[i]
+                    ),
+                    from_=P.TableRef(sides[i].name, None),
+                    where=None,
+                    group_by=(),
+                ),
+                quals[i],
+            )
+
+        def renamed(ident):
+            i = side_of(ident)
+            if i is None or ident.name not in rename[i]:
+                return ident
+            return P.Ident(rename[i][ident.name], quals[i])
+
+        items = tuple(
+            P.SelectItem(
+                _map_idents(it.expr, renamed),
+                it.alias
+                if it.alias is not None or not isinstance(it.expr, P.Ident)
+                else (
+                    it.expr.name
+                    if renamed(it.expr) is not it.expr else None
+                ),
+            )
+            for it in select.items
+        )
+        return dataclasses.replace(
+            select,
+            items=items,
+            from_=dataclasses.replace(
+                from_, left=sides[0], right=sides[1],
+                on=_map_idents(from_.on, renamed),
+            ),
+            where=_map_idents(select.where, renamed),
+            group_by=_map_idents(select.group_by, renamed),
+            having=_map_idents(select.having, renamed),
+            grouping_sets=_map_idents(select.grouping_sets, renamed),
+            order_by=tuple(
+                (renamed(i), d) for i, d in select.order_by
+            ),
+        )
 
     def _semi_anti_join(
         self, from_, sub: P.Select, i: int, anti: bool, in_expr

@@ -183,3 +183,29 @@ def test_compaction_cas_preserves_racing_commit():
     mgr.store.read = orig_read
     keys, vals = mgr.read_table("t")
     assert sorted(keys["k"].tolist()) == [1, 2, 3]
+
+
+def test_native_library_survives_concurrent_first_builds(tmp_path):
+    """Several processes that find no built library (a fresh checkout
+    under ``pytest -n 6``) each build it and all load it: none falls
+    back to the Python map. They used to share one temporary file, and
+    the loser of that race kept the Python backend for its whole life,
+    which is what made the test above unsteady in a worker that lost."""
+    import subprocess
+    import sys
+
+    code = (
+        "import sys; from risingwave_tpu import native; "
+        "native._BUILD_DIR = sys.argv[1]; "
+        "print(native.get_lib() is not None)"
+    )
+    procs = [
+        subprocess.Popen(
+            [sys.executable, "-c", code, str(tmp_path / "build")],
+            stdout=subprocess.PIPE, text=True,
+        )
+        for _ in range(5)
+    ]
+    assert [p.communicate(timeout=300)[0].strip() for p in procs] == ["True"] * 5
+    built = [f for f in (tmp_path / "build").iterdir()]
+    assert len(built) == 1 and built[0].suffix == ".so"

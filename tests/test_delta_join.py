@@ -51,8 +51,11 @@ def test_create_index_and_delta_join_from_sql():
 
 def test_without_session_var_or_index_no_delta_join():
     """The delta rule declines without the session variable or the
-    indexes; the bare-table join then falls to the hash path (which
-    requires subquery-form sides — its existing contract)."""
+    indexes; the bare-table join then falls to the hash path, which
+    reads a bare table as the derived table of the columns the select
+    names (PR 33; it used to refuse one)."""
+    from risingwave_tpu.executors.lookup import DeltaJoinExecutor
+
     s = SqlSession(Catalog({}), capacity=1 << 10)
     s.execute("CREATE TABLE a (k BIGINT, x BIGINT)")
     s.execute("CREATE TABLE b (k BIGINT, y BIGINT)")
@@ -62,15 +65,15 @@ def test_without_session_var_or_index_no_delta_join():
         "CREATE MATERIALIZED VIEW hj AS "
         "SELECT a.k AS k, x, y FROM a JOIN b ON a.k = b.k"
     )
-    with pytest.raises(TypeError, match="subqueries"):
-        s.execute(sql)  # var off -> hash path -> bare tables rejected
+    s.execute(sql)  # var off -> the hash path
+    assert not isinstance(s.catalog.mvs["hj"].pipeline.join, DeltaJoinExecutor)
     s.execute("SET enable_delta_join = true")
     # no index covers (x)/(y): the delta rule declines
-    with pytest.raises(TypeError, match="subqueries"):
-        s.execute(
-            "CREATE MATERIALIZED VIEW hj2 AS "
-            "SELECT a.k AS k, x, y FROM a JOIN b ON a.x = b.y"
-        )
+    s.execute(
+        "CREATE MATERIALIZED VIEW hj2 AS "
+        "SELECT a.k AS k, x, y FROM a JOIN b ON a.x = b.y"
+    )
+    assert not isinstance(s.catalog.mvs["hj2"].pipeline.join, DeltaJoinExecutor)
     # subquery-form joins never take the delta path
     s.execute(
         "CREATE MATERIALIZED VIEW hj3 AS SELECT l.k AS k, x, y FROM "

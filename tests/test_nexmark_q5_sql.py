@@ -17,6 +17,7 @@ from risingwave_tpu.array.chunk import StreamChunk
 from risingwave_tpu.executors import HashAggExecutor, HashJoinExecutor
 from risingwave_tpu.executors.filter import ResidualFilterExecutor
 from risingwave_tpu.executors.keyed_join import KeyedJoinExecutor
+from risingwave_tpu.executors.stream_join import StreamJoinExecutor
 from risingwave_tpu.expr import expr as E
 from risingwave_tpu.frontend import SqlSession
 from risingwave_tpu.runtime import StreamingRuntime
@@ -199,8 +200,10 @@ def test_the_planned_agg_calls_of_q5_q7_and_the_sql_tests_plans():
         [c.output for c in ex.calls] for ex in q5.pipeline.executors
         if isinstance(ex, HashAggExecutor)
     ] == [["num"], ["maxn"]]
-    # q7: MAX(price) over the bid table's tumbling window keeps the latch
-    # and the bucket join; so does tests/test_sql.py's q5-lite count
+    # q7: MAX(price) over the bid table's tumbling window keeps the
+    # latch; its bid side is a stream of rows tied to no key of its
+    # own, so the join is the chained layout (PR 33), its residual
+    # none. tests/test_sql.py's q5-lite count keeps the latch too
     q7 = planner.plan(
         "CREATE MATERIALIZED VIEW q7 AS SELECT b.auction, b.price, b.bidder "
         "FROM (SELECT auction, price, bidder, window_start AS ws FROM "
@@ -210,7 +213,7 @@ def test_the_planned_agg_calls_of_q5_q7_and_the_sql_tests_plans():
         "AS m ON b.price = m.maxprice AND b.ws = m.mws"
     )
     assert not _calls(q7)["maxprice"].materialized
-    assert type(q7.pipeline.join) is HashJoinExecutor
+    assert type(q7.pipeline.join) is StreamJoinExecutor
     assert q7.inputs == {"bid": "both"} and q7.pipeline.head == []
     lite = planner.plan(
         "CREATE MATERIALIZED VIEW l AS SELECT auction, window_start, "

@@ -248,7 +248,9 @@ PINNED = {
                   ["ProjectExecutor", None]],
         "tail": [["ProjectExecutor", None],
                  ["MaterializeExecutor", "q7.mview"]],
-        "join": ["HashJoinExecutor", "q7.join4"],
+        # (PR 33: the bids of a window are a stream of rows, tied to no
+        # key of their own: the chained layout, under the same table id)
+        "join": ["StreamJoinExecutor", "q7.join4"],
         "inputs": {"bid": "both"},
     }),
     "q8": (_plan_q8, {
@@ -273,7 +275,9 @@ PINNED = {
                  ["SimpleAggExecutor", "q17.sagg7"],
                  ["ProjectExecutor", None],
                  ["MaterializeExecutor", "q17.mview"]],
-        "join": ["HashJoinExecutor", "q17.join6"],
+        # (PR 33: ``part`` declares a PRIMARY KEY, so its stream and
+        # the join under it update: the many side is stored flat)
+        "join": ["KeyedJoinExecutor", "q17.join6"],
         "inputs": {"q17__j0": "left", "lineitem": "right"},
     }),
 }
