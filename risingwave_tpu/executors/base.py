@@ -145,6 +145,9 @@ class Executor:
           (capacity derives from live-row counts => RW-E802).
         - ``emission_caps``: tuple of declared emission capacities
           (fixed/bucketed kinds).
+        - ``flush_walks``: for an aggregate, the declared lengths of
+          the list of touched slots its flush programs range over
+          (``bucketing.touched_lattice``).
         - ``window_buckets``: for window-keyed executors, the declared
           bucket lattice of the per-window shape domain, or None =
           unbucketed (window churn re-traces without bound =>

@@ -48,10 +48,12 @@ from risingwave_tpu.ops.agg import AggCall, AggState
 from risingwave_tpu.ops.hash_table import HashTable, lookup, lookup_or_insert, stage_scalars, set_live
 from risingwave_tpu.metrics import REGISTRY
 from risingwave_tpu.runtime.bucketing import (
+    TOUCHED_MAX,
     BucketAllocator,
     BucketPolicy,
     flush_lattice,
     flush_lattice_pad,
+    touched_lattice,
 )
 from risingwave_tpu.trace import span
 
@@ -122,13 +124,17 @@ def agg_step_fn(
     nullable: Tuple[bool, ...],
     minput=None,
     mi_bad=None,
+    touched=None,
+    at=None,
 ):
     """One chunk through the group map + agg update (pure; jit it).
 
     With ``minput`` (materialized MIN/MAX multisets, ops/minput.py) the
     same dispatch also folds the batch into those and returns
     ``(table, state, dropped, minput, mi_bad)``; otherwise the classic
-    3-tuple."""
+    3-tuple. With ``touched`` (the executor's list of the slots its
+    steps wrote since the last flush; ``at`` its cursor) the chunk's
+    slots are appended there and the list is handed back last."""
     keys = _build_key_lanes(chunk, group_keys, nullable)
     table, slots, _, _ = lookup_or_insert(table, keys, chunk.valid)
     signs = chunk.effective_signs()
@@ -141,18 +147,23 @@ def agg_step_fn(
     }
     state = agg_ops.apply(state, calls, slots, signs, values, nulls)
     table = set_live(table, slots, state.row_count[slots] > 0)
-    if minput is None:
-        return table, state, dropped
-    state, minput, mi_bad = _minput_pass(
-        state, dict(minput), mi_bad, calls, slots, signs, chunk
-    )
-    return table, state, dropped, minput, mi_bad
+    out = (table, state, dropped)
+    if minput is not None:
+        # (the pass writes the slots ``apply`` dirtied, no others)
+        state, minput, mi_bad = _minput_pass(
+            state, dict(minput), mi_bad, calls, slots, signs, chunk
+        )
+        out = (table, state, dropped, minput, mi_bad)
+    if touched is not None:
+        out += (agg_ops.note_touched(touched, at, slots),)
+    return out
 
 
 _agg_step = jax.jit(
     agg_step_fn,
     static_argnames=("calls", "group_keys", "nullable"),
     donate_argnums=(0, 1),
+    donate_argnames=("touched",),
 )
 
 
@@ -160,11 +171,15 @@ _agg_step = jax.jit(
     jax.jit,
     static_argnames=("calls", "group_keys", "nullable"),
     donate_argnums=(0, 1, 3, 4),
+    donate_argnames=("touched",),
 )
-def _agg_step_mi(table, state, dropped, minput, mi_bad, chunk, calls, group_keys, nullable):
+def _agg_step_mi(
+    table, state, dropped, minput, mi_bad, chunk, calls, group_keys,
+    nullable, touched=None, at=None,
+):
     return agg_step_fn(
         table, state, dropped, chunk, calls, group_keys, nullable,
-        minput, mi_bad,
+        minput, mi_bad, touched, at,
     )
 
 
@@ -196,7 +211,7 @@ def _agg_scan(
 
 def _epoch_reduced_fn(
     table, state, dropped, stacked, calls, group_keys, nullable, pre,
-    minput=None, mi_bad=None,
+    minput=None, mi_bad=None, touched=None, at=None,
 ):
     """The TPU-first epoch path: vmap the stateless prefix over the
     chunk axis, flatten the whole epoch into one row batch, pre-reduce
@@ -208,7 +223,8 @@ def _epoch_reduced_fn(
     (BENCH_r02 fault analysis) showed running 20-50x slower than the
     CPU actor. Commutativity across one epoch's rows makes the
     reordering exact (sum/count; append-only min/max latch retractions
-    either way)."""
+    either way). ``touched`` / ``at`` as in ``agg_step_fn``: the
+    distinct keys' slots, one lane a row of the batch."""
     if pre is not None:
         chunks = jax.vmap(pre)(stacked)
     else:
@@ -237,23 +253,31 @@ def _epoch_reduced_fn(
         jnp.where(rep_valid, slots, -1),
         state.row_count[jnp.where(slots >= 0, slots, 0)] > 0,
     )
-    if minput is None:
-        return table, state, dropped
-    # materialized MIN/MAX: re-probe (read-only) for EVERY flat row's
-    # slot — the rep insert above guarantees hits — then fold the raw
-    # rows into the multisets
-    row_signs = flat.effective_signs()
-    row_slots, _ = lookup(table, keys, flat.valid & (row_signs != 0))
-    state, minput, mi_bad = _minput_pass(
-        state, dict(minput), mi_bad, calls, row_slots, row_signs, flat
-    )
-    return table, state, dropped, minput, mi_bad
+    out = (table, state, dropped)
+    if minput is not None:
+        # materialized MIN/MAX: re-probe (read-only) for EVERY flat
+        # row's slot — the rep insert above guarantees hits — then fold
+        # the raw rows into the multisets
+        row_signs = flat.effective_signs()
+        row_slots, _ = lookup(table, keys, flat.valid & (row_signs != 0))
+        state, minput, mi_bad = _minput_pass(
+            state, dict(minput), mi_bad, calls, row_slots, row_signs, flat
+        )
+        out = (table, state, dropped, minput, mi_bad)
+    if touched is not None:
+        out += (
+            agg_ops.note_touched(
+                touched, at, jnp.where(rep_valid, slots, -1)
+            ),
+        )
+    return out
 
 
 _agg_epoch_reduced = partial(
     jax.jit,
     static_argnames=("calls", "group_keys", "nullable", "pre"),
     donate_argnums=(0, 1),
+    donate_argnames=("touched",),
 )(_epoch_reduced_fn)
 
 
@@ -261,14 +285,15 @@ _agg_epoch_reduced = partial(
     jax.jit,
     static_argnames=("calls", "group_keys", "nullable", "pre"),
     donate_argnums=(0, 1, 8, 9),
+    donate_argnames=("touched",),
 )
 def _agg_epoch_reduced_mi(
     table, state, dropped, stacked, calls, group_keys, nullable, pre,
-    minput, mi_bad,
+    minput, mi_bad, touched=None, at=None,
 ):
     return _epoch_reduced_fn(
         table, state, dropped, stacked, calls, group_keys, nullable, pre,
-        minput, mi_bad,
+        minput, mi_bad, touched, at,
     )
 
 
@@ -503,6 +528,15 @@ class HashAggExecutor(Executor, Checkpointable):
         # old status-read loop was RW-E801 at the top of the fusion
         # worklist).
         self._dirty_bound = 0
+        # the flush's list: the slots the steps wrote since the last
+        # flush, appended on the device by the step programs
+        # (agg_ops.note_touched) at the cursor the host keeps here, the
+        # lanes they appended (every lane a step ranged over, so what
+        # ``_dirty_bound`` counts). None = the list no longer names
+        # every dirty slot (they moved, or something dirtied the table
+        # wholesale): the next flush walks the table and starts it anew
+        self._touched = jnp.full(TOUCHED_MAX, -1, jnp.int32)
+        self._touched_lanes: Optional[int] = 0
         # lanes a chunk holds after the traced-in prefix, per chunk shape
         self._lanes_after_pre: Dict[tuple, int] = {}
         # shape-stability: capacity walks the allocator's pow2 lattice;
@@ -604,6 +638,8 @@ class HashAggExecutor(Executor, Checkpointable):
             "emission": "bucketed",
             "emission_caps": caps,
             "window_buckets": caps,
+            # the lengths of the steps' list a flush program ranges over
+            "flush_walks": self.touched_sizes(),
             # the interpreted flush pays one packed status read per
             # round; the fused per-barrier step compiles its own
             # device-side flush (runtime/fused_step._fused_barrier_fn)
@@ -632,6 +668,10 @@ class HashAggExecutor(Executor, Checkpointable):
     def flush_sizes(self) -> Tuple[int, ...]:
         """The sizes a flush chunk of this aggregate can have."""
         return flush_lattice(self._round_cap())
+
+    def touched_sizes(self) -> Tuple[int, ...]:
+        """The lengths of the steps' list a flush can range over."""
+        return touched_lattice(self.table.capacity)
 
     def pin_max_bucket(self):
         """ShapeGovernor hook: freeze the group table at its high-water
@@ -664,13 +704,27 @@ class HashAggExecutor(Executor, Checkpointable):
         self._maybe_grow(chunk.capacity)
         self._insert_bound += chunk.capacity
         self._dirty_bound += chunk.capacity
+        at = self._touched_at(chunk.capacity)
         # the step's enqueue (the device runs it asynchronously), as
         # actor.join_step is for a join
         with span("actor.agg_step", table_id=self.table_id):
-            self._step(chunk)
+            self._step(chunk, at)
         return []
 
-    def _step(self, chunk: StreamChunk) -> None:
+    def _touched_at(self, lanes: int, advance: bool = True) -> int:
+        """The list's cursor for a step over ``lanes`` lanes, moved past
+        them (``advance``; the warm-up pass writes no slot and leaves
+        it). A step the largest declared length has no room for gives
+        the list up; its write then lands where nothing reads."""
+        at = self._touched_lanes
+        if at is None or at + lanes > self.touched_sizes()[-1]:
+            self._touched_lanes = None
+            return 0
+        if advance:
+            self._touched_lanes = at + lanes
+        return at
+
+    def _step(self, chunk: StreamChunk, at: int) -> None:
         if self.minput:
             (
                 self.table,
@@ -678,6 +732,7 @@ class HashAggExecutor(Executor, Checkpointable):
                 self.dropped,
                 self.minput,
                 self.mi_bad,
+                self._touched,
             ) = _agg_step_mi(
                 self.table,
                 self.state,
@@ -688,9 +743,11 @@ class HashAggExecutor(Executor, Checkpointable):
                 self.calls,
                 self.group_keys,
                 self.nullable,
+                touched=self._touched,
+                at=at,
             )
         else:
-            self.table, self.state, self.dropped = _agg_step(
+            self.table, self.state, self.dropped, self._touched = _agg_step(
                 self.table,
                 self.state,
                 self.dropped,
@@ -698,6 +755,8 @@ class HashAggExecutor(Executor, Checkpointable):
                 self.calls,
                 self.group_keys,
                 self.nullable,
+                touched=self._touched,
+                at=at,
             )
 
     def apply_stacked(
@@ -727,12 +786,13 @@ class HashAggExecutor(Executor, Checkpointable):
         self._maybe_grow(lanes)
         self._insert_bound += lanes
         self._dirty_bound += lanes
+        at = self._touched_at(lanes)
         with span(
             "actor.agg_step",
             table_id=self.table_id,
             chunks=int(stacked.valid.shape[0]),
         ):
-            self._step_stacked(stacked, pre, mode)
+            self._step_stacked(stacked, pre, mode, at)
         return []
 
     def _stacked_lanes(self, stacked: StreamChunk, pre) -> int:
@@ -755,7 +815,7 @@ class HashAggExecutor(Executor, Checkpointable):
             lanes = self._lanes_after_pre[key] = probe.valid.shape[0]
         return n_chunks * lanes
 
-    def _step_stacked(self, stacked, pre, mode) -> None:
+    def _step_stacked(self, stacked, pre, mode, at: int) -> None:
         if self.minput:
             if mode != "reduce":
                 raise ValueError(
@@ -768,6 +828,7 @@ class HashAggExecutor(Executor, Checkpointable):
                 self.dropped,
                 self.minput,
                 self.mi_bad,
+                self._touched,
             ) = _agg_epoch_reduced_mi(
                 self.table,
                 self.state,
@@ -779,10 +840,27 @@ class HashAggExecutor(Executor, Checkpointable):
                 pre,
                 self.minput,
                 self.mi_bad,
+                touched=self._touched,
+                at=at,
             )
             return
-        step = _agg_epoch_reduced if mode == "reduce" else _agg_scan
-        self.table, self.state, self.dropped = step(
+        if mode != "reduce":
+            # the scan keeps no list of what its chunks wrote
+            self._touched_lanes = None
+            self.table, self.state, self.dropped = _agg_scan(
+                self.table,
+                self.state,
+                self.dropped,
+                stacked,
+                self.calls,
+                self.group_keys,
+                self.nullable,
+                pre,
+            )
+            return
+        (
+            self.table, self.state, self.dropped, self._touched
+        ) = _agg_epoch_reduced(
             self.table,
             self.state,
             self.dropped,
@@ -791,6 +869,8 @@ class HashAggExecutor(Executor, Checkpointable):
             self.group_keys,
             self.nullable,
             pre,
+            touched=self._touched,
+            at=at,
         )
 
     def _survivor_count(self):
@@ -835,10 +915,21 @@ class HashAggExecutor(Executor, Checkpointable):
         # hysteresis, so the guard cannot re-trip right after a rebuild
         new_cap = self._buckets.plan(cap, incoming, claimed, claimed)
         if new_cap is not None and new_cap != cap:
-            self.table, self.state, self.minput = _rehash(
-                self.table, self.state, self.minput, self.calls, new_cap
-            )
+            self._rebuild(new_cap)
             self._insert_bound = min(claimed, new_cap)
+
+    def _rebuild(self, new_cap: int) -> None:
+        """Rehash into a table of ``new_cap`` slots."""
+        self.table, self.state, self.minput = _rehash(
+            self.table, self.state, self.minput, self.calls, new_cap
+        )
+        self._slots_moved()
+
+    def _slots_moved(self) -> None:
+        """The table was rebuilt: a list that names any slot names it
+        wrongly from here on (an empty one stays as good as it was)."""
+        if self._touched_lanes:
+            self._touched_lanes = None
 
     # -- control ---------------------------------------------------------
     def on_barrier(self, barrier: Barrier) -> List[StreamChunk]:
@@ -927,9 +1018,7 @@ class HashAggExecutor(Executor, Checkpointable):
             cap, 0, claimed, claimed, margin=max(claimed, epoch_inc)
         )
         if new_cap is not None and new_cap != cap:
-            self.table, self.state, self.minput = _rehash(
-                self.table, self.state, self.minput, self.calls, new_cap
-            )
+            self._rebuild(new_cap)
 
     # -- cold tier (state >> HBM) -----------------------------------------
     def state_nbytes(self) -> int:
@@ -990,6 +1079,7 @@ class HashAggExecutor(Executor, Checkpointable):
         )
         n = int(n)
         self._insert_bound = int(self.table.occupancy())
+        self._slots_moved()
         return n
 
     # -- fault-in on touch (the minput-compatible cold path) -------------
@@ -1038,6 +1128,9 @@ class HashAggExecutor(Executor, Checkpointable):
             return
         self._maybe_grow(nt)
         self._insert_bound += nt
+        # groups come back outside any step, and the growth above may
+        # have moved every slot: the next flush walks the table
+        self._touched_lanes = None
         key_lanes = tuple(
             jnp.asarray(lanes_np[f"k{i}"][found])
             for i in range(len(dtypes))
@@ -1071,7 +1164,9 @@ class HashAggExecutor(Executor, Checkpointable):
             {k: jnp.asarray(v) for k, v in cold.items()},
             self.calls,
         )
-        self._dirty_bound += int(found.sum())  # merged slots are dirtied
+        # merged slots are dirtied, by no step: the bound now passes
+        # the list's lanes, and the flush walks the table (_flush_walk)
+        self._dirty_bound += int(found.sum())
         # liveness may have flipped (e.g. deletes landed on a fresh slot
         # before the merge restored the cold row_count)
         slots = jnp.asarray(hit.astype(np.int32))
@@ -1102,19 +1197,40 @@ class HashAggExecutor(Executor, Checkpointable):
         slice follows the exact count the read brings anyway: the
         span's rows / lanes is the filled share of what is handed on
         (rows = the 2 lanes a drained group, its U-/U+ pair; a group's
-        first emission leaves its U- lane masked)."""
+        first emission leaves its U- lane masked).
+
+        Every round ranges over the steps' list where it names every
+        dirty slot (``_flush_walk``), else over the table: the span's
+        ``path`` / ``walked``; ``table_round`` over ``round`` is the
+        share of rounds that walked a table."""
         outs = []
+        walk = self._flush_walk()
+        listed = {} if walk is None else dict(
+            touched=self._touched, n_touched=self._touched_lanes, walk=walk
+        )
+        path = "table" if walk is None else "touched"
         while True:
-            with span("agg.flush", table_id=self.table_id) as sp:
+            with span(
+                "agg.flush",
+                table_id=self.table_id,
+                path=path,
+                walked=self.table.capacity if walk is None else walk,
+                round=1,
+                table_round=int(walk is None),
+            ) as sp:
                 self.state, delta = agg_ops.flush(
                     self.state,
                     self.table.keys,
                     self.out_cap,
                     self._float_extremes,
+                    **listed,
                 )
                 n_take, overflow = np.asarray(delta["status"]).tolist()
                 chunk = self._delta_to_chunk(delta, n_take)
                 sp.args.update(rows=2 * n_take, lanes=chunk.capacity)
+            REGISTRY.counter("agg_flush_rounds_total").inc(
+                table_id=self.table_id, path=path
+            )
             REGISTRY.counter("agg_flush_chunks_total").inc(
                 table_id=self.table_id, lanes=str(chunk.capacity)
             )
@@ -1124,8 +1240,21 @@ class HashAggExecutor(Executor, Checkpointable):
             outs.append(chunk)
             if not overflow:
                 break
+        # nothing is dirty: the bound and the list start over
         self._dirty_bound = 0
+        self._touched_lanes = 0
         return outs
+
+    def _flush_walk(self) -> Optional[int]:
+        """The declared length of the steps' list this barrier's flush
+        ranges over, or None where it has to walk the table: the list
+        was given up (``_touched_lanes`` None), or the host's dirty
+        bound counts lanes no step listed (a cold merge, a fused
+        program that stepped this state, a retracting expiry)."""
+        lanes = self._touched_lanes
+        if lanes is None or lanes != self._dirty_bound:
+            return None
+        return next((s for s in self.touched_sizes() if s >= lanes), None)
 
     # -- the lattice, before it is met -----------------------------------
     def warm_emissions(self) -> List[StreamChunk]:
@@ -1140,6 +1269,17 @@ class HashAggExecutor(Executor, Checkpointable):
         self.state, delta = agg_ops.flush(
             self.state, self.table.keys, self.out_cap, self._float_extremes
         )
+        # and the flush over the steps' list, of every declared length
+        for walk in self.touched_sizes():
+            self.state, _ = agg_ops.flush(
+                self.state,
+                self.table.keys,
+                self.out_cap,
+                self._float_extremes,
+                touched=self._touched,
+                n_touched=0,
+                walk=walk,
+            )
         return [
             delta_to_chunk(
                 delta, self.group_keys, self.nullable, self.calls, pad
@@ -1153,14 +1293,17 @@ class HashAggExecutor(Executor, Checkpointable):
         group; no host bound moves and nothing grows."""
         if self._would_grow(chunk.capacity):
             return []
-        self._step(chunk)
+        self._step(chunk, self._touched_at(chunk.capacity, advance=False))
         return []
 
     def warm_stacked(self, stacked: StreamChunk, pre, mode) -> None:
         """``apply_stacked`` likewise (the epoch-batched path)."""
-        if self._would_grow(self._stacked_lanes(stacked, pre)):
+        lanes = self._stacked_lanes(stacked, pre)
+        if self._would_grow(lanes):
             return
-        self._step_stacked(stacked, pre, mode)
+        self._step_stacked(
+            stacked, pre, mode, self._touched_at(lanes, advance=False)
+        )
 
     def _would_grow(self, incoming: int) -> bool:
         """Whether ``_maybe_grow`` would rebuild the table before a
@@ -1238,8 +1381,9 @@ class HashAggExecutor(Executor, Checkpointable):
         if emit_deletes:
             # retracting expiry can dirty up to every live group; the
             # host cannot count them without a sync — bound by capacity
-            # (flush_rounds clamps there anyway)
+            # (flush_rounds clamps there anyway), and no list names them
             self._dirty_bound = self.table.capacity
+            self._touched_lanes = None
         self.table, self.state = _expire(
             self.table, self.state, cutoff, self.calls, key_index, emit_deletes
         )
@@ -1544,6 +1688,7 @@ def _agg_restore_state(self, table_id, key_cols, value_cols) -> None:
     self.mi_bad = jnp.zeros((), jnp.bool_)
     self._insert_bound = int(n)
     self._dirty_bound = 0  # restored groups carry no unflushed change
+    self._touched_lanes = None  # a table the steps' list never saw
     # recovery restored every durable group as RESIDENT state
     self._evicted = set()
 

@@ -594,14 +594,54 @@ def forget_groups(
     return _reset_groups(state, calls, slots, mark_dirty=False)
 
 
+def note_touched(touched: jnp.ndarray, at, slots: jnp.ndarray) -> jnp.ndarray:
+    """Append the slots a step wrote (-1 = none) to the flush's list at
+    lane ``at`` (pure; jit-composable, in place where ``touched`` is
+    donated). The host keeps the cursor and answers for the room: a
+    start past ``len(touched) - len(slots)`` would be clamped onto
+    earlier entries, so where it counts more lanes than the list holds
+    it gives the list up and flushes by the table."""
+    if slots.shape[0] > touched.shape[0]:
+        return touched
+    return jax.lax.dynamic_update_slice(
+        touched, slots.astype(touched.dtype), (at,)
+    )
+
+
+def _dirty_head_listed(dirty, touched, n_touched, walk: int, m: int):
+    """The first ``m`` dirty slots, ascending, and how many are dirty,
+    from the first ``n_touched`` of ``walk`` lanes of the steps' list:
+    nothing here ranges over the table. A slot the steps wrote twice
+    is listed twice and one already flushed is listed still, so the
+    list is cut to its dirty entries, sorted, made unique, and sorted
+    again to close the gaps. ``cap`` stands for no slot."""
+    cap = dirty.shape[0]
+    lst = touched[:walk]
+    listed = (jnp.arange(walk) < n_touched) & (lst >= 0)
+    listed = listed & dirty[jnp.where(listed, lst, 0)]
+    s = jax.lax.sort(jnp.where(listed, lst, cap), is_stable=False)
+    first = jnp.concatenate([jnp.ones(1, jnp.bool_), s[1:] != s[:-1]])
+    uniq = first & (s < cap)
+    n_dirty = jnp.sum(uniq.astype(jnp.int32))
+    s = jax.lax.sort(jnp.where(uniq, s, cap), is_stable=False)
+    if walk < m:
+        s = jnp.concatenate([s, jnp.full(m - walk, cap, s.dtype)])
+    return s[:m], n_dirty
+
+
 @partial(
-    jax.jit, static_argnames=("out_cap", "float_extremes"), donate_argnums=(0,)
+    jax.jit,
+    static_argnames=("out_cap", "float_extremes", "walk"),
+    donate_argnums=(0,),
 )
 def flush(
     state: AggState,
     table_keys: Tuple[jnp.ndarray, ...],
     out_cap: int,
     float_extremes: tuple = (),
+    touched: Optional[jnp.ndarray] = None,
+    n_touched=None,
+    walk: Optional[int] = None,
 ):
     """Emit the per-barrier delta for dirty groups (hash_agg.rs:406).
 
@@ -623,14 +663,33 @@ def flush(
     ``float_extremes`` (static, from ``float_extreme_meta``) lists agg
     outputs stored as float total-order keys; their lanes are decoded
     back to floats on emission.
+
+    Which slots are dirty is read one of two ways, and the groups come
+    out in ascending slot order either way, lane for lane the same
+    (the reference's flush_data walks its set of dirty groups, never
+    the state table). With ``touched`` — the list the steps kept of
+    the slots they wrote since the last flush (``note_touched``), its
+    first ``n_touched`` lanes filled, ``walk`` (static) a declared
+    length at or above that — the program ranges over ``walk`` lanes
+    and over no lane of the table but the ``out_cap`` it gathers. The
+    caller holds that every dirty slot is on the list. Without it the
+    table is sorted by its dirty bit, as many lanes as it has.
     """
     cap = state.capacity
-    # compact dirty slot ids to the front: sort puts False (0) last
-    order = jnp.argsort(~state.dirty, stable=True)
-    dirty_sorted = state.dirty[order]
-    n_dirty = jnp.sum(state.dirty.astype(jnp.int32))
-    take = dirty_sorted[:out_cap]
-    slot_ids = order[:out_cap]
+    m = min(out_cap, cap)
+    if touched is None:
+        # compact dirty slot ids to the front: sort puts False (0) last
+        order = jnp.argsort(~state.dirty, stable=True)
+        n_dirty = jnp.sum(state.dirty.astype(jnp.int32))
+        slot_ids = order[:m]
+    else:
+        slot_ids, n_dirty = _dirty_head_listed(
+            state.dirty, touched, n_touched, walk, m
+        )
+    # the dirty slots come first either way (over the table this is
+    # dirty[order][:m], without gathering every lane to keep m of them)
+    take = jnp.arange(m) < n_dirty
+    slot_ids = jnp.where(take, slot_ids, 0)
     overflow = n_dirty > out_cap
 
     live = take & (state.row_count[slot_ids] > 0)

@@ -54,6 +54,7 @@ __all__ = [
     "flush_lattice_pad",
     "flush_pad",
     "flush_pad_schedule",
+    "touched_lattice",
     "lattice_between",
     "needs_plan",
     "padding_fraction",
@@ -166,6 +167,33 @@ def flush_lattice_pad(out_cap: int, n_take: int) -> int:
     that overflowed took ``out_cap`` groups and so the full size."""
     need = 2 * int(n_take)
     return next(s for s in flush_lattice(out_cap) if s >= need)
+
+
+# the steps' list an aggregate's flush ranges over (PR 34): the buffer's
+# lanes, and the shortest declared walk
+TOUCHED_MAX = 1 << 18
+TOUCHED_SMALL = 1 << 14
+
+
+def touched_lattice(capacity: int) -> Tuple[int, ...]:
+    """The declared lengths of the list of touched slots an aggregate's
+    flush ranges over (``ops/agg.flush``'s ``walk``): x4 steps from
+    16,384 to 262,144 lanes, none longer than the table, since a list
+    as long as the table has nothing over walking the table. A flush
+    takes the shortest that holds the lanes the epoch's steps ranged
+    over (a lane a row of their batches, 32,768 to 163,840 an epoch in
+    the benchmark's cells), and one program a length is compiled when
+    the view is created (``HashAggExecutor.warm_emissions``); an epoch
+    of more lanes than the longest walks the table.
+
+    x4 and not x2, for ``flush_lattice``'s reason; what a length costs a
+    flush is a sort of its lanes twice and one gather of them (PERF.md
+    6, PR 34)."""
+    return tuple(
+        sorted({min(s, int(capacity)) for s in (
+            TOUCHED_SMALL, TOUCHED_SMALL * 4, TOUCHED_MAX
+        )})
+    )
 
 
 # the narrowest chunk ``push_lattice`` cuts to: under it a per-chunk

@@ -220,3 +220,42 @@ def test_every_size_is_compiled_at_creation_and_the_view_stays_exact(
         assert rows.get(table_id="q5.agg2") - rows0 == 10 * base
     finally:
         served.close()
+
+
+def test_the_flush_over_the_steps_list_is_compiled_at_creation(tmp_path):
+    """PR 34: a barrier's flush ranges over the list of the slots the
+    epoch's steps wrote, cut to a declared length
+    (``bucketing.touched_lattice``), one program a length. Creating the
+    view compiles them all beside the table walk's, so a stream's first
+    barrier compiles no flush, and goes by the list. (The count's table
+    at a capacity no other test of the process builds.)"""
+    TRACER.clear()
+    served = Served(tmp_path, 2048, capacity=1 << 19)
+    try:
+        count = next(
+            ex for ex in served.rt.fragments["q5"]._executors
+            if getattr(ex, "table_id", "") == "q5.agg2"
+        )
+        assert count.touched_sizes() == (1 << 14, 1 << 16, 1 << 18)
+        flushes = [
+            sp for sp in TRACER.spans() if sp.name == "compile"
+            and sp.args.get("fun_name") == "jit(flush)"
+            and sp.args.get("event") == "backend_compile_duration"
+        ]
+        # the table walk and the three lengths, at the least the count's
+        assert len(flushes) >= 1 + len(count.touched_sizes())
+        TRACER.clear()
+        assert _one_epoch(served) == served.read()
+        spans = TRACER.spans()
+        assert not [
+            sp for sp in spans if sp.name == "compile"
+            and "flush" in sp.args.get("fun_name", "")
+        ]
+        rounds = [sp.args for sp in spans if sp.name == "agg.flush"]
+        assert {a["table_id"] for a in rounds} == {"q5.agg2", "q5.agg4"}
+        assert all(
+            (a["path"], a["walked"], a["table_round"]) == ("touched", 1 << 14, 0)
+            for a in rounds
+        )
+    finally:
+        served.close()
