@@ -34,7 +34,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from risingwave_tpu.trace import span
+from risingwave_tpu.trace import device_read, span
 from risingwave_tpu.types import Schema, op_sign
 
 
@@ -187,7 +187,9 @@ class DataChunk:
         copy, never worse."""
         from risingwave_tpu.runtime.bucketing import prefix_pad
 
-        valid = np.asarray(self.valid)
+        # the copy that waits for the step that made the chunk
+        with device_read("chunk.valid", lanes=self.valid.shape[0]):
+            valid = np.asarray(self.valid)
         nz = np.flatnonzero(valid)
         if len(nz) == 0:
             return valid[:0], 0
@@ -200,12 +202,11 @@ class DataChunk:
         NULL lanes come back as ``<name>__null`` bool columns.
         """
         valid, pad = self._live_slice()
-        out = {
-            n: np.asarray(a[:pad])[valid] for n, a in self.columns.items()
-        }
-        for n, lane in self.nulls.items():
-            out[n + "__null"] = np.asarray(lane[:pad])[valid]
-        return out
+        lanes = {n: a[:pad] for n, a in self.columns.items()}
+        lanes.update({n + "__null": a[:pad] for n, a in self.nulls.items()})
+        with device_read("chunk.lanes", lanes=pad):
+            host = {n: np.asarray(a) for n, a in lanes.items()}
+        return {n: a[valid] for n, a in host.items()}
 
 
 @partial(jax.jit, static_argnames=("lanes",))
@@ -357,7 +358,9 @@ class StreamChunk(DataChunk):
         out = super().to_numpy()
         if with_ops:
             valid, pad = self._live_slice()
-            out["__op__"] = np.asarray(self.ops[:pad])[valid]
+            with device_read("chunk.ops", lanes=pad):
+                ops = np.asarray(self.ops[:pad])
+            out["__op__"] = ops[valid]
         return out
 
 

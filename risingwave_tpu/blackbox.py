@@ -144,7 +144,9 @@ class FlightRecorder:
            device memory_stats), mb (modeled bytes per barrier from the
            compiled-executable roofline), pf (padding-bytes fraction of
            the modeled traffic), tel ({fragment: fused telemetry-lane
-           scalars: per-member rows + dirty groups})
+           scalars: per-member rows + dirty groups}), slow (a slow
+           barrier's own critical path: trace.barrier_path's by_kind,
+           its largest by_span rows, the other threads' open spans)
 
     Counters are recorded CUMULATIVE (cheap snapshot, no per-record
     subtraction on the hot path); the reader derives per-barrier
@@ -153,6 +155,9 @@ class FlightRecorder:
     directory is configured (RW_BLACKBOX_DIR / config [blackbox])."""
 
     SEGMENT_PREFIX = "BLACKBOX_"
+    # a barrier is slow when its wall is over SLOW_MS and over SLOW_X
+    # times the median wall of the ring's barriers
+    SLOW_MS, SLOW_X = 1000.0, 3.0
 
     def __init__(self):
         self.enabled = True  # ring recording (in-memory, always cheap)
@@ -246,6 +251,15 @@ class FlightRecorder:
         REGISTRY.counter("blackbox_records_total").inc()
         if self.dir is not None:
             self._persist(rec)
+
+    def is_slow(self, wall_ms: float) -> bool:
+        """Whether a barrier of this wall stands out of the ring's (ask
+        before it is recorded): one comparison when it does not."""
+        if wall_ms <= self.SLOW_MS:
+            return False
+        with self._lock:
+            walls = sorted(rec["wall"] for rec in self.ring)
+        return bool(walls) and wall_ms > self.SLOW_X * walls[len(walls) // 2]
 
     def _build_record(self, trace, runtime) -> Dict:
         from risingwave_tpu.profiler import PROFILER
@@ -343,6 +357,9 @@ class FlightRecorder:
                     compact[mv] = row
             if compact:
                 rec["fr"] = compact
+        slow = getattr(trace, "slow_path", None)
+        if slow:
+            rec["slow"] = slow
         bpf = getattr(trace, "backpressure_fragment", None)
         if bpf:
             rec["bp"] = {
@@ -407,9 +424,10 @@ class FlightRecorder:
                 seq=0,
                 checkpoint=False,
                 wall_ms=dispatch_ms + device_ms,
+                # the keys its two spans stamp (pipeline.walk, .fence)
                 stages_ms={
                     "dispatch": dispatch_ms,
-                    "device_step": device_ms,
+                    "dispatch.fence": device_ms,
                 },
             )
         )
@@ -1092,6 +1110,8 @@ def read_segment(path: str, last: Optional[int] = None) -> Dict:
             out["freshness"] = rec["fr"]
         if "bp" in rec:
             out["backpressure"] = rec["bp"]
+        if "slow" in rec:
+            out["slow_barrier"] = rec["slow"]
         if "msh" in rec:
             m = rec["msh"]
             out["mesh"] = {

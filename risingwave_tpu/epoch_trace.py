@@ -146,6 +146,11 @@ class EpochTrace:
                                previous barrier
       ingest.permit_wait     — [push.permit_wait] of it, blocked on a
                                graph channel's permits
+      <root>.device_wait     — [device.read] of ``<root>`` (ingest,
+                               dispatch, checkpoint_stage, actor: summed
+                               over the actors), the thread blocked on
+                               the device: a device->host copy or a
+                               block_until_ready, wherever under it
       dispatch               — [barrier.fragment] per-fragment barrier
                                walk: inject, wait, drain, route
       dispatch.drain         — [dispatch.drain] barrier injected -> the
@@ -165,13 +170,13 @@ class EpochTrace:
                                finish_barrier: the barrier-only device
                                fence (block_until_ready + staged-scalar
                                materialization)
-      device_step            — written by nobody: the fence lies inside
-                               dispatch's wall (dispatch.fence, actor_
-                               fence.*); the benchmark's dispatch.ms_
-                               per_barrier still adds the key, as 0
       checkpoint_stage       — [checkpoint.stage] delta pull + mark
                                flips (mgr.stage), on the barrier's
                                thread
+      checkpoint_stage.marks — [checkpoint.marks, less the pulls inside
+                               it] an executor's staging outside its
+                               row pull: the dirty/live/stored marks
+                               read off the device, classified, flipped
       checkpoint_stage.pull  — [checkpoint.pull] pull_rows: gather
                                dispatch + the device->host copy
       checkpoint_stage.dictionary — [checkpoint.dictionary] the session
@@ -237,6 +242,9 @@ class EpochTrace:
     # the multi-chip path, folded by MESHPROF.observe_barrier. None on
     # serial barriers (the common case costs one attribute slot).
     mesh: Optional[Dict] = None
+    # a slow barrier's own critical path (trace.barrier_path, cut to
+    # its largest rows), set by the runtime's bookkeeping; None else
+    slow_path: Optional[Dict] = None
 
     def add_stage(self, stage: str, ms: float, fragment: str = "-") -> None:
         self.stages_ms[stage] = self.stages_ms.get(stage, 0.0) + ms

@@ -44,6 +44,11 @@ Recording sites (grow as subsystems need them):
                        recompile storm: the named executor class was
                        pinned to its max bucket (reason
                        budget_exceeded | slow_device)
+- ``slow_barrier``   — runtime bookkeeping: a barrier over 1 s and over
+                       3 x the flight recorder's median, reduced to its
+                       critical path (trace.barrier_path: by_kind, the
+                       eight largest by_span rows, the actors crossed,
+                       the other threads' open spans)
 - ``skew``           — parallel/meshprof.py hot-shard verdict: one
                        shard's routed rows exceeded RW_SKEW_RATIO x
                        the per-shard mean this barrier (fields:

@@ -34,6 +34,7 @@ from risingwave_tpu.storage.state_table import (
     StateDelta,
     grow_pow2,
     pull_rows,
+    read_marks,
     stage_marks,
 )
 
@@ -331,11 +332,11 @@ class AppendOnlyDedupExecutor(Executor, Checkpointable):
     def checkpoint_delta(self):
         import numpy as np
 
-        sdirty = np.asarray(self.sdirty)
+        (sdirty,) = read_marks(self.sdirty)
         if not sdirty.any():
             return []
         upsert, tomb, sel = stage_marks(
-            sdirty, np.asarray(self.table.live), np.asarray(self.stored)
+            sdirty, *read_marks(self.table.live, self.stored)
         )
         lanes = {f"k{i}": l for i, l in enumerate(self.table.keys)}
         keys = pull_rows(lanes, sel)

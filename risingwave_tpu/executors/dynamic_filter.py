@@ -43,6 +43,7 @@ from risingwave_tpu.storage.state_table import (
     StateDelta,
     grow_pow2,
     pull_rows,
+    read_marks,
     stage_marks,
 )
 
@@ -355,11 +356,11 @@ class DynamicMaxFilterExecutor(Executor, Checkpointable):
     def checkpoint_delta(self):
         import numpy as np
 
-        sdirty = np.asarray(self.sdirty)
+        (sdirty,) = read_marks(self.sdirty)
         if not sdirty.any():
             return []
         upsert, tomb, sel = stage_marks(
-            sdirty, np.asarray(self.table.live), np.asarray(self.stored)
+            sdirty, *read_marks(self.table.live, self.stored)
         )
         pulled = pull_rows(
             {"k0": self.table.keys[0], "max": self.maxes}, sel
@@ -700,10 +701,10 @@ class DynamicFilterExecutor(Executor, Checkpointable):
 
     def checkpoint_delta(self):
         out = []
-        sdirty = np.asarray(self.sdirty)
+        (sdirty,) = read_marks(self.sdirty)
         if sdirty.any():
             upsert, tomb, sel = stage_marks(
-                sdirty, np.asarray(self.table.live), np.asarray(self.stored)
+                sdirty, *read_marks(self.table.live, self.stored)
             )
             lanes = {
                 f"k{i}": lane for i, lane in enumerate(self.table.keys)

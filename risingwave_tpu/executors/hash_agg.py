@@ -40,6 +40,7 @@ from risingwave_tpu.storage.state_table import (
     host_key_view,
     lanes_from_host_keys,
     pull_rows,
+    read_marks,
     stage_marks,
 )
 from risingwave_tpu.ops import agg as agg_ops
@@ -55,7 +56,7 @@ from risingwave_tpu.runtime.bucketing import (
     flush_lattice_pad,
     touched_lattice,
 )
-from risingwave_tpu.trace import span
+from risingwave_tpu.trace import device_read, span
 
 GROW_AT = 0.5  # rehash when claimed slots may exceed this load factor
 # mid-epoch rebuild only when the HOST insert bound nears the table
@@ -1147,33 +1148,45 @@ class HashAggExecutor(Executor, Checkpointable):
         checkpoint: candidates are sdirty & ~stored; a cold-store hit
         means the key was evicted earlier and its persisted accumulators
         must combine with what accrued since (merge-on-return; the
-        reference reloads through its state-table cache instead)."""
-        cand = np.asarray(self.state.sdirty & ~self.state.stored)
-        sel = np.flatnonzero(cand)
-        if not len(sel):
-            return 0
-        lanes = {f"k{i}": lane for i, lane in enumerate(self.table.keys)}
-        keys = pull_rows(lanes, sel)
-        found, vals = self.cold_reader(keys)
-        if not found.any():
-            return 0
-        hit = sel[found]
-        cold = {k: v[found] for k, v in vals.items()}
-        self.state = _cold_merge(
-            self.state, jnp.asarray(hit.astype(np.int32)),
-            {k: jnp.asarray(v) for k, v in cold.items()},
-            self.calls,
-        )
-        # merged slots are dirtied, by no step: the bound now passes
-        # the list's lanes, and the flush walks the table (_flush_walk)
-        self._dirty_bound += int(found.sum())
-        # liveness may have flipped (e.g. deletes landed on a fresh slot
-        # before the merge restored the cold row_count)
-        slots = jnp.asarray(hit.astype(np.int32))
-        self.table = set_live(
-            self.table, slots, self.state.row_count[slots] > 0
-        )
-        return int(found.sum())
+        reference reloads through its state-table cache instead).
+
+        The span ``agg.merge_cold`` (table_id; candidates = groups new
+        since the last checkpoint, found = those the cold store held):
+        every barrier of an aggregate over a store reads the candidate
+        lane off the device, waiting out the epoch's steps, and looks
+        every candidate's key up in the store."""
+        with span("agg.merge_cold", table_id=self.table_id) as sp:
+            cand = self.state.sdirty & ~self.state.stored
+            with device_read("agg.merge_cold", lanes=cand.shape[0]):
+                cand = np.asarray(cand)
+            sel = np.flatnonzero(cand)
+            sp.args.update(candidates=len(sel), found=0)
+            if not len(sel):
+                return 0
+            lanes = {f"k{i}": lane for i, lane in enumerate(self.table.keys)}
+            keys = pull_rows(lanes, sel)
+            with span("agg.cold_lookup", keys=len(sel)):
+                found, vals = self.cold_reader(keys)
+            if not found.any():
+                return 0
+            sp.args["found"] = int(found.sum())
+            hit = sel[found]
+            cold = {k: v[found] for k, v in vals.items()}
+            self.state = _cold_merge(
+                self.state, jnp.asarray(hit.astype(np.int32)),
+                {k: jnp.asarray(v) for k, v in cold.items()},
+                self.calls,
+            )
+            # merged slots are dirtied, by no step: the bound now passes
+            # the list's lanes, and the flush walks the table (_flush_walk)
+            self._dirty_bound += int(found.sum())
+            # liveness may have flipped (e.g. deletes landed on a fresh
+            # slot before the merge restored the cold row_count)
+            slots = jnp.asarray(hit.astype(np.int32))
+            self.table = set_live(
+                self.table, slots, self.state.row_count[slots] > 0
+            )
+            return int(found.sum())
 
     def flush_rounds(self) -> int:
         """Upper bound of flush rounds this barrier needs, from the
@@ -1225,7 +1238,9 @@ class HashAggExecutor(Executor, Checkpointable):
                     self._float_extremes,
                     **listed,
                 )
-                n_take, overflow = np.asarray(delta["status"]).tolist()
+                with device_read("agg.flush.status", lanes=2):
+                    status = np.asarray(delta["status"])
+                n_take, overflow = status.tolist()
                 chunk = self._delta_to_chunk(delta, n_take)
                 sp.args.update(rows=2 * n_take, lanes=chunk.capacity)
             REGISTRY.counter("agg_flush_rounds_total").inc(
@@ -1550,15 +1565,16 @@ def _agg_checkpoint_delta(self) -> List[StateDelta]:
     restore rebuilds byte-identical operator state. Only the selected
     rows cross the device boundary (pull_rows).
     """
-    sdirty = np.asarray(self.state.sdirty)
+    (sdirty,) = read_marks(self.state.sdirty)
     if not sdirty.any():
         return []
-    alive = (
-        np.asarray(self.table.live)
-        | np.asarray(self.state.emitted_valid)
-        | np.asarray(self.state.dirty)
+    live, emitted_valid, dirty, stored = read_marks(
+        self.table.live, self.state.emitted_valid, self.state.dirty,
+        self.state.stored,
     )
-    upsert, tomb, sel = stage_marks(sdirty, alive, np.asarray(self.state.stored))
+    upsert, tomb, sel = stage_marks(
+        sdirty, live | emitted_valid | dirty, stored
+    )
     lanes = {
         f"k{i}": lane for i, lane in enumerate(self.table.keys)
     }

@@ -54,6 +54,7 @@ from risingwave_tpu.storage.state_table import (
     StateDelta,
     grow_pow2,
     pull_rows,
+    read_marks,
     stage_marks,
 )
 from risingwave_tpu.ops.join import (
@@ -942,11 +943,11 @@ def _side_delta(side: JoinSide, table_id: str):
     or None."""
     import numpy as np
 
-    sdirty = np.asarray(side.sdirty)
+    (sdirty,) = read_marks(side.sdirty)
     if not sdirty.any():
         return None
     upsert, tomb, sel = stage_marks(
-        sdirty, np.asarray(side.table.live), np.asarray(side.stored)
+        sdirty, *read_marks(side.table.live, side.stored)
     )
     lanes = {
         f"k{i}": lane for i, lane in enumerate(side.table.keys)

@@ -69,6 +69,7 @@ from risingwave_tpu.storage.state_table import (
     StateDelta,
     grow_pow2,
     pull_rows,
+    read_marks,
     stage_marks,
 )
 from risingwave_tpu.trace import span
@@ -434,11 +435,11 @@ class KeyedJoinExecutor(Executor, Checkpointable):
         out = []
         for name in ("left", "right"):
             side = getattr(self, name)
-            sdirty = np.asarray(side.sdirty)
+            (sdirty,) = read_marks(side.sdirty)
             if not sdirty.any():
                 continue
             upsert, tomb, sel = stage_marks(
-                sdirty, np.asarray(side.table.live), np.asarray(side.stored)
+                sdirty, *read_marks(side.table.live, side.stored)
             )
             lanes = {f"k{i}": k for i, k in enumerate(side.table.keys)}
             key_names = tuple(lanes)

@@ -525,6 +525,7 @@ from risingwave_tpu.runtime.bucketing import BucketAllocator, BucketPolicy
 from risingwave_tpu.storage.state_table import (
     grow_pow2,
     pull_rows,
+    read_marks,
     stage_marks,
 )
 
@@ -867,12 +868,12 @@ class DeviceMaterializeExecutor(MvDeviceReadMixin, Executor, Checkpointable):
 
     # -- checkpoint/restore -----------------------------------------------
     def checkpoint_delta(self):
-        sdirty = np.asarray(self.state.sdirty)
+        (sdirty,) = read_marks(self.state.sdirty)
         if not sdirty.any():
             return []
-        alive = np.asarray(self.table.live)
-        stored = np.asarray(self.state.stored)
-        upsert, tomb, sel = stage_marks(sdirty, alive, stored)
+        upsert, tomb, sel = stage_marks(
+            sdirty, *read_marks(self.table.live, self.state.stored)
+        )
         if not len(sel):
             self.state.sdirty = jnp.zeros_like(self.state.sdirty)
             return []

@@ -42,6 +42,7 @@ from risingwave_tpu.storage.state_table import (
     StateDelta,
     grow_pow2,
     pull_rows,
+    read_marks,
     stage_marks,
 )
 
@@ -858,15 +859,13 @@ class OverWindowExecutor(Executor, Checkpointable):
 
     # -- checkpoint/restore ----------------------------------------------
     def checkpoint_delta(self) -> List[StateDelta]:
-        sdirty = np.asarray(self.sdirty)
+        (sdirty,) = read_marks(self.sdirty)
         if not sdirty.any():
             return []
         # partitions never die in the append-only executor: alive =
         # every claimed slot, so there are no tombstones
-        alive = np.asarray(self.table.fp1) != 0
-        upsert, tomb, sel = stage_marks(
-            sdirty, alive, np.asarray(self.stored)
-        )
+        fp1, stored = read_marks(self.table.fp1, self.stored)
+        upsert, tomb, sel = stage_marks(sdirty, fp1 != 0, stored)
         lanes = {f"k{i}": l for i, l in enumerate(self.table.keys)}
         key_names = tuple(lanes)
         for name, a in self.accums.items():
@@ -1587,12 +1586,11 @@ class GeneralOverWindowExecutor(Executor, Checkpointable):
 
     # -- checkpoint/restore ----------------------------------------------
     def checkpoint_delta(self) -> List[StateDelta]:
-        sdirty = np.asarray(self.sdirty)
+        (sdirty,) = read_marks(self.sdirty)
         if not sdirty.any():
             return []
-        alive = np.asarray(self.present | self.em_valid)
         upsert, tomb, sel = stage_marks(
-            sdirty, alive, np.asarray(self.stored)
+            sdirty, *read_marks(self.present | self.em_valid, self.stored)
         )
         lanes = {f"k{i}": l for i, l in enumerate(self.table.keys)}
         key_names = tuple(lanes)

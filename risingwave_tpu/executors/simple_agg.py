@@ -29,6 +29,7 @@ from risingwave_tpu.storage.state_table import (
     Checkpointable,
     StateDelta,
     pull_rows,
+    read_marks,
 )
 from risingwave_tpu.types import Op
 
@@ -205,7 +206,7 @@ class SimpleAggExecutor(Executor, Checkpointable):
 
     # -- checkpoint -------------------------------------------------------
     def checkpoint_delta(self) -> List[StateDelta]:
-        if not bool(np.asarray(self.state.sdirty[:1])[0]):
+        if not bool(read_marks(self.state.sdirty[:1])[0][0]):
             return []
         lanes = {"row_count": self.state.row_count}
         for n, a in self.state.accums.items():

@@ -29,6 +29,7 @@ from risingwave_tpu.storage.state_table import (
     Checkpointable,
     StateDelta,
     pull_rows,
+    read_marks,
 )
 
 
@@ -231,7 +232,7 @@ class ArenaBufferedExecutor(Executor, Checkpointable):
         is pulled to diff against the previously-stored set — a freed
         slot may already hold a new row, so slot marks alone cannot
         name the departed seqs."""
-        valid_np = np.asarray(self.valid)
+        (valid_np,) = read_marks(self.valid)
         sel_all = np.flatnonzero(valid_np)
         seq_rows = pull_rows({"k0": self.seq}, sel_all)
         cur = (

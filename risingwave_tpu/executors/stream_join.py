@@ -57,9 +57,10 @@ from risingwave_tpu.storage.state_table import (
     StateDelta,
     grow_pow2,
     pull_rows,
+    read_marks,
     stage_marks,
 )
-from risingwave_tpu.trace import span
+from risingwave_tpu.trace import device_read, span
 
 GROW_AT = 0.5
 
@@ -309,7 +310,9 @@ class StreamJoinExecutor(Executor, Checkpointable):
             # the two numbers a chunk costs the host: its pairs in all
             # (more than a step takes: another step) and the buffer's
             # fill (what the next step may still append)
-            total, self._fill = read_scalars(total_dev, self._cursor)
+            total, self._fill = read_scalars(
+                total_dev, self._cursor, what="join.step"
+            )
             start += self.out_cap
         return outs
 
@@ -344,7 +347,9 @@ class StreamJoinExecutor(Executor, Checkpointable):
             return
         side = getattr(self, name)
         # ONE packed read of what is truly appended and claimed
-        n_rows, claimed = read_scalars(side.n_rows, side.table.occupancy())
+        n_rows, claimed = read_scalars(
+            side.n_rows, side.table.occupancy(), what="join.occupancy"
+        )
         row_cap, key_cap = side.row_cap, side.key_cap
         while n_rows + incoming > row_cap:
             row_cap *= 2
@@ -359,7 +364,8 @@ class StreamJoinExecutor(Executor, Checkpointable):
                     side, key_cap=key_cap, row_cap=row_cap,
                     key_names=self._keys(name),
                 )
-                jax.block_until_ready(grown.row_valid)
+                with device_read("join.regrow"):
+                    jax.block_until_ready(grown.row_valid)
             REGISTRY.counter("join_regrows_total").inc(
                 1, join=self.table_id, side=name
             )
@@ -456,12 +462,11 @@ class StreamJoinExecutor(Executor, Checkpointable):
         for name in ("left", "right"):
             side = getattr(self, name)
             if self._retract[name]:
-                rdirty = np.asarray(side.rdirty)
+                (rdirty,) = read_marks(side.rdirty)
                 if not rdirty.any():
                     continue
                 upsert, tomb, sel = stage_marks(
-                    rdirty, np.asarray(side.row_valid),
-                    np.asarray(side.stored),
+                    rdirty, *read_marks(side.row_valid, side.stored)
                 )
                 tombstone = tomb[sel]
                 marks = dict(

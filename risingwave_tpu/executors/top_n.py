@@ -52,6 +52,7 @@ from risingwave_tpu.storage.state_table import (
     StateDelta,
     grow_pow2,
     pull_rows,
+    read_marks,
     stage_marks,
 )
 from risingwave_tpu.types import Op
@@ -407,11 +408,11 @@ class GroupTopNExecutor(Executor, Checkpointable):
 
     # -- checkpoint/restore ----------------------------------------------
     def checkpoint_delta(self):
-        sdirty = np.asarray(self.state["sdirty"])
+        (sdirty,) = read_marks(self.state["sdirty"])
         if not sdirty.any():
             return []
         upsert, tomb, sel = stage_marks(
-            sdirty, np.asarray(self.table.live), np.asarray(self.state["stored"])
+            sdirty, *read_marks(self.table.live, self.state["stored"])
         )
         lanes = {f"k{i}": x for i, x in enumerate(self.table.keys)}
         key_names = tuple(lanes)

@@ -43,9 +43,10 @@ from risingwave_tpu.storage.state_table import (
     StateDelta,
     grow_pow2,
     pull_rows,
+    read_marks,
     stage_marks,
 )
-from risingwave_tpu.trace import span
+from risingwave_tpu.trace import device_read, span
 from risingwave_tpu.types import Op
 
 GROW_AT = 0.5
@@ -295,11 +296,11 @@ class TopNExecutor(Executor, Checkpointable):
 
     # -- checkpoint -------------------------------------------------------
     def checkpoint_delta(self) -> List[StateDelta]:
-        sdirty = np.asarray(self.sdirty)
+        (sdirty,) = read_marks(self.sdirty)
         if not sdirty.any():
             return []
         upsert, tomb, sel = stage_marks(
-            sdirty, np.asarray(self.table.live), np.asarray(self.stored)
+            sdirty, *read_marks(self.table.live, self.stored)
         )
         lanes = {f"k{i}": lane for i, lane in enumerate(self.table.keys)}
         key_names = tuple(lanes)
@@ -956,8 +957,10 @@ class RetractableGroupTopNExecutor(Executor, Checkpointable):
         # ONE read for the counts, the latch and the occupancy; it
         # waits for the rank
         with span("topn.pull", table_id=self.table_id) as sp:
+            with device_read("topn.status", lanes=8):
+                status = jax.device_get(status)
             (n_ret, n_ins, groups, overflow, dropped, claimed, live,
-             passes) = jax.device_get(status).tolist()
+             passes) = status.tolist()
             sp.args.update(rows=n_ret + n_ins, groups=groups, passes=passes)
         with span(
             "topn.diff",
@@ -1025,11 +1028,11 @@ class RetractableGroupTopNExecutor(Executor, Checkpointable):
         return host_digest(*self.digest_lanes())
 
     def checkpoint_delta(self) -> List[StateDelta]:
-        sdirty = np.asarray(self.sdirty)
+        (sdirty,) = read_marks(self.sdirty)
         if not sdirty.any():
             return []
         upsert, tomb, sel = stage_marks(
-            sdirty, np.asarray(self.table.live), np.asarray(self.stored)
+            sdirty, *read_marks(self.table.live, self.stored)
         )
         lanes = {f"k{i}": lane for i, lane in enumerate(self.table.keys)}
         key_names = tuple(lanes)

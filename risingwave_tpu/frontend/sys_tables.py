@@ -188,6 +188,10 @@ SYS_SCHEMAS: Dict[str, Schema] = {
 }
 
 
+# columns whose None is SQL NULL (the others keep their fill values)
+_NULLABLE = {("rw_barrier_latency", "device_step_ms")}
+
+
 class SysTable:
     """A read-only virtual relation over live introspection state.
 
@@ -218,6 +222,10 @@ class SysTable:
                     [enc("" if v is None else str(v)) for v in vals],
                     np.int32,
                 )
+            elif (self.name, f.name) in _NULLABLE and None in vals:
+                # the engine's nullable-column convention for a scan's
+                # input: an object lane with None cells
+                out[f.name] = np.asarray(vals, object)
             elif f.dtype is DataType.FLOAT64:
                 out[f.name] = np.asarray(
                     [float(v) if v is not None else -1.0 for v in vals],
@@ -319,11 +327,15 @@ def _rows_mv_freshness(session) -> List[dict]:
 
 
 def _rows_barrier_latency(session) -> List[dict]:
+    from risingwave_tpu.trace import TRACER, barrier_path
+
     rt = session.runtime
     traces = list(getattr(rt, "epoch_traces", ()) or ())[-128:]
+    spans = TRACER.spans()
     rows = []
     for tr in traces:
         st = getattr(tr, "stages_ms", {}) or {}
+        path = barrier_path(getattr(tr, "epoch", 0), spans)
         rows.append(
             {
                 "epoch": getattr(tr, "epoch", 0),
@@ -331,16 +343,13 @@ def _rows_barrier_latency(session) -> List[dict]:
                 "checkpoint": int(getattr(tr, "checkpoint", False)),
                 "wall_ms": round(getattr(tr, "wall_ms", 0.0), 3),
                 "dispatch_ms": round(st.get("dispatch", 0.0), 3),
-                # the barrier-only device fences: a serial pipeline's
-                # (dispatch.fence) or, in graph mode, the actors'
-                "device_step_ms": round(
-                    sum(
-                        v
-                        for k, v in st.items()
-                        if k == "dispatch.fence"
-                        or k.startswith("actor_fence.")
-                    ),
-                    3,
+                # what of the barrier its critical path spent blocked
+                # on the device (its ``device.read`` spans along the
+                # slowest actor); NULL once the span ring has let go of
+                # the epoch
+                "device_step_ms": (
+                    None if path is None
+                    else round(path["by_kind"]["device_wait"], 3)
                 ),
                 "backpressure_fragment": getattr(
                     tr, "backpressure_fragment", None

@@ -43,6 +43,7 @@ import jax
 import jax.numpy as jnp
 
 from risingwave_tpu.ops.hashing import hash128
+from risingwave_tpu.trace import device_read
 
 EMPTY = jnp.uint32(0)  # slot status: fingerprint 0 reserved for "empty"
 TOMBSTONE_FLAG = 0x1  # bit in `status` lane
@@ -293,21 +294,24 @@ def stage_scalars(*xs):
     return arr
 
 
-def finish_scalars(arr) -> list:
-    """Blocking counterpart: materialize a staged pack.
+def finish_scalars(arr, what: str = "scalars") -> list:
+    """Blocking counterpart: materialize a staged pack (the span
+    ``device.read``; ``what`` names the read for a barrier's path).
 
     Uses ``jax.device_get`` — an EXPLICIT transfer — because this runs
     inside the per-barrier device step, which tests arm with
     ``jax.transfer_guard("disallow")`` (RW_TRANSFER_GUARD): the one
     sanctioned D2H read per barrier must not trip the guard that
     exists to catch the unsanctioned ones."""
-    return jax.device_get(arr).tolist()
+    with device_read(what, lanes=arr.shape[0]):
+        host = jax.device_get(arr)
+    return host.tolist()
 
 
-def read_scalars(*xs) -> list:
+def read_scalars(*xs, what: str = "scalars") -> list:
     """ONE packed, blocking device->host read of several scalars
     (latches, occupancy counters) — stage + finish in one call."""
-    return finish_scalars(stage_scalars(*xs))
+    return finish_scalars(stage_scalars(*xs), what)
 
 
 def plan_rehash(

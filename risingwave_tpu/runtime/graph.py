@@ -54,7 +54,13 @@ from risingwave_tpu.runtime.pipeline import (
     walk_chain,
     warm_chain,
 )
-from risingwave_tpu.trace import add_stage, bind, close_epoch, span
+from risingwave_tpu.trace import (
+    add_stage,
+    bind,
+    close_epoch,
+    device_read,
+    span,
+)
 
 
 def _default_barrier_timeout() -> float:
@@ -149,7 +155,7 @@ class PermitChannel:
             )
         short = cost - self._avail
         REGISTRY.counter("permits_waited_total").inc(short, sender=sender)
-        with span(name, stage=stage, permits=short):
+        with span(name, stage=stage, wait="permit", permits=short):
             while self._avail < cost:
                 if self._abort is not None and self._abort.is_set():
                     return False  # graph aborting: drop data, never wedge
@@ -353,9 +359,13 @@ class FragmentActor(threading.Thread):
         halt: Optional[threading.Event] = None,
         head: Sequence[Executor] = (),
         shared: int = 0,
+        upstream: Sequence[str] = (),
     ):
         super().__init__(name=f"actor-{name}", daemon=True)
         self.actor_name = name
+        # the actors that feed this one, by name: a barrier's path
+        # (trace.barrier_path) goes up them from an actor that waited
+        self.upstream = tuple(upstream)
         # the actor's name in stage keys: unique across a runtime's
         # graphs once the runtime has labelled them
         self.label = f"{mgr.label}/{name}" if mgr.label else name
@@ -405,7 +415,9 @@ class FragmentActor(threading.Thread):
         the span has)."""
         if self._edge_rows is None:
             return
-        inserted, retracted = jax.device_get(self._edge_rows).tolist()
+        with device_read("edge_rows"):
+            edge_rows = jax.device_get(self._edge_rows)
+        inserted, retracted = edge_rows.tolist()
         self._edge_rows = None
         fence.args.update(
             actor=self.label, insert_rows=inserted, retract_rows=retracted
@@ -575,6 +587,8 @@ class FragmentActor(threading.Thread):
             epoch=b.epoch.curr,
             fragment=self.actor_name,
             actor=self.actor_name,
+            graph=self.mgr.label or None,
+            upstream=self.upstream,
             **({} if self.join_exec is None else {"shared": self.shared}),
         ):
             self._process_barrier_inner(b)
@@ -835,7 +849,9 @@ class FragmentActor(threading.Thread):
                     cv = waitable[0]._cv
                     self.busy = False
                     try:
-                        with span("actor.idle", stage="actor_idle"), cv:
+                        with span(
+                            "actor.idle", stage="actor_idle", wait="queue"
+                        ), cv:
                             cv.wait_for(
                                 lambda: self.halt.is_set()
                                 or any(len(ch._q) for ch in waitable),
@@ -1076,6 +1092,11 @@ class GraphRuntime:
         else:
             coll = self.collectors.setdefault(s.name, _Collector())
             dispatcher = coll
+        upstream = [
+            f"{up}#{ui}"
+            for up in dict.fromkeys(name for name, _port in s.inputs)
+            for ui in range(self.specs[up].parallelism)
+        ]
         if isinstance(built, dict):
             actor = FragmentActor(
                 f"{s.name}#{inst}",
@@ -1083,6 +1104,7 @@ class GraphRuntime:
                 self._in_ch[s.name][inst],
                 dispatcher,
                 self,
+                upstream=upstream,
                 join=built["join"],
                 right_chain=built.get("right", []),
                 tail=built.get("tail", []),
@@ -1098,6 +1120,7 @@ class GraphRuntime:
                 dispatcher,
                 self,
                 halt=self._halts[(s.name, inst)],
+                upstream=upstream,
             )
         self.actors.append(actor)
         return actor
@@ -1357,7 +1380,10 @@ class GraphRuntime:
         with self._collect_lock:
             try:
                 # the actors finishing the epoch's queued chunks ...
-                with span("dispatch.drain", stage="dispatch.drain", **tag):
+                with span(
+                    "dispatch.drain", stage="dispatch.drain", wait="actor",
+                    **tag,
+                ):
                     ok = self._await(
                         lambda: len(self._taken.get(epoch, ()))
                         >= len(self.actors),
@@ -1365,7 +1391,10 @@ class GraphRuntime:
                         epoch,
                     )
                 # ... then flush, finish_barrier fence, collection
-                with span("dispatch.flush", stage="dispatch.flush", **tag):
+                with span(
+                    "dispatch.flush", stage="dispatch.flush", wait="actor",
+                    **tag,
+                ):
                     ok = ok and self._await(
                         lambda: len(self._collected.get(epoch, ()))
                         >= len(self.actors),

@@ -32,6 +32,8 @@ fall back to it (and three consecutive fulls raise).
 
 from __future__ import annotations
 
+import json
+import logging
 import os
 import threading
 import time
@@ -59,9 +61,18 @@ from risingwave_tpu.resilience import (
     RetryingObjectStore,
     RetryPolicy,
 )
-from risingwave_tpu.trace import TRACER, bind, close_epoch, span
+from risingwave_tpu.trace import (
+    TRACER,
+    active_spans,
+    barrier_path,
+    bind,
+    close_epoch,
+    span,
+)
 from risingwave_tpu.storage.object_store import ObjectStore
 from risingwave_tpu.storage.state_table import CheckpointManager
+
+_LOG = logging.getLogger(__name__)
 
 
 class StreamingRuntime:
@@ -1428,13 +1439,17 @@ class StreamingRuntime:
         close_epoch(tr.epoch)
         # what this path instruments reads 0.0 where its span does not
         # run (no permit waited for, no string new), never absent
-        tr.declare("ingest", "ingest.permit_wait", "publish", "bookkeeping")
+        tr.declare(
+            "ingest", "ingest.permit_wait", "ingest.device_wait",
+            "publish", "bookkeeping",
+        )
         if self.in_flight_barriers <= 1:
             tr.declare("dispatch")
         if is_ckpt:
             tr.declare(
-                "checkpoint_stage", "checkpoint_stage.pull",
-                "checkpoint_stage.dictionary",
+                "checkpoint_stage", "checkpoint_stage.marks",
+                "checkpoint_stage.pull", "checkpoint_stage.dictionary",
+                "checkpoint_stage.device_wait",
             )
         # charge accumulated push() time/bytes to this epoch's ingest
         sums: Dict[str, float] = {}
@@ -1496,8 +1511,11 @@ class StreamingRuntime:
         with span("bookkeeping.meshprof"):
             MESHPROF.observe_barrier(self, tr)
         # flight recorder: the finalized trace is exactly one black-box
-        # record (ring always; segment file when a dir is configured)
+        # record (ring always; segment file when a dir is configured);
+        # a barrier that stands out of the ring's takes its own path along
         with span("bookkeeping.recorder"):
+            if blackbox.RECORDER.is_slow(tr.wall_ms):
+                self._note_slow_barrier(tr)
             blackbox.RECORDER.record_barrier(tr, runtime=self)
         if tr.checkpoint:
             EVENT_LOG.record(
@@ -1506,6 +1524,40 @@ class StreamingRuntime:
                 wall_ms=round(tr.wall_ms, 2),
                 achieved_bw_frac=tr.achieved_bw_frac,
             )
+
+    def _note_slow_barrier(self, tr: EpochTrace) -> None:
+        """Where a slow barrier sat (S15): its critical path out of the
+        span ring, while the ring still holds it, as one ``slow_barrier``
+        event, one warning on stderr (an untraced run prints nothing
+        else of a barrier's inside) and the ``slow`` field of the
+        barrier's flight record. Never faults the barrier."""
+        try:
+            path = barrier_path(tr.epoch)
+            if path is None:
+                return
+            me = threading.current_thread().name + "("
+            tr.slow_path = {
+                "epoch": tr.epoch,
+                "wall_ms": round(path["wall_ms"], 3),
+                "by_kind": {
+                    k: round(v, 3) for k, v in path["by_kind"].items()
+                },
+                "by_span": [
+                    [name, kind, round(ms, 3)]
+                    for name, kind, ms in path["by_span"][:8]
+                ],
+                "actors": path["actors"],
+                "threads": {
+                    t: stack for t, stack in active_spans().items()
+                    if not t.startswith(me)
+                },
+            }
+            EVENT_LOG.record("slow_barrier", **tr.slow_path)
+            _LOG.warning(
+                "slow_barrier %s", json.dumps(tr.slow_path, default=str)
+            )
+        except Exception:  # noqa: BLE001 — accounting never faults
+            pass
 
     def _observe_freshness(self, tr: EpochTrace) -> None:
         """Per-MV freshness deltas at the VISIBLE point + the barrier's
@@ -1781,6 +1833,7 @@ class StreamingRuntime:
                             "checkpoint.queue_wait",
                             t_queued,
                             time.perf_counter() - t_queued,
+                            wait="queue",
                         )
                         with span("checkpoint.commit"):
                             durable = self._commit_or_degrade(

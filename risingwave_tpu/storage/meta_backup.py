@@ -78,7 +78,7 @@ class MetaStore:
         segment named by ``first``; bytes written."""
         with span("dictionary.json", strings=len(strings)):
             blob = json.dumps({"first": first, "strings": strings}).encode()
-        with span("dictionary.put", bytes=len(blob)):
+        with span("dictionary.put", wait="io", bytes=len(blob)):
             self.store.put(_segment_path(first), blob)
         return len(blob)
 

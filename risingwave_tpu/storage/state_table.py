@@ -54,7 +54,7 @@ from risingwave_tpu.resilience import (
     RetryPolicy,
 )
 from risingwave_tpu.storage.object_store import ObjectStore
-from risingwave_tpu.trace import bind, span
+from risingwave_tpu.trace import add_stage, bind, device_read, span
 from risingwave_tpu.storage.block_sst import (
     BlockSst,
     build_block_sst,
@@ -100,6 +100,16 @@ class StateDelta:
     value_cols: Dict[str, np.ndarray]
     tombstone: np.ndarray
     key_order: Tuple[str, ...]
+
+
+def read_marks(*lanes) -> List[np.ndarray]:
+    """Mark lanes of a table (sdirty, live, stored: a byte a slot, the
+    table's capacity long) copied to the host: the blocking reads
+    inside ``checkpoint.marks``, one ``device.read``."""
+    with device_read(
+        "checkpoint.marks", bytes=sum(int(a.nbytes) for a in lanes)
+    ):
+        return [np.asarray(a) for a in lanes]
 
 
 def stage_marks(
@@ -190,8 +200,10 @@ def pull_rows(
         table_id=table_id,
         rows=n,
         padded_rows=pad,
-    ):
-        return _pull(device_lanes, sel, n, block)
+    ) as sp:
+        out = _pull(device_lanes, sel, n, block)
+    _STAGING.pull_s = getattr(_STAGING, "pull_s", 0.0) + sp.dur
+    return out
 
 
 def _pull(device_lanes, sel, n: int, block: int) -> Dict[str, np.ndarray]:
@@ -203,12 +215,14 @@ def _pull(device_lanes, sel, n: int, block: int) -> Dict[str, np.ndarray]:
         _gather(lanes, jnp.asarray(idx[a : a + block]))
         for a in range(0, len(idx), block)
     ]
-    if len(parts) == 1:
-        return {k: np.asarray(a)[:n] for k, a in parts[0].items()}
-    return {
-        k: np.concatenate([np.asarray(p[k]) for p in parts])[:n]
-        for k in parts[0]
-    }
+    with device_read(
+        "pull_rows",
+        bytes=sum(int(a.nbytes) for p in parts for a in p.values()),
+    ):
+        host = [{k: np.asarray(a) for k, a in p.items()} for p in parts]
+    if len(host) == 1:
+        return {k: a[:n] for k, a in host[0].items()}
+    return {k: np.concatenate([p[k] for p in host])[:n] for k in host[0]}
 
 
 @jax.jit
@@ -245,14 +259,20 @@ class Checkpointable:
         an executor's staging does outside its row pull — reading the
         dirty/live/stored marks off the device, classifying them,
         flipping them. Its ``pull_rows`` nest inside as
-        ``checkpoint.pull``, carrying this table's id."""
+        ``checkpoint.pull``, carrying this table's id; the stage key
+        ``checkpoint_stage.marks`` holds the span less those pulls, so
+        that it lies beside ``checkpoint_stage.pull`` and not over it."""
         tid = self.table_id or ",".join(self.checkpoint_table_ids())
         if not tid:  # state that is no table (the session dictionary)
             return self.checkpoint_delta()
-        _STAGING.table_id = tid
+        _STAGING.table_id, _STAGING.pull_s = tid, 0.0
         try:
-            with span("checkpoint.marks", table_id=tid):
-                return self.checkpoint_delta()
+            with span("checkpoint.marks", table_id=tid) as sp:
+                deltas = self.checkpoint_delta()
+            add_stage(
+                "checkpoint_stage.marks", (sp.dur - _STAGING.pull_s) * 1e3
+            )
+            return deltas
         finally:
             _STAGING.table_id = None
 
@@ -553,7 +573,8 @@ class CheckpointManager:
                 path = (
                     f"{self.prefix}/sst/{delta.table_id}/{epoch:020d}.sst"
                 )
-                self.store.put(path, blob)
+                with span("upload.put", wait="io", bytes=len(blob)):
+                    self.store.put(path, blob)
                 up.args["bytes"] = len(blob)
             REGISTRY.counter("checkpoint_upload_bytes_total").inc(
                 len(blob), table_id=delta.table_id
@@ -575,7 +596,7 @@ class CheckpointManager:
         # epoch); tests inject crashes here (utils_sync_point)
         sync_point.hit("before_manifest_commit")
         with span(
-            "checkpoint.manifest", stage="manifest_commit"
+            "checkpoint.manifest", stage="manifest_commit", wait="io"
         ), self._lock:
             # re-validate under the lock: a concurrent commit may have
             # advanced the epoch while our SSTs uploaded; publishing

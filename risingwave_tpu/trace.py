@@ -2,7 +2,7 @@
 
 Reference: the reference threads `tracing` spans through every actor/
 executor and exports via opentelemetry (src/utils/runtime/src/, await
-tree dumps). Here ``span(name, *, stage=None, **args)`` is that call:
+tree dumps). Here ``span(name, *, stage=None, wait=None, **args)`` is that call:
 
 - every span records name, start, duration, thread, its **parent** (the
   span open on the same thread when it began) and the **epoch** it
@@ -23,6 +23,17 @@ tree dumps). Here ``span(name, *, stage=None, **args)`` is that call:
   stamps are always on; ``Span.traced`` says whether the session held
   the span from start to end (whether the xplane has its event), so a
   reader of the ring can tell the epochs a device trace covers;
+- with ``wait=`` a span says that its thread did not work but waited,
+  and for what (``WAITS``): ``device`` (a blocking device->host read or
+  ``block_until_ready``), ``actor`` (other threads of the graph),
+  ``permit``, ``queue`` (an empty input, the checkpoint lane), ``io`` (a
+  put or an fsync on the barrier's thread). It is kept on the ``Span``
+  and is an argument of the xplane's event. ``device_read(what, ...)``
+  is the one span around a blocking read, ``device.read``: it reports
+  to the stage key ``<root>.device_wait`` of whatever encloses it;
+- ``barrier_path(epoch)`` reduces the ring's spans of one epoch to the
+  barrier's critical path: host time, device wait, I/O, permits and
+  queues along the slowest actor (no cost unless called);
 - jax's ``/jax/core/compile/*`` events become ``compile`` spans (stage
   ``compile``), children of whatever span was open on the compiling
   thread, and a finished backend compile under an open span becomes one
@@ -60,6 +71,18 @@ from risingwave_tpu.event_log import EVENT_LOG
 
 _MAX_EVENTS = 65_536
 _MAX_PENDING = 8_192  # spans of one thread waiting for their epoch
+
+# what a span may say it waits for (``wait=``) -> the kind its time is
+# booked to on a barrier's path. An ``actor`` wait is never booked: over
+# its interval the path goes on along the actor that released it.
+WAITS = {
+    "device": "device_wait",  # a blocking device->host read, a fence
+    "actor": None,  # other threads of the graph
+    "permit": "permit",  # a full channel downstream
+    "queue": "queue",  # an empty input, the checkpoint lane
+    "io": "io",  # a put or an fsync
+}
+KINDS = ("host", "device_wait", "io", "permit", "queue", "unattributed")
 
 _SIDS = itertools.count(1)  # span ids; next() is atomic under the GIL
 _profiling = TraceAnnotation.is_enabled  # is a profiler session running
@@ -133,6 +156,7 @@ def active_spans() -> dict:
                 {
                     "span": sp.name,
                     "elapsed_s": round(now - sp.t0, 4),
+                    **({"wait": sp.wait} if sp.wait else {}),
                     **({"args": dict(sp.args)} if sp.args else {}),
                 }
                 for sp in stack
@@ -158,14 +182,17 @@ class Span:
     ``args`` may be added to while it is open (``sp.args["rows"] = n``)."""
 
     __slots__ = (
-        "tracer", "name", "stage", "args", "tid", "sid", "parent",
+        "tracer", "name", "stage", "wait", "args", "tid", "sid", "parent",
         "epoch", "t0", "dur", "_ann", "traced",
     )
 
-    def __init__(self, tracer, name, stage, args):
+    def __init__(self, tracer, name, stage, args, wait=None):
         self.tracer = tracer
         self.name = name
         self.stage = stage
+        if wait is not None and wait not in WAITS:
+            raise ValueError(f"span {name!r}: unknown wait {wait!r}")
+        self.wait = wait  # what the thread waits for (WAITS), or None
         self.args = args
         self.dur = None
         self._ann = None
@@ -204,6 +231,8 @@ class Span:
             elif st.closed is not None:
                 # open epoch, number not known yet: the one after this
                 kw["after"] = st.closed
+            if self.wait is not None:
+                kw["wait"] = self.wait
             self._ann = TraceAnnotation("rw/" + self.name, **kw)
             self._ann.__enter__()
         stack.append(self)
@@ -234,6 +263,8 @@ class Span:
         it, parent and epoch among the args."""
         args = dict(self.args)
         args["sid"] = self.sid
+        if self.wait is not None:
+            args["wait"] = self.wait
         if self.parent is not None:
             args["parent"] = self.parent
         if self.epoch is not None:
@@ -261,24 +292,57 @@ class Tracer:
         self._events: deque = deque(maxlen=max_events)
         self.enabled = True
 
-    def span(self, name: str, *, stage=None, **args):
+    def span(self, name: str, *, stage=None, wait=None, **args):
         if not self.enabled:
             return _Off()
-        return Span(self, name, stage, args)
+        return Span(self, name, stage, args, wait)
+
+    def device_read(self, what: str, **args):
+        """The span ``device.read`` (``wait="device"``): put exactly
+        around a call that blocks this thread on the device (a device->
+        host copy, a ``block_until_ready``) and around nothing else.
+        ``what`` names the read; ``lanes`` or ``bytes`` where the site
+        knows them. Its time goes to the stage key ``<root>.device_wait``,
+        ``<root>`` being the first component of the stage of the
+        outermost open span that has one (``ingest``, ``dispatch``,
+        ``checkpoint_stage``; an actor's ``actor_fence`` reads
+        ``actor``): the stage whose time the wait lies in, so a table
+        fragment's copy under a push is the ingest's and not the view's.
+        With no stage open it is the outermost span's name that is read
+        (``actor.chunk``: ``actor``); a read under no span stamps none."""
+        if not self.enabled:
+            return _Off()
+        stack = _state().stack
+        within = next(
+            (sp.stage for sp in stack if sp.stage is not None),
+            stack[0].name if stack else None,
+        )
+        stage = None
+        if within is not None:
+            root = within.split(".", 1)[0]
+            if root.startswith("actor_"):  # actor_fence, actor_busy, ...
+                root = "actor"
+            stage = root + ".device_wait"
+        return Span(
+            self, "device.read", stage, dict(args, what=what), "device"
+        )
 
     def _record(self, sp: Span, st: _ThreadState) -> None:
         self._events.append(sp)  # deque.append is atomic
         if sp.epoch is None:
             st.pending.append(sp)
 
-    def record(self, name: str, t0: float, dur: float, *, stage=None, **args):
+    def record(
+        self, name: str, t0: float, dur: float, *, stage=None, wait=None,
+        **args
+    ):
         """A span that is only known once it is over (jax reports a
         compile when it has finished): child of the span open on this
         thread now, stamped and recorded like any other."""
         if not self.enabled:
             return None
         st = _state()
-        sp = Span(self, name, stage, args)
+        sp = Span(self, name, stage, args, wait)
         up = sp._adopt(st)
         # jax times a compile on the wall clock: keep the span inside
         # the parent it fell in (the stage is stamped with jax's figure)
@@ -291,6 +355,7 @@ class Tracer:
             # end says what it was and how long it took
             with TraceAnnotation(
                 "rw/" + name, ms=round(ms, 3),
+                **({} if wait is None else {"wait": wait}),
                 **{k: v for k, v in args.items() if isinstance(v, (str, int))},
             ):
                 pass
@@ -374,6 +439,197 @@ def _log_compile(sp: Span) -> None:
         fun_name=sp.args["fun_name"],
         ms=round(sp.dur * 1e3, 3),
     )
+
+
+# -- a barrier's critical path --------------------------------------------
+
+# an ``actor`` wait ends when the last actor of its graph reaches a point
+# of its ``actor.barrier``: these at its start (it has taken the barrier
+# off its inputs), every other at its end (it has collected)
+_RELEASED_AT_START = ("dispatch.drain",)
+# an actor's spans that feed the actors downstream of it
+_ACTOR_WORK = ("actor.chunk", "actor.barrier")
+_NO_SPAN = "(no span)"
+
+
+class _Path:
+    """The walk of ``barrier_path`` over one epoch's spans."""
+
+    def __init__(self, mine):
+        self.by_kind = dict.fromkeys(KINDS, 0.0)
+        self.by_span: dict = {}
+        self.actors: list = []
+        sids = {sp.sid for sp in mine}
+        self.kids: dict = {}  # parent's sid -> its children, by start
+        self.tops: dict = {}  # tid -> the thread's outermost spans
+        self.barriers: dict = {}  # (graph, actor) -> its actor.barrier
+        for sp in sorted(mine, key=lambda sp: sp.t0):
+            if sp.parent is not None:
+                self.kids.setdefault(sp.parent, []).append(sp)
+            if sp.parent not in sids:
+                self.tops.setdefault(sp.tid, []).append(sp)
+            if sp.name == "actor.barrier":
+                key = (sp.args.get("graph"), sp.args.get("actor"))
+                self.barriers[key] = sp
+        self.actor_of = {sp.tid: key for key, sp in self.barriers.items()}
+
+    def book(self, name, kind, seconds) -> None:
+        if seconds > 0.0:
+            self.by_kind[kind] += seconds
+            key = (name, kind)
+            self.by_span[key] = self.by_span.get(key, 0.0) + seconds
+
+    @staticmethod
+    def end(sp, hi):
+        # a span still open (the root, asked from inside) ends at ``hi``
+        return hi if sp.dur is None else sp.t0 + sp.dur
+
+    def span(self, sp, lo, hi) -> None:
+        """``sp`` clipped to [lo, hi]: its children, and between them
+        its own time."""
+        a, b = max(sp.t0, lo), min(self.end(sp, hi), hi)
+        cursor = a
+        for ch in self.kids.get(sp.sid, ()):
+            ca, cb = max(ch.t0, cursor), min(self.end(ch, b), b)
+            if cb <= ca:
+                continue
+            self.own(sp, cursor, ca)
+            self.span(ch, ca, cb)
+            cursor = cb
+        self.own(sp, cursor, b)
+
+    def own(self, sp, a, b) -> None:
+        """[a, b] of ``sp`` that no child covers."""
+        if b <= a:
+            return
+        if sp.wait == "actor":
+            self.await_actor(sp, a, b)
+        elif sp.wait == "queue":
+            self.await_upstream(sp, a, b)
+        else:
+            # a blocking read goes by what it read: device.read[<what>]
+            what = sp.args.get("what") if sp.wait == "device" else None
+            name = sp.name if what is None else f"{sp.name}[{what}]"
+            self.book(name, WAITS.get(sp.wait) or "host", b - a)
+
+    def thread(self, tid, lo, hi) -> None:
+        """What thread ``tid`` did over [lo, hi]; what no span of it
+        covers is unattributed."""
+        cursor = lo
+        for sp in self.tops.get(tid, ()):
+            a, b = max(sp.t0, cursor), min(self.end(sp, hi), hi)
+            if b <= a:
+                continue
+            self.book(_NO_SPAN, "unattributed", a - cursor)
+            self.span(sp, a, b)
+            cursor = b
+        self.book(_NO_SPAN, "unattributed", hi - cursor)
+
+    def cross(self, key) -> None:
+        graph, actor = key
+        label = f"{graph}/{actor}" if graph else actor
+        if label not in self.actors:
+            self.actors.append(label)
+
+    def await_actor(self, sp, a, b) -> None:
+        """The barrier's thread waited for its graph's actors over
+        [a, b]: the path goes along the actor that released it last;
+        from the release on it is the waiter's own waking up."""
+        graph = sp.args.get("fragment")
+        mine = [
+            (key, x) for key, x in self.barriers.items() if key[0] == graph
+        ]
+        if not mine:
+            self.book(sp.name, "unattributed", b - a)
+            return
+        if sp.name in _RELEASED_AT_START:
+            key, last = max(mine, key=lambda kx: kx[1].t0)
+            released = last.t0
+        else:
+            key, last = max(mine, key=lambda kx: self.end(kx[1], b))
+            released = self.end(last, b)
+        released = min(max(released, a), b)
+        self.cross(key)
+        self.thread(last.tid, a, released)
+        self.book(sp.name, "host", b - released)
+
+    def await_upstream(self, sp, a, b) -> None:
+        """An actor sat on an empty input over [a, b]: the path goes
+        along the actor upstream of it whose chunk or barrier was at
+        work nearest to the wait's end (it sends from inside that span),
+        and so on up to the sources, whose wait is the queue's; from
+        that span's end on it is the message's way and the waking up."""
+        me = self.actor_of.get(sp.tid)
+        upstream = self.barriers[me].args.get("upstream", ()) if me else ()
+        best = None  # (how near to b its work came, its key, its thread)
+        for name in upstream:
+            up = self.barriers.get((me[0], name))
+            for x in self.tops.get(up.tid, ()) if up is not None else ():
+                if x.name in _ACTOR_WORK and x.t0 < b:
+                    reach = min(self.end(x, b), b)
+                    if best is None or reach > best[0]:
+                        best = (reach, (me[0], name), up.tid)
+        if best is None or best[0] <= a:
+            self.book(sp.name, "queue", b - a)
+            return
+        released, key, tid = best
+        self.cross(key)
+        self.thread(tid, a, released)
+        self.book(sp.name, "queue", b - released)
+
+
+def barrier_path(epoch: int, spans=None):
+    """One epoch's barrier reduced to its critical path, from the ring
+    (or from ``spans``); pure, and no cost unless called.
+
+    It starts at the epoch's ``barrier`` root span on the barrier's
+    thread. A span's own time (its duration less what its children
+    cover) is booked to its kind: ``host`` when it has no ``wait``, else
+    ``device_wait``, ``io``, ``permit``, ``queue``. A span with
+    ``wait="actor"`` is replaced, over its own interval, by the spans of
+    the actor thread that released it last (``dispatch.flush``: the actor
+    whose ``actor.barrier`` of the epoch ended last; ``dispatch.drain``:
+    the one whose began last, which is when it had taken the barrier),
+    clipped to the interval and reduced the same way; a ``queue`` wait of
+    that actor inside the interval is replaced in turn by the actor
+    upstream of it, up to the sources, so a chain of actors reads as the
+    work that was done and not as each one's wait for the one before.
+    What no span covers is ``unattributed``.
+
+    -> ``{"wall_ms", "by_kind": {kind: ms}, "by_span": [(span name,
+    kind, ms), ... largest first; a blocking read as ``device.read[<what
+    it read>]``], "actors": [the actors the path crossed]}``; ``by_kind``
+    sums to ``wall_ms``. None when the ring does
+    not hold the epoch's barrier whole (it wrapped, or there was none: a
+    pipelined barrier has no root span)."""
+    if spans is None:
+        spans = TRACER.spans()
+    mine = [sp for sp in spans if sp.epoch == epoch]
+    root = next((sp for sp in mine if sp.name == "barrier"), None)
+    if root is not None:
+        end = root.t0 + root.dur
+    else:
+        # asked from inside the barrier: the root is still open here
+        stack = _state().stack
+        if not (stack and stack[0].name == "barrier"
+                and stack[0].epoch == epoch):
+            return None
+        root, end = stack[0], time.perf_counter()
+    if len(spans) >= TRACER._events.maxlen:
+        oldest = spans[0]
+        if oldest.t0 + oldest.dur > root.t0:
+            return None  # spans of this barrier's time have been dropped
+    walk = _Path(mine)
+    walk.span(root, root.t0, end)
+    return {
+        "wall_ms": (end - root.t0) * 1e3,
+        "by_kind": {k: v * 1e3 for k, v in walk.by_kind.items()},
+        "by_span": sorted(
+            ((n, k, s * 1e3) for (n, k), s in walk.by_span.items()),
+            key=lambda row: -row[2],
+        ),
+        "actors": walk.actors,
+    }
 
 
 _COMPILE_PREFIX = "/jax/core/compile/"
@@ -493,3 +749,4 @@ def render_chrome_trace(events, thread_names=None) -> str:
 
 TRACER = Tracer()
 span = TRACER.span
+device_read = TRACER.device_read

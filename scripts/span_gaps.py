@@ -6,9 +6,15 @@ the harness would delete, and reduces it: the device's idle gaps (the
 complement of the union of ``XLA Ops``) split by the innermost ``rw/``
 span open on the feed loop's thread, beside the harness's own ``bench/``
 phases, and for every other thread that carries ``rw/`` spans what it
-was in while the device idled. From the program's span ring it adds,
-per epoch, the summed milliseconds of every span name, so that a step
-in the barriers' times can be put on a stage.
+was in while the device idled, and whether it worked or waited there
+(the ``wait`` argument of the innermost ``rw/`` event: a gap under a
+span with none is the host working, under ``device`` the host waiting
+on the device). From the program's span ring it adds, per epoch, the
+summed milliseconds of every span name, so that a step in the barriers'
+times can be put on a stage, and the barrier's critical path
+(``trace.barrier_path``) of every epoch of the window beside the stages
+it has to account for, with the ``by_span`` table of the median and of
+the slowest barrier printed.
 
     python scripts/span_gaps.py --out chiprun_out/gaps_steady.json -- \\
         --workload nexmark_q8.steady --seed 4300000001 --seconds 40 --trace 1
@@ -131,6 +137,16 @@ def intersect(a, b):
     return out
 
 
+def by_wait(evs, gaps) -> dict:
+    """Seconds of ``gaps`` by what the thread's innermost ``rw/`` span
+    waited for: ``working`` where it says nothing."""
+    flat = innermost([
+        (s, e, stats.get("wait", "working"))
+        for s, e, name, stats in evs if name.startswith("rw/")
+    ])
+    return overlap_by_name(flat, gaps)
+
+
 def evs_of(evs, prefix):
     return [e[:3] for e in evs if e[2].startswith(prefix)]
 
@@ -176,6 +192,7 @@ def reduce_xplane(path: str) -> dict:
             "idle_gap_s_by_innermost_span": dict(
                 sorted(by_span.items(), key=lambda kv: -kv[1])
             ),
+            "idle_gap_s_by_wait": by_wait(evs, gaps),
         }
         if label == "feed+barrier":
             bench = innermost(evs_of(evs, "bench/"))
@@ -271,10 +288,93 @@ def summarize_ring(epochs: list) -> dict:
     }
 
 
+# the stages of the barrier's thread that a barrier's path accounts for
+PATH_STAGES = ("dispatch", "checkpoint_stage", "publish", "bookkeeping")
+
+
+def window_paths(window) -> dict:
+    """``trace.barrier_path`` of every epoch of the window (as the
+    benchmark's own ``readers/epoch_spans.py`` finds them), each beside
+    the summed ``PATH_STAGES`` spans of the same epoch, and the
+    ``by_span`` table of the median and of the slowest barrier."""
+    from readers.epoch_spans import window_epochs  # benchmarks/ is on the path
+    from risingwave_tpu.trace import TRACER, barrier_path
+
+    spans = TRACER.spans()
+    epochs = window_epochs(
+        {"epochs": [
+            {"t_inject": e.t_inject, "t_return": e.t_return, "events": 0}
+            for e in window if e.ok
+        ]},
+        spans,
+    )
+    rows = []
+    for sp in spans:
+        if sp.name != "barrier" or sp.epoch not in epochs:
+            continue
+        path = barrier_path(sp.epoch, spans)
+        if path is None:
+            continue
+        stages = dict.fromkeys(PATH_STAGES, 0.0)
+        for x in spans:
+            if x.epoch == sp.epoch and x.tid == sp.tid and x.stage in stages:
+                stages[x.stage] += x.dur * 1e3
+        rows.append({"epoch": sp.epoch, "stages_ms": stages, **path})
+    if not rows:
+        return {"epochs": 0}
+    by_wall = sorted(rows, key=lambda r: r["wall_ms"])
+    kinds = sorted(rows[0]["by_kind"])
+    accounted = [
+        sum(v for k, v in r["by_kind"].items() if k != "unattributed")
+        for r in rows
+    ]
+    staged = [sum(r["stages_ms"].values()) for r in rows]
+    return {
+        "epochs": len(rows),
+        "median_by_kind_ms": {
+            k: statistics.median(r["by_kind"][k] for r in rows) for k in kinds
+        },
+        "median_wall_ms": statistics.median(r["wall_ms"] for r in rows),
+        "median_accounted_ms": statistics.median(accounted),
+        "median_stages_ms": statistics.median(staged),
+        "worst_sum_error": max(
+            abs(sum(r["by_kind"].values()) - r["wall_ms"]) / r["wall_ms"]
+            for r in rows
+        ),
+        "worst_accounted_over_stages": max(
+            abs(a / s - 1.0) for a, s in zip(accounted, staged) if s
+        ),
+        "median_barrier": by_wall[len(by_wall) // 2],
+        "slowest_barrier": by_wall[-1],
+        "every_epoch": [
+            {k: r[k] for k in ("epoch", "wall_ms", "by_kind", "stages_ms")}
+            for r in rows
+        ],
+    }
+
+
+def print_path(title: str, row: dict) -> None:
+    print(f"BARRIER_PATH {title}: epoch {row['epoch']} "
+          f"wall {row['wall_ms']:.1f} ms, actors {row['actors']}")
+    print("  " + "  ".join(f"{k} {v:.1f}" for k, v in row["by_kind"].items()))
+    for name, kind, ms in row["by_span"][:12]:
+        print(f"  {ms:9.2f} ms  {kind:12s} {name}")
+
+
 def run_cell(harness_args, out_path: str) -> int:
     import jax
 
+    sys.path.insert(0, os.path.join(ROOT, "benchmarks"))  # as a script has it
+    import feed  # the harness's own module: its window is kept below
+
     kept = {}
+    run_feed = feed.run_feed
+
+    def keep_window(*a, **kw):
+        kept["window"] = run_feed(*a, **kw)
+        return kept["window"]
+
+    feed.run_feed = keep_window
     start, stop = jax.profiler.start_trace, jax.profiler.stop_trace
 
     def start_trace(log_dir, *a, **kw):
@@ -293,7 +393,6 @@ def run_cell(harness_args, out_path: str) -> int:
 
     jax.profiler.start_trace, jax.profiler.stop_trace = start_trace, stop_trace
     sys.argv = [os.path.join(ROOT, "benchmarks", "run.py"), *harness_args]
-    sys.path.insert(0, os.path.dirname(sys.argv[0]))  # as a script has it
     rc = 0
     try:
         runpy.run_path(sys.argv[0], run_name="__main__")
@@ -301,6 +400,7 @@ def run_cell(harness_args, out_path: str) -> int:
         rc = int(e.code or 0)
     finally:
         jax.profiler.start_trace, jax.profiler.stop_trace = start, stop
+        feed.run_feed = run_feed
     report = {"harness_args": harness_args, "harness_rc": rc}
     if "pb" in kept:
         report.update(reduce_xplane(kept["pb"]))
@@ -309,10 +409,20 @@ def run_cell(harness_args, out_path: str) -> int:
     epochs = ring_epochs()
     report["ring"] = summarize_ring(epochs)
     report["ring_epochs"] = epochs
+    if "window" in kept:
+        report["paths"] = window_paths(kept["window"].epochs)
     with open(out_path, "w") as f:
         json.dump(report, f, indent=1, default=str)
     brief = {k: v for k, v in report.items() if k != "ring_epochs"}
+    paths = brief.pop("paths", {"epochs": 0})
     print("SPAN_GAPS " + json.dumps(brief, default=str))
+    if paths["epochs"]:
+        print("BARRIER_PATHS " + json.dumps(
+            {k: v for k, v in paths.items()
+             if k not in ("median_barrier", "slowest_barrier", "every_epoch")}
+        ))
+        print_path("median", paths["median_barrier"])
+        print_path("slowest", paths["slowest_barrier"])
     return rc
 
 
