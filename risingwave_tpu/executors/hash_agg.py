@@ -574,6 +574,14 @@ class HashAggExecutor(Executor, Checkpointable):
         # (a delete pre-merge would falsely latch inconsistent), so
         # evicted keys fault in ON TOUCH via this host-side set
         self._evicted: set = set()
+        # whether ``evict_cold`` has dropped any group since this state
+        # was built or restored. Only an eviction takes a key out of
+        # the table and leaves its row in the store (a rebuild keeps
+        # every group that is live, emitted, dirty or unpersisted; an
+        # expiry tombstones what it closes; a restore brings every
+        # durable group back resident), so until one has, the barrier's
+        # merge has nothing to find and reads nothing (_merge_cold)
+        self._has_evicted = False
 
     @property
     def cold_reader(self):
@@ -1079,6 +1087,8 @@ class HashAggExecutor(Executor, Checkpointable):
             self.table, self.state, self.minput, self.calls, new_cap
         )
         n = int(n)
+        if n:
+            self._has_evicted = True
         self._insert_bound = int(self.table.occupancy())
         self._slots_moved()
         return n
@@ -1143,24 +1153,51 @@ class HashAggExecutor(Executor, Checkpointable):
         )
         self.dropped = self.dropped | ovf
 
-    def _merge_cold(self) -> int:
+    def _merge_cold(self, land=None) -> int:
         """Fold durable state into groups (re)created since the last
         checkpoint: candidates are sdirty & ~stored; a cold-store hit
         means the key was evicted earlier and its persisted accumulators
         must combine with what accrued since (merge-on-return; the
         reference reloads through its state-table cache instead).
 
-        The span ``agg.merge_cold`` (table_id; candidates = groups new
-        since the last checkpoint, found = those the cold store held):
-        every barrier of an aggregate over a store reads the candidate
-        lane off the device, waiting out the epoch's steps, and looks
-        every candidate's key up in the store."""
-        with span("agg.merge_cold", table_id=self.table_id) as sp:
+        A hit takes an eviction (``_has_evicted``). Until there has
+        been one this returns at once: no lane is copied, no key
+        pulled, the store is not asked, and the barrier's flush is
+        enqueued behind the epoch's steps with nothing read before it.
+        ``land``: a fused barrier still holds the epoch's rows when it
+        calls here, and steps and flushes in one program; where the
+        merge runs it has them stepped first (the slots it folds into
+        are theirs), where it does not they stay one program.
+
+        The span ``agg.merge_cold`` (table_id; barrier = 1, ran = 1
+        where the merge ran, 0 where it was skipped; candidates = groups
+        new since the last checkpoint, found = those the cold store
+        held), at every barrier of an aggregate over a store, and the
+        counter ``agg_cold_merge_total{table_id, outcome}`` (``ran`` |
+        ``skipped``). Where it runs it reads the candidate lane off the
+        device, waiting out the epoch's steps, and looks every
+        candidate's key up in the store."""
+        ran = self._has_evicted
+        REGISTRY.counter("agg_cold_merge_total").inc(
+            table_id=self.table_id, outcome="ran" if ran else "skipped"
+        )
+        with span(
+            "agg.merge_cold",
+            table_id=self.table_id,
+            barrier=1,
+            ran=int(ran),
+            candidates=0,
+            found=0,
+        ) as sp:
+            if not ran:
+                return 0
+            if land is not None:
+                land()
             cand = self.state.sdirty & ~self.state.stored
             with device_read("agg.merge_cold", lanes=cand.shape[0]):
                 cand = np.asarray(cand)
             sel = np.flatnonzero(cand)
-            sp.args.update(candidates=len(sel), found=0)
+            sp.args["candidates"] = len(sel)
             if not len(sel):
                 return 0
             lanes = {f"k{i}": lane for i, lane in enumerate(self.table.keys)}
@@ -1707,6 +1744,7 @@ def _agg_restore_state(self, table_id, key_cols, value_cols) -> None:
     self._touched_lanes = None  # a table the steps' list never saw
     # recovery restored every durable group as RESIDENT state
     self._evicted = set()
+    self._has_evicted = False
 
 
 def _agg_digest_lanes(self):

@@ -604,9 +604,14 @@ class FusedChainExecutor(Executor):
 
     # -- control path -----------------------------------------------------
     def on_barrier(self, barrier: Barrier) -> List[StreamChunk]:
+        outs: List[StreamChunk] = []
         if self.agg is not None and self.agg._cold_barrier_hook is not None:
-            self.agg._cold_barrier_hook()
-        outs = self._run(flush=True, stage=True)
+            # a merge folds into slots the buffered rows create: where
+            # one runs they are stepped first, in a program of their own
+            self.agg._cold_barrier_hook(
+                lambda: outs.extend(self._run(flush=False, stage=False))
+            )
+        outs += self._run(flush=True, stage=True)
         if barrier is None:  # direct drive: checks fire inline
             self.finish_barrier()
         return outs
@@ -1467,9 +1472,13 @@ class FusedTwoInputExecutor(Executor):
 
     # -- control path -----------------------------------------------------
     def on_barrier(self, barrier: Barrier) -> List[StreamChunk]:
+        outs: List[StreamChunk] = []
         if self.agg is not None and self.agg._cold_barrier_hook is not None:
-            self.agg._cold_barrier_hook()
-        outs = self._run(flush=True, stage=True)
+            # as FusedChainExecutor.on_barrier
+            self.agg._cold_barrier_hook(
+                lambda: outs.extend(self.flush_data())
+            )
+        outs += self._run(flush=True, stage=True)
         self._barriers += 1
         if barrier is None:  # direct drive: checks fire inline
             self.finish_barrier(force=True)

@@ -305,14 +305,17 @@ def test_is_slow_is_one_comparison_under_a_second():
 
 
 def test_the_cold_merge_at_a_barrier_is_a_span_and_its_read_a_device_read():
-    """An aggregate over a store looks every group new since the last
-    checkpoint up in the store at every barrier, after reading the
-    candidates' lane off the device: both are named in the ring."""
+    """An aggregate over a store that has evicted looks every group new
+    since the last checkpoint up in the store at every barrier, after
+    reading the candidates' lane off the device: both are named in the
+    ring."""
     import numpy as np
 
     from risingwave_tpu.array.chunk import StreamChunk
     from risingwave_tpu.executors.hash_agg import HashAggExecutor
     from risingwave_tpu.ops.agg import AggCall
+    from risingwave_tpu.storage.object_store import MemObjectStore
+    from risingwave_tpu.storage.state_table import CheckpointManager
 
     agg = HashAggExecutor(
         group_keys=("k",),
@@ -329,6 +332,14 @@ def test_the_cold_merge_at_a_barrier_is_a_span_and_its_read_a_device_read():
         return np.zeros(len(keys["k0"]), bool), {}
 
     agg.cold_reader = nothing_cold
+    # the merge engages once a group has been evicted: three durable
+    # groups, dropped from the table
+    agg.apply(
+        StreamChunk.from_numpy({"k": np.arange(100, 103, dtype=np.int64)}, 16)
+    )
+    agg.on_barrier(None)
+    CheckpointManager(MemObjectStore()).commit_epoch(1 << 16, [agg])
+    assert agg.evict_cold() == 3
     agg.apply(StreamChunk.from_numpy({"k": np.arange(7, dtype=np.int64)}, 16))
     TRACER.clear()
     with trace.span("actor.barrier"):
@@ -336,7 +347,10 @@ def test_the_cold_merge_at_a_barrier_is_a_span_and_its_read_a_device_read():
     spans = TRACER.spans()
     by_sid = {sp.sid: sp for sp in spans}
     (merge,) = [sp for sp in spans if sp.name == "agg.merge_cold"]
-    assert merge.args == {"table_id": "unit.cold", "candidates": 7, "found": 0}
+    assert merge.args == {
+        "table_id": "unit.cold", "barrier": 1, "ran": 1,
+        "candidates": 7, "found": 0,
+    }
     assert by_sid[merge.parent].name == "actor.barrier"
     kids = {
         (sp.name, sp.args.get("what"), sp.wait)

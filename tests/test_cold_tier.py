@@ -4,15 +4,26 @@ hash_agg.rs:49 + compute memory controller)."""
 
 import jax.numpy as jnp
 import numpy as np
+import pytest
 
 from risingwave_tpu.array.chunk import StreamChunk
 from risingwave_tpu.executors.hash_agg import HashAggExecutor
+from risingwave_tpu.metrics import REGISTRY
 from risingwave_tpu.ops.agg import AggCall
 from risingwave_tpu.runtime import StreamingRuntime
+from risingwave_tpu.runtime.fused_step import (
+    FusedChainExecutor,
+    FusedTwoInputExecutor,
+    fuse_pipeline,
+)
 from risingwave_tpu.runtime.pipeline import Pipeline
-from risingwave_tpu.executors.materialize import MaterializeExecutor
+from risingwave_tpu.executors.materialize import (
+    DeviceMaterializeExecutor,
+    MaterializeExecutor,
+)
 from risingwave_tpu.storage.object_store import MemObjectStore
 from risingwave_tpu.storage.state_table import CheckpointManager
+from risingwave_tpu.trace import TRACER
 from risingwave_tpu.types import Op
 
 DT = {"k": jnp.int64, "v": jnp.int64}
@@ -565,3 +576,226 @@ def test_evicted_minput_groups_expire_under_watermark():
     assert (1000,) not in snap, "closed window row was not retracted"
     assert snap[(2000,)] == (7,)
     assert all(t[0] >= 1500 for t in ex._evicted)
+
+
+# -- the barrier's merge engages only once an eviction made a hit possible --
+GATE_ROWS = [(k, v, Op.INSERT) for k in range(40) for v in (k, k + 50)]
+# after the eviction: rows on ten cold groups, three of them retractions
+# of a row the store holds, and two groups the store never saw
+GATE_RETURN = (
+    [(k, 100 + k, Op.INSERT) for k in range(10)]
+    + [(k, k + 50, Op.DELETE) for k in range(3)]
+    + [(k, 7, Op.INSERT) for k in (900, 901)]
+)
+
+
+def _gated(kind, barrier, table_id):
+    """[aggregate -> device MV] over a store of its own, walked
+    interpreted or as one fused program a barrier: (pipeline, agg, mv,
+    manager, store)."""
+    calls = {
+        "plain": (
+            AggCall("count_star", None, "cnt"),
+            AggCall("sum", "v", "s"),
+        ),
+        "minput": (
+            AggCall("max", "v", "mx", materialized=True),
+            AggCall("count_star", None, "cnt"),
+        ),
+    }[kind]
+    agg = HashAggExecutor(
+        group_keys=("k",),
+        calls=calls,
+        schema_dtypes=DT,
+        capacity=1 << 10,
+        out_cap=1 << 8,
+        table_id=table_id,
+    )
+    cols = tuple(c.output for c in calls)
+    mv = DeviceMaterializeExecutor(
+        ("k",),
+        cols,
+        {"k": jnp.int64, **{c: jnp.int64 for c in cols}},
+        table_id=table_id + ".mv",
+        capacity=1 << 10,
+    )
+    pipe = Pipeline([agg, mv])
+    if barrier == "fused":
+        (wrapper,) = fuse_pipeline(pipe, label=table_id)
+        assert isinstance(wrapper, FusedChainExecutor)
+    store = MemObjectStore()
+    mgr = CheckpointManager(store)
+    agg.cold_reader = lambda keys: mgr.get_rows(table_id, keys)
+    return pipe, agg, mv, mgr, store
+
+
+def _push_rows(pipe, rows):
+    for at in range(0, len(rows), CAP):
+        pipe.push(_chunk(rows[at : at + CAP]))
+
+
+def _barrier_spans(pipe):
+    """One barrier of ``pipe``; its spans, in the order they began."""
+    TRACER.clear()
+    pipe.barrier()
+    return sorted(TRACER.spans(), key=lambda sp: sp.t0)
+
+
+def _merge_span(spans, table_id):
+    (sp,) = [
+        sp for sp in spans
+        if sp.name == "agg.merge_cold" and sp.args["table_id"] == table_id
+    ]
+    return sp
+
+
+def _merge_count(table_id, outcome):
+    return REGISTRY.counter("agg_cold_merge_total").get(
+        table_id=table_id, outcome=outcome
+    )
+
+
+GATE_CASES = [
+    (kind, barrier)
+    for kind in ("plain", "minput")
+    for barrier in ("interpreted", "fused")
+]
+
+
+@pytest.mark.parametrize("kind,barrier", GATE_CASES)
+def test_a_barrier_before_any_eviction_reads_nothing_for_the_merge(
+    kind, barrier
+):
+    """Nothing was evicted, so the store can hold no group the table
+    lacks: the barrier asks it nothing (the reader here raises), copies
+    no lane, and its flush is enqueued before the first blocking read."""
+    tid = f"gate0.{kind}.{barrier}"
+    pipe, agg, mv, _mgr, _store = _gated(kind, barrier, tid)
+
+    def never(keys):
+        raise AssertionError("the cold store was asked before an eviction")
+
+    agg.cold_reader = never
+    skipped = _merge_count(tid, "skipped")
+    for epoch in range(2):
+        _push_rows(pipe, GATE_ROWS if epoch == 0 else GATE_RETURN)
+        spans = _barrier_spans(pipe)
+        merge = _merge_span(spans, tid)
+        assert merge.args == {
+            "table_id": tid, "barrier": 1, "ran": 0,
+            "candidates": 0, "found": 0,
+        }
+        assert not [sp for sp in spans if sp.parent == merge.sid]
+        reads = [sp for sp in spans if sp.name == "device.read"]
+        assert "agg.merge_cold" not in {sp.args["what"] for sp in reads}
+        if barrier == "interpreted":
+            assert reads[0].args["what"] == "agg.flush.status"
+        else:
+            # the one program steps and flushes: nothing is read before
+            # it is on the device's queue
+            (program,) = [sp for sp in spans if sp.name == f"fused:{tid}"]
+            assert all(sp.t0 > program.t0 for sp in reads)
+    assert _merge_count(tid, "skipped") == skipped + 2
+    assert _merge_count(tid, "ran") == 0
+    assert len(mv.snapshot()) == 42
+
+
+@pytest.mark.parametrize("kind,barrier", GATE_CASES)
+def test_after_an_eviction_the_merge_runs_and_a_restore_disarms_it(
+    kind, barrier
+):
+    """From the first eviction on every barrier merges as it always
+    did: a group touched again emits what its twin that never evicted
+    emits. A restore brings every durable group back resident, and the
+    barriers after it read nothing for the merge again."""
+    tid = f"gate1.{kind}.{barrier}"
+    pipe, agg, mv, mgr, store = _gated(kind, barrier, tid)
+    twin_pipe, twin, twin_mv, twin_mgr, _ = _gated(
+        kind, barrier, tid + ".twin"
+    )
+    for p, a, m in ((pipe, agg, mgr), (twin_pipe, twin, twin_mgr)):
+        _push_rows(p, GATE_ROWS)
+        p.barrier()
+        # nothing is durable yet: an eviction that evicts nothing arms
+        # nothing
+        assert a.evict_cold() == 0 and not a._has_evicted
+        m.commit_epoch(1 << 16, [a])
+    assert agg.evict_cold() == 40
+    assert agg._has_evicted and not twin._has_evicted
+
+    for p in (pipe, twin_pipe):
+        _push_rows(p, GATE_RETURN)
+    ran = _merge_count(tid, "ran")
+    merge = _merge_span(_barrier_spans(pipe), tid)
+    assert (merge.args["barrier"], merge.args["ran"]) == (1, 1)
+    if kind == "plain":
+        # the ten cold groups fold back in; the two new ones miss
+        assert (merge.args["candidates"], merge.args["found"]) == (12, 10)
+    else:
+        # multisets fault in on touch, before the step: stored already
+        assert (merge.args["candidates"], merge.args["found"]) == (2, 0)
+    assert _merge_count(tid, "ran") == ran + 1
+    assert _merge_span(_barrier_spans(twin_pipe), tid + ".twin").args[
+        "ran"
+    ] == 0
+    want = twin_mv.snapshot()
+    assert len(want) == 42 and mv.snapshot() == want
+
+    mgr.commit_epoch(2 << 16, [agg])
+    twin_mgr.commit_epoch(2 << 16, [twin])
+    CheckpointManager(store).recover([agg])
+    assert not agg._has_evicted and agg._evicted == set()
+    again = [(k, 1, Op.INSERT) for k in range(5, 15)]
+    for p in (pipe, twin_pipe):
+        _push_rows(p, again)
+    skipped = _merge_count(tid, "skipped")
+    merge = _merge_span(_barrier_spans(pipe), tid)
+    assert (merge.args["ran"], merge.args["candidates"]) == (0, 0)
+    assert _merge_count(tid, "skipped") == skipped + 1
+    twin_pipe.barrier()
+    assert mv.snapshot() == twin_mv.snapshot()
+
+
+def test_two_input_fused_barrier_lands_the_epoch_before_it_merges():
+    """q7's MAX side inside the two-input fused program: a window
+    evicted and bid on again keeps the maximum the store holds, as the
+    interpreted walk of the same bids does."""
+    from risingwave_tpu.connectors.nexmark import (
+        NexmarkConfig,
+        NexmarkGenerator,
+    )
+    from risingwave_tpu.queries.nexmark_q import build_q7
+
+    def drive(fuse):
+        q7 = build_q7(capacity=1 << 12, state_cleaning=False)
+        (agg,) = [
+            e for e in q7.pipeline.right if isinstance(e, HashAggExecutor)
+        ]
+        mgr = CheckpointManager(MemObjectStore())
+        agg.cold_reader = lambda keys: mgr.get_rows(agg.table_id, keys)
+        if fuse:
+            (wrapper,) = fuse_pipeline(q7.pipeline, label="q7")
+            assert isinstance(wrapper, FusedTwoInputExecutor)
+        gen = NexmarkGenerator(NexmarkConfig(first_event_rate=2_000))
+        maxima = []
+        for epoch in range(3):
+            for _ in range(2):
+                bid = gen.next_chunks(600, 1024)["bid"].select(
+                    ["auction", "bidder", "price", "date_time"]
+                )
+                q7.pipeline.push_left(bid)
+                q7.pipeline.push_right(bid)
+            q7.pipeline.barrier()
+            live = np.asarray(agg.table.live)
+            maxima.append(
+                sorted(np.asarray(agg.state.emitted["maxprice"])[live])
+            )
+            mgr.commit_epoch((epoch + 1) << 16, [agg])
+            if epoch == 0:
+                assert agg.evict_cold() == 1
+        return maxima, q7.mview.snapshot()
+
+    want, want_mv = drive(fuse=False)
+    got, got_mv = drive(fuse=True)
+    assert len(want[0]) == 1 and want[1] == want[0]  # the cold maximum stood
+    assert got == want and got_mv == want_mv
