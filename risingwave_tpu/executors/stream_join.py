@@ -154,6 +154,10 @@ class StreamJoinExecutor(Executor, Checkpointable):
             "left": not left_append_only, "right": not right_append_only
         }
         self.out_cap = int(out_cap)
+        # one pair's lanes as the join hands it on: every column of both
+        self._emit_row_bytes = sum(
+            d.itemsize for d in self._out_dtypes().values()
+        )
         self.left = ChainSide.create(
             capacity, capacity,
             tuple(self._lint_left[k] for k in self.left_keys),
@@ -413,6 +417,7 @@ class StreamJoinExecutor(Executor, Checkpointable):
             self.table_id, kept, matched - kept, layout=self.layout,
             left_rows=held["left"], right_rows=held["right"],
             key_rows_max=max(fullest.values()), probe_lanes=probe_lanes,
+            emit_row_bytes=self._emit_row_bytes,
         )
         for name, (overflow, inconsistent, *_rest) in stats.items():
             if overflow:

@@ -15,7 +15,7 @@ so per-barrier host traffic is O(n), not O(state).
 from __future__ import annotations
 
 from functools import partial, reduce
-from typing import Dict, List, Optional, Sequence, Tuple
+from typing import Dict, List, Optional, Sequence, Tuple, Union
 
 import jax
 import jax.numpy as jnp
@@ -402,12 +402,17 @@ _EMITTED_BIT, _DIRTY_BIT, _REDO_BIT, _LIVE_BIT = 30, 29, 28, 27
 _EMIT_FLOOR = 1 << 14  # the smallest emission size, x4 steps above it
 
 
+def _n_digits(dtype) -> int:
+    """How many 32-bit digits ``_digits`` cuts a key lane into."""
+    dtype = jnp.dtype(dtype)
+    narrow = dtype.itemsize <= 4 and not jnp.issubdtype(dtype, jnp.floating)
+    return 1 if narrow else 2
+
+
 def _digits(lane) -> Tuple[jnp.ndarray, ...]:
     """A key lane as unsigned 32-bit digits, least significant first,
     whose lexicographic order (most significant first) is the lane's."""
-    if lane.dtype.itemsize <= 4 and not jnp.issubdtype(
-        lane.dtype, jnp.floating
-    ):
+    if _n_digits(lane.dtype) == 1:
         if jnp.issubdtype(lane.dtype, jnp.unsignedinteger) or (
             lane.dtype == jnp.bool_
         ):
@@ -485,21 +490,28 @@ def _sort_by_words(words, payload):
     return ops[:n], ops[n], passes
 
 
-def _rank_sorted(table: HashTable, order_lane, flags, k, desc, n_group):
-    """Every lane ranked by (group lanes, dead last, order key, the
-    rest of the stream key). Returns, in sorted order: each lane's slot
-    with ``flags`` (bits above ``_SLOT_MASK``) carried along, whether it
-    is in its group's top-k, the position its group starts at, and the
+def _rank_sorted(table: HashTable, order_lanes, flags, k, descs, n_group):
+    """Every lane ranked by (group lanes, dead last, the order keys, the
+    rest of the stream key). ``order_lanes`` / ``descs``: the order
+    keys' lanes and their directions, most significant first
+    (``_order_of``). Returns, in sorted order: each lane's slot with
+    ``flags`` (bits above ``_SLOT_MASK``) carried along, whether it is
+    in its group's top-k, the position its group starts at, and the
     sorts the ranking took."""
     cap = table.capacity
     # liveness as its own sort key within the group (a dead-row
     # sentinel would collide with INT64-extreme order values)
     live_last = (~table.live).astype(jnp.uint32)
-    okey = _order_key_u64(order_lane, desc)
+    okeys = tuple(
+        _order_key_u64(lane, d) for lane, d in zip(order_lanes, descs)
+    )
     digits: Tuple[jnp.ndarray, ...] = ()
     for lane in reversed(table.keys[n_group:]):
         digits += _digits(lane)
-    digits += _digits(okey) + (live_last,)
+    # a later order key's digits lie below an earlier one's
+    for okey in reversed(okeys):
+        digits += _digits(okey)
+    digits += (live_last,)
     n_below = len(digits)  # the digits below the group's
     for lane in reversed(table.keys[:n_group]):
         digits += _digits(lane)
@@ -547,21 +559,32 @@ def _rank(
     emitted: jnp.ndarray,
     epoch_dirty: jnp.ndarray,
     k: int,
-    desc: bool,
+    desc: Union[bool, Tuple[bool, ...]],
     n_group: int,
-    order_col: str,
+    order_col: Union[str, Tuple[str, ...]],
 ):
     """The barrier's first program, one per store capacity: every lane
-    ranked, with what the diff needs carried along. Returns, in sorted
-    order, (slot | flags, in its group's top-k, where its group starts)
-    and the sorts made."""
+    ranked, with what the diff needs carried along. ``order_col`` /
+    ``desc``: the order key and its direction, or a tuple of each for
+    an order of several keys, most significant first. Returns, in
+    sorted order, (slot | flags, in its group's top-k, where its group
+    starts) and the sorts made."""
     redo = epoch_dirty & _any_differs(rows, shadow)
     flags = (
         (emitted.astype(jnp.int32) << _EMITTED_BIT)
         | (epoch_dirty.astype(jnp.int32) << _DIRTY_BIT)
         | (redo.astype(jnp.int32) << _REDO_BIT)
     )
-    return _rank_sorted(table, rows[order_col], flags, k, desc, n_group)
+    lanes, descs = _order_of(rows, order_col, desc)
+    return _rank_sorted(table, lanes, flags, k, descs, n_group)
+
+
+def _order_of(rows, order_col, desc):
+    """The order keys' lanes and directions as two tuples, most
+    significant first, from one column and its flag or a tuple of each."""
+    if isinstance(order_col, str):
+        return (rows[order_col],), (bool(desc),)
+    return tuple(rows[c] for c in order_col), tuple(desc)
 
 
 @partial(jax.jit, static_argnames=("out_lanes",), donate_argnums=(2, 3))
@@ -645,18 +668,26 @@ def _diff_gather(
     return emitted, shadow, chunks[0], chunks[1], status
 
 
+@jax.jit
+def _count_valid(total, valid):
+    """A chunk's valid rows onto the epoch's running count (on the
+    device: the barrier reads it with its status)."""
+    return total + jnp.sum(valid, dtype=jnp.int32)
+
+
 def _any_differs(rows, shadow):
     """Per lane: does any column of ``rows`` differ from ``shadow``'s."""
     return reduce(jnp.logical_or, (a != shadow[n] for n, a in rows.items()))
 
 
-@partial(jax.jit, static_argnames=("k", "desc", "n_group"))
-def _topk_mask(table: HashTable, order_lane, k: int, desc: bool, n_group: int):
+@partial(jax.jit, static_argnames=("k", "descs", "n_group"))
+def _topk_mask(table: HashTable, order_lanes, k: int, descs, n_group: int):
     """Per slot: is the row in its group's top-k (a restore's rebuild
-    of ``emitted``; the barrier never leaves the sorted order)."""
+    of ``emitted``; the barrier never leaves the sorted order).
+    ``order_lanes`` / ``descs`` as ``_rank_sorted`` takes them."""
     cap = table.capacity
     packed_s, in_topk_s, _, _ = _rank_sorted(
-        table, order_lane, jnp.zeros(cap, jnp.int32), k, desc, n_group
+        table, order_lanes, jnp.zeros(cap, jnp.int32), k, descs, n_group
     )
     return jnp.zeros(cap, jnp.bool_).at[packed_s & _SLOT_MASK].set(in_topk_s)
 
@@ -672,9 +703,12 @@ def emission_lanes(epoch_lanes: int, capacity: int) -> int:
 
 
 class RetractableGroupTopNExecutor(Executor, Checkpointable):
-    """GROUP BY g ORDER BY o LIMIT k with full retraction support
-    (group_top_n.rs:63): deletes/updates crossing a group's top-k
-    boundary re-emit the displaced/promoted rows exactly.
+    """GROUP BY g ORDER BY o [DESC], ... LIMIT k with full retraction
+    support (group_top_n.rs:63): deletes/updates crossing a group's
+    top-k boundary re-emit the displaced/promoted rows exactly.
+    ``order_col``: one column (its direction in ``desc``), or the
+    order keys as (column, desc) pairs, most significant first; rows
+    equal in every order key rank by the stream key ``pk``.
 
     TPU re-design: ONE pk-keyed row store holds every input row; the
     barrier ranks rows within groups on device (one sort + segmented
@@ -687,7 +721,7 @@ class RetractableGroupTopNExecutor(Executor, Checkpointable):
     def __init__(
         self,
         group_by: Sequence[str],
-        order_col: str,
+        order_col: Union[str, Sequence[Tuple[str, bool]]],
         limit: int,
         pk: Sequence[str],
         schema_dtypes: Dict[str, object],
@@ -697,6 +731,7 @@ class RetractableGroupTopNExecutor(Executor, Checkpointable):
         table_id: str = "group_top_n",
         bucket_policy: Optional[BucketPolicy] = None,
         bucketed: bool = True,
+        upstream: str = "unknown",
     ):
         self._buckets = (
             BucketAllocator(
@@ -706,10 +741,22 @@ class RetractableGroupTopNExecutor(Executor, Checkpointable):
             else None
         )
         self.group_by = tuple(group_by)
-        self.order_col = order_col
+        self.order: Tuple[Tuple[str, bool], ...] = (
+            ((order_col, bool(desc)),)
+            if isinstance(order_col, str)
+            else tuple((c, bool(d)) for c, d in order_col)
+        )
+        # ``_rank``'s static arguments: one key goes as the column and
+        # its direction (the program q18 runs), several as two tuples
+        if len(self.order) == 1:
+            ((self.order_col, self.desc),) = self.order
+        else:
+            self.order_col, self.desc = map(tuple, zip(*self.order))
         self.limit = int(limit)
-        self.desc = desc
         self.pk = tuple(pk)
+        # the kind of executor that feeds it (the planner says), a label
+        # of the counter of its input rows
+        self.upstream = upstream
         # row identity INCLUDES the group (group_top_n.rs keys state by
         # group key + pk): a row "moving" groups is two distinct rows,
         # so the old group's retraction is never lost
@@ -737,6 +784,16 @@ class RetractableGroupTopNExecutor(Executor, Checkpointable):
         # lanes of the chunks applied since the last barrier: what
         # bounds either delta of the barrier (``_rank_diff``)
         self._epoch_lanes = 0
+        # valid rows of those chunks, counted on the device
+        self._in_rows = jnp.zeros((), jnp.int32)
+        # what ``topn.rank`` says of the program: the operands of a sort
+        # (a word a digit of the store's key lanes, of the order keys —
+        # 64 bits each — and of liveness, and the slot) and a row's bytes
+        self._sort_operands = (
+            sum(_n_digits(self._dtypes[c]) for c in self.store_keys)
+            + 2 * len(self.order) + 1 + 1
+        )
+        self._row_bytes = sum(d.itemsize for d in self._dtypes.values())
         if window_key is not None and window_key[0] not in self.group_by:
             raise ValueError(
                 "window_key must be one of the group columns (a closed "
@@ -819,7 +876,7 @@ class RetractableGroupTopNExecutor(Executor, Checkpointable):
         }
 
     def apply(self, chunk: StreamChunk) -> List[StreamChunk]:
-        for c in self.pk + self.group_by + (self.order_col,):
+        for c in self.pk + self.group_by + tuple(c for c, _ in self.order):
             if c in chunk.nulls:
                 raise ValueError(f"GroupTopN key column {c!r} cannot be NULL")
         self._maybe_grow(chunk.capacity)
@@ -829,6 +886,7 @@ class RetractableGroupTopNExecutor(Executor, Checkpointable):
         # actor.agg_step is for an aggregate
         with span("actor.topn_step", table_id=self.table_id):
             self._step(chunk)
+            self._in_rows = _count_valid(self._in_rows, chunk.valid)
         return []
 
     def _step(self, chunk: StreamChunk) -> None:
@@ -873,6 +931,8 @@ class RetractableGroupTopNExecutor(Executor, Checkpointable):
         ):
             return []
         self._step(chunk)
+        # (compiled for the width; no valid row, so the count stands)
+        _count_valid(self._in_rows, chunk.valid)
         return []
 
     def _maybe_grow(self, incoming: int):
@@ -949,16 +1009,19 @@ class RetractableGroupTopNExecutor(Executor, Checkpointable):
             return []
         lanes = emission_lanes(self._epoch_lanes, cap)
         with span(
-            "topn.rank", table_id=self.table_id, lanes=lanes, capacity=cap
+            "topn.rank", table_id=self.table_id, lanes=lanes, capacity=cap,
+            order_keys=len(self.order), words=self._sort_operands,
+            row_bytes=self._row_bytes,
         ):
             ret, ins, status = self._rank_diff(lanes)
             self.epoch_dirty = jnp.zeros_like(self.epoch_dirty)
             self._epoch_lanes = 0
-        # ONE read for the counts, the latch and the occupancy; it
-        # waits for the rank
+            fed, self._in_rows = self._in_rows, jnp.zeros((), jnp.int32)
+        # ONE read for the counts, the latch, the occupancy and the
+        # epoch's input rows; it waits for the rank
         with span("topn.pull", table_id=self.table_id) as sp:
-            with device_read("topn.status", lanes=8):
-                status = jax.device_get(status)
+            with device_read("topn.status", lanes=9):
+                status, fed = jax.device_get((status, fed))
             (n_ret, n_ins, groups, overflow, dropped, claimed, live,
              passes) = status.tolist()
             sp.args.update(rows=n_ret + n_ins, groups=groups, passes=passes)
@@ -985,6 +1048,9 @@ class RetractableGroupTopNExecutor(Executor, Checkpointable):
                 )
             REGISTRY.counter("group_topn_touched_groups_total").inc(
                 groups, table_id=self.table_id
+            )
+            REGISTRY.counter("group_topn_input_rows_total").inc(
+                int(fed), table_id=self.table_id, upstream=self.upstream
             )
             emitted = REGISTRY.counter("group_topn_emitted_rows_total")
             emitted.inc(n_ret, table_id=self.table_id, op="retract")
@@ -1076,13 +1142,14 @@ class RetractableGroupTopNExecutor(Executor, Checkpointable):
         self.rows = rows
         self._bound = int(n)
         self._epoch_lanes = 0
+        self._in_rows = jnp.zeros((), jnp.int32)
         self._dropped = jnp.zeros((), jnp.bool_)
         # every group's current top-k stands downstream (the MV was
         # restored to exactly this view), with the values the rows hold
         self.emitted = (
             _topk_mask(
-                table, rows[self.order_col], self.limit, self.desc,
-                len(self.group_by),
+                table, tuple(rows[c] for c, _ in self.order), self.limit,
+                tuple(d for _, d in self.order), len(self.group_by),
             )
             if n
             else jnp.zeros(cap, jnp.bool_)

@@ -634,7 +634,8 @@ def _sharded_equiv(ex, mesh, stacked_out: bool = False):
     from risingwave_tpu.parallel.sharded_top_n import ShardedGroupTopN
 
     if isinstance(ex, RetractableGroupTopNExecutor):
-        if ex.window_key is not None:
+        # (the sharded twin ranks by one order key)
+        if ex.window_key is not None or len(ex.order) != 1:
             return None
         return ShardedGroupTopN(
             mesh,

@@ -67,7 +67,9 @@ from risingwave_tpu.trace import (
     barrier_path,
     bind,
     close_epoch,
+    profiling,
     span,
+    whole_call,
 )
 from risingwave_tpu.storage.object_store import ObjectStore
 from risingwave_tpu.storage.state_table import CheckpointManager
@@ -326,6 +328,7 @@ class StreamingRuntime:
         self.partial_recoveries = 0
         self._epoch = self.mgr.max_committed_epoch if self.mgr else 0
         self._barrier_seq = 0
+        self._barrier_root = None  # the last barrier's root span
         self._last_barrier_at = 0.0
         self.barrier_latencies_ms: List[float] = []
         self.checkpoint_sync_ms: List[float] = []  # stage->durable, per ckpt
@@ -777,6 +780,14 @@ class StreamingRuntime:
         actor, commit-lane error) recovers in place and returns {} —
         the failed epoch is abandoned, offsets roll back, and the
         caller's next pump replays it (no manual recover())."""
+        began = profiling()
+        try:
+            return self._barrier()
+        finally:
+            root, self._barrier_root = self._barrier_root, None
+            whole_call(root, began)
+
+    def _barrier(self) -> Dict[str, List[StreamChunk]]:
         with self.lock:
             watchdog = self._arm_stall_watchdog()
             try:
@@ -1362,7 +1373,8 @@ class StreamingRuntime:
             and self._barrier_seq % self.checkpoint_frequency == 0
         )
         tr = self._begin_trace(is_ckpt)
-        with bind(tr), span("barrier", seq=self._barrier_seq):
+        with bind(tr), span("barrier", seq=self._barrier_seq) as root:
+            self._barrier_root = root
             outs = self._barrier_walk(tr, prev, is_ckpt)
         ms = (time.perf_counter() - t0) * 1e3
         self.barrier_latencies_ms.append(ms)

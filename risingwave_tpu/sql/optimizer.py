@@ -709,6 +709,9 @@ def _explain_stream_join(sql: str, catalog) -> str:
 
     from risingwave_tpu.executors.hash_agg import HashAggExecutor
     from risingwave_tpu.executors.stream_join import StreamJoinExecutor
+    from risingwave_tpu.executors.top_n_plain import (
+        RetractableGroupTopNExecutor,
+    )
     from risingwave_tpu.sql.planner import StreamPlanner
 
     if catalog is None:
@@ -734,6 +737,14 @@ def _explain_stream_join(sql: str, catalog) -> str:
             return (
                 f"HashAgg group=[{', '.join(ex.group_keys)}] "
                 f"calls=[{calls}]"
+            )
+        if isinstance(ex, RetractableGroupTopNExecutor):
+            order = ", ".join(
+                c + (" DESC" if desc else "") for c, desc in ex.order
+            )
+            return (
+                f"RetractableGroupTopN group=[{', '.join(ex.group_by)}] "
+                f"order=[{order}, stream key] limit={ex.limit}"
             )
         pk = getattr(ex, "pk", None)
         name = type(ex).__name__.replace("Executor", "")
@@ -772,14 +783,21 @@ def _explain_topn(select: P.Select) -> str:
     shape = over_window_topn_shape(select)
     if shape is None:
         return ""
-    inner = select.from_.select.from_
-    source = inner.table.name if isinstance(inner, P.WindowTVF) else inner.name
-    group = ", ".join(c.name for c in shape.partition_by)
-    order = shape.order.name + (" DESC" if shape.desc else "")
-    return (
+    head = (
         f"-- {shape.rank_name} <= {shape.limit}: per-group top-n, not a "
         "window\n"
-        f"StreamScan {source} -> RowIdGen (a source with no key) -> "
+    )
+    inner = select.from_.select.from_
+    if isinstance(inner, P.Join):
+        return head  # behind the join: the stream plan shows the chain
+    source = inner.table.name if isinstance(inner, P.WindowTVF) else inner.name
+    group = ", ".join(c.name for c in shape.partition_by)
+    order = ", ".join(
+        o.name + (" DESC" if desc else "") for o, desc in shape.order
+    )
+    return (
+        head
+        + f"StreamScan {source} -> RowIdGen (a source with no key) -> "
         f"RetractableGroupTopN group=[{group}] order=[{order}, stream key] "
         f"limit={shape.limit} -> Project -> Materialize\n"
     )

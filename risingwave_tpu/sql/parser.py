@@ -37,8 +37,11 @@ class FuncCall:
 
 @dataclass(frozen=True)
 class Star:
-    """SELECT * — expanded to the relation's columns before planning
-    (the reference's binder star expansion, binder/select.rs)."""
+    """SELECT * or SELECT q.* — expanded to the relation's columns (the
+    columns of the FROM item named ``qualifier``) before planning (the
+    reference's binder star expansion, binder/select.rs)."""
+
+    qualifier: Optional[str] = None
 
 
 @dataclass(frozen=True)
@@ -542,6 +545,10 @@ class Parser:
         while True:
             if self.accept("op", "*"):
                 items.append(SelectItem(Star(), None))
+            elif self._at_qualified_star():
+                qualifier = self.next().value
+                self.i += 2  # . *
+                items.append(SelectItem(Star(qualifier), None))
             else:
                 items.append(self.select_item())
             if not self.accept("op", ","):
@@ -659,6 +666,14 @@ class Parser:
             tuple(items), rel, where, group, order, limit, gsets,
             having=having, distinct=distinct,
         )
+
+    def _at_qualified_star(self) -> bool:
+        """The next three tokens are ``<ident> . *``."""
+        # (the lexer ends on an eof token: the slice may come up short)
+        after = [(t.kind, t.value) for t in self.toks[self.i + 1 : self.i + 3]]
+        return self.peek().kind == "ident" and after == [
+            ("op", "."), ("op", "*")
+        ]
 
     def select_item(self) -> SelectItem:
         e = self.expr()

@@ -649,8 +649,8 @@ class TopNShape:
     rank."""
 
     partition_by: Tuple[P.Ident, ...]
-    order: P.Ident
-    desc: bool
+    # (column, desc), most significant first
+    order: Tuple[Tuple[P.Ident, bool], ...]
     limit: int
     rank_name: str
 
@@ -659,15 +659,18 @@ def over_window_topn_shape(select: P.Select) -> Optional[TopNShape]:
     """The syntactic half of over_window_to_topn_rule.rs: is ``select``
 
         SELECT cols FROM (SELECT cols | *, row_number() OVER
-          (PARTITION BY g ORDER BY o [DESC]) AS rn FROM t) AS x
+          (PARTITION BY g ORDER BY o [DESC], ...) AS rn FROM t) AS x
         WHERE rn <= k      (also rn < k, rn = 1)
 
-    with the rank itself not selected? The planner's rule and EXPLAIN
-    both ask; None = the window path."""
+    with the rank itself not selected? ``t`` is a table, a window TVF
+    or a join (a comma list's WHERE is its ON; NEXmark q9): the rule
+    does not care what lies under the window, the relation's stream key
+    is the rows' identity. The planner's rule and EXPLAIN both ask;
+    None = the window path."""
     f = select.from_
     if not (
         isinstance(f, P.SubQuery)
-        and isinstance(f.select.from_, (P.TableRef, P.WindowTVF))
+        and isinstance(f.select.from_, (P.TableRef, P.WindowTVF, P.Join))
         and select.where is not None
         and not select.group_by
         and not select.having
@@ -675,7 +678,13 @@ def over_window_topn_shape(select: P.Select) -> Optional[TopNShape]:
     ):
         return None
     inner = f.select
-    if inner.where is not None or inner.group_by or inner.limit:
+    over_join = isinstance(inner.from_, P.Join)
+    if over_join and inner.from_.join_type.startswith("temporal"):
+        return None
+    # (a join's own WHERE is a filter behind it: ``_join_core_rel``)
+    if inner.group_by or inner.limit or (
+        inner.where is not None and not over_join
+    ):
         return None
     wins = [
         (i, it)
@@ -689,7 +698,7 @@ def over_window_topn_shape(select: P.Select) -> Optional[TopNShape]:
     if (
         w.func.name != "row_number"
         or w.frame is not None
-        or len(w.order_by) != 1
+        or not w.order_by
         or not w.partition_by
     ):
         return None
@@ -724,8 +733,8 @@ def over_window_topn_shape(select: P.Select) -> Optional[TopNShape]:
     for it in select.items:
         if not isinstance(it.expr, P.Ident) or it.expr.name == rn_name:
             return None
-    oident, desc = w.order_by[0]
-    return TopNShape(tuple(w.partition_by), oident, bool(desc), k, rn_name)
+    order = tuple((o, bool(desc)) for o, desc in w.order_by)
+    return TopNShape(tuple(w.partition_by), order, k, rn_name)
 
 
 class StreamPlanner:
@@ -1272,15 +1281,13 @@ class StreamPlanner:
         shape = over_window_topn_shape(select)
         if shape is None:
             return None
-        f, k = select.from_, shape.limit
-        inner = f.select
+        inner = select.from_.select
+        if isinstance(inner.from_, P.Join):
+            return self._topn_over_join(name, select, shape)
 
         bound_rel = self._from_bound(name, inner.from_)
         schema = dict(bound_rel.schema)
         binder = Binder(schema, bound_rel.alias)
-        part_cols = tuple(binder.resolve(c) for c in shape.partition_by)
-        desc = shape.desc
-        ocol = binder.resolve(shape.order)
         chain = list(bound_rel.chain)
         # the rows' identity: what an upstream plan keys its stream by,
         # a table's declared primary key (its DELETEs and UPDATEs name
@@ -1310,27 +1317,58 @@ class StreamPlanner:
                 )
             elif it.alias or isinstance(it.expr, P.Ident):
                 amap[it.alias or it.expr.name] = it.expr
+        tail = self._topn_tail(
+            name, select, shape, schema, pk, binder, amap,
+            upstream=type(chain[-1]).__name__ if chain else "scan",
+        )
+        if tail is None:
+            return None  # an inner item is computed: the window path
+        execs, out_schema, out_pk = tail
+        chain.extend(execs)
+        rel = BoundRel(
+            chain, out_schema, out_pk, bound_rel.source, bound_rel.alias
+        )
+        mview = self._make_mview(name, rel)
+        chain.append(mview)
+        return PlannedMV(
+            name,
+            Pipeline(chain),
+            mview,
+            {bound_rel.source: "single"},
+            schema=out_schema,
+        )
+
+    def _topn_tail(
+        self, name, select, shape, schema, pk, binder, amap, upstream
+    ):
+        """The rule's executors over a bound relation: the retractable
+        GroupTopN keyed by ``pk`` and the projection of the outer
+        select's columns (``amap``: what each stands for in ``schema``)
+        with the stream key beside them. (executors, the output's
+        schema, its key), or None when an outer item is no bare column
+        of the relation."""
         from risingwave_tpu.executors.top_n_plain import (
             RetractableGroupTopNExecutor,
         )
 
         gt = RetractableGroupTopNExecutor(
-            group_by=part_cols,
-            order_col=ocol,
-            limit=k,
+            group_by=tuple(binder.resolve(c) for c in shape.partition_by),
+            order_col=tuple(
+                (binder.resolve(o), desc) for o, desc in shape.order
+            ),
+            limit=shape.limit,
             pk=pk,
             schema_dtypes=schema,
-            desc=desc,
             capacity=self.capacity,
             table_id=self._tid(name, "gtopn"),
+            upstream=upstream,
         )
-        chain.append(gt)
         post: Dict[str, E.Expr] = {}
         out_schema: Dict[str, object] = {}
         for it in select.items:
             src = amap.get(it.expr.name)
             if not isinstance(src, P.Ident):
-                return None  # inner item is computed: window path
+                return None
             incol = binder.resolve(src)
             out = it.alias or it.expr.name
             post[out] = E.col(incol)
@@ -1349,20 +1387,76 @@ class StreamPlanner:
             post[target] = E.col(pcol)
             out_schema[target] = schema[pcol]
             out_pk.append(target)
-        chain.append(ProjectExecutor(post))
-        rel = BoundRel(
-            chain, out_schema, tuple(out_pk), bound_rel.source,
-            bound_rel.alias,
+        return [gt, ProjectExecutor(post)], out_schema, tuple(out_pk)
+
+    def _topn_over_join(
+        self, name: str, select: P.Select, shape: TopNShape
+    ) -> Optional[PlannedMV]:
+        """The rule over a join (NEXmark q9: every bid joined to its
+        auction, the auction's best pair kept): the join is planned as
+        any select FROM a join is (``_join_rel``: the chained layout
+        for a side tied to no key of its own, the residual inside it,
+        each bare side cut to what the select and the window read),
+        its stream key — both sides' — is the rows' identity, and the
+        GroupTopN and the outer projection go on behind its projection
+        in the same actor's tail, as q4's aggregates do."""
+        f = select.from_
+        inner = self._bare_join_sides(f.select)
+        (w,) = [  # the window, its columns as the sides now name them
+            it.expr for it in inner.items
+            if isinstance(it.expr, P.WindowFuncCall)
+        ]
+        joined = dataclasses.replace(
+            inner,
+            items=tuple(
+                it for it in inner.items
+                if not isinstance(it.expr, P.WindowFuncCall)
+            ),
         )
-        mview = self._make_mview(name, rel)
-        chain.append(mview)
-        return PlannedMV(
-            name,
-            Pipeline(chain),
-            mview,
-            {bound_rel.source: "single"},
-            schema=out_schema,
+        aux: List[PlannedMV] = []
+        parts, rel = self._join_rel(name, joined, aux)
+        left, right = parts[0], parts[1]
+        proj = rel.chain[-1]
+        if not isinstance(proj, ProjectExecutor) or not rel.pk:
+            return None
+        # a window column by the name the join's projection gives it; one
+        # the inner select does not list rides along under a hidden name
+        outputs = dict(proj.outputs)
+        merged = {**left.schema, **right.schema}
+        schema = dict(rel.schema)
+        named = {
+            e.name: out for out, e in outputs.items() if isinstance(e, E.Col)
+        }
+
+        def output_of(ident: P.Ident) -> P.Ident:
+            col = self._join_resolve(ident, left, right)
+            if col not in named:
+                named[col] = f"_w_{col}"
+                outputs[named[col]] = E.col(col)
+                schema[named[col]] = merged[col]
+            return P.Ident(named[col])
+
+        shape = dataclasses.replace(
+            shape,
+            partition_by=tuple(output_of(c) for c in w.partition_by),
+            order=tuple((output_of(o), bool(d)) for o, d in w.order_by),
         )
+        if len(outputs) != len(proj.outputs):
+            rel.chain[-1] = ProjectExecutor(outputs)
+        amap = {c: P.Ident(c) for c in rel.schema if not c.startswith("_")}
+        tail = self._topn_tail(
+            name, select, shape, schema, tuple(rel.pk),
+            Binder(schema, f.alias), amap, upstream=type(parts[2]).__name__,
+        )
+        if tail is None:
+            return None
+        execs, out_schema, out_pk = tail
+        out = BoundRel(
+            rel.chain + execs, out_schema, out_pk, rel.source, f.alias,
+            append_only=False,
+        )
+        planned = self._joined_mv(name, parts, out, self._make_mview(name, out))
+        return dataclasses.replace(planned, aux=tuple(aux))
 
     def _plan_over_window(
         self, name: str, select: P.Select, binder: Binder,

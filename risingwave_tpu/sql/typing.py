@@ -332,6 +332,20 @@ def _names_of_rel(rel, catalog, strict: bool) -> list:
     return []
 
 
+def _rel_named(rel, qualifier: str):
+    """The FROM item a qualifier names (its alias, or a bare table's
+    own name), down a join tree; None when there is none."""
+    if isinstance(rel, P.Join):
+        return _rel_named(rel.left, qualifier) or _rel_named(
+            rel.right, qualifier
+        )
+    if isinstance(rel, P.WindowTVF):
+        name = rel.alias or rel.table.name
+    else:
+        name = getattr(rel, "alias", None) or getattr(rel, "name", None)
+    return rel if name == qualifier else None
+
+
 def expand_star(select: P.Select, catalog, strict: bool = True) -> P.Select:
     """SELECT * -> explicit Ident items in relation column order
     (binder star expansion, binder/select.rs). ``strict=False``
@@ -342,17 +356,24 @@ def expand_star(select: P.Select, catalog, strict: bool = True) -> P.Select:
     with an underscore."""
     if not any(isinstance(it.expr, P.Star) for it in select.items):
         return select
-    names = _names_of_rel(select.from_, catalog, strict)
-    if not names:
-        if not strict:
-            return select
-        raise ValueError("SELECT *: unknown relation columns")
     items = []
     for it in select.items:
-        if isinstance(it.expr, P.Star):
-            items.extend(P.SelectItem(P.Ident(n), None) for n in names)
-        else:
+        if not isinstance(it.expr, P.Star):
             items.append(it)
+            continue
+        q = it.expr.qualifier
+        names = _names_of_rel(
+            select.from_ if q is None else _rel_named(select.from_, q),
+            catalog, strict,
+        )
+        if not names:
+            if not strict:
+                return select
+            raise ValueError(
+                f"SELECT {q + '.' if q else ''}*: unknown relation columns"
+            )
+        # q.* keeps its qualifier: the other side may share a name
+        items.extend(P.SelectItem(P.Ident(n, q), None) for n in names)
     import dataclasses
 
     return dataclasses.replace(select, items=tuple(items))

@@ -22,7 +22,11 @@ tree dumps). Here ``span(name, *, stage=None, wait=None, **args)`` is that call:
   flag test: "tracing off" means no session, the ring and the stage
   stamps are always on; ``Span.traced`` says whether the session held
   the span from start to end (whether the xplane has its event), so a
-  reader of the ring can tell the epochs a device trace covers;
+  reader of the ring can tell the epochs a device trace covers. The
+  ``barrier`` root's flag is held to the whole of ``Runtime.barrier()``
+  (``whole_call``): a caller that wraps the call in an annotation of
+  its own counts the same barriers as the ring does, also where the
+  session began or ended between the call's first line and the span;
 - with ``wait=`` a span says that its thread did not work but waited,
   and for what (``WAITS``): ``device`` (a blocking device->host read or
   ``block_until_ready``), ``actor`` (other threads of the graph),
@@ -85,7 +89,7 @@ WAITS = {
 KINDS = ("host", "device_wait", "io", "permit", "queue", "unattributed")
 
 _SIDS = itertools.count(1)  # span ids; next() is atomic under the GIL
-_profiling = TraceAnnotation.is_enabled  # is a profiler session running
+profiling = _profiling = TraceAnnotation.is_enabled  # is a session running
 
 # per-thread state: the live span stack (the await-tree analogue: the
 # reference dumps every actor's pending await tree on stall; here every
@@ -397,6 +401,17 @@ def bind(sink=None, epoch=None):
         yield sink
     finally:
         st.sink, st.epoch = old
+
+
+def whole_call(root, began: bool) -> None:
+    """Hold ``root.traced`` to the call that opened the span: ``began``
+    is ``profiling()`` at the call's first statement, and this runs at
+    its last. Between the two and the span lie a lock, a watchdog
+    thread and the stage stamps, 1 + 3 ms of a barrier on the chip, and
+    a session that starts or stops there is in the ring and not in what
+    the caller wrapped around the call (or the reverse)."""
+    if getattr(root, "traced", False):
+        root.traced = began and _profiling()
 
 
 def bound() -> bool:

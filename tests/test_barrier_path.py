@@ -164,6 +164,55 @@ def test_three_barriers_sum_to_their_wall_and_cross_the_join_actor(q8):
     assert trace.barrier_path(tr.epoch) is None
 
 
+@pytest.mark.parametrize(
+    "span_held, began, ended, expect",
+    [
+        (True, True, True, True),
+        # the session began after the caller's annotation, before the span
+        (True, False, True, False),
+        # it ended once the span had closed, before the caller's did
+        (True, True, False, False),
+        # a span no session held stays so, whatever the call's ends saw
+        (False, True, True, False),
+    ],
+)
+def test_whole_call_holds_the_roots_flag_to_the_calls_ends(
+    monkeypatch, span_held, began, ended, expect
+):
+    root = SimpleNamespace(traced=span_held)
+    monkeypatch.setattr(trace, "_profiling", lambda: ended)
+    trace.whole_call(root, began)
+    assert root.traced is expect
+    trace.whole_call(None, began)  # a barrier that opened no root span
+
+
+def test_a_session_that_begins_inside_the_call_leaves_the_root_untraced(
+    q8, monkeypatch, tmp_path
+):
+    """A reader of the ring counts the traced ``barrier`` roots against
+    the annotations its caller wrapped around ``Runtime.barrier()``: one
+    session over whole calls marks their roots, and a call whose first
+    statement saw no session does not, though its span has its event."""
+    import jax
+    import risingwave_tpu.runtime.runtime as runtime_mod
+
+    opts = jax.profiler.ProfileOptions()
+    opts.python_tracer_level = 0
+    jax.profiler.start_trace(str(tmp_path), profiler_options=opts)
+    try:
+        whole = q8.epoch()
+        monkeypatch.setattr(runtime_mod, "profiling", lambda: False)
+        late = q8.epoch()
+        monkeypatch.undo()
+    finally:
+        jax.profiler.stop_trace()
+    after = q8.epoch()
+    assert _root(whole.epoch).traced is True
+    assert _root(late.epoch).traced is False
+    assert _root(after.epoch).traced is False
+    assert q8.rt._barrier_root is None
+
+
 def _sp(sid, name, tid, t0, t1, parent=None, wait=None, **args):
     return SimpleNamespace(
         sid=sid, name=name, tid=tid, t0=t0, dur=t1 - t0, parent=parent,

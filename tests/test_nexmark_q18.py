@@ -172,8 +172,9 @@ def test_the_sources_text_plans_onto_group_topn_not_the_window_path():
     (gt,) = [ex for ex in planned.pipeline.executors
              if isinstance(ex, RetractableGroupTopNExecutor)]
     assert gt.group_by == ("bidder", "auction") and gt.limit == 1
+    assert gt.order == (("date_time", True),)
     assert gt.order_col == "date_time" and gt.desc
-    assert gt.pk == ("_row_id",)
+    assert gt.pk == ("_row_id",) and gt.upstream == "RowIdGenExecutor"
     # the star stands for the table's columns and for nothing hidden
     assert list(planned.schema)[:6] == [
         "auction", "bidder", "price", "channel", "date_time", "extra"

@@ -3,7 +3,7 @@ parser's window grammar lowers to GeneralOverWindowExecutor — incl.
 DESC ordering (hidden negated lane), frames, and retracting inputs
 (MV-on-MV: upstream agg updates shift ranks downstream).
 
-Reference: binder window_function.rs; e2e nexmark q9 shape."""
+Reference: binder window_function.rs; a top-1 per partition over one table."""
 
 import pytest
 
@@ -112,9 +112,11 @@ def test_non_partition_predicate_stays_above_window():
     assert list(out["auction"]) == [2] and list(out["price"]) == [150]
 
 
-def test_q9_shape_top1_per_partition():
-    """The Nexmark q9 shape: highest bid per auction via row_number()
-    OVER (... ORDER BY price DESC) filtered to 1 in an outer select."""
+def test_top1_per_partition_over_one_table():
+    """Highest bid per auction via row_number() OVER (... ORDER BY price
+    DESC) filtered to 1 in an outer select, over ``bid`` alone: one
+    table, one order key (NEXmark q9 as its source writes it, the join
+    and both order keys, is tests/test_nexmark_q9.py)."""
     s = _session()
     s.execute(
         "CREATE MATERIALIZED VIEW q9 AS SELECT auction, price, bidder FROM "
