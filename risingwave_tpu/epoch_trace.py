@@ -176,7 +176,9 @@ class EpochTrace:
       checkpoint_stage.marks — [checkpoint.marks, less the pulls inside
                                it] an executor's staging outside its
                                row pull: the dirty/live/stored marks
-                               read off the device, classified, flipped
+                               classified and flipped on the device
+                               (classify_marks), the count and the
+                               changed slots' tombstone bits read down
       checkpoint_stage.pull  — [checkpoint.pull] pull_rows: gather
                                dispatch + the device->host copy
       checkpoint_stage.dictionary — [checkpoint.dictionary] the session

@@ -32,10 +32,9 @@ from risingwave_tpu.runtime.bucketing import (
 from risingwave_tpu.storage.state_table import (
     Checkpointable,
     StateDelta,
+    classify_marks,
     grow_pow2,
     pull_rows,
-    read_marks,
-    stage_marks,
 )
 
 GROW_AT = 0.5
@@ -332,22 +331,18 @@ class AppendOnlyDedupExecutor(Executor, Checkpointable):
     def checkpoint_delta(self):
         import numpy as np
 
-        (sdirty,) = read_marks(self.sdirty)
-        if not sdirty.any():
+        marks = classify_marks(self.sdirty, self.table.live, self.stored)
+        self.sdirty, self.stored = marks.sdirty, marks.stored
+        if not len(marks):
             return []
-        upsert, tomb, sel = stage_marks(
-            sdirty, *read_marks(self.table.live, self.stored)
-        )
         lanes = {f"k{i}": l for i, l in enumerate(self.table.keys)}
-        keys = pull_rows(lanes, sel)
-        self.stored = (self.stored | jnp.asarray(upsert)) & ~jnp.asarray(tomb)
-        self.sdirty = jnp.zeros_like(self.sdirty)
+        keys = pull_rows(lanes, marks)
         return [
             StateDelta(
                 self.table_id,
                 keys,
                 {},
-                tomb[sel],
+                marks.tombstone,
                 tuple(f"k{i}" for i in range(len(self.table.keys))),
             )
         ]

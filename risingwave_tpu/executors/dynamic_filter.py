@@ -41,10 +41,9 @@ from risingwave_tpu.runtime.bucketing import (
 from risingwave_tpu.storage.state_table import (
     Checkpointable,
     StateDelta,
+    classify_marks,
     grow_pow2,
     pull_rows,
-    read_marks,
-    stage_marks,
 )
 
 GROW_AT = 0.5
@@ -356,20 +355,18 @@ class DynamicMaxFilterExecutor(Executor, Checkpointable):
     def checkpoint_delta(self):
         import numpy as np
 
-        (sdirty,) = read_marks(self.sdirty)
-        if not sdirty.any():
+        marks = classify_marks(self.sdirty, self.table.live, self.stored)
+        self.sdirty, self.stored = marks.sdirty, marks.stored
+        if not len(marks):
             return []
-        upsert, tomb, sel = stage_marks(
-            sdirty, *read_marks(self.table.live, self.stored)
-        )
         pulled = pull_rows(
-            {"k0": self.table.keys[0], "max": self.maxes}, sel
+            {"k0": self.table.keys[0], "max": self.maxes}, marks
         )
         keys = {"k0": pulled["k0"]}
         vals = {"max": pulled["max"]}
-        self.stored = (self.stored | jnp.asarray(upsert)) & ~jnp.asarray(tomb)
-        self.sdirty = jnp.zeros_like(self.sdirty)
-        return [StateDelta(self.table_id, keys, vals, tomb[sel], ("k0",))]
+        return [
+            StateDelta(self.table_id, keys, vals, marks.tombstone, ("k0",))
+        ]
 
     def restore_state(self, table_id, key_cols, value_cols):
         import numpy as np
@@ -701,11 +698,9 @@ class DynamicFilterExecutor(Executor, Checkpointable):
 
     def checkpoint_delta(self):
         out = []
-        (sdirty,) = read_marks(self.sdirty)
-        if sdirty.any():
-            upsert, tomb, sel = stage_marks(
-                sdirty, *read_marks(self.table.live, self.stored)
-            )
+        marks = classify_marks(self.sdirty, self.table.live, self.stored)
+        self.sdirty, self.stored = marks.sdirty, marks.stored
+        if len(marks):
             lanes = {
                 f"k{i}": lane for i, lane in enumerate(self.table.keys)
             }
@@ -713,16 +708,13 @@ class DynamicFilterExecutor(Executor, Checkpointable):
             for n in self.names:
                 lanes[f"r_{n}"] = self.rows[n]
             lanes["pass"] = self.passing
-            pulled = pull_rows(lanes, sel)
+            pulled = pull_rows(lanes, marks)
             keys = {k: pulled[k] for k in key_names}
             vals = {k: v for k, v in pulled.items() if k not in key_names}
-            self.stored = (
-                self.stored | jnp.asarray(upsert)
-            ) & ~jnp.asarray(tomb)
-            self.sdirty = jnp.zeros_like(self.sdirty)
             out.append(
                 StateDelta(
-                    f"{self.table_id}.rows", keys, vals, tomb[sel], key_names
+                    f"{self.table_id}.rows", keys, vals, marks.tombstone,
+                    key_names,
                 )
             )
         if self._rv_dirty:

@@ -23,6 +23,7 @@ chunk. The host only:
 
 from __future__ import annotations
 
+import dataclasses
 from functools import partial
 from typing import Dict, List, Optional, Sequence, Tuple
 
@@ -36,12 +37,11 @@ from risingwave_tpu.executors.base import Barrier, Executor, Watermark
 from risingwave_tpu.storage.state_table import (
     Checkpointable,
     StateDelta,
+    classify_marks,
     grow_pow2,
     host_key_view,
     lanes_from_host_keys,
     pull_rows,
-    read_marks,
-    stage_marks,
 )
 from risingwave_tpu.ops import agg as agg_ops
 from risingwave_tpu.ops import minput as mi_ops
@@ -1574,25 +1574,6 @@ def _cold_merge(state: AggState, slots, cold, calls):
 
 
 # -- checkpoint/restore (StateTable integration) -------------------------
-@jax.jit
-def _mark_checkpointed(state: AggState, upsert, tomb):
-    """Flip storage marks after a successful commit: persisted slots
-    become stored, tombstoned slots forget their stored bit, and every
-    sdirty mark clears (mem_table seal analogue)."""
-    return AggState(
-        row_count=state.row_count,
-        accums=state.accums,
-        nonnull=state.nonnull,
-        emitted=state.emitted,
-        emitted_isnull=state.emitted_isnull,
-        emitted_valid=state.emitted_valid,
-        dirty=state.dirty,
-        minmax_retracted=state.minmax_retracted,
-        sdirty=jnp.zeros_like(state.sdirty),
-        stored=(state.stored | upsert) & ~tomb,
-    )
-
-
 def _agg_checkpoint_delta(self) -> List[StateDelta]:
     """Stage rows changed since the last checkpoint (device -> host).
 
@@ -1602,16 +1583,17 @@ def _agg_checkpoint_delta(self) -> List[StateDelta]:
     restore rebuilds byte-identical operator state. Only the selected
     rows cross the device boundary (pull_rows).
     """
-    (sdirty,) = read_marks(self.state.sdirty)
-    if not sdirty.any():
-        return []
-    live, emitted_valid, dirty, stored = read_marks(
-        self.table.live, self.state.emitted_valid, self.state.dirty,
+    marks = classify_marks(
+        self.state.sdirty,
+        (self.table.live, self.state.emitted_valid, self.state.dirty),
         self.state.stored,
     )
-    upsert, tomb, sel = stage_marks(
-        sdirty, live | emitted_valid | dirty, stored
+    # eager flip — see StateDelta's durability contract
+    self.state = dataclasses.replace(
+        self.state, sdirty=marks.sdirty, stored=marks.stored
     )
+    if not len(marks):
+        return []
     lanes = {
         f"k{i}": lane for i, lane in enumerate(self.table.keys)
     }
@@ -1627,19 +1609,15 @@ def _agg_checkpoint_delta(self) -> List[StateDelta]:
         lanes[f"miv_{n}"] = v  # 2D (rows re-land whole)
         lanes[f"mic_{n}"] = c
     lanes["ev"] = self.state.emitted_valid
-    pulled = pull_rows(lanes, sel)
+    pulled = pull_rows(lanes, marks)
     keys = {k: pulled[k] for k in key_names}
     vals = {k: v for k, v in pulled.items() if k not in key_names}
-    # eager flip — see StateDelta's durability contract
-    self.state = _mark_checkpointed(
-        self.state, jnp.asarray(upsert), jnp.asarray(tomb)
-    )
     return [
         StateDelta(
             self.table_id,
             keys,
             vals,
-            tomb[sel],
+            marks.tombstone,
             # positional lane order, NOT sorted() ("k10" < "k2" lexically)
             key_names,
         )

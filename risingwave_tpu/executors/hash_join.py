@@ -30,6 +30,7 @@ barrier, the capacity-growth contract shared with HashAgg).
 
 from __future__ import annotations
 
+import dataclasses
 from functools import partial
 from typing import Dict, List, Optional, Sequence, Tuple
 
@@ -48,14 +49,13 @@ from risingwave_tpu.runtime.bucketing import (
     plan_capacity,
 )
 from risingwave_tpu.storage.state_table import (
-    host_key_view,
-    lanes_from_host_keys,
     Checkpointable,
     StateDelta,
+    classify_marks,
     grow_pow2,
+    host_key_view,
+    lanes_from_host_keys,
     pull_rows,
-    read_marks,
-    stage_marks,
 )
 from risingwave_tpu.ops.join import (
     JoinSide,
@@ -920,35 +920,18 @@ class HashJoinExecutor(Executor, Checkpointable):
 
 
 # -- checkpoint/restore (StateTable integration) -------------------------
-@jax.jit
-def _side_mark_checkpointed(side: JoinSide, upsert, tomb) -> JoinSide:
-    return JoinSide(
-        side.table,
-        side.rows,
-        side.row_nulls,
-        side.row_valid,
-        side.overflow,
-        side.inconsistent,
-        jnp.zeros_like(side.sdirty),
-        (side.stored | upsert) & ~tomb,
-        side.degree,
-    )
-
-
 def _side_delta(side: JoinSide, table_id: str):
     """Stage one side's changed keys: the whole bucket rides as 2D
     value lanes (rows re-land at the same in-bucket positions on
     restore, so emitted pair identity is stable). Marks flip eagerly
-    (see StateDelta's durability contract). Returns (delta, new_side)
-    or None."""
+    (see StateDelta's durability contract). Returns (delta, new_side),
+    the delta None where no key changed."""
     import numpy as np
 
-    (sdirty,) = read_marks(side.sdirty)
-    if not sdirty.any():
-        return None
-    upsert, tomb, sel = stage_marks(
-        sdirty, *read_marks(side.table.live, side.stored)
-    )
+    marks = classify_marks(side.sdirty, side.table.live, side.stored)
+    side = dataclasses.replace(side, sdirty=marks.sdirty, stored=marks.stored)
+    if not len(marks):
+        return None, side
     lanes = {
         f"k{i}": lane for i, lane in enumerate(side.table.keys)
     }
@@ -959,13 +942,11 @@ def _side_delta(side: JoinSide, table_id: str):
         lanes[f"r_{n}"] = a
     for n, a in side.row_nulls.items():
         lanes[f"n_{n}"] = a
-    pulled = pull_rows(lanes, sel)
+    pulled = pull_rows(lanes, marks)
     keys = {k: pulled[k] for k in key_names}
     vals = {k: v for k, v in pulled.items() if k not in key_names}
-    new_side = _side_mark_checkpointed(
-        side, jnp.asarray(upsert), jnp.asarray(tomb)
-    )
-    return StateDelta(table_id, keys, vals, tomb[sel], key_names), new_side
+    delta = StateDelta(table_id, keys, vals, marks.tombstone, key_names)
+    return delta, side
 
 
 def _side_restore(side: JoinSide, key_cols, value_cols) -> JoinSide:
@@ -1038,14 +1019,13 @@ def _join_checkpoint_table_ids(self):
 
 def _join_checkpoint_delta(self):
     out = []
-    got = _side_delta(self.left, f"{self.table_id}.left")
-    if got is not None:
-        out.append(got[0])
-        self.left = got[1]
-    got = _side_delta(self.right, f"{self.table_id}.right")
-    if got is not None:
-        out.append(got[0])
-        self.right = got[1]
+    for name in ("left", "right"):
+        delta, side = _side_delta(
+            getattr(self, name), f"{self.table_id}.{name}"
+        )
+        setattr(self, name, side)
+        if delta is not None:
+            out.append(delta)
     # watermark-closed EVICTED keys: their buckets live only in the
     # store — stage explicit tombstones so recovery cannot resurrect
     # closed windows (resident expiry tombstones ride _side_delta)

@@ -67,10 +67,9 @@ from risingwave_tpu.ops.join import (
 from risingwave_tpu.storage.state_table import (
     Checkpointable,
     StateDelta,
+    classify_marks,
     grow_pow2,
     pull_rows,
-    read_marks,
-    stage_marks,
 )
 from risingwave_tpu.trace import span
 
@@ -435,32 +434,27 @@ class KeyedJoinExecutor(Executor, Checkpointable):
         out = []
         for name in ("left", "right"):
             side = getattr(self, name)
-            (sdirty,) = read_marks(side.sdirty)
-            if not sdirty.any():
+            marks = classify_marks(side.sdirty, side.table.live, side.stored)
+            setattr(self, name, FlatSide(
+                side.table, side.rows, side.row_nulls,
+                marks.sdirty, marks.stored, side.dropped,
+            ))
+            if not len(marks):
                 continue
-            upsert, tomb, sel = stage_marks(
-                sdirty, *read_marks(side.table.live, side.stored)
-            )
             lanes = {f"k{i}": k for i, k in enumerate(side.table.keys)}
             key_names = tuple(lanes)
             lanes.update({f"r_{n}": a for n, a in side.rows.items()})
             lanes.update({f"n_{n}": a for n, a in side.row_nulls.items()})
-            pulled = pull_rows(lanes, sel)
+            pulled = pull_rows(lanes, marks)
             out.append(
                 StateDelta(
                     f"{self.table_id}.{name}",
                     {k: pulled[k] for k in key_names},
                     {k: v for k, v in pulled.items() if k not in key_names},
-                    tomb[sel],
+                    marks.tombstone,
                     key_names,
                 )
             )
-            setattr(self, name, FlatSide(
-                side.table, side.rows, side.row_nulls,
-                jnp.zeros_like(side.sdirty),
-                (side.stored | jnp.asarray(upsert)) & ~jnp.asarray(tomb),
-                side.dropped,
-            ))
         return out
 
     def restore_state(self, table_id, key_cols, value_cols) -> None:

@@ -523,10 +523,9 @@ from functools import partial
 from risingwave_tpu.ops.hash_table import HashTable, last_occurrence_mask, lookup_or_insert, stage_scalars
 from risingwave_tpu.runtime.bucketing import BucketAllocator, BucketPolicy
 from risingwave_tpu.storage.state_table import (
+    classify_marks,
     grow_pow2,
     pull_rows,
-    read_marks,
-    stage_marks,
 )
 
 GROW_AT = 0.5
@@ -868,14 +867,13 @@ class DeviceMaterializeExecutor(MvDeviceReadMixin, Executor, Checkpointable):
 
     # -- checkpoint/restore -----------------------------------------------
     def checkpoint_delta(self):
-        (sdirty,) = read_marks(self.state.sdirty)
-        if not sdirty.any():
-            return []
-        upsert, tomb, sel = stage_marks(
-            sdirty, *read_marks(self.table.live, self.state.stored)
+        marks = classify_marks(
+            self.state.sdirty, self.table.live, self.state.stored
         )
-        if not len(sel):
-            self.state.sdirty = jnp.zeros_like(self.state.sdirty)
+        # eager mark flip (same discipline as the other executors: the
+        # runtime stages on the main thread before the async commit)
+        self.state.sdirty, self.state.stored = marks.sdirty, marks.stored
+        if not len(marks):
             return []
         lanes = {f"k{j}": k for j, k in enumerate(self.table.keys)}
         lanes.update(
@@ -884,27 +882,19 @@ class DeviceMaterializeExecutor(MvDeviceReadMixin, Executor, Checkpointable):
         lanes.update(
             {f"n_{c}": lane for c, lane in self.state.vnulls.items()}
         )
-        rows = pull_rows(lanes, sel)
+        rows = pull_rows(lanes, marks)
         key_cols = {f"k{j}": rows[f"k{j}"] for j in range(len(self.pk))}
         value_cols = {
             f"v{j}": rows[f"v{j}"] for j in range(len(self.columns))
         }
         for c in self.state.vnulls:
             value_cols[f"n_{c}"] = rows[f"n_{c}"].astype(np.uint8)
-        tombstone = tomb[sel]
-        # eager mark flip (same discipline as the other executors: the
-        # runtime stages on the main thread before the async commit)
-        dev_sel = jnp.asarray(sel.astype(np.int32))
-        self.state.stored = (
-            self.state.stored.at[dev_sel].set(jnp.asarray(upsert[sel]))
-        )
-        self.state.sdirty = jnp.zeros_like(self.state.sdirty)
         return [
             StateDelta(
                 self.table_id,
                 key_cols,
                 value_cols,
-                tombstone,
+                marks.tombstone,
                 tuple(f"k{j}" for j in range(len(self.pk))),
             )
         ]

@@ -40,10 +40,9 @@ from risingwave_tpu.executors.sort import ArenaBufferedExecutor
 from risingwave_tpu.storage.state_table import (
     Checkpointable,
     StateDelta,
+    classify_marks,
     grow_pow2,
     pull_rows,
-    read_marks,
-    stage_marks,
 )
 
 GROW_AT = 0.5
@@ -859,25 +858,22 @@ class OverWindowExecutor(Executor, Checkpointable):
 
     # -- checkpoint/restore ----------------------------------------------
     def checkpoint_delta(self) -> List[StateDelta]:
-        (sdirty,) = read_marks(self.sdirty)
-        if not sdirty.any():
-            return []
         # partitions never die in the append-only executor: alive =
-        # every claimed slot, so there are no tombstones
-        fp1, stored = read_marks(self.table.fp1, self.stored)
-        upsert, tomb, sel = stage_marks(sdirty, fp1 != 0, stored)
+        # every claimed slot (fp1 != 0), so there are no tombstones
+        marks = classify_marks(self.sdirty, self.table.fp1, self.stored)
+        self.sdirty, self.stored = marks.sdirty, marks.stored
+        if not len(marks):
+            return []
         lanes = {f"k{i}": l for i, l in enumerate(self.table.keys)}
         key_names = tuple(lanes)
         for name, a in self.accums.items():
             lanes[f"acc_{name}"] = a
-        pulled = pull_rows(lanes, sel)
+        pulled = pull_rows(lanes, marks)
         keys = {k: pulled[k] for k in key_names}
         vals = {k: v for k, v in pulled.items() if k not in key_names}
-        self.stored = (self.stored | jnp.asarray(upsert)) & ~jnp.asarray(
-            tomb
-        )
-        self.sdirty = jnp.zeros_like(self.sdirty)
-        return [StateDelta(self.table_id, keys, vals, tomb[sel], key_names)]
+        return [
+            StateDelta(self.table_id, keys, vals, marks.tombstone, key_names)
+        ]
 
     def restore_state(self, table_id, key_cols, value_cols) -> None:
         n = len(next(iter(key_cols.values()))) if key_cols else 0
@@ -1586,12 +1582,12 @@ class GeneralOverWindowExecutor(Executor, Checkpointable):
 
     # -- checkpoint/restore ----------------------------------------------
     def checkpoint_delta(self) -> List[StateDelta]:
-        (sdirty,) = read_marks(self.sdirty)
-        if not sdirty.any():
-            return []
-        upsert, tomb, sel = stage_marks(
-            sdirty, *read_marks(self.present | self.em_valid, self.stored)
+        marks = classify_marks(
+            self.sdirty, (self.present, self.em_valid), self.stored
         )
+        self.sdirty, self.stored = marks.sdirty, marks.stored
+        if not len(marks):
+            return []
         lanes = {f"k{i}": l for i, l in enumerate(self.table.keys)}
         key_names = tuple(lanes)
         for n in self.lane_names:
@@ -1604,14 +1600,12 @@ class GeneralOverWindowExecutor(Executor, Checkpointable):
             lanes[f"en_{n}"] = a
         lanes["seq"] = self.seq
         lanes["present"] = self.present
-        pulled = pull_rows(lanes, sel)
+        pulled = pull_rows(lanes, marks)
         keys = {k: pulled[k] for k in key_names}
         vals = {k: v for k, v in pulled.items() if k not in key_names}
-        self.stored = (self.stored | jnp.asarray(upsert)) & ~jnp.asarray(
-            tomb
-        )
-        self.sdirty = jnp.zeros_like(self.sdirty)
-        return [StateDelta(self.table_id, keys, vals, tomb[sel], key_names)]
+        return [
+            StateDelta(self.table_id, keys, vals, marks.tombstone, key_names)
+        ]
 
     def restore_state(self, table_id, key_cols, value_cols) -> None:
         n = len(next(iter(key_cols.values()))) if key_cols else 0

@@ -55,10 +55,9 @@ from risingwave_tpu.ops.stream_join import (
 from risingwave_tpu.storage.state_table import (
     Checkpointable,
     StateDelta,
+    classify_marks,
     grow_pow2,
     pull_rows,
-    read_marks,
-    stage_marks,
 )
 from risingwave_tpu.trace import device_read, span
 
@@ -467,40 +466,34 @@ class StreamJoinExecutor(Executor, Checkpointable):
         for name in ("left", "right"):
             side = getattr(self, name)
             if self._retract[name]:
-                (rdirty,) = read_marks(side.rdirty)
-                if not rdirty.any():
+                # the rows of the selection and, a table keyed by its
+                # slots, the slots themselves
+                sel = classify_marks(side.rdirty, side.row_valid, side.stored)
+                setattr(self, name, dataclasses.replace(
+                    side, rdirty=sel.sdirty, stored=sel.stored
+                ))
+                if not len(sel):
                     continue
-                upsert, tomb, sel = stage_marks(
-                    rdirty, *read_marks(side.row_valid, side.stored)
-                )
-                tombstone = tomb[sel]
-                marks = dict(
-                    rdirty=jnp.zeros_like(side.rdirty),
-                    stored=(side.stored | jnp.asarray(upsert))
-                    & ~jnp.asarray(tomb),
-                )
+                k0, tombstone = sel.slots(), sel.tombstone
             else:
                 # inserts only: the rows appended since the last
                 # checkpoint, as the barrier's read counted them
                 sel = np.arange(self._rows_ckpt[name], self._rows_now[name])
                 if not len(sel):
                     continue
-                tombstone = np.zeros(len(sel), bool)
-                marks = {}
+                k0, tombstone = sel.astype(np.int64), np.zeros(len(sel), bool)
             self._rows_ckpt[name] = self._rows_now[name]
             lanes = {f"r_{n}": a for n, a in side.rows.items()}
             lanes.update({f"n_{n}": a for n, a in side.row_nulls.items()})
             out.append(
                 StateDelta(
                     f"{self.table_id}.{name}",
-                    {"k0": sel.astype(np.int64)},
+                    {"k0": k0},
                     pull_rows(lanes, sel),
                     tombstone,
                     ("k0",),
                 )
             )
-            if marks:
-                setattr(self, name, dataclasses.replace(side, **marks))
         return out
 
     def restore_state(self, table_id, key_cols, value_cols) -> None:

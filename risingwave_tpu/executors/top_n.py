@@ -50,10 +50,9 @@ from risingwave_tpu.runtime.bucketing import (
 from risingwave_tpu.storage.state_table import (
     Checkpointable,
     StateDelta,
+    classify_marks,
     grow_pow2,
     pull_rows,
-    read_marks,
-    stage_marks,
 )
 from risingwave_tpu.types import Op
 
@@ -408,26 +407,26 @@ class GroupTopNExecutor(Executor, Checkpointable):
 
     # -- checkpoint/restore ----------------------------------------------
     def checkpoint_delta(self):
-        (sdirty,) = read_marks(self.state["sdirty"])
-        if not sdirty.any():
-            return []
-        upsert, tomb, sel = stage_marks(
-            sdirty, *read_marks(self.table.live, self.state["stored"])
+        marks = classify_marks(
+            self.state["sdirty"], self.table.live, self.state["stored"]
         )
+        self.state = dict(
+            self.state, sdirty=marks.sdirty, stored=marks.stored
+        )
+        if not len(marks):
+            return []
         lanes = {f"k{i}": x for i, x in enumerate(self.table.keys)}
         key_names = tuple(lanes)
         lanes["bv"] = self.state["band_valid"]
         lanes["order"] = self.state["order"]
         for p in self.payload:
             lanes[f"p_{p}"] = self.state[p]
-        pulled = pull_rows(lanes, sel)
+        pulled = pull_rows(lanes, marks)
         keys = {x: pulled[x] for x in key_names}
         vals = {x: v for x, v in pulled.items() if x not in key_names}
-        st = dict(self.state)
-        st["stored"] = (st["stored"] | jnp.asarray(upsert)) & ~jnp.asarray(tomb)
-        st["sdirty"] = jnp.zeros_like(st["sdirty"])
-        self.state = st
-        return [StateDelta(self.table_id, keys, vals, tomb[sel], key_names)]
+        return [
+            StateDelta(self.table_id, keys, vals, marks.tombstone, key_names)
+        ]
 
     def restore_state(self, table_id, key_cols, value_cols):
         n = len(next(iter(key_cols.values()))) if key_cols else 0

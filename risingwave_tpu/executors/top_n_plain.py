@@ -41,10 +41,9 @@ from risingwave_tpu.runtime.bucketing import (
 from risingwave_tpu.storage.state_table import (
     Checkpointable,
     StateDelta,
+    classify_marks,
     grow_pow2,
     pull_rows,
-    read_marks,
-    stage_marks,
 )
 from risingwave_tpu.trace import device_read, span
 from risingwave_tpu.types import Op
@@ -296,22 +295,20 @@ class TopNExecutor(Executor, Checkpointable):
 
     # -- checkpoint -------------------------------------------------------
     def checkpoint_delta(self) -> List[StateDelta]:
-        (sdirty,) = read_marks(self.sdirty)
-        if not sdirty.any():
+        marks = classify_marks(self.sdirty, self.table.live, self.stored)
+        self.sdirty, self.stored = marks.sdirty, marks.stored
+        if not len(marks):
             return []
-        upsert, tomb, sel = stage_marks(
-            sdirty, *read_marks(self.table.live, self.stored)
-        )
         lanes = {f"k{i}": lane for i, lane in enumerate(self.table.keys)}
         key_names = tuple(lanes)
         for n in self.names:
             lanes[f"r_{n}"] = self.rows[n]
-        pulled = pull_rows(lanes, sel)
+        pulled = pull_rows(lanes, marks)
         keys = {k: pulled[k] for k in key_names}
         vals = {k: v for k, v in pulled.items() if k not in key_names}
-        self.stored = (self.stored | jnp.asarray(upsert)) & ~jnp.asarray(tomb)
-        self.sdirty = jnp.zeros_like(self.sdirty)
-        return [StateDelta(self.table_id, keys, vals, tomb[sel], key_names)]
+        return [
+            StateDelta(self.table_id, keys, vals, marks.tombstone, key_names)
+        ]
 
     def restore_state(self, table_id, key_cols, value_cols) -> None:
         n = len(next(iter(key_cols.values()))) if key_cols else 0
@@ -1094,22 +1091,20 @@ class RetractableGroupTopNExecutor(Executor, Checkpointable):
         return host_digest(*self.digest_lanes())
 
     def checkpoint_delta(self) -> List[StateDelta]:
-        (sdirty,) = read_marks(self.sdirty)
-        if not sdirty.any():
+        marks = classify_marks(self.sdirty, self.table.live, self.stored)
+        self.sdirty, self.stored = marks.sdirty, marks.stored
+        if not len(marks):
             return []
-        upsert, tomb, sel = stage_marks(
-            sdirty, *read_marks(self.table.live, self.stored)
-        )
         lanes = {f"k{i}": lane for i, lane in enumerate(self.table.keys)}
         key_names = tuple(lanes)
         for n in self.names:
             lanes[f"r_{n}"] = self.rows[n]
-        pulled = pull_rows(lanes, sel)
+        pulled = pull_rows(lanes, marks)
         keys = {x: pulled[x] for x in key_names}
         vals = {x: v for x, v in pulled.items() if x not in key_names}
-        self.stored = (self.stored | jnp.asarray(upsert)) & ~jnp.asarray(tomb)
-        self.sdirty = jnp.zeros_like(self.sdirty)
-        return [StateDelta(self.table_id, keys, vals, tomb[sel], key_names)]
+        return [
+            StateDelta(self.table_id, keys, vals, marks.tombstone, key_names)
+        ]
 
     def restore_state(self, table_id, key_cols, value_cols) -> None:
         n = len(next(iter(key_cols.values()))) if key_cols else 0

@@ -30,6 +30,7 @@ overflow, surfaced at the next barrier.
 
 from __future__ import annotations
 
+import dataclasses
 from typing import Dict, List, Optional, Sequence
 
 import jax
@@ -41,7 +42,6 @@ from risingwave_tpu.array.chunk import StreamChunk
 from risingwave_tpu.executors.base import Barrier, Executor
 from risingwave_tpu.executors.hash_agg import (
     _build_key_lanes,
-    _mark_checkpointed,
     _rehash,
     build_restored_agg,
 )
@@ -61,8 +61,8 @@ from risingwave_tpu.parallel.exchange import (
 from risingwave_tpu.storage.state_table import (
     Checkpointable,
     StateDelta,
+    classify_marks,
     pull_rows,
-    stage_marks,
 )
 
 GROW_AT = 0.5
@@ -503,18 +503,17 @@ def _sharded_agg_checkpoint_delta(self) -> List[StateDelta]:
     """Stage ALL shards' changed rows as ONE table (keys are globally
     unique across shards); same lane naming as the single-chip agg so
     either executor can restore the other's checkpoint."""
-    shape = (self.n_shards, self.capacity)
-    sdirty = np.asarray(self.state.sdirty).reshape(-1)
-    if not sdirty.any():
-        return []
-    alive = (
-        np.asarray(self.table.live)
-        | np.asarray(self.state.emitted_valid)
-        | np.asarray(self.state.dirty)
-    ).reshape(-1)
-    upsert, tomb, sel = stage_marks(
-        sdirty, alive, np.asarray(self.state.stored).reshape(-1)
+    marks = classify_marks(
+        self.state.sdirty,
+        (self.table.live, self.state.emitted_valid, self.state.dirty),
+        self.state.stored,
     )
+    # eager flip — see StateDelta's durability contract
+    self.state = dataclasses.replace(
+        self.state, sdirty=marks.sdirty, stored=marks.stored
+    )
+    if not len(marks):
+        return []
     flat = lambda a: a.reshape((-1,) + a.shape[2:])
     lanes = {f"k{i}": flat(lane) for i, lane in enumerate(self.table.keys)}
     key_names = tuple(lanes)
@@ -526,15 +525,10 @@ def _sharded_agg_checkpoint_delta(self) -> List[StateDelta]:
         lanes[f"nn_{n}"] = flat(a)
         lanes[f"ei_{n}"] = flat(self.state.emitted_isnull[n])
     lanes["ev"] = flat(self.state.emitted_valid)
-    pulled = pull_rows(lanes, sel)
+    pulled = pull_rows(lanes, marks)
     keys = {k: pulled[k] for k in key_names}
     vals = {k: v for k, v in pulled.items() if k not in key_names}
-    self.state = _mark_checkpointed(
-        self.state,
-        jnp.asarray(upsert.reshape(shape)),
-        jnp.asarray(tomb.reshape(shape)),
-    )
-    return [StateDelta(self.table_id, keys, vals, tomb[sel], key_names)]
+    return [StateDelta(self.table_id, keys, vals, marks.tombstone, key_names)]
 
 
 def _sharded_agg_restore_state(self, table_id, key_cols, value_cols) -> None:

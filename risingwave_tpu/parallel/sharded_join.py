@@ -40,9 +40,9 @@ from risingwave_tpu.parallel.exchange import dest_shard, exchange_chunk
 from risingwave_tpu.storage.state_table import (
     Checkpointable,
     StateDelta,
+    classify_marks,
     grow_pow2,
     pull_rows,
-    stage_marks,
 )
 
 GROW_AT = 0.5
@@ -283,24 +283,17 @@ class ShardedDedup(Executor, Checkpointable):
         """Same lane naming as the single-chip dedup (k{i}), keys
         globally unique across shards — either executor can restore the
         other's checkpoint."""
-        sdirty = np.asarray(self.sdirty).reshape(-1)
-        if not sdirty.any():
+        marks = classify_marks(self.sdirty, self.table.live, self.stored)
+        self.sdirty, self.stored = marks.sdirty, marks.stored
+        if not len(marks):
             return []
-        shape = self.sdirty.shape
-        upsert, tomb, sel = stage_marks(
-            sdirty,
-            np.asarray(self.table.live).reshape(-1),
-            np.asarray(self.stored).reshape(-1),
-        )
         flat = lambda a: a.reshape((-1,) + a.shape[2:])
         lanes = {f"k{i}": flat(l) for i, l in enumerate(self.table.keys)}
         key_names = tuple(lanes)
-        keys = pull_rows(lanes, sel)
-        self.stored = (
-            self.stored | jnp.asarray(upsert.reshape(shape))
-        ) & ~jnp.asarray(tomb.reshape(shape))
-        self.sdirty = jnp.zeros_like(self.sdirty)
-        return [StateDelta(self.table_id, keys, {}, tomb[sel], key_names)]
+        keys = pull_rows(lanes, marks)
+        return [
+            StateDelta(self.table_id, keys, {}, marks.tombstone, key_names)
+        ]
 
     def restore_state(self, table_id, key_cols, value_cols) -> None:
         """Re-partition recovered keys by vnode and rebuild every shard
@@ -711,15 +704,16 @@ class ShardedHashJoin(Executor, Checkpointable):
 
     def _sharded_side_delta(self, name: str) -> Optional[StateDelta]:
         side = getattr(self, name)
-        sdirty = np.asarray(side.sdirty).reshape(-1)
-        if not sdirty.any():
-            return None
-        shape = side.sdirty.shape
-        upsert, tomb, sel = stage_marks(
-            sdirty,
-            np.asarray(side.table.live).reshape(-1),
-            np.asarray(side.stored).reshape(-1),
+        marks = classify_marks(side.sdirty, side.table.live, side.stored)
+        setattr(
+            self,
+            name,
+            dataclasses.replace(
+                side, sdirty=marks.sdirty, stored=marks.stored
+            ),
         )
+        if not len(marks):
+            return None
         flat = lambda a: a.reshape((-1,) + a.shape[2:])
         lanes = {f"k{i}": flat(l) for i, l in enumerate(side.table.keys)}
         key_names = tuple(lanes)
@@ -729,22 +723,11 @@ class ShardedHashJoin(Executor, Checkpointable):
             lanes[f"r_{nm}"] = flat(a)
         for nm, a in side.row_nulls.items():
             lanes[f"n_{nm}"] = flat(a)
-        pulled = pull_rows(lanes, sel)
+        pulled = pull_rows(lanes, marks)
         keys = {k: pulled[k] for k in key_names}
         vals = {k: v for k, v in pulled.items() if k not in key_names}
-        setattr(
-            self,
-            name,
-            dataclasses.replace(
-                side,
-                sdirty=jnp.zeros_like(side.sdirty),
-                stored=(
-                    side.stored | jnp.asarray(upsert.reshape(shape))
-                ) & ~jnp.asarray(tomb.reshape(shape)),
-            ),
-        )
         return StateDelta(
-            f"{self.table_id}.{name}", keys, vals, tomb[sel], key_names
+            f"{self.table_id}.{name}", keys, vals, marks.tombstone, key_names
         )
 
     def restore_state(self, table_id, key_cols, value_cols) -> None:

@@ -45,9 +45,9 @@ from risingwave_tpu.parallel.sharded_join import (
 from risingwave_tpu.storage.state_table import (
     Checkpointable,
     StateDelta,
+    classify_marks,
     grow_pow2,
     pull_rows,
-    stage_marks,
 )
 
 GROW_AT = 0.5
@@ -359,15 +359,11 @@ class ShardedMaterialize(MvDeviceReadMixin, Executor, Checkpointable):
 
     # -- checkpoint/restore (one logical table across shards) ------------
     def checkpoint_delta(self) -> List[StateDelta]:
-        shape = self.state.sdirty.shape
-        sdirty = np.asarray(self.state.sdirty).reshape(-1)
-        if not sdirty.any():
-            return []
-        alive = np.asarray(self.table.live).reshape(-1)
-        stored = np.asarray(self.state.stored).reshape(-1)
-        upsert, tomb, sel = stage_marks(sdirty, alive, stored)
-        if not len(sel):
-            self.state.sdirty = jnp.zeros_like(self.state.sdirty)
+        marks = classify_marks(
+            self.state.sdirty, self.table.live, self.state.stored
+        )
+        self.state.sdirty, self.state.stored = marks.sdirty, marks.stored
+        if not len(marks):
             return []
         flat = lambda a: a.reshape((-1,) + a.shape[2:])
         lanes = {f"k{j}": flat(k) for j, k in enumerate(self.table.keys)}
@@ -380,23 +376,19 @@ class ShardedMaterialize(MvDeviceReadMixin, Executor, Checkpointable):
         lanes.update(
             {f"n_{c}": flat(lane) for c, lane in self.state.vnulls.items()}
         )
-        rows = pull_rows(lanes, sel)
+        rows = pull_rows(lanes, marks)
         key_cols = {f"k{j}": rows[f"k{j}"] for j in range(len(self.pk))}
         value_cols = {
             f"v{j}": rows[f"v{j}"] for j in range(len(self.columns))
         }
         for c in self.state.vnulls:
             value_cols[f"n_{c}"] = rows[f"n_{c}"].astype(np.uint8)
-        self.state.stored = jnp.asarray(
-            ((stored | upsert) & ~tomb).reshape(shape)
-        )
-        self.state.sdirty = jnp.zeros_like(self.state.sdirty)
         return [
             StateDelta(
                 self.table_id,
                 key_cols,
                 value_cols,
-                tomb[sel],
+                marks.tombstone,
                 tuple(f"k{j}" for j in range(len(self.pk))),
             )
         ]

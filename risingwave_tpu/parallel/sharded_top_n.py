@@ -46,9 +46,9 @@ from risingwave_tpu.runtime.bucketing import emission_bucket
 from risingwave_tpu.storage.state_table import (
     Checkpointable,
     StateDelta,
+    classify_marks,
     grow_pow2,
     pull_rows,
-    stage_marks,
 )
 from risingwave_tpu.types import Op
 
@@ -454,28 +454,21 @@ class ShardedGroupTopN(Executor, Checkpointable):
 
     # -- checkpoint/restore (single-chip lane naming) ---------------------
     def checkpoint_delta(self) -> List[StateDelta]:
-        sdirty = np.asarray(self.sdirty).reshape(-1)
-        if not sdirty.any():
+        marks = classify_marks(self.sdirty, self.table.live, self.stored)
+        self.sdirty, self.stored = marks.sdirty, marks.stored
+        if not len(marks):
             return []
-        shape = self.sdirty.shape
-        upsert, tomb, sel = stage_marks(
-            sdirty,
-            np.asarray(self.table.live).reshape(-1),
-            np.asarray(self.stored).reshape(-1),
-        )
         flat = lambda a: a.reshape((-1,) + a.shape[2:])
         lanes = {f"k{i}": flat(l) for i, l in enumerate(self.table.keys)}
         key_names = tuple(lanes)
         for n in self.names:
             lanes[f"r_{n}"] = flat(self.rows[n])
-        pulled = pull_rows(lanes, sel)
+        pulled = pull_rows(lanes, marks)
         keys = {k: pulled[k] for k in key_names}
         vals = {k: v for k, v in pulled.items() if k not in key_names}
-        self.stored = (
-            self.stored | jnp.asarray(upsert.reshape(shape))
-        ) & ~jnp.asarray(tomb.reshape(shape))
-        self.sdirty = jnp.zeros_like(self.sdirty)
-        return [StateDelta(self.table_id, keys, vals, tomb[sel], key_names)]
+        return [
+            StateDelta(self.table_id, keys, vals, marks.tombstone, key_names)
+        ]
 
     def restore_state(self, table_id, key_cols, value_cols) -> None:
         """Re-partition recovered rows by GROUP-column vnode and

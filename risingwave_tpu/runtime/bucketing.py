@@ -124,6 +124,25 @@ def delta_blocks(n: int) -> Tuple[int, int]:
     return DELTA_BLOCK, -(-int(n) // DELTA_BLOCK)
 
 
+# the ranks one selection program of a checkpoint's changed slots ranges
+# over (storage/state_table.py::_select): a block, or SELECT_SPAN. As
+# the barrier's thread sees it on the chip, dispatch to result, one
+# costs 1.1 ms at a block, 1.6 at 16,384 ranks and 3.0 at 65,536, four
+# back to back 3.3 / 4.1 / 7.2 (most of it the round trip: the device's
+# own clock reads a sixth of a millisecond for the classification of
+# 2^23 lanes); compiling one costs 0.3 / 0.5 / 0.9 s, a capacity of a
+# session's tables, when its view is created (PERF.md 6, PR 39)
+SELECT_SPAN = 1 << 14
+
+
+def select_spans(n: int) -> Tuple[int, int]:
+    """(ranks a selection program, programs) for ``n`` changed slots: one
+    block where that holds them, else as many spans as do."""
+    if n <= DELTA_BLOCK:
+        return DELTA_BLOCK, 1
+    return SELECT_SPAN, -(-int(n) // SELECT_SPAN)
+
+
 def prefix_pad(k: int, capacity: int) -> int:
     """Lanes to copy of a chunk whose live rows lie in its first ``k``
     lanes: all of them, or for a chunk past PREFIX_WHOLE lanes a power

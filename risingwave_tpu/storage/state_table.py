@@ -24,6 +24,7 @@ the executor's ``restore_state`` to rebuild device state.
 
 from __future__ import annotations
 
+import functools
 import json
 import threading
 from collections import deque as _deque
@@ -89,7 +90,10 @@ class StateDelta:
 
     Staging flips the executor's device sdirty/stored marks EAGERLY —
     slot indices shift on rehash, so a deferred flip would hit wrong
-    slots. The durability contract is therefore the reference's
+    slots. The flipped lanes are outputs of the program that classified
+    the marks (``classify_marks``) and never leave the device; the
+    executor adopts them as it stages. The durability contract is
+    therefore the reference's
     (barrier/mod.rs:676): if a commit FAILS, in-memory marks are ahead
     of storage and the process MUST recover() from the last durable
     manifest — never retry the commit against live state.
@@ -103,23 +107,204 @@ class StateDelta:
 
 
 def read_marks(*lanes) -> List[np.ndarray]:
-    """Mark lanes of a table (sdirty, live, stored: a byte a slot, the
-    table's capacity long) copied to the host: the blocking reads
-    inside ``checkpoint.marks``, one ``device.read``."""
-    with device_read(
-        "checkpoint.marks", bytes=sum(int(a.nbytes) for a in lanes)
-    ):
-        return [np.asarray(a) for a in lanes]
+    """Whole lanes copied to the host inside ``checkpoint.marks``, one
+    ``device.read``: for state that keeps no sdirty/stored marks a slot
+    (a sort buffer's valid lane, a simple aggregate's one flag). A
+    table that does keeps them on the device: ``classify_marks``."""
+    nbytes = sum(int(a.nbytes) for a in lanes)
+    with device_read("checkpoint.marks", bytes=nbytes):
+        out = [np.asarray(a) for a in lanes]
+    _note_marks(0, 0, nbytes)
+    return out
 
 
-def stage_marks(
-    sdirty: np.ndarray, alive: np.ndarray, stored: np.ndarray
-) -> Tuple[np.ndarray, np.ndarray, np.ndarray]:
-    """The shared upsert/tombstone classification every Checkpointable
-    executor uses: returns (upsert_mask, tombstone_mask, sel_indices)."""
-    upsert = sdirty & alive
-    tomb = sdirty & stored & ~alive
-    return upsert, tomb, np.flatnonzero(upsert | tomb)
+# lanes of a mark lane that one row of the classification's two-level
+# count holds, and rows a group: a rank is found among the groups, then
+# among its group's rows, then among its row's lanes, each by one
+# compare over MARK_ROW entries a rank and with no loop
+MARK_ROW = 128
+
+
+def _note_marks(capacity: int, selected: int, read_bytes: int) -> None:
+    """Add to what the open ``checkpoint.marks`` span will say of its
+    executor's tables (``Checkpointable._pull_delta``)."""
+    told = getattr(_STAGING, "marks", None)
+    if told is not None:
+        told[0] += capacity
+        told[1] += selected
+        told[2] += read_bytes
+
+
+def _running(lanes):
+    """Inclusive running sum along rows of MARK_ROW entries, each at
+    most MARK_ROW: by a triangle of ones on the MXU, exact in bfloat16
+    x bfloat16 -> float32 (no sum passes MARK_ROW squared)."""
+    return jnp.dot(
+        lanes.astype(jnp.bfloat16),
+        jnp.triu(jnp.ones((MARK_ROW, MARK_ROW), jnp.bfloat16)),
+        preferred_element_type=jnp.float32,
+    ).astype(jnp.int32)
+
+
+@jax.jit
+def _classify(sdirty, alive, stored):
+    """A table's marks classified where they lie. ``alive`` is a tuple
+    of lanes, any of which keeps a slot's row (a lane that is no bool
+    does so where it is not 0). Hands back the count of changed slots,
+    a byte a slot in rows of MARK_ROW (bit 0: changed, bit 1: a
+    tombstone), the running count of changed slots by row in groups of
+    MARK_ROW rows (rows past the table's last repeat the count), and the
+    flipped ``stored`` / ``sdirty`` in the lanes' own shape."""
+    live = functools.reduce(
+        jnp.logical_or, [a.astype(jnp.bool_) for a in alive]
+    )
+    upsert = sdirty & live
+    tomb = sdirty & stored & ~live
+    code = (upsert | tomb).astype(jnp.uint8) + 2 * tomb.astype(jnp.uint8)
+    code = jnp.pad(code.reshape(-1), (0, -code.size % MARK_ROW))
+    code = code.reshape(-1, MARK_ROW)
+    per_row = jnp.sum(code & 1, axis=1, dtype=jnp.int32)
+    per_row = jnp.pad(per_row, (0, -len(per_row) % MARK_ROW))
+    groups = _running(per_row.reshape(-1, MARK_ROW))
+    ahead = jnp.cumsum(groups[:, -1])
+    groups = groups + (ahead - groups[:, -1])[:, None]
+    return (
+        ahead[-1], code, groups,
+        (stored | upsert) & ~tomb, jnp.zeros_like(sdirty),
+    )
+
+
+@functools.partial(jax.jit, static_argnames="span")
+def _select(code, groups, offset, *, span: int):
+    """The changed slots of ranks ``offset`` to ``offset + span`` in
+    ascending order, as ``pull_rows`` gathers by them: the first
+    DELTA_SMALL alone, and all in pieces of DELTA_BLOCK; and whether
+    each is a tombstone. Slot 0 and False past the last. No scatter
+    (the TPU compiler lays a flat one of capacity updates out anew:
+    PERF.md 6, PR 32) and no search loop: a rank's group, row and lane
+    are each the number of running counts at or under it, and the one
+    gather is of its row."""
+    from risingwave_tpu.runtime.bucketing import DELTA_BLOCK, DELTA_SMALL
+
+    i32 = jnp.int32
+    rank = offset + jnp.arange(span, dtype=i32)
+    at = rank[:, None]
+    group = jnp.sum(groups[:, -1][None, :] <= at, axis=1, dtype=i32)
+    group = jnp.minimum(group, len(groups) - 1)
+    counts = groups[group]
+    row = jnp.sum(counts <= at, axis=1, dtype=i32)
+    # the running count before the rank's row: the last of its group's
+    # at or under the rank, or of the groups before
+    before = jnp.max(jnp.where(counts <= at, counts, 0), axis=1)
+    before = jnp.maximum(
+        before, jnp.where(group > 0, groups[:, -1][group - 1], 0)
+    )
+    row = jnp.minimum(group * MARK_ROW + row, len(code) - 1)
+    lanes = code[row]
+    upto = _running(lanes & 1)
+    lane = jnp.sum(upto <= (rank - before)[:, None], axis=1, dtype=i32)
+    lane = jnp.minimum(lane, MARK_ROW - 1)
+    ok = rank < groups[-1, -1]
+    hit = jnp.arange(MARK_ROW, dtype=i32)[None, :] == lane[:, None]
+    dead = jnp.any(hit & (lanes >= 2), axis=1) & ok
+    slots = jnp.where(ok, row * MARK_ROW + lane, 0)
+    small = slots[:DELTA_SMALL], dead[:DELTA_SMALL]
+    return small, tuple(slots.reshape(-1, DELTA_BLOCK)), dead
+
+
+@functools.lru_cache(maxsize=None)
+def _warm_select(rows: int) -> None:
+    """Compile ``_select`` at both spans of ``select_spans`` the first
+    time a table of ``rows`` rows of marks is classified: which of them
+    a barrier takes follows what its epoch changed, and a span first
+    met inside a stream would open a compile there."""
+    from risingwave_tpu.runtime.bucketing import DELTA_BLOCK, SELECT_SPAN
+
+    code = jnp.zeros((rows, MARK_ROW), jnp.uint8)
+    groups = jnp.zeros((-(-rows // MARK_ROW), MARK_ROW), jnp.int32)
+    for span in (DELTA_BLOCK, SELECT_SPAN):
+        _select(code, groups, 0, span=span)
+
+
+@dataclass
+class Marks:
+    """What ``classify_marks`` found of one table: ``len()`` changed
+    slots, their indices on the device in ``blocks`` (ascending, in
+    ``delta_blocks``' pieces: what ``pull_rows`` gathers by),
+    ``tombstone`` on the host (a byte a changed slot), and the flipped
+    ``sdirty`` / ``stored`` lanes for the eager flip."""
+
+    n: int
+    blocks: List[jax.Array]
+    tombstone: np.ndarray
+    sdirty: jax.Array
+    stored: jax.Array
+
+    def __len__(self) -> int:
+        return self.n
+
+    def slots(self) -> np.ndarray:
+        """The changed slots copied to the host (4 bytes each): for a
+        table that is keyed by them (the chained join's row stores)."""
+        if not self.n:
+            return np.zeros(0, np.int64)
+        nbytes = sum(int(b.nbytes) for b in self.blocks)
+        with device_read("checkpoint.marks", bytes=nbytes):
+            host = [np.asarray(b) for b in self.blocks]
+        _note_marks(0, 0, nbytes)
+        return np.concatenate(host)[: self.n].astype(np.int64)
+
+
+def classify_marks(sdirty, alive, stored) -> Marks:
+    """The upsert / tombstone classification every Checkpointable table
+    shares, on the device (``_classify``):
+
+        upsert = sdirty & alive          tomb = sdirty & stored & ~alive
+
+    ``alive`` is one lane or a tuple of them, any of which keeps a slot
+    (``live``; ``live, emitted_valid, dirty`` for an aggregate). The
+    host reads ONE count (the wait for the epoch's steps lands on that
+    read), and for a count of 0 nothing more; else the tombstone bit of
+    each changed slot, a byte a rank of the spans that hold the count
+    (``select_spans``; of 256 ranks for a count no larger). The slots
+    themselves stay on the device for ``pull_rows``, and so do the
+    flipped lanes the caller adopts (``StateDelta``)."""
+    from risingwave_tpu.runtime.bucketing import (
+        DELTA_BLOCK,
+        delta_blocks,
+        select_spans,
+    )
+
+    if not isinstance(alive, (tuple, list)):
+        alive = (alive,)
+    count, code, groups, stored, cleared = _classify(
+        sdirty, tuple(alive), stored
+    )
+    _warm_select(len(code))
+    with device_read("checkpoint.marks", bytes=int(count.nbytes)):
+        n = int(count)
+    read = int(count.nbytes)
+    blocks, tombstone = [], np.zeros(0, bool)
+    if n:
+        span, programs = select_spans(n)
+        parts = [
+            _select(code, groups, i * span, span=span)
+            for i in range(programs)
+        ]
+        # the pieces that hold a changed slot, as pull_rows counts them
+        block, pieces = delta_blocks(n)
+        if block < DELTA_BLOCK:
+            (piece, dead), _, _ = parts[0]
+            blocks, bits = [piece], [dead]
+        else:
+            blocks = [piece for _, cut, _ in parts for piece in cut][:pieces]
+            bits = [dead for _, _, dead in parts]
+        nbytes = sum(int(dead.nbytes) for dead in bits)
+        with device_read("checkpoint.marks", bytes=nbytes):
+            tombstone = np.concatenate([np.asarray(d) for d in bits])[:n]
+        read += nbytes
+    _note_marks(int(sdirty.size), n, read)
+    return Marks(n, blocks, tombstone, cleared, stored)
 
 
 def grow_pow2(n: int, cap: int, grow_at: float = 0.5) -> int:
@@ -166,14 +351,17 @@ _STAGING = threading.local()
 
 def pull_rows(
     device_lanes: Dict[str, object],
-    sel: np.ndarray,
+    sel: np.ndarray | Marks,
     table_id: Optional[str] = None,
 ) -> Dict[str, np.ndarray]:
     """Device->host transfer of SELECTED rows only (checkpoint staging
     must be O(changed rows), not O(capacity)). ``sel`` goes in pieces of
     one of two sizes (bucketing.delta_blocks), so jit caches two gather
     programs per lane set whatever an epoch changed, instead of one per
-    power of two its count ever crossed.
+    power of two its count ever crossed. A table's ``Marks`` bring the
+    pieces as ``classify_marks`` left them on the device, so the
+    gathers' index makes no round trip through the host; a host array
+    of slots (a read, a table that keeps no marks) is uploaded.
 
     While an executor's checkpoint delta is being pulled (``table_id``
     given, or the executor's own under ``_pull_delta``) the gathers'
@@ -208,13 +396,16 @@ def pull_rows(
 
 def _pull(device_lanes, sel, n: int, block: int) -> Dict[str, np.ndarray]:
     lanes = dict(device_lanes)
-    idx = np.zeros(-(-n // block) * block, np.int32)
-    idx[:n] = sel
+    if isinstance(sel, Marks):
+        pieces = sel.blocks
+    else:
+        idx = np.zeros(-(-n // block) * block, np.int32)
+        idx[:n] = sel
+        pieces = [
+            jnp.asarray(idx[a : a + block]) for a in range(0, len(idx), block)
+        ]
     # every block's gather is enqueued before the first copy is awaited
-    parts = [
-        _gather(lanes, jnp.asarray(idx[a : a + block]))
-        for a in range(0, len(idx), block)
-    ]
+    parts = [_gather(lanes, piece) for piece in pieces]
     with device_read(
         "pull_rows",
         bytes=sum(int(a.nbytes) for p in parts for a in p.values()),
@@ -256,25 +447,38 @@ class Checkpointable:
 
     def _pull_delta(self) -> List[StateDelta]:
         """``checkpoint_delta`` under the span ``checkpoint.marks``: what
-        an executor's staging does outside its row pull — reading the
-        dirty/live/stored marks off the device, classifying them,
-        flipping them. Its ``pull_rows`` nest inside as
-        ``checkpoint.pull``, carrying this table's id; the stage key
-        ``checkpoint_stage.marks`` holds the span less those pulls, so
-        that it lies beside ``checkpoint_stage.pull`` and not over it."""
+        an executor's staging does outside its row pull — classifying
+        the dirty/live/stored marks where they lie (``classify_marks``),
+        reading the count and the tombstone bits, adopting the flipped
+        lanes. The span says how many lanes its tables' mark lanes hold
+        (``capacity``), how many slots were classified changed
+        (``selected``) and how many bytes its ``device.read``s copied to
+        the host (``read_bytes``, also the counter
+        ``checkpoint_marks_read_bytes_total``). Its ``pull_rows`` nest
+        inside as ``checkpoint.pull``, carrying this table's id; the
+        stage key ``checkpoint_stage.marks`` holds the span less those
+        pulls, so that it lies beside ``checkpoint_stage.pull`` and not
+        over it."""
         tid = self.table_id or ",".join(self.checkpoint_table_ids())
         if not tid:  # state that is no table (the session dictionary)
             return self.checkpoint_delta()
         _STAGING.table_id, _STAGING.pull_s = tid, 0.0
+        _STAGING.marks = told = [0, 0, 0]
         try:
             with span("checkpoint.marks", table_id=tid) as sp:
                 deltas = self.checkpoint_delta()
+                sp.args.update(
+                    capacity=told[0], selected=told[1], read_bytes=told[2]
+                )
+            REGISTRY.counter("checkpoint_marks_read_bytes_total").inc(
+                told[2], table_id=tid
+            )
             add_stage(
                 "checkpoint_stage.marks", (sp.dur - _STAGING.pull_s) * 1e3
             )
             return deltas
         finally:
-            _STAGING.table_id = None
+            _STAGING.table_id = _STAGING.marks = None
 
     def capture_checkpoint(self) -> None:
         if self._captured_deltas is None:

@@ -62,6 +62,10 @@ def _clear_jax_caches_per_module():
     accumulated CPU executables have produced in-compile segfaults deep
     into the full suite (observed in jax backend_compile during a late
     module); modules are self-contained, so bounding the live cache
-    costs only per-module recompiles."""
+    costs only per-module recompiles. What the program remembers of
+    having compiled goes with them."""
     yield
     jax.clear_caches()
+    from risingwave_tpu.storage.state_table import _warm_select
+
+    _warm_select.cache_clear()

@@ -5,6 +5,9 @@ barrier writes of itself. Structure, kinds and sums; never a rate."""
 
 import json
 import logging
+import os
+import subprocess
+import sys
 import time
 from types import SimpleNamespace
 
@@ -13,6 +16,7 @@ import pytest
 
 from risingwave_tpu import blackbox, trace
 from risingwave_tpu.event_log import EVENT_LOG
+from risingwave_tpu.metrics import REGISTRY
 from risingwave_tpu.ops.hash_table import read_scalars
 from risingwave_tpu.trace import TRACER
 
@@ -126,6 +130,68 @@ def test_the_new_stage_keys_are_in_the_epochs_trace(q8):
     )
     assert st["actor.device_wait"] > 0.0  # summed over the actors
     assert "device_step" not in st
+
+
+def test_checkpoint_marks_says_what_it_classified_and_what_it_read(q8):
+    """The marks are classified where they live: the span carries the
+    lanes its tables' mark lanes hold, the slots classified changed and
+    the bytes its own ``device.read``s copied down (the pulls' reads are
+    ``checkpoint.pull``'s) - a count and a byte a changed slot, not
+    whole lanes."""
+    read_bytes = REGISTRY.counter("checkpoint_marks_read_bytes_total")
+    before = read_bytes.total()
+    TRACER.clear()
+    tr = q8.epoch()
+    spans = [sp for sp in TRACER.spans() if sp.epoch == tr.epoch]
+    marks = [sp for sp in spans if sp.name == "checkpoint.marks"]
+    assert len(marks) >= 3  # the join's two sides, the view, the tables
+    total = own_ms = 0.0
+    for sp in marks:
+        assert {"table_id", "capacity", "selected", "read_bytes"} <= set(
+            sp.args
+        )
+        reads = [
+            k for k in spans if k.name == "device.read" and k.parent == sp.sid
+        ]
+        assert {k.args["what"] for k in reads} <= {"checkpoint.marks"}
+        assert sum(k.args["bytes"] for k in reads) == sp.args["read_bytes"]
+        total += sp.args["read_bytes"]
+        pulls = [
+            k for k in spans
+            if k.name == "checkpoint.pull" and k.parent == sp.sid
+        ]
+        own_ms += (sp.dur - sum(k.dur for k in pulls)) * 1e3
+    assert read_bytes.total() - before == total
+    # 50 persons and 50 auctions an epoch in tables of 4,096 lanes and
+    # more: each table's marks cost its count and a padded byte a row
+    slotted = [sp for sp in marks if sp.args["capacity"]]
+    assert slotted and all(
+        0 < sp.args["selected"] <= 100
+        and sp.args["read_bytes"] < sp.args["capacity"]
+        for sp in slotted
+    )
+    assert tr.stages_ms["checkpoint_stage.marks"] == pytest.approx(own_ms)
+
+
+def test_a_traced_rehearsal_of_q4_catchup_reports_marks_bytes_per_event():
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    env = {**os.environ, "JAX_PLATFORMS": "cpu"}
+    env.pop("XLA_FLAGS", None)
+    run = subprocess.run(
+        [sys.executable, os.path.join(root, "benchmarks", "run.py"),
+         "--workload", "nexmark_q4.catchup", "--seed", "3900000039",
+         "--seconds", "4", "--trace", "1", "--dry-run-cpu"],
+        capture_output=True, text=True, cwd=root, env=env, timeout=600,
+    )
+    assert run.returncode == 0, run.stderr[-2000:]
+    doc = json.loads(run.stdout.strip().splitlines()[-1])
+    assert doc["correct"] is True and doc["device"]["platform"] == "cpu"
+    got = doc["metrics"]["checkpoint.marks_bytes_per_event.catchup"]
+    # two aggregates' counts and padded tombstone bytes an epoch of
+    # 2,048 events: under a byte an event where five lanes of the
+    # session's capacity were 1,280 (a count made by the program; no
+    # device number comes from a dry run)
+    assert got["unit"] == "bytes/event" and 0.0 < got["value"] < 64.0
 
 
 # -- (b) the ring reduced to a barrier's critical path -------------------
