@@ -130,8 +130,10 @@ class MaterializeExecutor(Executor, Checkpointable):
     def apply(self, chunk: StreamChunk) -> List[StreamChunk]:
         # host-map MV: the chunk comes to the host (waiting for the
         # step that made it), then the map applies it row by row
-        with span("mv.apply", stage="actor.mv_apply", table_id=self.table_id):
-            return self._apply(chunk)
+        with span(
+            "mv.apply", stage="actor.mv_apply", table_id=self.table_id
+        ) as sp:
+            return self._apply(chunk, sp)
 
     # one host apply a chunk at the chunk's own width: takes the push
     # lattice (a table fragment copies a quarter of the lanes)
@@ -142,11 +144,13 @@ class MaterializeExecutor(Executor, Checkpointable):
         row ``_apply`` returns before it touches the map."""
         return self._apply(chunk)
 
-    def _apply(self, chunk: StreamChunk) -> List[StreamChunk]:
+    def _apply(self, chunk: StreamChunk, sp=None) -> List[StreamChunk]:
         with span("mv.to_numpy"):
             data = chunk.to_numpy(with_ops=True)
         ops = data["__op__"]
         n = len(ops)
+        if sp is not None:
+            sp.args["rows"] = n  # the chunk's valid rows
         if n == 0:
             return [chunk]
         for name in self.pk + self.columns:

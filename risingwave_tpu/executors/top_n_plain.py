@@ -455,10 +455,10 @@ def _packed_words(digits):
     return tuple(words), offsets
 
 
-def _sort_by_words(words, payload):
-    """``words`` (least significant first) and ``payload`` in the
+def _sort_by_words(words, payloads):
+    """``words`` (least significant first) and the ``payloads`` in the
     lanes' order by the words: one stable sort a word, keyed on it and
-    carrying every other word and the payload along, in a loop, so the
+    carrying every other word and the payloads along, in a loop, so the
     program holds ONE sort whatever the key is made of. (A sort keyed on
     all of q18's key lanes at once, six operands and 64-bit compares,
     took the TPU's compiler ten minutes at 2^21 lanes; a loop of
@@ -466,7 +466,7 @@ def _sort_by_words(words, payload):
     compiled in half a minute and spent three quarters of its time in
     the gathers: 40 ms a gather of 2^22 32-bit lanes against 12 ms a
     sort.) A word every lane shares is skipped on the device. Returns
-    (words, payload, sorts made)."""
+    (words, payloads, sorts made)."""
     n = len(words)
 
     def one(_, carry):
@@ -482,19 +482,23 @@ def _sort_by_words(words, payload):
         return ops[1:n] + ops[:1] + ops[n:], passes
 
     ops, passes = jax.lax.fori_loop(
-        0, n, one, (tuple(words) + (payload,), jnp.zeros((), jnp.int32))
+        0, n, one, (tuple(words) + tuple(payloads), jnp.zeros((), jnp.int32))
     )
-    return ops[:n], ops[n], passes
+    return ops[:n], ops[n:], passes
 
 
-def _rank_sorted(table: HashTable, order_lanes, flags, k, descs, n_group):
+def _rank_sorted(
+    table: HashTable, order_lanes, flags, k, descs, n_group, carried=()
+):
     """Every lane ranked by (group lanes, dead last, the order keys, the
     rest of the stream key). ``order_lanes`` / ``descs``: the order
     keys' lanes and their directions, most significant first
     (``_order_of``). Returns, in sorted order: each lane's slot with
     ``flags`` (bits above ``_SLOT_MASK``) carried along, whether it is
-    in its group's top-k, the position its group starts at, and the
-    sorts the ranking took."""
+    in its group's top-k, the position its group starts at, the sorts
+    the ranking took, and then every lane of ``carried`` (operands that
+    ride along the sort beside the slot: a capacity-wide gather into
+    the sorted order costs three sorts)."""
     cap = table.capacity
     # liveness as its own sort key within the group (a dead-row
     # sentinel would collide with INT64-extreme order values)
@@ -514,8 +518,8 @@ def _rank_sorted(table: HashTable, order_lanes, flags, k, descs, n_group):
         digits += _digits(lane)
     words, offsets = _packed_words(digits)
     flags = flags | (table.live.astype(jnp.int32) << _LIVE_BIT)
-    words, packed_s, passes = _sort_by_words(
-        words, jnp.arange(cap, dtype=jnp.int32) | flags
+    words, (packed_s, *carried_s), passes = _sort_by_words(
+        words, (jnp.arange(cap, dtype=jnp.int32) | flags,) + tuple(carried)
     )
     # a group starts where a bit of the group's digits differs from the
     # lane before: the bits from ``offsets[n_below]`` up
@@ -534,15 +538,18 @@ def _rank_sorted(table: HashTable, order_lanes, flags, k, descs, n_group):
     seg_start = jax.lax.cummax(jnp.where(boundary, pos, 0))
     live_s = ((packed_s >> _LIVE_BIT) & 1) > 0
     in_topk_s = live_s & ((pos - seg_start) < k)
-    return packed_s, in_topk_s, seg_start, passes
+    return (packed_s, in_topk_s, seg_start, passes) + tuple(carried_s)
 
 
-def _compact(mask, out_lanes: int):
+def _compact(mask, out_lanes: int, start=None):
     """Positions of the first ``out_lanes`` set lanes of ``mask`` (in
-    order), how many are set, and which output lanes hold one."""
+    order; of those after the first ``start``, where one is given), how
+    many are set in all, and which output lanes hold one."""
     csum = jnp.cumsum(mask.astype(jnp.int32))
     n = csum[-1]
     want = jnp.arange(1, out_lanes + 1, dtype=jnp.int32)
+    if start is not None:
+        want = want + start
     pos = jnp.searchsorted(csum, want, side="left").astype(jnp.int32)
     valid = want <= n
     return jnp.where(valid, pos, 0), n, valid
@@ -559,13 +566,16 @@ def _rank(
     desc: Union[bool, Tuple[bool, ...]],
     n_group: int,
     order_col: Union[str, Tuple[str, ...]],
+    erank: Optional[jnp.ndarray] = None,
 ):
     """The barrier's first program, one per store capacity: every lane
     ranked, with what the diff needs carried along. ``order_col`` /
     ``desc``: the order key and its direction, or a tuple of each for
     an order of several keys, most significant first. Returns, in
     sorted order, (slot | flags, in its group's top-k, where its group
-    starts) and the sorts made."""
+    starts) and the sorts made; then, for a Top-N that hands the rank
+    on, ``erank`` (the rank each row was handed on with), which rides
+    along the sort beside the slot."""
     redo = epoch_dirty & _any_differs(rows, shadow)
     flags = (
         (emitted.astype(jnp.int32) << _EMITTED_BIT)
@@ -573,7 +583,10 @@ def _rank(
         | (redo.astype(jnp.int32) << _REDO_BIT)
     )
     lanes, descs = _order_of(rows, order_col, desc)
-    return _rank_sorted(table, lanes, flags, k, descs, n_group)
+    return _rank_sorted(
+        table, lanes, flags, k, descs, n_group,
+        carried=() if erank is None else (erank,),
+    )
 
 
 def _order_of(rows, order_col, desc):
@@ -584,7 +597,37 @@ def _order_of(rows, order_col, desc):
     return tuple(rows[c] for c in order_col), tuple(desc)
 
 
-@partial(jax.jit, static_argnames=("out_lanes",), donate_argnums=(2, 3))
+def _touched_groups(dirty_s, seg_start):
+    """Groups (in sorted order) that hold a dirty row: the dirty rows
+    with no dirty row before them in their group."""
+    pos = jnp.arange(dirty_s.shape[0], dtype=jnp.int32)
+    last_dirty = jax.lax.cummax(jnp.where(dirty_s, pos, -1))
+    before = jnp.concatenate([jnp.full(1, -1, jnp.int32), last_dirty[:-1]])
+    return jnp.sum((dirty_s & (before < seg_start)).astype(jnp.int32))
+
+
+def _delta_chunks(ret_cols, ret_valid, ins_cols, ins_valid, out_lanes: int):
+    """The two chunks a barrier (or a round of one) hands on."""
+    return tuple(
+        StreamChunk(
+            columns=cols,
+            valid=valid,
+            nulls={},
+            ops=jnp.full(out_lanes, int(op), jnp.int32),
+        )
+        for cols, valid, op in (
+            (ret_cols, ret_valid, Op.DELETE),
+            (ins_cols, ins_valid, Op.INSERT),
+        )
+    )
+
+
+@partial(
+    jax.jit,
+    static_argnames=("out_lanes", "rank_col"),
+    donate_argnums=(2, 3),
+    donate_argnames=("erank",),
+)
 def _diff_gather(
     table: HashTable,
     rows: Dict[str, jnp.ndarray],
@@ -593,9 +636,15 @@ def _diff_gather(
     ranked,
     dropped: jnp.ndarray,
     out_lanes: int,
+    erank: Optional[jnp.ndarray] = None,
+    start: Optional[jnp.ndarray] = None,
+    rank_col: Optional[str] = None,
 ):
     """The barrier's second program, one per emission size: the ranking
-    diffed against what was handed on, and both deltas gathered.
+    diffed against what was handed on, and both deltas gathered. (With
+    ``rank_col`` the rank is handed on as that column and this is
+    ``_diff_gather_numbered``, below: the same program to the device
+    trace and to what reads it, another body.)
 
     A row is retracted when it was handed on and is no longer in its
     group's top-k, or was rewritten this epoch with other values
@@ -609,6 +658,11 @@ def _diff_gather(
     Returns (emitted, shadow, retractions, insertions, status) with
     status = [retract rows, insert rows, touched groups, overflow,
     dropped latch, slots claimed, live rows, sorts made]."""
+    if rank_col is not None:
+        return _diff_gather_numbered(
+            table, rows, shadow, emitted, erank, ranked, dropped, start,
+            rank_col, out_lanes,
+        )
     cap = table.capacity
     packed_s, in_topk_s, seg_start, passes = ranked
     slot_s = packed_s & _SLOT_MASK
@@ -617,12 +671,7 @@ def _diff_gather(
     redo_s = ((packed_s >> _REDO_BIT) & 1) > 0
     ret_s = emitted_s & (~in_topk_s | redo_s)
     ins_s = in_topk_s & (~emitted_s | redo_s)
-    # groups that hold a dirty row: the dirty rows with no dirty row
-    # before them in their group
-    pos = jnp.arange(cap, dtype=jnp.int32)
-    last_dirty = jax.lax.cummax(jnp.where(dirty_s, pos, -1))
-    before = jnp.concatenate([jnp.full(1, -1, jnp.int32), last_dirty[:-1]])
-    groups = jnp.sum((dirty_s & (before < seg_start)).astype(jnp.int32))
+    groups = _touched_groups(dirty_s, seg_start)
 
     ret_pos, n_ret, ret_valid = _compact(ret_s, out_lanes)
     ins_pos, n_ins, ins_valid = _compact(ins_s, out_lanes)
@@ -650,19 +699,111 @@ def _diff_gather(
             passes,
         ]
     )
-    chunks = tuple(
-        StreamChunk(
-            columns=cols,
-            valid=valid,
-            nulls={},
-            ops=jnp.full(out_lanes, int(op), jnp.int32),
-        )
-        for cols, valid, op in (
-            (ret_cols, ret_valid, Op.DELETE),
-            (ins_cols, ins_valid, Op.INSERT),
-        )
+    chunks = _delta_chunks(
+        ret_cols, ret_valid, ins_cols, ins_valid, out_lanes
     )
     return emitted, shadow, chunks[0], chunks[1], status
+
+
+# The same barrier for a Top-N whose rank is a column of its output
+# (NEXmark q19: ROW_NUMBER() selected beside the row). A row then has to
+# be handed on again when its rank moved and nothing else of it did, so
+# the rank as handed on is kept beside ``shadow`` (``erank``, 0 = not
+# handed on), rides along ``_rank``'s sort beside the slot, and joins
+# the diff. One input row can now move up to k rows each way, so a delta
+# may pass the lanes its epoch's chunks held: the gathers are cut into
+# rounds of ``out_lanes`` (``start``, a device scalar: one program
+# whatever the round), every round computed from the SAME ranking. The
+# diff is this body and not the one above with switches all through it,
+# so that a Top-N that hands on no rank compiles what it always did.
+def _diff_gather_numbered(
+    table: HashTable,
+    rows: Dict[str, jnp.ndarray],
+    shadow: Dict[str, jnp.ndarray],
+    emitted: jnp.ndarray,
+    erank: jnp.ndarray,
+    ranked,
+    dropped: jnp.ndarray,
+    start: jnp.ndarray,
+    rank_col: str,
+    out_lanes: int,
+):
+    """``_diff_gather`` for a rank that is handed on as ``rank_col``
+    (BIGINT, 1-based): a row is retracted and inserted again when its
+    rank differs from the one it was handed on with, too. One round:
+    the retractions and the insertions numbered ``start`` to ``start +
+    out_lanes`` of the whole delta, which ``status`` counts; the host
+    asks for further rounds while a count passes what it has.
+
+    Rounds read nothing an earlier round wrote: the masks come from
+    ``ranked`` alone, a retraction's old values from ``shadow`` at
+    slots no other round writes (a row that is retracted AND inserted
+    has its shadow renewed by the round that retracts it, after the
+    gather; a row only inserted is retracted by none), and ``emitted``
+    / ``erank`` are cleared only for a row that leaves for good.
+
+    Returns (emitted, erank, shadow, retractions, insertions, status)
+    with status = [retract rows, insert rows, touched groups, rows
+    moved for their rank alone, dropped latch, slots claimed, live
+    rows, sorts made]."""
+    cap = table.capacity
+    packed_s, in_topk_s, seg_start, passes, erank_s = ranked
+    slot_s = packed_s & _SLOT_MASK
+    emitted_s = ((packed_s >> _EMITTED_BIT) & 1) > 0
+    dirty_s = ((packed_s >> _DIRTY_BIT) & 1) > 0
+    redo_s = ((packed_s >> _REDO_BIT) & 1) > 0
+    pos = jnp.arange(cap, dtype=jnp.int32)
+    rank_s = jnp.where(in_topk_s, pos - seg_start + 1, 0)
+    moved_s = emitted_s & in_topk_s & ~redo_s & (erank_s != rank_s)
+    again_s = redo_s | moved_s
+    ret_s = emitted_s & (~in_topk_s | again_s)
+    ins_s = in_topk_s & (~emitted_s | again_s)
+    groups = _touched_groups(dirty_s, seg_start)
+
+    ret_pos, n_ret, ret_valid = _compact(ret_s, out_lanes, start)
+    ins_pos, n_ins, ins_valid = _compact(ins_s, out_lanes, start)
+    ret_slot = jnp.where(ret_valid, slot_s[ret_pos], cap)
+    ins_slot = jnp.where(ins_valid, slot_s[ins_pos], cap)
+    # retracted and inserted again / gone for good / new downstream
+    both_slot = jnp.where(ins_s[ret_pos], ret_slot, cap)
+    gone_slot = jnp.where(ins_s[ret_pos], cap, ret_slot)
+    fresh_slot = jnp.where(ret_s[ins_pos], cap, ins_slot)
+    ret_cols = {n: a.at[ret_slot].get(mode="fill", fill_value=0)
+                for n, a in shadow.items()}
+    ins_cols = {n: a.at[ins_slot].get(mode="fill", fill_value=0)
+                for n, a in rows.items()}
+    ins_rank = jnp.where(ins_valid, rank_s[ins_pos], 0)
+    emitted = emitted.at[gone_slot].set(False, mode="drop")
+    emitted = emitted.at[ins_slot].set(True, mode="drop")
+    erank = erank.at[gone_slot].set(0, mode="drop")
+    erank = erank.at[ins_slot].set(ins_rank, mode="drop")
+    shadow = {
+        n: a.at[fresh_slot].set(ins_cols[n], mode="drop")
+        .at[both_slot].set(
+            rows[n].at[both_slot].get(mode="fill", fill_value=0), mode="drop"
+        )
+        for n, a in shadow.items()
+    }
+    ret_cols[rank_col] = jnp.where(ret_valid, erank_s[ret_pos], 0).astype(
+        jnp.int64
+    )
+    ins_cols[rank_col] = ins_rank.astype(jnp.int64)
+    status = jnp.stack(
+        [
+            n_ret,
+            n_ins,
+            groups,
+            jnp.sum(moved_s.astype(jnp.int32)),
+            dropped.astype(jnp.int32),
+            table.occupancy(),
+            table.num_live(),
+            passes,
+        ]
+    )
+    chunks = _delta_chunks(
+        ret_cols, ret_valid, ins_cols, ins_valid, out_lanes
+    )
+    return emitted, erank, shadow, chunks[0], chunks[1], status
 
 
 @jax.jit
@@ -678,15 +819,19 @@ def _any_differs(rows, shadow):
 
 
 @partial(jax.jit, static_argnames=("k", "descs", "n_group"))
-def _topk_mask(table: HashTable, order_lanes, k: int, descs, n_group: int):
-    """Per slot: is the row in its group's top-k (a restore's rebuild
-    of ``emitted``; the barrier never leaves the sorted order).
-    ``order_lanes`` / ``descs`` as ``_rank_sorted`` takes them."""
+def _topk_ranks(table: HashTable, order_lanes, k: int, descs, n_group: int):
+    """Per slot: the row's rank in its group where it is in the top-k,
+    else 0 (a restore's rebuild of ``emitted`` and ``erank``; the
+    barrier never leaves the sorted order). ``order_lanes`` / ``descs``
+    as ``_rank_sorted`` takes them."""
     cap = table.capacity
-    packed_s, in_topk_s, _, _ = _rank_sorted(
+    packed_s, in_topk_s, seg_start, _ = _rank_sorted(
         table, order_lanes, jnp.zeros(cap, jnp.int32), k, descs, n_group
     )
-    return jnp.zeros(cap, jnp.bool_).at[packed_s & _SLOT_MASK].set(in_topk_s)
+    rank_s = jnp.arange(cap, dtype=jnp.int32) - seg_start + 1
+    return jnp.zeros(cap, jnp.int32).at[packed_s & _SLOT_MASK].set(
+        jnp.where(in_topk_s, rank_s, 0)
+    )
 
 
 def emission_lanes(epoch_lanes: int, capacity: int) -> int:
@@ -713,7 +858,22 @@ class RetractableGroupTopNExecutor(Executor, Checkpointable):
     on (``emitted``; their values as handed on in ``shadow``) and
     gathers the rows to retract and to insert into two chunks of a
     declared size, in two programs (``_rank``, ``_diff_gather``). The
-    host reads eight counts a barrier and walks no row."""
+    host reads eight counts and the epoch's input rows a barrier, in
+    one read, and walks no row.
+
+    ``rank_col``: the name under which the row's rank in its group
+    (ROW_NUMBER(): BIGINT, 1-based, ties by the stream key) is handed
+    on as a column, or None. With it the rank as handed on is kept too
+    (``erank``), a row whose rank moved is retracted and inserted again
+    though nothing else of it changed (``_rank`` carries ``erank``
+    along its sort, ``_diff_gather`` takes ``_diff_gather_numbered``
+    for its body), and the second program runs once more for every
+    ``lanes`` rows by which a delta passes the chunks' size (a row that
+    enters at the top moves up to k - 1 others), the retractions of all
+    rounds handed on before the insertions. Without it either delta is
+    bounded by the lanes the epoch's chunks held, the pair runs once,
+    and the two programs are what they were before a rank could be a
+    column."""
 
     def __init__(
         self,
@@ -729,6 +889,7 @@ class RetractableGroupTopNExecutor(Executor, Checkpointable):
         bucket_policy: Optional[BucketPolicy] = None,
         bucketed: bool = True,
         upstream: str = "unknown",
+        rank_col: Optional[str] = None,
     ):
         self._buckets = (
             BucketAllocator(
@@ -737,6 +898,11 @@ class RetractableGroupTopNExecutor(Executor, Checkpointable):
             if bucketed
             else None
         )
+        if rank_col is not None and rank_col in schema_dtypes:
+            raise ValueError(
+                f"rank column {rank_col!r} is a column of the input"
+            )
+        self.rank_col = rank_col
         self.group_by = tuple(group_by)
         self.order: Tuple[Tuple[str, bool], ...] = (
             ((order_col, bool(desc)),)
@@ -778,8 +944,13 @@ class RetractableGroupTopNExecutor(Executor, Checkpointable):
         self.shadow = {
             n: jnp.zeros(capacity, self._dtypes[n]) for n in self.names
         }
-        # lanes of the chunks applied since the last barrier: what
-        # bounds either delta of the barrier (``_rank_diff``)
+        # the rank each row was handed on with, 0 = not handed on
+        # (derived like ``emitted``; only where the rank is a column)
+        self.erank = (
+            jnp.zeros(capacity, jnp.int32) if rank_col is not None else None
+        )
+        # lanes of the chunks applied since the last barrier: what the
+        # chunks a barrier hands on are sized from (``emission_lanes``)
         self._epoch_lanes = 0
         # valid rows of those chunks, counted on the device
         self._in_rows = jnp.zeros((), jnp.int32)
@@ -791,6 +962,11 @@ class RetractableGroupTopNExecutor(Executor, Checkpointable):
             + 2 * len(self.order) + 1 + 1
         )
         self._row_bytes = sum(d.itemsize for d in self._dtypes.values())
+        if rank_col is not None:
+            # the rank as handed on rides along every sort, and is a
+            # lane of the store
+            self._sort_operands += 1
+            self._row_bytes += self.erank.dtype.itemsize
         if window_key is not None and window_key[0] not in self.group_by:
             raise ValueError(
                 "window_key must be one of the group columns (a closed "
@@ -802,9 +978,13 @@ class RetractableGroupTopNExecutor(Executor, Checkpointable):
         self._dropped = jnp.zeros((), jnp.bool_)
 
     def lint_info(self):
+        emits = dict(self._dtypes)
+        if self.rank_col is not None:
+            # the rank lane: made here, no column of the input
+            emits[self.rank_col] = jnp.dtype(jnp.int64)
         return {
             "expects": dict(self._dtypes),
-            "emits": dict(self._dtypes),
+            "emits": emits,
             "renames": {n: n for n in self.names},
             "keys": self.group_by,
             "state_pk": tuple(self.store_keys),
@@ -827,12 +1007,15 @@ class RetractableGroupTopNExecutor(Executor, Checkpointable):
             "state": (self.table, self.rows),
             "donate": True,
             # the barrier ranks, diffs and gathers on the device and
-            # hands on two chunks of a declared size (emission_lanes:
-            # the sizes below are compiled when a graph-mode view is
-            # created, larger x4 steps when an epoch first needs one);
-            # the row store walks the allocator's declared lattice
+            # hands on chunks of a declared size (emission_lanes: the
+            # sizes below are compiled when a graph-mode view is
+            # created, larger x4 steps when an epoch first needs one):
+            # two of them, or with the rank a column (``rank_lane``)
+            # two a round, as many rounds as the delta takes at that
+            # size; the row store walks the allocator's declared lattice
             "emission": "bucketed",
             "emission_caps": self.emission_sizes(),
+            "rank_lane": self.rank_col,
             "window_buckets": (
                 self._buckets.lattice if self._buckets is not None else None
             ),
@@ -852,7 +1035,7 @@ class RetractableGroupTopNExecutor(Executor, Checkpointable):
             leaf.nbytes
             for leaf in jax.tree.leaves(
                 (self.table, self.rows, self.shadow, self.emitted,
-                 self.epoch_dirty, self.sdirty, self.stored)
+                 self.erank, self.epoch_dirty, self.sdirty, self.stored)
             )
         )
 
@@ -965,6 +1148,8 @@ class RetractableGroupTopNExecutor(Executor, Checkpointable):
             self.stored = move(self.stored)
             self.epoch_dirty = move(self.epoch_dirty)
             self.emitted = move(self.emitted)
+            if self.erank is not None:
+                self.erank = move(self.erank)
             self.table = new
             claimed = int(self.table.occupancy())
         self._bound = claimed
@@ -973,7 +1158,8 @@ class RetractableGroupTopNExecutor(Executor, Checkpointable):
         """The barrier's two programs at one emission size: the rank
         (one program a capacity), then the diff and the gathers (one an
         emission size). Returns (retractions, insertions, status on the
-        device)."""
+        device, the ranking: what a further round of a numbered Top-N
+        is computed from)."""
         ranked = _rank(
             self.table,
             self.rows,
@@ -984,7 +1170,10 @@ class RetractableGroupTopNExecutor(Executor, Checkpointable):
             self.desc,
             len(self.group_by),
             self.order_col,
+            self.erank,
         )
+        if self.rank_col is not None:
+            return self._round(ranked, 0, out_lanes) + (ranked,)
         self.emitted, self.shadow, ret, ins, status = _diff_gather(
             self.table,
             self.rows,
@@ -993,6 +1182,26 @@ class RetractableGroupTopNExecutor(Executor, Checkpointable):
             ranked,
             self._dropped,
             out_lanes,
+        )
+        return ret, ins, status, ranked
+
+    def _round(self, ranked, start: int, out_lanes: int):
+        """One round of a numbered Top-N's delta: the retractions and
+        the insertions from the ``start``-th on. (retractions,
+        insertions, status on the device)."""
+        (
+            self.emitted, self.erank, self.shadow, ret, ins, status
+        ) = _diff_gather(
+            self.table,
+            self.rows,
+            self.shadow,
+            self.emitted,
+            ranked,
+            self._dropped,
+            out_lanes,
+            erank=self.erank,
+            start=jnp.asarray(start, jnp.int32),
+            rank_col=self.rank_col,
         )
         return ret, ins, status
 
@@ -1005,12 +1214,14 @@ class RetractableGroupTopNExecutor(Executor, Checkpointable):
                 self._buckets.note_barrier(cap, self._bound)
             return []
         lanes = emission_lanes(self._epoch_lanes, cap)
+        numbered = self.rank_col is not None
         with span(
             "topn.rank", table_id=self.table_id, lanes=lanes, capacity=cap,
             order_keys=len(self.order), words=self._sort_operands,
-            row_bytes=self._row_bytes,
+            row_bytes=self._row_bytes, limit=self.limit,
+            rank_emitted=numbered,
         ):
-            ret, ins, status = self._rank_diff(lanes)
+            ret, ins, status, ranked = self._rank_diff(lanes)
             self.epoch_dirty = jnp.zeros_like(self.epoch_dirty)
             self._epoch_lanes = 0
             fed, self._in_rows = self._in_rows, jnp.zeros((), jnp.int32)
@@ -1019,9 +1230,17 @@ class RetractableGroupTopNExecutor(Executor, Checkpointable):
         with span("topn.pull", table_id=self.table_id) as sp:
             with device_read("topn.status", lanes=9):
                 status, fed = jax.device_get((status, fed))
-            (n_ret, n_ins, groups, overflow, dropped, claimed, live,
+            # (the fourth count: of a numbered Top-N the rows that moved
+            # for their rank alone; else whether a delta passed ``lanes``)
+            (n_ret, n_ins, groups, fourth, dropped, claimed, live,
              passes) = status.tolist()
-            sp.args.update(rows=n_ret + n_ins, groups=groups, passes=passes)
+            moved, overflow = (fourth, 0) if numbered else (0, fourth)
+            # a numbered delta beyond the chunks' size takes more rounds
+            rounds = max(1, -(-max(n_ret, n_ins) // lanes)) if numbered else 1
+            sp.args.update(
+                rows=n_ret + n_ins, groups=groups, passes=passes,
+                rank_moved_rows=moved, rounds=rounds,
+            )
         with span(
             "topn.diff",
             stage="topn_diff",
@@ -1029,6 +1248,8 @@ class RetractableGroupTopNExecutor(Executor, Checkpointable):
             groups=groups,
             retract_rows=n_ret,
             insert_rows=n_ins,
+            rank_moved_rows=moved,
+            rounds=rounds,
         ):
             self._bound = int(claimed)
             if self._buckets is not None:
@@ -1038,10 +1259,14 @@ class RetractableGroupTopNExecutor(Executor, Checkpointable):
                     "GroupTopN row store overflowed; grow capacity"
                 )
             if overflow:
+                # no rank handed on: a row that comes or goes moves at
+                # most one other across the top-k's edge, so this is a
+                # fault of the program and not a size to grow
                 raise RuntimeError(
                     f"{self.table_id}: a barrier's delta ({n_ret} "
                     f"retractions, {n_ins} insertions) passed the "
-                    f"{lanes} lanes its epoch's chunks held"
+                    f"{lanes} lanes its epoch's chunks held, which bound "
+                    "it where no rank is handed on"
                 )
             REGISTRY.counter("group_topn_touched_groups_total").inc(
                 groups, table_id=self.table_id
@@ -1052,12 +1277,24 @@ class RetractableGroupTopNExecutor(Executor, Checkpointable):
             emitted = REGISTRY.counter("group_topn_emitted_rows_total")
             emitted.inc(n_ret, table_id=self.table_id, op="retract")
             emitted.inc(n_ins, table_id=self.table_id, op="insert")
+            if numbered:
+                REGISTRY.counter("group_topn_rank_moved_rows_total").inc(
+                    moved, table_id=self.table_id
+                )
             REGISTRY.gauge("group_topn_rows").set(
                 float(live), table_id=self.table_id
             )
+            rets, inss = [ret], [ins]
+            for r in range(1, rounds):
+                ret, ins, _ = self._round(ranked, r * lanes, lanes)
+                rets.append(ret)
+                inss.append(ins)
             # retractions first: an UPDATE's old row leaves the view
-            # before its new one enters under the same key
-            return [c for c, n in ((ret, n_ret), (ins, n_ins)) if n]
+            # before its new one enters under the same key (so every
+            # round's retractions before any round's insertions)
+            return (
+                rets[: -(-n_ret // lanes)] + inss[: -(-n_ins // lanes)]
+            )
 
     def on_watermark(self, watermark):
         """Window-bounded groups expire silently below the watermark
@@ -1076,6 +1313,8 @@ class RetractableGroupTopNExecutor(Executor, Checkpointable):
         self.sdirty = self.sdirty | expired
         # closed groups leave ``emitted`` without a retraction
         self.emitted = self.emitted & ~expired
+        if self.erank is not None:
+            self.erank = jnp.where(expired, 0, self.erank)
         return watermark, []
 
     # -- checkpoint/restore (pk-keyed row store, plain-TopN layout) -------
@@ -1141,12 +1380,16 @@ class RetractableGroupTopNExecutor(Executor, Checkpointable):
         self._dropped = jnp.zeros((), jnp.bool_)
         # every group's current top-k stands downstream (the MV was
         # restored to exactly this view), with the values the rows hold
-        self.emitted = (
-            _topk_mask(
+        ranks = (
+            _topk_ranks(
                 table, tuple(rows[c] for c, _ in self.order), self.limit,
                 tuple(d for _, d in self.order), len(self.group_by),
             )
             if n
-            else jnp.zeros(cap, jnp.bool_)
+            else jnp.zeros(cap, jnp.int32)
         )
+        self.emitted = ranks > 0
+        if self.erank is not None:
+            # ... under the ranks the restored rows have
+            self.erank = ranks
         self.shadow = {nm: jnp.copy(a) for nm, a in rows.items()}

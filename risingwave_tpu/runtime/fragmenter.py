@@ -634,8 +634,12 @@ def _sharded_equiv(ex, mesh, stacked_out: bool = False):
     from risingwave_tpu.parallel.sharded_top_n import ShardedGroupTopN
 
     if isinstance(ex, RetractableGroupTopNExecutor):
-        # (the sharded twin ranks by one order key)
-        if ex.window_key is not None or len(ex.order) != 1:
+        # (the sharded twin ranks by one order key and hands on no rank)
+        if (
+            ex.window_key is not None
+            or len(ex.order) != 1
+            or ex.rank_col is not None
+        ):
             return None
         return ShardedGroupTopN(
             mesh,

@@ -745,6 +745,7 @@ def _explain_stream_join(sql: str, catalog) -> str:
             return (
                 f"RetractableGroupTopN group=[{', '.join(ex.group_by)}] "
                 f"order=[{order}, stream key] limit={ex.limit}"
+                + (f" rank={ex.rank_col}" if ex.rank_col else "")
             )
         pk = getattr(ex, "pk", None)
         name = type(ex).__name__.replace("Executor", "")
@@ -795,11 +796,12 @@ def _explain_topn(select: P.Select) -> str:
     order = ", ".join(
         o.name + (" DESC" if desc else "") for o, desc in shape.order
     )
+    rank = f" rank={shape.rank_name}" if shape.rank_selected else ""
     return (
         head
         + f"StreamScan {source} -> RowIdGen (a source with no key) -> "
         f"RetractableGroupTopN group=[{group}] order=[{order}, stream key] "
-        f"limit={shape.limit} -> Project -> Materialize\n"
+        f"limit={shape.limit}{rank} -> Project -> Materialize\n"
     )
 
 

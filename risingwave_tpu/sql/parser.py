@@ -107,7 +107,7 @@ class WindowTVF:
 @dataclass(frozen=True)
 class SubQuery:
     select: "Select"
-    alias: str
+    alias: Optional[str]
 
 
 @dataclass(frozen=True)
@@ -689,9 +689,9 @@ class Parser:
         if self.accept("op", "("):
             sel = self.select()
             self.expect("op", ")")
-            self.accept("kw", "as")
-            alias = self.expect("ident").value
-            return SubQuery(sel, alias)
+            # (the alias is optional, as in Flink's SQL and PostgreSQL
+            # 16: NEXmark q19 writes its derived table without one)
+            return SubQuery(sel, self._rel_alias())
         if self.peek().kind == "kw" and self.peek().value in ("tumble", "hop"):
             kind = self.next().value
             self.expect("op", "(")

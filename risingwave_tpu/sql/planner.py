@@ -653,20 +653,24 @@ class TopNShape:
     order: Tuple[Tuple[P.Ident, bool], ...]
     limit: int
     rank_name: str
+    # the outer select lists the rank (by name or through its ``*``):
+    # the GroupTopN hands it on as a column
+    rank_selected: bool = False
 
 
 def over_window_topn_shape(select: P.Select) -> Optional[TopNShape]:
     """The syntactic half of over_window_to_topn_rule.rs: is ``select``
 
         SELECT cols FROM (SELECT cols | *, row_number() OVER
-          (PARTITION BY g ORDER BY o [DESC], ...) AS rn FROM t) AS x
+          (PARTITION BY g ORDER BY o [DESC], ...) AS rn FROM t) [AS x]
         WHERE rn <= k      (also rn < k, rn = 1)
 
-    with the rank itself not selected? ``t`` is a table, a window TVF
-    or a join (a comma list's WHERE is its ON; NEXmark q9): the rule
-    does not care what lies under the window, the relation's stream key
-    is the rows' identity. The planner's rule and EXPLAIN both ask;
-    None = the window path."""
+    with the rank selected (by name, or through the outer ``*``:
+    NEXmark q19; the GroupTopN then hands it on as a column) or not?
+    ``t`` is a table, a window TVF or a join (a comma list's WHERE is
+    its ON; NEXmark q9): the rule does not care what lies under the
+    window, the relation's stream key is the rows' identity. The
+    planner's rule and EXPLAIN both ask; None = the window path."""
     f = select.from_
     if not (
         isinstance(f, P.SubQuery)
@@ -703,8 +707,7 @@ def over_window_topn_shape(select: P.Select) -> Optional[TopNShape]:
     ):
         return None
     rn_name = witem.alias or f"row_number_{wi}"
-    # the outer WHERE must be exactly a bound on rn; rn must not be
-    # selected (GroupTopN emits rows without a rank column)
+    # the outer WHERE must be exactly a bound on rn
     conjs = _split_and(select.where)
     k = None
     for c in conjs:
@@ -730,11 +733,22 @@ def over_window_topn_shape(select: P.Select) -> Optional[TopNShape]:
         k = bound if k is None else min(k, bound)
     if k is None or k < 1:
         return None
+    # the outer items are bare columns of the derived table, the rank
+    # among them or not, or its ``*`` (which lists the rank)
+    rank_selected = False
     for it in select.items:
-        if not isinstance(it.expr, P.Ident) or it.expr.name == rn_name:
+        if isinstance(it.expr, P.Star):
+            if it.expr.qualifier not in (None, f.alias):
+                return None
+            rank_selected = True
+        elif not isinstance(it.expr, P.Ident):
             return None
+        elif it.expr.name == rn_name:
+            rank_selected = True
     order = tuple((o, bool(desc)) for o, desc in w.order_by)
-    return TopNShape(tuple(w.partition_by), order, k, rn_name)
+    return TopNShape(
+        tuple(w.partition_by), order, k, rn_name, rank_selected
+    )
 
 
 class StreamPlanner:
@@ -1276,7 +1290,10 @@ class StreamPlanner:
 
         onto the retractable GroupTopN executor — per-group top-k
         maintenance is O(changed groups x k) per barrier where the
-        general over-window recomputes whole partitions. Returns None
+        general over-window recomputes whole partitions. ``cols`` may
+        name ``rn``: the executor then hands the rank on as a column
+        (NEXmark q19), and the view stays keyed by the rows' stream
+        key, so a shifted rank is an update in place. Returns None
         when the shape doesn't match (the window path handles it)."""
         shape = over_window_topn_shape(select)
         if shape is None:
@@ -1343,7 +1360,8 @@ class StreamPlanner:
     ):
         """The rule's executors over a bound relation: the retractable
         GroupTopN keyed by ``pk`` and the projection of the outer
-        select's columns (``amap``: what each stands for in ``schema``)
+        select's columns (``amap``: what each stands for in ``schema``;
+        the rank, where it is selected, is the executor's own column)
         with the stream key beside them. (executors, the output's
         schema, its key), or None when an outer item is no bare column
         of the relation."""
@@ -1351,6 +1369,20 @@ class StreamPlanner:
             RetractableGroupTopNExecutor,
         )
 
+        rank_col = shape.rank_name if shape.rank_selected else None
+        if rank_col in schema:
+            return None  # the rank's name is a column's: the window path
+        # the outer ``*``: the derived table's columns as its select
+        # lists them, the rank where the window call stands
+        star = list(amap)
+        if rank_col is not None and rank_col not in amap:
+            star.append(rank_col)
+        items: List[P.SelectItem] = []
+        for it in select.items:
+            if isinstance(it.expr, P.Star):
+                items += [P.SelectItem(P.Ident(c), None) for c in star]
+            else:
+                items.append(it)
         gt = RetractableGroupTopNExecutor(
             group_by=tuple(binder.resolve(c) for c in shape.partition_by),
             order_col=tuple(
@@ -1362,15 +1394,20 @@ class StreamPlanner:
             capacity=self.capacity,
             table_id=self._tid(name, "gtopn"),
             upstream=upstream,
+            rank_col=rank_col,
         )
         post: Dict[str, E.Expr] = {}
         out_schema: Dict[str, object] = {}
-        for it in select.items:
+        for it in items:
+            out = it.alias or it.expr.name
+            if it.expr.name == rank_col:
+                post[out] = E.col(rank_col)
+                out_schema[out] = jnp.dtype(jnp.int64)
+                continue
             src = amap.get(it.expr.name)
             if not isinstance(src, P.Ident):
                 return None
             incol = binder.resolve(src)
-            out = it.alias or it.expr.name
             post[out] = E.col(incol)
             out_schema[out] = schema[incol]
         out_pk = []

@@ -183,16 +183,28 @@ def test_the_sources_text_plans_onto_group_topn_not_the_window_path():
 
 def test_a_shape_the_rule_cannot_take_keeps_the_window_path():
     planner = StreamPlanner(_bid_catalog())
-    # the rank in the select list (Flink's q19 at k = 10): GroupTopN
-    # emits rows without a rank column
-    ranked = planner.plan(
-        "CREATE MATERIALIZED VIEW q19 AS SELECT auction, bidder, rank_number "
-        "FROM (SELECT *, ROW_NUMBER() OVER (PARTITION BY auction ORDER BY "
-        "price DESC) AS rank_number FROM bid) B WHERE rank_number <= 10"
+    ranked = (
+        "CREATE MATERIALIZED VIEW {name} AS SELECT auction, bidder, "
+        "rank_number FROM (SELECT *, {call} OVER (PARTITION BY auction ORDER "
+        "BY price DESC) AS rank_number FROM bid) B WHERE rank_number <= 10"
     )
-    kinds = [type(ex) for ex in ranked.pipeline.executors]
+    # rank() numbers ties alike: no Top-N of rows
+    tied = planner.plan(ranked.format(name="r19", call="RANK()"))
+    kinds = [type(ex) for ex in tied.pipeline.executors]
     assert GeneralOverWindowExecutor in kinds
     assert RetractableGroupTopNExecutor not in kinds
+    # the rank in the select list (Flink's q19 at k = 10) no longer
+    # takes that detour: the GroupTopN hands the rank on as a column
+    # (tests/test_nexmark_q19.py holds that plan to the reference)
+    numbered = planner.plan(ranked.format(name="q19", call="ROW_NUMBER()"))
+    kinds = [type(ex) for ex in numbered.pipeline.executors]
+    assert GeneralOverWindowExecutor not in kinds
+    (gt,) = [ex for ex in numbered.pipeline.executors
+             if isinstance(ex, RetractableGroupTopNExecutor)]
+    assert gt.rank_col == "rank_number" and gt.limit == 10
+    assert list(numbered.schema) == [
+        "auction", "bidder", "rank_number", "_row_id"
+    ]
 
 
 def test_explain_shows_the_topn_plan(tmp_path):

@@ -279,11 +279,19 @@ def test_a_window_column_the_select_does_not_list_rides_along():
     ]
 
 
-def test_shapes_the_rule_refuses_keep_the_general_over_window():
-    """The rank selected (R-m13) stays on the window path."""
+@pytest.mark.parametrize(
+    "call",
+    [
+        "RANK()",  # ties numbered alike: no Top-N of rows
+        "DENSE_RANK()",
+    ],
+)
+def test_shapes_the_rule_refuses_keep_the_general_over_window(call):
+    """What is no ROW_NUMBER() stays on the window path; the rank
+    selected (R-m13) no longer does (tests/test_nexmark_q19.py)."""
     sql = (
         "CREATE MATERIALIZED VIEW w AS SELECT auction, price, rn FROM "
-        "(SELECT auction, price, ROW_NUMBER() OVER (PARTITION BY auction "
+        f"(SELECT auction, price, {call} OVER (PARTITION BY auction "
         "ORDER BY price DESC) AS rn FROM bid) t WHERE rn <= 1"
     )
     planned = StreamPlanner(_catalog(AUCTION_DDL, BID_DDL)).plan(sql)
@@ -291,6 +299,12 @@ def test_shapes_the_rule_refuses_keep_the_general_over_window():
         isinstance(ex, GeneralOverWindowExecutor)
         for ex in planned.pipeline.executors
     )
+    numbered = StreamPlanner(_catalog(AUCTION_DDL, BID_DDL)).plan(
+        sql.replace(call, "ROW_NUMBER()")
+    )
+    kinds = [type(ex) for ex in numbered.pipeline.executors]
+    assert GeneralOverWindowExecutor not in kinds
+    assert RetractableGroupTopNExecutor in kinds
 
 
 def test_explain_shows_the_join_and_the_topn_behind_it(tmp_path):

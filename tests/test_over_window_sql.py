@@ -139,37 +139,46 @@ def test_top1_per_partition_over_one_table():
     assert list(out["bidder"]) == [8, 11]
 
 
-def test_over_window_to_topn_rule():
+@pytest.mark.parametrize("ranked", [False, True])
+def test_over_window_to_topn_rule(ranked):
     """row_number() ... WHERE rn <= k plans onto GroupTopN (the
     reference's over_window_to_topn_rule), not the general window
-    executor — per-group maintenance instead of partition recompute."""
+    executor — per-group maintenance instead of partition recompute —
+    whether the rank is selected (the executor hands it on as a column;
+    tests/test_nexmark_q19.py) or not."""
+    from risingwave_tpu.executors.over_window import (
+        GeneralOverWindowExecutor,
+    )
     from risingwave_tpu.executors.top_n_plain import (
         RetractableGroupTopNExecutor,
     )
 
+    cols = ("auction", "price") + (("rn",) if ranked else ())
     s = _session()
     s.execute(
-        "CREATE MATERIALIZED VIEW t2 AS SELECT auction, price FROM "
+        f"CREATE MATERIALIZED VIEW t2 AS SELECT {', '.join(cols)} FROM "
         "(SELECT auction, price, row_number() OVER "
         "(PARTITION BY auction ORDER BY price DESC) AS rn FROM bid) AS x "
         "WHERE rn <= 2"
     )
     planned = s.catalog.mvs["t2"]
-    assert any(
-        isinstance(e, RetractableGroupTopNExecutor)
-        for e in planned.pipeline.executors
-    ), [type(e).__name__ for e in planned.pipeline.executors]
+    kinds = [type(e) for e in planned.pipeline.executors]
+    assert RetractableGroupTopNExecutor in kinds, kinds
+    assert GeneralOverWindowExecutor not in kinds
+
+    def view():
+        out, _ = s.execute(f"SELECT {', '.join(cols)} FROM t2 ORDER BY price")
+        return sorted(zip(*(out[c].tolist() for c in cols)))
+
+    def rows(*ranked_rows):
+        return sorted(r if ranked else r[:2] for r in ranked_rows)
+
     s.execute(
         "INSERT INTO bid VALUES (1, 0, 10, 0), (1, 0, 30, 0), "
         "(1, 0, 20, 0), (2, 0, 5, 0)"
     )
-    out, _ = s.execute("SELECT auction, price FROM t2 ORDER BY price")
-    assert sorted(zip(out["auction"], out["price"])) == [
-        (1, 20), (1, 30), (2, 5),
-    ]
-    # a new maximum displaces the group's k-th row
+    assert view() == rows((1, 20, 2), (1, 30, 1), (2, 5, 1))
+    # a new maximum displaces the group's k-th row, and the row that
+    # stays moves down one
     s.execute("INSERT INTO bid VALUES (1, 0, 40, 0)")
-    out, _ = s.execute("SELECT auction, price FROM t2 ORDER BY price")
-    assert sorted(zip(out["auction"], out["price"])) == [
-        (1, 30), (1, 40), (2, 5),
-    ]
+    assert view() == rows((1, 30, 2), (1, 40, 1), (2, 5, 1))
