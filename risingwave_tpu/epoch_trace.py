@@ -180,7 +180,10 @@ class EpochTrace:
                                (classify_marks), the count and the
                                changed slots' tombstone bits read down
       checkpoint_stage.pull  — [checkpoint.pull] pull_rows: gather
-                               dispatch + the device->host copy
+                               dispatch + the device->host copies, one
+                               a piece and (dtype, row shape) of the
+                               table's lanes, all started before the
+                               first is awaited
       checkpoint_stage.dictionary — [checkpoint.dictionary] the session
                                dictionary's new strings, put as one
                                segment (0.0: none was new)

@@ -361,23 +361,31 @@ def pull_rows(
     power of two its count ever crossed. A table's ``Marks`` bring the
     pieces as ``classify_marks`` left them on the device, so the
     gathers' index makes no round trip through the host; a host array
-    of slots (a read, a table that keeps no marks) is uploaded.
+    of slots (a read, a table that keeps no marks) is uploaded. A
+    piece's lanes leave the device as one array a (dtype, row shape)
+    they share, and every copy is under way before the first is waited
+    for (``_pull``).
 
     While an executor's checkpoint delta is being pulled (``table_id``
     given, or the executor's own under ``_pull_delta``) the gathers'
     dispatch and the device->host copies they wait for are the span
-    ``checkpoint.pull``, with the rows and the padded rows it moved."""
+    ``checkpoint.pull``, with the rows and the padded rows it moved and
+    the device->host arrays it moved them in (``copies``, also the
+    counter ``checkpoint_pull_copies_total``)."""
     from risingwave_tpu.runtime.bucketing import delta_blocks
 
     n = len(sel)
     if n == 0:
-        return {k: np.asarray(a)[:0] for k, a in device_lanes.items()}
+        return {
+            k: np.zeros((0,) + a.shape[1:], a.dtype)
+            for k, a in device_lanes.items()
+        }
     block, blocks = delta_blocks(n)
     pad = block * blocks
     if table_id is None:
         table_id = getattr(_STAGING, "table_id", None)
     if table_id is None:  # a read, not a checkpoint
-        return _pull(device_lanes, sel, n, block)
+        return _pull(device_lanes, sel, n, block)[0]
     REGISTRY.counter("checkpoint_pull_rows_total").inc(n, table_id=table_id)
     REGISTRY.counter("checkpoint_pull_padded_rows_total").inc(
         pad, table_id=table_id
@@ -389,13 +397,30 @@ def pull_rows(
         rows=n,
         padded_rows=pad,
     ) as sp:
-        out = _pull(device_lanes, sel, n, block)
+        out, copies = _pull(device_lanes, sel, n, block)
+        sp.args.update(copies=copies)
+    REGISTRY.counter("checkpoint_pull_copies_total").inc(
+        copies, table_id=table_id
+    )
     _STAGING.pull_s = getattr(_STAGING, "pull_s", 0.0) + sp.dur
     return out
 
 
-def _pull(device_lanes, sel, n: int, block: int) -> Dict[str, np.ndarray]:
-    lanes = dict(device_lanes)
+def _pull(
+    device_lanes, sel, n: int, block: int
+) -> Tuple[Dict[str, np.ndarray], int]:
+    """The selected rows of every lane, and the device->host arrays they
+    came in: one a piece and group of lanes of one dtype and row shape
+    (a bucket join's (capacity, fanout) lanes are a group of their
+    own), stacked on the device by the piece's gather. No lane changes
+    its type on the way, so a row arrives bit for bit."""
+    groups: Dict[tuple, List[str]] = {}
+    for name, a in device_lanes.items():
+        groups.setdefault((a.dtype, a.shape[1:]), []).append(name)
+    stacked = tuple(
+        tuple(device_lanes[name] for name in names)
+        for names in groups.values()
+    )
     if isinstance(sel, Marks):
         pieces = sel.blocks
     else:
@@ -404,21 +429,30 @@ def _pull(device_lanes, sel, n: int, block: int) -> Dict[str, np.ndarray]:
         pieces = [
             jnp.asarray(idx[a : a + block]) for a in range(0, len(idx), block)
         ]
-    # every block's gather is enqueued before the first copy is awaited
-    parts = [_gather(lanes, piece) for piece in pieces]
-    with device_read(
-        "pull_rows",
-        bytes=sum(int(a.nbytes) for p in parts for a in p.values()),
-    ):
-        host = [{k: np.asarray(a) for k, a in p.items()} for p in parts]
-    if len(host) == 1:
-        return {k: a[:n] for k, a in host[0].items()}
-    return {k: np.concatenate([p[k] for p in host])[:n] for k in host[0]}
+    # every piece's gather is enqueued and every copy started before the
+    # first is awaited: the wait is the longest copy's, not their sum
+    parts = [_gather(stacked, piece) for piece in pieces]
+    copies = [a for part in parts for a in part]
+    for a in copies:
+        a.copy_to_host_async()
+    with device_read("pull_rows", bytes=sum(int(a.nbytes) for a in copies)):
+        host = [[np.asarray(a) for a in part] for part in parts]
+    out = {}
+    for g, names in enumerate(groups.values()):
+        buf = (
+            host[0][g] if len(host) == 1
+            else np.concatenate([part[g] for part in host], axis=1)
+        )
+        for i, name in enumerate(names):
+            out[name] = buf[i, :n]
+    return {name: out[name] for name in device_lanes}, len(copies)
 
 
 @jax.jit
-def _gather(lanes, idx):
-    return jax.tree.map(lambda a: a[idx], lanes)
+def _gather(groups, idx):
+    """``idx``'s rows of every lane, a group of lanes as one array
+    (lanes of the group, rows of ``idx``, a row's shape)."""
+    return [jnp.stack([a[idx] for a in lanes]) for lanes in groups]
 
 
 class Checkpointable:

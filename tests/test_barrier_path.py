@@ -173,6 +173,30 @@ def test_checkpoint_marks_says_what_it_classified_and_what_it_read(q8):
     assert tr.stages_ms["checkpoint_stage.marks"] == pytest.approx(own_ms)
 
 
+def test_checkpoint_pull_says_in_how_many_arrays_its_rows_left(q8):
+    """A table's rows leave the device in one array a piece and (dtype,
+    row shape) of its lanes: q8's bucket sides keep key lanes and
+    (capacity, fanout) lanes of three types, four arrays in all, its
+    dedups and tables fewer; each pull waits in one ``device.read``."""
+    copies = REGISTRY.counter("checkpoint_pull_copies_total")
+    before = copies.total()
+    TRACER.clear()
+    tr = q8.epoch()
+    spans = [sp for sp in TRACER.spans() if sp.epoch == tr.epoch]
+    pulls = [sp for sp in spans if sp.name == "checkpoint.pull"]
+    assert len(pulls) >= 3
+    for sp in pulls:
+        # 50 persons and 50 auctions an epoch: one piece of 256 lanes
+        assert sp.args["padded_rows"] == 256
+        assert 1 <= sp.args["copies"] <= 4
+        reads = [
+            k for k in spans if k.name == "device.read" and k.parent == sp.sid
+        ]
+        assert [k.args["what"] for k in reads] == ["pull_rows"]
+    assert max(sp.args["copies"] for sp in pulls) == 4  # a bucket side
+    assert copies.total() - before == sum(sp.args["copies"] for sp in pulls)
+
+
 def test_a_traced_rehearsal_of_q4_catchup_reports_marks_bytes_per_event():
     root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
     env = {**os.environ, "JAX_PLATFORMS": "cpu"}

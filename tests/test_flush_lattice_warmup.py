@@ -66,7 +66,8 @@ def test_the_warm_up_leaves_no_mark(tmp_path, monkeypatch):
     after CREATE MATERIALIZED VIEW, and a checkpoint stages nothing for
     either."""
     pulled = REGISTRY.counter("checkpoint_pull_rows_total")
-    before = pulled.total()
+    copied = REGISTRY.counter("checkpoint_pull_copies_total")
+    before, copies_before = pulled.total(), copied.total()
     warmed = Served(tmp_path / "warmed", 256)
     ran = [sp for sp in TRACER.spans() if sp.name == "actor.warm"]
     assert [sp.args["lanes"] for sp in ran[-2:]] == 2 * [[256, 2048, 8192]]
@@ -83,6 +84,7 @@ def test_the_warm_up_leaves_no_mark(tmp_path, monkeypatch):
         warmed.rt.barrier()
         warmed.rt.wait_checkpoints()
         assert pulled.total() == before
+        assert copied.total() == copies_before
         # after the first rows both plans hold the same
         assert _one_epoch(warmed) == warmed.read()
         assert _one_epoch(plain) == plain.read()
