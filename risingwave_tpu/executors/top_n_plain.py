@@ -14,8 +14,9 @@ so per-barrier host traffic is O(n), not O(state).
 
 from __future__ import annotations
 
+import math
 from functools import partial, reduce
-from typing import Dict, List, Optional, Sequence, Tuple, Union
+from typing import Dict, List, NamedTuple, Optional, Sequence, Tuple, Union
 
 import jax
 import jax.numpy as jnp
@@ -390,7 +391,7 @@ def _upsert_step_ed(table, rows, sdirty, epoch_dirty, chunk, pk, names):
 # ``emitted``), and that difference is two masks in the sorted order.
 # So the rows to retract and the rows to insert are compacted and
 # gathered on the device too (``_diff_gather``), into two chunks of
-# ``out_lanes`` lanes, and the host reads eight counts. ``shadow`` keeps
+# ``out_lanes`` lanes, and the host reads nine counts. ``shadow`` keeps
 # every column as it was when the row was last handed on: an UPDATE
 # overwrites a stored row in place, and its retraction has to carry the
 # old values.
@@ -541,18 +542,91 @@ def _rank_sorted(
     return (packed_s, in_topk_s, seg_start, passes) + tuple(carried_s)
 
 
-def _compact(mask, out_lanes: int, start=None):
-    """Positions of the first ``out_lanes`` set lanes of ``mask`` (in
-    order; of those after the first ``start``, where one is given), how
-    many are set in all, and which output lanes hold one."""
-    csum = jnp.cumsum(mask.astype(jnp.int32))
-    n = csum[-1]
-    want = jnp.arange(1, out_lanes + 1, dtype=jnp.int32)
-    if start is not None:
-        want = want + start
-    pos = jnp.searchsorted(csum, want, side="left").astype(jnp.int32)
-    valid = want <= n
-    return jnp.where(valid, pos, 0), n, valid
+# What the diff hands on is the set lanes of two masks over the sorted
+# order, in order, and a barrier's delta is a few thousand of a store's
+# millions of lanes. So a mask is counted in two levels once (a dense
+# pass: ``_WORD`` lanes a word, ``_ROW`` words a row, a running count
+# over the rows alone) and the wanted lanes are then found a block at a
+# time, each from its row's counts and its word's bits: nothing runs the
+# capacity's length but that one pass, and no search runs at all.
+_WORD = 32  # lanes of a mask in one word
+_ROW = 128  # words in one row of counts
+# Lanes a turn of the gathers' loops moves. A lane of a turn costs what
+# a row does whether it holds one (a scatter into a 64-bit lane some 50
+# ns a lane a column on a v5e), a turn some 2 ms before it moves a row:
+# at 8,192 the three NEXmark Top-Ns' second program ran 36.7 / 32.0 /
+# 43.0 ms, at 16,384 45.8 / 43.5 / 54.3, at 32,768 42.1 / 67.0 / 69.7
+# (PERF.md, PR 42).
+_GATHER_LANES = 1 << 13
+
+
+class _SetLanes(NamedTuple):
+    """A mask counted for ``_compact``."""
+
+    words: jnp.ndarray  # the mask, lane i the bit i % _WORD of word i // _WORD
+    counts: jnp.ndarray  # (rows, words a row): each word's set lanes
+    row_end: jnp.ndarray  # the set lanes up to each row's end
+
+    @property
+    def total(self):
+        return self.row_end[-1]
+
+
+def _mask_words(mask):
+    """``mask`` as words of ``_WORD`` lanes (of all its lanes, where it
+    has fewer), lowest lane in the lowest bit."""
+    width = min(_WORD, mask.shape[0])
+    bits = mask.reshape(-1, width).astype(jnp.uint32) << jnp.arange(
+        width, dtype=jnp.uint32
+    )
+    return jnp.sum(bits, axis=1, dtype=jnp.uint32)
+
+
+def _count_set(mask) -> _SetLanes:
+    """The one pass over a mask's whole length."""
+    words = _mask_words(mask)
+    counts = jax.lax.population_count(words).astype(jnp.int32)
+    counts = counts.reshape(-1, min(_ROW, counts.shape[0]))
+    return _SetLanes(words, counts, jnp.cumsum(jnp.sum(counts, axis=1)))
+
+
+def _nth_bit(word, nth):
+    """The position of the ``nth`` set bit (from 1, lowest first) of
+    each ``word``: five halvings by the count of the lower half."""
+    at = jnp.zeros_like(word)
+    for width in (16, 8, 4, 2, 1):
+        low = jax.lax.population_count(
+            (word >> at) & jnp.uint32((1 << width) - 1)
+        )
+        above = nth > low
+        nth = jnp.where(above, nth - low, nth)
+        at = jnp.where(above, at + width, at)
+    return at.astype(jnp.int32)
+
+
+def _compact(lanes: _SetLanes, out_lanes: int, start):
+    """Positions of the set lanes numbered ``start`` to ``start +
+    out_lanes`` (from 0, in order) of a counted mask, and which output
+    lanes hold one (the others read position 0)."""
+    want = jnp.arange(1, out_lanes + 1, dtype=jnp.int32) + start
+    valid = want <= lanes.total
+    # the row: the first whose end reaches the wanted lane; what lies
+    # before it is the largest end that does not
+    short = lanes.row_end[None, :] < want[:, None]
+    row = jnp.minimum(
+        jnp.sum(short, axis=1, dtype=jnp.int32), lanes.row_end.shape[0] - 1
+    )
+    want = want - jnp.max(jnp.where(short, lanes.row_end[None, :], 0), axis=1)
+    # the word: the same along the row's own counts
+    counts = lanes.counts[row]
+    short = jnp.cumsum(counts, axis=1) < want[:, None]
+    word = jnp.minimum(
+        jnp.sum(short, axis=1, dtype=jnp.int32), counts.shape[1] - 1
+    )
+    want = want - jnp.sum(jnp.where(short, counts, 0), axis=1)
+    word = row * counts.shape[1] + word
+    pos = word * _WORD + _nth_bit(lanes.words[word], want.astype(jnp.uint32))
+    return jnp.where(valid, pos, 0), valid
 
 
 @partial(jax.jit, static_argnames=("k", "desc", "n_group", "order_col"))
@@ -599,11 +673,54 @@ def _order_of(rows, order_col, desc):
 
 def _touched_groups(dirty_s, seg_start):
     """Groups (in sorted order) that hold a dirty row: the dirty rows
-    with no dirty row before them in their group."""
-    pos = jnp.arange(dirty_s.shape[0], dtype=jnp.int32)
-    last_dirty = jax.lax.cummax(jnp.where(dirty_s, pos, -1))
-    before = jnp.concatenate([jnp.full(1, -1, jnp.int32), last_dirty[:-1]])
-    return jnp.sum((dirty_s & (before < seg_start)).astype(jnp.int32))
+    with no dirty row before them in their group. The dirty row before
+    a lane is read off its word's lower bits, and where they hold none
+    off a running maximum over the words."""
+    words = _mask_words(dirty_s)
+    width = dirty_s.shape[0] // words.shape[0]
+    first = jnp.arange(words.shape[0], dtype=jnp.int32) * width
+
+    def last(bits):  # the highest set lane of each word; below 0: none
+        return 31 - jax.lax.clz(bits).astype(jnp.int32)
+
+    word_last = jnp.where(words != 0, first + last(words), -1)
+    earlier = jnp.concatenate(
+        [jnp.full(1, -1, jnp.int32), jax.lax.cummax(word_last)[:-1]]
+    )
+    below = words[:, None] & (
+        (jnp.uint32(1) << jnp.arange(width, dtype=jnp.uint32)) - 1
+    )
+    before = jnp.where(
+        below != 0, first[:, None] + last(below), earlier[:, None]
+    )
+    firsts = dirty_s.reshape(-1, width) & (
+        before < seg_start.reshape(-1, width)
+    )
+    return jnp.sum(firsts.astype(jnp.int32))
+
+
+def _in_turns(lanes: _SetLanes, out_lanes: int, start, turn, carry):
+    """``carry = turn(carry, at, pos, valid)`` over the set lanes
+    numbered ``start`` to ``start + out_lanes`` of a counted mask, a
+    block of ``_GATHER_LANES`` a turn (``at``: where the block lies in
+    the ``out_lanes``; ``pos``, ``valid``: ``_compact``'s), for as many
+    turns as hold a set lane: the trip count is a value on the device.
+    Returns (carry, the lanes the turns covered)."""
+    block = math.gcd(_GATHER_LANES, out_lanes)
+    turns = -(-jnp.clip(lanes.total - start, 0, out_lanes) // block)
+
+    def one(t, carry):
+        at = t * block
+        return turn(carry, at, *_compact(lanes, block, start + at))
+
+    return jax.lax.fori_loop(0, turns, one, carry), turns * block
+
+
+def _put(tree, block, at):
+    """``block``'s leaves written into ``tree``'s from lane ``at`` on."""
+    return jax.tree.map(
+        lambda a, b: jax.lax.dynamic_update_slice(a, b, (at,)), tree, block
+    )
 
 
 def _delta_chunks(ret_cols, ret_valid, ins_cols, ins_valid, out_lanes: int):
@@ -653,11 +770,15 @@ def _diff_gather(
     touched can differ, and a touched row displaces or promotes at most
     one other, so neither delta passes the lanes the epoch's chunks
     held: ``out_lanes`` is sized from that on the host, and ``status``
-    says if it ever did not hold (a raise, not a silent cut).
+    says if it ever did not hold (a raise, not a silent cut). The rows
+    are gathered a block a turn (``_in_turns``) for as many turns as
+    the delta has rows, not for ``out_lanes``: the lanes past them keep
+    their zeros.
 
     Returns (emitted, shadow, retractions, insertions, status) with
     status = [retract rows, insert rows, touched groups, overflow,
-    dropped latch, slots claimed, live rows, sorts made]."""
+    dropped latch, slots claimed, live rows, sorts made, lanes the
+    gathers' turns covered]."""
     if rank_col is not None:
         return _diff_gather_numbered(
             table, rows, shadow, emitted, erank, ranked, dropped, start,
@@ -672,21 +793,38 @@ def _diff_gather(
     ret_s = emitted_s & (~in_topk_s | redo_s)
     ins_s = in_topk_s & (~emitted_s | redo_s)
     groups = _touched_groups(dirty_s, seg_start)
+    ret_set, ins_set = _count_set(ret_s), _count_set(ins_s)
 
-    ret_pos, n_ret, ret_valid = _compact(ret_s, out_lanes)
-    ins_pos, n_ins, ins_valid = _compact(ins_s, out_lanes)
-    ret_slot = jnp.where(ret_valid, slot_s[ret_pos], cap)
-    ins_slot = jnp.where(ins_valid, slot_s[ins_pos], cap)
-    ret_cols = {n: a.at[ret_slot].get(mode="fill", fill_value=0)
-                for n, a in shadow.items()}
-    ins_cols = {n: a.at[ins_slot].get(mode="fill", fill_value=0)
-                for n, a in rows.items()}
-    emitted = emitted.at[ret_slot].set(False, mode="drop")
-    emitted = emitted.at[ins_slot].set(True, mode="drop")
-    shadow = {
-        n: a.at[ins_slot].set(ins_cols[n], mode="drop")
-        for n, a in shadow.items()
-    }
+    # every retraction reads ``shadow`` before an insertion renews it
+    def retract(carry, at, pos, valid):
+        emitted, cols, valids = carry
+        slot = jnp.where(valid, slot_s[pos], cap)
+        block = {n: a.at[slot].get(mode="fill", fill_value=0)
+                 for n, a in shadow.items()}
+        emitted = emitted.at[slot].set(False, mode="drop")
+        return emitted, _put(cols, block, at), _put(valids, valid, at)
+
+    def insert(carry, at, pos, valid):
+        emitted, shadow, cols, valids = carry
+        slot = jnp.where(valid, slot_s[pos], cap)
+        block = {n: a.at[slot].get(mode="fill", fill_value=0)
+                 for n, a in rows.items()}
+        emitted = emitted.at[slot].set(True, mode="drop")
+        shadow = {n: a.at[slot].set(block[n], mode="drop")
+                  for n, a in shadow.items()}
+        return emitted, shadow, _put(cols, block, at), _put(valids, valid, at)
+
+    empty = (
+        {n: jnp.zeros(out_lanes, a.dtype) for n, a in rows.items()},
+        jnp.zeros(out_lanes, jnp.bool_),
+    )
+    (emitted, ret_cols, ret_valid), ret_lanes = _in_turns(
+        ret_set, out_lanes, 0, retract, (emitted,) + empty
+    )
+    (emitted, shadow, ins_cols, ins_valid), ins_lanes = _in_turns(
+        ins_set, out_lanes, 0, insert, (emitted, shadow) + empty
+    )
+    n_ret, n_ins = ret_set.total, ins_set.total
     status = jnp.stack(
         [
             n_ret,
@@ -697,6 +835,7 @@ def _diff_gather(
             table.occupancy(),
             table.num_live(),
             passes,
+            ret_lanes + ins_lanes,
         ]
     )
     chunks = _delta_chunks(
@@ -745,7 +884,7 @@ def _diff_gather_numbered(
     Returns (emitted, erank, shadow, retractions, insertions, status)
     with status = [retract rows, insert rows, touched groups, rows
     moved for their rank alone, dropped latch, slots claimed, live
-    rows, sorts made]."""
+    rows, sorts made, lanes the gathers' turns covered]."""
     cap = table.capacity
     packed_s, in_topk_s, seg_start, passes, erank_s = ranked
     slot_s = packed_s & _SLOT_MASK
@@ -759,45 +898,67 @@ def _diff_gather_numbered(
     ret_s = emitted_s & (~in_topk_s | again_s)
     ins_s = in_topk_s & (~emitted_s | again_s)
     groups = _touched_groups(dirty_s, seg_start)
+    ret_set, ins_set = _count_set(ret_s), _count_set(ins_s)
 
-    ret_pos, n_ret, ret_valid = _compact(ret_s, out_lanes, start)
-    ins_pos, n_ins, ins_valid = _compact(ins_s, out_lanes, start)
-    ret_slot = jnp.where(ret_valid, slot_s[ret_pos], cap)
-    ins_slot = jnp.where(ins_valid, slot_s[ins_pos], cap)
-    # retracted and inserted again / gone for good / new downstream
-    both_slot = jnp.where(ins_s[ret_pos], ret_slot, cap)
-    gone_slot = jnp.where(ins_s[ret_pos], cap, ret_slot)
-    fresh_slot = jnp.where(ret_s[ins_pos], cap, ins_slot)
-    ret_cols = {n: a.at[ret_slot].get(mode="fill", fill_value=0)
-                for n, a in shadow.items()}
-    ins_cols = {n: a.at[ins_slot].get(mode="fill", fill_value=0)
-                for n, a in rows.items()}
-    ins_rank = jnp.where(ins_valid, rank_s[ins_pos], 0)
-    emitted = emitted.at[gone_slot].set(False, mode="drop")
-    emitted = emitted.at[ins_slot].set(True, mode="drop")
-    erank = erank.at[gone_slot].set(0, mode="drop")
-    erank = erank.at[ins_slot].set(ins_rank, mode="drop")
-    shadow = {
-        n: a.at[fresh_slot].set(ins_cols[n], mode="drop")
-        .at[both_slot].set(
-            rows[n].at[both_slot].get(mode="fill", fill_value=0), mode="drop"
-        )
-        for n, a in shadow.items()
-    }
-    ret_cols[rank_col] = jnp.where(ret_valid, erank_s[ret_pos], 0).astype(
-        jnp.int64
+    def retract(carry, at, pos, valid):
+        emitted, erank, shadow, cols, valids = carry
+        slot = jnp.where(valid, slot_s[pos], cap)
+        # retracted and inserted again / gone for good
+        both_slot = jnp.where(ins_s[pos], slot, cap)
+        gone_slot = jnp.where(ins_s[pos], cap, slot)
+        block = {n: a.at[slot].get(mode="fill", fill_value=0)
+                 for n, a in shadow.items()}
+        block[rank_col] = jnp.where(valid, erank_s[pos], 0).astype(jnp.int64)
+        emitted = emitted.at[gone_slot].set(False, mode="drop")
+        erank = erank.at[gone_slot].set(0, mode="drop")
+        shadow = {
+            n: a.at[both_slot].set(
+                rows[n].at[both_slot].get(mode="fill", fill_value=0),
+                mode="drop",
+            )
+            for n, a in shadow.items()
+        }
+        return (emitted, erank, shadow, _put(cols, block, at),
+                _put(valids, valid, at))
+
+    def insert(carry, at, pos, valid):
+        emitted, erank, shadow, cols, valids = carry
+        slot = jnp.where(valid, slot_s[pos], cap)
+        # new downstream: no round retracts it, so none reads its shadow
+        fresh_slot = jnp.where(ret_s[pos], cap, slot)
+        rank = jnp.where(valid, rank_s[pos], 0)
+        block = {n: a.at[slot].get(mode="fill", fill_value=0)
+                 for n, a in rows.items()}
+        emitted = emitted.at[slot].set(True, mode="drop")
+        erank = erank.at[slot].set(rank, mode="drop")
+        shadow = {n: a.at[fresh_slot].set(block[n], mode="drop")
+                  for n, a in shadow.items()}
+        block[rank_col] = rank.astype(jnp.int64)
+        return (emitted, erank, shadow, _put(cols, block, at),
+                _put(valids, valid, at))
+
+    empty = (
+        {n: jnp.zeros(out_lanes, a.dtype) for n, a in rows.items()}
+        | {rank_col: jnp.zeros(out_lanes, jnp.int64)},
+        jnp.zeros(out_lanes, jnp.bool_),
     )
-    ins_cols[rank_col] = ins_rank.astype(jnp.int64)
+    (emitted, erank, shadow, ret_cols, ret_valid), ret_lanes = _in_turns(
+        ret_set, out_lanes, start, retract, (emitted, erank, shadow) + empty
+    )
+    (emitted, erank, shadow, ins_cols, ins_valid), ins_lanes = _in_turns(
+        ins_set, out_lanes, start, insert, (emitted, erank, shadow) + empty
+    )
     status = jnp.stack(
         [
-            n_ret,
-            n_ins,
+            ret_set.total,
+            ins_set.total,
             groups,
             jnp.sum(moved_s.astype(jnp.int32)),
             dropped.astype(jnp.int32),
             table.occupancy(),
             table.num_live(),
             passes,
+            ret_lanes + ins_lanes,
         ]
     )
     chunks = _delta_chunks(
@@ -858,7 +1019,7 @@ class RetractableGroupTopNExecutor(Executor, Checkpointable):
     on (``emitted``; their values as handed on in ``shadow``) and
     gathers the rows to retract and to insert into two chunks of a
     declared size, in two programs (``_rank``, ``_diff_gather``). The
-    host reads eight counts and the epoch's input rows a barrier, in
+    host reads nine counts and the epoch's input rows a barrier, in
     one read, and walks no row.
 
     ``rank_col``: the name under which the row's rank in its group
@@ -1228,18 +1389,26 @@ class RetractableGroupTopNExecutor(Executor, Checkpointable):
         # ONE read for the counts, the latch, the occupancy and the
         # epoch's input rows; it waits for the rank
         with span("topn.pull", table_id=self.table_id) as sp:
-            with device_read("topn.status", lanes=9):
+            with device_read("topn.status", lanes=10):
                 status, fed = jax.device_get((status, fed))
             # (the fourth count: of a numbered Top-N the rows that moved
             # for their rank alone; else whether a delta passed ``lanes``)
             (n_ret, n_ins, groups, fourth, dropped, claimed, live,
-             passes) = status.tolist()
+             passes, gathered) = status.tolist()
             moved, overflow = (fourth, 0) if numbered else (0, fourth)
-            # a numbered delta beyond the chunks' size takes more rounds
+            # a numbered delta beyond the chunks' size takes more rounds,
+            # whose turns follow from the counts as the first's did
             rounds = max(1, -(-max(n_ret, n_ins) // lanes)) if numbered else 1
+            block = math.gcd(_GATHER_LANES, lanes)
+            gathered += sum(
+                -(-min(n - r * lanes, lanes) // block) * block
+                for r in range(1, rounds)
+                for n in (n_ret, n_ins)
+                if n > r * lanes
+            )
             sp.args.update(
                 rows=n_ret + n_ins, groups=groups, passes=passes,
-                rank_moved_rows=moved, rounds=rounds,
+                rank_moved_rows=moved, rounds=rounds, gather_lanes=gathered,
             )
         with span(
             "topn.diff",
@@ -1277,6 +1446,9 @@ class RetractableGroupTopNExecutor(Executor, Checkpointable):
             emitted = REGISTRY.counter("group_topn_emitted_rows_total")
             emitted.inc(n_ret, table_id=self.table_id, op="retract")
             emitted.inc(n_ins, table_id=self.table_id, op="insert")
+            REGISTRY.counter("group_topn_gathered_lanes_total").inc(
+                gathered, table_id=self.table_id
+            )
             if numbered:
                 REGISTRY.counter("group_topn_rank_moved_rows_total").inc(
                     moved, table_id=self.table_id
