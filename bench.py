@@ -170,7 +170,7 @@ def _shape_watch_begin():
         _BENCH_GOV = None
         return
     from risingwave_tpu.analysis.jax_sanitizer import SIGNATURES
-    from risingwave_tpu.runtime.bucketing import ShapeGovernor
+    from risingwave_tpu.runtime.shape_governor import ShapeGovernor
 
     SIGNATURES.start()
     _BENCH_GOV = ShapeGovernor()
@@ -191,7 +191,7 @@ def _shape_fields(prefix, executors):
     and the padding overhead of the bucketed state buffers
     (wasted-lane fraction — the price paid for shape stability)."""
     from risingwave_tpu.analysis.jax_sanitizer import SIGNATURES
-    from risingwave_tpu.runtime.bucketing import padding_stats
+    from risingwave_tpu.ops.bucketing import padding_stats
 
     out = {f"{prefix}_padding": padding_stats(executors)}
     if SIGNATURES.enabled:
@@ -787,7 +787,7 @@ def bench_q5_unified(epochs, events_per_epoch, chunk_events):
         barrier_times.append(time.perf_counter() - tb)
         _governor_tick(_expand(list(mv.pipeline.executors)))
     dt = time.perf_counter() - t0
-    # measured roofline (PROFILE.md "measured vs modeled"): HBM bytes
+    # measured roofline: HBM bytes
     # actually moved this run = chunks pushed + live executor state
     from risingwave_tpu.epoch_trace import chunk_nbytes, roofline
 
@@ -921,7 +921,7 @@ def bench_q5(args_epochs, events_per_epoch, chunk_events, agg_mode):
 
     from risingwave_tpu.array.chunk import StreamChunk
     from risingwave_tpu.executors.hop_window import hop_step_fn
-    from risingwave_tpu.parallel.sharded_agg import stack_chunks
+    from risingwave_tpu.array.chunk import stack_chunks
 
     cap = chunk_events  # bids per chunk <= events per chunk
     # one fused lax.scan per epoch: hop + agg over every chunk in ONE

@@ -28,7 +28,7 @@ import jax as _jax
 # SQL semantics demand real 64-bit integers (BIGINT ids in every Nexmark
 # stream) and real f64 accumulation (SUM over DOUBLE). Without this flag
 # jnp silently truncates int64 -> int32, which merges distinct group/join
-# keys (see ADVICE.md r1, high). XLA:TPU emulates 64-bit lanes with
+# keys. XLA:TPU emulates 64-bit lanes with
 # 32-bit pairs; the hot hash path bit-splits to u32 lanes up front, so
 # only wide aggregation payloads pay the emulation cost.
 #

@@ -53,6 +53,7 @@ from risingwave_tpu.analysis.shape_domain import (
     recompile_budget,
     trace_signature,
 )
+from risingwave_tpu.array.lattice import validate_lattice
 
 # ---------------------------------------------------------------------------
 # host-sync scanner: AST over an executor's hot methods
@@ -491,8 +492,6 @@ def classify_executor(
                 "re-trace the fused step without bound",
             )
         else:
-            from risingwave_tpu.runtime.bucketing import validate_lattice
-
             why = validate_lattice(wb)
             if why is not None:
                 blocker(

@@ -4,7 +4,7 @@ behind ``python -m risingwave_tpu lint``.
 
 Cost contract: ``lint_planned`` is pure host-side metadata walking —
 no tracing, no XLA — so the DDL path stays O(plan size), well under
-the 50ms/query budget (PROFILE.md has measured numbers). The deep
+the 50ms/query budget (tests/test_rwlint.py holds it). The deep
 sanitizer (``--deep``) traces jaxprs and is CLI/test-only.
 """
 

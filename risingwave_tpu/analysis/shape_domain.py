@@ -137,7 +137,7 @@ def push_lattice_specs(
 ) -> Tuple[ChunkSpec, ...]:
     """The spec at every width a chain of executors takes a host-built
     chunk built at ``spec.capacity`` lanes: the push lattice
-    (``runtime/bucketing.push_lattice``) where every executor declares
+    (``array/lattice.push_lattice``) where every executor declares
     it (``Executor.push_widths``), the full width alone where one does
     not. The same set ``StreamingRuntime.push`` cuts to: both read
     ``pipeline.chain_push_widths``."""

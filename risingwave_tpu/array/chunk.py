@@ -28,12 +28,13 @@ from __future__ import annotations
 
 from dataclasses import dataclass, field
 from functools import partial
-from typing import Dict, Mapping, Optional
+from typing import Dict, Mapping, Optional, Sequence
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 
+from risingwave_tpu.array.lattice import prefix_pad
 from risingwave_tpu.trace import device_read, span
 from risingwave_tpu.types import Schema, op_sign
 
@@ -182,11 +183,9 @@ class DataChunk:
         compact valid rows to the front (compact_pairs / agg flush), so
         this turns O(capacity) device->host copies into O(live) for a
         large chunk. A chunk of up to 2^16 lanes is copied whole
-        (bucketing.prefix_pad): its slice program does not depend on
+        (lattice.prefix_pad): its slice program does not depend on
         what an epoch holds; scattered-valid chunks degrade to the full
         copy, never worse."""
-        from risingwave_tpu.runtime.bucketing import prefix_pad
-
         # the copy that waits for the step that made the chunk
         with device_read("chunk.valid", lanes=self.valid.shape[0]):
             valid = np.asarray(self.valid)
@@ -393,3 +392,10 @@ def concat_chunks(chunks, capacity: Optional[int] = None) -> StreamChunk:
     ops = np.concatenate([d["__op__"] for d in nps])
     cap = capacity or max(1, len(ops))
     return StreamChunk.from_numpy(cols, cap, ops=ops, nulls=nulls or None)
+
+
+def stack_chunks(chunks: Sequence[StreamChunk]) -> StreamChunk:
+    """Stack per-shard chunks (same capacity/columns) into one stacked
+    chunk with a leading shard axis — the input format ShardedHashAgg
+    expects (each shard = one source split)."""
+    return jax.tree.map(lambda *xs: jnp.stack(xs), *chunks)

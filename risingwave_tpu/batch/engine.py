@@ -14,6 +14,7 @@ from typing import Dict
 import numpy as np
 
 from risingwave_tpu.array.chunk import DataChunk
+from risingwave_tpu.array.lattice import DELTA_SMALL, pow2_at_least
 from risingwave_tpu.executors.materialize import MaterializeExecutor
 from risingwave_tpu.sql import parser as P
 from risingwave_tpu.sql.planner import (
@@ -36,8 +37,6 @@ def _scan_cap(n: int) -> int:
     of two, never under the delta lattice's small size, so that a view
     that grows from 20 rows to 80 inside a run (a dashboard's probe of
     it, every 50 ms) does not meet a new program at 32 and at 64."""
-    from risingwave_tpu.runtime.bucketing import DELTA_SMALL, pow2_at_least
-
     return pow2_at_least(max(n, DELTA_SMALL))
 
 

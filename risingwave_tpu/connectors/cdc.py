@@ -200,7 +200,7 @@ class CdcBackfillExecutor(Checkpointable):
             )
         ]
 
-    def staged_or_live_delta(self) -> List[StateDelta]:
+    def _pull_delta(self) -> List[StateDelta]:
         return self.checkpoint_delta()
 
     def restore_state(self, table_id, key_cols, value_cols) -> None:

@@ -15,7 +15,7 @@ every stamp into the ``barrier_stage_ms{stage,fragment}`` histogram
 per-barrier HBM telemetry: bytes touched = device-state delta
 (utils_heap accounting) + chunk bytes moved, reported as achieved
 bandwidth vs the configured chip peak so every bench JSON carries a
-MEASURED roofline fraction (PROFILE.md "measured vs modeled").
+MEASURED roofline fraction.
 
 ``dump_stalls()`` is the q7-wedge forensic path: when a barrier exceeds
 its deadline, snapshot every thread's open span stack, each actor's
@@ -74,8 +74,7 @@ def hbm_peak_gbps(device_kind: Optional[str] = None) -> float:
 def roofline(bytes_touched: int, seconds: float, device_kind=None) -> Dict:
     """Measured achieved-bandwidth vs chip peak. ``bytes_touched`` is
     the accounted HBM traffic (state delta + chunks moved); ``seconds``
-    the wall time it moved in. Model ceiling lives in PROFILE.md; this
-    is the measured half."""
+    the wall time it moved in."""
     peak = hbm_peak_gbps(device_kind)
     bw = (bytes_touched / seconds / 1e9) if seconds > 0 else 0.0
     return {
@@ -508,7 +507,6 @@ def _runtime_snapshot(rt) -> Dict:
         "epoch": getattr(rt, "_epoch", None),
         "committed_epoch": rt.mgr.max_committed_epoch if rt.mgr else None,
         "inflight_commits": getattr(rt, "_inflight", 0),
-        "closer_queue": len(getattr(rt, "_closer_q", ())),
         # partial-recovery provenance: which fragments are fenced for a
         # deferred scoped recovery, and how many partials have run —
         # a wedge mid-partial-recovery is debuggable from this alone

@@ -40,7 +40,7 @@ Recording sites (grow as subsystems need them):
 - ``recompile_hazard`` — SignatureWatch saw a post-warmup novel
                        abstract input signature (shape escaped the
                        bucket lattice; RW-E403/E803 cross-reference)
-- ``shape_governor`` — runtime/bucketing.ShapeGovernor throttled a
+- ``shape_governor`` — runtime/shape_governor.ShapeGovernor throttled a
                        recompile storm: the named executor class was
                        pinned to its max bucket (reason
                        budget_exceeded | slow_device)

@@ -21,6 +21,7 @@ from dataclasses import dataclass
 from typing import List, Optional, Sequence, Tuple
 
 from risingwave_tpu.array.chunk import StreamChunk
+from risingwave_tpu.array.lattice import push_lattice
 
 
 @dataclass(frozen=True)
@@ -147,7 +148,7 @@ class Executor:
           (fixed/bucketed kinds).
         - ``flush_walks``: for an aggregate, the declared lengths of
           the list of touched slots its flush programs range over
-          (``bucketing.touched_lattice``).
+          (``lattice.touched_lattice``).
         - ``window_buckets``: for window-keyed executors, the declared
           bucket lattice of the per-window shape domain, or None =
           unbucketed (window churn re-traces without bound =>
@@ -192,7 +193,7 @@ class Executor:
         recompiles the fused program."""
         return None
 
-    # -- the push lattice (runtime/bucketing.push_lattice; PR 32) --------
+    # -- the push lattice (array/lattice.push_lattice; PR 32) --------
     # True on a stateful executor whose data path is one step a chunk
     # at the chunk's own width, and which knows ``warm``
     per_chunk_step = False
@@ -208,9 +209,6 @@ class Executor:
         head, a fused barrier program) takes the full width only. A
         fragment takes what every executor of it takes
         (``pipeline.chain_push_widths``)."""
-        # (deferred: the runtime package imports this module)
-        from risingwave_tpu.runtime.bucketing import push_lattice
-
         if self.per_chunk_step or self.pure_step() is not None:
             return push_lattice(capacity)
         return (int(capacity),)

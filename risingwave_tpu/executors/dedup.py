@@ -23,7 +23,7 @@ import numpy as np
 from risingwave_tpu.array.chunk import StreamChunk
 from risingwave_tpu.executors.base import Barrier, Executor, Watermark
 from risingwave_tpu.ops.hash_table import HashTable, first_occurrence_mask, lookup_or_insert, read_scalars, stage_scalars, set_live
-from risingwave_tpu.runtime.bucketing import (
+from risingwave_tpu.ops.bucketing import (
     BucketAllocator,
     BucketPolicy,
     needs_plan,
@@ -108,7 +108,7 @@ class AppendOnlyDedupExecutor(Executor, Checkpointable):
         self.stored = jnp.zeros(capacity, jnp.bool_)
         self.window_key = window_key
         # shape-stability: capacities drawn from a declared pow2
-        # lattice (runtime/bucketing) — ``bucketed=False`` is the
+        # lattice (ops/bucketing) — ``bucketed=False`` is the
         # legacy unbounded-rehash twin (tests, soak baselines)
         self._buckets = (
             BucketAllocator(

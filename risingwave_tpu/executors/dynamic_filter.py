@@ -31,10 +31,10 @@ import numpy as np
 from risingwave_tpu.array.chunk import StreamChunk
 from risingwave_tpu.executors.base import Barrier, Executor, Watermark
 from risingwave_tpu.ops.hash_table import HashTable, lookup_or_insert, read_scalars, stage_scalars, set_live
-from risingwave_tpu.runtime.bucketing import (
+from risingwave_tpu.array.lattice import emission_bucket
+from risingwave_tpu.ops.bucketing import (
     BucketAllocator,
     BucketPolicy,
-    emission_bucket,
     needs_plan,
     plan_capacity,
 )

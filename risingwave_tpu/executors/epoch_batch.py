@@ -33,10 +33,9 @@ from __future__ import annotations
 
 from typing import List, Sequence
 
-from risingwave_tpu.array.chunk import StreamChunk
+from risingwave_tpu.array.chunk import StreamChunk, stack_chunks
 from risingwave_tpu.executors.base import Barrier, Executor, Watermark
 from risingwave_tpu.executors.hash_agg import HashAggExecutor
-from risingwave_tpu.parallel.sharded_agg import stack_chunks
 
 
 class ComposedSteps:
@@ -228,11 +227,6 @@ class EpochBatchedAggExecutor(Executor):
         for p in self.prefix:
             p.finish_barrier()
         self.agg.finish_barrier()
-
-    def capture_checkpoint(self) -> None:
-        # pipelined barriers: the actor seals the wrapped agg's delta
-        # (the agg object is the one the checkpoint registry holds)
-        self.agg.capture_checkpoint()
 
 
 def _compose_lint_infos(infos):

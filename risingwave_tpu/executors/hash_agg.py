@@ -48,14 +48,13 @@ from risingwave_tpu.ops import minput as mi_ops
 from risingwave_tpu.ops.agg import AggCall, AggState
 from risingwave_tpu.ops.hash_table import HashTable, lookup, lookup_or_insert, stage_scalars, set_live
 from risingwave_tpu.metrics import REGISTRY
-from risingwave_tpu.runtime.bucketing import (
+from risingwave_tpu.array.lattice import (
     TOUCHED_MAX,
-    BucketAllocator,
-    BucketPolicy,
     flush_lattice,
     flush_lattice_pad,
     touched_lattice,
 )
+from risingwave_tpu.ops.bucketing import BucketAllocator, BucketPolicy
 from risingwave_tpu.trace import device_read, span
 
 GROW_AT = 0.5  # rehash when claimed slots may exceed this load factor
@@ -629,7 +628,7 @@ class HashAggExecutor(Executor, Checkpointable):
         # the interpreted flush cuts every delta chunk to one of these
         # sizes (_delta_to_chunk) — they ARE the declared bucket
         # lattice that keeps the windowed agg shape-stable; the fused
-        # programs use its two ends (bucketing.flush_pad)
+        # programs use its two ends (lattice.flush_pad)
         caps = self.flush_sizes()
         contract = {
             "kind": "device",
@@ -691,7 +690,7 @@ class HashAggExecutor(Executor, Checkpointable):
         }
 
     def padding_stats(self):
-        """Wasted-lane accounting (runtime/bucketing.padding_stats —
+        """Wasted-lane accounting (ops/bucketing.padding_stats —
         bench/PROFILE surface; reads device occupancy)."""
         import jax.numpy as jnp
 
@@ -1459,7 +1458,7 @@ class HashAggExecutor(Executor, Checkpointable):
             # every emitted row sits in the first 2*n_take slots (dirty
             # slots compact to the front); slice before handing on, to
             # the smallest size of the declared lattice that holds
-            # them (bucketing.flush_lattice: 256, a quarter, the full 2*out_cap).
+            # them (lattice.flush_lattice: 256, a quarter, the full 2*out_cap).
             # Every DOWNSTREAM device program (join step, aggregate
             # step, MV) compiles once per input capacity and walks
             # every lane of it, masked or not: a bare pow2 of the

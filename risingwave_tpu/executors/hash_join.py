@@ -42,7 +42,7 @@ from risingwave_tpu.array.chunk import StreamChunk
 from risingwave_tpu.executors.base import Barrier, Executor, Watermark
 from risingwave_tpu.ops.hash_table import read_scalars, stage_scalars
 from risingwave_tpu.ops.hash_table import lookup_or_insert, set_live
-from risingwave_tpu.runtime.bucketing import (
+from risingwave_tpu.ops.bucketing import (
     BucketAllocator,
     BucketPolicy,
     needs_plan,

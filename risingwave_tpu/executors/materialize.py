@@ -525,7 +525,7 @@ from dataclasses import dataclass
 from functools import partial
 
 from risingwave_tpu.ops.hash_table import HashTable, last_occurrence_mask, lookup_or_insert, stage_scalars
-from risingwave_tpu.runtime.bucketing import BucketAllocator, BucketPolicy
+from risingwave_tpu.ops.bucketing import BucketAllocator, BucketPolicy
 from risingwave_tpu.storage.state_table import (
     classify_marks,
     grow_pow2,
@@ -735,7 +735,7 @@ class DeviceMaterializeExecutor(MvDeviceReadMixin, Executor, Checkpointable):
         }
 
     def padding_stats(self):
-        """Wasted-lane accounting (runtime/bucketing.padding_stats —
+        """Wasted-lane accounting (ops/bucketing.padding_stats —
         bench/PROFILE surface; reads device occupancy)."""
         return {
             "capacity": self.table.capacity,

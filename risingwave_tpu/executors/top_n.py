@@ -41,7 +41,7 @@ from risingwave_tpu.ops.hash_table import (
     lookup_or_insert,
     set_live,
 )
-from risingwave_tpu.runtime.bucketing import (
+from risingwave_tpu.ops.bucketing import (
     BucketAllocator,
     BucketPolicy,
     needs_plan,

@@ -30,14 +30,16 @@ from risingwave_tpu.ops.hash_table import (
     lookup_or_insert,
     set_live,
 )
-from risingwave_tpu.runtime.bucketing import (
-    BucketAllocator,
-    BucketPolicy,
+from risingwave_tpu.array.lattice import (
     emission_bucket,
     lattice_between,
+    pow2_at_least,
+)
+from risingwave_tpu.ops.bucketing import (
+    BucketAllocator,
+    BucketPolicy,
     needs_plan,
     plan_capacity,
-    pow2_at_least,
 )
 from risingwave_tpu.storage.state_table import (
     Checkpointable,

@@ -550,7 +550,7 @@ class SqlSession:
     def _fusion_lint(self, planned, strict: bool) -> None:
         """Fusion-feasibility findings at CREATE-MV time (analysis/
         fusion_analyzer.py, shallow pass): STRICT BY DEFAULT now that
-        the bucketing layer exists (runtime/bucketing.py) — RW-E803
+        the bucketing layer exists (array/lattice.py, ops/bucketing.py) — RW-E803
         (unbucketed shape-polymorphic window, the class that wedges
         real TPUs) and RW-E806 (unsatisfiable declared lattice) refuse
         the DDL on window-keyed plans, same path as strict_lint; every

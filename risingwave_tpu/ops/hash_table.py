@@ -168,7 +168,7 @@ def lookup_or_insert(
             # scatter of the row index; the winner then writes fp + every
             # key lane uncontended. (Four independent scatters could pick
             # different winners per lane, leaving a torn chimera slot that
-            # matches no key and leaks capacity — ADVICE.md r1, medium.)
+            # matches no key and leaks capacity.)
             # Index lanes are EXPLICIT int32 (rwlint RW-E30x dtype
             # audit): weak python-int sentinels must never promote the
             # probe arithmetic under a different default-int regime.

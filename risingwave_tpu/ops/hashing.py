@@ -55,8 +55,8 @@ def _to_u32_lanes(col: jnp.ndarray) -> list[jnp.ndarray]:
     64-bit columns yield BOTH halves as separate lanes (lo, hi) so the
     full 64 bits of the key flow into every downstream mix — folding to a
     single u32 would make the "independent" fingerprints of ``hash128``
-    collide together for int64 ids, the most common key type in Nexmark
-    (ADVICE.md r1 weak #6). Everything downstream of this function is
+    collide together for int64 ids, the most common key type in Nexmark.
+    Everything downstream of this function is
     EXPLICITLY uint32: no 64-bit op may appear in the mixing chain.
     """
     if col.dtype == jnp.bool_:

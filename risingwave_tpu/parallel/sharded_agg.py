@@ -604,10 +604,3 @@ ShardedHashAgg.restore_state = _sharded_agg_restore_state
 ShardedHashAgg.state_nbytes = _sharded_agg_state_nbytes
 ShardedHashAgg.state_digest = _sharded_agg_state_digest
 ShardedHashAgg.state_nbytes_per_shard = stacked_state_nbytes_per_shard
-
-
-def stack_chunks(chunks: Sequence[StreamChunk]) -> StreamChunk:
-    """Stack per-shard chunks (same capacity/columns) into one stacked
-    chunk with a leading shard axis — the input format ShardedHashAgg
-    expects (each shard = one source split)."""
-    return jax.tree.map(lambda *xs: jnp.stack(xs), *chunks)

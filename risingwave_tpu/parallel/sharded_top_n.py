@@ -38,11 +38,12 @@ from risingwave_tpu.ops.hash_table import (
 )
 from risingwave_tpu.parallel.exchange import dest_shard, exchange_chunk
 from risingwave_tpu.parallel.sharded_join import (
+    double_bucket_cap,
     stack_for_mesh,
     stacked_state_nbytes_per_shard,
     track_bucket_cap,
 )
-from risingwave_tpu.runtime.bucketing import emission_bucket
+from risingwave_tpu.array.lattice import emission_bucket
 from risingwave_tpu.storage.state_table import (
     Checkpointable,
     StateDelta,
@@ -415,8 +416,6 @@ class ShardedGroupTopN(Executor, Checkpointable):
         return bool(jnp.any(self.dropped))
 
     def grow_for_replay(self) -> None:
-        from risingwave_tpu.parallel.sharded_join import double_bucket_cap
-
         cap = 2 * self.table.keys[0].shape[-1]
         double_bucket_cap(self)
         table1 = HashTable.create(

@@ -7,6 +7,15 @@
 from risingwave_tpu.blackbox import DeviceWedged
 from risingwave_tpu.runtime.pipeline import Pipeline, TwoInputPipeline
 from risingwave_tpu.runtime.runtime import StreamingRuntime
+from risingwave_tpu.runtime.arrangements import ArrangementRegistry
+from risingwave_tpu.runtime.fused_step import (
+    FusedChainExecutor,
+    fuse_chain,
+    fuse_pipeline,
+)
+from risingwave_tpu.runtime.notification import NotificationHub
+from risingwave_tpu.runtime.source_manager import SourceManager
+from risingwave_tpu.runtime.dml import DmlManager
 
 __all__ = [
     "ArrangementRegistry",
@@ -21,46 +30,3 @@ __all__ = [
     "fuse_chain",
     "fuse_pipeline",
 ]
-
-# Lazy (PEP 562) exports: DmlManager pulls in the SQL planner, which
-# imports the executors package — and executors now import
-# runtime.bucketing at module level (the shape-stability layer), so an
-# eager import here would close a cycle through a partially
-# initialized executors package.
-_LAZY = {
-    "ArrangementRegistry": (
-        "risingwave_tpu.runtime.arrangements",
-        "ArrangementRegistry",
-    ),
-    "DmlManager": ("risingwave_tpu.runtime.dml", "DmlManager"),
-    # the fused per-barrier step imports the executors package (it
-    # composes their pure steps), so it must stay lazy here too
-    "FusedChainExecutor": (
-        "risingwave_tpu.runtime.fused_step",
-        "FusedChainExecutor",
-    ),
-    "fuse_chain": ("risingwave_tpu.runtime.fused_step", "fuse_chain"),
-    "fuse_pipeline": (
-        "risingwave_tpu.runtime.fused_step",
-        "fuse_pipeline",
-    ),
-    "SourceManager": (
-        "risingwave_tpu.runtime.source_manager",
-        "SourceManager",
-    ),
-    "NotificationHub": (
-        "risingwave_tpu.runtime.notification",
-        "NotificationHub",
-    ),
-}
-
-
-def __getattr__(name):
-    entry = _LAZY.get(name)
-    if entry is None:
-        raise AttributeError(name)
-    import importlib
-
-    value = getattr(importlib.import_module(entry[0]), entry[1])
-    globals()[name] = value
-    return value

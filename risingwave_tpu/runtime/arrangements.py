@@ -553,10 +553,7 @@ class ArrangementRegistry:
         if not self._live:
             return
         rt = self._runtime_ref()
-        if rt is None or rt.in_flight_barriers > 1:
-            # pipelined barriers close in the closer lane without the
-            # runtime lock — versioned serving is a serial-clock
-            # feature (sessions always run in_flight=1)
+        if rt is None:
             return
         gen = rt._write_gen
         if self.shed_eager:

@@ -167,10 +167,7 @@ class PartitionedStateView(Checkpointable):
         by_tid: Dict[str, List[StateDelta]] = {}
         order: List[str] = []
         for inst in self._instances:
-            # instances capture per-epoch deltas in their actor threads
-            # under pipelined barriers; consume those (epoch order) or
-            # fall back to a live pull in synchronous mode
-            for d in inst.staged_or_live_delta():
+            for d in inst._pull_delta():
                 if d.table_id not in by_tid:
                     order.append(d.table_id)
                 by_tid.setdefault(d.table_id, []).append(d)
@@ -239,10 +236,6 @@ class PartitionedStateView(Checkpointable):
             fn = getattr(i, "discard_pending", None)
             if fn is not None:
                 fn()
-
-    def discard_captured(self) -> None:
-        for i in self._instances:
-            i.discard_captured()
 
     def on_recover(self, epoch: int) -> None:
         for i in self._instances:
@@ -355,7 +348,6 @@ class GraphPipeline(FreshnessSurface):
             self._specs, epoch_batch=self._epoch_batch, label=self._label
         ).start()
         self.graph._epoch = self._epoch
-        self.graph.capture_deltas = getattr(self, "_capture", False)
 
     def set_label(self, name: str) -> None:
         """The name the runtime registered this pipeline under: its
@@ -500,7 +492,7 @@ class GraphPipeline(FreshnessSurface):
         self._sample_freshness((time.perf_counter() - t0) * 1e3)
         return outs
 
-    # -- pipelined barriers (in-flight epochs, barrier/mod.rs:538) -------
+    # -- the barrier's two halves: inject, then wait ---------------------
     def barrier_nowait(
         self, checkpoint: bool = True, epoch: Optional[int] = None
     ) -> int:
@@ -529,12 +521,6 @@ class GraphPipeline(FreshnessSurface):
         if MESHPROF.enabled:
             MESHPROF.pipeline_barrier(self)
         return outs
-
-    def set_capture(self, enabled: bool) -> None:
-        """Actors seal checkpoint deltas at the barrier (pipelined
-        checkpointing); survives ``rebuild``."""
-        self._capture = enabled
-        self.graph.capture_deltas = enabled
 
     def close(self) -> None:
         self.graph.stop()

@@ -47,7 +47,7 @@ from typing import List, Optional, Sequence, Tuple
 import jax
 import jax.numpy as jnp
 
-from risingwave_tpu.array.chunk import StreamChunk
+from risingwave_tpu.array.chunk import StreamChunk, stack_chunks
 from risingwave_tpu.executors.base import Barrier, Executor, Watermark
 from risingwave_tpu.executors.dedup import (
     AppendOnlyDedupExecutor,
@@ -77,10 +77,10 @@ from risingwave_tpu.executors.materialize import (
 from risingwave_tpu import integrity
 from risingwave_tpu.expr.expr import StaticTree, lift_literals, param_scope
 from risingwave_tpu.ops import agg as agg_ops
-from risingwave_tpu.parallel.sharded_agg import stack_chunks
+from risingwave_tpu.ops.bucketing import padding_fraction
 from risingwave_tpu.trace import span
 from risingwave_tpu.profiler import PROFILER
-from risingwave_tpu.runtime.bucketing import flush_pad_schedule
+from risingwave_tpu.array.lattice import flush_pad_schedule
 
 __all__ = [
     "FusedChainExecutor",
@@ -701,10 +701,8 @@ class FusedChainExecutor(Executor):
             # padded-lane waste over the members' state tables, from
             # the occupancies that rode the packed read (live lanes)
             # weighted by each member's state bytes — the live/capacity
-            # accounting runtime/bucketing.padding_stats reads from the
+            # accounting ops/bucketing.padding_stats reads from the
             # device, here for free
-            from risingwave_tpu.runtime.bucketing import padding_fraction
-
             pad_frac = padding_fraction(
                 (ex.table.capacity, occupancy[key], ex.state_nbytes())
                 for key, ex in (("agg", self.agg), ("mv", self.mv))
@@ -846,12 +844,6 @@ class FusedChainExecutor(Executor):
         except Exception:  # noqa: BLE001 — observability never faults
             pass
 
-    def capture_checkpoint(self) -> None:
-        for m in self.members:
-            cap = getattr(m, "capture_checkpoint", None)
-            if cap is not None:
-                cap()
-
     # -- the program ------------------------------------------------------
     def _run(self, flush: bool, stage: bool) -> List[StreamChunk]:
         buf, self._buf, self._sig = self._buf, [], None
@@ -899,10 +891,10 @@ class FusedChainExecutor(Executor):
             out_cap = self.plan.agg.out_cap
             bound = min(self.agg._dirty_bound, self.agg.table.capacity)
             flush_rounds = max(1, -(-bound // out_cap))
-            # the fused pads: {small, full} (bucketing.flush_pad's
+            # the fused pads: {small, full} (lattice.flush_pad's
             # rule), from the host dirty bound. The interpreted
             # _flush_all used to share this pair; since PR 30 it cuts
-            # to bucketing.flush_lattice from the exact count it
+            # to lattice.flush_lattice from the exact count it
             # reads. This program knows only the bound, too loose to
             # pick a small size, and bakes every round's pad into one
             # executable, so it keeps the pair: a fragment is either
@@ -1127,7 +1119,7 @@ def _fused_two_input_fn(
                   agg's flatten+reduce epoch path);
     flush phase — ``flush_rounds`` device flushes of the agg's dirty
                   groups, each delta PADDED TO A LATTICE BUCKET with a
-                  validity mask (runtime/bucketing.flush_pad — the
+                  validity mask (array/lattice.flush_pad — the
                   "padded flush made the join 80x slower" objection
                   predates masked lanes: the join's probe/build kernels
                   treat masked rows as provably inert, so the pad costs
@@ -1529,12 +1521,6 @@ class FusedTwoInputExecutor(Executor):
                 m.finish_barrier()  # no-op: members never stage here
             del retired  # the fence above ran: retiring is a plain free
 
-    def capture_checkpoint(self) -> None:
-        for m in self.members:
-            cap = getattr(m, "capture_checkpoint", None)
-            if cap is not None:
-                cap()
-
     def lint_info(self):
         return None  # the pipeline's chains stay the lint surface
 
@@ -1642,8 +1628,6 @@ class FusedTwoInputExecutor(Executor):
                 occupancy["right"] = side_occ(self.r_stateful, slices["r"])
             if self.mv is not None:
                 occupancy["mv"] = int(slices["mv"][1])
-            from risingwave_tpu.runtime.bucketing import padding_fraction
-
             def nbytes(ex):
                 return sum(
                     leaf.nbytes

@@ -601,14 +601,6 @@ class FragmentActor(threading.Thread):
                 for ex in self.executors:
                     ex.finish_barrier()
                 self._note_edge_rows(fence)
-            if b.checkpoint and self.mgr.capture_deltas:
-                # pipelined barriers: seal this epoch's delta NOW, before
-                # any next-epoch chunk in the input queue mutates state
-                # (shared-buffer seal; uploader.rs:548 overlap analogue)
-                for ex in self.executors:
-                    cap = getattr(ex, "capture_checkpoint", None)
-                    if cap is not None:
-                        cap()
             self.dispatcher.control(BARRIER, b)
         self.mgr._collect(self.actor_name, b, self._epoch_sums())
 
@@ -921,9 +913,6 @@ class GraphRuntime:
         # the owning pipeline's name in its runtime: makes actor names
         # unique in stage keys (two graphs may both have a "mv#0")
         self.label = label
-        # pipelined barriers: actors seal checkpoint deltas at the
-        # barrier instead of the runtime staging after a full drain
-        self.capture_deltas = False
         self.actors: List[FragmentActor] = []
         self.collectors: Dict[str, _Collector] = {}
         self._source_channels: Dict[str, List[PermitChannel]] = {}
@@ -1511,7 +1500,7 @@ class GraphRuntime:
             for a in self.actors:
                 a.join(timeout=5.0)
         # wake anyone blocked in wait_barrier on an epoch this graph
-        # will never collect (a pipelined closer during recovery)
+        # will never collect
         with self._collect_lock:
             if self._failure is None and self._collected:
                 self._failure = RuntimeError("graph stopped")

@@ -25,8 +25,9 @@ from risingwave_tpu.analysis.jax_sanitizer import SIGNATURES, transfer_guard
 from risingwave_tpu.array.chunk import StreamChunk
 from risingwave_tpu.blackbox import RECORDER
 from risingwave_tpu.executors.base import Barrier, Epoch, Executor, Watermark
+from risingwave_tpu.parallel.meshprof import MESHPROF
 from risingwave_tpu.profiler import PROFILER
-from risingwave_tpu.runtime.bucketing import push_lattice
+from risingwave_tpu.array.lattice import push_lattice
 from risingwave_tpu.trace import bound, span
 
 
@@ -145,7 +146,7 @@ def warm_chain(chain: Sequence[Executor], chunks, tap=None):
 def chain_push_widths(chain: Sequence[Executor], capacity: int):
     """The widths at which every executor of ``chain`` takes a
     host-built chunk built at ``capacity`` lanes: the push lattice
-    (``bucketing.push_lattice``) where each declares it
+    (``lattice.push_lattice``) where each declares it
     (``Executor.push_widths``), else the full width alone."""
     widths = set(push_lattice(capacity))
     for ex in chain:
@@ -242,11 +243,7 @@ class Pipeline(FreshnessSurface):
         # EpochTrace instead
         RECORDER.record_pipeline_barrier(self._epoch, walk_ms, fence_ms)
         # mesh observability: close this pipeline's per-shard window
-        # (no-op unless MESHPROF is armed and watched this chain; the
-        # import is deferred — meshprof pulls in the parallel package,
-        # which imports the executors this module's package feeds)
-        from risingwave_tpu.parallel.meshprof import MESHPROF
-
+        # (no-op unless MESHPROF is armed and watched this chain)
         if MESHPROF.enabled:
             MESHPROF.pipeline_barrier(self)
         return pending
@@ -426,8 +423,6 @@ class TwoInputPipeline(FreshnessSurface):
         walk_ms, fence_ms = walk.dur * 1e3, fence.dur * 1e3
         self._sample_freshness(walk_ms + fence_ms)
         RECORDER.record_pipeline_barrier(self._epoch, walk_ms, fence_ms)
-        from risingwave_tpu.parallel.meshprof import MESHPROF
-
         if MESHPROF.enabled:
             MESHPROF.pipeline_barrier(self)
         return outs

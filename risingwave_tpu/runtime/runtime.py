@@ -71,6 +71,9 @@ from risingwave_tpu.trace import (
     span,
     whole_call,
 )
+from risingwave_tpu.executors.materialize import DeviceMaterializeExecutor, MaterializeExecutor
+from risingwave_tpu.parallel.meshprof import MESHPROF
+from risingwave_tpu.runtime.shape_governor import ShapeGovernor
 from risingwave_tpu.storage.object_store import ObjectStore
 from risingwave_tpu.storage.state_table import CheckpointManager
 
@@ -136,7 +139,6 @@ class StreamingRuntime:
         compact_at: int = 8,
         memory_budget_bytes: Optional[int] = None,
         auto_recover: bool = False,
-        in_flight_barriers: int = 1,
         retry_policy: Optional[RetryPolicy] = None,
         breaker: Optional[CircuitBreaker] = None,
         degraded_dir: Optional[str] = None,
@@ -152,13 +154,11 @@ class StreamingRuntime:
         # (serve without --config, compute_node, direct construction),
         # not only from_config; a no-op when the env vars are unset
         blackbox.from_env()
-        # recompile-storm governor (runtime/bucketing.py): per-barrier
+        # recompile-storm governor (runtime/shape_governor.py): per-barrier
         # SignatureWatch hazard deltas vs RW_FUSION_RECOMPILE_BUDGET;
         # over budget (or ANY hazard while the device sentinel reports
         # SLOW) pins the offending executors to their max bucket. Own
         # instance per runtime — pin state never leaks across runtimes.
-        from risingwave_tpu.runtime.bucketing import ShapeGovernor
-
         self.shape_governor = ShapeGovernor()
         # HBM memory governor + overload ladder (runtime/
         # memory_governor.py): global device-state ledger enforcing
@@ -194,13 +194,6 @@ class StreamingRuntime:
                 # management (its governor still consumes deltas)
                 self._shape_watch_warmup = 0
             else:
-                # pipelined runtimes admit barrier N while epochs
-                # N-k..N-1 are still executing in the closer lane:
-                # stretch warmup by the in-flight depth so mark_stable
-                # only fires once every warmup epoch has actually run
-                # (admission control proves barrier N closed before
-                # N+k is admitted)
-                self._shape_watch_warmup += max(0, in_flight_barriers - 1)
                 SIGNATURES.start()
         # state >> HBM control (the reference's LRU memory controller,
         # src/compute/src/memory/controller.rs role): when accounted
@@ -345,19 +338,6 @@ class StreamingRuntime:
         self._compact_idle.set()
         self.compaction_errors: List[BaseException] = []
         self._work_abort = threading.Event()
-        # pipelined barriers (barrier/mod.rs:538 in_flight_barrier_nums):
-        # barrier() returns at ADMISSION (inject only); a closer thread
-        # waits for collection, stages the actor-sealed deltas, and
-        # feeds the async commit lane — up to ``in_flight_barriers``
-        # epochs overlap. Requires graph-backed fragments and no
-        # subscription edges (validated at the first pipelined barrier).
-        self.in_flight_barriers = max(1, in_flight_barriers)
-        self._closer_q: deque = deque()
-        self._closer_cv = threading.Condition()
-        self._closer: Optional[threading.Thread] = None
-        self._closer_err: List[BaseException] = []
-        self._closer_abort = threading.Event()
-        self.epoch_close_ms: List[float] = []  # admission -> closed
         # serializes barrier/DDL/DML against a background barrier clock
         # (the CLI's tick thread vs pgwire sessions — the reference
         # serializes via the meta barrier scheduler's command queue)
@@ -367,7 +347,6 @@ class StreamingRuntime:
         # the recent history for /events-style inspection and bench
         self.epoch_traces: deque = deque(maxlen=256)
         self.last_epoch_trace: Optional[EpochTrace] = None
-        self._traces_by_epoch: Dict[int, EpochTrace] = {}
         # stage sums of the pushes since the last barrier (the open
         # epoch has no EpochTrace yet): _begin_trace folds them in
         self._open_stages = StageSums()
@@ -441,10 +420,7 @@ class StreamingRuntime:
                     ex.cold_get_rows = self.mgr.get_rows
         # mesh observability: instrument sharded chains as they come up
         # (no-op unless MESHPROF is armed AND the chain carries sharded
-        # executors — serial fragments stay byte-for-byte untouched;
-        # deferred import, same cycle as runtime/__init__'s lazy list)
-        from risingwave_tpu.parallel.meshprof import MESHPROF
-
+        # executors — serial fragments stay byte-for-byte untouched)
         if MESHPROF.enabled:
             MESHPROF.watch(pipeline, name=name)
         if upstream is not None:
@@ -563,11 +539,6 @@ class StreamingRuntime:
                     m[new] = m.pop(old)
 
     def _fragment_mview(self, name: str):
-        from risingwave_tpu.executors.materialize import (
-            DeviceMaterializeExecutor,
-            MaterializeExecutor,
-        )
-
         for ex in reversed(self.fragments[name].executors):
             if isinstance(
                 ex, (MaterializeExecutor, DeviceMaterializeExecutor)
@@ -680,7 +651,7 @@ class StreamingRuntime:
         self._ingest_bytes += chunk_nbytes(chunk)
         return outs
 
-    # -- the push lattice (bucketing.push_lattice, PR 32) ------------------
+    # -- the push lattice (lattice.push_lattice, PR 32) ------------------
     def _cut_to_rows(
         self, name: str, chunk: StreamChunk, side: str
     ) -> StreamChunk:
@@ -949,9 +920,9 @@ class StreamingRuntime:
         # replay (the reference reschedules with more parallelism,
         # scale.rs:453 — here capacity is the per-shard analogue) and
         # refund the deterministic-fault budget so the grown replay
-        # gets its attempt. Quiesce FIRST: an in-flight worker step or
-        # queued closer commit could otherwise write an old-shape
-        # table back over the grown one.
+        # gets its attempt. Quiesce FIRST: an in-flight worker step
+        # could otherwise write an old-shape table back over the grown
+        # one.
         self._quiesce()
         grew = 0
         for ex in self.executors():
@@ -994,9 +965,8 @@ class StreamingRuntime:
         fragments whose actor graphs recorded an actor death, plus
         their transitive subscribers (MV-on-MV closure). None when the
         failure is not scopeable — no graph attributed it, the scope
-        covers every fragment, the replay window was lost, or pipelined
-        barriers are on (their closer lane owns epoch bookkeeping)."""
-        if self.mgr is None or self.in_flight_barriers > 1:
+        covers every fragment, or the replay window was lost."""
+        if self.mgr is None:
             return None
         failed = set()
         for name, p in self.fragments.items():
@@ -1037,7 +1007,7 @@ class StreamingRuntime:
 
     def _discard_scope(self, scope: set) -> None:
         """Store-free cleanup of a blast radius: drop held sink batches
-        and captured deltas of every scoped fragment, so no later
+        of every scoped fragment, so no later
         durable epoch can release output whose producing state is about
         to roll back and replay (double delivery). Runs BEFORE any
         store touch — a deferred restore must leave nothing stale."""
@@ -1045,10 +1015,9 @@ class StreamingRuntime:
             if name not in scope:
                 continue
             for ex in p.executors:
-                for hook in ("discard_pending", "discard_captured"):
-                    fn = getattr(ex, hook, None)
-                    if fn is not None:
-                        fn()
+                fn = getattr(ex, "discard_pending", None)
+                if fn is not None:
+                    fn()
 
     def _partial_recover(self, scope: set, cause: str) -> None:
         """Restore + replay ONLY ``scope``: rebuild each affected
@@ -1109,9 +1078,7 @@ class StreamingRuntime:
         finally:
             self._compact_pause.clear()
         self._work_abort.clear()
-        self._closer_abort.clear()
         self._work_err.clear()
-        self._closer_err.clear()
         # shared arrangements must not keep serving snapshots that
         # postdate the restored state — republish off the recovery
         self.arrangements.on_recovery(committed)
@@ -1228,128 +1195,6 @@ class StreamingRuntime:
         out.extend(self._aux_state)
         return out
 
-    # -- pipelined barrier path (in_flight_barriers > 1) -----------------
-    def _validate_pipelined(self) -> None:
-        if self._subs:
-            raise ValueError(
-                "pipelined barriers do not support subscription edges "
-                "(MV-on-MV needs synchronous epoch routing) — use "
-                "in_flight_barriers=1"
-            )
-        for name, p in self.fragments.items():
-            if not hasattr(p, "barrier_nowait"):
-                raise ValueError(
-                    f"fragment {name!r} is not graph-backed; pipelined "
-                    "barriers need GraphPipeline fragments"
-                )
-            if self.mgr is not None:
-                p.set_capture(True)
-
-    def _barrier_pipelined(self) -> Dict[str, List[StreamChunk]]:
-        t0 = time.perf_counter()
-        self._raise_closer_error()
-        self._raise_worker_error()
-        self._validate_pipelined()
-        prev, self._epoch = self._epoch, self.next_epoch()
-        self._barrier_seq += 1
-        is_ckpt = (
-            self.mgr is not None
-            and self._barrier_seq % self.checkpoint_frequency == 0
-        )
-        tr = self._begin_trace(is_ckpt)
-        for _name, p in self.fragments.items():
-            p._epoch = prev
-            p.barrier_nowait(checkpoint=is_ckpt, epoch=self._epoch)
-            # pipelined mode never takes the partial path, but the
-            # marker keeps the replay buffer's pruning cursor moving
-            self._record_barrier(_name, self._epoch, is_ckpt)
-        with self._closer_cv:
-            self._closer_q.append((self._epoch, is_ckpt, t0))
-            self._ensure_closer()
-            self._closer_cv.notify_all()
-            # admission control: bounded in-flight epochs
-            self._closer_cv.wait_for(
-                lambda: len(self._closer_q) < self.in_flight_barriers
-                or bool(self._closer_err)
-            )
-        self._raise_closer_error()
-        # recompile-storm governor rides the admission clock too
-        self._shape_watch_tick()
-        self.shape_governor.observe_barrier(self)
-        # the trace is NOT finalized here: admission wall time would
-        # inflate achieved_bw to nonsense — the closer lane finalizes
-        # it once the epoch actually closed (commit stages land later)
-        ms = (time.perf_counter() - t0) * 1e3
-        self.barrier_latencies_ms.append(ms)  # ADMISSION latency
-        REGISTRY.histogram("barrier_latency_ms").observe(ms)
-        REGISTRY.counter("barriers_total").inc()
-        return {}
-
-    def _ensure_closer(self) -> None:
-        if self._closer is None or not self._closer.is_alive():
-            self._closer = threading.Thread(
-                target=self._closer_loop, daemon=True
-            )
-            self._closer.start()
-
-    def _closer_loop(self) -> None:
-        while True:
-            with self._closer_cv:
-                if not self._closer_q:
-                    self._closer_cv.wait(timeout=0.5)
-                    if not self._closer_q:
-                        continue
-                epoch, is_ckpt, t_adm = self._closer_q[0]
-            try:
-                if not self._closer_err and not self._closer_abort.is_set():
-                    tr = self._traces_by_epoch.get(epoch)
-                    with bind(tr, epoch):
-                        for name, p in self.fragments.items():
-                            with span(
-                                "barrier.close", stage="close", fragment=name
-                            ):
-                                p.wait_barrier(epoch)
-                        if is_ckpt:
-                            # deltas were SEALED by the actors at the
-                            # barrier (capture_checkpoint): stage consumes
-                            # host buffers, never racing next-epoch compute
-                            self._enqueue_commit(
-                                epoch, *self._stage(), tr
-                            )
-                        if tr is not None:
-                            # finalize over admission->closed (the epoch's
-                            # real span), not admission-only wall time
-                            self._end_trace(tr)
-                    self.epoch_close_ms.append(
-                        (time.perf_counter() - t_adm) * 1e3
-                    )
-            except BaseException as e:  # surfaced at the next barrier
-                self._closer_err.append(e)
-            finally:
-                with self._closer_cv:
-                    if self._closer_q and self._closer_q[0][0] == epoch:
-                        self._closer_q.popleft()
-                    self._closer_cv.notify_all()
-
-    def _raise_closer_error(self) -> None:
-        if self._closer_err:
-            raise RuntimeError(
-                "pipelined barrier close failed"
-            ) from self._closer_err[0]
-
-    def wait_epochs(self) -> None:
-        """Join the closer lane: every admitted barrier fully closed
-        (collection + staging done; commits may still be in the async
-        lane — ``wait_checkpoints`` joins those too)."""
-        with self._closer_cv:
-            self._closer_cv.wait_for(lambda: not self._closer_q)
-        self._raise_closer_error()
-
-    def p99_epoch_close_ms(self) -> float:
-        if not self.epoch_close_ms:
-            return 0.0
-        return float(np.percentile(self.epoch_close_ms, 99))
-
     def _barrier_locked(self) -> Dict[str, List[StreamChunk]]:
         # device-wedge fail-fast: an armed sentinel wedge raises the
         # structured DeviceWedged HERE instead of letting the barrier
@@ -1363,8 +1208,6 @@ class StreamingRuntime:
         self._maybe_restore_degraded()
         # deferred partial recovery probes on the same clock
         self._maybe_resume_partial()
-        if self.in_flight_barriers > 1:
-            return self._barrier_pipelined()
         t0 = time.perf_counter()
         prev, self._epoch = self._epoch, self.next_epoch()
         self._barrier_seq += 1
@@ -1453,10 +1296,8 @@ class StreamingRuntime:
         # run (no permit waited for, no string new), never absent
         tr.declare(
             "ingest", "ingest.permit_wait", "ingest.device_wait",
-            "publish", "bookkeeping",
+            "publish", "bookkeeping", "dispatch",
         )
-        if self.in_flight_barriers <= 1:
-            tr.declare("dispatch")
         if is_ckpt:
             tr.declare(
                 "checkpoint_stage", "checkpoint_stage.marks",
@@ -1471,10 +1312,6 @@ class StreamingRuntime:
             tr.add_stage(stage, ms)
         tr.chunk_bytes = self._ingest_bytes
         self._ingest_bytes = 0
-        self._traces_by_epoch[tr.epoch] = tr
-        # bound the pending map (async commits resolve FIFO)
-        while len(self._traces_by_epoch) > 512:
-            self._traces_by_epoch.pop(next(iter(self._traces_by_epoch)))
         return tr
 
     def _end_trace(self, tr: EpochTrace) -> None:
@@ -1509,17 +1346,14 @@ class StreamingRuntime:
             pass
         # memory governor + overload ladder: consumes the fresh state
         # bytes and this barrier's backpressure verdict, applies veto/
-        # spill/ladder/credit actions. Runs on BOTH barrier paths (the
-        # pipelined closer lane finalizes traces here too); dormant =
-        # one attribute check. Never faults a barrier (self-guarded).
+        # spill/ladder/credit actions. Dormant = one attribute check.
+        # Never faults a barrier (self-guarded).
         with span("bookkeeping.memory_governor"):
             self.memory_governor.observe_barrier(self, tr)
         # mesh observability: fold the per-pipeline shard windows closed
         # this barrier into one mesh doc on the trace (per-shard stage
         # lanes + exchange matrix + skew verdict). Dormant = one
         # attribute check; self-guarded, never faults a barrier.
-        from risingwave_tpu.parallel.meshprof import MESHPROF
-
         with span("bookkeeping.meshprof"):
             MESHPROF.observe_barrier(self, tr)
         # flight recorder: the finalized trace is exactly one black-box
@@ -1781,7 +1615,11 @@ class StreamingRuntime:
         # stage on the main thread (device pull + eager mark flips, with
         # the duplicate-table_id check) — ONE code path with the sync
         # commit (CheckpointManager.stage / commit_staged)
-        staged, t_staged = self._stage()
+        t_staged = time.perf_counter()
+        with span("checkpoint.stage", stage="checkpoint_stage"):
+            staged = self.mgr.stage(self._staging_executors())
+        REGISTRY.counter("checkpoints_total").inc()
+        REGISTRY.gauge("checkpoint_staged_tables").set(len(staged))
         if not self.async_checkpoint:
             with span("checkpoint.commit"):
                 durable = self._commit_or_degrade(epoch, staged, tr)
@@ -1792,19 +1630,7 @@ class StreamingRuntime:
                 self._on_epoch_durable(epoch)
                 self._kick_compactor()
             return
-        self._enqueue_commit(epoch, staged, t_staged, tr)
-
-    def _stage(self):
-        """(staged deltas, when staging began), on the calling thread."""
-        t_staged = time.perf_counter()
-        with span("checkpoint.stage", stage="checkpoint_stage"):
-            staged = self.mgr.stage(self._staging_executors())
-        REGISTRY.counter("checkpoints_total").inc()
-        REGISTRY.gauge("checkpoint_staged_tables").set(len(staged))
-        return staged, t_staged
-
-    def _enqueue_commit(self, epoch, staged, t_staged, tr) -> None:
-        """Hand staged deltas to the async checkpoint worker."""
+        # hand the staged deltas to the async checkpoint worker
         with self._inflight_lock:
             self._inflight += 1
         self._work_q.append(
@@ -1935,8 +1761,6 @@ class StreamingRuntime:
         """Join the async lane (the FLUSH / sync-epoch analogue).
         Compaction intentionally does NOT block this (it runs on its
         own worker — ADVICE r2: inline compaction stalled FLUSH)."""
-        if self.in_flight_barriers > 1:
-            self.wait_epochs()  # staging happens in the closer lane
         while True:
             with self._inflight_lock:
                 if self._inflight == 0:
@@ -1961,16 +1785,12 @@ class StreamingRuntime:
     # -- recovery --------------------------------------------------------
     def _quiesce(self) -> None:
         """Drain the async commit lane and in-flight worker steps.
-        Leaves the abort flags SET — recover() clears them after the
+        Leaves the abort flag SET — recover() clears it after the
         restore. Idempotent (auto-recovery quiesces before growing
         capacities; recover() quiesces again trivially)."""
         # abort the async lane FIRST: staged epochs still queued refer
         # to pre-recovery state; committing one after the restore would
         # advance the manifest past the epoch we just recovered to
-        self._closer_abort.set()
-        with self._closer_cv:
-            self._closer_cv.notify_all()
-            self._closer_cv.wait_for(lambda: not self._closer_q, timeout=150)
         self._work_abort.set()
         while True:
             with self._inflight_lock:
@@ -2067,13 +1887,7 @@ class StreamingRuntime:
             fn = getattr(ex, "discard_pending", None)
             if fn is not None:
                 fn()
-            # captured deltas of rolled-back epochs are stale
-            fn = getattr(ex, "discard_captured", None)
-            if fn is not None:
-                fn()
         self._work_err.clear()
-        self._closer_err.clear()
-        self._closer_abort.clear()
         # a full restore supersedes any deferred partial recovery and
         # resets the replay window: everything rolls back to the
         # committed epoch and sources replay from their offsets, so the

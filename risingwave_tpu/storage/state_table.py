@@ -27,7 +27,6 @@ from __future__ import annotations
 import functools
 import json
 import threading
-from collections import deque as _deque
 from contextlib import nullcontext
 from dataclasses import dataclass
 from typing import Dict, List, Optional, Sequence, Tuple
@@ -36,6 +35,13 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from risingwave_tpu.array.lattice import (
+    DELTA_BLOCK,
+    DELTA_SMALL,
+    SELECT_SPAN,
+    delta_blocks,
+    select_spans,
+)
 from risingwave_tpu.integrity import (
     StateCorruption,
     crc32_bytes,
@@ -184,8 +190,6 @@ def _select(code, groups, offset, *, span: int):
     PERF.md 6, PR 32) and no search loop: a rank's group, row and lane
     are each the number of running counts at or under it, and the one
     gather is of its row."""
-    from risingwave_tpu.runtime.bucketing import DELTA_BLOCK, DELTA_SMALL
-
     i32 = jnp.int32
     rank = offset + jnp.arange(span, dtype=i32)
     at = rank[:, None]
@@ -218,8 +222,6 @@ def _warm_select(rows: int) -> None:
     time a table of ``rows`` rows of marks is classified: which of them
     a barrier takes follows what its epoch changed, and a span first
     met inside a stream would open a compile there."""
-    from risingwave_tpu.runtime.bucketing import DELTA_BLOCK, SELECT_SPAN
-
     code = jnp.zeros((rows, MARK_ROW), jnp.uint8)
     groups = jnp.zeros((-(-rows // MARK_ROW), MARK_ROW), jnp.int32)
     for span in (DELTA_BLOCK, SELECT_SPAN):
@@ -269,12 +271,6 @@ def classify_marks(sdirty, alive, stored) -> Marks:
     (``select_spans``; of 256 ranks for a count no larger). The slots
     themselves stay on the device for ``pull_rows``, and so do the
     flipped lanes the caller adopts (``StateDelta``)."""
-    from risingwave_tpu.runtime.bucketing import (
-        DELTA_BLOCK,
-        delta_blocks,
-        select_spans,
-    )
-
     if not isinstance(alive, (tuple, list)):
         alive = (alive,)
     count, code, groups, stored, cleared = _classify(
@@ -356,7 +352,7 @@ def pull_rows(
 ) -> Dict[str, np.ndarray]:
     """Device->host transfer of SELECTED rows only (checkpoint staging
     must be O(changed rows), not O(capacity)). ``sel`` goes in pieces of
-    one of two sizes (bucketing.delta_blocks), so jit caches two gather
+    one of two sizes (lattice.delta_blocks), so jit caches two gather
     programs per lane set whatever an epoch changed, instead of one per
     power of two its count ever crossed. A table's ``Marks`` bring the
     pieces as ``classify_marks`` left them on the device, so the
@@ -372,8 +368,6 @@ def pull_rows(
     ``checkpoint.pull``, with the rows and the padded rows it moved and
     the device->host arrays it moved them in (``copies``, also the
     counter ``checkpoint_pull_copies_total``)."""
-    from risingwave_tpu.runtime.bucketing import delta_blocks
-
     n = len(sel)
     if n == 0:
         return {
@@ -469,16 +463,6 @@ class Checkpointable:
         device-side sdirty marks (update stored marks)."""
         raise NotImplementedError
 
-    # -- pipelined barriers: capture-at-barrier (the memtable seal) ----
-    # With more than one barrier in flight, the delta for epoch N must
-    # be pulled BEFORE any epoch-N+1 row mutates this executor's state.
-    # Actor threads call ``capture_checkpoint`` while processing the
-    # checkpoint barrier (FIFO channels guarantee nothing from N+1 has
-    # been applied yet — the shared-buffer seal point,
-    # /root/reference/src/storage/src/hummock/shared_buffer/); the
-    # checkpoint manager later consumes captures in epoch order.
-    _captured_deltas = None
-
     def _pull_delta(self) -> List[StateDelta]:
         """``checkpoint_delta`` under the span ``checkpoint.marks``: what
         an executor's staging does outside its row pull — classifying
@@ -513,23 +497,6 @@ class Checkpointable:
             return deltas
         finally:
             _STAGING.table_id = _STAGING.marks = None
-
-    def capture_checkpoint(self) -> None:
-        if self._captured_deltas is None:
-            self._captured_deltas = _deque()
-        self._captured_deltas.append(self._pull_delta())
-
-    def staged_or_live_delta(self) -> List[StateDelta]:
-        """Oldest captured delta if any (pipelined mode), else a live
-        pull (synchronous mode)."""
-        if self._captured_deltas:
-            return self._captured_deltas.popleft()
-        return self._pull_delta()
-
-    def discard_captured(self) -> None:
-        """Recovery: captured deltas of rolled-back epochs are stale."""
-        if self._captured_deltas is not None:
-            self._captured_deltas.clear()
 
     def restore_state(
         self, table_id: str, key_cols: Dict[str, np.ndarray],
@@ -757,7 +724,7 @@ class CheckpointManager:
                         cur = self._pending_watermarks.get(tid)
                         if cur is None or cur[0] != key or cur[1] < val:
                             self._pending_watermarks[tid] = (key, int(val))
-            for delta in ex.staged_or_live_delta():
+            for delta in ex._pull_delta():
                 if delta.table_id in seen_ids:
                     raise ValueError(
                         f"duplicate table_id {delta.table_id!r} in one "

@@ -321,7 +321,7 @@ def test_apply_stacked_matches_per_chunk(rng):
     from risingwave_tpu.array.chunk import StreamChunk
     from risingwave_tpu.executors import HashAggExecutor
     from risingwave_tpu.executors.hop_window import hop_step_fn
-    from risingwave_tpu.parallel.sharded_agg import stack_chunks
+    from risingwave_tpu.array.chunk import stack_chunks
 
     calls = (AggCall("count_star", None, "num"),)
     dt = {"auction": jnp.int64, "window_start": jnp.int64, "date_time": jnp.int64}

@@ -16,15 +16,14 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from risingwave_tpu.array.chunk import StreamChunk
+from risingwave_tpu.array.chunk import StreamChunk, stack_chunks
 from risingwave_tpu.executors import hash_agg
 from risingwave_tpu.executors.base import Watermark
 from risingwave_tpu.executors.hash_agg import HashAggExecutor
 from risingwave_tpu.metrics import REGISTRY
 from risingwave_tpu.ops import agg as agg_ops
 from risingwave_tpu.ops.agg import AggCall
-from risingwave_tpu.parallel.sharded_agg import stack_chunks
-from risingwave_tpu.runtime.bucketing import TOUCHED_MAX, touched_lattice
+from risingwave_tpu.array.lattice import TOUCHED_MAX, touched_lattice
 from risingwave_tpu.storage.object_store import MemObjectStore
 from risingwave_tpu.storage.state_table import CheckpointManager
 from risingwave_tpu.trace import TRACER

@@ -8,10 +8,9 @@ import jax
 import numpy as np
 import pytest
 
-from risingwave_tpu.array.chunk import StreamChunk
+from risingwave_tpu.array.chunk import StreamChunk, stack_chunks
 from risingwave_tpu.executors.hash_agg import HashAggExecutor
 from risingwave_tpu.ops.agg import AggCall
-from risingwave_tpu.parallel.sharded_agg import stack_chunks
 
 
 CALLS = (

@@ -16,7 +16,7 @@ from risingwave_tpu.executors.materialize import DeviceMaterializeExecutor
 from risingwave_tpu.executors.stream_join import StreamJoinExecutor
 from risingwave_tpu.executors.top_n_plain import RetractableGroupTopNExecutor
 from risingwave_tpu.ops.agg import AggCall
-from risingwave_tpu.runtime.bucketing import (
+from risingwave_tpu.array.lattice import (
     DELTA_BLOCK,
     DELTA_SMALL,
     SELECT_SPAN,

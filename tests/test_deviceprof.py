@@ -24,7 +24,7 @@ from risingwave_tpu.deviceprof import (
 from risingwave_tpu.epoch_trace import EpochTrace
 from risingwave_tpu.profiler import PROFILER
 from risingwave_tpu.queries.nexmark_q import build_q5_lite
-from risingwave_tpu.runtime.bucketing import padding_fraction
+from risingwave_tpu.ops.bucketing import padding_fraction
 from risingwave_tpu.runtime.fused_step import fuse_pipeline
 
 pytestmark = pytest.mark.smoke

@@ -1,6 +1,6 @@
 """The flush lattice exists before a stream meets it (PR 30). When a
 graph-mode view is created, every aggregate's declared flush sizes
-(``runtime/bucketing.flush_lattice``) are sent as chunks with no valid
+(``array/lattice.flush_lattice``) are sent as chunks with no valid
 row down what follows the aggregate inside its actor
 (``FragmentActor.warm_flush_lattice``). Three things are held here, on
 NEXmark q5 as its source writes it: the pass leaves no mark; a size
@@ -227,7 +227,7 @@ def test_every_size_is_compiled_at_creation_and_the_view_stays_exact(
 def test_the_flush_over_the_steps_list_is_compiled_at_creation(tmp_path):
     """PR 34: a barrier's flush ranges over the list of the slots the
     epoch's steps wrote, cut to a declared length
-    (``bucketing.touched_lattice``), one program a length. Creating the
+    (``lattice.touched_lattice``), one program a length. Creating the
     view compiles them all beside the table walk's, so a stream's first
     barrier compiles no flush, and goes by the list. (The count's table
     at a capacity no other test of the process builds.)"""

@@ -148,7 +148,7 @@ def test_e803_q7_window_path():
     must yield RW-E803 with exact executor provenance on both the
     dynamic max filter and the join; the SHIPPED bucketed q7 (the lint
     corpus) must be clean — its executors declare the allocator's pow2
-    lattice (runtime/bucketing.py)."""
+    lattice (array/lattice.py)."""
     from risingwave_tpu.analysis.lint import (
         NEXMARK_SOURCE_SCHEMAS,
         build_nexmark_corpus,

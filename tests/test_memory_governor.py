@@ -10,7 +10,7 @@ import pytest
 
 from risingwave_tpu.array.chunk import StreamChunk
 from risingwave_tpu.runtime import SourceManager
-from risingwave_tpu.runtime.bucketing import BucketAllocator, BucketPolicy
+from risingwave_tpu.ops.bucketing import BucketAllocator, BucketPolicy
 from risingwave_tpu.runtime.memory_governor import (
     DEGRADED,
     LADDER,

@@ -109,7 +109,7 @@ def test_random_stream_matches_oracle(mode):
         if mode == "chunk":
             outs = ex.apply(_chunk(rows))
         else:
-            from risingwave_tpu.parallel.sharded_agg import stack_chunks
+            from risingwave_tpu.array.chunk import stack_chunks
 
             outs = ex.apply_stacked(stack_chunks([_chunk(rows)]))
         _replay(snap, outs, ("g",), ("cnt", "mn", "mx"))

@@ -11,7 +11,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from risingwave_tpu.array.chunk import StreamChunk
+from risingwave_tpu.array.chunk import StreamChunk, stack_chunks
 from risingwave_tpu.executors.materialize import MaterializeExecutor
 from risingwave_tpu.ops.agg import AggCall
 from risingwave_tpu.parallel import (
@@ -20,7 +20,6 @@ from risingwave_tpu.parallel import (
     flatten_stacked,
     make_mesh,
 )
-from risingwave_tpu.parallel.sharded_agg import stack_chunks
 from risingwave_tpu.runtime import Pipeline
 from risingwave_tpu.runtime.runtime import StreamingRuntime
 from risingwave_tpu.storage.object_store import MemObjectStore

@@ -9,7 +9,7 @@ import numpy as np
 import pytest
 
 from risingwave_tpu.metrics import REGISTRY
-from risingwave_tpu.runtime.bucketing import (
+from risingwave_tpu.array.lattice import (
     DELTA_BLOCK,
     DELTA_SMALL,
     delta_blocks,

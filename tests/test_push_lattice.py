@@ -1,6 +1,6 @@
 """A pushed chunk as wide as what it holds (PR 32). ``StreamingRuntime.
-push`` cuts a host-built chunk to the smallest size of ``runtime/
-bucketing.push_lattice`` that holds its rows, from the count
+push`` cuts a host-built chunk to the smallest size of ``array/
+lattice.push_lattice`` that holds its rows, from the count
 ``StreamChunk.from_numpy`` leaves on the chunk, where the fragment and
 what it is routed on to declare that they take such widths. Held here,
 on NEXmark q8 as the benchmark's configuration writes it: the answers

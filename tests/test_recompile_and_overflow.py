@@ -191,7 +191,7 @@ def test_bench_shape_stacked_scan_at_bench_capacity():
     import functools
 
     from risingwave_tpu.executors.hop_window import hop_step_fn
-    from risingwave_tpu.parallel.sharded_agg import stack_chunks
+    from risingwave_tpu.array.chunk import stack_chunks
 
     q5 = build_q5_lite(capacity=1 << 16, state_cleaning=False)
     gen = NexmarkGenerator(NexmarkConfig(first_event_rate=10_000))

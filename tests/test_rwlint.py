@@ -139,7 +139,7 @@ def test_sql_create_mv_lints_clean_and_under_budget():
     assert not [d for _n, d in session.lint_findings]
     h = REGISTRY.histogram("lint_ms")
     assert h.count() > before  # the DDL hook really ran
-    # PROFILE budget: <50ms per CREATE MV (pure metadata walking)
+    # budget: <50ms per CREATE MV (pure metadata walking)
     t0 = time.perf_counter()
     planned = session.catalog.mvs["v"]
     lint_planned(planned, catalog=session.catalog, strict=True)

@@ -13,7 +13,7 @@ from risingwave_tpu.executors import HashAggExecutor
 from risingwave_tpu.ops.agg import AggCall
 from risingwave_tpu.parallel import ShardedHashAgg, make_mesh
 from risingwave_tpu.parallel.scale import ScaleController
-from risingwave_tpu.parallel.sharded_agg import stack_chunks
+from risingwave_tpu.array.chunk import stack_chunks
 from risingwave_tpu.runtime import Pipeline, StreamingRuntime
 from risingwave_tpu.storage.object_store import MemObjectStore
 

@@ -1,5 +1,5 @@
-"""Shape-stability hardening (runtime/bucketing.py, PR 9): the pow2
-bucket allocator's grow-eager/shrink-lazy hysteresis, emission
+"""Shape-stability hardening (array/lattice.py, ops/bucketing.py, PR 9):
+the pow2 bucket allocator's grow-eager/shrink-lazy hysteresis, emission
 bucketing mask correctness at exactly-full/one-over boundaries, the
 bucket-boundary-oscillation recompile bound (one trace per bucket,
 never per shape), RW-E806 lattice validation + strict-fusion DDL
@@ -14,21 +14,23 @@ import jax.numpy as jnp
 
 from risingwave_tpu.array.chunk import StreamChunk
 from risingwave_tpu.executors.base import Executor, Watermark
-from risingwave_tpu.runtime.bucketing import (
-    BucketAllocator,
-    BucketPolicy,
-    ShapeGovernor,
+from risingwave_tpu.array.lattice import (
     emission_bucket,
     flush_lattice,
     flush_lattice_pad,
     flush_pad,
     flush_pad_schedule,
     lattice_between,
-    padding_stats,
     pow2_at_least,
     push_lattice,
     validate_lattice,
 )
+from risingwave_tpu.ops.bucketing import (
+    BucketAllocator,
+    BucketPolicy,
+    padding_stats,
+)
+from risingwave_tpu.runtime.shape_governor import ShapeGovernor
 
 pytestmark = pytest.mark.smoke
 

@@ -7,12 +7,11 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from risingwave_tpu.array.chunk import StreamChunk
+from risingwave_tpu.array.chunk import StreamChunk, stack_chunks
 from risingwave_tpu.connectors.nexmark import NexmarkConfig, NexmarkGenerator
 from risingwave_tpu.executors import HashAggExecutor
 from risingwave_tpu.ops.agg import AggCall
 from risingwave_tpu.parallel import ShardedHashAgg, make_mesh
-from risingwave_tpu.parallel.sharded_agg import stack_chunks
 from risingwave_tpu.types import Op
 
 

@@ -12,7 +12,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from risingwave_tpu.array.chunk import StreamChunk
+from risingwave_tpu.array.chunk import StreamChunk, stack_chunks
 from risingwave_tpu.executors.dedup import AppendOnlyDedupExecutor
 from risingwave_tpu.executors.hash_join import HashJoinExecutor
 from risingwave_tpu.executors.materialize import MaterializeExecutor
@@ -22,7 +22,6 @@ from risingwave_tpu.parallel import (
     flatten_stacked,
     make_mesh,
 )
-from risingwave_tpu.parallel.sharded_agg import stack_chunks
 from risingwave_tpu.storage.object_store import MemObjectStore
 from risingwave_tpu.storage.state_table import CheckpointManager
 

@@ -12,7 +12,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from risingwave_tpu.array.chunk import StreamChunk
+from risingwave_tpu.array.chunk import StreamChunk, stack_chunks
 from risingwave_tpu.connectors.nexmark import NexmarkConfig, NexmarkGenerator
 from risingwave_tpu.executors.hash_join import HashJoinExecutor
 from risingwave_tpu.executors.hop_window import _hop_step
@@ -24,7 +24,6 @@ from risingwave_tpu.parallel import (
     make_mesh,
 )
 from risingwave_tpu.executors.dedup import AppendOnlyDedupExecutor
-from risingwave_tpu.parallel.sharded_agg import stack_chunks
 from risingwave_tpu.types import Op
 
 N = 8

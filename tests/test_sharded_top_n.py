@@ -7,10 +7,9 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from risingwave_tpu.array.chunk import StreamChunk
+from risingwave_tpu.array.chunk import StreamChunk, stack_chunks
 from risingwave_tpu.executors.top_n_plain import RetractableGroupTopNExecutor
 from risingwave_tpu.parallel import ShardedGroupTopN, make_mesh
-from risingwave_tpu.parallel.sharded_agg import stack_chunks
 from risingwave_tpu.storage.object_store import MemObjectStore
 from risingwave_tpu.storage.state_table import CheckpointManager
 
