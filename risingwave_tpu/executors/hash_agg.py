@@ -297,6 +297,31 @@ def _agg_epoch_reduced_mi(
     )
 
 
+@partial(jax.jit, static_argnames=("pre",))
+def _visible_rows(stacked, pre):
+    """The rows of an epoch's batch that count (a sign of their own)
+    once ``pre`` has run over it."""
+    chunks = jax.vmap(pre)(stacked) if pre is not None else stacked
+    flat = jax.tree.map(lambda a: a.reshape((-1,) + a.shape[2:]), chunks)
+    return jnp.sum(flat.effective_signs() != 0, dtype=jnp.int32)
+
+
+@partial(jax.jit, static_argnames=("lanes",))
+def _listed_rows_max(so_far, touched, at, visible, lanes: int):
+    """``so_far`` or, if larger, the rows of the largest group among
+    the ``lanes`` lanes ``_epoch_reduced_fn`` listed at ``at``: a
+    distinct key stands at the first lane of its run in sort order and
+    the ``visible`` rows come first, so its rows are the lanes up to
+    the next listed one, or up to ``visible``."""
+    listed = jax.lax.dynamic_slice(touched, (at,), (lanes,)) >= 0
+    pos = jnp.arange(lanes, dtype=jnp.int32)
+    nxt = jax.lax.cummin(jnp.where(listed, pos, lanes), reverse=True)
+    end = jnp.minimum(
+        jnp.concatenate([nxt[1:], jnp.full(1, lanes, jnp.int32)]), visible
+    )
+    return jnp.maximum(so_far, jnp.max(jnp.where(listed, end - pos, 0)))
+
+
 @partial(jax.jit, static_argnames=("calls", "new_cap"))
 def _rehash(
     table: HashTable,
@@ -537,6 +562,9 @@ class HashAggExecutor(Executor, Checkpointable):
         # wholesale): the next flush walks the table and starts it anew
         self._touched = jnp.full(TOUCHED_MAX, -1, jnp.int32)
         self._touched_lanes: Optional[int] = 0
+        # the rows of the largest group a step of this epoch met, on
+        # the device (``_note_group_rows``); None = some step did not say
+        self._group_rows = jnp.zeros((), jnp.int32)
         # lanes a chunk holds after the traced-in prefix, per chunk shape
         self._lanes_after_pre: Dict[tuple, int] = {}
         # shape-stability: capacity walks the allocator's pow2 lattice;
@@ -713,6 +741,7 @@ class HashAggExecutor(Executor, Checkpointable):
         self._insert_bound += chunk.capacity
         self._dirty_bound += chunk.capacity
         at = self._touched_at(chunk.capacity)
+        self._group_rows = None  # (a lane a row: no run to measure)
         # the step's enqueue (the device runs it asynchronously), as
         # actor.join_step is for a join
         with span("actor.agg_step", table_id=self.table_id):
@@ -801,7 +830,27 @@ class HashAggExecutor(Executor, Checkpointable):
             chunks=int(stacked.valid.shape[0]),
         ):
             self._step_stacked(stacked, pre, mode, at)
+            self._note_group_rows(stacked, pre, mode, at, lanes)
         return []
+
+    def _note_group_rows(self, stacked, pre, mode, at: int, lanes: int):
+        """The rows of the largest group of the batch just stepped,
+        kept as the epoch's maximum on the device (``agg.flush`` reads
+        it): ``_epoch_reduced_fn`` lists the batch's distinct keys at
+        the first lane of their run in sort order, the rows that count
+        first, so a group's rows are the lanes to the next listed one.
+        Two small programs beside the step's own, which stays as it
+        was. None where a step kept no such list (the per-chunk step,
+        the scan, a list given up): the epoch then has no such count."""
+        if self._group_rows is None:
+            return
+        if mode != "reduce" or self._touched_lanes is None:
+            self._group_rows = None
+            return
+        self._group_rows = _listed_rows_max(
+            self._group_rows, self._touched, at,
+            _visible_rows(stacked, pre), lanes=lanes,
+        )
 
     def _stacked_lanes(self, stacked: StreamChunk, pre) -> int:
         """Lanes the batch holds once ``pre`` has run (a hop multiplies
@@ -1258,6 +1307,12 @@ class HashAggExecutor(Executor, Checkpointable):
             touched=self._touched, n_touched=self._touched_lanes, walk=walk
         )
         path = "table" if walk is None else "touched"
+        # the rows of the epoch's largest group, where every step said
+        # (``_note_group_rows``): it comes with the first round's status,
+        # in the one read
+        rows_max, self._group_rows = (
+            self._group_rows, jnp.zeros((), jnp.int32)
+        )
         while True:
             with span(
                 "agg.flush",
@@ -1275,10 +1330,18 @@ class HashAggExecutor(Executor, Checkpointable):
                     **listed,
                 )
                 with device_read("agg.flush.status", lanes=2):
-                    status = np.asarray(delta["status"])
+                    status, largest = jax.device_get(
+                        (delta["status"], rows_max)
+                    )
                 n_take, overflow = status.tolist()
                 chunk = self._delta_to_chunk(delta, n_take)
                 sp.args.update(rows=2 * n_take, lanes=chunk.capacity)
+                if largest is not None:
+                    rows_max = None
+                    sp.args.update(group_rows_max=largest.tolist())
+                    REGISTRY.gauge("agg_group_rows_max").set(
+                        largest.tolist(), table_id=self.table_id
+                    )
             REGISTRY.counter("agg_flush_rounds_total").inc(
                 table_id=self.table_id, path=path
             )
@@ -1355,6 +1418,11 @@ class HashAggExecutor(Executor, Checkpointable):
         self._step_stacked(
             stacked, pre, mode, self._touched_at(lanes, advance=False)
         )
+        if mode == "reduce":
+            _listed_rows_max(
+                jnp.zeros((), jnp.int32), self._touched, 0,
+                _visible_rows(stacked, pre), lanes=lanes,
+            )
 
     def _would_grow(self, incoming: int) -> bool:
         """Whether ``_maybe_grow`` would rebuild the table before a

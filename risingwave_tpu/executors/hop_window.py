@@ -15,7 +15,7 @@ kept for safety).
 from __future__ import annotations
 
 from functools import partial
-from typing import List
+from typing import List, Optional
 
 import jax
 import jax.numpy as jnp
@@ -25,7 +25,8 @@ from risingwave_tpu.executors.base import Executor
 
 
 def hop_step_fn(
-    chunk: StreamChunk, ts_col: str, size_ms: int, slide_ms: int, out_start: str
+    chunk: StreamChunk, ts_col: str, size_ms: int, slide_ms: int,
+    out_start: str, out_end: Optional[str] = None,
 ) -> StreamChunk:
     factor = -(-size_ms // slide_ms)  # ceil
     cap = chunk.capacity
@@ -47,17 +48,23 @@ def hop_step_fn(
 
     cols = {n: tile(a) for n, a in chunk.columns.items()}
     cols[out_start] = starts
-    # a pre-existing null lane on the output column must not survive the
-    # replacement (freshly computed starts are never NULL)
-    nulls = {n: tile(a) for n, a in chunk.nulls.items() if n != out_start}
+    if out_end is not None:  # only where the query names it
+        cols[out_end] = starts + size_ms
+    # a pre-existing null lane on an output column must not survive the
+    # replacement (freshly computed bounds are never NULL)
+    nulls = {
+        n: tile(a) for n, a in chunk.nulls.items()
+        if n not in (out_start, out_end)
+    }
     valid = tile(chunk.valid) & in_window
     ops = tile(chunk.ops)
     return StreamChunk(cols, valid, nulls, ops)
 
 
-_hop_step = partial(jax.jit, static_argnames=("ts_col", "size_ms", "slide_ms", "out_start"))(
-    hop_step_fn
-)
+_hop_step = partial(
+    jax.jit,
+    static_argnames=("ts_col", "size_ms", "slide_ms", "out_start", "out_end"),
+)(hop_step_fn)
 
 
 class HopWindowExecutor(Executor):
@@ -67,6 +74,7 @@ class HopWindowExecutor(Executor):
         size_ms: int,
         slide_ms: int,
         out_start: str = "window_start",
+        out_end: Optional[str] = None,
     ):
         if size_ms % slide_ms:
             raise ValueError("size must be a multiple of slide")
@@ -74,10 +82,14 @@ class HopWindowExecutor(Executor):
         self.size_ms = size_ms
         self.slide_ms = slide_ms
         self.out_start = out_start
+        self.out_end = out_end
 
     def apply(self, chunk: StreamChunk) -> List[StreamChunk]:
         return [
-            _hop_step(chunk, self.ts_col, self.size_ms, self.slide_ms, self.out_start)
+            _hop_step(
+                chunk, self.ts_col, self.size_ms, self.slide_ms,
+                self.out_start, self.out_end,
+            )
         ]
 
     def lint_info(self):
@@ -85,7 +97,10 @@ class HopWindowExecutor(Executor):
 
         return {
             "requires": (self.ts_col,),
-            "adds": {self.out_start: jnp.int64},
+            "adds": {
+                n: jnp.int64
+                for n in (self.out_start, self.out_end) if n is not None
+            },
             "watermark_map": {self.ts_col: self.out_start},
         }
 
@@ -100,6 +115,7 @@ class HopWindowExecutor(Executor):
             size_ms=self.size_ms,
             slide_ms=self.slide_ms,
             out_start=self.out_start,
+            out_end=self.out_end,
         )
 
     def on_watermark(self, watermark):

@@ -60,6 +60,7 @@ from risingwave_tpu.storage.state_table import (
     pull_rows,
 )
 from risingwave_tpu.trace import device_read, span
+from risingwave_tpu.types import Op
 
 GROW_AT = 0.5
 
@@ -86,6 +87,14 @@ def _leading(buf: StreamChunk, fill, lanes_like):
         valid=jnp.arange(n, dtype=jnp.int32) < fill,
         nulls={k: a[:n] for k, a in buf.nulls.items()},
         ops=buf.ops[:n],
+    )
+
+
+@jax.jit
+def _count_deletes(n, out: StreamChunk):
+    """``n`` + the pairs ``out`` takes back."""
+    return n + jnp.sum(
+        out.valid & (out.ops == jnp.int32(Op.DELETE)), dtype=jnp.int64
     )
 
 
@@ -179,6 +188,15 @@ class StreamJoinExecutor(Executor, Checkpointable):
         self._rows_ckpt = {"left": 0, "right": 0}
         self._rows_now = {"left": 0, "right": 0}
         self._counts = jnp.zeros(3, jnp.int64)  # matched, kept, probe lanes
+        # what an updating side (one whose stream takes rows back)
+        # costs: the pairs handed on as DELETEs this epoch, counted on
+        # the device where a side retracts (None: no side does, and no
+        # pair is ever taken back), and the lanes each side has left
+        # dead so far (a retracted row's lane is not reused)
+        self._retracted_pairs = (
+            jnp.zeros((), jnp.int64) if any(self._retract.values()) else None
+        )
+        self._dead = {"left": 0, "right": 0}
 
     # -- the pair buffer ---------------------------------------------------
     def _out_dtypes(self):
@@ -210,8 +228,13 @@ class StreamJoinExecutor(Executor, Checkpointable):
         return tuple(sorted({max(full // 4, 1), max(full // 2, 1), full}))
 
     def warm_emissions(self) -> List[StreamChunk]:
-        # (through the cut itself, so that its program exists too)
-        return [self._cut(w, 0) for w in self.emission_caps]
+        # (through the cut itself, so that its program exists too, and
+        # the count of the pairs it takes back where a side retracts)
+        outs = [self._cut(w, 0) for w in self.emission_caps]
+        if self._retracted_pairs is not None:
+            for out in outs:
+                _count_deletes(self._retracted_pairs, out)
+        return outs
 
     def _cut(self, width: int, fill: int) -> StreamChunk:
         return _leading(
@@ -223,6 +246,8 @@ class StreamJoinExecutor(Executor, Checkpointable):
             return []
         width = next(w for w in self.emission_caps if w >= self._fill)
         out = self._cut(width, self._fill)
+        if self._retracted_pairs is not None:
+            self._retracted_pairs = _count_deletes(self._retracted_pairs, out)
         # (the lanes stay: a pair lane is written whole when it is taken)
         self._cursor = jnp.zeros((), jnp.int32)
         self._fill = 0
@@ -390,8 +415,13 @@ class StreamJoinExecutor(Executor, Checkpointable):
     # -- control ---------------------------------------------------------
     def on_barrier(self, barrier: Barrier) -> List[StreamChunk]:
         outs = self._flush()
+        took_back = ()
+        if self._retracted_pairs is not None:
+            took_back = (self._retracted_pairs,)
+            self._retracted_pairs = jnp.zeros((), jnp.int64)
         self._staged_scalars = stage_scalars(
-            *_side_stats(self.left), *_side_stats(self.right), *self._counts
+            *_side_stats(self.left), *_side_stats(self.right), *self._counts,
+            *took_back,
         )
         self._counts = jnp.zeros(3, jnp.int64)
         if barrier is None:  # direct drive: checks fire inline
@@ -401,8 +431,21 @@ class StreamJoinExecutor(Executor, Checkpointable):
     def _on_barrier_scalars(self, vals) -> None:
         stats = {"left": vals[0:6], "right": vals[6:12]}
         matched, kept, probe_lanes = (int(v) for v in vals[12:15])
+        retract_pairs = int(vals[15]) if len(vals) > 15 else 0
         held, fullest = {}, {}
+        # the rows the updating sides took this epoch, inserts (rows
+        # appended) and deletes (lanes newly dead) alike
+        retract_rows = dead_lanes = 0
         for name, (_, _, n_rows, claimed, rows, key_max) in stats.items():
+            dead = int(n_rows) - int(rows)
+            if self._retract[name]:
+                retract_rows += int(n_rows) - self._rows_now[name]
+                retract_rows += dead - self._dead[name]
+            dead_lanes += dead - self._dead[name]
+            self._dead[name] = dead
+            REGISTRY.gauge("join_dead_lanes").set(
+                dead, join=self.table_id, side=name
+            )
             self._rows_bound[name] = self._rows_now[name] = int(n_rows)
             self._keys_bound[name] = int(claimed)
             held[name], fullest[name] = int(rows), int(key_max)
@@ -417,6 +460,14 @@ class StreamJoinExecutor(Executor, Checkpointable):
             left_rows=held["left"], right_rows=held["right"],
             key_rows_max=max(fullest.values()), probe_lanes=probe_lanes,
             emit_row_bytes=self._emit_row_bytes,
+            retract_rows=retract_rows, retract_pairs=retract_pairs,
+            dead_lanes=dead_lanes,
+        )
+        REGISTRY.counter("join_retract_rows_total").inc(
+            retract_rows, join=self.table_id
+        )
+        REGISTRY.counter("join_retract_pairs_total").inc(
+            retract_pairs, join=self.table_id
         )
         for name, (overflow, inconsistent, *_rest) in stats.items():
             if overflow:
@@ -550,4 +601,5 @@ class StreamJoinExecutor(Executor, Checkpointable):
         setattr(self, name, fresh)
         self._rows_bound[name] = self._rows_now[name] = n_rows
         self._rows_ckpt[name] = n_rows
+        self._dead[name] = n_rows - len(at)  # (the store keeps live rows)
         self._keys_bound[name] = int(fresh.table.occupancy())

@@ -15,7 +15,8 @@ Rules:
 - SplitFilter / MergeFilter: conjunct normalization
 - PushFilterThroughProject: rewrite via the projection's alias map
 - PushFilterThroughJoin: route conjuncts to the side that owns their
-  columns (cross-side conjuncts stay at the join)
+  columns (cross-side conjuncts stay at the join: in an INNER join's
+  ON, above any other join)
 - PushFilterThroughAgg: predicates on group keys move below the agg
 - SimplifyOuterJoin: a null-rejecting predicate on the nullable side
   turns LEFT/RIGHT/FULL into INNER (the reference's
@@ -381,7 +382,7 @@ def _push_into(node, conjuncts: List[object]):
         return _push_into(node.input, node.conjuncts + conjuncts)
 
     if isinstance(node, LJoin):
-        left_c, right_c, here = [], [], []
+        left_c, right_c, on_c, here = [], [], [], []
         for c in conjuncts:
             # pushing a filter below an outer join's null-padded side
             # would change results; only the row-preserved side accepts
@@ -393,12 +394,23 @@ def _push_into(node, conjuncts: List[object]):
                 left_c.append(c)
             elif can_right and _absorbable(node.right, c):
                 right_c.append(c)
+            elif (
+                node.join_type == "inner"
+                and _owned_by(c, node)
+                and not _owned_by(c, node.left)
+                and not _owned_by(c, node.right)
+            ):
+                # sigma over an inner join is the join under a wider
+                # predicate: a conjunct that reads both sides joins the
+                # ON (an equality becomes a key, anything else the
+                # residual the join evaluates on every matched pair)
+                on_c.append(c)
             else:
                 here.append(c)
         new = LJoin(
             _push_into(node.left, left_c) if left_c else node.left,
             _push_into(node.right, right_c) if right_c else node.right,
-            node.on,
+            _and_all([node.on] + on_c),
             node.join_type,
         )
         return LFilter(new, here) if here else new

@@ -729,7 +729,13 @@ class Parser:
     def interval_ms(self) -> int:
         self.expect("kw", "interval")
         raw = self.expect("str").value
-        unit_tok = self.accept("kw")
+        # (a unit, not whatever keyword follows the literal: in a scalar
+        # expression that may be AS, AND, FROM)
+        t = self.peek()
+        unit_tok = (
+            self.next() if t.kind == "kw" and t.value in INTERVAL_SCALES
+            else None
+        )
         text = raw.strip()
         m = re.fullmatch(r"(\d+)(?:\s+(\w+))?", text)
         if not m:
@@ -899,6 +905,10 @@ class Parser:
             return Literal(True)
         if self.accept("kw", "false"):
             return Literal(False)
+        if t.kind == "kw" and t.value == "interval":
+            # a timestamp lane counts milliseconds, so timestamp +/-
+            # INTERVAL is integer arithmetic on the interval's length
+            return Literal(self.interval_ms())
         if self.accept("kw", "case"):
             branches = []
             while self.accept("kw", "when"):

@@ -517,6 +517,21 @@ def _map_idents(ast, fn):
     return ast
 
 
+def _names_read(select: Optional[P.Select]) -> set:
+    """The column names ``select`` itself reads, anywhere but in its
+    FROM."""
+    names: set = set()
+    if select is not None:
+        _map_idents(
+            (
+                select.items, select.where, select.group_by, select.having,
+                select.grouping_sets, tuple(i for i, _ in select.order_by),
+            ),
+            lambda i: names.add(i.name) or i,
+        )
+    return names
+
+
 def _share_key(select: P.Select):
     """What two sub-selects must agree on to be one sub-plan: the
     relation, the WHERE, the SET of GROUP BY columns and the aggregate
@@ -1005,9 +1020,11 @@ class StreamPlanner:
             return False
         return False
 
-    def _from_bound(self, name: str, src) -> BoundRel:
+    def _from_bound(self, name: str, src, select=None) -> BoundRel:
         """FROM clause -> BoundRel (source chain + schema, no select
-        logic applied yet)."""
+        logic applied yet). ``select``: the select that reads it; a
+        window function computes ``window_end`` only where that names
+        the column."""
         if isinstance(src, _Planned):
             return src.bound
         chain: List[Executor] = []
@@ -1022,13 +1039,18 @@ class StreamPlanner:
             source = src.table.name
             schema = dict(self.catalog.schema_dtypes(source))
             self._maybe_watermark_filter(chain, source, schema)
+            out_end = (
+                "window_end" if "window_end" in _names_read(select) else None
+            )
             chain.append(
                 HopWindowExecutor(
                     src.ts_col, src.size_ms, src.slide_ms,
-                    out_start="window_start",
+                    out_start="window_start", out_end=out_end,
                 )
             )
             schema["window_start"] = jnp.dtype(jnp.int64)
+            if out_end is not None:
+                schema[out_end] = jnp.dtype(jnp.int64)
             # the hop translates the event-time watermark into a
             # window_start watermark (hop_window.py on_watermark), so
             # downstream windowed aggs can clean closed windows
@@ -1092,7 +1114,10 @@ class StreamPlanner:
         select = self._rewrite_distinct(select)
         if select.having is not None and not select.group_by:
             raise ValueError("HAVING requires GROUP BY")
-        bound = pre if pre is not None else self._from_bound(name, select.from_)
+        bound = (
+            pre if pre is not None
+            else self._from_bound(name, select.from_, select)
+        )
         chain = bound.chain
         schema = bound.schema
         pk = bound.pk
@@ -2605,14 +2630,20 @@ class StreamPlanner:
         else:
             pk = tuple(left.pk) + tuple(right.pk)
         proj = {alias or n: E.col(n) for n, alias in out_names}
-        for p in pk:  # pk columns must survive into the MV
-            proj.setdefault(p, E.col(p))
-        tail.append(ProjectExecutor(proj))
         rename = {n: (alias or n) for n, alias in out_names}
         merged = {**left.schema, **right.schema}
         out_schema = {alias or n: merged[n] for n, alias in out_names}
-        for p in pk:
-            out_schema.setdefault(rename.get(p, p), merged[p])
+        for p in pk:  # pk columns must survive into the MV
+            if p in rename:
+                continue
+            # not selected: kept under its own name, or, where a
+            # selected column took that name (``B.date_time`` beside
+            # ``B1``'s key ``date_time``), under a hidden one
+            out = p
+            while out in proj:
+                out = "_" + out
+            proj[out], rename[p], out_schema[out] = E.col(p), out, merged[p]
+        tail.append(ProjectExecutor(proj))
         return (left, right, hj, head), BoundRel(
             tail, out_schema, tuple(rename.get(p, p) for p in pk),
             left.source, None, append_only=out_append_only,

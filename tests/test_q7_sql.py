@@ -108,3 +108,72 @@ def test_q7_via_session_insert_routing():
     out, _ = s.execute("SELECT auction, price FROM q7 ORDER BY auction")
     assert list(out["auction"]) == [3, 4]
     assert list(out["price"]) == [250, 300]
+
+
+# the source's own text (upstream RisingWave's nexmark q7; tests/
+# test_nexmark_q7.py holds it to the benchmark's reference): a bare
+# table beside a derived one, window_end, timestamp - INTERVAL, the
+# band in WHERE, the equi key the price alone
+Q7_SOURCE = (
+    "CREATE MATERIALIZED VIEW q7s AS "
+    "SELECT B.auction, B.price, B.bidder, B.date_time FROM bid B JOIN ("
+    "SELECT MAX(price) AS maxprice, window_end AS date_time "
+    "FROM TUMBLE(bid, date_time, INTERVAL '10' SECOND) GROUP BY window_end"
+    ") B1 ON B.price = B1.maxprice "
+    "WHERE B.date_time BETWEEN B1.date_time - INTERVAL '10' SECOND "
+    "AND B1.date_time"
+)
+
+
+def test_q7_as_the_source_writes_it_lands_on_the_rewritten_shapes_view():
+    """The source's text and the rewritten one (window_start in the
+    equi key, both sides derived) give the same winners over generated
+    bids: none of them is stamped on a window's edge, so the source's
+    inclusive band and the rewrite's window key select alike."""
+    planner = StreamPlanner(Catalog({"bid": BID_SCHEMA}), capacity=1 << 14)
+    source = planner.plan(Q7_SOURCE)
+    rewritten = planner.plan(Q7_SQL)
+    assert source.inputs == rewritten.inputs == {"bid": "both"}
+    assert source.pipeline.join.left_keys == ("price",)
+    for c in _bid_chunks(8):
+        assert not np.any(
+            c.to_numpy()["date_time"] % 10_000 == 0
+        )  # no bid on an edge
+        for mv in (source, rewritten):
+            mv.pipeline.push_left(c)
+            mv.pipeline.push_right(c)
+            mv.pipeline.barrier()
+
+    def winners(mview):
+        cols = mview.to_numpy()
+        return sorted(zip(*(
+            np.asarray(cols[n]).tolist()
+            for n in ("auction", "bidder", "price")
+        )))
+
+    assert winners(source.mview) == winners(rewritten.mview) != []
+
+
+def test_q7_source_text_via_session_retracts_and_keeps_the_edge():
+    from risingwave_tpu.frontend.session import SqlSession
+
+    s = SqlSession(Catalog({}), capacity=1 << 10)
+    s.execute("CREATE TABLE bid (auction BIGINT, bidder BIGINT, "
+              "price BIGINT, date_time BIGINT)")
+    s.execute(Q7_SOURCE)
+    s.execute(
+        "INSERT INTO bid VALUES (1, 10, 100, 1000), (2, 11, 250, 2000), "
+        "(3, 12, 250, 11000)"
+    )
+    out, _ = s.execute("SELECT auction, price FROM q7s ORDER BY auction")
+    assert list(out["auction"]) == [2, 3]
+    # a new max in window 0 RETRACTS auction 2's row
+    s.execute("INSERT INTO bid VALUES (4, 13, 300, 3000)")
+    out, _ = s.execute("SELECT auction, price FROM q7s ORDER BY auction")
+    assert list(out["auction"]) == [3, 4]
+    # a bid stamped exactly on window 0's end at its maximum: window 1's
+    # (where 300 now wins) and, the band being inclusive, window 0's
+    # too — the view is keyed by (row id, window), so both rows stand
+    s.execute("INSERT INTO bid VALUES (5, 14, 300, 10000)")
+    out, _ = s.execute("SELECT auction, price FROM q7s ORDER BY auction")
+    assert list(out["auction"]) == [4, 5, 5]
