@@ -31,10 +31,12 @@ from risingwave_tpu.ops.hash_table import (
     set_live,
 )
 from risingwave_tpu.array.lattice import (
+    TOUCHED_MAX,
     emission_bucket,
     lattice_between,
     pow2_at_least,
 )
+from risingwave_tpu.ops.agg import note_touched
 from risingwave_tpu.ops.bucketing import (
     BucketAllocator,
     BucketPolicy,
@@ -365,11 +367,23 @@ class TopNExecutor(Executor, Checkpointable):
 
 
 @partial(
-    jax.jit, static_argnames=("pk", "names"), donate_argnums=(0, 1, 2, 3)
+    jax.jit,
+    static_argnames=("pk", "names", "n_group"),
+    donate_argnums=(0, 1, 2, 3),
+    donate_argnames=("groups", "listed"),
 )
-def _upsert_step_ed(table, rows, sdirty, epoch_dirty, chunk, pk, names):
+def _upsert_step_ed(
+    table, rows, sdirty, epoch_dirty, chunk, pk, names,
+    groups=None, listed=None, at=None, n_group=0,
+):
     """_upsert_step that also marks epoch_dirty (cleared per barrier)
-    in the same scatter — one probe, two mark lanes."""
+    in the same scatter — one probe, two mark lanes. With ``groups``
+    (a ``_Groups``; the first ``n_group`` lanes of ``pk`` are the group's
+    columns) each row's group is probed beside it and kept by the row's
+    slot, and the slots the step wrote are appended to ``listed`` at
+    lane ``at`` (``note_touched``): what a barrier's rank over the
+    epoch's rows alone starts from. Returns ``groups`` and ``listed``
+    then too."""
     keys = tuple(chunk.col(k) for k in pk)
     signs = chunk.effective_signs()
     active = chunk.valid & (signs != 0)
@@ -382,18 +396,65 @@ def _upsert_step_ed(table, rows, sdirty, epoch_dirty, chunk, pk, names):
     table = set_live(table, jnp.where(active, slots, -1), signs > 0)
     sdirty = sdirty.at[idx].set(True, mode="drop")
     epoch_dirty = epoch_dirty.at[idx].set(True, mode="drop")
-    return table, rows, sdirty, epoch_dirty, dropped
+    if groups is None:
+        return table, rows, sdirty, epoch_dirty, dropped
+    gtable, gslots, _, _ = lookup_or_insert(
+        groups.table, keys[:n_group], active
+    )
+    dropped = dropped | jnp.any(active & (gslots < 0))
+    groups = _Groups(
+        gtable, groups.of_row.at[idx].set(gslots, mode="drop")
+    )
+    listed = note_touched(listed, at, jnp.where(active, slots, -1))
+    return table, rows, sdirty, epoch_dirty, dropped, groups, listed
+
+
+class _Groups(NamedTuple):
+    """The groups that hold a row, beside the row store and as large."""
+
+    table: HashTable  # keyed by the group's columns
+    of_row: jnp.ndarray  # by row slot: its group's slot, -1 = no row yet
+
+
+class _Tops(NamedTuple):
+    """Each group's top k as of the last barrier, a chain through the
+    row slots in rank order: the rows the lane ``emitted`` marks, found
+    from the group and not by a pass over the store."""
+
+    head: jnp.ndarray  # by group slot: its first row's slot, -1 = none
+    after: jnp.ndarray  # by row slot: the next of its group's top k, -1
+
+
+class _Ranked(NamedTuple):
+    """``_rank``'s lanes, in sorted order: all of the store's, or the
+    candidates of the epoch's groups."""
+
+    packed: jnp.ndarray  # the slot, under four flags
+    in_topk: jnp.ndarray
+    seg_start: jnp.ndarray  # the position the lane's group starts at
+    passes: jnp.ndarray  # the sorts the ranking took
+    erank: Optional[jnp.ndarray] = None  # the rank as handed on
+    group: Optional[jnp.ndarray] = None  # the group's slot
+    # the candidates cannot answer (a scalar): rank the store
+    full_rank: Optional[jnp.ndarray] = None
 
 
 # The barrier of the retractable GroupTopN, on the device. A row store
-# that keeps every input row ranks all of its lanes once a barrier (one
-# sort over (group, liveness, order key, stream key)); what the barrier
-# then needs is the difference between the rows that are in their
-# group's top-k NOW and the rows the executor has handed on (the lane
-# ``emitted``), and that difference is two masks in the sorted order.
-# So the rows to retract and the rows to insert are compacted and
+# keeps every input row, and a barrier ranks what the epoch could have
+# moved: the rows its chunks wrote and the top k, as the last barrier
+# left them, of those rows' groups (``_Tops``; ``_candidates``), in one
+# sort over (group, liveness, order key, stream key) of a few tens of
+# thousands of lanes. A group's new top k lies among those unless a row
+# of its old top k was deleted or rewritten while rows stand behind it,
+# which the program sees in its input (``_Ranked.full_rank``); that
+# barrier, and the first after a restore or a re-slotted store, ranks
+# all of the store's lanes by the same sort, as every barrier once did.
+# What the barrier then needs is the difference between the rows that
+# are in their group's top-k NOW and the rows the executor has handed on
+# (the lane ``emitted``), and that difference is two masks in the sorted
+# order. So the rows to retract and the rows to insert are compacted and
 # gathered on the device too (``_diff_gather``), into two chunks of
-# ``out_lanes`` lanes, and the host reads nine counts. ``shadow`` keeps
+# ``out_lanes`` lanes, and the host reads ten counts. ``shadow`` keeps
 # every column as it was when the row was last handed on: an UPDATE
 # overwrites a stored row in place, and its retraction has to carry the
 # old values.
@@ -490,44 +551,88 @@ def _sort_by_words(words, payloads):
     return ops[:n], ops[n:], passes
 
 
+def _sort_by_words_gathered(words, payloads):
+    """``_sort_by_words`` for a few tens of thousands of lanes (a
+    barrier's candidates): the loop's sort carries the order alone and
+    each word is gathered into it, a two-operand sort where one of a
+    dozen. The TPU's compiler takes a sort by its operands — 25 s for
+    one here, 284 s for eleven, whatever the lanes (the chip's compiler
+    for a described v5e, PR 46) — and this program is compiled once a
+    candidate size; a gather of 65,536 lanes costs the device a
+    millisecond, where one of 2^22 cost 40."""
+    lanes = words[0].shape[0]
+    stacked = jnp.stack(words)
+
+    def one(i, carry):
+        order, passes = carry
+        word = stacked[i]
+        shared = jnp.all(word == word[0])
+
+        def sort(order):
+            return jax.lax.sort(
+                (word[order], order), num_keys=1, is_stable=True
+            )[1]
+
+        order = jax.lax.cond(shared, lambda order: order, sort, order)
+        return order, passes + (~shared).astype(jnp.int32)
+
+    order, passes = jax.lax.fori_loop(
+        0, len(words), one,
+        (jnp.arange(lanes, dtype=jnp.int32), jnp.zeros((), jnp.int32)),
+    )
+    return (
+        tuple(stacked[:, order]), tuple(p[order] for p in payloads), passes
+    )
+
+
 def _rank_sorted(
-    table: HashTable, order_lanes, flags, k, descs, n_group, carried=()
+    keys, live, order_lanes, flags, k, descs, n_group, carried=(),
+    slots=None, valid=None,
 ):
-    """Every lane ranked by (group lanes, dead last, the order keys, the
-    rest of the stream key). ``order_lanes`` / ``descs``: the order
-    keys' lanes and their directions, most significant first
-    (``_order_of``). Returns, in sorted order: each lane's slot with
-    ``flags`` (bits above ``_SLOT_MASK``) carried along, whether it is
-    in its group's top-k, the position its group starts at, the sorts
-    the ranking took, and then every lane of ``carried`` (operands that
-    ride along the sort beside the slot: a capacity-wide gather into
-    the sorted order costs three sorts)."""
-    cap = table.capacity
+    """The lanes ranked by (group lanes, dead last, the order keys, the
+    rest of the stream key). ``keys`` / ``live``: the store's key lanes
+    and liveness, whole or gathered at ``slots`` (the candidates' slots;
+    ``valid``: which lanes hold one, the others rank behind every
+    group). ``order_lanes`` / ``descs``: the order keys' lanes and
+    their directions, most significant first (``_order_of``). Returns
+    (``_Ranked``'s first four): each lane's slot with ``flags`` (bits
+    above ``_SLOT_MASK``) carried along, whether it is in its group's
+    top-k, the position its group starts at, the sorts the ranking
+    took; and then every lane of ``carried`` (operands that ride along
+    the sort beside the slot: a capacity-wide gather into the sorted
+    order costs three sorts)."""
+    lanes = live.shape[0]
+    if slots is None:
+        slots = jnp.arange(lanes, dtype=jnp.int32)
     # liveness as its own sort key within the group (a dead-row
     # sentinel would collide with INT64-extreme order values)
-    live_last = (~table.live).astype(jnp.uint32)
+    live_last = (~live).astype(jnp.uint32)
     okeys = tuple(
         _order_key_u64(lane, d) for lane, d in zip(order_lanes, descs)
     )
     digits: Tuple[jnp.ndarray, ...] = ()
-    for lane in reversed(table.keys[n_group:]):
+    for lane in reversed(keys[n_group:]):
         digits += _digits(lane)
     # a later order key's digits lie below an earlier one's
     for okey in reversed(okeys):
         digits += _digits(okey)
     digits += (live_last,)
     n_below = len(digits)  # the digits below the group's
-    for lane in reversed(table.keys[:n_group]):
+    for lane in reversed(keys[:n_group]):
         digits += _digits(lane)
+    if valid is not None:
+        # on top: the lanes that hold no candidate are one group, last
+        digits += ((~valid).astype(jnp.uint32),)
     words, offsets = _packed_words(digits)
-    flags = flags | (table.live.astype(jnp.int32) << _LIVE_BIT)
-    words, (packed_s, *carried_s), passes = _sort_by_words(
-        words, (jnp.arange(cap, dtype=jnp.int32) | flags,) + tuple(carried)
+    flags = flags | (live.astype(jnp.int32) << _LIVE_BIT)
+    sort = _sort_by_words if valid is None else _sort_by_words_gathered
+    words, (packed_s, *carried_s), passes = sort(
+        words, (slots | flags,) + tuple(carried)
     )
     # a group starts where a bit of the group's digits differs from the
     # lane before: the bits from ``offsets[n_below]`` up
     group_bit = offsets[n_below]
-    differs = jnp.zeros(cap - 1, jnp.bool_)
+    differs = jnp.zeros(lanes - 1, jnp.bool_)
     for w, word in enumerate(words):
         below = jnp.clip(group_bit - 32 * w, 0, 32).astype(jnp.uint32)
         mask = jnp.where(
@@ -537,7 +642,7 @@ def _rank_sorted(
         )
         differs = differs | (((word[1:] ^ word[:-1]) & mask) != 0)
     boundary = jnp.concatenate([jnp.ones(1, jnp.bool_), differs])
-    pos = jnp.arange(cap, dtype=jnp.int32)
+    pos = jnp.arange(lanes, dtype=jnp.int32)
     seg_start = jax.lax.cummax(jnp.where(boundary, pos, 0))
     live_s = ((packed_s >> _LIVE_BIT) & 1) > 0
     in_topk_s = live_s & ((pos - seg_start) < k)
@@ -631,7 +736,59 @@ def _compact(lanes: _SetLanes, out_lanes: int, start):
     return jnp.where(valid, pos, 0), valid
 
 
-@partial(jax.jit, static_argnames=("k", "desc", "n_group", "order_col"))
+def _fetch(lane, at, fill=0):
+    """``lane`` at the slots ``at``; ``fill`` where ``at`` holds none
+    (below 0: -1 would read the last lane)."""
+    return lane.at[jnp.where(at >= 0, at, lane.shape[0])].get(
+        mode="fill", fill_value=fill
+    )
+
+
+def _candidates(listed, n_listed, groups: _Groups, tops: _Tops,
+                k: int, lanes: int):
+    """The slots a barrier has to rank, each once, ascending, in
+    ``lanes`` lanes (-1 past them): the rows the epoch's steps wrote
+    (the first ``n_listed`` of ``listed``, which holds a row as often as
+    it was written) and the top k of their groups as the last barrier
+    left it. Two sorts of one operand over ``lanes x (1 + k)`` lanes,
+    the second to close the gaps the first one's repeats leave (one
+    sort in the program, in a loop of two turns:
+    ``_sort_by_words_gathered`` says what a sort costs to compile).
+    Also: whether the lane's group had k rows in its chain (rows may
+    then stand behind them in the store), and how many slots there
+    were (more than ``lanes``: not all are here)."""
+    row = jnp.where(
+        jnp.arange(lanes, dtype=jnp.int32) < n_listed, listed[:lanes], -1
+    )
+
+    def step(at, _):
+        return _fetch(tops.after, at, -1), at
+
+    _, chain = jax.lax.scan(
+        step, _fetch(tops.head, _fetch(groups.of_row, row, -1), -1), None,
+        length=k,
+    )
+    whole = jnp.tile(chain[k - 1] >= 0, 1 + k).astype(jnp.int32)
+    both = jnp.concatenate([row, chain.reshape(-1)])
+    none = jnp.iinfo(jnp.int32).max
+    # a slot's group says ``whole``, so a slot's repeats are equal
+    def sift(_, s):
+        s = jax.lax.sort(s)
+        again = jnp.concatenate([jnp.zeros(1, jnp.bool_), s[1:] == s[:-1]])
+        return jnp.where(again, none, s)
+
+    s = jax.lax.fori_loop(
+        0, 2, sift, jnp.where(both >= 0, both * 2 + whole, none)
+    )
+    n = jnp.sum((s != none).astype(jnp.int32))
+    s = s[:lanes]
+    return jnp.where(s != none, s >> 1, -1), (s != none) & ((s & 1) > 0), n
+
+
+@partial(
+    jax.jit,
+    static_argnames=("k", "desc", "n_group", "order_col", "cand_lanes"),
+)
 def _rank(
     table: HashTable,
     rows: Dict[str, jnp.ndarray],
@@ -643,34 +800,89 @@ def _rank(
     n_group: int,
     order_col: Union[str, Tuple[str, ...]],
     erank: Optional[jnp.ndarray] = None,
-):
-    """The barrier's first program, one per store capacity: every lane
-    ranked, with what the diff needs carried along. ``order_col`` /
-    ``desc``: the order key and its direction, or a tuple of each for
-    an order of several keys, most significant first. Returns, in
-    sorted order, (slot | flags, in its group's top-k, where its group
-    starts) and the sorts made; then, for a Top-N that hands the rank
-    on, ``erank`` (the rank each row was handed on with), which rides
-    along the sort beside the slot."""
-    redo = epoch_dirty & _any_differs(rows, shadow)
+    groups: Optional[_Groups] = None,
+    tops: Optional[_Tops] = None,
+    listed: Optional[jnp.ndarray] = None,
+    n_listed=None,
+    cand_lanes: Optional[int] = None,
+) -> _Ranked:
+    """The barrier's first program: the ranking, with what the diff
+    needs carried along. ``order_col`` / ``desc``: the order key and
+    its direction, or a tuple of each for an order of several keys,
+    most significant first. One program a store capacity ranks every
+    lane; with ``cand_lanes`` one a candidate size ranks the epoch's
+    candidates (``_candidates``, from ``tops`` and ``listed``) in that
+    many lanes, and says in ``full_rank`` if they cannot answer: more
+    of them than lanes, or a row of a group's old top k gone or
+    rewritten where the chain was whole, so that the next row may stand
+    in the store behind it. Returns ``_Ranked``: (slot | flags, in its
+    group's top-k, where its group starts) in sorted order and the
+    sorts made; for a Top-N that hands the rank on ``erank`` (the rank
+    each row was handed on with) and with ``groups`` each lane's
+    group's slot, which ride along the sort beside the slot."""
+    order_cols, descs = _order_of(order_col, desc)
+    if cand_lanes is None:
+        at = valid = None
+
+        def take(lane, fill=0):
+            return lane
+    else:
+        at, whole, n_cand = _candidates(
+            listed, n_listed, groups, tops, k, cand_lanes
+        )
+        valid = at >= 0
+
+        def take(lane, fill=0):
+            return _fetch(lane, at, fill)
+
+    live, handed, dirty = map(take, (table.live, emitted, epoch_dirty))
+
+    def rewritten():
+        return dirty & _any_differs(
+            {n: take(a) for n, a in rows.items()},
+            {n: take(a) for n, a in shadow.items()},
+        )
+
+    if cand_lanes is None:
+        redo = rewritten()
+    else:
+        # only a row that was handed on is asked whether it was
+        # rewritten: an epoch of new rows reads no column for it
+        redo = jax.lax.cond(
+            jnp.any(handed & dirty), rewritten, lambda: jnp.zeros_like(dirty)
+        )
     flags = (
-        (emitted.astype(jnp.int32) << _EMITTED_BIT)
-        | (epoch_dirty.astype(jnp.int32) << _DIRTY_BIT)
+        (handed.astype(jnp.int32) << _EMITTED_BIT)
+        | (dirty.astype(jnp.int32) << _DIRTY_BIT)
         | (redo.astype(jnp.int32) << _REDO_BIT)
     )
-    lanes, descs = _order_of(rows, order_col, desc)
-    return _rank_sorted(
-        table, lanes, flags, k, descs, n_group,
-        carried=() if erank is None else (erank,),
+    carried = () if erank is None else (take(erank),)
+    if groups is not None:
+        carried += (take(groups.of_row, -1),)
+    packed, in_topk, seg_start, passes, *carried = _rank_sorted(
+        tuple(take(lane) for lane in table.keys), live,
+        tuple(take(rows[c]) for c in order_cols), flags, k, descs, n_group,
+        carried=carried,
+        slots=None if at is None else jnp.where(valid, at, _SLOT_MASK),
+        valid=valid,
+    )
+    return _Ranked(
+        packed, in_topk, seg_start, passes,
+        erank=None if erank is None else carried[0],
+        group=None if groups is None else carried[-1],
+        full_rank=None if cand_lanes is None else (
+            (n_cand > cand_lanes)
+            | jnp.any(handed & (~live | redo) & whole)
+        ),
     )
 
 
-def _order_of(rows, order_col, desc):
-    """The order keys' lanes and directions as two tuples, most
+def _order_of(order_col, desc):
+    """The order keys' columns and directions as two tuples, most
     significant first, from one column and its flag or a tuple of each."""
     if isinstance(order_col, str):
-        return (rows[order_col],), (bool(desc),)
-    return tuple(rows[c] for c in order_col), tuple(desc)
+        return (order_col,), (bool(desc),)
+    return tuple(order_col), tuple(desc)
 
 
 def _touched_groups(dirty_s, seg_start):
@@ -745,19 +957,20 @@ def _delta_chunks(ret_cols, ret_valid, ins_cols, ins_valid, out_lanes: int):
     jax.jit,
     static_argnames=("out_lanes", "rank_col"),
     donate_argnums=(2, 3),
-    donate_argnames=("erank",),
+    donate_argnames=("erank", "tops"),
 )
 def _diff_gather(
     table: HashTable,
     rows: Dict[str, jnp.ndarray],
     shadow: Dict[str, jnp.ndarray],
     emitted: jnp.ndarray,
-    ranked,
+    ranked: _Ranked,
     dropped: jnp.ndarray,
     out_lanes: int,
     erank: Optional[jnp.ndarray] = None,
     start: Optional[jnp.ndarray] = None,
     rank_col: Optional[str] = None,
+    tops: Optional[_Tops] = None,
 ):
     """The barrier's second program, one per emission size: the ranking
     diffed against what was handed on, and both deltas gathered. (With
@@ -777,23 +990,30 @@ def _diff_gather(
     the delta has rows, not for ``out_lanes``: the lanes past them keep
     their zeros.
 
-    Returns (emitted, shadow, retractions, insertions, status) with
-    status = [retract rows, insert rows, touched groups, overflow,
+    ``ranked`` holds the store's lanes or an epoch's candidates
+    (``_rank``). Where it says ``full_rank`` the candidates could not
+    answer: nothing is diffed, gathered or written, and the caller
+    ranks the store. With ``tops`` every group ``ranked`` holds has its
+    chain rewritten from the ranking (``_relink``).
+
+    Returns (emitted, shadow, retractions, insertions, status, tops)
+    with status = [retract rows, insert rows, touched groups, overflow,
     dropped latch, slots claimed, live rows, sorts made, lanes the
-    gathers' turns covered]."""
+    gathers' turns covered, ``full_rank``]."""
     if rank_col is not None:
         return _diff_gather_numbered(
             table, rows, shadow, emitted, erank, ranked, dropped, start,
-            rank_col, out_lanes,
+            rank_col, out_lanes, tops,
         )
     cap = table.capacity
-    packed_s, in_topk_s, seg_start, passes = ranked
+    packed_s, in_topk_s, seg_start, passes = ranked[:4]
+    stay = _stay(ranked)
     slot_s = packed_s & _SLOT_MASK
     emitted_s = ((packed_s >> _EMITTED_BIT) & 1) > 0
     dirty_s = ((packed_s >> _DIRTY_BIT) & 1) > 0
     redo_s = ((packed_s >> _REDO_BIT) & 1) > 0
-    ret_s = emitted_s & (~in_topk_s | redo_s)
-    ins_s = in_topk_s & (~emitted_s | redo_s)
+    ret_s = emitted_s & (~in_topk_s | redo_s) & ~stay
+    ins_s = in_topk_s & (~emitted_s | redo_s) & ~stay
     groups = _touched_groups(dirty_s, seg_start)
     ret_set, ins_set = _count_set(ret_s), _count_set(ins_s)
 
@@ -838,12 +1058,56 @@ def _diff_gather(
             table.num_live(),
             passes,
             ret_lanes + ins_lanes,
+            stay.astype(jnp.int32),
         ]
     )
     chunks = _delta_chunks(
         ret_cols, ret_valid, ins_cols, ins_valid, out_lanes
     )
-    return emitted, shadow, chunks[0], chunks[1], status
+    tops = _relink(tops, ranked, cap)
+    return emitted, shadow, chunks[0], chunks[1], status, tops
+
+
+def _stay(ranked: _Ranked):
+    """A scalar: the ranking is of candidates that cannot answer, and
+    nothing may be written from it."""
+    if ranked.full_rank is None:
+        return jnp.zeros((), jnp.bool_)
+    return ranked.full_rank
+
+
+def _relink(tops: Optional[_Tops], ranked: _Ranked, cap: int):
+    """Every group of ``ranked`` (its lanes lie together, first-ranked
+    first) has its chain rewritten: the head from the lane the group
+    starts at, each row of the top k pointing at the lane after it.
+    Over all of the store's lanes the heads start from none, so that a
+    group no lane stands for keeps no row. A lane that writes nothing
+    scatters to a place of its own past the end: the indices are
+    unique, and said to be."""
+    if tops is None:
+        return None
+    packed_s, in_topk_s, seg_start = ranked[:3]
+    pos = jnp.arange(packed_s.shape[0], dtype=jnp.int32)
+    slot_s = packed_s & _SLOT_MASK
+    write = ~_stay(ranked)
+    follows = jnp.concatenate(
+        [in_topk_s[1:] & (seg_start[1:] == seg_start[:-1]),
+         jnp.zeros(1, jnp.bool_)]
+    )
+    after_s = jnp.where(follows, jnp.roll(slot_s, -1), -1)
+    head = tops.head
+    if ranked.full_rank is None:
+        head = jnp.full_like(head, -1)
+    head = head.at[
+        jnp.where(
+            write & (seg_start == pos) & (ranked.group >= 0),
+            ranked.group, cap + pos,
+        )
+    ].set(jnp.where(in_topk_s, slot_s, -1), mode="drop", unique_indices=True)
+    after = tops.after.at[
+        jnp.where(write & in_topk_s, slot_s, cap + pos)
+    ].set(after_s, mode="drop", unique_indices=True)
+    return _Tops(head, after)
 
 
 # The same barrier for a Top-N whose rank is a column of its output
@@ -868,6 +1132,7 @@ def _diff_gather_numbered(
     start: jnp.ndarray,
     rank_col: str,
     out_lanes: int,
+    tops: Optional[_Tops] = None,
 ):
     """``_diff_gather`` for a rank that is handed on as ``rank_col``
     (BIGINT, 1-based): a row is retracted and inserted again when its
@@ -883,22 +1148,24 @@ def _diff_gather_numbered(
     gather; a row only inserted is retracted by none), and ``emitted``
     / ``erank`` are cleared only for a row that leaves for good.
 
-    Returns (emitted, erank, shadow, retractions, insertions, status)
-    with status = [retract rows, insert rows, touched groups, rows
-    moved for their rank alone, dropped latch, slots claimed, live
-    rows, sorts made, lanes the gathers' turns covered]."""
+    Returns (emitted, erank, shadow, retractions, insertions, status,
+    tops) with status = [retract rows, insert rows, touched groups,
+    rows moved for their rank alone, dropped latch, slots claimed, live
+    rows, sorts made, lanes the gathers' turns covered, ``full_rank``].
+    (Every round rewrites the chains, from the same ranking, the same.)"""
     cap = table.capacity
-    packed_s, in_topk_s, seg_start, passes, erank_s = ranked
+    packed_s, in_topk_s, seg_start, passes, erank_s = ranked[:5]
+    stay = _stay(ranked)
     slot_s = packed_s & _SLOT_MASK
     emitted_s = ((packed_s >> _EMITTED_BIT) & 1) > 0
     dirty_s = ((packed_s >> _DIRTY_BIT) & 1) > 0
     redo_s = ((packed_s >> _REDO_BIT) & 1) > 0
-    pos = jnp.arange(cap, dtype=jnp.int32)
+    pos = jnp.arange(packed_s.shape[0], dtype=jnp.int32)
     rank_s = jnp.where(in_topk_s, pos - seg_start + 1, 0)
-    moved_s = emitted_s & in_topk_s & ~redo_s & (erank_s != rank_s)
+    moved_s = emitted_s & in_topk_s & ~redo_s & (erank_s != rank_s) & ~stay
     again_s = redo_s | moved_s
-    ret_s = emitted_s & (~in_topk_s | again_s)
-    ins_s = in_topk_s & (~emitted_s | again_s)
+    ret_s = emitted_s & (~in_topk_s | again_s) & ~stay
+    ins_s = in_topk_s & (~emitted_s | again_s) & ~stay
     groups = _touched_groups(dirty_s, seg_start)
     ret_set, ins_set = _count_set(ret_s), _count_set(ins_s)
 
@@ -961,12 +1228,14 @@ def _diff_gather_numbered(
             table.num_live(),
             passes,
             ret_lanes + ins_lanes,
+            stay.astype(jnp.int32),
         ]
     )
     chunks = _delta_chunks(
         ret_cols, ret_valid, ins_cols, ins_valid, out_lanes
     )
-    return emitted, erank, shadow, chunks[0], chunks[1], status
+    tops = _relink(tops, ranked, cap)
+    return emitted, erank, shadow, chunks[0], chunks[1], status, tops
 
 
 @jax.jit
@@ -989,7 +1258,8 @@ def _topk_ranks(table: HashTable, order_lanes, k: int, descs, n_group: int):
     as ``_rank_sorted`` takes them."""
     cap = table.capacity
     packed_s, in_topk_s, seg_start, _ = _rank_sorted(
-        table, order_lanes, jnp.zeros(cap, jnp.int32), k, descs, n_group
+        table.keys, table.live, order_lanes, jnp.zeros(cap, jnp.int32), k,
+        descs, n_group,
     )
     rank_s = jnp.arange(cap, dtype=jnp.int32) - seg_start + 1
     return jnp.zeros(cap, jnp.int32).at[packed_s & _SLOT_MASK].set(
@@ -1007,6 +1277,20 @@ def emission_lanes(epoch_lanes: int, capacity: int) -> int:
     return min(lanes, capacity)
 
 
+def candidate_lanes(epoch_lanes: int, capacity: int, k: int) -> Optional[int]:
+    """Lanes of the barrier's rank over an epoch's candidates: the
+    emission sizes' smallest that holds twice the lanes the epoch's
+    chunks held (each of their rows and, where k is 1, its group's old
+    first; the program says when a larger k's did not fit). None where
+    the store is to be ranked: the candidates' sorts, of that many
+    lanes x (1 + k), would cover no fewer lanes than the store has, or
+    they are more than the list of the epoch's slots holds."""
+    lanes = emission_lanes(2 * epoch_lanes, capacity)
+    if lanes > TOUCHED_MAX or lanes * (1 + k) >= capacity:
+        return None
+    return lanes
+
+
 class RetractableGroupTopNExecutor(Executor, Checkpointable):
     """GROUP BY g ORDER BY o [DESC], ... LIMIT k with full retraction
     support (group_top_n.rs:63): deletes/updates crossing a group's
@@ -1015,14 +1299,26 @@ class RetractableGroupTopNExecutor(Executor, Checkpointable):
     order keys as (column, desc) pairs, most significant first; rows
     equal in every order key rank by the stream key ``pk``.
 
-    TPU re-design: ONE pk-keyed row store holds every input row; the
-    barrier ranks rows within groups on device (one sort + segmented
-    scans), diffs the ranking against the lane of rows it has handed
-    on (``emitted``; their values as handed on in ``shadow``) and
-    gathers the rows to retract and to insert into two chunks of a
-    declared size, in two programs (``_rank``, ``_diff_gather``). The
-    host reads nine counts and the epoch's input rows a barrier, in
-    one read, and walks no row.
+    TPU re-design: ONE pk-keyed row store holds every input row, and
+    beside it every group's top k is kept as a chain through the row
+    slots (``_Tops``, found through ``_Groups``: the reference's
+    ``TopNCache`` beside its state table, in lanes; derived like
+    ``emitted``, never checkpointed). A step probes each row's group
+    beside the row and lists the slots it wrote. The barrier ranks, on
+    the device, the listed rows and the chains of their groups — what
+    the epoch touched, not the store — diffs the ranking against the
+    lane of rows it has handed on (``emitted``; their values as handed
+    on in ``shadow``), gathers the rows to retract and to insert into
+    two chunks of a declared size and rewrites the chains, in two
+    programs (``_rank``, ``_diff_gather``). The host reads ten counts
+    and the epoch's input rows a barrier, in one read, and walks no
+    row. Where the candidates cannot answer — a row of a group's top k
+    was deleted or rewritten and rows stand behind it in the store,
+    which the program sees and says in that read; or the chains are
+    cold (after ``restore_state`` or a re-slotted store), or the
+    epoch's lanes x (1 + k) are no fewer than the store's — the same
+    two programs rank all of the store's lanes, exactly, and rewrite
+    every chain from that.
 
     ``rank_col``: the name under which the row's rank in its group
     (ROW_NUMBER(): BIGINT, 1-based, ties by the stream key) is handed
@@ -1112,6 +1408,16 @@ class RetractableGroupTopNExecutor(Executor, Checkpointable):
         self.erank = (
             jnp.zeros(capacity, jnp.int32) if rank_col is not None else None
         )
+        # the groups and each one's top k (derived too: ``_regroup``)
+        self.groups, self.tops = self._regroup(self.table, None)
+        # the slots the epoch's steps wrote, from lane 0 (``_step``);
+        # its cursor, None from the step the list had no room for until
+        # the barrier (which then ranks the store)
+        self.listed = jnp.full(TOUCHED_MAX, -1, jnp.int32)
+        self._listed_lanes: Optional[int] = 0
+        # the chains do not say the groups' top k: the next barrier
+        # ranks the store and rewrites them all
+        self._cold = False
         # lanes of the chunks applied since the last barrier: what the
         # chunks a barrier hands on are sized from (``emission_lanes``)
         self._epoch_lanes = 0
@@ -1166,8 +1472,12 @@ class RetractableGroupTopNExecutor(Executor, Checkpointable):
                 c,
                 self.store_keys,
                 self.names,
+                groups=self.groups,
+                listed=self.listed,
+                at=0,
+                n_group=len(self.group_by),
             ),
-            "state": (self.table, self.rows),
+            "state": (self.table, self.rows, self.groups),
             "donate": True,
             # the barrier ranks, diffs and gathers on the device and
             # hands on chunks of a declared size (emission_lanes: the
@@ -1184,12 +1494,21 @@ class RetractableGroupTopNExecutor(Executor, Checkpointable):
             ),
         }
 
+    # epochs whose barrier programs a view's creation compiles: one of
+    # every pair of sizes (the candidates', the emissions') an epoch of
+    # up to 2^15 lanes (4 chunks of 8,192) can take. A program costs a
+    # start some 0.7 s to load even from a warm cache (PERF.md, PR 46),
+    # so an epoch of up to 2^16 lanes finds its emission size compiled,
+    # as before, and the candidates' next size up (262,144 lanes) is
+    # compiled when an epoch first needs it, as larger emissions are
+    _WARM_EPOCHS = (1, 1 << 14, 1 << 15)
+
     def emission_sizes(self) -> Tuple[int, ...]:
         """The emission sizes a view's creation compiles: what epochs
-        of up to 2^16 lanes (8 chunks of 8,192) hand on."""
+        of up to 2^16 lanes hand on."""
         cap = self.table.capacity
         return tuple(
-            sorted({emission_lanes(n, cap) for n in (1, 1 << 16)})
+            sorted({emission_lanes(n, cap) for n in self._WARM_EPOCHS})
         )
 
     def state_nbytes(self) -> int:
@@ -1198,7 +1517,8 @@ class RetractableGroupTopNExecutor(Executor, Checkpointable):
             leaf.nbytes
             for leaf in jax.tree.leaves(
                 (self.table, self.rows, self.shadow, self.emitted,
-                 self.erank, self.epoch_dirty, self.sdirty, self.stored)
+                 self.erank, self.epoch_dirty, self.sdirty, self.stored,
+                 self.groups, self.tops, self.listed)
             )
         )
 
@@ -1227,18 +1547,25 @@ class RetractableGroupTopNExecutor(Executor, Checkpointable):
         self._epoch_lanes += chunk.capacity
         # the step's enqueue (the device runs it asynchronously), as
         # actor.agg_step is for an aggregate
+        at = self._listed_lanes
+        if at is not None and at + chunk.capacity > self.listed.shape[0]:
+            at = None
+        self._listed_lanes = None if at is None else at + chunk.capacity
         with span("actor.topn_step", table_id=self.table_id):
-            self._step(chunk)
+            # (a list given up: the write lands where nothing reads)
+            self._step(chunk, at or 0)
             self._in_rows = _count_valid(self._in_rows, chunk.valid)
         return []
 
-    def _step(self, chunk: StreamChunk) -> None:
+    def _step(self, chunk: StreamChunk, at: int) -> None:
         (
             self.table,
             self.rows,
             self.sdirty,
             self.epoch_dirty,
             dropped,
+            self.groups,
+            self.listed,
         ) = _upsert_step_ed(
             self.table,
             self.rows,
@@ -1247,18 +1574,48 @@ class RetractableGroupTopNExecutor(Executor, Checkpointable):
             chunk,
             self.store_keys,
             self.names,
+            groups=self.groups,
+            listed=self.listed,
+            at=at,
+            n_group=len(self.group_by),
         )
         self._dropped = self._dropped | dropped
+
+    def _regroup(self, table: HashTable, keep: Optional[jnp.ndarray]):
+        """``_Groups`` of the rows ``keep`` marks in ``table`` (None:
+        an empty store), and chains that hold no row yet: what a
+        re-slotted or restored store starts from."""
+        cap, n = table.capacity, len(self.group_by)
+        gtable = HashTable.create(cap, tuple(x.dtype for x in table.keys[:n]))
+        of_row = jnp.full(cap, -1, jnp.int32)
+        if keep is not None:
+            gtable, of_row, _, _ = lookup_or_insert(
+                gtable, table.keys[:n], keep
+            )
+        none = jnp.full(cap, -1, jnp.int32)
+        return _Groups(gtable, of_row), _Tops(none, jnp.copy(none))
 
     # -- the sizes, before they are met ----------------------------------
     def warm_emissions(self) -> List[StreamChunk]:
         """One chunk with no valid row of every declared emission size,
         for the actor's warm-up pass; each comes out of the barrier's
-        own program over a store nothing has dirtied, so that program
-        is compiled for the size too."""
+        own programs over a store nothing has dirtied — the rank over
+        candidates at every size ``_WARM_EPOCHS`` can take, and the
+        rank over the store — so those are compiled too."""
         if self._epoch_lanes:
             raise RuntimeError(f"{self.table_id}: warm-up after rows arrived")
-        return [self._rank_diff(lanes)[0] for lanes in self.emission_sizes()]
+        cap = self.table.capacity
+        outs: Dict[Optional[int], set] = {}
+        for n in self._WARM_EPOCHS:
+            for cand in (candidate_lanes(n, cap, self.limit), None):
+                outs.setdefault(cand, set()).add(emission_lanes(n, cap))
+        chunks = {}
+        for cand, sizes in outs.items():
+            # one ranking a length, diffed at each emission size
+            ranked = self._ranked(cand, self.epoch_dirty, 0)
+            for out in sizes:
+                chunks[out] = self._round(ranked, 0, out)[0]
+        return [chunks[out] for out in sorted(chunks)]
 
     # one upsert step a chunk at the chunk's own width: takes the push
     # lattice (the rank is sized by the lanes the epoch's chunks held)
@@ -1273,7 +1630,7 @@ class RetractableGroupTopNExecutor(Executor, Checkpointable):
             chunk.capacity, GROW_AT,
         ):
             return []
-        self._step(chunk)
+        self._step(chunk, 0)
         # (compiled for the width; no valid row, so the count stands)
         _count_valid(self._in_rows, chunk.valid)
         return []
@@ -1314,58 +1671,77 @@ class RetractableGroupTopNExecutor(Executor, Checkpointable):
             if self.erank is not None:
                 self.erank = move(self.erank)
             self.table = new
+            # the groups' slots and the chains named the old slots
+            self.groups, self.tops = self._regroup(new, move(keep))
+            self._listed_lanes, self._cold = None, True
             claimed = int(self.table.occupancy())
         self._bound = claimed
 
-    def _rank_diff(self, out_lanes: int):
+    def _rank_diff(
+        self, out_lanes: int, cand_lanes: Optional[int], dirty, n_listed: int
+    ):
         """The barrier's two programs at one emission size: the rank
-        (one program a capacity), then the diff and the gathers (one an
-        emission size). Returns (retractions, insertions, status on the
-        device, the ranking: what a further round of a numbered Top-N
-        is computed from)."""
-        ranked = _rank(
+        (``_ranked``), then the diff and the gathers (one program an
+        emission size and a ranking's length). Returns (retractions,
+        insertions, status on the device, the ranking: what a further
+        round of a numbered Top-N is computed from)."""
+        ranked = self._ranked(cand_lanes, dirty, n_listed)
+        return self._round(ranked, 0, out_lanes) + (ranked,)
+
+    def _ranked(self, cand_lanes: Optional[int], dirty, n_listed: int):
+        """The barrier's first program: over ``cand_lanes`` candidates
+        of the ``n_listed`` listed slots (one program a size), or with
+        None over the store (one a capacity). ``dirty``: the epoch's
+        dirty lane."""
+        return _rank(
             self.table,
             self.rows,
             self.shadow,
             self.emitted,
-            self.epoch_dirty,
+            dirty,
             self.limit,
             self.desc,
             len(self.group_by),
             self.order_col,
             self.erank,
+            groups=self.groups,
+            **(
+                {}
+                if cand_lanes is None
+                else dict(
+                    tops=self.tops, listed=self.listed, n_listed=n_listed,
+                    cand_lanes=cand_lanes,
+                )
+            ),
         )
-        if self.rank_col is not None:
-            return self._round(ranked, 0, out_lanes) + (ranked,)
-        self.emitted, self.shadow, ret, ins, status = _diff_gather(
-            self.table,
-            self.rows,
-            self.shadow,
-            self.emitted,
-            ranked,
-            self._dropped,
-            out_lanes,
-        )
-        return ret, ins, status, ranked
 
-    def _round(self, ranked, start: int, out_lanes: int):
-        """One round of a numbered Top-N's delta: the retractions and
-        the insertions from the ``start``-th on. (retractions,
-        insertions, status on the device)."""
-        (
-            self.emitted, self.erank, self.shadow, ret, ins, status
-        ) = _diff_gather(
-            self.table,
-            self.rows,
-            self.shadow,
-            self.emitted,
-            ranked,
-            self._dropped,
-            out_lanes,
-            erank=self.erank,
-            start=jnp.asarray(start, jnp.int32),
-            rank_col=self.rank_col,
+    def _round(self, ranked: _Ranked, start: int, out_lanes: int):
+        """One round of the delta: the retractions and the insertions
+        from the ``start``-th on (a Top-N that hands on no rank makes
+        one). (retractions, insertions, status on the device)."""
+        numbered = (
+            {}
+            if self.rank_col is None
+            else dict(
+                erank=self.erank, start=jnp.asarray(start, jnp.int32),
+                rank_col=self.rank_col,
+            )
         )
+        self.emitted, *erank, self.shadow, ret, ins, status, self.tops = (
+            _diff_gather(
+                self.table,
+                self.rows,
+                self.shadow,
+                self.emitted,
+                ranked,
+                self._dropped,
+                out_lanes,
+                tops=self.tops,
+                **numbered,
+            )
+        )
+        if erank:
+            (self.erank,) = erank
         return ret, ins, status
 
     def on_barrier(self, barrier: Barrier) -> List[StreamChunk]:
@@ -1377,26 +1753,51 @@ class RetractableGroupTopNExecutor(Executor, Checkpointable):
                 self._buckets.note_barrier(cap, self._bound)
             return []
         lanes = emission_lanes(self._epoch_lanes, cap)
+        # the candidates' lanes; None (the store is ranked) where the
+        # chains are cold, the list was given up or the sizes say so
+        cand = (
+            None
+            if self._cold or self._listed_lanes is None
+            else candidate_lanes(self._epoch_lanes, cap, self.limit)
+        )
         numbered = self.rank_col is not None
         with span(
             "topn.rank", table_id=self.table_id, lanes=lanes, capacity=cap,
             order_keys=len(self.order), words=self._sort_operands,
             row_bytes=self._row_bytes, limit=self.limit,
-            rank_emitted=numbered,
+            rank_emitted=numbered, candidates=cand or 0,
         ):
-            ret, ins, status, ranked = self._rank_diff(lanes)
-            self.epoch_dirty = jnp.zeros_like(self.epoch_dirty)
-            self._epoch_lanes = 0
+            dirty = self.epoch_dirty
+            ret, ins, status, ranked = self._rank_diff(
+                lanes, cand, dirty, self._listed_lanes or 0
+            )
+            self.epoch_dirty = jnp.zeros_like(dirty)
+            self._epoch_lanes = self._listed_lanes = 0
             fed, self._in_rows = self._in_rows, jnp.zeros((), jnp.int32)
         # ONE read for the counts, the latch, the occupancy and the
         # epoch's input rows; it waits for the rank
         with span("topn.pull", table_id=self.table_id) as sp:
-            with device_read("topn.status", lanes=10):
+            with device_read("topn.status", lanes=11):
                 status, fed = jax.device_get((status, fed))
+            *counts, sorts, gathered, full_rank = status.tolist()
+            touched_passes = 0 if cand is None else sorts
+            if full_rank:
+                # the candidates could not answer, and their programs
+                # wrote nothing: the store, by the same two
+                ret, ins, status, ranked = self._rank_diff(
+                    lanes, None, dirty, 0
+                )
+                with device_read("topn.status", lanes=10):
+                    *counts, sorts, gathered, _ = jax.device_get(
+                        status
+                    ).tolist()
+            full_rank = int(full_rank or cand is None)
+            passes = sorts if full_rank else 0
+            # (a rank over the store rewrites every chain)
+            self._cold = False
             # (the fourth count: of a numbered Top-N the rows that moved
             # for their rank alone; else whether a delta passed ``lanes``)
-            (n_ret, n_ins, groups, fourth, dropped, claimed, live,
-             passes, gathered) = status.tolist()
+            n_ret, n_ins, groups, fourth, dropped, claimed, live = counts
             moved, overflow = (fourth, 0) if numbered else (0, fourth)
             # a numbered delta beyond the chunks' size takes more rounds,
             # whose turns follow from the counts as the first's did
@@ -1408,9 +1809,18 @@ class RetractableGroupTopNExecutor(Executor, Checkpointable):
                 for n in (n_ret, n_ins)
                 if n > r * lanes
             )
+            # ``passes``: the sorts over the store's capacity (none
+            # where the candidates answered); ``ranked_lanes``: the
+            # lanes this barrier's ranking sorts ran over;
+            # ``sifted_lanes``: what the candidates' own two sorts, of
+            # one operand, ran over to find them
+            ranked_lanes = (cand or 0) + (cap if full_rank else 0)
             sp.args.update(
                 rows=n_ret + n_ins, groups=groups, passes=passes,
                 rank_moved_rows=moved, rounds=rounds, gather_lanes=gathered,
+                touched_passes=touched_passes, ranked_lanes=ranked_lanes,
+                sifted_lanes=(cand or 0) * (1 + self.limit),
+                full_rank=full_rank, rank_calls=1,
             )
         with span(
             "topn.diff",
@@ -1450,6 +1860,12 @@ class RetractableGroupTopNExecutor(Executor, Checkpointable):
             emitted.inc(n_ins, table_id=self.table_id, op="insert")
             REGISTRY.counter("group_topn_gathered_lanes_total").inc(
                 gathered, table_id=self.table_id
+            )
+            REGISTRY.counter("group_topn_ranked_lanes_total").inc(
+                ranked_lanes, table_id=self.table_id
+            )
+            REGISTRY.counter("group_topn_full_ranks_total").inc(
+                full_rank, table_id=self.table_id
             )
             if numbered:
                 REGISTRY.counter("group_topn_rank_moved_rows_total").inc(
@@ -1548,8 +1964,13 @@ class RetractableGroupTopNExecutor(Executor, Checkpointable):
             self.stored = self.stored.at[slots].set(True)
         self.table = table
         self.rows = rows
+        # the groups anew; the chains wait for the next barrier's rank
+        self.groups, self.tops = self._regroup(
+            table, table.live if n else None
+        )
+        self._cold = bool(n)
         self._bound = int(n)
-        self._epoch_lanes = 0
+        self._epoch_lanes = self._listed_lanes = 0
         self._in_rows = jnp.zeros((), jnp.int32)
         self._dropped = jnp.zeros((), jnp.bool_)
         # every group's current top-k stands downstream (the MV was

@@ -21,6 +21,7 @@ from risingwave_tpu.executors.over_window import GeneralOverWindowExecutor
 from risingwave_tpu.executors.row_id_gen import RowIdGenExecutor
 from risingwave_tpu.executors.top_n_plain import (
     RetractableGroupTopNExecutor,
+    candidate_lanes,
     emission_lanes,
 )
 from risingwave_tpu.frontend import SqlSession
@@ -449,6 +450,14 @@ def test_one_barrier_leaves_the_topn_spans_and_counters(tmp_path):
         pairs = set(zip(bids["bidder"][256:512].tolist(),
                         bids["auction"][256:512].tolist()))
         assert pull.args["groups"] == diff.args["groups"] == len(pairs)
+        # which way the barrier went, and over how many lanes: at this
+        # size the store has no more lanes than the candidates' sorts
+        # would cover, so it is ranked, in one to nine sorts
+        cand = candidate_lanes(256, ex.table.capacity, ex.limit)
+        assert cand is None and rank.args["candidates"] == 0
+        assert pull.args["full_rank"] == pull.args["rank_calls"] == 1
+        assert pull.args["ranked_lanes"] == ex.table.capacity
+        assert pull.args["touched_passes"] == pull.args["sifted_lanes"] == 0
         assert 1 <= pull.args["passes"] <= 9
         assert touched.get(table_id=tid) - before[0] == len(pairs)
         assert rows.get(table_id=tid, op="insert") - before[1] == inserted

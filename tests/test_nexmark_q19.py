@@ -297,10 +297,10 @@ def test_an_executor_that_hands_on_no_moved_rank_fails_the_reference(
     real = top_n_plain._diff_gather
 
     def blind(table, rows, shadow, emitted, ranked, *rest, **numbered):
-        packed_s, in_topk_s, seg_start, passes, erank_s = ranked
-        now = jnp.arange(table.capacity, dtype=jnp.int32) - seg_start + 1
-        ranked = (packed_s, in_topk_s, seg_start, passes,
-                  jnp.where(in_topk_s, now, 0))
+        now = jnp.arange(
+            ranked.packed.shape[0], dtype=jnp.int32
+        ) - ranked.seg_start + 1
+        ranked = ranked._replace(erank=jnp.where(ranked.in_topk, now, 0))
         return real(table, rows, shadow, emitted, ranked, *rest, **numbered)
 
     monkeypatch.setattr(top_n_plain, "_diff_gather", blind)
@@ -490,7 +490,12 @@ def test_the_pull_says_the_lanes_the_gathers_covered(tmp_path, monkeypatch):
             served.rt.barrier()
             (pull,) = _spans("topn.pull")
             assert pull.args["rows"] == 2 * 10 * auctions
-            assert pull.args["rounds"] == rounds and pull.args["passes"] > 0
+            assert pull.args["rounds"] == rounds
+            # (the first epoch's candidates answer; the second's 64
+            # auctions bring ten rows each, more than the lanes hold)
+            assert pull.args["full_rank"] == (auctions == 64)
+            assert pull.args["touched_passes"] > 0
+            assert (pull.args["passes"] > 0) == (auctions == 64)
             assert pull.args["gather_lanes"] == lanes
             assert covered.get(table_id=tid) - before == lanes
         assert len(served.ranks()) == 640
@@ -642,17 +647,20 @@ def test_the_rank_over_a_join_is_handed_on_too(tmp_path):
 # sha256 of ``_rank.lower(...).as_text()`` and of
 # ``_diff_gather.lower(...).as_text()`` for the Top-N the planner makes
 # of each configuration's text, at 2^22 lanes and chunks of 2^16: the
-# rank's taken on the commit before the rank could be a column (7f7d2b4)
-# and standing since; the diff's taken anew where its gathers went into
-# blocks (PR 42), which left the rank's as they were
+# rank's stood from the commit before the rank could be a column
+# (7f7d2b4) until the ranking became a named tuple that a rank over an
+# epoch's candidates shares (PR 46: the results' names and the place of
+# the slots' iota are what differs in the text, no operation; taken anew
+# there), the diff's from where its gathers went into blocks (PR 42)
+# until it said ``full_rank`` in a tenth count (PR 46, taken anew)
 PINNED = {
     "nexmark_q18": (
-        "a626d518cd2aa7d99df18a5663b08838a0219bb85b0f0f3fe4fa39f9aeb331d5",
-        "76f2bfd22372e7c0a957c90079b0d19829613135c33c94ed894f7d454db43b99",
+        "3c78c8f708df032a5f90537bb1d6509cdfe2962752957a39c85c8b55b745d067",
+        "0e699b9f3b7b6acd12abd19217c3fd9c98b2b7a7a8302bd0124ac0d6479afd76",
     ),
     "nexmark_q9": (
-        "c4cd9ea6c31242113d9e796655ef4aa682c77efbc66b2770df6b9c8cbf6ece0e",
-        "d6cf434b3e64c14b9c6352e6e081fd1f1583f4382a60d3eb51e0d2453eb7a0ae",
+        "ef9384bc6e70c5b3556c05d3073a748314d0205e76236182370d80c1f11729cf",
+        "4f112dd061b64d5c9799eb74415bcea9311338ecdc2c083fa6f6765a53cf1821",
     ),
 }
 
@@ -719,7 +727,9 @@ def test_the_numbered_topn_runs_the_same_two_programs_by_name():
     ranked = jax.eval_shape(
         lambda *a: _rank(*a, erank=ex.erank, **static), *args
     )
-    assert len(ranked) == 5  # ... and the rank as handed on, sorted
+    # ... and the rank as handed on, sorted
+    assert ranked.erank.shape == ranked.packed.shape
+    assert ranked.group is None and ranked.full_rank is None
     diff = _diff_gather.lower(
         *args[:4], ranked, jax.ShapeDtypeStruct((), jnp.bool_),
         out_lanes=64, erank=ex.erank,

@@ -447,6 +447,53 @@ def test_agg_declares_and_cuts_to_the_flush_lattice(out_cap):
     assert small.trace_contract()["emission_caps"] == (256, 512, 2048)
 
 
+def test_topn_candidate_sizes_compile_when_the_view_is_created():
+    """The GroupTopN's barrier ranks an epoch's candidates in a declared
+    number of lanes (``candidate_lanes``: twice the epoch's, on the
+    emission lattice) and hands on chunks of a declared size; every
+    pair an epoch of up to 2^15 lanes can take, and the pair that ranks
+    the store, is compiled by ``warm_emissions``: epochs of one, two
+    and four chunks of 8,192 then open no compile, whichever way their
+    barrier goes."""
+    from risingwave_tpu.executors.top_n_plain import (
+        RetractableGroupTopNExecutor,
+        _diff_gather,
+        _rank,
+        candidate_lanes,
+    )
+    from risingwave_tpu.trace import TRACER
+
+    cap = 1 << 18
+    ex = RetractableGroupTopNExecutor(
+        ("g",), "v", 1, ("id",), {"g": I64, "id": I64, "v": I64},
+        capacity=cap, table_id="sizes.gtopn",
+    )
+    assert [candidate_lanes(n, cap, 1) for n in ex._WARM_EPOCHS] == [
+        1 << 14, 1 << 16, 1 << 16,
+    ]
+    assert [c.capacity for c in ex.warm_emissions()] == [1 << 14, 1 << 16]
+    assert ex.emission_sizes() == (1 << 14, 1 << 16)
+    assert int(ex.table.occupancy()) == 0 and not ex._cold
+    compiled = _rank._cache_size(), _diff_gather._cache_size()
+    next_id = 0
+    for chunks, cold in ((1, False), (2, False), (4, False), (1, True)):
+        for _ in range(chunks):
+            ids = np.arange(next_id, next_id + 100, dtype=np.int64)
+            next_id += 100
+            ex.apply(StreamChunk.from_numpy(
+                {"g": ids % 7, "id": ids, "v": ids % 13}, 1 << 13
+            ))
+        ex._cold = cold
+        TRACER.clear()
+        ex.on_barrier(None)
+        (pull,) = [sp for sp in TRACER.spans() if sp.name == "topn.pull"]
+        assert pull.args["full_rank"] == int(cold)
+        assert pull.args["ranked_lanes"] == (
+            cap if cold else candidate_lanes(chunks << 13, cap, 1)
+        )
+    assert (_rank._cache_size(), _diff_gather._cache_size()) == compiled
+
+
 def test_padding_stats_accounting():
     from risingwave_tpu.executors.dedup import AppendOnlyDedupExecutor
 

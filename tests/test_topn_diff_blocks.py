@@ -269,6 +269,7 @@ def _recompute(ex, delta, passes, start, out_lanes):
         int(np.asarray(ex.table.live).sum()),
         passes,
         covered,
+        0,  # the ranking is the store's: it answers
     ]
     return emitted, erank, shadow, ret, ins, status
 
@@ -277,7 +278,8 @@ def _run_round(ex, ranked, start, out_lanes):
     """``_diff_gather`` as the executor calls it, its results put back
     into the executor: (retract chunk, insert chunk, status)."""
     if ex.rank_col is None:
-        ex.emitted, ex.shadow, ret, ins, status = _diff_gather(
+        # (no chains handed in: none comes back)
+        ex.emitted, ex.shadow, ret, ins, status, _ = _diff_gather(
             ex.table, ex.rows, ex.shadow, ex.emitted, ranked, ex._dropped,
             out_lanes,
         )
@@ -314,6 +316,7 @@ def _rank_of(ex):
     return _rank(
         ex.table, ex.rows, ex.shadow, ex.emitted, ex.epoch_dirty, ex.limit,
         ex.desc, len(ex.group_by), ex.order_col, ex.erank,
+        groups=ex.groups,
     )
 
 
