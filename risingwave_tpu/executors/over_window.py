@@ -37,6 +37,16 @@ from risingwave_tpu.ops.hash_table import (
     set_live,
 )
 from risingwave_tpu.executors.sort import ArenaBufferedExecutor
+from risingwave_tpu.executors.top_n_plain import (
+    _EMIT_FLOOR,
+    _count_set,
+    _digits,
+    _in_turns,
+    _order_by_words,
+    _packed_words,
+    _put,
+)
+from risingwave_tpu.metrics import REGISTRY
 from risingwave_tpu.storage.state_table import (
     Checkpointable,
     StateDelta,
@@ -44,6 +54,8 @@ from risingwave_tpu.storage.state_table import (
     grow_pow2,
     pull_rows,
 )
+from risingwave_tpu.trace import device_read, span
+from risingwave_tpu.types import Op
 
 GROW_AT = 0.5
 
@@ -70,7 +82,9 @@ class WindowCall:
     ..CURRENT ROW (running). ``offset``: lead/lag distance."""
 
     kind: str
-    input: Optional[str]  # None for row_number / count(*)
+    # None for row_number / count(*); a count WITH an input counts the
+    # rows whose input is not NULL (the general executor alone)
+    input: Optional[str]
     output: str
     frame: Optional[Tuple[int, int]] = None
     offset: int = 1
@@ -78,7 +92,9 @@ class WindowCall:
     def __post_init__(self):
         if self.kind not in KINDS:
             raise ValueError(f"unsupported window kind {self.kind!r}")
-        if (self.input is None) != (self.kind in ("row_number", "count")):
+        if self.kind != "count" and (self.input is None) != (
+            self.kind == "row_number"
+        ):
             raise ValueError(f"{self.kind} input mismatch")
         if self.frame is not None:
             lo, hi = self.frame
@@ -86,13 +102,22 @@ class WindowCall:
                 raise ValueError(f"frame {self.frame}: lo > hi")
             if hi - lo + 1 > 64:
                 raise ValueError(
-                    "ROWS frames wider than 64 are not supported (the "
-                    "fused kernel combines one shift per frame row)"
+                    "ROWS frames wider than 64 are not supported (a step "
+                    "combines one shifted copy of the lane per frame row)"
                 )
             if self.kind not in ("sum", "min", "max", "count"):
                 raise ValueError(f"{self.kind} does not take a frame")
         if self.offset < 1:
             raise ValueError("lead/lag offset must be >= 1")
+
+
+def _no_counted_input(calls) -> None:
+    for c in calls:
+        if c.kind == "count" and c.input is not None:
+            raise ValueError(
+                f"COUNT({c.input}) OVER counts non-NULL inputs: the "
+                "general executor's (GeneralOverWindowExecutor)"
+            )
 
 
 def _accum_names(call: "WindowCall"):
@@ -613,6 +638,7 @@ class EowcOverWindowExecutor(ArenaBufferedExecutor):
                 "(a closed partition may receive no further rows)"
             )
         self.calls = tuple(calls)
+        _no_counted_input(self.calls)
         for c in self.calls:
             if (
                 c.kind in ("rank", "dense_rank")
@@ -688,6 +714,7 @@ class OverWindowExecutor(Executor, Checkpointable):
     ):
         self.part_keys = tuple(partition_by)
         self.calls = tuple(calls)
+        _no_counted_input(self.calls)
         for c in self.calls:
             if c.kind == "lead" or c.frame is not None:
                 raise ValueError(
@@ -913,23 +940,64 @@ class OverWindowExecutor(Executor, Checkpointable):
 # General (retractable) over-window
 # ---------------------------------------------------------------------------
 
+# What a step hands on is sized from what it changed: the smallest of
+# ``_EMIT_FLOOR`` x 4^i up to ``_EMIT_MAX`` that holds the larger delta
+# (never more than the arena), and a delta beyond ``_EMIT_MAX`` goes in
+# further rounds of that size. Both sizes are compiled, with what
+# follows the executor, when a graph-mode view is created.
+_EMIT_MAX = 1 << 16
+
+
+def emission_sizes(capacity: int) -> Tuple[int, ...]:
+    """The chunk sizes a step's two deltas are handed on in."""
+    sizes, lanes = [], _EMIT_FLOOR
+    while lanes < min(capacity, _EMIT_MAX):
+        sizes.append(lanes)
+        lanes *= 4
+    return tuple(sizes) + (min(capacity, _EMIT_MAX),)
+
+
+def _shift(a, d: int, fill):
+    """``a[i + d]`` at lane i, ``fill`` where that lies outside: a
+    static slice, where a gather of every lane would cost the device
+    tens of milliseconds a frame row."""
+    if d == 0:
+        return a
+    pad = jnp.full(abs(d), fill, a.dtype)
+    if d > 0:
+        return jnp.concatenate([a[d:], pad])
+    return jnp.concatenate([pad, a[:d]])
+
+
+def _seg_any(seg_start, last, x):
+    """Whether any lane of each lane's segment holds ``x``, from where
+    the segments start (a lane a lane) and which lanes end one: the
+    nearest lane before and the nearest after that holds it, each
+    against the segment's own bounds. Four running extremes and no
+    gather; an ``associative_scan`` of this length takes the TPU's
+    compiler the better part of an hour (PERF.md 6, PR 49)."""
+    lanes = x.shape[0]
+    at = jnp.arange(lanes, dtype=jnp.int32)
+    seg_end = jax.lax.cummin(jnp.where(last, at, lanes), reverse=True)
+    before = jax.lax.cummax(jnp.where(x, at, -1))
+    after = jax.lax.cummin(jnp.where(x, at, lanes), reverse=True)
+    return (before >= seg_start) | (after <= seg_end)
+
 
 @partial(
     jax.jit,
     static_argnames=("calls", "part_keys", "order_col", "pk", "lane_names"),
-    donate_argnums=(0, 1, 2, 3, 4, 5, 6, 7, 8),
+    donate_argnums=(0, 1, 2, 3, 4),
 )
 def _general_over_step(
     table: HashTable,
     buf: Dict[str, jnp.ndarray],
     bnulls: Dict[str, jnp.ndarray],
     present: jnp.ndarray,
-    seq: jnp.ndarray,
+    sdirty: jnp.ndarray,
     em: Dict[str, jnp.ndarray],
     emnulls: Dict[str, jnp.ndarray],
     em_valid: jnp.ndarray,
-    sdirty: jnp.ndarray,
-    seq_base: jnp.ndarray,
     chunk: StreamChunk,
     calls: Tuple[WindowCall, ...],
     part_keys: Tuple[str, ...],
@@ -937,15 +1005,25 @@ def _general_over_step(
     pk: Tuple[str, ...],
     lane_names: Tuple[str, ...],
 ):
-    """One fused retractable over-window step (general.rs:49 the TPU
-    way): apply the chunk's inserts/deletes to the pk-keyed row arena,
-    mark every touched partition dirty, re-sort the arena and recompute
-    EVERY window call over the dirty partitions, then diff against the
-    previously-emitted lanes and emit retract/re-emit pairs. The
-    reference walks per-row affected frame ranges (frame_finder.rs); on
-    TPU whole-partition recomputation in one sorted-segment program is
-    the idiomatic equivalent — segment scans are near-free on the VPU
-    and the emitted diff is identical."""
+    """One retractable over-window step (general.rs:49 the TPU way):
+    apply the chunk's inserts/deletes to the pk-keyed row arena, mark
+    every touched partition dirty, order the arena by (partition, the
+    order column, the stream key) and recompute EVERY window call over
+    the dirty partitions, then diff against the previously-emitted
+    lanes. The reference walks per-row affected frame ranges
+    (frame_finder.rs); here whole partitions are recomputed in one
+    sorted-segment program and the diff comes out the same. What the
+    program costs is the arena's capacity, whatever the chunk held
+    (PERF.md 6, PR 49): the order is three two-operand sorts and as many
+    gathers of every lane, the lanes the calls read are gathered into
+    it and their results gathered back, and the scans between are next
+    to nothing.
+
+    Returns the arena's new state, each call's values and NULL flags a
+    slot, the slots to retract and to insert (``_general_over_emit``
+    gathers them, ``_general_over_commit`` adopts them) and ``status``
+    = [dropped latch, bad-delete latch, valid rows of the chunk, rows
+    to retract, rows to insert, dirty partitions, rows they hold]."""
     cap = present.shape[0]
     n = chunk.capacity
     total = cap + n  # sort domain: arena + ghost entries (one per row)
@@ -996,8 +1074,6 @@ def _general_over_step(
         if name in bnulls:
             lane = chunk.nulls.get(name, jnp.zeros(n, jnp.bool_))
             bnulls[name] = bnulls[name].at[target].set(lane, mode="drop")
-    pos = jnp.arange(n, dtype=jnp.int64)
-    seq = seq.at[target].set(seq_base + pos, mode="drop")
     touched = (
         jnp.zeros(cap, jnp.bool_)
         .at[jnp.where(rows_active, slots, cap)]
@@ -1005,41 +1081,49 @@ def _general_over_step(
     )
     sdirty = sdirty | touched
 
-    # ---- sort the arena: members = rows needing compute or retraction
+    # ---- order the arena: members = rows needing compute or
+    # retraction, by (partition, live rows first, the order column, the
+    # stream key), every other lane behind them. The key's digits are
+    # packed into as many 32-bit words as they hold information (a
+    # lane that is no member reads a member's values, so that it
+    # widens no digit's range)
     member = present | em_valid
     member_e = jnp.concatenate([member, ghost])
     present_e = jnp.concatenate([present, jnp.zeros(n, jnp.bool_)])
-    plane_e = tuple(
-        jnp.concatenate(
+    MINI = jnp.iinfo(jnp.int64).min
+
+    def keyed(own, emitted, ghosts):
+        """A key lane over the sort domain: a row's own value where it
+        is present, else the one it was handed on with; the ghosts'."""
+        lane = jnp.concatenate(
             [
-                jnp.where(present, buf[k], em[k]).astype(jnp.int64),
-                em[k][gslots],
+                jnp.where(present, own.astype(jnp.int64), emitted),
+                ghosts.astype(jnp.int64),
             ]
         )
-        for k in part_keys
+        fill = jnp.max(jnp.where(member_e, lane, MINI))
+        return jnp.where(member_e, lane, fill)
+
+    plane_e = tuple(
+        keyed(buf[k], em[k], em[k][gslots]) for k in part_keys
     )
-    order_e = jnp.concatenate(
-        [
-            jnp.where(present, buf[order_col], em[order_col]).astype(
-                jnp.int64
-            ),
-            em[order_col][gslots],
-        ]
-    )
-    seq_e = jnp.concatenate([seq, seq[gslots]])
-    touched_e = jnp.concatenate([touched, ghost])
-    idx = jnp.arange(total, dtype=jnp.int32)  # >= cap identifies ghosts
-    sort_in = (
-        (~member_e).astype(jnp.int32),
-        *plane_e,
-        (~present_e).astype(jnp.int32),  # live rows first per partition
-        order_e,
-        seq_e,
-        idx,
-    )
-    nk = len(sort_in) - 1
-    sorted_all = jax.lax.sort(sort_in, num_keys=nk)
-    s_idx = sorted_all[-1]
+    order_e = keyed(buf[order_col], em[order_col], em[order_col][gslots])
+    digits: Tuple[jnp.ndarray, ...] = ()
+    for k, lane in reversed(tuple(zip(pk, table.keys))):
+        digits += _digits(keyed(lane, lane.astype(jnp.int64), chunk.col(k)))
+    digits += _digits(order_e)
+    digits += ((~present_e).astype(jnp.uint32),)  # live rows first
+    for lane in reversed(plane_e):
+        digits += _digits(lane)
+    digits += ((~member_e).astype(jnp.uint32),)
+    # (one two-operand sort a word that differs between lanes: what a
+    # sort of many operands costs the TPU's compiler is said there)
+    s_idx, _ = _order_by_words(jnp.stack(_packed_words(digits)[0]))
+    # where each slot stands in the order (the ghosts', past ``cap``,
+    # are not asked for)
+    inv = jax.lax.sort(
+        (s_idx, jnp.arange(total, dtype=jnp.int32)), num_keys=1
+    )[1][:cap]
 
     def s(a, fill=0):
         """Gather an arena lane into the sorted domain (ghost entries
@@ -1048,87 +1132,86 @@ def _general_over_step(
             [a, jnp.full(n, fill, a.dtype)]
         )[s_idx]
 
-    member_s = member_e[s_idx]
-    live_s = present_e[s_idx]
+    # one gather for every flag: member, live, touched, and each input
+    # column's NULL
+    null_cols = tuple(
+        dict.fromkeys(
+            c.input for c in calls
+            if c.input is not None and c.input in bnulls
+        )
+    )
+    flags_e = (
+        member_e.astype(jnp.int32)
+        | (present_e.astype(jnp.int32) << 1)
+        | (jnp.concatenate([touched, ghost]).astype(jnp.int32) << 2)
+    )
+    for i, name in enumerate(null_cols):
+        flags_e = flags_e | (
+            jnp.concatenate([bnulls[name], jnp.ones(n, jnp.bool_)])
+            .astype(jnp.int32) << (3 + i)
+        )
+    flags_s = flags_e[s_idx]
+    member_s = (flags_s & 1) > 0
+    live_s = (flags_s & 2) > 0
+    touched_s = (flags_s & 4) > 0
     plane_s = [p[s_idx] for p in plane_e]
-    v_order = order_e[s_idx]
 
-    arange = jnp.arange(total, dtype=jnp.int64)
-    boundary = jnp.zeros(total, jnp.bool_)
+    arange = jnp.arange(total, dtype=jnp.int32)
+    first = jnp.zeros(total, jnp.bool_).at[0].set(True)
+    boundary = first | jnp.concatenate(
+        [jnp.ones(1, jnp.bool_), member_s[1:] != member_s[:-1]]
+    )
     for lane in plane_s:
         boundary = boundary | jnp.concatenate(
             [jnp.ones(1, jnp.bool_), lane[1:] != lane[:-1]]
         )
-    boundary = boundary | jnp.concatenate(
-        [jnp.ones(1, jnp.bool_), member_s[1:] != member_s[:-1]]
-    )
-    boundary = boundary.at[0].set(True)
     gid = jnp.cumsum(boundary.astype(jnp.int32)) - 1
-    seg_start = jax.ops.segment_max(
-        jnp.where(boundary, arange, 0), gid, num_segments=total
-    )[gid]
-    in_seg = arange - seg_start
-    dirty_s = (
-        jax.ops.segment_max(
-            touched_e[s_idx].astype(jnp.int32), gid, num_segments=total
-        )[gid]
-        > 0
-    ) & member_s
+    # where each lane's segment starts, and what lies there
+    seg_start = jax.lax.cummax(jnp.where(boundary, arange, 0))
+    in_seg = (arange - seg_start).astype(jnp.int64)
+    last = jnp.concatenate([boundary[1:], jnp.ones(1, jnp.bool_)])
+    dirty_s = _seg_any(seg_start, last, touched_s) & member_s
 
     MAXI = jnp.iinfo(jnp.int64).max
-    MINI = jnp.iinfo(jnp.int64).min
     zero_nulls = jnp.zeros(total, jnp.bool_)
 
     def shifted(vals, nullm, d):
-        j = jnp.arange(total, dtype=jnp.int32) + d
-        jc = jnp.clip(j, 0, total - 1)
-        ok = (
-            (j >= 0)
-            & (j < total)
-            & (gid[jc] == gid)
-            & live_s[jc]
-            & live_s
+        ok = (_shift(gid, d, -1) == gid) & _shift(live_s, d, False) & live_s
+        return (
+            jnp.where(ok, _shift(vals, d, 0), 0),
+            jnp.where(ok, _shift(nullm, d, True), True),
         )
-        return jnp.where(ok, vals[jc], 0), jnp.where(ok, nullm[jc], True)
 
+    gathered: Dict[str, jnp.ndarray] = {}
     out_sorted: Dict[str, jnp.ndarray] = {}
     out_nulls_sorted: Dict[str, jnp.ndarray] = {}
     for c in calls:
         if c.input is not None:
-            v = s(buf[c.input]).astype(jnp.int64)
+            if c.input not in gathered:
+                gathered[c.input] = s(buf[c.input]).astype(jnp.int64)
+            v = gathered[c.input]
             vnull = (
-                s(bnulls[c.input], True)
-                if c.input in bnulls
+                (flags_s & (8 << null_cols.index(c.input))) > 0
+                if c.input in null_cols
                 else zero_nulls
             )
         if c.kind == "row_number":
             o, onull = in_seg + 1, zero_nulls
         elif c.kind in ("rank", "dense_rank"):
+            if order_col not in gathered:
+                gathered[order_col] = order_e[s_idx]
+            v_order = gathered[order_col]
             pv = jnp.concatenate(
                 [jnp.zeros(1, v_order.dtype), v_order[:-1]]
             )
             vb = boundary | (v_order != pv)
-            cum_vb_all = jnp.cumsum(vb.astype(jnp.int64))
-            seg_vb = jax.ops.segment_max(
-                jnp.where(boundary, cum_vb_all - 1, MINI),
-                gid,
-                num_segments=total,
-            )[gid]
             if c.kind == "dense_rank":
-                o = cum_vb_all - seg_vb
+                cum_vb = jnp.cumsum(vb.astype(jnp.int64))
+                o = cum_vb - cum_vb[seg_start] + 1
             else:
-
-                def reset_max(a, b):
-                    fa, va = a
-                    fb, vb_ = b
-                    return fa | fb, jnp.where(
-                        fb, vb_, jnp.maximum(va, vb_)
-                    )
-
-                _, grp_start = jax.lax.associative_scan(
-                    reset_max, (boundary, jnp.where(vb, in_seg, MINI))
-                )
-                o = grp_start + 1
+                # where the run of rows equal in the order began
+                run_start = jax.lax.cummax(jnp.where(vb, arange, 0))
+                o = (run_start - seg_start).astype(jnp.int64) + 1
             onull = zero_nulls
         elif c.kind in ("lead", "lag"):
             d = c.offset if c.kind == "lead" else -c.offset
@@ -1136,7 +1219,8 @@ def _general_over_step(
         elif c.frame is not None:
             lo, hi = c.frame
             if c.kind == "count":
-                v, vnull = jnp.ones(total, jnp.int64), zero_nulls
+                v = jnp.ones(total, jnp.int64)
+                vnull = zero_nulls if c.input is None else vnull
             ident = (
                 MAXI if c.kind == "min" else MINI if c.kind == "max" else 0
             )
@@ -1161,7 +1245,7 @@ def _general_over_step(
         else:
             # running UNBOUNDED PRECEDING .. CURRENT ROW
             if c.kind == "count":
-                real = live_s
+                real = live_s if c.input is None else live_s & ~vnull
                 vv = jnp.ones(total, jnp.int64)
             else:
                 real = live_s & ~vnull
@@ -1169,12 +1253,8 @@ def _general_over_step(
             if c.kind in ("sum", "count"):
                 vv = jnp.where(real, vv, 0)
                 csum = jnp.cumsum(vv)
-                base = jax.ops.segment_max(
-                    jnp.where(boundary, csum - vv, MINI),
-                    gid,
-                    num_segments=total,
-                )[gid]
-                o, onull = csum - base, zero_nulls
+                o = csum - (csum - vv)[seg_start]
+                onull = zero_nulls
             else:
                 sent = MAXI if c.kind == "min" else MINI
                 vv = jnp.where(real, vv, sent)
@@ -1196,111 +1276,125 @@ def _general_over_step(
         out_sorted[c.output] = o
         out_nulls_sorted[c.output] = onull
 
-    # ---- unsort to slots (ghost entries, s_idx >= cap, are dropped);
-    # diff against the emitted lanes
-    dirty_slot = (
-        jnp.zeros(cap, jnp.bool_).at[s_idx].set(dirty_s, mode="drop")
-    )
-    new_out = {
-        name: jnp.zeros(cap, jnp.int64).at[s_idx].set(o, mode="drop")
-        for name, o in out_sorted.items()
-    }
+    # ---- back to slots (a gather at each slot's place in the order;
+    # one for the flags); diff against the emitted lanes
+    out_flags = dirty_s.astype(jnp.int32)
+    for i, c in enumerate(calls):
+        out_flags = out_flags | (
+            out_nulls_sorted[c.output].astype(jnp.int32) << (1 + i)
+        )
+    out_flags = out_flags[inv]
+    dirty_slot = (out_flags & 1) > 0
+    new_out = {name: o[inv] for name, o in out_sorted.items()}
     new_out_nulls = {
-        name: jnp.zeros(cap, jnp.bool_).at[s_idx].set(o, mode="drop")
-        for name, o in out_nulls_sorted.items()
+        c.output: (out_flags & (2 << i)) > 0 for i, c in enumerate(calls)
     }
     both = present & em_valid
     changed = jnp.zeros(cap, jnp.bool_)
     for name in lane_names:
-        cn = bnulls.get(name, jnp.zeros(cap, jnp.bool_))
-        en = emnulls.get(name, jnp.zeros(cap, jnp.bool_))
-        # compare values only where both sides are non-NULL — the cell
-        # under a NULL flag is an arbitrary fill
-        changed = changed | (
-            ~cn & ~en & (buf[name].astype(jnp.int64) != em[name])
-        )
-        changed = changed | (cn != en)
+        differs = buf[name].astype(jnp.int64) != em[name]
+        if name in bnulls:
+            # compare values only where both sides are non-NULL — the
+            # cell under a NULL flag is an arbitrary fill
+            cn, en = bnulls[name], emnulls[name]
+            differs = (~cn & ~en & differs) | (cn != en)
+        changed = changed | differs
     for c in calls:
-        nn = new_out_nulls[c.output]
-        en = emnulls.get(c.output, jnp.zeros(cap, jnp.bool_))
+        nn, en = new_out_nulls[c.output], emnulls[c.output]
         changed = changed | (
             jnp.where(~nn, new_out[c.output], 0)
             != jnp.where(~en, em[c.output], 0)
-        )
-        changed = changed | (nn != en)
+        ) | (nn != en)
     changed = changed & both
     retract = em_valid & dirty_slot & (~present | changed)
     insert = present & dirty_slot & (~em_valid | changed)
     sdirty = sdirty | retract | insert
 
-    ops_del = jnp.full(cap, 1, jnp.int32)  # Op.DELETE
-    ops_ins = jnp.zeros(cap, jnp.int32)  # Op.INSERT
-    out_names = tuple(c.output for c in calls)
-    # compact each diff to a dense prefix: a scattered-valid chunk
-    # defeats downstream _live_slice and host conversion fast paths
-    rorder = jnp.argsort(~retract, stable=True)
-    iorder = jnp.argsort(~insert, stable=True)
-    ret_cols = {
-        name: em[name][rorder] for name in lane_names + out_names
-    }
-    ret_nulls = {name: a[rorder] for name, a in emnulls.items()}
-    ret_chunk = StreamChunk(
-        columns=ret_cols,
-        valid=retract[rorder],
-        nulls=ret_nulls,
-        ops=ops_del,
-    )
-    ins_cols = {
-        name: buf[name].astype(jnp.int64)[iorder] for name in lane_names
-    }
-    ins_cols.update({name: new_out[name][iorder] for name in out_names})
-    ins_nulls = {name: a[iorder] for name, a in bnulls.items()}
-    ins_nulls.update(
-        {name: a[iorder] for name, a in new_out_nulls.items()}
-    )
-    ins_chunk = StreamChunk(
-        columns=ins_cols,
-        valid=insert[iorder],
-        nulls=ins_nulls,
-        ops=ops_ins,
-    )
+    def count(mask):
+        return jnp.sum(mask, dtype=jnp.int32)
 
-    # emitted state := what downstream now holds
-    upd = jnp.where(insert, jnp.arange(cap, dtype=jnp.int32), cap)
-    for name in lane_names:
-        em[name] = (
-            em[name].at[upd].set(buf[name].astype(jnp.int64), mode="drop")
-        )
-        cn = bnulls.get(name, jnp.zeros(cap, jnp.bool_))
-        emnulls[name] = (
-            emnulls.get(name, jnp.zeros(cap, jnp.bool_))
-            .at[upd]
-            .set(cn, mode="drop")
-        )
-    for name in out_names:
-        em[name] = em[name].at[upd].set(new_out[name], mode="drop")
-        emnulls[name] = (
-            emnulls.get(name, jnp.zeros(cap, jnp.bool_))
-            .at[upd]
-            .set(new_out_nulls[name], mode="drop")
-        )
-    em_valid = (em_valid & ~retract) | insert
-
+    status = jnp.stack(
+        [
+            dropped.astype(jnp.int32),
+            bad_delete.astype(jnp.int32),
+            count(rows_active),
+            count(retract),
+            count(insert),
+            count(boundary & dirty_s),
+            count(dirty_s & live_s),
+        ]
+    )
     return (
-        table,
-        buf,
-        bnulls,
-        present,
-        seq,
-        em,
-        emnulls,
-        em_valid,
-        sdirty,
-        ret_chunk,
-        ins_chunk,
-        dropped,
-        bad_delete,
+        table, buf, bnulls, present, sdirty,
+        new_out, new_out_nulls, retract, insert, status,
     )
+
+
+@partial(jax.jit, static_argnames=("lanes", "lane_names", "out_names"))
+def _general_over_emit(
+    buf, bnulls, em, emnulls, new_out, new_out_nulls, retract, insert,
+    start, lanes: int, lane_names, out_names,
+):
+    """The ``start``-th to ``start + lanes``-th rows of a step's two
+    deltas as two chunks of ``lanes`` lanes: the retractions as they
+    were handed on (``em``), the insertions as they now stand. The rows
+    are found and gathered a block a turn for as many turns as hold one
+    (``top_n_plain._in_turns``): the lanes past the delta keep their
+    zeros and cost nothing. Changes no state."""
+
+    def delta(mask, cols, nulls, op):
+        def turn(carry, at, pos, valid):
+            block = (
+                {name: jnp.where(valid, a[pos], 0)
+                 for name, a in cols.items()},
+                {name: a[pos] & valid for name, a in nulls.items()},
+                valid,
+            )
+            return _put(carry, block, at)
+
+        empty = (
+            {name: jnp.zeros(lanes, jnp.int64) for name in cols},
+            {name: jnp.zeros(lanes, jnp.bool_) for name in nulls},
+            jnp.zeros(lanes, jnp.bool_),
+        )
+        (cols, nulls, valid), _ = _in_turns(
+            _count_set(mask), lanes, start, turn, empty
+        )
+        return StreamChunk(
+            columns=cols, valid=valid, nulls=nulls,
+            ops=jnp.full(lanes, int(op), jnp.int32),
+        )
+
+    names = lane_names + out_names
+    now = {name: buf[name].astype(jnp.int64) for name in lane_names}
+    now.update(new_out)
+    return (
+        delta(retract, {name: em[name] for name in names}, emnulls,
+              Op.DELETE),
+        delta(insert, now, {**bnulls, **new_out_nulls}, Op.INSERT),
+    )
+
+
+@partial(
+    jax.jit,
+    static_argnames=("lane_names",),
+    donate_argnums=(0, 1, 2),
+)
+def _general_over_commit(
+    em, emnulls, em_valid, buf, bnulls, new_out, new_out_nulls,
+    retract, insert, lane_names,
+):
+    """Emitted state := what downstream now holds."""
+    for name in lane_names:
+        em[name] = jnp.where(insert, buf[name].astype(jnp.int64), em[name])
+        if name in bnulls:
+            emnulls[name] = jnp.where(insert, bnulls[name], emnulls[name])
+    for name, o in new_out.items():
+        em[name] = jnp.where(insert, o, em[name])
+        emnulls[name] = jnp.where(
+            insert, new_out_nulls[name], emnulls[name]
+        )
+    return em, emnulls, (em_valid & ~retract) | insert
 
 
 def _chunk_dup(slots: jnp.ndarray, valid: jnp.ndarray) -> jnp.ndarray:
@@ -1318,16 +1412,29 @@ class GeneralOverWindowExecutor(Executor, Checkpointable):
     order, retracting and re-emitting every row whose window value
     changes. The reference computes per-row affected frame ranges
     (frame_finder.rs); the TPU re-design keeps all rows in a pk-keyed
-    device arena and recomputes complete dirty partitions in one fused
-    sorted-segment program per chunk — recompute is near-free on the
-    VPU, and the diff against the previously-emitted lanes yields the
-    exact minimal retract/re-emit set.
+    device arena and recomputes complete dirty partitions in one
+    sorted-segment program per chunk, and the diff against the
+    previously-emitted lanes yields the exact minimal retract/re-emit
+    set. Rows of a partition equal in the order column stand in the
+    order of their stream key ``pk``, as upstream's do.
 
-    Supports every WindowCall kind including lead/lag(k) and static
-    ROWS frames (deletes may reopen any frame, so the general executor
-    has no hold-back constraint — it simply recomputes).
+    The recompute is NOT free: a step costs what the arena's CAPACITY
+    costs, whatever the chunk held — at 2^22 lanes on a v5e 0.70 s of
+    device time for 1,000 rows, most of it the order's sorts and the
+    capacity-wide gathers around them — and runs once a chunk: twice a
+    barrier behind a Top-N that hands on a retract and an insert chunk
+    (1,393 ms an epoch of 32,768 events in ``nexmark_q6.catchup``;
+    PERF.md 5 and 6, PR 49). What it hands on follows what
+    changed (``emission_sizes``).
+
+    Supports every WindowCall kind including lead/lag(k), static ROWS
+    frames and COUNT(col) (deletes may reopen any frame, so the general
+    executor has no hold-back constraint — it simply recomputes).
     Checkpointable: current rows + emitted rows persist; recovery is
     bit-exact."""
+
+    # one step a chunk at the chunk's own width; knows ``warm``
+    per_chunk_step = True
 
     def __init__(
         self,
@@ -1362,11 +1469,15 @@ class GeneralOverWindowExecutor(Executor, Checkpointable):
         self.schema_dtypes = dict(schema_dtypes)
         self.nullable = tuple(nullable)
         self.table_id = table_id
+        self._frame_rows = max(
+            (c.frame[1] - c.frame[0] + 1 for c in self.calls if c.frame),
+            default=0,
+        )
         self._alloc(capacity)
-        self._seq_base = 0
-        self._dropped = jnp.zeros((), jnp.bool_)
-        self._bad_delete = jnp.zeros((), jnp.bool_)
+        self._dropped = False
+        self._bad_delete = False
         self._bound = 0
+        self._epoch = _zero_epoch()
 
     def lint_info(self):
         requires = set(self.part_keys) | set(self.pk) | {self.order_col}
@@ -1386,32 +1497,23 @@ class GeneralOverWindowExecutor(Executor, Checkpointable):
             "table_ids": (self.table_id,),
         }
 
+    def _step(self, chunk: StreamChunk):
+        return _general_over_step(
+            self.table, self.buf, self.bnulls, self.present, self.sdirty,
+            self.em, self.emnulls, self.em_valid, chunk,
+            self.calls, self.part_keys, self.order_col, self.pk,
+            self.lane_names,
+        )
+
     def trace_contract(self):
         return {
             "kind": "device",
-            "trace_step": lambda c: _general_over_step(
-                self.table,
-                self.buf,
-                self.bnulls,
-                self.present,
-                self.seq,
-                self.em,
-                self.emnulls,
-                self.em_valid,
-                self.sdirty,
-                jnp.int64(self._seq_base),
-                c,
-                self.calls,
-                self.part_keys,
-                self.order_col,
-                self.pk,
-                self.lane_names,
-            ),
+            "trace_step": self._step,
             "state": (self.table, self.buf, self.em),
             "donate": True,
-            # retract/re-emit diff chunks are arena-capacity lanes
-            "emission": "fixed",
-            "emission_caps": (self.capacity,),
+            # the two deltas go on in chunks sized from their rows
+            "emission": "bucketed",
+            "emission_caps": emission_sizes(self.capacity),
         }
 
     def _alloc(self, cap: int):
@@ -1424,12 +1526,14 @@ class GeneralOverWindowExecutor(Executor, Checkpointable):
         }
         self.bnulls = {n: jnp.zeros(cap, jnp.bool_) for n in self.nullable}
         self.present = jnp.zeros(cap, jnp.bool_)
-        self.seq = jnp.zeros(cap, jnp.int64)
         self.em = {
             n: jnp.zeros(cap, jnp.int64)
             for n in self.lane_names + self.out_names
         }
-        self.emnulls = {}
+        self.emnulls = {
+            n: jnp.zeros(cap, jnp.bool_)
+            for n in self.nullable + self.out_names
+        }
         self.em_valid = jnp.zeros(cap, jnp.bool_)
         self.sdirty = jnp.zeros(cap, jnp.bool_)
         self.stored = jnp.zeros(cap, jnp.bool_)
@@ -1438,16 +1542,58 @@ class GeneralOverWindowExecutor(Executor, Checkpointable):
     def capacity(self) -> int:
         return self.present.shape[0]
 
+    @property
+    def row_bytes(self) -> int:
+        """One input row's lanes, as the arena stores them."""
+        return sum(a.dtype.itemsize for a in self.buf.values()) + len(
+            self.bnulls
+        )
+
     def state_nbytes(self) -> int:
         """Device bytes held (host-side estimate; no sync)."""
         return sum(
             leaf.nbytes
             for leaf in jax.tree.leaves((
                 self.table, self.buf, self.bnulls, self.present,
-                self.seq, self.em, self.emnulls, self.em_valid,
+                self.em, self.emnulls, self.em_valid,
                 self.sdirty, self.stored,
             ))
         )
+
+    def _run(self, chunk: StreamChunk, sizes=None):
+        """The step's programs for ``chunk``: the arena's step, ONE read
+        of its seven counts (which waits for it: what is handed on is
+        sized from them, and the view behind this executor reads the
+        chunks at once anyway), a round of the two deltas for every
+        ``lanes`` rows the larger holds (every retraction before any
+        insertion), and the adoption of what was handed on. ``sizes``:
+        the warm-up's, a round of each whatever the counts."""
+        (
+            self.table, self.buf, self.bnulls, self.present, self.sdirty,
+            new_out, new_nulls, retract, insert, status,
+        ) = self._step(chunk)
+        with device_read("over.status", lanes=status.shape[0]):
+            status = jax.device_get(status).tolist()
+        if sizes is None:
+            rows = max(status[3], status[4])  # the larger delta
+            caps = emission_sizes(self.capacity)
+            lanes = next((c for c in caps if c >= rows), caps[-1])
+            rounds = [(lanes, at) for at in range(0, max(rows, 1), lanes)]
+        else:
+            rounds = [(size, 0) for size in sizes]
+        pairs = [
+            _general_over_emit(
+                self.buf, self.bnulls, self.em, self.emnulls, new_out,
+                new_nulls, retract, insert, jnp.int32(at), size,
+                self.lane_names, self.out_names,
+            )
+            for size, at in rounds
+        ]
+        self.em, self.emnulls, self.em_valid = _general_over_commit(
+            self.em, self.emnulls, self.em_valid, self.buf, self.bnulls,
+            new_out, new_nulls, retract, insert, self.lane_names,
+        )
+        return status, [r for r, _ in pairs] + [i for _, i in pairs]
 
     def apply(self, chunk: StreamChunk) -> List[StreamChunk]:
         for c in self.calls:
@@ -1457,43 +1603,39 @@ class GeneralOverWindowExecutor(Executor, Checkpointable):
                     "(NULL ordering unsupported)"
                 )
         self._maybe_grow(chunk.capacity)
-        (
-            self.table,
-            self.buf,
-            self.bnulls,
-            self.present,
-            self.seq,
-            self.em,
-            self.emnulls,
-            self.em_valid,
-            self.sdirty,
-            ret,
-            ins,
-            dr,
-            bd,
-        ) = _general_over_step(
-            self.table,
-            self.buf,
-            self.bnulls,
-            self.present,
-            self.seq,
-            self.em,
-            self.emnulls,
-            self.em_valid,
-            self.sdirty,
-            jnp.int64(self._seq_base),
-            chunk,
-            self.calls,
-            self.part_keys,
-            self.order_col,
-            self.pk,
-            self.lane_names,
-        )
-        self._seq_base += chunk.capacity
-        self._bound += chunk.capacity
-        self._dropped = self._dropped | dr
-        self._bad_delete = self._bad_delete | bd
-        return [ret, ins]
+        with span(
+            "over.step", table_id=self.table_id, capacity=self.capacity,
+            chunk_lanes=chunk.capacity, calls=len(self.calls),
+            frame_rows=self._frame_rows,
+            row_bytes=self.row_bytes,
+        ) as sp:
+            status, outs = self._run(chunk)
+            dropped, bad, in_rows, n_ret, n_ins, parts, rows = status
+            emit_lanes = sum(c.capacity for c in outs)
+            sp.args.update(
+                in_rows=in_rows, retract_rows=n_ret, insert_rows=n_ins,
+                emit_lanes=emit_lanes,
+            )
+        # every valid row claims at most one slot
+        self._bound += in_rows
+        self._dropped |= bool(dropped)
+        self._bad_delete |= bool(bad)
+        e = self._epoch
+        e["steps"] += 1
+        e["in_rows"] += in_rows
+        e["dirty_partitions"] += parts
+        e["dirty_rows"] += rows
+        e["retract_rows"] += n_ret
+        e["insert_rows"] += n_ins
+        e["emit_lanes"] += emit_lanes
+        return outs
+
+    # -- the warm-up pass --------------------------------------------------
+    def warm(self, chunk: StreamChunk) -> List[StreamChunk]:
+        """The step's programs for a chunk of this shape, and a round
+        of every emission size: a chunk with no valid row touches no
+        slot, so the arena it hands back is the arena it was given."""
+        return self._run(chunk, sizes=emission_sizes(self.capacity))[1]
 
     def _maybe_grow(self, incoming: int):
         cap = self.capacity
@@ -1532,7 +1674,6 @@ class GeneralOverWindowExecutor(Executor, Checkpointable):
         self.buf = {n: mv(a) for n, a in self.buf.items()}
         self.bnulls = {n: mv(a) for n, a in self.bnulls.items()}
         self.present = mv(self.present)
-        self.seq = mv(self.seq)
         self.em = {n: mv(a) for n, a in self.em.items()}
         self.emnulls = {n: mv(a) for n, a in self.emnulls.items()}
         self.em_valid = mv(self.em_valid)
@@ -1541,27 +1682,37 @@ class GeneralOverWindowExecutor(Executor, Checkpointable):
         self.table = new
 
     def on_barrier(self, barrier: Barrier) -> List[StreamChunk]:
-        from risingwave_tpu.ops.hash_table import stage_scalars
-
-        self._staged_scalars = stage_scalars(
-            self._dropped, self._bad_delete
-        )
-        if barrier is None:
-            self.finish_barrier()
-        return []
-
-    def _on_barrier_scalars(self, vals) -> None:
-        dr, bd = vals
-        if dr:
+        e, self._epoch = self._epoch, _zero_epoch()
+        if e["steps"]:
+            # the epoch's steps, from the counts each of them read
+            with span(
+                "over.barrier", table_id=self.table_id,
+                emitted_rows=e["retract_rows"] + e["insert_rows"], **e,
+            ):
+                pass
+            count = REGISTRY.counter
+            count("over_window_steps_total").inc(
+                e["steps"], table_id=self.table_id
+            )
+            count("over_window_input_rows_total").inc(
+                e["in_rows"], table_id=self.table_id
+            )
+            count("over_window_emitted_rows_total").inc(
+                e["retract_rows"] + e["insert_rows"], table_id=self.table_id
+            )
+        if self._dropped:
             raise RuntimeError("general OverWindow row arena overflowed")
-        if bd:
+        if self._bad_delete:
             raise RuntimeError(
                 "general OverWindow received a DELETE for an unknown pk "
                 "(inconsistent upstream)"
             )
+        return []
 
     # -- integrity --------------------------------------------------------
-    def digest_lanes(self):
+    def _lanes(self):
+        """Every lane a slot's state is made of, by its checkpoint name
+        (the key lanes first)."""
         lanes = {f"k{i}": k for i, k in enumerate(self.table.keys)}
         for n in self.lane_names:
             lanes[f"c_{n}"] = self.buf[n]
@@ -1571,9 +1722,11 @@ class GeneralOverWindowExecutor(Executor, Checkpointable):
             lanes[f"e_{n}"] = a
         for n, a in self.emnulls.items():
             lanes[f"en_{n}"] = a
-        lanes["seq"] = self.seq
         lanes["present"] = self.present
-        return lanes, self.present | self.em_valid
+        return lanes
+
+    def digest_lanes(self):
+        return self._lanes(), self.present | self.em_valid
 
     def state_digest(self) -> int:
         from risingwave_tpu.integrity import host_digest
@@ -1588,19 +1741,8 @@ class GeneralOverWindowExecutor(Executor, Checkpointable):
         self.sdirty, self.stored = marks.sdirty, marks.stored
         if not len(marks):
             return []
-        lanes = {f"k{i}": l for i, l in enumerate(self.table.keys)}
-        key_names = tuple(lanes)
-        for n in self.lane_names:
-            lanes[f"c_{n}"] = self.buf[n]
-        for n, a in self.bnulls.items():
-            lanes[f"cn_{n}"] = a
-        for n, a in self.em.items():
-            lanes[f"e_{n}"] = a
-        for n, a in self.emnulls.items():
-            lanes[f"en_{n}"] = a
-        lanes["seq"] = self.seq
-        lanes["present"] = self.present
-        pulled = pull_rows(lanes, marks)
+        key_names = tuple(f"k{i}" for i in range(len(self.table.keys)))
+        pulled = pull_rows(self._lanes(), marks)
         keys = {k: pulled[k] for k in key_names}
         vals = {k: v for k, v in pulled.items() if k not in key_names}
         return [
@@ -1622,60 +1764,36 @@ class GeneralOverWindowExecutor(Executor, Checkpointable):
             )
             self.table = set_live(self.table, slots, True)
             self.stored = self.stored.at[slots].set(True)
-            pres = jnp.asarray(
-                np.asarray(value_cols["present"], dtype=bool)
-            )
-            self.present = self.present.at[slots].set(pres)
-            self.em_valid = self.em_valid.at[slots].set(pres)
-            self.seq = self.seq.at[slots].set(
-                jnp.asarray(np.asarray(value_cols["seq"], np.int64))
-            )
-            self._seq_base = int(np.asarray(value_cols["seq"]).max()) + 1
-            for nme in self.lane_names:
-                self.buf[nme] = (
-                    self.buf[nme]
-                    .at[slots]
-                    .set(
-                        jnp.asarray(
-                            np.asarray(
-                                value_cols[f"c_{nme}"],
-                                self.buf[nme].dtype,
-                            )
-                        )
-                    )
+
+            def put(lane, values):
+                return lane.at[slots].set(
+                    jnp.asarray(np.asarray(values, lane.dtype))
                 )
-            for nme in self.bnulls:
-                if f"cn_{nme}" in value_cols:
-                    self.bnulls[nme] = (
-                        self.bnulls[nme]
-                        .at[slots]
-                        .set(
-                            jnp.asarray(
-                                np.asarray(value_cols[f"cn_{nme}"], bool)
-                            )
+
+            # at a barrier everything current has been handed on
+            self.present = put(self.present, value_cols["present"])
+            self.em_valid = put(self.em_valid, value_cols["present"])
+            for nme in self.lane_names:
+                self.buf[nme] = put(self.buf[nme], value_cols[f"c_{nme}"])
+            for prefix, group in (
+                ("cn_", self.bnulls), ("e_", self.em), ("en_", self.emnulls)
+            ):
+                for nme in group:
+                    if prefix + nme in value_cols:
+                        group[nme] = put(
+                            group[nme], value_cols[prefix + nme]
                         )
-                    )
-            for nme in self.em:
-                if f"e_{nme}" in value_cols:
-                    self.em[nme] = (
-                        self.em[nme]
-                        .at[slots]
-                        .set(
-                            jnp.asarray(
-                                np.asarray(
-                                    value_cols[f"e_{nme}"], np.int64
-                                )
-                            )
-                        )
-                    )
-            for key, v in value_cols.items():
-                if key.startswith("en_"):
-                    nme = key[3:]
-                    self.emnulls[nme] = (
-                        jnp.zeros(cap, jnp.bool_)
-                        .at[slots]
-                        .set(jnp.asarray(np.asarray(v, bool)))
-                    )
         self._bound = int(n)
-        self._dropped = jnp.zeros((), jnp.bool_)
-        self._bad_delete = jnp.zeros((), jnp.bool_)
+        self._dropped = self._bad_delete = False
+        self._epoch = _zero_epoch()
+
+
+def _zero_epoch() -> Dict[str, int]:
+    """What ``over.barrier`` says of an epoch's steps."""
+    return dict.fromkeys(
+        (
+            "steps", "in_rows", "dirty_partitions", "dirty_rows",
+            "retract_rows", "insert_rows", "emit_lanes",
+        ),
+        0,
+    )

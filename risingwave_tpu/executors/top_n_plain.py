@@ -560,8 +560,17 @@ def _sort_by_words_gathered(words, payloads):
     for a described v5e, PR 46) — and this program is compiled once a
     candidate size; a gather of 65,536 lanes costs the device a
     millisecond, where one of 2^22 cost 40."""
-    lanes = words[0].shape[0]
     stacked = jnp.stack(words)
+    order, passes = _order_by_words(stacked)
+    return (
+        tuple(stacked[:, order]), tuple(p[order] for p in payloads), passes
+    )
+
+
+def _order_by_words(stacked):
+    """The lanes' order by the words ``stacked`` (a row a word, least
+    significant first), and the sorts it took."""
+    lanes = stacked.shape[1]
 
     def one(i, carry):
         order, passes = carry
@@ -576,12 +585,9 @@ def _sort_by_words_gathered(words, payloads):
         order = jax.lax.cond(shared, lambda order: order, sort, order)
         return order, passes + (~shared).astype(jnp.int32)
 
-    order, passes = jax.lax.fori_loop(
-        0, len(words), one,
+    return jax.lax.fori_loop(
+        0, stacked.shape[0], one,
         (jnp.arange(lanes, dtype=jnp.int32), jnp.zeros((), jnp.int32)),
-    )
-    return (
-        tuple(stacked[:, order]), tuple(p[order] for p in payloads), passes
     )
 
 

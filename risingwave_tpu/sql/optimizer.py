@@ -720,6 +720,7 @@ def _explain_stream_join(sql: str, catalog) -> str:
     import copy
 
     from risingwave_tpu.executors.hash_agg import HashAggExecutor
+    from risingwave_tpu.executors.over_window import GeneralOverWindowExecutor
     from risingwave_tpu.executors.stream_join import StreamJoinExecutor
     from risingwave_tpu.executors.top_n_plain import (
         RetractableGroupTopNExecutor,
@@ -759,6 +760,18 @@ def _explain_stream_join(sql: str, catalog) -> str:
                 f"order=[{order}, stream key] limit={ex.limit}"
                 + (f" rank={ex.rank_col}" if ex.rank_col else "")
             )
+        if isinstance(ex, GeneralOverWindowExecutor):
+            calls = ", ".join(
+                f"{c.kind}({c.input or '*'})"
+                + (f" ROWS {c.frame[0]}..{c.frame[1]}" if c.frame else "")
+                + f" AS {c.output}"
+                for c in ex.calls
+            )
+            return (
+                f"GeneralOverWindow partition=[{', '.join(ex.part_keys)}] "
+                f"order=[{ex.order_col}, stream key] calls=[{calls}] "
+                f"pk=[{', '.join(ex.pk)}]"
+            )
         pk = getattr(ex, "pk", None)
         name = type(ex).__name__.replace("Executor", "")
         return f"{name} pk=[{', '.join(pk)}]" if pk is not None else name
@@ -791,11 +804,20 @@ def _explain_topn(select: P.Select) -> str:
     retractable GroupTopN and not as a window (sql/planner.py,
     ``_try_over_window_to_topn``): the executor chain behind the scan,
     as the planner builds it."""
-    from risingwave_tpu.sql.planner import over_window_topn_shape
+    from risingwave_tpu.sql.planner import (
+        over_window_topn_shape,
+        topn_under_select,
+    )
 
     shape = over_window_topn_shape(select)
     if shape is None:
-        return ""
+        under = topn_under_select(select)
+        if under is None:
+            return ""
+        return (
+            f"-- {under[1].rank_name} <= {under[1].limit}: per-group top-n "
+            "under the select, which reads its change stream\n"
+        )
     head = (
         f"-- {shape.rank_name} <= {shape.limit}: per-group top-n, not a "
         "window\n"

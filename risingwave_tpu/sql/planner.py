@@ -766,6 +766,41 @@ def over_window_topn_shape(select: P.Select) -> Optional[TopNShape]:
     )
 
 
+def topn_under_select(select: P.Select):
+    """Is ``select``'s derived table the rule's shape — a bounded
+    ``row_number()``, the bound in ``select``'s WHERE — under a select
+    that does more than list its columns (a window call, an aggregate,
+    an expression)? Then (the Top-N's own select: the columns ``select``
+    reads, over the same derived table and bound; its shape; ``select``
+    without the WHERE, to be planned over the Top-N's output). None where
+    the rule does not hold, or holds for ``select`` as it stands."""
+    if not isinstance(select.from_, P.SubQuery) or select.where is None:
+        return None
+    if over_window_topn_shape(select) is not None:
+        return None
+    names: Dict[str, None] = {}  # in the order read
+    _map_idents(
+        (
+            tuple(it.expr for it in select.items), select.group_by,
+            select.having, select.grouping_sets,
+            tuple(i for i, _ in select.order_by),
+        ),
+        lambda i: names.setdefault(i.name) or i,
+    )
+    if not names:
+        return None
+    topn = P.Select(
+        items=tuple(P.SelectItem(P.Ident(n), None) for n in names),
+        from_=select.from_,
+        where=select.where,
+        group_by=(),
+    )
+    shape = over_window_topn_shape(topn)
+    if shape is None:
+        return None
+    return topn, shape, dataclasses.replace(select, where=None)
+
+
 class StreamPlanner:
     def __init__(self, catalog: Catalog, capacity: int = 1 << 14):
         self.catalog = catalog
@@ -1455,13 +1490,27 @@ class StreamPlanner:
         self, name: str, select: P.Select, shape: TopNShape
     ) -> Optional[PlannedMV]:
         """The rule over a join (NEXmark q9: every bid joined to its
-        auction, the auction's best pair kept): the join is planned as
-        any select FROM a join is (``_join_rel``: the chained layout
-        for a side tied to no key of its own, the residual inside it,
-        each bare side cut to what the select and the window read),
-        its stream key — both sides' — is the rows' identity, and the
-        GroupTopN and the outer projection go on behind its projection
-        in the same actor's tail, as q4's aggregates do."""
+        auction, the auction's best pair kept), as a view of its own."""
+        aux: List[PlannedMV] = []
+        planned = self._topn_over_join_rel(name, select, shape, aux)
+        if planned is None:
+            return None
+        parts, out = planned
+        planned = self._joined_mv(name, parts, out, self._make_mview(name, out))
+        return dataclasses.replace(planned, aux=tuple(aux))
+
+    def _topn_over_join_rel(
+        self, name: str, select: P.Select, shape: TopNShape,
+        aux: List[PlannedMV],
+    ):
+        """The rule over a join, as (the join's parts, what follows the
+        join): the join is planned as any select FROM a join is
+        (``_join_rel``: the chained layout for a side tied to no key of
+        its own, the residual inside it, each bare side cut to what the
+        select and the window read), its stream key — both sides' — is
+        the rows' identity, and the GroupTopN and the outer projection
+        go on behind its projection in the same actor's tail, as q4's
+        aggregates do."""
         f = select.from_
         inner = self._bare_join_sides(f.select)
         (w,) = [  # the window, its columns as the sides now name them
@@ -1475,7 +1524,6 @@ class StreamPlanner:
                 if not isinstance(it.expr, P.WindowFuncCall)
             ),
         )
-        aux: List[PlannedMV] = []
         parts, rel = self._join_rel(name, joined, aux)
         left, right = parts[0], parts[1]
         proj = rel.chain[-1]
@@ -1513,12 +1561,10 @@ class StreamPlanner:
         if tail is None:
             return None
         execs, out_schema, out_pk = tail
-        out = BoundRel(
+        return parts, BoundRel(
             rel.chain + execs, out_schema, out_pk, rel.source, f.alias,
             append_only=False,
         )
-        planned = self._joined_mv(name, parts, out, self._make_mview(name, out))
-        return dataclasses.replace(planned, aux=tuple(aux))
 
     def _plan_over_window(
         self, name: str, select: P.Select, binder: Binder,
@@ -1557,6 +1603,31 @@ class StreamPlanner:
         groups: Dict[tuple, dict] = {}
         passthrough: List[Tuple[str, str]] = []  # (out name, in col)
         out_names: List[str] = []
+        # (window, kind, column, frame) -> the SUM / COUNT lane an AVG
+        # over the same is made of: the select's own where it lists one
+        base: Dict[tuple, str] = {}
+        avgs: Dict[str, E.Expr] = {}  # AVG's name -> sum / count
+        for i, item in enumerate(select.items):
+            ast = item.expr
+            if (
+                isinstance(ast, P.WindowFuncCall)
+                and ast.func.name in ("sum", "count")
+                and ast.func.args != ("*",)
+                and len(ast.order_by) == 1
+            ):
+                base.setdefault(
+                    (
+                        (
+                            tuple(binder.resolve(c) for c in ast.partition_by),
+                            binder.resolve(ast.order_by[0][0]),
+                            ast.order_by[0][1],
+                        ),
+                        ast.func.name,
+                        binder.resolve(ast.func.args[0]),
+                        ast.frame,
+                    ),
+                    item.alias or f"{ast.func.name}_{i}",
+                )
         for i, item in enumerate(select.items):
             ast = item.expr
             if isinstance(ast, P.Ident):
@@ -1607,11 +1678,28 @@ class StreamPlanner:
                 g["calls"].append(
                     WindowCall("count", None, out, frame=ast.frame)
                 )
-            elif fn in ("sum", "min", "max"):
+            elif fn in ("sum", "count", "min", "max"):
                 incol = binder.resolve(args[0])
                 g["calls"].append(
                     WindowCall(fn, incol, out, frame=ast.frame)
                 )
+            elif fn == "avg":
+                # a framed SUM over a framed COUNT of the non-null
+                # inputs, divided in the closing projection, as the
+                # GROUP BY one is made (``_lower_extended_agg``): a SUM
+                # / COUNT the select lists over the same column and
+                # frame is that base call (two calls, not four)
+                incol = binder.resolve(args[0])
+                parts = []
+                for kind in ("sum", "count"):
+                    bkey = (key, kind, incol, ast.frame)
+                    if bkey not in base:
+                        base[bkey] = f"__w{len(base)}"
+                        g["calls"].append(
+                            WindowCall(kind, incol, base[bkey], frame=ast.frame)
+                        )
+                    parts.append(E.col(base[bkey]))
+                avgs[out] = E.BinOp("/", *parts)
             elif fn in ("lag", "lead"):
                 incol = binder.resolve(args[0])
                 k = 1
@@ -1685,8 +1773,12 @@ class StreamPlanner:
             post[out] = E.col(incol)
             out_schema[out] = win_schema[incol]
         for out in out_names:
-            post[out] = E.col(out)  # window outputs are int64 lanes
-            out_schema[out] = jnp.dtype(jnp.int64)
+            # window outputs are int64 lanes; an AVG is the DOUBLE its
+            # two lanes divide to (NULL over no non-null input: 0 / 0)
+            post[out] = avgs.get(out, E.col(out))
+            out_schema[out] = jnp.dtype(
+                jnp.float64 if out in avgs else jnp.int64
+            )
         for pcol in pk:
             if pcol not in post:
                 post[pcol] = E.col(pcol)
@@ -2360,6 +2452,18 @@ class StreamPlanner:
         reads the inner one's change stream inside the same barrier."""
         if isinstance(select.from_, P.Join):
             return self._join_core_rel(name, select, aux)
+        split = topn_under_select(select)
+        if split is not None and isinstance(
+            select.from_.select.from_, P.Join
+        ):
+            # a bounded ROW_NUMBER() over a join read by more than a
+            # list of its columns (NEXmark q6's framed AVG over each
+            # auction's kept bid): the GroupTopN behind the join, and
+            # the select over the Top-N's change stream behind that
+            topn, shape, rest = split
+            planned = self._topn_over_join_rel(name, topn, shape, aux)
+            if planned is not None:
+                return planned[0], self._plan_rel(name, rest, pre=planned[1])
         parts, inner = self._join_rel(name, select.from_.select, aux)
         inner.alias = select.from_.alias
         return parts, self._plan_rel(name, select, pre=inner)

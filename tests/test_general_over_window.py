@@ -41,7 +41,7 @@ def _oracle(rows, calls):
         by_part.setdefault(p, []).append((o, seq, rid, x))
     out = set()
     for p, items in by_part.items():
-        items.sort()
+        items.sort(key=lambda it: (it[0], it[2]))  # (order, stream key)
         n = len(items)
         for i, (o, seq, rid, x) in enumerate(items):
             vals = []
