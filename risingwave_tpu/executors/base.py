@@ -206,9 +206,10 @@ class Executor:
         lattice: ``StreamingRuntime.push`` may hand it the chunk cut to
         the smallest declared size that holds its rows. One that keys
         compiled programs on a uniform chunk width (an epoch-batched
-        head, a fused barrier program) takes the full width only. A
-        fragment takes what every executor of it takes
-        (``pipeline.chain_push_widths``)."""
+        head, a fused barrier program) takes the full width only; one
+        that keeps its chunks for a step of its own width says so
+        itself (the general over-window). A fragment takes what every
+        executor of it takes (``pipeline.chain_push_widths``)."""
         if self.per_chunk_step or self.pure_step() is not None:
             return push_lattice(capacity)
         return (int(capacity),)

@@ -27,6 +27,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from risingwave_tpu.array.chunk import StreamChunk
+from risingwave_tpu.array.lattice import push_lattice
 from risingwave_tpu.executors.base import Barrier, Executor
 from risingwave_tpu.ops.hash_table import (
     HashTable,
@@ -957,6 +958,48 @@ def emission_sizes(capacity: int) -> Tuple[int, ...]:
     return tuple(sizes) + (min(capacity, _EMIT_MAX),)
 
 
+def step_widths(capacity: int) -> Tuple[int, ...]:
+    """The widths a step is compiled at: twice each emission size. The
+    epoch's chunks are laid end to end into the smallest that holds
+    them (``_general_over_lay``), and the largest is the most the
+    executor holds before it steps: behind a Top-N, whose barrier hands
+    on a retract and an insert chunk of one of ITS emission sizes, the
+    two fill a width exactly."""
+    return tuple(2 * lanes for lanes in emission_sizes(capacity))
+
+
+@partial(
+    jax.jit,
+    static_argnames=("width", "lanes", "nullable"),
+    donate_argnums=(0,),
+)
+def _general_over_lay(
+    laid: Optional[StreamChunk],
+    chunk: StreamChunk,
+    at,
+    width: int,
+    lanes: Tuple[Tuple[str, object], ...],
+    nullable: Tuple[str, ...],
+) -> StreamChunk:
+    """``chunk``'s rows at lanes ``at`` and on of the epoch's one chunk
+    of ``width`` lanes (made here, with no valid row, where ``laid`` is
+    None): the columns the arena keeps, in its types, and a NULL lane
+    for each column that may hold one, whatever else the chunk carries.
+    One program a pair of widths; where the chunk lands is a value."""
+    piece = StreamChunk(
+        columns={name: chunk.col(name).astype(d) for name, d in lanes},
+        valid=chunk.valid,
+        nulls={
+            name: chunk.nulls.get(name, jnp.zeros(chunk.capacity, jnp.bool_))
+            for name in nullable
+        },
+        ops=chunk.ops,
+    )
+    if laid is None:
+        laid = jax.tree.map(lambda a: jnp.zeros(width, a.dtype), piece)
+    return _put(laid, piece, at)
+
+
 def _shift(a, d: int, fill):
     """``a[i + d]`` at lane i, ``fill`` where that lies outside: a
     static slice, where a gather of every lane would cost the device
@@ -1010,14 +1053,20 @@ def _general_over_step(
     every touched partition dirty, order the arena by (partition, the
     order column, the stream key) and recompute EVERY window call over
     the dirty partitions, then diff against the previously-emitted
-    lanes. The reference walks per-row affected frame ranges
-    (frame_finder.rs); here whole partitions are recomputed in one
-    sorted-segment program and the diff comes out the same. What the
-    program costs is the arena's capacity, whatever the chunk held
-    (PERF.md 6, PR 49): the order is three two-operand sorts and as many
-    gathers of every lane, the lanes the calls read are gathered into
-    it and their results gathered back, and the scans between are next
-    to nothing.
+    lanes. ``chunk`` is an epoch's chunks laid end to end in the order
+    they came (``_general_over_lay``): of a stream key's rows the last
+    stands (``last_occurrence_mask``), a delete may meet a row the
+    same chunk brought (``_chunk_dup``) and a row that left a
+    partition dirties it through a ghost entry, so what is diffed is
+    the epoch's net change whatever chunks it came in. The reference
+    walks per-row affected frame ranges (frame_finder.rs); here whole
+    partitions are recomputed in one sorted-segment program and the
+    diff comes out the same. What the program costs is the arena's
+    capacity, whatever the chunk held (PERF.md 6, PR 49): the order is
+    three two-operand sorts and as many gathers of every lane, the
+    lanes the calls read are gathered into it and their results
+    gathered back, and the scans between are next to nothing — which
+    is why it runs once a barrier and not once a chunk (PR 50).
 
     Returns the arena's new state, each call's values and NULL flags a
     slot, the slots to retract and to insert (``_general_over_emit``
@@ -1419,22 +1468,33 @@ class GeneralOverWindowExecutor(Executor, Checkpointable):
     order of their stream key ``pk``, as upstream's do.
 
     The recompute is NOT free: a step costs what the arena's CAPACITY
-    costs, whatever the chunk held — at 2^22 lanes on a v5e 0.70 s of
-    device time for 1,000 rows, most of it the order's sorts and the
-    capacity-wide gathers around them — and runs once a chunk: twice a
-    barrier behind a Top-N that hands on a retract and an insert chunk
-    (1,393 ms an epoch of 32,768 events in ``nexmark_q6.catchup``;
-    PERF.md 5 and 6, PR 49). What it hands on follows what
-    changed (``emission_sizes``).
+    costs, whatever the chunks held — at 2^22 lanes on a v5e 0.69 s of
+    device time for 1,000 rows in a chunk of 65,536 lanes, most of it
+    the order's sorts and the capacity-wide gathers around them
+    (PERF.md 6, PR 49), and 0.84 s for 2,000 rows in one of 131,072:
+    the chunk's part is 0.15 s for every 65,536 lanes, valid or not,
+    each probed, scattered and gathered (PERF.md 6, PR 50). So it
+    runs once a BARRIER: ``apply`` keeps the chunk and hands on nothing,
+    ``on_barrier`` lays the epoch's chunks end to end, in the order
+    they came, as one chunk of a declared width (``step_widths``) and
+    steps over that, and the step's own netting of a stream key's
+    ``-old / +new`` gives the epoch's net delta. Behind a Top-N, whose
+    barrier hands on a retract and an insert chunk, that is one step
+    where the parent ran two (``nexmark_q6.catchup``: 1,381 -> 840 ms
+    of the step's device time an epoch of 32,768 events, 14,915 ->
+    20,119 events/s; PERF.md 5 and 6, PR 50). No more lanes are
+    kept than the widest step takes: a chunk that would pass that makes
+    ``apply`` step what is kept first (an epoch wider than
+    ``step_widths``' largest takes more steps than one), and a barrier
+    that kept nothing runs no program. Nothing kept crosses a barrier,
+    so a checkpoint reads the arena after the epoch's last step. What
+    the step hands on follows what changed (``emission_sizes``).
 
     Supports every WindowCall kind including lead/lag(k), static ROWS
     frames and COUNT(col) (deletes may reopen any frame, so the general
     executor has no hold-back constraint — it simply recomputes).
     Checkpointable: current rows + emitted rows persist; recovery is
     bit-exact."""
-
-    # one step a chunk at the chunk's own width; knows ``warm``
-    per_chunk_step = True
 
     def __init__(
         self,
@@ -1473,11 +1533,21 @@ class GeneralOverWindowExecutor(Executor, Checkpointable):
             (c.frame[1] - c.frame[0] + 1 for c in self.calls if c.frame),
             default=0,
         )
+        self._lay_lanes = tuple(
+            (n, jnp.dtype(d)) for n, d in schema_dtypes.items()
+        )
         self._alloc(capacity)
         self._dropped = False
         self._bad_delete = False
         self._bound = 0
         self._epoch = _zero_epoch()
+        # the epoch's chunks as they came
+        self._held: List[StreamChunk] = []
+
+    def push_widths(self, capacity: int) -> Tuple[int, ...]:
+        """Any width of the push lattice: a chunk is kept as it comes
+        and laid into a step's own width, and ``warm`` knows how."""
+        return push_lattice(capacity)
 
     def lint_info(self):
         requires = set(self.part_keys) | set(self.pk) | {self.order_col}
@@ -1560,14 +1630,25 @@ class GeneralOverWindowExecutor(Executor, Checkpointable):
             ))
         )
 
+    def _laid(self, chunks: Sequence[StreamChunk], width: int):
+        """``chunks`` end to end as one chunk of ``width`` lanes."""
+        laid, at = None, 0
+        for chunk in chunks:
+            laid = _general_over_lay(
+                laid, chunk, at, width, self._lay_lanes, self.nullable
+            )
+            at += chunk.capacity
+        return laid
+
     def _run(self, chunk: StreamChunk, sizes=None):
-        """The step's programs for ``chunk``: the arena's step, ONE read
-        of its seven counts (which waits for it: what is handed on is
-        sized from them, and the view behind this executor reads the
-        chunks at once anyway), a round of the two deltas for every
-        ``lanes`` rows the larger holds (every retraction before any
-        insertion), and the adoption of what was handed on. ``sizes``:
-        the warm-up's, a round of each whatever the counts."""
+        """The step's programs for ``chunk``, the epoch's chunks laid
+        out as one: the arena's step, ONE read of its seven counts
+        (which waits for it: what is handed on is sized from them, and
+        the view behind this executor reads the chunks at once anyway),
+        a round of the two deltas for every ``lanes`` rows the larger
+        holds (every retraction before any insertion), and the adoption
+        of what was handed on. ``sizes``: the warm-up's, a round of
+        each whatever the counts."""
         (
             self.table, self.buf, self.bnulls, self.present, self.sdirty,
             new_out, new_nulls, retract, insert, status,
@@ -1596,20 +1677,48 @@ class GeneralOverWindowExecutor(Executor, Checkpointable):
         return status, [r for r, _ in pairs] + [i for _, i in pairs]
 
     def apply(self, chunk: StreamChunk) -> List[StreamChunk]:
+        """Keep the chunk for the barrier's step. Hands on nothing, but
+        in an epoch wider than the widest step: what is kept is stepped
+        before the chunk that would pass that width joins it."""
         for c in self.calls:
             if c.kind in ("rank", "dense_rank") and c.input in chunk.nulls:
                 raise ValueError(
                     f"rank order column {c.input!r} carries a null lane "
                     "(NULL ordering unsupported)"
                 )
-        self._maybe_grow(chunk.capacity)
+        limit = step_widths(self.capacity)[-1]
+        outs: List[StreamChunk] = []
+        if self._held_lanes() + chunk.capacity > limit:
+            outs = self._step_held()
+        self._held.append(chunk)
+        if chunk.capacity > limit:  # wider than it alone: as it comes
+            outs += self._step_held()
+        return outs
+
+    def _held_lanes(self) -> int:
+        # (known on the host: no read)
+        return sum(c.capacity for c in self._held)
+
+    def _step_held(self) -> List[StreamChunk]:
+        """ONE step over every chunk kept, and what it hands on;
+        nothing, and no program, where none is kept."""
+        chunks, lanes = self._held, self._held_lanes()
+        if not chunks:
+            return []
+        self._held = []
+        self._maybe_grow(lanes)
+        # the smallest declared width that holds them; their own where
+        # none does (one chunk wider than all of them)
+        width = next(
+            (w for w in step_widths(self.capacity) if w >= lanes), lanes
+        )
         with span(
             "over.step", table_id=self.table_id, capacity=self.capacity,
-            chunk_lanes=chunk.capacity, calls=len(self.calls),
+            chunk_lanes=width, calls=len(self.calls),
             frame_rows=self._frame_rows,
             row_bytes=self.row_bytes,
         ) as sp:
-            status, outs = self._run(chunk)
+            status, outs = self._run(self._laid(chunks, width))
             dropped, bad, in_rows, n_ret, n_ins, parts, rows = status
             emit_lanes = sum(c.capacity for c in outs)
             sp.args.update(
@@ -1622,6 +1731,7 @@ class GeneralOverWindowExecutor(Executor, Checkpointable):
         self._bad_delete |= bool(bad)
         e = self._epoch
         e["steps"] += 1
+        e["chunks"] += len(chunks)
         e["in_rows"] += in_rows
         e["dirty_partitions"] += parts
         e["dirty_rows"] += rows
@@ -1632,10 +1742,26 @@ class GeneralOverWindowExecutor(Executor, Checkpointable):
 
     # -- the warm-up pass --------------------------------------------------
     def warm(self, chunk: StreamChunk) -> List[StreamChunk]:
-        """The step's programs for a chunk of this shape, and a round
-        of every emission size: a chunk with no valid row touches no
+        """The programs a chunk of this shape can end in: its place in
+        every declared width that holds it, as an epoch's first chunk
+        and as a later one, a step at each of those widths, and a round
+        of every emission size. A chunk with no valid row touches no
         slot, so the arena it hands back is the arena it was given."""
-        return self._run(chunk, sizes=emission_sizes(self.capacity))[1]
+        widths = [
+            w for w in step_widths(self.capacity) if w >= chunk.capacity
+        ] or [chunk.capacity]
+        for width in widths:
+            # (a later chunk's program over the first one's lanes: there
+            # is room for that at every width)
+            laid = _general_over_lay(
+                self._laid([chunk], width), chunk, 0, width,
+                self._lay_lanes, self.nullable,
+            )
+            last = width == widths[-1]
+            outs = self._run(
+                laid, sizes=emission_sizes(self.capacity) if last else ()
+            )[1]
+        return outs
 
     def _maybe_grow(self, incoming: int):
         cap = self.capacity
@@ -1682,6 +1808,9 @@ class GeneralOverWindowExecutor(Executor, Checkpointable):
         self.table = new
 
     def on_barrier(self, barrier: Barrier) -> List[StreamChunk]:
+        # the epoch's step: before the latches are raised, and before
+        # the checkpoint reads the arena
+        outs = self._step_held()
         e, self._epoch = self._epoch, _zero_epoch()
         if e["steps"]:
             # the epoch's steps, from the counts each of them read
@@ -1693,6 +1822,9 @@ class GeneralOverWindowExecutor(Executor, Checkpointable):
             count = REGISTRY.counter
             count("over_window_steps_total").inc(
                 e["steps"], table_id=self.table_id
+            )
+            count("over_window_buffered_chunks_total").inc(
+                e["chunks"], table_id=self.table_id
             )
             count("over_window_input_rows_total").inc(
                 e["in_rows"], table_id=self.table_id
@@ -1707,7 +1839,7 @@ class GeneralOverWindowExecutor(Executor, Checkpointable):
                 "general OverWindow received a DELETE for an unknown pk "
                 "(inconsistent upstream)"
             )
-        return []
+        return outs
 
     # -- integrity --------------------------------------------------------
     def _lanes(self):
@@ -1786,13 +1918,14 @@ class GeneralOverWindowExecutor(Executor, Checkpointable):
         self._bound = int(n)
         self._dropped = self._bad_delete = False
         self._epoch = _zero_epoch()
+        self._held = []
 
 
 def _zero_epoch() -> Dict[str, int]:
     """What ``over.barrier`` says of an epoch's steps."""
     return dict.fromkeys(
         (
-            "steps", "in_rows", "dirty_partitions", "dirty_rows",
+            "steps", "chunks", "in_rows", "dirty_partitions", "dirty_rows",
             "retract_rows", "insert_rows", "emit_lanes",
         ),
         0,

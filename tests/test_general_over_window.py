@@ -79,6 +79,12 @@ def _oracle(rows, calls):
                     ]
                     # frame sum is NULL when no non-NULL row is in frame
                     vals.append(sum(window) if window else None)
+                elif c.kind == "count" and c.frame is not None:
+                    lo, hi = c.frame
+                    vals.append(sum(
+                        items[j][3] is not None
+                        for j in range(max(0, i + lo), min(n, i + hi + 1))
+                    ))
                 elif c.kind == "lead":
                     j = i + c.offset
                     vals.append(items[j][3] if j < n else None)
@@ -91,59 +97,69 @@ def _oracle(rows, calls):
     return out
 
 
-def _drive(ex, chunks_ops, calls, mv=None, np=None):
-    """Push op lists through the executor, maintaining the downstream
-    MV from its retract/insert emissions. Returns the MV set."""
+def _chunk(ops_rows, np, cap=CAP):
     from risingwave_tpu.array.chunk import StreamChunk
 
-    mv = set() if mv is None else mv
+    cols = {
+        "id": np.array([r[1] for r in ops_rows], np.int64),
+        "p": np.array([r[2] for r in ops_rows], np.int64),
+        "o": np.array([r[3] for r in ops_rows], np.int64),
+        "x": np.array(
+            [0 if r[4] is None else r[4] for r in ops_rows], np.int64
+        ),
+    }
+    nulls = {"x": np.array([r[4] is None for r in ops_rows], bool)}
+    opcodes = np.array(
+        [0 if r[0] == "+" else 1 for r in ops_rows], np.int32
+    )
+    return StreamChunk.from_numpy(cols, cap, ops=opcodes, nulls=nulls)
+
+
+def _fold(mv, outs, calls, np):
+    """The handed-on deltas into the downstream MV (a set of rows)."""
     out_names = [c.output for c in calls]
-    for ops_rows in chunks_ops:
-        cols = {
-            "id": np.array([r[1] for r in ops_rows], np.int64),
-            "p": np.array([r[2] for r in ops_rows], np.int64),
-            "o": np.array([r[3] for r in ops_rows], np.int64),
-            "x": np.array(
-                [0 if r[4] is None else r[4] for r in ops_rows], np.int64
-            ),
-        }
-        nulls = {"x": np.array([r[4] is None for r in ops_rows], bool)}
-        opcodes = np.array(
-            [0 if r[0] == "+" else 1 for r in ops_rows], np.int32
-        )
-        chunk = StreamChunk.from_numpy(
-            cols, CAP, ops=opcodes, nulls=nulls
-        )
-        for out in ex.apply(chunk):
-            d = out.to_numpy()
-            for i in range(len(d["id"])):
-                x = (
-                    None
-                    if d.get("x__null", np.zeros(len(d["id"]), bool))[i]
-                    else int(d["x"][i])
-                )
-                vals = tuple(
-                    None
-                    if d.get(f"{nm}__null", np.zeros(len(d["id"]), bool))[
-                        i
-                    ]
-                    else int(d[nm][i])
-                    for nm in out_names
-                )
-                row = (
-                    int(d["id"][i]),
-                    int(d["p"][i]),
-                    int(d["o"][i]),
-                    x,
-                ) + vals
-                if int(d["__op__"][i]) == 1:  # DELETE
-                    assert row in mv, f"retracting absent row {row}"
-                    mv.remove(row)
-                else:
-                    assert row not in mv, f"double insert {row}"
-                    mv.add(row)
-        ex.on_barrier(None)
+    for out in outs:
+        d = out.to_numpy()
+        no_nulls = np.zeros(len(d["id"]), bool)
+        for i in range(len(d["id"])):
+            x = None if d.get("x__null", no_nulls)[i] else int(d["x"][i])
+            vals = tuple(
+                None if d.get(f"{nm}__null", no_nulls)[i] else int(d[nm][i])
+                for nm in out_names
+            )
+            row = (int(d["id"][i]), int(d["p"][i]), int(d["o"][i]), x) + vals
+            if int(d["__op__"][i]) == 1:  # DELETE
+                assert row in mv, f"retracting absent row {row}"
+                mv.remove(row)
+            else:
+                assert row not in mv, f"double insert {row}"
+                mv.add(row)
+
+
+def _drive(ex, chunks_ops, calls, mv=None, np=None, per_epoch=1):
+    """Push op lists through the executor, a barrier after every
+    ``per_epoch`` of them, maintaining the downstream MV from its
+    retract/insert emissions. Returns the MV set."""
+    mv = set() if mv is None else mv
+    for at in range(0, len(chunks_ops), per_epoch):
+        for ops_rows in chunks_ops[at:at + per_epoch]:
+            # (nothing is handed on before the barrier: the widest step
+            # holds every epoch of these tests)
+            assert ex.apply(_chunk(ops_rows, np)) == []
+        _fold(mv, ex.on_barrier(None), calls, np)
     return mv
+
+
+def _replay(rows, chunks_ops):
+    """The live {id: (p, o, x, seq)} after the chunks' ops, in order."""
+    rows = dict(rows)
+    for ops_rows in chunks_ops:
+        for op, rid, p, o, x in ops_rows:
+            if op == "+":
+                rows[rid] = (p, o, x, 0)
+            else:
+                assert rows.pop(rid)[:3] == (p, o, x)
+    return rows
 
 
 def _random_stream(rng, n_chunks, rows, next_id):
@@ -353,3 +369,215 @@ def test_checkpoint_restore_mid_stream():
         ex2.restore_state("general_over", key_cols, value_cols)
     mv2 = _drive(ex2, chunks[6:], calls, mv=set(mv), np=np)
     assert mv2 == _oracle(rows, calls)
+
+
+# -- one step a barrier: the epoch's chunks wait and are stepped as one -------
+
+def _all_calls():
+    from risingwave_tpu.executors.over_window import WindowCall
+
+    return (
+        WindowCall("row_number", None, "rn"),
+        WindowCall("rank", "o", "rk"),
+        WindowCall("sum", "x", "sx"),
+        WindowCall("sum", "x", "fs", frame=(-2, 0)),
+        WindowCall("count", "x", "fc", frame=(-2, 0)),
+        WindowCall("lag", "x", "lg"),
+    )
+
+
+def _check_epochs(epochs, np, jnp):
+    """Three executors over the same rows: one steps once a barrier
+    over the epoch's chunks, one has a barrier behind every chunk (a
+    step a chunk, which is what the parent ran), one is handed each
+    epoch's rows as ONE chunk. The view after every barrier equals the
+    oracle's and the chunk-a-step twin's, and the three states are the
+    same state."""
+    calls = _all_calls()
+    ex, twin, one = (_mk_exec(jnp, calls) for _ in range(3))
+    mv, twin_mv, one_mv, rows = set(), set(), set(), {}
+    for chunks in epochs:
+        _drive(ex, chunks, calls, mv=mv, np=np, per_epoch=len(chunks))
+        _drive(twin, chunks, calls, mv=twin_mv, np=np)
+        flat = [r for ops_rows in chunks for r in ops_rows]
+        assert one.apply(_chunk(flat, np, cap=4 * CAP)) == []
+        _fold(one_mv, one.on_barrier(None), calls, np)
+        rows = _replay(rows, chunks)
+        assert mv == _oracle(rows, calls)
+        assert mv == twin_mv == one_mv
+        assert ex.state_digest() == twin.state_digest() == one.state_digest()
+        assert ex._epoch["steps"] == 0 and ex._held == []
+    return mv
+
+
+HAND_MADE = {
+    # -old in one chunk, +new in the next: an update of one stream key
+    "an_update_split_across_chunks": [
+        [[("+", 0, 1, 10, 5), ("+", 1, 1, 20, 6), ("+", 2, 1, 30, 7)]],
+        [[("-", 1, 1, 20, 6)], [("+", 1, 1, 20, 60)]],
+        [[("-", 0, 1, 10, 5)], [("+", 3, 2, 1, 1)], [("+", 0, 1, 10, 50)]],
+    ],
+    # a delete, and the very row again in the next chunk: no net change
+    "a_delete_and_the_row_again": [
+        [[("+", 0, 1, 10, 5), ("+", 1, 1, 20, 6)], [("+", 2, 1, 30, 7)]],
+        [[("-", 1, 1, 20, 6)], [("+", 1, 1, 20, 6)]],
+        [[("-", 2, 1, 30, 7), ("-", 0, 1, 10, 5)], [("+", 2, 1, 30, 7)],
+         [("-", 2, 1, 30, 7)], [("+", 2, 1, 30, 8)]],
+    ],
+    # a row leaves a partition in one chunk and joins another in the
+    # next: the rows it left re-number, and a second move in the same
+    # epoch passes through a partition it never shows in
+    "a_partition_move_across_chunks": [
+        [[("+", 0, 1, 10, 5), ("+", 1, 1, 20, 6), ("+", 2, 1, 30, 7),
+          ("+", 3, 2, 5, 1)]],
+        [[("-", 1, 1, 20, 6)], [("+", 1, 2, 20, 6)]],
+        [[("-", 0, 1, 10, 5)], [("+", 0, 2, 10, 5)], [("-", 0, 2, 10, 5)],
+         [("+", 0, 3, 10, 5)]],
+    ],
+    # the order column moves a row inside its neighbours' frames
+    "an_order_move_inside_a_frame": [
+        [[("+", i, 1, 10 * i, i + 1) for i in range(6)]],
+        [[("-", 4, 1, 40, 5)], [("+", 4, 1, 15, 5)]],
+        [[("-", 0, 1, 0, 1)], [("+", 0, 1, 35, 1), ("-", 2, 1, 20, 3)],
+         [("+", 2, 1, 36, 3)]],
+    ],
+    # NULL inputs: they count for nothing in a frame, a frame of
+    # nothing else is NULL, and a NULL can become a value
+    "null_inputs": [
+        [[("+", 0, 1, 10, None), ("+", 1, 1, 20, None)],
+         [("+", 2, 1, 30, 7), ("+", 3, 2, 1, None)]],
+        [[("-", 1, 1, 20, None)], [("+", 1, 1, 20, 4)]],
+        [[("-", 2, 1, 30, 7)], [("+", 2, 1, 30, None)],
+         [("-", 3, 2, 1, None), ("+", 3, 2, 1, 9)]],
+    ],
+}
+
+
+@_pytest.mark.parametrize("case", sorted(HAND_MADE))
+def test_hand_made_epochs_of_several_chunks(case):
+    import jax.numpy as jnp
+    import numpy as np
+
+    assert _check_epochs(HAND_MADE[case], np, jnp)
+
+
+@_pytest.mark.parametrize(
+    "seed,per_epoch", [(3, 2), (5, 3), (7, 4), (2147483999, 5)]
+)
+def test_seeded_epochs_of_several_chunks(seed, per_epoch):
+    import jax.numpy as jnp
+    import numpy as np
+
+    rng = np.random.default_rng(seed)
+    chunks, rows, _ = _random_stream(rng, 3 * per_epoch, {}, 0)
+    epochs = [
+        chunks[at:at + per_epoch] for at in range(0, len(chunks), per_epoch)
+    ]
+    mv = _check_epochs(epochs, np, jnp)
+    assert {r[0] for r in mv} == set(rows)
+
+
+def test_checkpoint_restore_between_epochs_of_several_chunks():
+    """Nothing kept crosses a barrier: a restore from the deltas staged
+    at barriers continues to the state an uninterrupted twin reaches."""
+    import jax.numpy as jnp
+    import numpy as np
+
+    calls = _all_calls()
+    rng = np.random.default_rng(29)
+    chunks, rows, _ = _random_stream(rng, 12, {}, 0)
+    ex, twin = _mk_exec(jnp, calls), _mk_exec(jnp, calls)
+    store = {}
+    mv = _drive(ex, chunks[:6], calls, np=np, per_epoch=3)
+    for d in ex.checkpoint_delta():
+        for i in range(len(d.key_cols["k0"])):
+            k = int(d.key_cols["k0"][i])
+            if d.tombstone[i]:
+                store.pop(k, None)
+            else:
+                store[k] = {vn: v[i] for vn, v in d.value_cols.items()}
+    assert ex._held == []
+    keys = sorted(store)
+    ex2 = _mk_exec(jnp, calls)
+    ex2.restore_state(
+        "general_over", {"k0": np.array(keys, np.int64)},
+        {vn: np.array([store[k][vn] for k in keys])
+         for vn in next(iter(store.values()))},
+    )
+    assert ex2.state_digest() == ex.state_digest()
+    mv2 = _drive(ex2, chunks[6:], calls, mv=set(mv), np=np, per_epoch=3)
+    _drive(twin, chunks, calls, np=np, per_epoch=3)
+    assert mv2 == _oracle(rows, calls)
+    assert ex2.state_digest() == twin.state_digest()
+
+
+def _steps(table_id):
+    from risingwave_tpu.metrics import REGISTRY
+
+    return sum(
+        v for k, v in REGISTRY.counter("over_window_steps_total")._values.items()
+        if dict(k).get("table_id") == table_id
+    )
+
+
+def test_a_barrier_with_nothing_kept_runs_no_step(monkeypatch):
+    import jax.numpy as jnp
+    import numpy as np
+
+    from risingwave_tpu.executors import over_window
+    from risingwave_tpu.trace import TRACER
+
+    calls = _all_calls()
+    ex = _mk_exec(jnp, calls)
+    ex.table_id = "general_over_idle"
+    _drive(ex, [[("+", 0, 1, 10, 5)]], calls, np=np)
+    assert _steps(ex.table_id) == 1
+
+    def no_program(*a, **k):
+        raise AssertionError("an idle barrier ran a program")
+
+    for name in ("_general_over_step", "_general_over_lay",
+                 "_general_over_emit", "_general_over_commit"):
+        monkeypatch.setattr(over_window, name, no_program)
+    TRACER.clear()
+    assert ex.on_barrier(None) == []
+    assert _steps(ex.table_id) == 1
+    assert not [
+        sp for sp in TRACER.spans() if sp.name in ("over.step", "over.barrier")
+    ]
+
+
+def test_an_epoch_wider_than_the_widest_step_steps_early(monkeypatch):
+    """The lanes kept never pass the widest declared step: the chunk
+    that would is what makes ``apply`` step, and the view stands."""
+    import jax.numpy as jnp
+    import numpy as np
+
+    from risingwave_tpu.executors import over_window
+
+    monkeypatch.setattr(over_window, "_EMIT_MAX", 2 * CAP)
+    calls = _all_calls()
+    ex = _mk_exec(jnp, calls, capacity=1 << 10)
+    (limit,) = over_window.step_widths(ex.capacity)
+    assert limit == 4 * CAP
+    rng = np.random.default_rng(41)
+    chunks, rows, _ = _random_stream(rng, 11, {}, 0)
+    mv, handed = set(), []
+    for ops_rows in chunks:
+        outs = ex.apply(_chunk(ops_rows, np))
+        handed.append(bool(outs))
+        _fold(mv, outs, calls, np)
+        assert ex._held_lanes() <= limit
+    # four chunks fill the widest step; the fifth and the ninth step them
+    assert handed == [i in (4, 8) for i in range(11)]
+    assert ex._epoch["steps"] == 2 and ex._epoch["chunks"] == 8
+    _fold(mv, ex.on_barrier(None), calls, np)
+    assert mv == _oracle(rows, calls)
+    assert ex.capacity == 1 << 10  # (the widths stood: nothing grew)
+    # a chunk wider than the widest step is stepped alone, as it comes
+    wide = [("+", 10_000 + i, 7, i, i) for i in range(5)]
+    outs = ex.apply(_chunk(wide, np, cap=8 * CAP))
+    assert outs and ex._held == []
+    _fold(mv, outs, calls, np)
+    assert ex.on_barrier(None) == []
+    assert mv == _oracle(_replay(rows, [wide]), calls)

@@ -29,6 +29,7 @@ from risingwave_tpu.executors.over_window import (
     GeneralOverWindowExecutor,
     WindowCall,
     emission_sizes,
+    step_widths,
 )
 from risingwave_tpu.executors.project import ProjectExecutor
 from risingwave_tpu.executors.stream_join import StreamJoinExecutor
@@ -447,6 +448,7 @@ def test_a_later_lower_bid_is_a_retraction_into_the_window(tmp_path):
             for name in (
                 "over_window_input_rows_total",
                 "over_window_emitted_rows_total", "over_window_steps_total",
+                "over_window_buffered_chunks_total",
             )
         }
 
@@ -465,32 +467,38 @@ def test_a_later_lower_bid_is_a_retraction_into_the_window(tmp_path):
         spans = TRACER.spans()
         (barrier,) = [sp for sp in spans if sp.name == "over.barrier"]
         steps = [sp for sp in spans if sp.name == "over.step"]
-        # the Top-N's delta: a retract chunk and an insert chunk
-        assert barrier.args["steps"] == len(steps) == 2
+        # the Top-N's delta, a retract chunk and an insert chunk, in ONE
+        # step at the barrier
+        assert barrier.args["steps"] == len(steps) == 1
+        assert barrier.args["chunks"] == 2
         assert barrier.args["table_id"] == over.table_id
         assert barrier.args["in_rows"] == 2  # the U- and the U+
-        assert barrier.args["dirty_partitions"] == 2  # one, in both steps
-        # the U- retracts the seller's three rows and re-inserts the two
-        # that stay, each a frame shorter; the U+ inserts the row at the
-        # partition's end and moves no other
+        assert barrier.args["dirty_partitions"] == 1
+        # the row leaves the front of the seller's order for its end: its
+        # own frame and both others' change, and each is handed on once
         assert barrier.args["retract_rows"] == barrier.args["insert_rows"] == 3
         assert barrier.args["emitted_rows"] == 6
-        assert barrier.args["emit_lanes"] == 4 * emission_sizes(over.capacity)[0]
+        assert barrier.args["emit_lanes"] == 2 * emission_sizes(over.capacity)[0]
         for sp in steps:
             assert sp.args["capacity"] == over.capacity
+            # the two chunks laid end to end, in a declared width
+            assert sp.args["chunk_lanes"] == step_widths(over.capacity)[0]
+            assert sp.args["in_rows"] == 2 and sp.args["retract_rows"] == 3
             assert sp.args["calls"] == 2 and sp.args["frame_rows"] == 11
             assert sp.args["row_bytes"] == over.row_bytes == 5 * 8 + 1
             assert sp.epoch == barrier.epoch
         after = {name: count(name) for name in counters}
         assert after["over_window_steps_total"] - before[
-            "over_window_steps_total"] == 2
+            "over_window_steps_total"] == 1
+        assert after["over_window_buffered_chunks_total"] - before[
+            "over_window_buffered_chunks_total"] == 2
         assert after["over_window_input_rows_total"] - before[
             "over_window_input_rows_total"] == 2
         assert after["over_window_emitted_rows_total"] - before[
             "over_window_emitted_rows_total"] == 6
         # one blocking read a step, and it is the step's own
         reads = [sp.args["what"] for sp in spans if sp.name == "device.read"]
-        assert reads.count("over.status") == 2
+        assert reads.count("over.status") == 1
     finally:
         served.close()
 
@@ -534,7 +542,8 @@ def test_the_warm_up_leaves_no_mark_and_compiles_every_emission_size():
     chunk = StreamChunk.from_numpy(
         {k: np.asarray(v, np.int64) for k, v in cols.items()}, 64
     )
-    outs = over.apply(chunk)
+    assert over.apply(chunk) == []  # kept for the barrier's step
+    outs = over.on_barrier(None)
     assert [int(c.valid.sum()) for c in outs] == [0, 2]
     digest, bound = over.state_digest(), over._bound
     empty = StreamChunk.from_numpy(
@@ -545,7 +554,8 @@ def test_the_warm_up_leaves_no_mark_and_compiles_every_emission_size():
     assert [c.capacity for c in warmed] == list(sizes) * 2
     assert not any(int(c.valid.sum()) for c in warmed)
     assert over.state_digest() == digest and over._bound == bound
-    assert over.on_barrier(None) == []
+    # and keeps nothing: the next barrier has no step to run
+    assert over._held == [] and over.on_barrier(None) == []
     # a delta past the largest size goes in rounds of it
     assert emission_sizes(1 << 22) == (1 << 14, 1 << 16)
     assert over.trace_contract()["emission_caps"] == sizes == (1 << 9,)
@@ -566,22 +576,25 @@ def test_a_delta_past_the_largest_size_goes_in_rounds(monkeypatch):
         capacity=1 << 10, nullable=("x",),
     )
     assert emission_sizes(over.capacity) == (64,)
-    ids = np.arange(200, dtype=np.int64)
+    assert step_widths(over.capacity) == (128,)
+    ids = np.arange(120, dtype=np.int64)
     cols = {"id": ids, "p": ids % 3, "o": ids, "x": ids}
-    outs = over.apply(StreamChunk.from_numpy(cols, 256))
-    # four rounds of 64 lanes: every retraction before any insertion
-    assert [c.capacity for c in outs] == [64] * 8
-    assert [int(c.valid.sum()) for c in outs] == [0] * 4 + [64, 64, 64, 8]
-    got = np.concatenate([c.to_numpy()["id"] for c in outs[4:]])
+    assert over.apply(StreamChunk.from_numpy(cols, 128)) == []
+    outs = over.on_barrier(None)
+    # two rounds of 64 lanes: every retraction before any insertion
+    assert [c.capacity for c in outs] == [64] * 4
+    assert [int(c.valid.sum()) for c in outs] == [0] * 2 + [64, 56]
+    got = np.concatenate([c.to_numpy()["id"] for c in outs[2:]])
     assert sorted(got.tolist()) == ids.tolist()
     # one row's order moves to the front of its partition: it and the
     # row it now stands before change (the row behind its old place
     # still counts two), in one round
-    moved = {"id": [150], "p": [0], "o": [-1], "x": [150]}
-    outs = over.apply(StreamChunk.from_numpy(
-        {k: np.asarray(v, np.int64) for k, v in moved.items()}, 256
-    ))
+    moved = {"id": [114], "p": [0], "o": [-1], "x": [114]}
+    assert over.apply(StreamChunk.from_numpy(
+        {k: np.asarray(v, np.int64) for k, v in moved.items()}, 128
+    )) == []
+    outs = over.on_barrier(None)
     assert [c.capacity for c in outs] == [64, 64]
     ret, ins = (c.to_numpy() for c in outs)
-    assert sorted(ret["id"].tolist()) == sorted(ins["id"].tolist()) == [0, 150]
-    assert dict(zip(ins["id"].tolist(), ins["c"].tolist())) == {150: 1, 0: 2}
+    assert sorted(ret["id"].tolist()) == sorted(ins["id"].tolist()) == [0, 114]
+    assert dict(zip(ins["id"].tolist(), ins["c"].tolist())) == {114: 1, 0: 2}
