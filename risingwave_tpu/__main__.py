@@ -10,6 +10,7 @@ metrics endpoint; `CREATE TABLE` / `CREATE MATERIALIZED VIEW` /
 from __future__ import annotations
 
 import argparse
+import contextlib
 import os
 import sys
 import threading
@@ -247,6 +248,26 @@ def main() -> None:
     )
     bb.add_argument("--json", action="store_true")
     bb.set_defaults(fn=_blackbox_read)
+    pg = sub.add_parser(
+        "programs",
+        help="what the device's operations are: the instruction -> "
+        "named scope map (trace.program_ops) of the programs a session "
+        "compiles for a configuration's DDL at a capacity, after one "
+        "empty chunk a stream and one barrier; with --rows, the rows of "
+        "a run's breakdown.device_ops (the last line of a benchmark "
+        "run, on stdin) each with its scope, source line and whether it "
+        "is nested (trace.name_ops). Instruction names are the "
+        "compiler's: name a chip run's rows with --device tpu",
+    )
+    pg.add_argument(
+        "config", help="a configuration with ddl, mv_sql, streams, "
+        "chunk_rows and session.exec_mode (benchmarks/configs/*.json)"
+    )
+    pg.add_argument("--capacity", type=int, required=True)
+    pg.add_argument("--module", default=None, help="one XLA module alone")
+    pg.add_argument("--rows", action="store_true")
+    pg.add_argument("--device", choices=["cpu", "tpu"], default="cpu")
+    pg.set_defaults(fn=_programs)
     cn = sub.add_parser(
         "compute-node",
         help="start a compute-node role behind a TCP wire "
@@ -264,6 +285,72 @@ def _compute_node(args) -> None:
     from risingwave_tpu.cluster.compute_node import run
 
     run(args.port, args.state_dir, args.device)
+
+
+@contextlib.contextmanager
+def driven_session(config: dict, capacity: int):
+    """The session ``serve`` would build for a configuration's DDL
+    (``ddl``, ``mv_sql``, ``streams``, ``chunk_rows``,
+    ``session.exec_mode``) at ``capacity``, driven just far enough that
+    the programs of its route exist: one chunk with no row a stream
+    (which settles the push widths and warms each) and one barrier."""
+    import numpy as np
+
+    from risingwave_tpu.array.chunk import StreamChunk
+    from risingwave_tpu.frontend import SqlSession
+    from risingwave_tpu.runtime import StreamingRuntime
+    from risingwave_tpu.sql import Catalog
+    from risingwave_tpu.storage.object_store import MemObjectStore
+
+    runtime = StreamingRuntime(MemObjectStore())
+    session = SqlSession(
+        Catalog({}), runtime, capacity=capacity,
+        exec_mode=config["session"]["exec_mode"],
+    )
+    try:
+        for sql in config["ddl"] + config["mv_sql"]:
+            session.execute(sql)
+        for stream in config["streams"]:
+            schema = session.catalog.tables[stream]
+            chunk = StreamChunk.from_numpy(
+                {n: np.zeros(0, np.int64) for n in schema.names},
+                config["chunk_rows"], schema=schema,
+            )
+            with runtime.lock:
+                for frag, side in session.dml._targets.get(stream, ()):
+                    runtime.push(frag, chunk, side)
+        runtime.barrier()
+        runtime.wait_checkpoints()
+        yield session
+    finally:
+        session.close()
+        for p in runtime.fragments.values():
+            close = getattr(p, "close", None)
+            if close is not None:
+                close()
+
+
+def _programs(args) -> None:
+    """``trace.program_ops`` / ``name_ops`` of a ``driven_session``, as
+    JSON."""
+    import json
+
+    from risingwave_tpu.config import enable_compile_cache, select_device
+
+    select_device(args.device)
+    enable_compile_cache()
+    from risingwave_tpu import trace
+
+    with open(args.config) as f:
+        config = json.load(f)
+    with driven_session(config, args.capacity):
+        if args.rows:
+            last = [ln for ln in sys.stdin.read().splitlines() if ln.strip()]
+            rows = json.loads(last[-1])["breakdown"]["device_ops"]
+            out = trace.name_ops(rows)
+        else:
+            out = trace.program_ops(args.module)
+        print(json.dumps(out, indent=1))
 
 
 def _blackbox_read(args) -> None:

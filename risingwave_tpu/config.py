@@ -193,10 +193,29 @@ def enable_compile_cache() -> str:
     backend, device kind and XLA flags, so one flat directory serves
     every context. The environment is never written. Returns the
     directory in effect."""
+    import re
+
     import jax
 
     if not os.environ.get("JAX_COMPILATION_CACHE_DIR"):
         jax.config.update(
             "jax_compilation_cache_dir", DEFAULT_COMPILE_CACHE_DIR
         )
+    # An instruction's named scope and source line are metadata, and
+    # jax keeps metadata out of a cache entry's key unless told: a
+    # program whose text stood while its scopes were written (or its
+    # file edited) would load with the names it was first compiled
+    # with, and ``trace.program_ops`` would read those off it. So the
+    # key takes the metadata in, made the same wherever the checkout
+    # lies (file names from its root on) and whoever called first (a
+    # location is its innermost frame, not the ten above it).
+    jax.config.update("jax_compilation_cache_include_metadata_in_key", True)
+    jax.config.update(
+        "jax_hlo_source_file_canonicalization_regex",
+        re.escape(os.path.dirname(DEFAULT_COMPILE_CACHE_DIR) + os.sep),
+    )
+    # (the limit, not ``jax_include_full_tracebacks_in_locations``:
+    # without full tracebacks jax 0.9 drops the scopes a called program
+    # opens from its operations' names)
+    jax.config.update("jax_traceback_in_locations_limit", 1)
     return jax.config.jax_compilation_cache_dir

@@ -51,14 +51,16 @@ def dedup_step_fn(
     signs = chunk.effective_signs()
     saw_delete = jnp.any(chunk.valid & (signs < 0))
     valid = chunk.valid & (signs > 0)
-    table, slots, _, inserted = lookup_or_insert(table, key_cols, valid)
-    table = set_live(table, jnp.where(inserted, slots, -1), True)
-    sdirty = sdirty.at[
-        jnp.where(inserted, slots, table.capacity)
-    ].set(True, mode="drop")
-    dropped = jnp.any(valid & (slots < 0))
-    # `inserted` marks a claim's winner AND its same-key twins; keep one
-    emit = inserted & first_occurrence_mask(slots, inserted)
+    with jax.named_scope("dedup/seen"):
+        table, slots, _, inserted = lookup_or_insert(table, key_cols, valid)
+        table = set_live(table, jnp.where(inserted, slots, -1), True)
+        sdirty = sdirty.at[
+            jnp.where(inserted, slots, table.capacity)
+        ].set(True, mode="drop")
+        dropped = jnp.any(valid & (slots < 0))
+    with jax.named_scope("dedup/first"):
+        # `inserted` marks a claim's winner AND its same-key twins; keep one
+        emit = inserted & first_occurrence_mask(slots, inserted)
     return table, sdirty, chunk.mask(emit), saw_delete, dropped
 
 

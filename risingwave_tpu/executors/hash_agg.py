@@ -46,7 +46,16 @@ from risingwave_tpu.storage.state_table import (
 from risingwave_tpu.ops import agg as agg_ops
 from risingwave_tpu.ops import minput as mi_ops
 from risingwave_tpu.ops.agg import AggCall, AggState
-from risingwave_tpu.ops.hash_table import HashTable, lookup, lookup_or_insert, stage_scalars, set_live
+from risingwave_tpu.ops.hash_table import (
+    PROBE_STATS,
+    HashTable,
+    lookup,
+    lookup_or_insert,
+    lookup_or_insert_counted,
+    note_probes,
+    set_live,
+    stage_scalars,
+)
 from risingwave_tpu.metrics import REGISTRY
 from risingwave_tpu.array.lattice import (
     TOUCHED_MAX,
@@ -88,6 +97,7 @@ def _build_key_lanes(
     return tuple(lanes)
 
 
+@jax.named_scope("agg/minput")
 def _minput_pass(state, minput, mi_bad, calls, slots, signs, chunk):
     """Fold a row batch into every materialized MIN/MAX multiset and
     write each touched group's new extreme / live count back into the
@@ -211,7 +221,7 @@ def _agg_scan(
 
 def _epoch_reduced_fn(
     table, state, dropped, stacked, calls, group_keys, nullable, pre,
-    minput=None, mi_bad=None, touched=None, at=None,
+    minput=None, mi_bad=None, touched=None, at=None, probes=None,
 ):
     """The TPU-first epoch path: vmap the stateless prefix over the
     chunk axis, flatten the whole epoch into one row batch, pre-reduce
@@ -224,7 +234,9 @@ def _epoch_reduced_fn(
     CPU actor. Commutativity across one epoch's rows makes the
     reordering exact (sum/count; append-only min/max latch retractions
     either way). ``touched`` / ``at`` as in ``agg_step_fn``: the
-    distinct keys' slots, one lane a row of the batch."""
+    distinct keys' slots, one lane a row of the batch. With ``probes``
+    (the executor's running ``PROBE_STATS``) the one probe is the
+    counted one, and what it did is added and handed back last."""
     if pre is not None:
         chunks = jax.vmap(pre)(stacked)
     else:
@@ -243,7 +255,12 @@ def _epoch_reduced_fn(
     sorted_keys, rep_valid, w, reduced, mret = agg_ops.reduce_by_key(
         keys, signs, calls, values, nulls
     )
-    table, slots, _, _ = lookup_or_insert(table, sorted_keys, rep_valid)
+    if probes is None:
+        table, slots, _, _ = lookup_or_insert(table, sorted_keys, rep_valid)
+    else:
+        table, slots, _, _, stats = lookup_or_insert_counted(
+            table, sorted_keys, rep_valid
+        )
     dropped = dropped | jnp.any(rep_valid & (slots < 0))
     state = agg_ops.apply_reduced(
         state, calls, slots, rep_valid, w, reduced, mret
@@ -270,6 +287,8 @@ def _epoch_reduced_fn(
                 touched, at, jnp.where(rep_valid, slots, -1)
             ),
         )
+    if probes is not None:
+        out += (probes + stats,)
     return out
 
 
@@ -289,11 +308,11 @@ _agg_epoch_reduced = partial(
 )
 def _agg_epoch_reduced_mi(
     table, state, dropped, stacked, calls, group_keys, nullable, pre,
-    minput, mi_bad, touched=None, at=None,
+    minput, mi_bad, touched=None, at=None, probes=None,
 ):
     return _epoch_reduced_fn(
         table, state, dropped, stacked, calls, group_keys, nullable, pre,
-        minput, mi_bad, touched, at,
+        minput, mi_bad, touched, at, probes,
     )
 
 
@@ -565,6 +584,15 @@ class HashAggExecutor(Executor, Checkpointable):
         # the rows of the largest group a step of this epoch met, on
         # the device (``_note_group_rows``); None = some step did not say
         self._group_rows = jnp.zeros((), jnp.int32)
+        # what the epoch-batched steps' probes did since the last flush
+        # (``PROBE_STATS``, summed on the device and read with the
+        # flush's first status) and how many such steps ran; what was
+        # read waits for ``_on_barrier_scalars``, which knows ``claimed``
+        self._probes = self._no_probes = jax.device_put(
+            np.zeros(len(PROBE_STATS), np.int32)
+        )
+        self._probe_calls = 0
+        self._probes_read = None
         # lanes a chunk holds after the traced-in prefix, per chunk shape
         self._lanes_after_pre: Dict[tuple, int] = {}
         # shape-stability: capacity walks the allocator's pow2 lattice;
@@ -830,6 +858,7 @@ class HashAggExecutor(Executor, Checkpointable):
             chunks=int(stacked.valid.shape[0]),
         ):
             self._step_stacked(stacked, pre, mode, at)
+            self._probe_calls += int(mode == "reduce")
             self._note_group_rows(stacked, pre, mode, at, lanes)
         return []
 
@@ -886,6 +915,7 @@ class HashAggExecutor(Executor, Checkpointable):
                 self.minput,
                 self.mi_bad,
                 self._touched,
+                self._probes,
             ) = _agg_epoch_reduced_mi(
                 self.table,
                 self.state,
@@ -899,6 +929,7 @@ class HashAggExecutor(Executor, Checkpointable):
                 self.mi_bad,
                 touched=self._touched,
                 at=at,
+                probes=self._probes,
             )
             return
         if mode != "reduce":
@@ -916,7 +947,7 @@ class HashAggExecutor(Executor, Checkpointable):
             )
             return
         (
-            self.table, self.state, self.dropped, self._touched
+            self.table, self.state, self.dropped, self._touched, self._probes
         ) = _agg_epoch_reduced(
             self.table,
             self.state,
@@ -928,6 +959,7 @@ class HashAggExecutor(Executor, Checkpointable):
             pre,
             touched=self._touched,
             at=at,
+            probes=self._probes,
         )
 
     def _survivor_count(self):
@@ -1037,6 +1069,12 @@ class HashAggExecutor(Executor, Checkpointable):
         # occupancy note), and feeds the lazy-shrink streak
         epoch_inc = max(self._insert_bound - self._occ_note, 0)
         self._occ_note = int(claimed)
+        if self._probes_read is not None:
+            (calls, did), self._probes_read = self._probes_read, None
+            note_probes(
+                "agg", self.table_id, calls, did, self.table.capacity,
+                claimed=int(claimed),
+            )
         self._insert_bound = int(claimed)
         self._plan_at_barrier(int(claimed), epoch_inc)
         if dropped:
@@ -1313,6 +1351,11 @@ class HashAggExecutor(Executor, Checkpointable):
         rows_max, self._group_rows = (
             self._group_rows, jnp.zeros((), jnp.int32)
         )
+        # and what the epoch's counted probes did, where any ran
+        calls, self._probe_calls = self._probe_calls, 0
+        probes = None
+        if calls:
+            probes, self._probes = self._probes, self._no_probes
         while True:
             with span(
                 "agg.flush",
@@ -1329,10 +1372,15 @@ class HashAggExecutor(Executor, Checkpointable):
                     self._float_extremes,
                     **listed,
                 )
-                with device_read("agg.flush.status", lanes=2):
-                    status, largest = jax.device_get(
-                        (delta["status"], rows_max)
+                with device_read(
+                    "agg.flush.status",
+                    lanes=2 + (0 if probes is None else probes.size),
+                ):
+                    status, largest, did = jax.device_get(
+                        (delta["status"], rows_max, probes)
                     )
+                if did is not None:
+                    self._probes_read, probes = (calls, did), None
                 n_take, overflow = status.tolist()
                 chunk = self._delta_to_chunk(delta, n_take)
                 sp.args.update(rows=2 * n_take, lanes=chunk.capacity)

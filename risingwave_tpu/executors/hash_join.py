@@ -148,52 +148,53 @@ def join_step_fn(
     o_cols, o_nulls = gather_matches(other, sl, other_names)
     mc = jnp.sum(match.astype(jnp.int32), axis=1)
 
-    n, fanout = match.shape
-    flatm = lambda a: a.reshape(n * fanout)
-    bcast = lambda a: jnp.broadcast_to(a[:, None], (n, fanout))
+    with jax.named_scope("join/bucket/emit"):
+        n, fanout = match.shape
+        flatm = lambda a: a.reshape(n * fanout)
+        bcast = lambda a: jnp.broadcast_to(a[:, None], (n, fanout))
 
-    groups = []  # (cols, nulls, ops, valid) of flat lanes
+        groups = []  # (cols, nulls, ops, valid) of flat lanes
 
-    if pairs_on:
-        g_cols = {name: flatm(bcast(chunk.col(name))) for name in own_names}
-        g_cols.update({name: flatm(o_cols[name]) for name in other_names})
-        g_nulls = {
-            name: flatm(bcast(lane))
-            for name, lane in chunk.nulls.items()
-            if name in own_names
-        }
-        g_nulls.update({name: flatm(lane) for name, lane in o_nulls.items()})
-        g_ops = flatm(
-            bcast(
-                jnp.where(
-                    signs > 0, jnp.int32(Op.INSERT), jnp.int32(Op.DELETE)
+        if pairs_on:
+            g_cols = {name: flatm(bcast(chunk.col(name))) for name in own_names}
+            g_cols.update({name: flatm(o_cols[name]) for name in other_names})
+            g_nulls = {
+                name: flatm(bcast(lane))
+                for name, lane in chunk.nulls.items()
+                if name in own_names
+            }
+            g_nulls.update({name: flatm(lane) for name, lane in o_nulls.items()})
+            g_ops = flatm(
+                bcast(
+                    jnp.where(
+                        signs > 0, jnp.int32(Op.INSERT), jnp.int32(Op.DELETE)
+                    )
                 )
             )
-        )
-        groups.append((g_cols, g_nulls, g_ops, flatm(match)))
+            groups.append((g_cols, g_nulls, g_ops, flatm(match)))
 
-    # group 2: judged by current match count, on arrival rows
-    if own_outer or (semi_anti and arrival == drive):
-        if own_outer:
-            cond = active & (mc == 0)
-        elif join_type.endswith("semi"):
-            cond = active & (mc > 0)
-        else:  # anti
-            cond = active & (mc == 0)
-        g_cols = {name: chunk.col(name) for name in own_names}
-        g_nulls = {
-            name: lane
-            for name, lane in chunk.nulls.items()
-            if name in own_names
-        }
-        if own_outer:  # NULL-pad the other side
-            for name in other_names:
-                g_cols[name] = jnp.zeros(n, other.rows[name].dtype)
-                g_nulls[name] = jnp.ones(n, jnp.bool_)
-        g_ops = jnp.where(
-            signs > 0, jnp.int32(Op.INSERT), jnp.int32(Op.DELETE)
-        )
-        groups.append((g_cols, g_nulls, g_ops, cond))
+        # group 2: judged by current match count, on arrival rows
+        if own_outer or (semi_anti and arrival == drive):
+            if own_outer:
+                cond = active & (mc == 0)
+            elif join_type.endswith("semi"):
+                cond = active & (mc > 0)
+            else:  # anti
+                cond = active & (mc == 0)
+            g_cols = {name: chunk.col(name) for name in own_names}
+            g_nulls = {
+                name: lane
+                for name, lane in chunk.nulls.items()
+                if name in own_names
+            }
+            if own_outer:  # NULL-pad the other side
+                for name in other_names:
+                    g_cols[name] = jnp.zeros(n, other.rows[name].dtype)
+                    g_nulls[name] = jnp.ones(n, jnp.bool_)
+            g_ops = jnp.where(
+                signs > 0, jnp.int32(Op.INSERT), jnp.int32(Op.DELETE)
+            )
+            groups.append((g_cols, g_nulls, g_ops, cond))
 
     # degree maintenance + group 3: zero-crossing transitions
     if need_degree:
@@ -203,51 +204,53 @@ def join_step_fn(
         emit_trans = other_outer or (semi_anti and arrival != drive)
         if emit_trans:
             t_cols, t_nulls = gather_flat(other, trans_pid, other_names)
-            g_cols = dict(t_cols)
-            g_nulls = dict(t_nulls)
-            if other_outer:  # NULL-pad the arrival side
-                for name in own_names:
-                    g_cols[name] = jnp.zeros(
-                        trans_pid.shape[0], chunk.col(name).dtype
+            with jax.named_scope("join/bucket/emit"):
+                g_cols = dict(t_cols)
+                g_nulls = dict(t_nulls)
+                if other_outer:  # NULL-pad the arrival side
+                    for name in own_names:
+                        g_cols[name] = jnp.zeros(
+                            trans_pid.shape[0], chunk.col(name).dtype
+                        )
+                        g_nulls[name] = jnp.ones(trans_pid.shape[0], jnp.bool_)
+                if other_outer or join_type.endswith("anti"):
+                    # matched for the first time -> retract pad/bare row;
+                    # unmatched again -> emit it
+                    g_ops = jnp.where(
+                        went_pos, jnp.int32(Op.DELETE), jnp.int32(Op.INSERT)
                     )
-                    g_nulls[name] = jnp.ones(trans_pid.shape[0], jnp.bool_)
-            if other_outer or join_type.endswith("anti"):
-                # matched for the first time -> retract pad/bare row;
-                # unmatched again -> emit it
-                g_ops = jnp.where(
-                    went_pos, jnp.int32(Op.DELETE), jnp.int32(Op.INSERT)
-                )
-            else:  # semi: matched -> emit; unmatched -> retract
-                g_ops = jnp.where(
-                    went_pos, jnp.int32(Op.INSERT), jnp.int32(Op.DELETE)
-                )
-            groups.append((g_cols, g_nulls, g_ops, went_pos | went_zero))
+                else:  # semi: matched -> emit; unmatched -> retract
+                    g_ops = jnp.where(
+                        went_pos, jnp.int32(Op.INSERT), jnp.int32(Op.DELETE)
+                    )
+                groups.append((g_cols, g_nulls, g_ops, went_pos | went_zero))
 
-    # concatenate groups into one flat emission (schema = out_names)
-    flat_cols: Dict[str, jnp.ndarray] = {}
-    flat_nulls: Dict[str, jnp.ndarray] = {}
-    col_dtype = {}
-    for g_cols, _, _, _ in groups:
-        for name, a in g_cols.items():
-            col_dtype.setdefault(name, a.dtype)
-    null_names = set()
-    for _, g_nulls, _, _ in groups:
-        null_names.update(g_nulls)
-    for name in out_names:
-        parts, nparts = [], []
-        for g_cols, g_nulls, _, _ in groups:
-            m = next(iter(g_cols.values())).shape[0]
-            if name in g_cols:
-                parts.append(g_cols[name])
-            else:
-                parts.append(jnp.zeros(m, col_dtype[name]))
-            if name in null_names:
-                nparts.append(g_nulls.get(name, jnp.zeros(m, jnp.bool_)))
-        flat_cols[name] = jnp.concatenate(parts)
-        if nparts:
-            flat_nulls[name] = jnp.concatenate(nparts)
-    flat_ops = jnp.concatenate([g[2] for g in groups])
-    flat_valid = jnp.concatenate([g[3] for g in groups])
+    with jax.named_scope("join/bucket/emit"):
+        # concatenate groups into one flat emission (schema = out_names)
+        flat_cols: Dict[str, jnp.ndarray] = {}
+        flat_nulls: Dict[str, jnp.ndarray] = {}
+        col_dtype = {}
+        for g_cols, _, _, _ in groups:
+            for name, a in g_cols.items():
+                col_dtype.setdefault(name, a.dtype)
+        null_names = set()
+        for _, g_nulls, _, _ in groups:
+            null_names.update(g_nulls)
+        for name in out_names:
+            parts, nparts = [], []
+            for g_cols, g_nulls, _, _ in groups:
+                m = next(iter(g_cols.values())).shape[0]
+                if name in g_cols:
+                    parts.append(g_cols[name])
+                else:
+                    parts.append(jnp.zeros(m, col_dtype[name]))
+                if name in null_names:
+                    nparts.append(g_nulls.get(name, jnp.zeros(m, jnp.bool_)))
+            flat_cols[name] = jnp.concatenate(parts)
+            if nparts:
+                flat_nulls[name] = jnp.concatenate(nparts)
+        flat_ops = jnp.concatenate([g[2] for g in groups])
+        flat_valid = jnp.concatenate([g[3] for g in groups])
 
     out_cols, out_nulls, out_ops, out_valid, em_overflow = compact_pairs(
         flat_cols, flat_nulls, flat_ops, flat_valid, out_cap

@@ -973,6 +973,7 @@ def step_widths(capacity: int) -> Tuple[int, ...]:
     static_argnames=("width", "lanes", "nullable"),
     donate_argnums=(0,),
 )
+@jax.named_scope("over/lay")
 def _general_over_lay(
     laid: Optional[StreamChunk],
     chunk: StreamChunk,
@@ -1073,306 +1074,310 @@ def _general_over_step(
     gathers them, ``_general_over_commit`` adopts them) and ``status``
     = [dropped latch, bad-delete latch, valid rows of the chunk, rows
     to retract, rows to insert, dirty partitions, rows they hold]."""
-    cap = present.shape[0]
-    n = chunk.capacity
-    total = cap + n  # sort domain: arena + ghost entries (one per row)
-    rows_active = chunk.valid
-    signs = chunk.effective_signs()
-    is_ins = signs > 0
-    is_del = rows_active & (signs < 0)
+    with jax.named_scope("over/arena"):
+        cap = present.shape[0]
+        n = chunk.capacity
+        total = cap + n  # sort domain: arena + ghost entries (one per row)
+        rows_active = chunk.valid
+        signs = chunk.effective_signs()
+        is_ins = signs > 0
+        is_del = rows_active & (signs < 0)
 
-    keys = tuple(chunk.col(k) for k in pk)
-    table, slots, found, _ = lookup_or_insert(table, keys, rows_active)
-    gslots = jnp.clip(slots, 0, cap - 1)
-    dropped = jnp.any(rows_active & (slots < 0))
-    pre_present = present[gslots]
-    dup = _chunk_dup(slots, rows_active)
-    # a DELETE must target a currently-present pk (or one produced
-    # earlier in this very chunk); anything else is upstream
-    # inconsistency (the reference's consistency check)
-    bad_delete = jnp.any(
-        is_del & ~dup & ~(slots < 0) & ~(found & pre_present)
-    )
-
-    # last occurrence per pk wins (within-chunk -old/+new updates);
-    # the table's live lane tracks the final presence so dead slots are
-    # reclaimed at the next rehash
-    writer = last_occurrence_mask(slots, rows_active)
-    table = set_live(table, jnp.where(writer, slots, -1), is_ins)
-
-    # ghost entries: a same-chunk partition-key move leaves the OLD
-    # partition with no touched member (the slot now sorts under its
-    # new partition), so its remaining rows would keep stale window
-    # values. Emit one non-live ghost per moved row under the OLD
-    # (emitted) partition keys purely to carry the dirty mark there.
-    moved = jnp.zeros(n, jnp.bool_)
-    for k in part_keys:
-        moved = moved | (
-            em[k][gslots] != chunk.col(k).astype(jnp.int64)
+        keys = tuple(chunk.col(k) for k in pk)
+        table, slots, found, _ = lookup_or_insert(table, keys, rows_active)
+        gslots = jnp.clip(slots, 0, cap - 1)
+        dropped = jnp.any(rows_active & (slots < 0))
+        pre_present = present[gslots]
+        dup = _chunk_dup(slots, rows_active)
+        # a DELETE must target a currently-present pk (or one produced
+        # earlier in this very chunk); anything else is upstream
+        # inconsistency (the reference's consistency check)
+        bad_delete = jnp.any(
+            is_del & ~dup & ~(slots < 0) & ~(found & pre_present)
         )
-    ghost = writer & is_ins & em_valid[gslots] & moved
 
-    target = jnp.where(writer, slots, cap)
-    present = present.at[target].set(is_ins, mode="drop")
-    for name in lane_names:
-        buf[name] = (
-            buf[name]
-            .at[target]
-            .set(chunk.col(name).astype(buf[name].dtype), mode="drop")
+        # last occurrence per pk wins (within-chunk -old/+new updates);
+        # the table's live lane tracks the final presence so dead slots are
+        # reclaimed at the next rehash
+        writer = last_occurrence_mask(slots, rows_active)
+        table = set_live(table, jnp.where(writer, slots, -1), is_ins)
+
+        # ghost entries: a same-chunk partition-key move leaves the OLD
+        # partition with no touched member (the slot now sorts under its
+        # new partition), so its remaining rows would keep stale window
+        # values. Emit one non-live ghost per moved row under the OLD
+        # (emitted) partition keys purely to carry the dirty mark there.
+        moved = jnp.zeros(n, jnp.bool_)
+        for k in part_keys:
+            moved = moved | (
+                em[k][gslots] != chunk.col(k).astype(jnp.int64)
+            )
+        ghost = writer & is_ins & em_valid[gslots] & moved
+
+        target = jnp.where(writer, slots, cap)
+        present = present.at[target].set(is_ins, mode="drop")
+        for name in lane_names:
+            buf[name] = (
+                buf[name]
+                .at[target]
+                .set(chunk.col(name).astype(buf[name].dtype), mode="drop")
+            )
+            if name in bnulls:
+                lane = chunk.nulls.get(name, jnp.zeros(n, jnp.bool_))
+                bnulls[name] = bnulls[name].at[target].set(lane, mode="drop")
+        touched = (
+            jnp.zeros(cap, jnp.bool_)
+            .at[jnp.where(rows_active, slots, cap)]
+            .set(True, mode="drop")
         )
-        if name in bnulls:
-            lane = chunk.nulls.get(name, jnp.zeros(n, jnp.bool_))
-            bnulls[name] = bnulls[name].at[target].set(lane, mode="drop")
-    touched = (
-        jnp.zeros(cap, jnp.bool_)
-        .at[jnp.where(rows_active, slots, cap)]
-        .set(True, mode="drop")
-    )
-    sdirty = sdirty | touched
+        sdirty = sdirty | touched
 
-    # ---- order the arena: members = rows needing compute or
-    # retraction, by (partition, live rows first, the order column, the
-    # stream key), every other lane behind them. The key's digits are
-    # packed into as many 32-bit words as they hold information (a
-    # lane that is no member reads a member's values, so that it
-    # widens no digit's range)
-    member = present | em_valid
-    member_e = jnp.concatenate([member, ghost])
-    present_e = jnp.concatenate([present, jnp.zeros(n, jnp.bool_)])
-    MINI = jnp.iinfo(jnp.int64).min
+    with jax.named_scope("over/sort"):
+        # ---- order the arena: members = rows needing compute or
+        # retraction, by (partition, live rows first, the order column, the
+        # stream key), every other lane behind them. The key's digits are
+        # packed into as many 32-bit words as they hold information (a
+        # lane that is no member reads a member's values, so that it
+        # widens no digit's range)
+        member = present | em_valid
+        member_e = jnp.concatenate([member, ghost])
+        present_e = jnp.concatenate([present, jnp.zeros(n, jnp.bool_)])
+        MINI = jnp.iinfo(jnp.int64).min
 
-    def keyed(own, emitted, ghosts):
-        """A key lane over the sort domain: a row's own value where it
-        is present, else the one it was handed on with; the ghosts'."""
-        lane = jnp.concatenate(
+        def keyed(own, emitted, ghosts):
+            """A key lane over the sort domain: a row's own value where it
+            is present, else the one it was handed on with; the ghosts'."""
+            lane = jnp.concatenate(
+                [
+                    jnp.where(present, own.astype(jnp.int64), emitted),
+                    ghosts.astype(jnp.int64),
+                ]
+            )
+            fill = jnp.max(jnp.where(member_e, lane, MINI))
+            return jnp.where(member_e, lane, fill)
+
+        plane_e = tuple(
+            keyed(buf[k], em[k], em[k][gslots]) for k in part_keys
+        )
+        order_e = keyed(buf[order_col], em[order_col], em[order_col][gslots])
+        digits: Tuple[jnp.ndarray, ...] = ()
+        for k, lane in reversed(tuple(zip(pk, table.keys))):
+            digits += _digits(keyed(lane, lane.astype(jnp.int64), chunk.col(k)))
+        digits += _digits(order_e)
+        digits += ((~present_e).astype(jnp.uint32),)  # live rows first
+        for lane in reversed(plane_e):
+            digits += _digits(lane)
+        digits += ((~member_e).astype(jnp.uint32),)
+        # (one two-operand sort a word that differs between lanes: what a
+        # sort of many operands costs the TPU's compiler is said there)
+        s_idx, _ = _order_by_words(jnp.stack(_packed_words(digits)[0]))
+        # where each slot stands in the order (the ghosts', past ``cap``,
+        # are not asked for)
+        inv = jax.lax.sort(
+            (s_idx, jnp.arange(total, dtype=jnp.int32)), num_keys=1
+        )[1][:cap]
+
+    with jax.named_scope("over/frame"):
+        def s(a, fill=0):
+            """Gather an arena lane into the sorted domain (ghost entries
+            read the fill value — they are never live)."""
+            return jnp.concatenate(
+                [a, jnp.full(n, fill, a.dtype)]
+            )[s_idx]
+
+        # one gather for every flag: member, live, touched, and each input
+        # column's NULL
+        null_cols = tuple(
+            dict.fromkeys(
+                c.input for c in calls
+                if c.input is not None and c.input in bnulls
+            )
+        )
+        flags_e = (
+            member_e.astype(jnp.int32)
+            | (present_e.astype(jnp.int32) << 1)
+            | (jnp.concatenate([touched, ghost]).astype(jnp.int32) << 2)
+        )
+        for i, name in enumerate(null_cols):
+            flags_e = flags_e | (
+                jnp.concatenate([bnulls[name], jnp.ones(n, jnp.bool_)])
+                .astype(jnp.int32) << (3 + i)
+            )
+        flags_s = flags_e[s_idx]
+        member_s = (flags_s & 1) > 0
+        live_s = (flags_s & 2) > 0
+        touched_s = (flags_s & 4) > 0
+        plane_s = [p[s_idx] for p in plane_e]
+
+        arange = jnp.arange(total, dtype=jnp.int32)
+        first = jnp.zeros(total, jnp.bool_).at[0].set(True)
+        boundary = first | jnp.concatenate(
+            [jnp.ones(1, jnp.bool_), member_s[1:] != member_s[:-1]]
+        )
+        for lane in plane_s:
+            boundary = boundary | jnp.concatenate(
+                [jnp.ones(1, jnp.bool_), lane[1:] != lane[:-1]]
+            )
+        gid = jnp.cumsum(boundary.astype(jnp.int32)) - 1
+        # where each lane's segment starts, and what lies there
+        seg_start = jax.lax.cummax(jnp.where(boundary, arange, 0))
+        in_seg = (arange - seg_start).astype(jnp.int64)
+        last = jnp.concatenate([boundary[1:], jnp.ones(1, jnp.bool_)])
+        dirty_s = _seg_any(seg_start, last, touched_s) & member_s
+
+        MAXI = jnp.iinfo(jnp.int64).max
+        zero_nulls = jnp.zeros(total, jnp.bool_)
+
+        def shifted(vals, nullm, d):
+            ok = (_shift(gid, d, -1) == gid) & _shift(live_s, d, False) & live_s
+            return (
+                jnp.where(ok, _shift(vals, d, 0), 0),
+                jnp.where(ok, _shift(nullm, d, True), True),
+            )
+
+        gathered: Dict[str, jnp.ndarray] = {}
+        out_sorted: Dict[str, jnp.ndarray] = {}
+        out_nulls_sorted: Dict[str, jnp.ndarray] = {}
+        for c in calls:
+            if c.input is not None:
+                if c.input not in gathered:
+                    gathered[c.input] = s(buf[c.input]).astype(jnp.int64)
+                v = gathered[c.input]
+                vnull = (
+                    (flags_s & (8 << null_cols.index(c.input))) > 0
+                    if c.input in null_cols
+                    else zero_nulls
+                )
+            if c.kind == "row_number":
+                o, onull = in_seg + 1, zero_nulls
+            elif c.kind in ("rank", "dense_rank"):
+                if order_col not in gathered:
+                    gathered[order_col] = order_e[s_idx]
+                v_order = gathered[order_col]
+                pv = jnp.concatenate(
+                    [jnp.zeros(1, v_order.dtype), v_order[:-1]]
+                )
+                vb = boundary | (v_order != pv)
+                if c.kind == "dense_rank":
+                    cum_vb = jnp.cumsum(vb.astype(jnp.int64))
+                    o = cum_vb - cum_vb[seg_start] + 1
+                else:
+                    # where the run of rows equal in the order began
+                    run_start = jax.lax.cummax(jnp.where(vb, arange, 0))
+                    o = (run_start - seg_start).astype(jnp.int64) + 1
+                onull = zero_nulls
+            elif c.kind in ("lead", "lag"):
+                d = c.offset if c.kind == "lead" else -c.offset
+                o, onull = shifted(v, vnull, d)
+            elif c.frame is not None:
+                lo, hi = c.frame
+                if c.kind == "count":
+                    v = jnp.ones(total, jnp.int64)
+                    vnull = zero_nulls if c.input is None else vnull
+                ident = (
+                    MAXI if c.kind == "min" else MINI if c.kind == "max" else 0
+                )
+                comb = (
+                    jnp.minimum
+                    if c.kind == "min"
+                    else jnp.maximum
+                    if c.kind == "max"
+                    else (lambda a, b: a + b)
+                )
+                acc = jnp.full(total, ident, jnp.int64)
+                any_real = zero_nulls
+                for d in range(lo, hi + 1):
+                    sv, sn = shifted(v, vnull, d)
+                    real = ~sn
+                    acc = comb(acc, jnp.where(real, sv, ident))
+                    any_real = any_real | real
+                if c.kind == "count":
+                    o, onull = acc, zero_nulls
+                else:
+                    o, onull = acc, ~any_real
+            else:
+                # running UNBOUNDED PRECEDING .. CURRENT ROW
+                if c.kind == "count":
+                    real = live_s if c.input is None else live_s & ~vnull
+                    vv = jnp.ones(total, jnp.int64)
+                else:
+                    real = live_s & ~vnull
+                    vv = v
+                if c.kind in ("sum", "count"):
+                    vv = jnp.where(real, vv, 0)
+                    csum = jnp.cumsum(vv)
+                    o = csum - (csum - vv)[seg_start]
+                    onull = zero_nulls
+                else:
+                    sent = MAXI if c.kind == "min" else MINI
+                    vv = jnp.where(real, vv, sent)
+
+                    def op(a, b):
+                        fa, va, ra = a
+                        fb, vb_, rb = b
+                        cmb = jnp.minimum if c.kind == "min" else jnp.maximum
+                        return (
+                            fa | fb,
+                            jnp.where(fb, vb_, cmb(va, vb_)),
+                            jnp.where(fb, rb, ra | rb),
+                        )
+
+                    _, o, has = jax.lax.associative_scan(
+                        op, (boundary, vv, real)
+                    )
+                    onull = ~has
+            out_sorted[c.output] = o
+            out_nulls_sorted[c.output] = onull
+
+    with jax.named_scope("over/diff"):
+        # ---- back to slots (a gather at each slot's place in the order;
+        # one for the flags); diff against the emitted lanes
+        out_flags = dirty_s.astype(jnp.int32)
+        for i, c in enumerate(calls):
+            out_flags = out_flags | (
+                out_nulls_sorted[c.output].astype(jnp.int32) << (1 + i)
+            )
+        out_flags = out_flags[inv]
+        dirty_slot = (out_flags & 1) > 0
+        new_out = {name: o[inv] for name, o in out_sorted.items()}
+        new_out_nulls = {
+            c.output: (out_flags & (2 << i)) > 0 for i, c in enumerate(calls)
+        }
+        both = present & em_valid
+        changed = jnp.zeros(cap, jnp.bool_)
+        for name in lane_names:
+            differs = buf[name].astype(jnp.int64) != em[name]
+            if name in bnulls:
+                # compare values only where both sides are non-NULL — the
+                # cell under a NULL flag is an arbitrary fill
+                cn, en = bnulls[name], emnulls[name]
+                differs = (~cn & ~en & differs) | (cn != en)
+            changed = changed | differs
+        for c in calls:
+            nn, en = new_out_nulls[c.output], emnulls[c.output]
+            changed = changed | (
+                jnp.where(~nn, new_out[c.output], 0)
+                != jnp.where(~en, em[c.output], 0)
+            ) | (nn != en)
+        changed = changed & both
+        retract = em_valid & dirty_slot & (~present | changed)
+        insert = present & dirty_slot & (~em_valid | changed)
+        sdirty = sdirty | retract | insert
+
+        def count(mask):
+            return jnp.sum(mask, dtype=jnp.int32)
+
+        status = jnp.stack(
             [
-                jnp.where(present, own.astype(jnp.int64), emitted),
-                ghosts.astype(jnp.int64),
+                dropped.astype(jnp.int32),
+                bad_delete.astype(jnp.int32),
+                count(rows_active),
+                count(retract),
+                count(insert),
+                count(boundary & dirty_s),
+                count(dirty_s & live_s),
             ]
         )
-        fill = jnp.max(jnp.where(member_e, lane, MINI))
-        return jnp.where(member_e, lane, fill)
-
-    plane_e = tuple(
-        keyed(buf[k], em[k], em[k][gslots]) for k in part_keys
-    )
-    order_e = keyed(buf[order_col], em[order_col], em[order_col][gslots])
-    digits: Tuple[jnp.ndarray, ...] = ()
-    for k, lane in reversed(tuple(zip(pk, table.keys))):
-        digits += _digits(keyed(lane, lane.astype(jnp.int64), chunk.col(k)))
-    digits += _digits(order_e)
-    digits += ((~present_e).astype(jnp.uint32),)  # live rows first
-    for lane in reversed(plane_e):
-        digits += _digits(lane)
-    digits += ((~member_e).astype(jnp.uint32),)
-    # (one two-operand sort a word that differs between lanes: what a
-    # sort of many operands costs the TPU's compiler is said there)
-    s_idx, _ = _order_by_words(jnp.stack(_packed_words(digits)[0]))
-    # where each slot stands in the order (the ghosts', past ``cap``,
-    # are not asked for)
-    inv = jax.lax.sort(
-        (s_idx, jnp.arange(total, dtype=jnp.int32)), num_keys=1
-    )[1][:cap]
-
-    def s(a, fill=0):
-        """Gather an arena lane into the sorted domain (ghost entries
-        read the fill value — they are never live)."""
-        return jnp.concatenate(
-            [a, jnp.full(n, fill, a.dtype)]
-        )[s_idx]
-
-    # one gather for every flag: member, live, touched, and each input
-    # column's NULL
-    null_cols = tuple(
-        dict.fromkeys(
-            c.input for c in calls
-            if c.input is not None and c.input in bnulls
-        )
-    )
-    flags_e = (
-        member_e.astype(jnp.int32)
-        | (present_e.astype(jnp.int32) << 1)
-        | (jnp.concatenate([touched, ghost]).astype(jnp.int32) << 2)
-    )
-    for i, name in enumerate(null_cols):
-        flags_e = flags_e | (
-            jnp.concatenate([bnulls[name], jnp.ones(n, jnp.bool_)])
-            .astype(jnp.int32) << (3 + i)
-        )
-    flags_s = flags_e[s_idx]
-    member_s = (flags_s & 1) > 0
-    live_s = (flags_s & 2) > 0
-    touched_s = (flags_s & 4) > 0
-    plane_s = [p[s_idx] for p in plane_e]
-
-    arange = jnp.arange(total, dtype=jnp.int32)
-    first = jnp.zeros(total, jnp.bool_).at[0].set(True)
-    boundary = first | jnp.concatenate(
-        [jnp.ones(1, jnp.bool_), member_s[1:] != member_s[:-1]]
-    )
-    for lane in plane_s:
-        boundary = boundary | jnp.concatenate(
-            [jnp.ones(1, jnp.bool_), lane[1:] != lane[:-1]]
-        )
-    gid = jnp.cumsum(boundary.astype(jnp.int32)) - 1
-    # where each lane's segment starts, and what lies there
-    seg_start = jax.lax.cummax(jnp.where(boundary, arange, 0))
-    in_seg = (arange - seg_start).astype(jnp.int64)
-    last = jnp.concatenate([boundary[1:], jnp.ones(1, jnp.bool_)])
-    dirty_s = _seg_any(seg_start, last, touched_s) & member_s
-
-    MAXI = jnp.iinfo(jnp.int64).max
-    zero_nulls = jnp.zeros(total, jnp.bool_)
-
-    def shifted(vals, nullm, d):
-        ok = (_shift(gid, d, -1) == gid) & _shift(live_s, d, False) & live_s
-        return (
-            jnp.where(ok, _shift(vals, d, 0), 0),
-            jnp.where(ok, _shift(nullm, d, True), True),
-        )
-
-    gathered: Dict[str, jnp.ndarray] = {}
-    out_sorted: Dict[str, jnp.ndarray] = {}
-    out_nulls_sorted: Dict[str, jnp.ndarray] = {}
-    for c in calls:
-        if c.input is not None:
-            if c.input not in gathered:
-                gathered[c.input] = s(buf[c.input]).astype(jnp.int64)
-            v = gathered[c.input]
-            vnull = (
-                (flags_s & (8 << null_cols.index(c.input))) > 0
-                if c.input in null_cols
-                else zero_nulls
-            )
-        if c.kind == "row_number":
-            o, onull = in_seg + 1, zero_nulls
-        elif c.kind in ("rank", "dense_rank"):
-            if order_col not in gathered:
-                gathered[order_col] = order_e[s_idx]
-            v_order = gathered[order_col]
-            pv = jnp.concatenate(
-                [jnp.zeros(1, v_order.dtype), v_order[:-1]]
-            )
-            vb = boundary | (v_order != pv)
-            if c.kind == "dense_rank":
-                cum_vb = jnp.cumsum(vb.astype(jnp.int64))
-                o = cum_vb - cum_vb[seg_start] + 1
-            else:
-                # where the run of rows equal in the order began
-                run_start = jax.lax.cummax(jnp.where(vb, arange, 0))
-                o = (run_start - seg_start).astype(jnp.int64) + 1
-            onull = zero_nulls
-        elif c.kind in ("lead", "lag"):
-            d = c.offset if c.kind == "lead" else -c.offset
-            o, onull = shifted(v, vnull, d)
-        elif c.frame is not None:
-            lo, hi = c.frame
-            if c.kind == "count":
-                v = jnp.ones(total, jnp.int64)
-                vnull = zero_nulls if c.input is None else vnull
-            ident = (
-                MAXI if c.kind == "min" else MINI if c.kind == "max" else 0
-            )
-            comb = (
-                jnp.minimum
-                if c.kind == "min"
-                else jnp.maximum
-                if c.kind == "max"
-                else (lambda a, b: a + b)
-            )
-            acc = jnp.full(total, ident, jnp.int64)
-            any_real = zero_nulls
-            for d in range(lo, hi + 1):
-                sv, sn = shifted(v, vnull, d)
-                real = ~sn
-                acc = comb(acc, jnp.where(real, sv, ident))
-                any_real = any_real | real
-            if c.kind == "count":
-                o, onull = acc, zero_nulls
-            else:
-                o, onull = acc, ~any_real
-        else:
-            # running UNBOUNDED PRECEDING .. CURRENT ROW
-            if c.kind == "count":
-                real = live_s if c.input is None else live_s & ~vnull
-                vv = jnp.ones(total, jnp.int64)
-            else:
-                real = live_s & ~vnull
-                vv = v
-            if c.kind in ("sum", "count"):
-                vv = jnp.where(real, vv, 0)
-                csum = jnp.cumsum(vv)
-                o = csum - (csum - vv)[seg_start]
-                onull = zero_nulls
-            else:
-                sent = MAXI if c.kind == "min" else MINI
-                vv = jnp.where(real, vv, sent)
-
-                def op(a, b):
-                    fa, va, ra = a
-                    fb, vb_, rb = b
-                    cmb = jnp.minimum if c.kind == "min" else jnp.maximum
-                    return (
-                        fa | fb,
-                        jnp.where(fb, vb_, cmb(va, vb_)),
-                        jnp.where(fb, rb, ra | rb),
-                    )
-
-                _, o, has = jax.lax.associative_scan(
-                    op, (boundary, vv, real)
-                )
-                onull = ~has
-        out_sorted[c.output] = o
-        out_nulls_sorted[c.output] = onull
-
-    # ---- back to slots (a gather at each slot's place in the order;
-    # one for the flags); diff against the emitted lanes
-    out_flags = dirty_s.astype(jnp.int32)
-    for i, c in enumerate(calls):
-        out_flags = out_flags | (
-            out_nulls_sorted[c.output].astype(jnp.int32) << (1 + i)
-        )
-    out_flags = out_flags[inv]
-    dirty_slot = (out_flags & 1) > 0
-    new_out = {name: o[inv] for name, o in out_sorted.items()}
-    new_out_nulls = {
-        c.output: (out_flags & (2 << i)) > 0 for i, c in enumerate(calls)
-    }
-    both = present & em_valid
-    changed = jnp.zeros(cap, jnp.bool_)
-    for name in lane_names:
-        differs = buf[name].astype(jnp.int64) != em[name]
-        if name in bnulls:
-            # compare values only where both sides are non-NULL — the
-            # cell under a NULL flag is an arbitrary fill
-            cn, en = bnulls[name], emnulls[name]
-            differs = (~cn & ~en & differs) | (cn != en)
-        changed = changed | differs
-    for c in calls:
-        nn, en = new_out_nulls[c.output], emnulls[c.output]
-        changed = changed | (
-            jnp.where(~nn, new_out[c.output], 0)
-            != jnp.where(~en, em[c.output], 0)
-        ) | (nn != en)
-    changed = changed & both
-    retract = em_valid & dirty_slot & (~present | changed)
-    insert = present & dirty_slot & (~em_valid | changed)
-    sdirty = sdirty | retract | insert
-
-    def count(mask):
-        return jnp.sum(mask, dtype=jnp.int32)
-
-    status = jnp.stack(
-        [
-            dropped.astype(jnp.int32),
-            bad_delete.astype(jnp.int32),
-            count(rows_active),
-            count(retract),
-            count(insert),
-            count(boundary & dirty_s),
-            count(dirty_s & live_s),
-        ]
-    )
     return (
         table, buf, bnulls, present, sdirty,
         new_out, new_out_nulls, retract, insert, status,
@@ -1380,6 +1385,7 @@ def _general_over_step(
 
 
 @partial(jax.jit, static_argnames=("lanes", "lane_names", "out_names"))
+@jax.named_scope("over/emit")
 def _general_over_emit(
     buf, bnulls, em, emnulls, new_out, new_out_nulls, retract, insert,
     start, lanes: int, lane_names, out_names,
@@ -1429,6 +1435,7 @@ def _general_over_emit(
     static_argnames=("lane_names",),
     donate_argnums=(0, 1, 2),
 )
+@jax.named_scope("over/commit")
 def _general_over_commit(
     em, emnulls, em_valid, buf, bnulls, new_out, new_out_nulls,
     retract, insert, lane_names,

@@ -26,8 +26,11 @@ from risingwave_tpu.array.chunk import StreamChunk
 from risingwave_tpu.executors.base import Barrier, Executor
 from risingwave_tpu.metrics import REGISTRY
 from risingwave_tpu.ops.hash_table import (
+    PROBE_STATS,
     HashTable,
     lookup_or_insert,
+    lookup_or_insert_counted,
+    note_probes,
     set_live,
 )
 from risingwave_tpu.array.lattice import (
@@ -374,7 +377,7 @@ class TopNExecutor(Executor, Checkpointable):
 )
 def _upsert_step_ed(
     table, rows, sdirty, epoch_dirty, chunk, pk, names,
-    groups=None, listed=None, at=None, n_group=0,
+    groups=None, listed=None, at=None, n_group=0, probes=None,
 ):
     """_upsert_step that also marks epoch_dirty (cleared per barrier)
     in the same scatter — one probe, two mark lanes. With ``groups``
@@ -383,30 +386,40 @@ def _upsert_step_ed(
     slot, and the slots the step wrote are appended to ``listed`` at
     lane ``at`` (``note_touched``): what a barrier's rank over the
     epoch's rows alone starts from. Returns ``groups`` and ``listed``
-    then too."""
+    then too. With ``probes`` (int32, a row of ``PROBE_STATS`` a table:
+    the row store, the groups) both probes are the counted one and what
+    they did is added to it and handed back last."""
     keys = tuple(chunk.col(k) for k in pk)
     signs = chunk.effective_signs()
     active = chunk.valid & (signs != 0)
-    table, slots, _, _ = lookup_or_insert(table, keys, active)
-    dropped = jnp.any(active & (slots < 0))
-    idx = jnp.where(active, slots, table.capacity)
-    rows = {
-        n: rows[n].at[idx].set(chunk.col(n), mode="drop") for n in names
-    }
-    table = set_live(table, jnp.where(active, slots, -1), signs > 0)
-    sdirty = sdirty.at[idx].set(True, mode="drop")
-    epoch_dirty = epoch_dirty.at[idx].set(True, mode="drop")
+    probe = lookup_or_insert if probes is None else lookup_or_insert_counted
+    with jax.named_scope("topn/rows"):
+        table, slots, _, _, *row_stats = probe(table, keys, active)
+        dropped = jnp.any(active & (slots < 0))
+        idx = jnp.where(active, slots, table.capacity)
+        rows = {
+            n: rows[n].at[idx].set(chunk.col(n), mode="drop") for n in names
+        }
+        table = set_live(table, jnp.where(active, slots, -1), signs > 0)
+    with jax.named_scope("topn/marks"):
+        sdirty = sdirty.at[idx].set(True, mode="drop")
+        epoch_dirty = epoch_dirty.at[idx].set(True, mode="drop")
     if groups is None:
         return table, rows, sdirty, epoch_dirty, dropped
-    gtable, gslots, _, _ = lookup_or_insert(
-        groups.table, keys[:n_group], active
-    )
-    dropped = dropped | jnp.any(active & (gslots < 0))
-    groups = _Groups(
-        gtable, groups.of_row.at[idx].set(gslots, mode="drop")
-    )
-    listed = note_touched(listed, at, jnp.where(active, slots, -1))
-    return table, rows, sdirty, epoch_dirty, dropped, groups, listed
+    with jax.named_scope("topn/groups"):
+        gtable, gslots, _, _, *group_stats = probe(
+            groups.table, keys[:n_group], active
+        )
+        dropped = dropped | jnp.any(active & (gslots < 0))
+        groups = _Groups(
+            gtable, groups.of_row.at[idx].set(gslots, mode="drop")
+        )
+    with jax.named_scope("topn/marks"):
+        listed = note_touched(listed, at, jnp.where(active, slots, -1))
+    out = (table, rows, sdirty, epoch_dirty, dropped, groups, listed)
+    if probes is not None:
+        out += (probes + jnp.stack(row_stats + group_stats),)
+    return out
 
 
 class _Groups(NamedTuple):
@@ -483,10 +496,11 @@ def _digits(lane) -> Tuple[jnp.ndarray, ...]:
             ^ jnp.uint32(1 << 31),
         )
     key = _order_key_u64(lane, False)
-    return (
-        (key & jnp.uint64(0xFFFFFFFF)).astype(jnp.uint32),
-        (key >> jnp.uint64(32)).astype(jnp.uint32),
-    )
+    with jax.named_scope("x64/split"):
+        return (
+            (key & jnp.uint64(0xFFFFFFFF)).astype(jnp.uint32),
+            (key >> jnp.uint64(32)).astype(jnp.uint32),
+        )
 
 
 def _packed_words(digits):
@@ -607,51 +621,54 @@ def _rank_sorted(
     took; and then every lane of ``carried`` (operands that ride along
     the sort beside the slot: a capacity-wide gather into the sorted
     order costs three sorts)."""
-    lanes = live.shape[0]
-    if slots is None:
-        slots = jnp.arange(lanes, dtype=jnp.int32)
-    # liveness as its own sort key within the group (a dead-row
-    # sentinel would collide with INT64-extreme order values)
-    live_last = (~live).astype(jnp.uint32)
-    okeys = tuple(
-        _order_key_u64(lane, d) for lane, d in zip(order_lanes, descs)
-    )
-    digits: Tuple[jnp.ndarray, ...] = ()
-    for lane in reversed(keys[n_group:]):
-        digits += _digits(lane)
-    # a later order key's digits lie below an earlier one's
-    for okey in reversed(okeys):
-        digits += _digits(okey)
-    digits += (live_last,)
-    n_below = len(digits)  # the digits below the group's
-    for lane in reversed(keys[:n_group]):
-        digits += _digits(lane)
-    if valid is not None:
-        # on top: the lanes that hold no candidate are one group, last
-        digits += ((~valid).astype(jnp.uint32),)
-    words, offsets = _packed_words(digits)
-    flags = flags | (live.astype(jnp.int32) << _LIVE_BIT)
-    sort = _sort_by_words if valid is None else _sort_by_words_gathered
-    words, (packed_s, *carried_s), passes = sort(
-        words, (slots | flags,) + tuple(carried)
-    )
-    # a group starts where a bit of the group's digits differs from the
-    # lane before: the bits from ``offsets[n_below]`` up
-    group_bit = offsets[n_below]
-    differs = jnp.zeros(lanes - 1, jnp.bool_)
-    for w, word in enumerate(words):
-        below = jnp.clip(group_bit - 32 * w, 0, 32).astype(jnp.uint32)
-        mask = jnp.where(
-            below >= 32,
-            jnp.uint32(0),
-            ~((jnp.uint32(1) << jnp.minimum(below, 31)) - jnp.uint32(1)),
+    with jax.named_scope("topn/rank/digits"):
+        lanes = live.shape[0]
+        if slots is None:
+            slots = jnp.arange(lanes, dtype=jnp.int32)
+        # liveness as its own sort key within the group (a dead-row
+        # sentinel would collide with INT64-extreme order values)
+        live_last = (~live).astype(jnp.uint32)
+        okeys = tuple(
+            _order_key_u64(lane, d) for lane, d in zip(order_lanes, descs)
         )
-        differs = differs | (((word[1:] ^ word[:-1]) & mask) != 0)
-    boundary = jnp.concatenate([jnp.ones(1, jnp.bool_), differs])
-    pos = jnp.arange(lanes, dtype=jnp.int32)
-    seg_start = jax.lax.cummax(jnp.where(boundary, pos, 0))
-    live_s = ((packed_s >> _LIVE_BIT) & 1) > 0
-    in_topk_s = live_s & ((pos - seg_start) < k)
+        digits: Tuple[jnp.ndarray, ...] = ()
+        for lane in reversed(keys[n_group:]):
+            digits += _digits(lane)
+        # a later order key's digits lie below an earlier one's
+        for okey in reversed(okeys):
+            digits += _digits(okey)
+        digits += (live_last,)
+        n_below = len(digits)  # the digits below the group's
+        for lane in reversed(keys[:n_group]):
+            digits += _digits(lane)
+        if valid is not None:
+            # on top: the lanes that hold no candidate are one group, last
+            digits += ((~valid).astype(jnp.uint32),)
+        words, offsets = _packed_words(digits)
+        flags = flags | (live.astype(jnp.int32) << _LIVE_BIT)
+    with jax.named_scope("topn/rank/sort"):
+        sort = _sort_by_words if valid is None else _sort_by_words_gathered
+        words, (packed_s, *carried_s), passes = sort(
+            words, (slots | flags,) + tuple(carried)
+        )
+    with jax.named_scope("topn/rank/segments"):
+        # a group starts where a bit of the group's digits differs from the
+        # lane before: the bits from ``offsets[n_below]`` up
+        group_bit = offsets[n_below]
+        differs = jnp.zeros(lanes - 1, jnp.bool_)
+        for w, word in enumerate(words):
+            below = jnp.clip(group_bit - 32 * w, 0, 32).astype(jnp.uint32)
+            mask = jnp.where(
+                below >= 32,
+                jnp.uint32(0),
+                ~((jnp.uint32(1) << jnp.minimum(below, 31)) - jnp.uint32(1)),
+            )
+            differs = differs | (((word[1:] ^ word[:-1]) & mask) != 0)
+        boundary = jnp.concatenate([jnp.ones(1, jnp.bool_), differs])
+        pos = jnp.arange(lanes, dtype=jnp.int32)
+        seg_start = jax.lax.cummax(jnp.where(boundary, pos, 0))
+        live_s = ((packed_s >> _LIVE_BIT) & 1) > 0
+        in_topk_s = live_s & ((pos - seg_start) < k)
     return (packed_s, in_topk_s, seg_start, passes) + tuple(carried_s)
 
 
@@ -833,15 +850,17 @@ def _rank(
         def take(lane, fill=0):
             return lane
     else:
-        at, whole, n_cand = _candidates(
-            listed, n_listed, groups, tops, k, cand_lanes
-        )
+        with jax.named_scope("topn/rank/candidates"):
+            at, whole, n_cand = _candidates(
+                listed, n_listed, groups, tops, k, cand_lanes
+            )
         valid = at >= 0
 
         def take(lane, fill=0):
             return _fetch(lane, at, fill)
 
-    live, handed, dirty = map(take, (table.live, emitted, epoch_dirty))
+    with jax.named_scope("topn/rank/gather"):
+        live, handed, dirty = map(take, (table.live, emitted, epoch_dirty))
 
     def rewritten():
         return dirty & _any_differs(
@@ -849,25 +868,28 @@ def _rank(
             {n: take(a) for n, a in shadow.items()},
         )
 
-    if cand_lanes is None:
-        redo = rewritten()
-    else:
-        # only a row that was handed on is asked whether it was
-        # rewritten: an epoch of new rows reads no column for it
-        redo = jax.lax.cond(
-            jnp.any(handed & dirty), rewritten, lambda: jnp.zeros_like(dirty)
-        )
+    with jax.named_scope("topn/rank/rewritten"):
+        if cand_lanes is None:
+            redo = rewritten()
+        else:
+            # only a row that was handed on is asked whether it was
+            # rewritten: an epoch of new rows reads no column for it
+            redo = jax.lax.cond(
+                jnp.any(handed & dirty), rewritten, lambda: jnp.zeros_like(dirty)
+            )
     flags = (
         (handed.astype(jnp.int32) << _EMITTED_BIT)
         | (dirty.astype(jnp.int32) << _DIRTY_BIT)
         | (redo.astype(jnp.int32) << _REDO_BIT)
     )
-    carried = () if erank is None else (take(erank),)
-    if groups is not None:
-        carried += (take(groups.of_row, -1),)
+    with jax.named_scope("topn/rank/gather"):
+        carried = () if erank is None else (take(erank),)
+        if groups is not None:
+            carried += (take(groups.of_row, -1),)
+        keys = tuple(take(lane) for lane in table.keys)
+        order_lanes = tuple(take(rows[c]) for c in order_cols)
     packed, in_topk, seg_start, passes, *carried = _rank_sorted(
-        tuple(take(lane) for lane in table.keys), live,
-        tuple(take(rows[c]) for c in order_cols), flags, k, descs, n_group,
+        keys, live, order_lanes, flags, k, descs, n_group,
         carried=carried,
         slots=None if at is None else jnp.where(valid, at, _SLOT_MASK),
         valid=valid,
@@ -1011,17 +1033,18 @@ def _diff_gather(
             table, rows, shadow, emitted, erank, ranked, dropped, start,
             rank_col, out_lanes, tops,
         )
-    cap = table.capacity
-    packed_s, in_topk_s, seg_start, passes = ranked[:4]
-    stay = _stay(ranked)
-    slot_s = packed_s & _SLOT_MASK
-    emitted_s = ((packed_s >> _EMITTED_BIT) & 1) > 0
-    dirty_s = ((packed_s >> _DIRTY_BIT) & 1) > 0
-    redo_s = ((packed_s >> _REDO_BIT) & 1) > 0
-    ret_s = emitted_s & (~in_topk_s | redo_s) & ~stay
-    ins_s = in_topk_s & (~emitted_s | redo_s) & ~stay
-    groups = _touched_groups(dirty_s, seg_start)
-    ret_set, ins_set = _count_set(ret_s), _count_set(ins_s)
+    with jax.named_scope("topn/diff/masks"):
+        cap = table.capacity
+        packed_s, in_topk_s, seg_start, passes = ranked[:4]
+        stay = _stay(ranked)
+        slot_s = packed_s & _SLOT_MASK
+        emitted_s = ((packed_s >> _EMITTED_BIT) & 1) > 0
+        dirty_s = ((packed_s >> _DIRTY_BIT) & 1) > 0
+        redo_s = ((packed_s >> _REDO_BIT) & 1) > 0
+        ret_s = emitted_s & (~in_topk_s | redo_s) & ~stay
+        ins_s = in_topk_s & (~emitted_s | redo_s) & ~stay
+        groups = _touched_groups(dirty_s, seg_start)
+        ret_set, ins_set = _count_set(ret_s), _count_set(ins_s)
 
     # every retraction reads ``shadow`` before an insertion renews it
     def retract(carry, at, pos, valid):
@@ -1046,27 +1069,30 @@ def _diff_gather(
         {n: jnp.zeros(out_lanes, a.dtype) for n, a in rows.items()},
         jnp.zeros(out_lanes, jnp.bool_),
     )
-    (emitted, ret_cols, ret_valid), ret_lanes = _in_turns(
-        ret_set, out_lanes, 0, retract, (emitted,) + empty
-    )
-    (emitted, shadow, ins_cols, ins_valid), ins_lanes = _in_turns(
-        ins_set, out_lanes, 0, insert, (emitted, shadow) + empty
-    )
-    n_ret, n_ins = ret_set.total, ins_set.total
-    status = jnp.stack(
-        [
-            n_ret,
-            n_ins,
-            groups,
-            ((n_ret > out_lanes) | (n_ins > out_lanes)).astype(jnp.int32),
-            dropped.astype(jnp.int32),
-            table.occupancy(),
-            table.num_live(),
-            passes,
-            ret_lanes + ins_lanes,
-            stay.astype(jnp.int32),
-        ]
-    )
+    with jax.named_scope("topn/diff/retract"):
+        (emitted, ret_cols, ret_valid), ret_lanes = _in_turns(
+            ret_set, out_lanes, 0, retract, (emitted,) + empty
+        )
+    with jax.named_scope("topn/diff/insert"):
+        (emitted, shadow, ins_cols, ins_valid), ins_lanes = _in_turns(
+            ins_set, out_lanes, 0, insert, (emitted, shadow) + empty
+        )
+    with jax.named_scope("topn/diff/status"):
+        n_ret, n_ins = ret_set.total, ins_set.total
+        status = jnp.stack(
+            [
+                n_ret,
+                n_ins,
+                groups,
+                ((n_ret > out_lanes) | (n_ins > out_lanes)).astype(jnp.int32),
+                dropped.astype(jnp.int32),
+                table.occupancy(),
+                table.num_live(),
+                passes,
+                ret_lanes + ins_lanes,
+                stay.astype(jnp.int32),
+            ]
+        )
     chunks = _delta_chunks(
         ret_cols, ret_valid, ins_cols, ins_valid, out_lanes
     )
@@ -1082,6 +1108,7 @@ def _stay(ranked: _Ranked):
     return ranked.full_rank
 
 
+@jax.named_scope("topn/diff/relink")
 def _relink(tops: Optional[_Tops], ranked: _Ranked, cap: int):
     """Every group of ``ranked`` (its lanes lie together, first-ranked
     first) has its chain rewritten: the head from the lane the group
@@ -1159,21 +1186,22 @@ def _diff_gather_numbered(
     rows moved for their rank alone, dropped latch, slots claimed, live
     rows, sorts made, lanes the gathers' turns covered, ``full_rank``].
     (Every round rewrites the chains, from the same ranking, the same.)"""
-    cap = table.capacity
-    packed_s, in_topk_s, seg_start, passes, erank_s = ranked[:5]
-    stay = _stay(ranked)
-    slot_s = packed_s & _SLOT_MASK
-    emitted_s = ((packed_s >> _EMITTED_BIT) & 1) > 0
-    dirty_s = ((packed_s >> _DIRTY_BIT) & 1) > 0
-    redo_s = ((packed_s >> _REDO_BIT) & 1) > 0
-    pos = jnp.arange(packed_s.shape[0], dtype=jnp.int32)
-    rank_s = jnp.where(in_topk_s, pos - seg_start + 1, 0)
-    moved_s = emitted_s & in_topk_s & ~redo_s & (erank_s != rank_s) & ~stay
-    again_s = redo_s | moved_s
-    ret_s = emitted_s & (~in_topk_s | again_s) & ~stay
-    ins_s = in_topk_s & (~emitted_s | again_s) & ~stay
-    groups = _touched_groups(dirty_s, seg_start)
-    ret_set, ins_set = _count_set(ret_s), _count_set(ins_s)
+    with jax.named_scope("topn/diff/masks"):
+        cap = table.capacity
+        packed_s, in_topk_s, seg_start, passes, erank_s = ranked[:5]
+        stay = _stay(ranked)
+        slot_s = packed_s & _SLOT_MASK
+        emitted_s = ((packed_s >> _EMITTED_BIT) & 1) > 0
+        dirty_s = ((packed_s >> _DIRTY_BIT) & 1) > 0
+        redo_s = ((packed_s >> _REDO_BIT) & 1) > 0
+        pos = jnp.arange(packed_s.shape[0], dtype=jnp.int32)
+        rank_s = jnp.where(in_topk_s, pos - seg_start + 1, 0)
+        moved_s = emitted_s & in_topk_s & ~redo_s & (erank_s != rank_s) & ~stay
+        again_s = redo_s | moved_s
+        ret_s = emitted_s & (~in_topk_s | again_s) & ~stay
+        ins_s = in_topk_s & (~emitted_s | again_s) & ~stay
+        groups = _touched_groups(dirty_s, seg_start)
+        ret_set, ins_set = _count_set(ret_s), _count_set(ins_s)
 
     def retract(carry, at, pos, valid):
         emitted, erank, shadow, cols, valids = carry
@@ -1217,26 +1245,29 @@ def _diff_gather_numbered(
         | {rank_col: jnp.zeros(out_lanes, jnp.int64)},
         jnp.zeros(out_lanes, jnp.bool_),
     )
-    (emitted, erank, shadow, ret_cols, ret_valid), ret_lanes = _in_turns(
-        ret_set, out_lanes, start, retract, (emitted, erank, shadow) + empty
-    )
-    (emitted, erank, shadow, ins_cols, ins_valid), ins_lanes = _in_turns(
-        ins_set, out_lanes, start, insert, (emitted, erank, shadow) + empty
-    )
-    status = jnp.stack(
-        [
-            ret_set.total,
-            ins_set.total,
-            groups,
-            jnp.sum(moved_s.astype(jnp.int32)),
-            dropped.astype(jnp.int32),
-            table.occupancy(),
-            table.num_live(),
-            passes,
-            ret_lanes + ins_lanes,
-            stay.astype(jnp.int32),
-        ]
-    )
+    with jax.named_scope("topn/diff/retract"):
+        (emitted, erank, shadow, ret_cols, ret_valid), ret_lanes = _in_turns(
+            ret_set, out_lanes, start, retract, (emitted, erank, shadow) + empty
+        )
+    with jax.named_scope("topn/diff/insert"):
+        (emitted, erank, shadow, ins_cols, ins_valid), ins_lanes = _in_turns(
+            ins_set, out_lanes, start, insert, (emitted, erank, shadow) + empty
+        )
+    with jax.named_scope("topn/diff/status"):
+        status = jnp.stack(
+            [
+                ret_set.total,
+                ins_set.total,
+                groups,
+                jnp.sum(moved_s.astype(jnp.int32)),
+                dropped.astype(jnp.int32),
+                table.occupancy(),
+                table.num_live(),
+                passes,
+                ret_lanes + ins_lanes,
+                stay.astype(jnp.int32),
+            ]
+        )
     chunks = _delta_chunks(
         ret_cols, ret_valid, ins_cols, ins_valid, out_lanes
     )
@@ -1429,6 +1460,13 @@ class RetractableGroupTopNExecutor(Executor, Checkpointable):
         self._epoch_lanes = 0
         # valid rows of those chunks, counted on the device
         self._in_rows = jnp.zeros((), jnp.int32)
+        # what the epoch's steps' two probes did (``PROBE_STATS`` a
+        # table: the row store, the groups), summed on the device and
+        # read with the barrier's status; the steps, counted here
+        self._probes = self._no_probes = jax.device_put(
+            np.zeros((2, len(PROBE_STATS)), np.int32)
+        )
+        self._probe_calls = 0
         # what ``topn.rank`` says of the program: the operands of a sort
         # (a word a digit of the store's key lanes, of the order keys —
         # 64 bits each — and of liveness, and the slot) and a row's bytes
@@ -1560,6 +1598,7 @@ class RetractableGroupTopNExecutor(Executor, Checkpointable):
         with span("actor.topn_step", table_id=self.table_id):
             # (a list given up: the write lands where nothing reads)
             self._step(chunk, at or 0)
+            self._probe_calls += 1
             self._in_rows = _count_valid(self._in_rows, chunk.valid)
         return []
 
@@ -1572,6 +1611,7 @@ class RetractableGroupTopNExecutor(Executor, Checkpointable):
             dropped,
             self.groups,
             self.listed,
+            self._probes,
         ) = _upsert_step_ed(
             self.table,
             self.rows,
@@ -1584,6 +1624,7 @@ class RetractableGroupTopNExecutor(Executor, Checkpointable):
             listed=self.listed,
             at=at,
             n_group=len(self.group_by),
+            probes=self._probes,
         )
         self._dropped = self._dropped | dropped
 
@@ -1780,11 +1821,14 @@ class RetractableGroupTopNExecutor(Executor, Checkpointable):
             self.epoch_dirty = jnp.zeros_like(dirty)
             self._epoch_lanes = self._listed_lanes = 0
             fed, self._in_rows = self._in_rows, jnp.zeros((), jnp.int32)
-        # ONE read for the counts, the latch, the occupancy and the
-        # epoch's input rows; it waits for the rank
+            # (one array of zeros for good: no program makes another)
+            probes, self._probes = self._probes, self._no_probes
+            calls, self._probe_calls = self._probe_calls, 0
+        # ONE read for the counts, the latch, the occupancy, the epoch's
+        # input rows and what its probes did; it waits for the rank
         with span("topn.pull", table_id=self.table_id) as sp:
-            with device_read("topn.status", lanes=11):
-                status, fed = jax.device_get((status, fed))
+            with device_read("topn.status", lanes=11 + probes.size):
+                status, fed, probes = jax.device_get((status, fed, probes))
             *counts, sorts, gathered, full_rank = status.tolist()
             touched_passes = 0 if cand is None else sorts
             if full_rank:
@@ -1841,6 +1885,11 @@ class RetractableGroupTopNExecutor(Executor, Checkpointable):
             self._bound = int(claimed)
             if self._buckets is not None:
                 self._buckets.note_barrier(cap, self._bound)
+            note_probes(
+                "topn.rows", self.table_id, calls, probes[0], cap,
+                claimed=claimed,
+            )
+            note_probes("topn.groups", self.table_id, calls, probes[1], cap)
             if dropped:
                 raise RuntimeError(
                     "GroupTopN row store overflowed; grow capacity"

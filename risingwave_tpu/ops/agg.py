@@ -254,6 +254,7 @@ def create_state(capacity: int, calls: Sequence[AggCall], input_dtypes) -> AggSt
     )
 
 
+@jax.named_scope("agg/apply")
 def apply(
     state: AggState,
     calls: Tuple[AggCall, ...],
@@ -364,103 +365,106 @@ def reduce_by_key(
     """
     from risingwave_tpu.ops.hashing import hash128
 
-    n = signs.shape[0]
-    h1, h2 = hash128(key_lanes)
-    vmask = signs != 0
-    # invisible rows sort to the end (max fingerprint) and never become
-    # segment representatives
-    h1s = jnp.where(vmask, h1, jnp.uint32(0xFFFFFFFF))
-    h2s = jnp.where(vmask, h2, jnp.uint32(0xFFFFFFFF))
+    with jax.named_scope("agg/reduce_by_key/sort"):
+        n = signs.shape[0]
+        h1, h2 = hash128(key_lanes)
+        vmask = signs != 0
+        # invisible rows sort to the end (max fingerprint) and never become
+        # segment representatives
+        h1s = jnp.where(vmask, h1, jnp.uint32(0xFFFFFFFF))
+        h2s = jnp.where(vmask, h2, jnp.uint32(0xFFFFFFFF))
 
-    val_names = tuple(sorted(values))
-    null_names = tuple(sorted(nulls))
-    operands = (
-        [h1s, h2s]
-        + list(key_lanes)
-        + [signs.astype(jnp.int32), vmask]
-        + [values[nm] for nm in val_names]
-        + [nulls[nm] for nm in null_names]
-    )
-    sorted_ops = jax.lax.sort(tuple(operands), num_keys=2)
-    h1s, h2s = sorted_ops[0], sorted_ops[1]
-    nk = len(key_lanes)
-    sorted_keys = tuple(sorted_ops[2 : 2 + nk])
-    s_sign = sorted_ops[2 + nk].astype(jnp.int64)
-    s_vmask = sorted_ops[3 + nk]
-    s_vals = {
-        nm: sorted_ops[4 + nk + i] for i, nm in enumerate(val_names)
-    }
-    s_nulls = {
-        nm: sorted_ops[4 + nk + len(val_names) + i]
-        for i, nm in enumerate(null_names)
-    }
+        val_names = tuple(sorted(values))
+        null_names = tuple(sorted(nulls))
+        operands = (
+            [h1s, h2s]
+            + list(key_lanes)
+            + [signs.astype(jnp.int32), vmask]
+            + [values[nm] for nm in val_names]
+            + [nulls[nm] for nm in null_names]
+        )
+        sorted_ops = jax.lax.sort(tuple(operands), num_keys=2)
+        h1s, h2s = sorted_ops[0], sorted_ops[1]
+        nk = len(key_lanes)
+        sorted_keys = tuple(sorted_ops[2 : 2 + nk])
+        s_sign = sorted_ops[2 + nk].astype(jnp.int64)
+        s_vmask = sorted_ops[3 + nk]
+        s_vals = {
+            nm: sorted_ops[4 + nk + i] for i, nm in enumerate(val_names)
+        }
+        s_nulls = {
+            nm: sorted_ops[4 + nk + len(val_names) + i]
+            for i, nm in enumerate(null_names)
+        }
 
     # segment boundary: first row, or ANY exact lane change (fingerprint
     # collisions between different keys split correctly because the raw
     # key lanes participate)
-    def lane_change(lane):
-        return jnp.concatenate(
-            [jnp.ones(1, jnp.bool_), lane[1:] != lane[:-1]]
-        )
-
-    boundary = lane_change(h1s) | lane_change(h2s) | lane_change(s_vmask)
-    for lane in sorted_keys:
-        ch = lane_change(lane)
-        if jnp.issubdtype(lane.dtype, jnp.floating):
-            both_nan = jnp.concatenate(
-                [
-                    jnp.zeros(1, jnp.bool_),
-                    jnp.isnan(lane[1:]) & jnp.isnan(lane[:-1]),
-                ]
+    with jax.named_scope("agg/reduce_by_key/combine"):
+        def lane_change(lane):
+            return jnp.concatenate(
+                [jnp.ones(1, jnp.bool_), lane[1:] != lane[:-1]]
             )
-            ch = ch & ~both_nan  # NaN == NaN for grouping (total order)
-        boundary = boundary | ch
-    rep_valid = boundary & s_vmask
-    seg_id = jnp.cumsum(boundary.astype(jnp.int32)) - 1
 
-    def segsum(x):
-        return jax.ops.segment_sum(x, seg_id, num_segments=n)[seg_id]
+        boundary = lane_change(h1s) | lane_change(h2s) | lane_change(s_vmask)
+        for lane in sorted_keys:
+            ch = lane_change(lane)
+            if jnp.issubdtype(lane.dtype, jnp.floating):
+                both_nan = jnp.concatenate(
+                    [
+                        jnp.zeros(1, jnp.bool_),
+                        jnp.isnan(lane[1:]) & jnp.isnan(lane[:-1]),
+                    ]
+                )
+                ch = ch & ~both_nan  # NaN == NaN for grouping (total order)
+            boundary = boundary | ch
+        rep_valid = boundary & s_vmask
+        seg_id = jnp.cumsum(boundary.astype(jnp.int32)) - 1
 
-    w = segsum(s_sign)
-    reduced: Dict[str, jnp.ndarray] = {}
-    minmax_ret = jnp.zeros((), jnp.bool_)
-    for c in calls:
-        if c.kind == "count_star":
-            continue  # uses w directly
-        v = s_vals[c.input]
-        notnull = ~s_nulls.get(c.input, jnp.zeros(v.shape, jnp.bool_))
-        wn = jnp.where(notnull, s_sign, 0)
-        if c.kind == "count":
-            reduced[f"cnt_{c.output}"] = segsum(wn)
-        elif c.kind == "sum":
-            acc_dt = _accum_dtype(c, v.dtype)
-            contrib = jnp.where(
-                notnull, v.astype(acc_dt) * s_sign.astype(acc_dt), 0
-            )
-            reduced[f"sum_{c.output}"] = segsum(contrib)
-            reduced[f"nn_{c.output}"] = segsum(wn)
-        elif c.materialized:
-            continue  # minput pass maintains these (ops/minput.py)
-        else:  # min / max (append-only)
-            use = s_vmask & notnull & (s_sign > 0)
-            if jnp.issubdtype(v.dtype, jnp.floating):
-                v = _float_to_order_key(v)
-            acc_dt = _accum_dtype(c, s_vals[c.input].dtype)
-            sentinel = accum_init(c.kind, acc_dt)
-            vv = jnp.where(use, v.astype(acc_dt), sentinel)
-            seg_red = (
-                jax.ops.segment_min
-                if c.kind == "min"
-                else jax.ops.segment_max
-            )(vv, seg_id, num_segments=n)
-            reduced[f"ext_{c.output}"] = seg_red[seg_id]
-            reduced[f"nnp_{c.output}"] = segsum(
-                jnp.where(use, jnp.int64(1), jnp.int64(0))
-            )
-            minmax_ret = minmax_ret | jnp.any(s_vmask & notnull & (s_sign < 0))
+        def segsum(x):
+            return jax.ops.segment_sum(x, seg_id, num_segments=n)[seg_id]
+
+        w = segsum(s_sign)
+        reduced: Dict[str, jnp.ndarray] = {}
+        minmax_ret = jnp.zeros((), jnp.bool_)
+        for c in calls:
+            if c.kind == "count_star":
+                continue  # uses w directly
+            v = s_vals[c.input]
+            notnull = ~s_nulls.get(c.input, jnp.zeros(v.shape, jnp.bool_))
+            wn = jnp.where(notnull, s_sign, 0)
+            if c.kind == "count":
+                reduced[f"cnt_{c.output}"] = segsum(wn)
+            elif c.kind == "sum":
+                acc_dt = _accum_dtype(c, v.dtype)
+                contrib = jnp.where(
+                    notnull, v.astype(acc_dt) * s_sign.astype(acc_dt), 0
+                )
+                reduced[f"sum_{c.output}"] = segsum(contrib)
+                reduced[f"nn_{c.output}"] = segsum(wn)
+            elif c.materialized:
+                continue  # minput pass maintains these (ops/minput.py)
+            else:  # min / max (append-only)
+                use = s_vmask & notnull & (s_sign > 0)
+                if jnp.issubdtype(v.dtype, jnp.floating):
+                    v = _float_to_order_key(v)
+                acc_dt = _accum_dtype(c, s_vals[c.input].dtype)
+                sentinel = accum_init(c.kind, acc_dt)
+                vv = jnp.where(use, v.astype(acc_dt), sentinel)
+                seg_red = (
+                    jax.ops.segment_min
+                    if c.kind == "min"
+                    else jax.ops.segment_max
+                )(vv, seg_id, num_segments=n)
+                reduced[f"ext_{c.output}"] = seg_red[seg_id]
+                reduced[f"nnp_{c.output}"] = segsum(
+                    jnp.where(use, jnp.int64(1), jnp.int64(0))
+                )
+                minmax_ret = minmax_ret | jnp.any(s_vmask & notnull & (s_sign < 0))
     return sorted_keys, rep_valid, w, reduced, minmax_ret
 
 
+@jax.named_scope("agg/apply")
 def apply_reduced(
     state: AggState,
     calls: Tuple[AggCall, ...],
@@ -675,79 +679,82 @@ def flush(
     caller holds that every dirty slot is on the list. Without it the
     table is sorted by its dirty bit, as many lanes as it has.
     """
-    cap = state.capacity
-    m = min(out_cap, cap)
-    if touched is None:
-        # compact dirty slot ids to the front: sort puts False (0) last
-        order = jnp.argsort(~state.dirty, stable=True)
-        n_dirty = jnp.sum(state.dirty.astype(jnp.int32))
-        slot_ids = order[:m]
-    else:
-        slot_ids, n_dirty = _dirty_head_listed(
-            state.dirty, touched, n_touched, walk, m
+    with jax.named_scope("agg/flush/select"):
+        cap = state.capacity
+        m = min(out_cap, cap)
+        if touched is None:
+            # compact dirty slot ids to the front: sort puts False (0) last
+            order = jnp.argsort(~state.dirty, stable=True)
+            n_dirty = jnp.sum(state.dirty.astype(jnp.int32))
+            slot_ids = order[:m]
+        else:
+            slot_ids, n_dirty = _dirty_head_listed(
+                state.dirty, touched, n_touched, walk, m
+            )
+        # the dirty slots come first either way (over the table this is
+        # dirty[order][:m], without gathering every lane to keep m of them)
+        take = jnp.arange(m) < n_dirty
+        slot_ids = jnp.where(take, slot_ids, 0)
+        overflow = n_dirty > out_cap
+
+    with jax.named_scope("agg/flush/gather"):
+        live = take & (state.row_count[slot_ids] > 0)
+        was = take & state.emitted_valid[slot_ids]
+
+        minus_valid = was  # emit old row as U- or D
+        plus_valid = live  # emit new row as U+ or I
+        minus_op = jnp.where(live, jnp.int32(Op.UPDATE_DELETE), jnp.int32(Op.DELETE))
+        plus_op = jnp.where(was, jnp.int32(Op.UPDATE_INSERT), jnp.int32(Op.INSERT))
+
+        def interleave(a, b):
+            return jnp.stack([a, b], axis=1).reshape(-1)
+
+        delta = {
+            "ops": interleave(minus_op, plus_op),
+            "valid": interleave(minus_valid, plus_valid),
+            "overflow": overflow,
+            # [n dirty slots taken, overflow] — ONE host read serves both
+            # the emit-size slice and the continue-flush check (each device
+            # read is a full round-trip on the TPU)
+            "status": jnp.stack(
+                [jnp.sum(take.astype(jnp.int32)), overflow.astype(jnp.int32)]
+            ),
+        }
+        for i, lane in enumerate(table_keys):
+            kv = lane[slot_ids]
+            delta[f"key{i}"] = interleave(kv, kv)
+        decode = dict(float_extremes)
+        for name, acc in state.accums.items():
+            old = state.emitted[name][slot_ids]
+            new = acc[slot_ids]
+            if name in decode:
+                old = _order_key_to_float(old, jnp.dtype(decode[name]))
+                new = _order_key_to_float(new, jnp.dtype(decode[name]))
+            delta[name] = interleave(old, new)
+        for name, nn in state.nonnull.items():
+            old_isnull = state.emitted_isnull[name][slot_ids]
+            new_isnull = nn[slot_ids] == 0
+            delta[name + "__isnull"] = interleave(old_isnull, new_isnull)
+
+    with jax.named_scope("agg/flush/snapshot"):
+        # snapshot what we just emitted (only for flushed slots)
+        fidx = jnp.where(take, slot_ids, cap)
+        emitted = {
+            name: state.emitted[name]
+            .at[fidx]
+            .set(state.accums[name][slot_ids], mode="drop")
+            for name in state.accums
+        }
+        emitted_isnull = {
+            name: state.emitted_isnull[name]
+            .at[fidx]
+            .set(state.nonnull[name][slot_ids] == 0, mode="drop")
+            for name in state.nonnull
+        }
+        emitted_valid = state.emitted_valid.at[fidx].set(
+            state.row_count[slot_ids] > 0, mode="drop"
         )
-    # the dirty slots come first either way (over the table this is
-    # dirty[order][:m], without gathering every lane to keep m of them)
-    take = jnp.arange(m) < n_dirty
-    slot_ids = jnp.where(take, slot_ids, 0)
-    overflow = n_dirty > out_cap
-
-    live = take & (state.row_count[slot_ids] > 0)
-    was = take & state.emitted_valid[slot_ids]
-
-    minus_valid = was  # emit old row as U- or D
-    plus_valid = live  # emit new row as U+ or I
-    minus_op = jnp.where(live, jnp.int32(Op.UPDATE_DELETE), jnp.int32(Op.DELETE))
-    plus_op = jnp.where(was, jnp.int32(Op.UPDATE_INSERT), jnp.int32(Op.INSERT))
-
-    def interleave(a, b):
-        return jnp.stack([a, b], axis=1).reshape(-1)
-
-    delta = {
-        "ops": interleave(minus_op, plus_op),
-        "valid": interleave(minus_valid, plus_valid),
-        "overflow": overflow,
-        # [n dirty slots taken, overflow] — ONE host read serves both
-        # the emit-size slice and the continue-flush check (each device
-        # read is a full round-trip on the TPU)
-        "status": jnp.stack(
-            [jnp.sum(take.astype(jnp.int32)), overflow.astype(jnp.int32)]
-        ),
-    }
-    for i, lane in enumerate(table_keys):
-        kv = lane[slot_ids]
-        delta[f"key{i}"] = interleave(kv, kv)
-    decode = dict(float_extremes)
-    for name, acc in state.accums.items():
-        old = state.emitted[name][slot_ids]
-        new = acc[slot_ids]
-        if name in decode:
-            old = _order_key_to_float(old, jnp.dtype(decode[name]))
-            new = _order_key_to_float(new, jnp.dtype(decode[name]))
-        delta[name] = interleave(old, new)
-    for name, nn in state.nonnull.items():
-        old_isnull = state.emitted_isnull[name][slot_ids]
-        new_isnull = nn[slot_ids] == 0
-        delta[name + "__isnull"] = interleave(old_isnull, new_isnull)
-
-    # snapshot what we just emitted (only for flushed slots)
-    fidx = jnp.where(take, slot_ids, cap)
-    emitted = {
-        name: state.emitted[name]
-        .at[fidx]
-        .set(state.accums[name][slot_ids], mode="drop")
-        for name in state.accums
-    }
-    emitted_isnull = {
-        name: state.emitted_isnull[name]
-        .at[fidx]
-        .set(state.nonnull[name][slot_ids] == 0, mode="drop")
-        for name in state.nonnull
-    }
-    emitted_valid = state.emitted_valid.at[fidx].set(
-        state.row_count[slot_ids] > 0, mode="drop"
-    )
-    dirty = state.dirty.at[fidx].set(False, mode="drop")
+        dirty = state.dirty.at[fidx].set(False, mode="drop")
 
     state = AggState(
         row_count=state.row_count,

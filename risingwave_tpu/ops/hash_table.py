@@ -43,7 +43,7 @@ import jax
 import jax.numpy as jnp
 
 from risingwave_tpu.ops.hashing import hash128
-from risingwave_tpu.trace import device_read
+from risingwave_tpu.trace import device_read, span
 
 EMPTY = jnp.uint32(0)  # slot status: fingerprint 0 reserved for "empty"
 TOMBSTONE_FLAG = 0x1  # bit in `status` lane
@@ -116,20 +116,10 @@ def _keys_match(table: HashTable, slot: jnp.ndarray, key_cols) -> jnp.ndarray:
     return ok
 
 
-@partial(jax.jit, static_argnames=("insert_missing",), donate_argnums=(0,))
-def lookup_or_insert(
-    table: HashTable,
-    key_cols: Tuple[jnp.ndarray, ...],
-    valid: jnp.ndarray,
-    insert_missing: bool = True,
-):
-    """Batched find-or-insert. Returns (table', slots, found, inserted).
-
-    slots[i] == -1 iff row i is invalid, or the key was absent and
-    ``insert_missing`` is False, or the table overflowed MAX_PROBE
-    (callers treat -1 slots of valid rows as an overflow signal and
-    trigger a host-side rehash; see state/state_table.py).
-    """
+def _probe_or_insert(table, key_cols, valid, insert_missing: bool):
+    """The one probe loop behind ``lookup_or_insert`` and its counted
+    twin: (table', slots, found, inserted, rounds), ``rounds`` the
+    loop's own counter when it stopped."""
     cap = table.capacity
     mask = jnp.uint32(cap - 1)
     h1, h2 = hash128(key_cols)
@@ -148,20 +138,23 @@ def lookup_or_insert(
     claim = jnp.full(cap, n, jnp.int32)
 
     def body(t, carry):
+        # (scopes inside the loop are relative to ``hash/probe``, which
+        # the loop's own name stack begins with: ``hash/probe/match``)
         table, slots, found, inserted, unresolved, claim = carry
         cand = ((h1 + jnp.uint32(t)) & mask).astype(jnp.int32)
 
-        slot_fp1 = table.fp1[cand]
-        slot_fp2 = table.fp2[cand]
-        is_empty = slot_fp1 == EMPTY
-        fp_match = (slot_fp1 == fp1) & (slot_fp2 == fp2)
-        exact = fp_match & _keys_match(table, cand, key_cols)
+        with jax.named_scope("match"):
+            slot_fp1 = table.fp1[cand]
+            slot_fp2 = table.fp2[cand]
+            is_empty = slot_fp1 == EMPTY
+            fp_match = (slot_fp1 == fp1) & (slot_fp2 == fp2)
+            exact = fp_match & _keys_match(table, cand, key_cols)
 
-        # 1) resolve matches (live or tombstoned — caller reads `live`)
-        hit = unresolved & exact
-        slots = jnp.where(hit, cand, slots)
-        found = found | (hit & table.live[cand])
-        unresolved = unresolved & ~hit
+            # 1) resolve matches (live or tombstoned — caller reads `live`)
+            hit = unresolved & exact
+            slots = jnp.where(hit, cand, slots)
+            found = found | (hit & table.live[cand])
+            unresolved = unresolved & ~hit
 
         if insert_missing:
             # 2) elect ONE winner per contended empty slot with a single
@@ -172,31 +165,34 @@ def lookup_or_insert(
             # Index lanes are EXPLICIT int32 (rwlint RW-E30x dtype
             # audit): weak python-int sentinels must never promote the
             # probe arithmetic under a different default-int regime.
-            want = unresolved & is_empty
-            idx = jnp.where(want, cand, jnp.int32(cap))  # cap = drop lane
-            row_ids = jnp.arange(n, dtype=jnp.int32)
-            claim = claim.at[idx].set(row_ids, mode="drop")
-            won = want & (claim[cand] == row_ids)
-            # wipe this round's entries so the scratch stays all-sentinel
-            claim = claim.at[idx].set(n, mode="drop")
-            widx = jnp.where(won, cand, jnp.int32(cap))
-            new_fp1 = table.fp1.at[widx].set(fp1, mode="drop")
-            new_fp2 = table.fp2.at[widx].set(fp2, mode="drop")
-            new_keys = tuple(
-                tk.at[widx].set(k, mode="drop")
-                for tk, k in zip(table.keys, key_cols)
-            )
-            table = HashTable(new_fp1, new_fp2, new_keys, table.live)
+            with jax.named_scope("elect"):
+                want = unresolved & is_empty
+                idx = jnp.where(want, cand, jnp.int32(cap))  # cap = drop lane
+                row_ids = jnp.arange(n, dtype=jnp.int32)
+                claim = claim.at[idx].set(row_ids, mode="drop")
+                won = want & (claim[cand] == row_ids)
+                # wipe this round's entries so the scratch stays all-sentinel
+                claim = claim.at[idx].set(n, mode="drop")
+            with jax.named_scope("write"):
+                widx = jnp.where(won, cand, jnp.int32(cap))
+                new_fp1 = table.fp1.at[widx].set(fp1, mode="drop")
+                new_fp2 = table.fp2.at[widx].set(fp2, mode="drop")
+                new_keys = tuple(
+                    tk.at[widx].set(k, mode="drop")
+                    for tk, k in zip(table.keys, key_cols)
+                )
+                table = HashTable(new_fp1, new_fp2, new_keys, table.live)
             # 3) same-key twins of the winner resolve to the slot too
-            landed = (
-                want
-                & (table.fp1[cand] == fp1)
-                & (table.fp2[cand] == fp2)
-                & _keys_match(table, cand, key_cols)
-            )
-            slots = jnp.where(landed, cand, slots)
-            inserted = inserted | landed
-            unresolved = unresolved & ~landed
+            with jax.named_scope("twins"):
+                landed = (
+                    want
+                    & (table.fp1[cand] == fp1)
+                    & (table.fp2[cand] == fp2)
+                    & _keys_match(table, cand, key_cols)
+                )
+                slots = jnp.where(landed, cand, slots)
+                inserted = inserted | landed
+                unresolved = unresolved & ~landed
             # NOTE: a winner and its same-key twins all get `inserted`;
             # dedup is by first-occurrence masks downstream, slot identity
             # is what matters for correctness.
@@ -221,12 +217,60 @@ def lookup_or_insert(
         )
         return (t + 1, table, slots, found, inserted, unresolved, claim)
 
-    _, table, slots, found, inserted, _, _ = jax.lax.while_loop(
-        cond,
-        wbody,
-        (jnp.int32(0), table, slots, found, inserted, unresolved, claim),
+    with jax.named_scope("hash/probe"):
+        rounds, table, slots, found, inserted, _, _ = jax.lax.while_loop(
+            cond,
+            wbody,
+            (jnp.int32(0), table, slots, found, inserted, unresolved, claim),
+        )
+    return table, slots, found, inserted, rounds
+
+
+@partial(jax.jit, static_argnames=("insert_missing",), donate_argnums=(0,))
+def lookup_or_insert(
+    table: HashTable,
+    key_cols: Tuple[jnp.ndarray, ...],
+    valid: jnp.ndarray,
+    insert_missing: bool = True,
+):
+    """Batched find-or-insert. Returns (table', slots, found, inserted).
+
+    slots[i] == -1 iff row i is invalid, or the key was absent and
+    ``insert_missing`` is False, or the table overflowed MAX_PROBE
+    (callers treat -1 slots of valid rows as an overflow signal and
+    trigger a host-side rehash; see state/state_table.py).
+    """
+    return _probe_or_insert(table, key_cols, valid, insert_missing)[:4]
+
+
+# what ``lookup_or_insert_counted`` says of one call, in this order
+PROBE_STATS = ("rounds", "lane_rounds", "keys", "new_keys")
+
+
+@partial(jax.jit, static_argnames=("insert_missing",), donate_argnums=(0,))
+def lookup_or_insert_counted(
+    table: HashTable,
+    key_cols: Tuple[jnp.ndarray, ...],
+    valid: jnp.ndarray,
+    insert_missing: bool = True,
+):
+    """``lookup_or_insert`` over the same loop, which also says what the
+    loop did: (table', slots, found, inserted, stats), ``stats`` an
+    int32 vector of ``PROBE_STATS`` — the rounds the loop ran (the
+    longest chain any row walked, + 1), rounds x the lanes every round
+    ranged over, the valid rows, and those that claimed a slot or were
+    a twin of one that did. An executor adds it to a vector it keeps on
+    the device and reads it with its barrier's own status."""
+    table, slots, found, inserted, rounds = _probe_or_insert(
+        table, key_cols, valid, insert_missing
     )
-    return table, slots, found, inserted
+    stats = jnp.stack([
+        rounds,
+        rounds * jnp.int32(valid.shape[0]),
+        jnp.sum(valid, dtype=jnp.int32),
+        jnp.sum(inserted, dtype=jnp.int32),
+    ])
+    return table, slots, found, inserted, stats
 
 
 @jax.jit
@@ -268,18 +312,36 @@ def lookup(table: HashTable, key_cols, valid):
         slots, found, unresolved = body(t, (slots, found, unresolved))
         return (t + 1, slots, found, unresolved)
 
-    _, slots, found, _ = jax.lax.while_loop(
-        cond, wbody, (jnp.int32(0), slots, found, valid)
-    )
+    with jax.named_scope("hash/lookup"):
+        _, slots, found, _ = jax.lax.while_loop(
+            cond, wbody, (jnp.int32(0), slots, found, valid)
+        )
     return slots, found
 
 
 def set_live(table: HashTable, slots: jnp.ndarray, live_value: jnp.ndarray) -> HashTable:
     """Mark slots live/dead (dead = logical delete, slot stays claimed)."""
     cap = table.capacity
-    idx = jnp.where(slots >= 0, slots, jnp.int32(cap))
-    new_live = table.live.at[idx].set(live_value, mode="drop")
+    with jax.named_scope("hash/set_live"):
+        idx = jnp.where(slots >= 0, slots, jnp.int32(cap))
+        new_live = table.live.at[idx].set(live_value, mode="drop")
     return HashTable(table.fp1, table.fp2, table.keys, new_live)
+
+
+def note_probes(table, table_id, calls, stats, capacity, **more) -> None:
+    """One record a barrier and counted table in the span ring: the span
+    ``hash.probes`` (``table`` = which of the executor's tables,
+    ``calls`` = the epoch's counted steps, ``stats`` = their
+    ``PROBE_STATS`` summed, as the barrier's own status read brought
+    them; ``capacity``, and ``claimed`` where the executor reads it).
+    Nothing where no counted step ran."""
+    if calls:
+        with span(
+            "hash.probes", table=table, table_id=table_id, calls=calls,
+            **dict(zip(PROBE_STATS, map(int, stats))), capacity=capacity,
+            **more,
+        ):
+            pass
 
 
 def stage_scalars(*xs):

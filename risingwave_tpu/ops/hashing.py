@@ -37,6 +37,7 @@ def _mix32(h: jnp.ndarray) -> jnp.ndarray:
     return h
 
 
+@jax.named_scope("x64/split")
 def _split64(col: jnp.ndarray) -> list[jnp.ndarray]:
     """64-bit column -> (lo, hi) uint32 lanes via ONE bitcast.
 

@@ -152,10 +152,11 @@ def _intra_chunk_rank(
     """
     n = slots.shape[0]
     big = jnp.int64(1) << 62
-    key = (
-        slots.astype(jnp.int64) << jnp.int64(32)
-        | h1.astype(jnp.int64)
-    )
+    with jax.named_scope("x64/combine"):
+        key = (
+            slots.astype(jnp.int64) << jnp.int64(32)
+            | h1.astype(jnp.int64)
+        )
     key = jnp.where(m, key, big)
     # lexsort by (h2, composite) — h2 breaks 32-bit h1 ties
     order = jnp.lexsort((h2.astype(jnp.int64), key))
@@ -212,6 +213,7 @@ def _entry_matches(side: JoinSide, slots, payload_cols, payload_nulls, names):
     return ok
 
 
+@jax.named_scope("join/bucket/apply_side")
 def apply_side(
     side: JoinSide,
     key_cols: Tuple[jnp.ndarray, ...],
@@ -336,6 +338,7 @@ def apply_side(
     )
 
 
+@jax.named_scope("join/bucket/degree")
 def degree_apply(
     other: JoinSide,
     match: jnp.ndarray,  # (n, fanout) live matches of this chunk's rows
@@ -391,6 +394,7 @@ def degree_apply(
     return other, trans_pid, went_pos, went_zero
 
 
+@jax.named_scope("join/bucket/emit")
 def gather_flat(
     side: JoinSide, pid: jnp.ndarray, names: Sequence[str]
 ) -> Tuple[Dict[str, jnp.ndarray], Dict[str, jnp.ndarray]]:
@@ -404,6 +408,7 @@ def gather_flat(
     return cols, nulls
 
 
+@jax.named_scope("join/bucket/probe")
 def probe_side(
     other: JoinSide,
     key_cols: Tuple[jnp.ndarray, ...],
@@ -417,6 +422,7 @@ def probe_side(
     return sl, match
 
 
+@jax.named_scope("join/bucket/probe")
 def gather_matches(
     other: JoinSide, sl: jnp.ndarray, names: Sequence[str]
 ) -> Tuple[Dict[str, jnp.ndarray], Dict[str, jnp.ndarray]]:
@@ -426,6 +432,7 @@ def gather_matches(
     return cols, nulls
 
 
+@jax.named_scope("join/bucket/emit")
 def compact_pairs(
     flat_cols: Dict[str, jnp.ndarray],
     flat_nulls: Dict[str, jnp.ndarray],
@@ -598,6 +605,7 @@ def _not_null(mask, nulls, names):
     return mask
 
 
+@jax.named_scope("join/keyed/upsert")
 def _flat_upsert(side: FlatSide, chunk, pk: Tuple[str, ...]):
     """The chunk's rows applied to their lanes in order: the last row
     of a stream key wins (an insert stores it, a delete clears it). A
@@ -685,25 +693,27 @@ def flat_many_step(
     columns, nulls, ops, valid, [pairs matched, pairs kept])."""
     from risingwave_tpu.types import mend_update_pairs
 
-    # a NULL key matches nothing
-    key_ok = _not_null(chunk.valid, chunk.nulls, [mc for mc, _ in key_pairs])
-    uslots, found = lookup(
-        unique.table, tuple(chunk.col(c) for c in unique_pk_from), key_ok
-    )
-    g = jnp.maximum(uslots, 0)
-    hit = found & key_ok
-    for mc, uc in key_pairs:
-        hit &= unique.rows[uc][g] == chunk.col(mc)
-        if uc in unique.row_nulls:
-            hit &= ~unique.row_nulls[uc][g]
-    cols = {n: chunk.col(n) for n in many.rows}
-    cols.update({n: a[g] for n, a in unique.rows.items()})
-    nulls = {n: chunk.nulls[n] for n in many.rows if n in chunk.nulls}
-    nulls.update({n: a[g] for n, a in unique.row_nulls.items()})
-    keep = _keep_pairs(cond, cols, nulls, hit)
-    # a U-/U+ pair of which one half is not emitted is a bare op
-    ops = mend_update_pairs(chunk.ops, keep)
-    out_cols, out_nulls, out_ops, out_valid = _compact(cols, nulls, ops, keep)
+    with jax.named_scope("join/keyed/probe"):
+        # a NULL key matches nothing
+        key_ok = _not_null(chunk.valid, chunk.nulls, [mc for mc, _ in key_pairs])
+        uslots, found = lookup(
+            unique.table, tuple(chunk.col(c) for c in unique_pk_from), key_ok
+        )
+        g = jnp.maximum(uslots, 0)
+        hit = found & key_ok
+        for mc, uc in key_pairs:
+            hit &= unique.rows[uc][g] == chunk.col(mc)
+            if uc in unique.row_nulls:
+                hit &= ~unique.row_nulls[uc][g]
+        cols = {n: chunk.col(n) for n in many.rows}
+        cols.update({n: a[g] for n, a in unique.rows.items()})
+        nulls = {n: chunk.nulls[n] for n in many.rows if n in chunk.nulls}
+        nulls.update({n: a[g] for n, a in unique.row_nulls.items()})
+        keep = _keep_pairs(cond, cols, nulls, hit)
+        # a U-/U+ pair of which one half is not emitted is a bare op
+        ops = mend_update_pairs(chunk.ops, keep)
+    with jax.named_scope("join/keyed/emit"):
+        out_cols, out_nulls, out_ops, out_valid = _compact(cols, nulls, ops, keep)
     many, _, _ = _flat_upsert(many, chunk, many_pk)
     counts = jnp.stack([jnp.sum(hit), jnp.sum(keep)]).astype(jnp.int64)
     return many, out_cols, out_nulls, out_ops, out_valid, counts
@@ -744,6 +754,7 @@ def flat_unique_upsert(unique: FlatSide, chunk, pk: Tuple[str, ...]):
     )
 
 
+@jax.named_scope("join/keyed/scan")
 def flat_scan(
     many: FlatSide,
     changed,
@@ -807,6 +818,7 @@ def flat_emit(many: FlatSide, d_src, i_src, old, new, out_cap: int):
     pairs."""
     from risingwave_tpu.types import Op
 
+    @jax.named_scope("join/keyed/pick")
     def pick(src, row):
         _, vals, vnulls = row
         at, count = _first_set(src >= 0, out_cap)
@@ -818,15 +830,16 @@ def flat_emit(many: FlatSide, d_src, i_src, old, new, out_cap: int):
 
     d_cols, d_nulls, d_valid, d_n = pick(d_src, old)
     i_cols, i_nulls, i_valid, i_n = pick(i_src, new)
-    cols = {n: jnp.concatenate([d_cols[n], i_cols[n]]) for n in d_cols}
-    nulls = {n: jnp.concatenate([d_nulls[n], i_nulls[n]]) for n in d_nulls}
-    ops = jnp.concatenate([
-        jnp.full(out_cap, Op.DELETE, jnp.int32),
-        jnp.full(out_cap, Op.INSERT, jnp.int32),
-    ])
-    cols, nulls, ops, valid = _compact(
-        cols, nulls, ops, jnp.concatenate([d_valid, i_valid])
-    )
+    with jax.named_scope("join/keyed/emit"):
+        cols = {n: jnp.concatenate([d_cols[n], i_cols[n]]) for n in d_cols}
+        nulls = {n: jnp.concatenate([d_nulls[n], i_nulls[n]]) for n in d_nulls}
+        ops = jnp.concatenate([
+            jnp.full(out_cap, Op.DELETE, jnp.int32),
+            jnp.full(out_cap, Op.INSERT, jnp.int32),
+        ])
+        cols, nulls, ops, valid = _compact(
+            cols, nulls, ops, jnp.concatenate([d_valid, i_valid])
+        )
     return cols, nulls, ops, valid, (d_n > out_cap) | (i_n > out_cap)
 
 

@@ -178,6 +178,7 @@ def _rows_equal(side: ChainSide, p, chunk, names):
     return ok
 
 
+@jax.named_scope("join/stream/fold")
 def chain_apply(side: ChainSide, chunk, key_cols, valid, signs, names, retract):
     """A chunk folded into its own side: the inserts appended and
     linked, then (``retract``) each delete's row found on its key's
@@ -227,46 +228,47 @@ def chain_apply(side: ChainSide, chunk, key_cols, valid, signs, names, retract):
 
     # ---- deletes: the rank-th stored row equal to the lane's ----------
     if retract:
-        del_ok = dele & ok
-        h1, h2 = _row_fingerprint(
-            {name: chunk.col(name) for name in names}, chunk.nulls, names
-        )
-        want = _intra_chunk_rank(slots, h1, h2, del_ok)
-
-        def walking(c):
-            return jnp.any(c[0] >= 0)
-
-        def walk(c):
-            ptr, seen, hit = c
-            p = jnp.maximum(ptr, 0)
-            eq = (ptr >= 0) & side.row_valid[p] & _rows_equal(
-                side, p, chunk, names
+        with jax.named_scope("retract"):
+            del_ok = dele & ok
+            h1, h2 = _row_fingerprint(
+                {name: chunk.col(name) for name in names}, chunk.nulls, names
             )
-            take = eq & (seen == want)
-            hit = jnp.where(take, p, hit)
-            seen = seen + eq.astype(jnp.int32)
-            ptr = jnp.where((ptr >= 0) & ~take, side.nxt[p], -1)
-            return ptr, seen, hit
+            want = _intra_chunk_rank(slots, h1, h2, del_ok)
 
-        _, _, hit = jax.lax.while_loop(
-            walking, walk,
-            (
-                jnp.where(del_ok, side.head[g], -1),
-                jnp.zeros(n, jnp.int32),
-                jnp.full(n, -1, jnp.int32),
-            ),
-        )
-        found = hit >= 0
-        didx = jnp.where(found, hit, row_cap)
-        side = replace(
-            side,
-            row_valid=side.row_valid.at[didx].set(False, mode="drop"),
-            rdirty=side.rdirty.at[didx].set(True, mode="drop"),
-            count=side.count.at[jnp.where(found, slots, key_cap)].add(
-                -1, mode="drop"
-            ),
-            inconsistent=side.inconsistent | jnp.any(del_ok & ~found),
-        )
+            def walking(c):
+                return jnp.any(c[0] >= 0)
+
+            def walk(c):
+                ptr, seen, hit = c
+                p = jnp.maximum(ptr, 0)
+                eq = (ptr >= 0) & side.row_valid[p] & _rows_equal(
+                    side, p, chunk, names
+                )
+                take = eq & (seen == want)
+                hit = jnp.where(take, p, hit)
+                seen = seen + eq.astype(jnp.int32)
+                ptr = jnp.where((ptr >= 0) & ~take, side.nxt[p], -1)
+                return ptr, seen, hit
+
+            _, _, hit = jax.lax.while_loop(
+                walking, walk,
+                (
+                    jnp.where(del_ok, side.head[g], -1),
+                    jnp.zeros(n, jnp.int32),
+                    jnp.full(n, -1, jnp.int32),
+                ),
+            )
+            found = hit >= 0
+            didx = jnp.where(found, hit, row_cap)
+            side = replace(
+                side,
+                row_valid=side.row_valid.at[didx].set(False, mode="drop"),
+                rdirty=side.rdirty.at[didx].set(True, mode="drop"),
+                count=side.count.at[jnp.where(found, slots, key_cap)].add(
+                    -1, mode="drop"
+                ),
+                inconsistent=side.inconsistent | jnp.any(del_ok & ~found),
+            )
 
     # a key is live while it holds a row (a probe stops at a dead key)
     table = set_live(
@@ -282,41 +284,43 @@ def chain_probe(other: ChainSide, key_cols, active, start, out_cap: int):
     pair lanes hold a pair, pairs of the chunk in all, chain steps
     walked). Pairs are numbered in lane order, a lane's newest match
     first."""
-    n = active.shape[0]
-    slots, found = lookup(other.table, key_cols, active)
-    g = jnp.maximum(slots, 0)
-    hit = found & active
-    mc = jnp.where(hit, other.count[g], 0)
-    off = _cumsum32(mc) - mc - start
-    total = jnp.sum(mc, dtype=jnp.int32)
-    lanes = jnp.arange(n, dtype=jnp.int32)
+    with jax.named_scope("join/stream/probe"):
+        n = active.shape[0]
+        slots, found = lookup(other.table, key_cols, active)
+        g = jnp.maximum(slots, 0)
+        hit = found & active
+        mc = jnp.where(hit, other.count[g], 0)
+        off = _cumsum32(mc) - mc - start
+        total = jnp.sum(mc, dtype=jnp.int32)
+        lanes = jnp.arange(n, dtype=jnp.int32)
 
-    def walking(c):
-        return jnp.any(c[0] >= 0)
+    with jax.named_scope("join/stream/chain"):
+        def walking(c):
+            return jnp.any(c[0] >= 0)
 
-    def walk(c):
-        ptr, j, src_lane, src_row, steps = c
-        p = jnp.maximum(ptr, 0)
-        live = (ptr >= 0) & other.row_valid[p]
-        at = off + j
-        idx = jnp.where(live & (at >= 0) & (at < out_cap), at, out_cap)
-        src_lane = src_lane.at[idx].set(lanes, mode="drop")
-        src_row = src_row.at[idx].set(p, mode="drop")
-        j = j + live.astype(jnp.int32)
-        # a lane stops once it has every valid row of its key
-        ptr = jnp.where((ptr >= 0) & (j < mc), other.nxt[p], -1)
-        return ptr, j, src_lane, src_row, steps + 1
+        def walk(c):
+            ptr, j, src_lane, src_row, steps = c
+            p = jnp.maximum(ptr, 0)
+            live = (ptr >= 0) & other.row_valid[p]
+            at = off + j
+            idx = jnp.where(live & (at >= 0) & (at < out_cap), at, out_cap)
+            src_lane = src_lane.at[idx].set(lanes, mode="drop")
+            src_row = src_row.at[idx].set(p, mode="drop")
+            j = j + live.astype(jnp.int32)
+            # a lane stops once it has every valid row of its key
+            ptr = jnp.where((ptr >= 0) & (j < mc), other.nxt[p], -1)
+            return ptr, j, src_lane, src_row, steps + 1
 
-    _, _, src_lane, src_row, steps = jax.lax.while_loop(
-        walking, walk,
-        (
-            jnp.where(mc > 0, other.head[g], -1),
-            jnp.zeros(n, jnp.int32),
-            jnp.zeros(out_cap, jnp.int32),
-            jnp.zeros(out_cap, jnp.int32),
-            jnp.zeros((), jnp.int32),
-        ),
-    )
+        _, _, src_lane, src_row, steps = jax.lax.while_loop(
+            walking, walk,
+            (
+                jnp.where(mc > 0, other.head[g], -1),
+                jnp.zeros(n, jnp.int32),
+                jnp.zeros(out_cap, jnp.int32),
+                jnp.zeros(out_cap, jnp.int32),
+                jnp.zeros((), jnp.int32),
+            ),
+        )
     pair = jnp.arange(out_cap, dtype=jnp.int32) < (total - start)
     return src_lane, src_row, pair, total, steps
 
@@ -355,40 +359,41 @@ def stream_join_step(
     src_lane, src_row, pair, total, steps = chain_probe(
         other, key_cols, active, start, out_cap
     )
-    cols = {name: chunk.col(name)[src_lane] for name in own_names}
-    cols.update({name: other.rows[name][src_row] for name in other_names})
-    nulls = {
-        name: lane[src_lane]
-        for name, lane in chunk.nulls.items()
-        if name in own_names
-    }
-    nulls.update({name: a[src_row] for name, a in other.row_nulls.items()})
-    keep = _keep_pairs(cond, cols, nulls, pair)
-    ops = jnp.where(
-        signs[src_lane] > 0, jnp.int32(Op.INSERT), jnp.int32(Op.DELETE)
-    )
-    kept = jnp.sum(keep, dtype=jnp.int32)
-    counts = counts + jnp.stack([
-        jnp.sum(pair), kept, steps * chunk.valid.shape[0]
-    ]).astype(jnp.int64)
+    with jax.named_scope("join/stream/emit"):
+        cols = {name: chunk.col(name)[src_lane] for name in own_names}
+        cols.update({name: other.rows[name][src_row] for name in other_names})
+        nulls = {
+            name: lane[src_lane]
+            for name, lane in chunk.nulls.items()
+            if name in own_names
+        }
+        nulls.update({name: a[src_row] for name, a in other.row_nulls.items()})
+        keep = _keep_pairs(cond, cols, nulls, pair)
+        ops = jnp.where(
+            signs[src_lane] > 0, jnp.int32(Op.INSERT), jnp.int32(Op.DELETE)
+        )
+        kept = jnp.sum(keep, dtype=jnp.int32)
+        counts = counts + jnp.stack([
+            jnp.sum(pair), kept, steps * chunk.valid.shape[0]
+        ]).astype(jnp.int64)
 
-    # the kept pairs, in order, behind what the buffer holds
-    room = buf.valid.shape[0]
-    at = cursor + _cumsum32(keep) - 1
-    idx = jnp.where(keep & (at < room), at, room)
-    no_null = jnp.zeros(out_cap, jnp.bool_)
-    buf = type(buf)(
-        columns={
-            name: a.at[idx].set(cols[name].astype(a.dtype), mode="drop")
-            for name, a in buf.columns.items()
-        },
-        valid=buf.valid.at[idx].set(True, mode="drop"),
-        nulls={
-            name: a.at[idx].set(nulls.get(name, no_null), mode="drop")
-            for name, a in buf.nulls.items()
-        },
-        ops=buf.ops.at[idx].set(ops, mode="drop"),
-    )
+        # the kept pairs, in order, behind what the buffer holds
+        room = buf.valid.shape[0]
+        at = cursor + _cumsum32(keep) - 1
+        idx = jnp.where(keep & (at < room), at, room)
+        no_null = jnp.zeros(out_cap, jnp.bool_)
+        buf = type(buf)(
+            columns={
+                name: a.at[idx].set(cols[name].astype(a.dtype), mode="drop")
+                for name, a in buf.columns.items()
+            },
+            valid=buf.valid.at[idx].set(True, mode="drop"),
+            nulls={
+                name: a.at[idx].set(nulls.get(name, no_null), mode="drop")
+                for name, a in buf.nulls.items()
+            },
+            ops=buf.ops.at[idx].set(ops, mode="drop"),
+        )
     if fold:
         own = chain_apply(
             own, chunk, key_cols, valid, signs, own_names, retract

@@ -61,6 +61,8 @@ from __future__ import annotations
 
 import itertools
 import json
+import os
+import re
 import threading
 import time
 import weakref
@@ -645,6 +647,331 @@ def barrier_path(epoch: int, spans=None):
         ),
         "actors": walk.actors,
     }
+
+
+# -- what the device's operations are --------------------------------------
+
+# The named scopes of the unfused kernels (``jax.named_scope``): a scope
+# is ``layer/step``, lower case, no shape and no table id in it. An
+# instruction's scope is a PATH of them, outermost first: the loop of
+# the row table's probe in the per-group Top-N is ``topn/rows/hash/
+# probe``, an operation of its body ``topn/rows/hash/probe/match`` (a
+# scope opened inside a loop's body is named relative to the loop's). A
+# kernel that opens a scope this table does not hold fails
+# tests/test_device_scopes.py; PERF.md 3 says what each one covers.
+SCOPES = (
+    "hash/probe", "hash/probe/match", "hash/probe/elect",
+    "hash/probe/write", "hash/probe/twins", "hash/lookup", "hash/set_live",
+    "agg/reduce_by_key/sort", "agg/reduce_by_key/combine", "agg/apply",
+    "agg/minput", "agg/flush/select", "agg/flush/gather",
+    "agg/flush/snapshot",
+    "topn/rows", "topn/groups", "topn/marks",
+    "topn/rank/candidates", "topn/rank/gather", "topn/rank/rewritten",
+    "topn/rank/digits", "topn/rank/sort", "topn/rank/segments",
+    "topn/diff/masks", "topn/diff/retract", "topn/diff/insert",
+    "topn/diff/status", "topn/diff/relink",
+    "join/stream/probe", "join/stream/chain", "join/stream/emit",
+    "join/stream/fold", "join/stream/fold/retract",
+    "join/keyed/probe", "join/keyed/emit", "join/keyed/upsert",
+    "join/keyed/scan", "join/keyed/pick",
+    "join/bucket/probe", "join/bucket/emit", "join/bucket/apply_side",
+    "join/bucket/degree",
+    "dedup/seen", "dedup/first",
+    "over/lay", "over/arena", "over/sort", "over/frame", "over/diff",
+    "over/emit", "over/commit",
+    "x64/split", "x64/combine",
+)
+
+# what jax itself puts on an operation's name stack around the scopes:
+# the program and the programs it calls, the transforms (``name(...)``),
+# and the parts of a loop or a branch
+_STACK_WORD = re.compile(
+    r"^(\w+\(.*\)|while|body|cond|branch_\d+_fun|closed_call|core_call)$"
+)
+_SCOPE_WORDS = frozenset(tuple(sc.split("/")) for sc in SCOPES)
+_SCOPE_PREFIXES = frozenset(
+    sc[:i] for sc in _SCOPE_WORDS for i in range(1, len(sc) + 1)
+)
+
+
+def scope_of(op_name: str) -> str:
+    """The named-scope path cut out of an instruction's ``op_name``
+    (``jit(_upsert_step_ed)/topn/rows/jit(lookup_or_insert)/hash/probe/
+    while/body/match/eq`` -> ``topn/rows/hash/probe/match``): the words
+    that read as scopes of ``SCOPES`` one after the other, once the
+    program names, the transforms and a loop's or a branch's own words
+    are taken away; "" where no scope was open. What closes the name —
+    the primitive, and whatever word jax's own lowerings put before it
+    (``jit(cumsum)/<the calling function>/reduce_window_sum``), or
+    nothing at all (a ``cummax``'s is its scope alone) — continues no
+    scope and falls away, so no rule about a name's last word is
+    needed; a scope the table lacks falls away with it, which is why
+    tests/test_device_scopes.py holds the sources to the table."""
+    done, cur = [], ()
+    # (instructions the compiler merged list every name, ``;`` between)
+    for w in op_name.partition(";")[0].split("/"):
+        if not w or _STACK_WORD.match(w):
+            continue
+        if cur + (w,) in _SCOPE_PREFIXES:
+            cur += (w,)
+        elif (not cur or cur in _SCOPE_WORDS) and (w,) in _SCOPE_PREFIXES:
+            done += cur
+            cur = (w,)
+    while cur and cur not in _SCOPE_WORDS:
+        cur = cur[:-1]
+    return "/".join(done + list(cur))
+
+
+def split_scopes(path: str):
+    """``path`` as the scopes of ``SCOPES`` it is made of, outermost
+    first (``topn/rows/hash/probe/match`` -> [``topn/rows``,
+    ``hash/probe/match``]); None where it is not made of them."""
+    if not path:
+        return []
+    for sc in sorted(SCOPES, key=len, reverse=True):
+        if path == sc or path.startswith(sc + "/"):
+            rest = split_scopes(path[len(sc) + 1:])
+            if rest is not None:
+                return [sc] + rest
+    return None
+
+
+_HLO_TABLE = re.compile(r"^(FileNames|FunctionNames|FileLocations|StackFrames)$")
+_HLO_COMPUTATION = re.compile(r"^(?:ENTRY\s+)?%?([\w.\-]+)\s*\(.*\{\s*$")
+_HLO_INSTRUCTION = re.compile(r"^\s+(?:ROOT\s+)?%?([\w.\-]+)\s*=\s*(.*)$")
+_HLO_CALLED = re.compile(
+    r"\b(?:calls|body|condition|to_apply|true_computation|"
+    r"false_computation)=%?([\w.\-]+)|\bbranch_computations=\{([^}]*)\}"
+)
+_HLO_FIELD = re.compile(r'(\w+)=(?:"((?:[^"\\]|\\.)*)"|(\d+))')
+
+
+def _hlo_fields(text: str) -> dict:
+    """``key="text" key=7 ...`` of a table's entry or a metadata block."""
+    return {
+        f.group(1): f.group(2) if f.group(3) is None else int(f.group(3))
+        for f in _HLO_FIELD.finditer(text)
+    }
+
+
+def _skip_shape(rest: str) -> str:
+    """``rest`` of an instruction line past its shape: a tuple's closing
+    parenthesis, else the first blank."""
+    if rest.startswith("("):
+        depth = 0
+        for i, ch in enumerate(rest):
+            depth += ch == "("
+            depth -= ch == ")"
+            if depth == 0:
+                return rest[i + 1:].lstrip()
+    return rest.partition(" ")[2]
+
+
+def parse_hlo(text: str) -> dict:
+    """One optimized HLO module's text -> {instruction: {"scope",
+    "source", "opcode", "within"[, "scope_from"]}} (``program_ops`` says
+    what they are). Every computation's instructions, the fused ones too: the
+    xplane names a fusion, and ``within`` leads from what it holds up to
+    it."""
+    tables: dict = {}
+    ops, holder, computation_of, operands = {}, {}, {}, {}
+    table = computation = None
+    for line in text.splitlines():
+        if not line.strip():
+            table = None
+            continue
+        if _HLO_TABLE.match(line):
+            table = tables.setdefault(line.strip(), {})
+            continue
+        if table is not None and line[0].isdigit():
+            key, _, rest = line.partition(" ")
+            quoted = rest.strip()
+            table[int(key)] = (
+                quoted[1:-1] if quoted.startswith('"')
+                else _hlo_fields(quoted)
+            )
+            continue
+        table = None
+        if line.startswith("}"):
+            computation = None
+            continue
+        m = _HLO_COMPUTATION.match(line)
+        if m and not line.startswith(" "):
+            computation = m.group(1)
+            continue
+        m = _HLO_INSTRUCTION.match(line)
+        if not m or computation is None:
+            continue
+        name, rest = m.group(1), _skip_shape(m.group(2))
+        opcode = re.match(r"[\w\-]*", rest).group(0)
+        # the operands: what the opcode's own parentheses hold
+        args = rest[len(opcode):]
+        operands[name] = re.findall(
+            r"%([\w.\-]+)", args[: len(args) - len(_skip_shape(args))]
+        )
+        at = rest.find("metadata={")
+        meta = _hlo_fields(rest[at + 10: rest.find("}", at)]) if at >= 0 else {}
+        ops[name] = {
+            "scope": scope_of(meta.get("op_name", "")),
+            "source": _hlo_source(meta, tables),
+            "opcode": opcode,
+        }
+        computation_of[name] = computation
+        for called in _HLO_CALLED.finditer(rest[: at if at >= 0 else None]):
+            for comp in (called.group(1) or called.group(2)).split(","):
+                holder[comp.strip().lstrip("%")] = name
+    for name, op in ops.items():
+        op["within"] = holder.get(computation_of[name])
+    _lend_scopes(ops, operands, computation_of)
+    return ops
+
+
+def _lend_scopes(ops, operands, computation_of) -> None:
+    """What the compiler made carries no name: the halves it splits a
+    64-bit lane into (``X64SplitLow``, ``X64Combine``), the copies and
+    slices it moves between memories. Such an instruction takes the
+    scope of what it was made FOR — the nearest instruction of its own
+    computation that reads it and has a scope, else the nearest it
+    reads — and says so (``scope_from``: ``user`` / ``operand``)."""
+    users: dict = {}
+    for name, reads in operands.items():
+        for r in reads:
+            if computation_of.get(r) == computation_of[name]:
+                users.setdefault(r, []).append(name)
+    for _ in range(3):  # (a copy's start, its done, the fusion it feeds)
+        for name, op in ops.items():
+            if op["scope"] or op["opcode"] in ("parameter", "constant"):
+                continue
+            for how, near in (
+                ("user", users.get(name, ())),
+                ("operand", operands[name]),
+            ):
+                lent = next(
+                    (ops[n]["scope"] for n in near
+                     if n in ops and ops[n]["scope"]), None,
+                )
+                if lent:
+                    op.update(scope=lent, scope_from=how)
+                    break
+
+
+def _hlo_source(meta: dict, tables: dict):
+    """``file.py:line`` of an instruction's metadata: its own
+    ``source_file`` / ``source_line``, or its stack frame's through the
+    module's tables."""
+    if "source_file" in meta:
+        return f"{os.path.basename(meta['source_file'])}:{meta.get('source_line', 0)}"
+    frame = tables.get("StackFrames", {}).get(meta.get("stack_frame_id"))
+    where = frame and tables.get("FileLocations", {}).get(
+        frame.get("file_location_id")
+    )
+    file = where and tables.get("FileNames", {}).get(where.get("file_name_id"))
+    return f"{os.path.basename(file)}:{where.get('line', 0)}" if file else None
+
+
+def program_ops(module: str | None = None) -> dict:
+    """What the device's operations are, by the names the xplane prints:
+    {XLA module (a jitted program: ``jit__upsert_step_ed``): [one entry
+    an executable of that name this process holds — a program compiled
+    at two push widths is two — {"shapes": the entry computation's
+    layout, "ops": {instruction: {"scope": the named-scope path cut out
+    of its ``op_name`` (``scope_of``), "source": ``file.py:line``,
+    "opcode", "within": the instruction (a while, a conditional, a
+    fusion, a call) whose computation holds it, None in the entry
+    computation; "scope_from" only where the compiler made the
+    instruction and its scope is lent by what reads it}}}]}, read from
+    the OPTIMIZED HLO of the loaded
+    executables themselves (``client.live_executables()``), so at the
+    shapes the session ran them with and with the compiler's own
+    numbering. Made when asked and only then: nothing is noted per
+    compile, per dispatch or per barrier, and no program is compiled
+    for it. ``module``: that one alone."""
+    out: dict = {}
+    seen = set()
+    for client in {d.client for d in jax.devices()}:
+        for exe in client.live_executables():
+            for mod in exe.hlo_modules():
+                if module is not None and mod.name != module:
+                    continue
+                text = mod.to_string()
+                if text in seen:
+                    continue
+                seen.add(text)
+                layout = re.search(
+                    r"entry_computation_layout=\{(.*?)\}(?:, \w+=|$)",
+                    text.partition("\n")[0],
+                )
+                out.setdefault(mod.name, []).append({
+                    "shapes": layout.group(1) if layout else "",
+                    "ops": parse_hlo(text),
+                })
+    return out
+
+
+def _shared_scope(scopes):
+    """The scope path every one of ``scopes`` begins with (cut back to
+    whole scopes of the table); None where they share none."""
+    words = []
+    for column in zip(*((sc or "").split("/") for sc in scopes)):
+        if len(set(column)) > 1:
+            break
+        words.append(column[0])
+    while words and split_scopes("/".join(words)) is None:
+        words.pop()
+    return "/".join(words) or None
+
+
+def name_ops(rows, programs: dict | None = None) -> list:
+    """The rows of a run's ``breakdown.device_ops`` (``[["module/
+    instruction", seconds], ...]``, as benchmarks/trace_reduce.py makes
+    them) each with what ``program_ops`` knows of it: {"op", "seconds",
+    "scope", "source", "opcode", "within", "nested"}. ``nested`` is true
+    where an instruction that holds it (``within``, and so on up) is
+    among the rows too, so that a sum over the rows that are not nested
+    counts no second twice. One module name can stand for several
+    executables, each numbered by the compiler on its own: an
+    instruction is looked up in all of them, and where they do not agree
+    the row says ``ambiguous`` true and lists the ``candidates`` (its
+    ``scope`` is then the leading scopes they all share — two bodies'
+    fusions of one loop are surely that loop's — or None); an instruction none
+    of them holds has ``scope`` None. Call it in the process that ran
+    the programs. THIS is the function a ``benchmark`` PR's
+    ``trace_reduce`` calls to print scopes in ``breakdown`` and to cut a
+    ``*.device_ms_per_barrier`` by scope (PERF.md 7)."""
+    if programs is None:
+        programs = program_ops()
+    listed = {}
+    for key, _ in rows:
+        mod, _, instruction = key.partition("/")
+        listed.setdefault(mod, set()).add(instruction)
+    out = []
+    for key, seconds in rows:
+        mod, _, instruction = key.partition("/")
+        found = []
+        for variant in programs.get(mod, ()):
+            op = variant["ops"].get(instruction)
+            if op is None:
+                continue
+            up, nested = op["within"], False
+            while up is not None and not nested:
+                nested = up in listed[mod]
+                up = variant["ops"].get(up, {}).get("within")
+            answer = dict(op, nested=nested)
+            if answer not in found:
+                found.append(answer)
+        row = {"op": key, "seconds": seconds}
+        if len(found) == 1:
+            row.update(found[0])
+        else:
+            row.update(
+                scope=_shared_scope([c["scope"] for c in found]),
+                source=None, opcode=None, within=None,
+                nested=any(c["nested"] for c in found),
+            )
+            if found:
+                row.update(ambiguous=True, candidates=found)
+        out.append(row)
+    return out
 
 
 _COMPILE_PREFIX = "/jax/core/compile/"

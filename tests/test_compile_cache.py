@@ -72,3 +72,26 @@ def test_default_cache_dir_is_fixed_under_the_checkout():
     assert not any(
         e.is_dir() for e in os.scandir(want)
     ), "the default cache directory must stay flat"
+
+
+def test_the_keys_take_the_metadata_in_and_not_the_checkouts_path():
+    """A scope written onto a program whose text stood has to compile
+    anew, or the loaded executable carries the names it was first
+    compiled with (``trace.program_ops`` reads them off it); and the
+    same files at another path, or first called from another place,
+    have to find the same entries. conftest called the function."""
+    import re
+
+    import jax
+
+    assert jax.config.jax_compilation_cache_include_metadata_in_key is True
+    assert jax.config.jax_traceback_in_locations_limit == 1
+    cut = re.compile(jax.config.jax_hlo_source_file_canonicalization_regex)
+    here = os.path.join(ROOT, "risingwave_tpu", "ops", "hash_table.py")
+    assert cut.sub("", here) == os.path.join(
+        "risingwave_tpu", "ops", "hash_table.py"
+    )
+    # (a name stack's scopes survive: the limit is on frames, where
+    # ``jax_include_full_tracebacks_in_locations`` off drops a called
+    # program's scopes from its operations' names)
+    assert jax.config.jax_include_full_tracebacks_in_locations is True
