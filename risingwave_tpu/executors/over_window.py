@@ -962,9 +962,9 @@ def step_widths(capacity: int) -> Tuple[int, ...]:
     """The widths a step is compiled at: twice each emission size. The
     epoch's chunks are laid end to end into the smallest that holds
     them (``_general_over_lay``), and the largest is the most the
-    executor holds before it steps: behind a Top-N, whose barrier hands
-    on a retract and an insert chunk of one of ITS emission sizes, the
-    two fill a width exactly."""
+    executor holds before it steps. A Top-N's barrier hands on a retract
+    and an insert chunk, each of the smallest of ITS sizes that holds
+    its rows: two of one size fill a width, else lanes are left over."""
     return tuple(2 * lanes for lanes in emission_sizes(capacity))
 
 

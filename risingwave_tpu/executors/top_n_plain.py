@@ -1305,9 +1305,12 @@ def _topk_ranks(table: HashTable, order_lanes, k: int, descs, n_group: int):
 
 
 def emission_lanes(epoch_lanes: int, capacity: int) -> int:
-    """Lanes of the two chunks a barrier hands on: the smallest of
-    ``_EMIT_FLOOR`` x 4^i that holds the lanes the epoch's chunks held
-    (what bounds either delta), and never more than the store."""
+    """Lanes of the two chunks a barrier's second program gathers its
+    deltas into: the smallest of ``_EMIT_FLOOR`` x 4^i that holds the
+    lanes the epoch's chunks held (what bounds either delta, and all
+    the host knows before the counts are read), and never more than the
+    store. What is handed on is cut to the rows the status then counts
+    (``RetractableGroupTopNExecutor._cut``)."""
     lanes = _EMIT_FLOOR
     while lanes < epoch_lanes:
         lanes *= 4
@@ -1348,14 +1351,15 @@ class RetractableGroupTopNExecutor(Executor, Checkpointable):
     on in ``shadow``), gathers the rows to retract and to insert into
     two chunks of a declared size and rewrites the chains, in two
     programs (``_rank``, ``_diff_gather``). The host reads ten counts
-    and the epoch's input rows a barrier, in one read, and walks no
-    row. Where the candidates cannot answer — a row of a group's top k
-    was deleted or rewritten and rows stand behind it in the store,
-    which the program sees and says in that read; or the chains are
-    cold (after ``restore_state`` or a re-slotted store), or the
-    epoch's lanes x (1 + k) are no fewer than the store's — the same
-    two programs rank all of the store's lanes, exactly, and rewrite
-    every chain from that.
+    and the epoch's input rows a barrier, in one read, walks no row,
+    and hands each chunk on at the smallest declared size that holds
+    the rows the read counted (``_cut``). Where the candidates cannot
+    answer — a row of a group's top k was deleted or rewritten and
+    rows stand behind it in the store, which the program sees and says
+    in that read; or the chains are cold (after ``restore_state`` or a
+    re-slotted store), or the epoch's lanes x (1 + k) are no fewer
+    than the store's — the same two programs rank all of the store's
+    lanes, exactly, and rewrite every chain from that.
 
     ``rank_col``: the name under which the row's rank in its group
     (ROW_NUMBER(): BIGINT, 1-based, ties by the stream key) is handed
@@ -1456,7 +1460,9 @@ class RetractableGroupTopNExecutor(Executor, Checkpointable):
         # ranks the store and rewrites them all
         self._cold = False
         # lanes of the chunks applied since the last barrier: what the
-        # chunks a barrier hands on are sized from (``emission_lanes``)
+        # barrier's programs are sized from (``emission_lanes``,
+        # ``candidate_lanes``) before its one read says what the delta
+        # holds
         self._epoch_lanes = 0
         # valid rows of those chunks, counted on the device
         self._in_rows = jnp.zeros((), jnp.int32)
@@ -1523,13 +1529,15 @@ class RetractableGroupTopNExecutor(Executor, Checkpointable):
             ),
             "state": (self.table, self.rows, self.groups),
             "donate": True,
-            # the barrier ranks, diffs and gathers on the device and
-            # hands on chunks of a declared size (emission_lanes: the
-            # sizes below are compiled when a graph-mode view is
-            # created, larger x4 steps when an epoch first needs one):
-            # two of them, or with the rank a column (``rank_lane``)
-            # two a round, as many rounds as the delta takes at that
-            # size; the row store walks the allocator's declared lattice
+            # the barrier ranks, diffs and gathers on the device into
+            # chunks of a declared size (emission_lanes: the sizes below
+            # are compiled when a graph-mode view is created, larger x4
+            # steps when an epoch first needs one) and hands each on at
+            # the smallest of the sizes below that holds its rows
+            # (``_cut``): two of them, or with the rank a column
+            # (``rank_lane``) two a round, as many rounds as the delta
+            # takes at the gathers' size; the row store walks the
+            # allocator's declared lattice
             "emission": "bucketed",
             "emission_caps": self.emission_sizes(),
             "rank_lane": self.rank_col,
@@ -1662,6 +1670,11 @@ class RetractableGroupTopNExecutor(Executor, Checkpointable):
             ranked = self._ranked(cand, self.epoch_dirty, 0)
             for out in sizes:
                 chunks[out] = self._round(ranked, 0, out)[0]
+        for out, chunk in chunks.items():
+            # the cut of a delta that a smaller size holds (``_cut``)
+            for size in chunks:
+                if size < out:
+                    chunk.leading(size)
         return [chunks[out] for out in sorted(chunks)]
 
     # one upsert step a chunk at the chunk's own width: takes the push
@@ -1791,6 +1804,20 @@ class RetractableGroupTopNExecutor(Executor, Checkpointable):
             (self.erank,) = erank
         return ret, ins, status
 
+    def _cut(self, chunk: StreamChunk, rows: int) -> StreamChunk:
+        """``chunk``, a delta's ``rows`` in its leading lanes
+        (``_diff_gather`` packs them so), at the smallest declared
+        emission size that holds them: what follows the Top-N pays for
+        a chunk's lanes, rows or not (the general over-window's step
+        probes, scatters and gathers every one: PERF.md 6, PR 50 and
+        52), and the gathers' size was fixed from the epoch's lanes
+        before the status counted the delta. The same rows, ops and
+        order; a delta that no smaller size holds goes on as it is."""
+        size = next(
+            (s for s in self.emission_sizes() if s >= rows), chunk.capacity
+        )
+        return chunk.leading(size) if size < chunk.capacity else chunk
+
     def on_barrier(self, barrier: Barrier) -> List[StreamChunk]:
         cap = self.table.capacity
         if not self._epoch_lanes:
@@ -1881,7 +1908,7 @@ class RetractableGroupTopNExecutor(Executor, Checkpointable):
             insert_rows=n_ins,
             rank_moved_rows=moved,
             rounds=rounds,
-        ):
+        ) as sp:
             self._bound = int(claimed)
             if self._buckets is not None:
                 self._buckets.note_barrier(cap, self._bound)
@@ -1934,12 +1961,27 @@ class RetractableGroupTopNExecutor(Executor, Checkpointable):
                 ret, ins, _ = self._round(ranked, r * lanes, lanes)
                 rets.append(ret)
                 inss.append(ins)
-            # retractions first: an UPDATE's old row leaves the view
-            # before its new one enters under the same key (so every
-            # round's retractions before any round's insertions)
-            return (
-                rets[: -(-n_ret // lanes)] + inss[: -(-n_ins // lanes)]
-            )
+            # each round's chunk that holds a row, at the size its rows
+            # take (a round before the last is full). Retractions first:
+            # an UPDATE's old row leaves the view before its new one
+            # enters under the same key (so every round's retractions
+            # before any round's insertions)
+            handed: List[StreamChunk] = []
+            emit_lanes = REGISTRY.counter("group_topn_emitted_lanes_total")
+            for op, n, chunks in (
+                ("retract", n_ret, rets), ("insert", n_ins, inss)
+            ):
+                cut = [
+                    self._cut(c, min(n - r * lanes, lanes))
+                    for r, c in enumerate(chunks[: -(-n // lanes)])
+                ]
+                emit_lanes.inc(
+                    sum(c.capacity for c in cut),
+                    table_id=self.table_id, op=op,
+                )
+                handed += cut
+            sp.args.update(emit_lanes=sum(c.capacity for c in handed))
+            return handed
 
     def on_watermark(self, watermark):
         """Window-bounded groups expire silently below the watermark

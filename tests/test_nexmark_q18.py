@@ -18,6 +18,7 @@ import pytest
 
 from risingwave_tpu.array.chunk import StreamChunk
 from risingwave_tpu.executors.over_window import GeneralOverWindowExecutor
+from risingwave_tpu.executors import top_n_plain
 from risingwave_tpu.executors.row_id_gen import RowIdGenExecutor
 from risingwave_tpu.executors.top_n_plain import (
     RetractableGroupTopNExecutor,
@@ -404,6 +405,72 @@ def test_emission_sizes_are_declared_and_warmed_when_the_view_is_created(
         served.close()
 
 
+# programs that take the width of a chunk the Top-N hands on
+_WIDTH_PROGRAMS = (
+    "_leading_lanes", "_diff_gather", "_rank", "_project_step",
+    "_add_edge_rows", "dynamic_slice",
+)
+
+
+@pytest.mark.parametrize(
+    "first_met,floor", [("a_small_delta", 16), ("a_large_delta", 32)]
+)
+def test_no_barrier_of_either_size_compiles_once_the_view_is_created(
+    tmp_path, monkeypatch, first_met, floor
+):
+    """Emission sizes from 16 lanes up (x4), the Top-N declaring 16 /
+    1,024 / 4,096: an epoch of two 256-lane chunks gathers into 1,024
+    lanes. A delta of up to 16 rows goes on in 16 lanes, a larger one
+    in the 1,024; whichever kind the stream meets SECOND finds the cut,
+    the projection behind it and the view's edge compiled by the
+    warm-up pass, and the view stays the reference's. (From 32 lanes up
+    in the second case, 32 / 512 / 2,048: the process keeps compiled
+    programs, and a size the first case built would prove nothing.)"""
+    monkeypatch.setattr(top_n_plain, "_EMIT_FLOOR", floor)
+    monkeypatch.setattr(
+        RetractableGroupTopNExecutor, "_WARM_EPOCHS", (1, 512, 2048)
+    )
+    bids = _bids(21, 1200)
+    served = Served(tmp_path, 256, "graph")
+    try:
+        ex = served.topn()
+        gathered = emission_lanes(2 * 256, ex.table.capacity)
+        assert ex.emission_sizes() == (floor, gathered, 4 * gathered)
+        assert gathered == {16: 1024, 32: 512}[floor]
+        small, large = (5, 5), (256, 200)
+        plan = [large, large, small] if first_met == "a_small_delta" else [
+            small, small, large
+        ]
+        pos = 0
+        for epoch, rows in enumerate(plan):
+            TRACER.clear()
+            for n in rows:
+                served.push(bids, pos, pos + n)
+                pos += n
+            served.rt.barrier()
+            spans = TRACER.spans()
+            (rank,) = [sp for sp in spans if sp.name == "topn.rank"]
+            (diff,) = [sp for sp in spans if sp.name == "topn.diff"]
+            deltas = [diff.args["retract_rows"], diff.args["insert_rows"]]
+            assert rank.args["lanes"] == gathered
+            assert (max(deltas) <= floor) == (rows == small)
+            assert diff.args["emit_lanes"] == sum(
+                floor if n <= floor else gathered for n in deltas if n
+            )
+            if epoch == 2:
+                built = [
+                    sp.args.get("fun_name", "") for sp in spans
+                    if sp.name == "compile"
+                    and sp.args.get("event") == "backend_compile_duration"
+                ]
+                assert not [
+                    f for f in built if any(p in f for p in _WIDTH_PROGRAMS)
+                ], built
+            assert served.read() == last_k(bids, pos, 1)
+    finally:
+        served.close()
+
+
 def test_one_barrier_leaves_the_topn_spans_and_counters(tmp_path):
     served = Served(tmp_path, 256, "graph")
     try:
@@ -437,6 +504,8 @@ def test_one_barrier_leaves_the_topn_spans_and_counters(tmp_path):
         assert rank.args["capacity"] == ex.table.capacity
         assert rank.args["lanes"] == emission_lanes(256, ex.table.capacity)
         assert diff.stage == "topn_diff"
+        # (a store under the floor has one size: nothing to cut to)
+        assert diff.args["emit_lanes"] == 2 * rank.args["lanes"]
         (step,) = [sp for sp in spans if sp.name == "actor.topn_step"]
         assert step.args["table_id"] == tid
         # the counts agree with the view's change and with the actor's

@@ -29,6 +29,7 @@ from risingwave_tpu.executors.top_n_plain import (
     RetractableGroupTopNExecutor,
     _diff_gather,
     _rank,
+    emission_lanes,
 )
 from risingwave_tpu.frontend import SqlSession
 from risingwave_tpu.metrics import REGISTRY
@@ -453,6 +454,8 @@ def test_a_delta_larger_than_the_epochs_lanes_goes_out_in_rounds(
         assert diff.args["retract_rows"] == diff.args["insert_rows"] == 640
         assert diff.args["rank_moved_rows"] == 64 * 9
         assert diff.args["rounds"] == 10
+        # twenty full rounds' chunks, none of which a smaller size holds
+        assert diff.args["emit_lanes"] == 20 * 64
         applied = [sp.args["rows"] for sp in _spans("mv.apply")
                    if sp.args["table_id"] == "q19.mview"]
         assert applied == [64] * 20  # every round's U- before any U+
@@ -504,28 +507,38 @@ def test_the_pull_says_the_lanes_the_gathers_covered(tmp_path, monkeypatch):
         _diff_gather.clear_cache()
 
 
-def test_rounds_at_the_declared_floor_lose_no_row():
+@pytest.mark.parametrize("chunk_lanes", [2048, 32768])
+def test_rounds_at_the_declared_floor_lose_no_row(chunk_lanes):
     """The executor alone at the sizes it declares: 1,700 groups take a
-    new maximum each, a chunk of 2,048 lanes an epoch, until every group
-    is full and then once more: 17,000 rows each way against chunks of
-    16,384, so the last two barriers take two rounds."""
+    new maximum each, a chunk an epoch, until every group is full and
+    then once more. A chunk of 2,048 lanes: 17,000 rows each way
+    against gathers of 16,384, so the last two barriers take two
+    rounds. A chunk of 32,768 lanes (four of the cells' pushes): the
+    gathers run at 65,536 lanes, one round, and a delta goes on in
+    16,384 lanes until it holds more rows than that."""
     n_groups = 1700
     ex = RetractableGroupTopNExecutor(
         ("g",), (("v", True),), 10, ("id",),
         {"g": jnp.int64, "id": jnp.int64, "v": jnp.int64},
         capacity=1 << 16, table_id="rounds.gtopn", rank_col="rn",
     )
+    lanes = emission_lanes(chunk_lanes, 1 << 16)
+    assert lanes == (16384 if chunk_lanes == 2048 else 65536)
     view = {}  # id -> rank, as a view keyed by the stream key holds it
     for batch in range(11):
         ids = np.arange(batch * n_groups, (batch + 1) * n_groups)
         ex.apply(StreamChunk.from_numpy(
             {"g": np.arange(n_groups, dtype=np.int64), "id": ids,
-             "v": np.full(n_groups, 100 + batch, np.int64)}, 2048,
+             "v": np.full(n_groups, 100 + batch, np.int64)}, chunk_lanes,
         ))
         chunks = ex.on_barrier(None)
         ops = []
         for c in chunks:
-            assert c.capacity == 16384  # never a capacity-wide chunk
+            # the smallest declared size that holds the chunk's rows,
+            # never a capacity-wide chunk for a delta that needs none
+            assert c.capacity == (
+                16384 if int(c.valid.sum()) <= 16384 else lanes
+            )
             d = c.to_numpy(with_ops=True)
             ops += d["__op__"].tolist()
             for op, i, r in zip(d["__op__"].tolist(), d["id"].tolist(),
@@ -540,7 +553,9 @@ def test_rounds_at_the_declared_floor_lose_no_row():
         assert ops == sorted(ops, key=lambda op: op != int(Op.DELETE))
         n_ret = min(batch, 10) * n_groups
         assert ops.count(int(Op.DELETE)) == n_ret
-        assert len(chunks) == -(-n_ret // 16384) + -(-len(ids) * min(batch + 1, 10) // 16384)
+        assert len(chunks) == -(-n_ret // lanes) + -(
+            -len(ids) * min(batch + 1, 10) // lanes
+        )
         # batch b's bid is its group's highest so far
         want = {
             int(i): batch - b + 1
@@ -548,7 +563,10 @@ def test_rounds_at_the_declared_floor_lose_no_row():
             for i in range(b * n_groups, (b + 1) * n_groups)
         }
         assert view == want
-    assert len(chunks) == 4  # two rounds: 16,384 + 616 rows each way
+    # two rounds, 16,384 + 616 rows each way, or one of 17,000
+    assert [c.capacity for c in chunks] == (
+        [16384] * 4 if lanes == 16384 else [65536] * 2
+    )
     assert int(jnp.sum(ex.erank)) == 55 * n_groups
     assert int(jnp.sum(ex.emitted)) == 10 * n_groups
 

@@ -320,20 +320,42 @@ def _events(seed, ordinals):
 
 
 @pytest.mark.parametrize(
-    "mode,seed", [("graph", 1), ("graph", 2147483999), ("serial", 1)]
+    "mode,seed,floor",
+    [("graph", 1, None), ("graph", 2147483999, None), ("serial", 1, None),
+     # emission sizes from 1,024 lanes up: the Top-N's gathers run at
+     # 16,384 (the join hands it 8,192-lane chunks) and each delta, some
+     # 30 retractions and 270 insertions, goes on in 1,024
+     ("graph", 1, 1024)],
 )
 def test_q6_served_equals_the_reference_across_barriers_and_recovery(
-    tmp_path, mode, seed
+    tmp_path, monkeypatch, mode, seed, floor
 ):
+    if floor is not None:
+        from risingwave_tpu.executors import over_window, top_n_plain
+
+        monkeypatch.setattr(top_n_plain, "_EMIT_FLOOR", floor)
+        monkeypatch.setattr(over_window, "_EMIT_FLOOR", floor)
     events = _events(seed, 24_000)
     served = Served6(tmp_path, 512, mode, capacity=1 << 12)
     try:
         assert list(served.rt.fragments) == ["auction", "bid", "q6"]
         done, seen = 0, set()
         for epoch, cut in enumerate(range(4_000, 24_001, 4_000)):
+            TRACER.clear()
             served.push_until(events, done, cut)
             done = cut
             served.rt.barrier()
+            if floor is not None:
+                # the chunks follow the delta, and the step's width them
+                (rank,), (diff,), (step,) = (
+                    [sp.args for sp in TRACER.spans() if sp.name == name]
+                    for name in ("topn.rank", "topn.diff", "over.step")
+                )
+                deltas = [diff["retract_rows"], diff["insert_rows"]]
+                assert 0 < max(deltas) <= floor < rank["lanes"]
+                assert diff["emit_lanes"] == floor * sum(n > 0 for n in deltas)
+                assert step["chunk_lanes"] == 2 * floor
+                assert step["in_rows"] == sum(deltas)
             if epoch == 2:
                 # kill: drop the device state, rebuild it from the store
                 over = served.executor(GeneralOverWindowExecutor)
@@ -598,3 +620,129 @@ def test_a_delta_past_the_largest_size_goes_in_rounds(monkeypatch):
     ret, ins = (c.to_numpy() for c in outs)
     assert sorted(ret["id"].tolist()) == sorted(ins["id"].tolist()) == [0, 114]
     assert dict(zip(ins["id"].tolist(), ins["c"].tolist())) == {114: 1, 0: 2}
+
+
+# -- the Top-N's chunks follow its delta, and the step's width with them ------
+
+_CUT_FLOOR = 64  # the smallest emission size, for the two tests below
+
+
+def _cut_chain(monkeypatch, cut=True):
+    """A k = 1 Top-N by auction into the general over-window by seller,
+    as q6 plans them, at sizes a CPU test can hold: emission sizes from
+    64 lanes up (x4), the Top-N declaring 64 / 256 / 1,024 (epochs of
+    one, two and eight chunks of 128). ``cut`` False: the Top-N as it
+    was, every chunk at the size its gathers ran at."""
+    import jax.numpy as jnp
+
+    from risingwave_tpu.executors import over_window, top_n_plain
+
+    monkeypatch.setattr(top_n_plain, "_EMIT_FLOOR", _CUT_FLOOR)
+    monkeypatch.setattr(over_window, "_EMIT_FLOOR", _CUT_FLOOR)
+    monkeypatch.setattr(
+        RetractableGroupTopNExecutor, "_WARM_EPOCHS", (1, 256, 1024)
+    )
+    i64 = jnp.int64
+    dtypes = {"a": i64, "id": i64, "price": i64, "seller": i64, "ts": i64}
+    topn = RetractableGroupTopNExecutor(
+        ("a",), "price", 1, ("id",), dtypes, capacity=1 << 12,
+        table_id="cut.topn",
+    )
+    over = GeneralOverWindowExecutor(
+        partition_by=("seller",), order_col="ts", pk=("id",),
+        calls=(WindowCall("sum", "price", "total", frame=(-10, 0)),
+               WindowCall("count", "price", "n", frame=(-10, 0))),
+        schema_dtypes=dtypes, capacity=1 << 12, table_id="cut.over",
+    )
+    if not cut:
+        topn._cut = lambda chunk, rows: chunk
+    return topn, over
+
+
+def _cut_epoch(topn, over, auctions, price):
+    """A bid of ``price`` on each of ``auctions`` auctions and a chunk
+    that holds no row (256 lanes an epoch: the gathers run at 256), the
+    barrier of both executors; what the over-window handed on."""
+    ids = np.arange(auctions, dtype=np.int64)
+    cols = {"a": ids, "id": 1_000_000 - 1_000 * price + ids,
+            "price": np.full(auctions, price, np.int64), "seller": ids % 7,
+            "ts": 10 * ids + price}
+    for part in (cols, {k: v[:0] for k, v in cols.items()}):
+        assert topn.apply(StreamChunk.from_numpy(part, 128)) == []
+    handed = topn.on_barrier(None)
+    for c in handed:
+        assert over.apply(c) == []
+    return handed, over.on_barrier(None)
+
+
+def _sorted_rows(chunks):
+    out = []
+    for c in chunks:
+        d = c.to_numpy(with_ops=True)
+        names = sorted(d)
+        out.append(sorted(zip(*(d[n].tolist() for n in names))))
+    return out
+
+
+def _staged(ex):
+    out = []
+    for d in ex.checkpoint_delta():
+        cols = {**d.key_cols, **d.value_cols, "tomb": d.tombstone}
+        names = sorted(cols)
+        out.append((d.table_id, names, sorted(
+            zip(*(np.asarray(cols[n]).tolist() for n in names))
+        )))
+    return out
+
+
+@pytest.mark.parametrize("auctions", (_CUT_FLOOR, _CUT_FLOOR + 1))
+def test_a_delta_that_fits_the_floor_steps_the_window_at_twice_the_floor(
+    monkeypatch, auctions
+):
+    """Each auction's kept bid is undercut in the second epoch: as many
+    retractions and as many insertions as auctions. 64 of each go on in
+    two chunks of 64 lanes and the window steps over 128; 65 keep the
+    256 lanes the gathers ran at and the window steps over 512, as
+    every delta did. Either way the window hands on what it handed on
+    behind the uncut Top-N, the checkpoint stages the same rows of both
+    executors, and — the sizes compiled when the chain was warmed — no
+    barrier of either kind compiles a program."""
+    from risingwave_tpu.array.chunk import _leading_lanes
+    from risingwave_tpu.executors.over_window import (
+        _general_over_commit, _general_over_emit, _general_over_lay,
+        _general_over_step,
+    )
+    from risingwave_tpu.executors.top_n_plain import _diff_gather, _rank
+
+    topn, over = _cut_chain(monkeypatch)
+    ref_topn, ref_over = _cut_chain(monkeypatch, cut=False)
+    assert topn.emission_sizes() == (64, 256, 1024)
+    assert step_widths(over.capacity) == (128, 512, 2048, 8192)
+    for ex, window in ((topn, over), (ref_topn, ref_over)):
+        for c in ex.warm_emissions():
+            window.warm(c)
+    programs = (_leading_lanes, _rank, _diff_gather, _general_over_lay,
+                _general_over_step, _general_over_emit, _general_over_commit)
+    compiled = [f._cache_size() for f in programs]
+    small = auctions <= _CUT_FLOOR
+    for price in (900, 500):
+        TRACER.clear()
+        handed, got = _cut_epoch(topn, over, auctions, price)
+        (step,) = [sp for sp in TRACER.spans() if sp.name == "over.step"]
+        (diff,) = [sp for sp in TRACER.spans() if sp.name == "topn.diff"]
+        ref_handed, want = _cut_epoch(ref_topn, ref_over, auctions, price)
+        assert {c.capacity for c in ref_handed} == {256}
+        assert {c.capacity for c in handed} == {64 if small else 256}
+        assert diff.args["emit_lanes"] == sum(c.capacity for c in handed)
+        assert step.args["in_rows"] == (auctions if price == 900 else 2 * auctions)
+        assert _sorted_rows(handed) == _sorted_rows(ref_handed)
+        assert _sorted_rows(got) == _sorted_rows(want)
+        assert [c.capacity for c in got] == [c.capacity for c in want]
+        assert _staged(over) == _staged(ref_over)
+        assert _staged(topn) == _staged(ref_topn)
+    # the undercut epoch: a retract chunk and an insert chunk
+    assert len(handed) == 2 and diff.args["retract_rows"] == auctions
+    assert step.args["chunk_lanes"] == (
+        2 * _CUT_FLOOR if small else 2 * 4 * _CUT_FLOOR
+    )
+    assert [f._cache_size() for f in programs] == compiled

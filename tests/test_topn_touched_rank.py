@@ -19,6 +19,7 @@ from risingwave_tpu.executors.top_n_plain import (
     candidate_lanes,
     emission_lanes,
 )
+from risingwave_tpu.metrics import REGISTRY
 from risingwave_tpu.trace import TRACER
 from risingwave_tpu.types import Op
 
@@ -429,3 +430,173 @@ def test_the_sizes_of_the_candidates():
     assert candidate_lanes(4 * CHUNK, cap, 1) == 16 * FLOOR
     assert candidate_lanes(4 * CHUNK + 1, cap, 1) is None
     assert emission_lanes(2 * (4 * CHUNK + 1), cap) == 64 * FLOOR
+
+
+# -- what a barrier hands on is cut to its delta ------------------------------
+
+
+@pytest.fixture
+def ladder(monkeypatch):
+    """Declared emission sizes of 64 / 256 / 1,024 lanes: what epochs
+    of one, two and eight of these tests' chunks gather into."""
+    monkeypatch.setattr(
+        RetractableGroupTopNExecutor, "_WARM_EPOCHS", (1, 2 * CHUNK, 8 * CHUNK)
+    )
+
+
+def _uncut(shape):
+    """The executor as it was before a chunk followed its delta: every
+    chunk goes on at the size the gathers ran at."""
+    ref = _executor(shape)
+    ref._cut = lambda chunk, rows: chunk
+    return ref
+
+
+def _new_firsts(groups, v):
+    """A row that outranks all that stand, in each of ``groups`` groups
+    (``v`` differs by epoch), and a second chunk that holds no row: an
+    epoch of two chunks, whose gathers run at 256 lanes."""
+    rows = [(g, 1_000 * v + g, v, 0) for g in range(groups)]
+    return [_chunk(rows, [int(Op.INSERT)] * groups), _chunk([], [])]
+
+
+def _diff_of(table_id):
+    (sp,) = [
+        sp for sp in TRACER.spans()
+        if sp.name == "topn.diff" and sp.args["table_id"] == table_id
+    ]
+    return sp.args
+
+
+def _declared_size(ex, rows, gathered):
+    return next((s for s in ex.emission_sizes() if s >= rows), gathered)
+
+
+@pytest.mark.parametrize("groups", (FLOOR // 2, FLOOR // 2 + 1, FLOOR, FLOOR + 1))
+@pytest.mark.parametrize("shape", sorted(SHAPES))
+def test_a_barriers_chunks_are_as_wide_as_their_deltas(shape, groups, ladder):
+    """Each of the two chunks goes on at the smallest declared size
+    that holds ITS rows — ``FLOOR`` rows take ``FLOOR`` lanes, one more
+    the next size, the size the gathers ran at — with the rows, ops and
+    order of the chunk as gathered, and the lanes ``emitted`` /
+    ``erank`` / ``shadow`` after it as they were."""
+    ex, ref = _executor(shape), _uncut(shape)
+    assert ex.emission_sizes() == (FLOOR, 4 * FLOOR, 16 * FLOOR)
+    for v in (1, 2):  # the second epoch's firsts displace the first's
+        for c in _new_firsts(groups, v):
+            ex.apply(c)
+            ref.apply(c)
+        TRACER.clear()
+        got = ex.on_barrier(None)
+        diff = _diff_of(ex.table_id)
+        want = ref.on_barrier(None)
+        assert {c.capacity for c in want} == {4 * FLOOR}
+        assert _rows_of(got) == _rows_of(want)
+        rows = [r for r in (diff["retract_rows"], diff["insert_rows"]) if r]
+        assert [int(c.valid.sum()) for c in got] == rows
+        assert [c.capacity for c in got] == [
+            _declared_size(ex, r, 4 * FLOOR) for r in rows
+        ]
+        _same_state(ex, ref)
+    # at k = 1 each delta of the second epoch is the groups': FLOOR
+    # fits the floor, FLOOR + 1 does not (k = 3 has room for both rows
+    # and retracts nothing; k = 10 hands the old first on again, second)
+    assert len(got) == (1 if shape == "two_keys" else 2)
+    if shape == "k1":
+        assert rows == [groups, groups]
+        assert {c.capacity for c in got} == {
+            FLOOR if groups <= FLOOR else 4 * FLOOR
+        }
+
+
+def test_a_numbered_delta_of_two_rounds_is_cut_in_its_last_round_only(ladder):
+    """k = 10, thirty full groups, a new first in each: 300 rows each
+    way where the gathers run at 256 lanes, so two rounds a side. The
+    rounds are computed as they were (each from the ``256 x r``-th row
+    of the same ranking); the full round goes on whole, the last at the
+    size its 44 rows take, every retraction before any insertion, and
+    row for row what the uncut chunks hold."""
+    ex, ref = _executor("k10_rank_col"), _uncut("k10_rank_col")
+    for e in (ex, ref):
+        for i in range(10):
+            _standing(e, [(g, 100 * g + i, i, 0) for g in range(30)])
+        for c in _new_firsts(30, 50):
+            e.apply(c)
+    TRACER.clear()
+    got = ex.on_barrier(None)
+    diff, pull = _diff_of(ex.table_id), _pull_of(ex.table_id)
+    want = ref.on_barrier(None)
+    assert diff["retract_rows"] == diff["insert_rows"] == 300
+    assert diff["rounds"] == pull["rounds"] == 2
+    assert [c.capacity for c in want] == [4 * FLOOR] * 4
+    assert [c.capacity for c in got] == [4 * FLOOR, FLOOR] * 2
+    assert [int(c.valid.sum()) for c in got] == [256, 44] * 2
+    assert _rows_of(got) == _rows_of(want)
+    ops = [row[-1] for rows in _rows_of(got) for row in rows]
+    assert ops == [int(Op.DELETE)] * 300 + [int(Op.INSERT)] * 300
+    assert diff["emit_lanes"] == 2 * (4 * FLOOR + FLOOR)
+    _same_state(ex, ref)
+    _chains_say_the_top_k(ex)
+
+
+@pytest.mark.parametrize("shape", sorted(SHAPES))
+def test_the_lanes_and_rows_of_a_barrier_are_those_of_its_chunks(shape, ladder):
+    """``topn.diff``'s ``emit_lanes`` and the counter
+    ``group_topn_emitted_lanes_total{op}`` are the lanes of the chunks
+    handed on, as ``retract_rows`` / ``insert_rows`` and
+    ``group_topn_emitted_rows_total{op}`` are their rows: lanes / rows
+    of a barrier can be read from either."""
+    ex = _executor(shape)
+    stream = _Stream(13, groups=40)
+    lanes = REGISTRY.counter("group_topn_emitted_lanes_total")
+    rows = REGISTRY.counter("group_topn_emitted_rows_total")
+    for epoch in range(4):
+        before = {
+            op: (lanes.get(table_id=ex.table_id, op=op),
+                 rows.get(table_id=ex.table_id, op=op))
+            for op in ("retract", "insert")
+        }
+        for _ in range(2):
+            ex.apply(stream.chunk(int(stream.rng.integers(20, CHUNK))))
+        TRACER.clear()
+        got = ex.on_barrier(None)
+        diff = _diff_of(ex.table_id)
+        by_op = {"retract": [], "insert": []}
+        for c in got:
+            (op,) = set(np.asarray(c.ops).tolist())
+            by_op["retract" if op == int(Op.DELETE) else "insert"].append(c)
+        for op, chunks in by_op.items():
+            assert lanes.get(table_id=ex.table_id, op=op) - before[op][0] == sum(
+                c.capacity for c in chunks
+            )
+            assert rows.get(table_id=ex.table_id, op=op) - before[op][1] == sum(
+                int(c.valid.sum()) for c in chunks
+            ) == diff[f"{op}_rows"]
+        assert diff["emit_lanes"] == sum(c.capacity for c in got)
+        assert diff["emit_lanes"] <= 2 * diff["rounds"] * 4 * FLOOR
+
+
+def test_the_cut_is_compiled_with_the_sizes_it_cuts(ladder):
+    """``warm_emissions`` runs the cut from every declared size to every
+    smaller one, so that a barrier whose delta is small, and one whose
+    delta is not, compile nothing — the first of either kind a stream
+    meets included."""
+    from risingwave_tpu.array.chunk import _leading_lanes
+    from risingwave_tpu.executors.top_n_plain import _diff_gather, _rank
+
+    ex = _executor("k1")
+    assert [c.capacity for c in ex.warm_emissions()] == [
+        FLOOR, 4 * FLOOR, 16 * FLOOR
+    ]
+    compiled = [f._cache_size() for f in (_leading_lanes, _rank, _diff_gather)]
+    for groups in (3, FLOOR, FLOOR + 1):  # each epoch outranks the last
+        for v in (1, 2):
+            for c in _new_firsts(groups, 10 * groups + v):
+                ex.apply(c)
+            got = ex.on_barrier(None)
+        assert {c.capacity for c in got} == {
+            FLOOR if groups <= FLOOR else 4 * FLOOR
+        }
+    assert [
+        f._cache_size() for f in (_leading_lanes, _rank, _diff_gather)
+    ] == compiled
